@@ -5,8 +5,8 @@ PY ?= python
 CPU_ENV = JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8
 
 .PHONY: all native test fast-test unit-test e2e-test demo bench bench-smoke bench-8b bench-pressure bench-tier bench-lag10 \
-        routing-bench engine-bench engine-bench-8b moe-bench poolsize-bench \
-        kernel-parity dryrun docker lint
+        routing-bench engine-bench engine-bench-8b moe-bench \
+        chip-smoke chip-smoke-dry dryrun docker lint
 
 all: native test
 
@@ -36,7 +36,8 @@ demo:
 	$(CPU_ENV) $(PY) examples/kv_cache_aware_scorer.py
 	$(CPU_ENV) $(PY) examples/fleet_demo.py
 
-## Headline routing benchmark (TPU; smoke variant runs anywhere).
+## Headline routing benchmark (needs the TPU; exits non-zero without one —
+## the smoke variant is the explicit CPU run).
 bench:
 	$(PY) bench.py
 
@@ -53,7 +54,7 @@ bench-8b:
 bench-pressure:
 	BENCH_TOTAL_PAGES=1536 BENCH_POLICIES=precise,estimated $(PY) bench.py
 
-## Host-DRAM tier A/B at the round-3 thrash config (results/tiering.md).
+## Host-DRAM tier A/B at the round-3 thrash config.
 bench-tier:
 	BENCH_TOTAL_PAGES=192 BENCH_GROUPS=8 BENCH_PREFIX_LEN=2048 \
 	BENCH_HOST_PAGES=1024 BENCH_POLICIES=precise BENCH_PRESSURE=0 $(PY) bench.py
@@ -74,13 +75,17 @@ engine-bench-8b:
 moe-bench:
 	$(PY) benchmarking/bench_moe.py
 
-poolsize-bench:
-	$(PY) benchmarking/bench_decode_poolsize.py
+## The one command that proves the main path runs on the chip: compiled
+## kernels vs their references at served shapes, then a ScoringService and
+## PodServer(s) answering real HTTP traffic (one replica per visible chip).
+## Exits non-zero where JAX finds no TPU.
+chip-smoke:
+	$(PY) chip_smoke.py
 
-## On-chip numerics check for the Pallas flash-prefill kernel (run before
-## trusting kernel benchmarks — interpret-mode parity is not enough).
-kernel-parity:
-	$(PY) benchmarking/tpu_parity_flash_prefill.py
+## Same code on the CPU (tiny preset, interpreter, virtual devices) — for
+## debugging the command itself; never a device result.
+chip-smoke-dry:
+	$(PY) chip_smoke.py --dry-run
 
 ## Multi-chip dry-run on a virtual 8-device CPU mesh.
 dryrun:

@@ -130,7 +130,9 @@ def main() -> int:
         SchedulerConfig,
     )
 
-    smoke = os.environ.get("BENCH_SMOKE", "") == "1" or jax.default_backend() != "tpu"
+    # Smoke only when asked for; the chip config on a machine with no
+    # chip fails at engine construction.
+    smoke = os.environ.get("BENCH_SMOKE", "") == "1"
     if smoke:
         model_cfg, page, total_pages = TINY_LLAMA, 4, 512
         prefix_blocks = [1, 2, 4, 8, 16]

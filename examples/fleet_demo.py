@@ -7,8 +7,17 @@ HTTP; its BlockStored events cross a TCP ZMQ hop into the scoring service's
 SUB-bound subscriber; the indexer then scores the pod for the same prompt —
 the complete closed loop every deployment relies on.
 
-Run (CPU is fine):
+Run (CPU only):
     JAX_PLATFORMS=cpu python examples/fleet_demo.py
+
+NOT a pattern for a TPU host. This parent imports JAX and the ``server``
+package before it starts the pod as a child; it pins itself (and, through
+the inherited environment, the child) to the CPU, which is right for a
+demo. On a machine with a chip, one process holds the chip: a parent that
+has initialised a JAX backend takes it, and a child that needs it then
+fails or hangs. There, run every replica in ONE process, each engine on
+its own device (``PodServer(mesh=...)`` — see ``chip_smoke.py``), or keep
+the launching parent off JAX entirely.
 """
 
 import asyncio
@@ -91,6 +100,8 @@ def main() -> int:
         "MAX_MODEL_LEN": "128",
         "DECODE_BATCH_SIZE": "4",
         "HTTP_PORT": str(POD_PORT),
+        # The child is a CPU pod whatever the parent's environment said.
+        "JAX_PLATFORMS": "cpu",
         "INTERPRET": "1",
     }
     # Child output goes to a file, not a pipe: an undrained pipe fills at

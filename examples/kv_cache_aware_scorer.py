@@ -88,8 +88,7 @@ def main() -> int:
 
         # For schedulers that want the whole decision (not just one scorer
         # in a blend), kvcache.BlendedRouter ships the measured-best blend:
-        # index score -> routed-affinity tiebreak -> load
-        # (benchmarking/results/routing_capacity.md round 4).
+        # index score -> routed-affinity tiebreak -> load.
         from llm_d_kv_cache_manager_tpu.kvcache import (
             BlendedRouter,
             PrefixAffinityTracker,

@@ -186,16 +186,12 @@ class NativeMemoryIndex(Index):
         model_name: str,
         hashes: Sequence[int],
         pod_filter: Optional[set[str]] = None,
-    ) -> Optional[tuple[int, list[list[str]]]]:
+    ) -> tuple[int, list[list[str]]]:
         """Read-side lookup from raw chain hashes: C++ shared lock, no LRU
         promotion, no Python lock — the sharded score fan-out's per-shard
         read. Returns ``(processed, per-hash pod-name lists)`` with the
         same early-stop semantics as ``lookup`` (``processed < len(hashes)``
-        marks a present-but-empty key at that position), or ``None`` when
-        the loaded library predates the read-side symbol (caller falls back
-        to the promoting path)."""
-        if not self._idx.has_lookup_ro:
-            return None
+        marks a present-but-empty key at that position)."""
         if not hashes:
             return 0, []
         mid = self._model_id(model_name, create=False)
@@ -261,34 +257,28 @@ class NativeMemoryIndex(Index):
         if pods:
             self._idx.evict(mid, key.chunk_hash, pods, tiers)
 
-    def _distinct_pod_ids(self) -> Optional[list[int]]:
+    def _distinct_pod_ids(self) -> list[int]:
         """Exact distinct pod ids holding >= 1 entry via the C occupancy
-        walk; None on a pre-PR-11 library. Exactness matters once shards
-        share an intern table: the ever-interned count is GROUP-wide, so
-        per-shard gauges fed from it would read identically flat."""
+        walk. Exactness matters once shards share an intern table: the
+        ever-interned count is GROUP-wide, so per-shard gauges fed from it
+        would read identically flat."""
         snap = self._snap
         return self._idx.distinct_pods(max(len(snap.pod_names), 1))
 
     def size_info(self) -> dict:
-        ids = self._distinct_pod_ids()
-        if ids is None:
-            # Library predates the occupancy walk: pods ever interned this
-            # process (a documented superset — see docs/observability.md).
-            return {
-                "blocks": int(self._idx.size()),
-                "pods": len(self._snap.pod_names),
-            }
-        return {"blocks": int(self._idx.size()), "pods": len(ids)}
+        return {
+            "blocks": int(self._idx.size()),
+            "pods": len(self._distinct_pod_ids()),
+        }
 
     def pod_names(self) -> Optional[Sequence[str]]:
         """Distinct pods currently holding >= 1 entry (exact via the C
-        occupancy walk; falls back to the ever-interned superset on an old
-        library). Lets the sharded facade union pods across shards."""
-        ids = self._distinct_pod_ids()
+        occupancy walk). Lets the sharded facade union pods across
+        shards."""
         names = self._snap.pod_names
-        if ids is None:
-            return names
-        return sorted(names[pid] for pid in ids if pid < len(names))
+        return sorted(
+            names[pid] for pid in self._distinct_pod_ids() if pid < len(names)
+        )
 
     def evict_pod(self, pod_identifier: str) -> int:
         pid = self._pod_id(pod_identifier, create=False)

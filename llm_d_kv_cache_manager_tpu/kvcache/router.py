@@ -9,8 +9,8 @@ because round-4 fleet measurements showed pure index routing INVERTING
 under pool thrash: when every pod's cache churns, the index truthfully
 reports "cold everywhere", and load-tiebreaking then scatters each
 prefix group across pods so no warmth ever forms — an index-free sticky
-LRU beat it 2× at the tail (benchmarking/results/routing_capacity.md,
-round-4 section).
+LRU beat it at the tail (round-4 builder's account; on today's chip:
+not measured).
 
 ``BlendedRouter`` ranks pods by:
 

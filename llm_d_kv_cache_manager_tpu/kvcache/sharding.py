@@ -178,8 +178,8 @@ class ShardedIndex(Index):
 
     def _refresh_native_fan(self) -> None:
         """Detect the one-C-call read fan: every shard a NativeMemoryIndex
-        sharing ONE intern store (``NativeMemoryIndex.shard_group``), with
-        a library new enough for ``lruidx_score_sharded``. Then a score
+        sharing ONE intern store (``NativeMemoryIndex.shard_group``). Then
+        a score
         fan-out is a single native call that shared-locks every shard
         inside C — one GIL release round trip, no Python lock, concurrent
         with applies on all shards. Published as ONE immutable tuple in a
@@ -193,9 +193,8 @@ class ShardedIndex(Index):
         except Exception:  # pragma: no cover - import surface
             self._fan = None
             return
-        if (
-            _nl.score_sharded_available()
-            and all(isinstance(s, NativeMemoryIndex) for s in self.shards)
+        if _nl.available() and all(
+            isinstance(s, NativeMemoryIndex) for s in self.shards
         ):
             store = self.shards[0]._interns
             if all(s._interns is store for s in self.shards):
@@ -369,16 +368,14 @@ class ShardedIndex(Index):
                 return fused(model_name, hashes, pod_filter)
         for sid, (sub_pos, sub_hashes) in groups.items():
             shard = self.shards[sid]
-            resolved: Optional[list[Optional[list[str]]]] = None
+            resolved: list[Optional[list[str]]]
             ro = getattr(shard, "lookup_hashes_ro", None)
             if ro is not None:
-                out = ro(model_name, sub_hashes, pod_filter)
-                if out is not None:
-                    processed, per_hash = out
-                    resolved = list(per_hash) + [None] * (
-                        len(sub_hashes) - processed
-                    )
-            if resolved is None:
+                processed, per_hash = ro(model_name, sub_hashes, pod_filter)
+                resolved = list(per_hash) + [None] * (
+                    len(sub_hashes) - processed
+                )
+            else:
                 keys = [Key(model_name, h) for h in sub_hashes]
                 found = shard.lookup(keys, pod_filter)
                 resolved = [found.get(k) for k in keys]

@@ -76,8 +76,6 @@ def _paged_attention_tp(
         )
     from jax.sharding import PartitionSpec as P
 
-    from ..parallel.mesh import shard_map_compat
-
     kv_spec = (
         P(None, None, None, "tp") if kp.ndim == 5 else P(None, None, "tp")
     )
@@ -99,20 +97,23 @@ def _paged_attention_tp(
                 interpret=interpret, layer=layer,
             )
 
-        fn = shard_map_compat(
+        fn = jax.shard_map(
             call,
             mesh=mesh,
             in_specs=tuple(in_specs + [scale_spec, scale_spec]),
             out_specs=P(None, "tp"),
+            check_vma=False,
         )
         return fn(*args, k_scale, v_scale)
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         functools.partial(paged_attention, interpret=interpret, layer=layer),
         mesh=mesh,
         in_specs=tuple(in_specs),
         out_specs=P(None, "tp"),
+        check_vma=False,
     )
     return fn(*args)
+
 
 def _sp_prefill_attention(
     q, k, v, k_pages_l, v_pages_l, block_tables, ctx_lens, positions, valid, mesh
@@ -140,7 +141,6 @@ def _sp_prefill_attention(
     """
     from jax.sharding import PartitionSpec as P
 
-    from ..parallel.mesh import shard_map_compat
     from ..parallel.ring_attention import ring_attention_shard
 
     has_tp = mesh.shape.get("tp", 1) > 1
@@ -181,7 +181,7 @@ def _sp_prefill_attention(
     head = "tp" if has_tp else None
     qkv_spec = P(None, "sp", head, None)
     seq_spec = P(None, "sp")
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(
@@ -190,6 +190,7 @@ def _sp_prefill_attention(
             P(None, "sp"), P(),
         ),
         out_specs=qkv_spec,
+        check_vma=False,
     )
     return fn(
         q, k, v, positions, valid, k_pages_l, v_pages_l, block_tables, ctx_lens
@@ -208,7 +209,8 @@ def _check_right_padded_mask(ok) -> None:
 
 
 def _flash_prefill_tp(
-    q, k, v, k_pages_l, v_pages_l, block_tables, ctx_lens, n_valid, *, mesh
+    q, k, v, k_pages_l, v_pages_l, block_tables, ctx_lens, n_valid, *,
+    interpret, mesh,
 ):
     """Pallas flash prefill, head-parallel over the ``tp`` mesh axis.
 
@@ -220,22 +222,22 @@ def _flash_prefill_tp(
     """
     from ..ops.flash_prefill import flash_prefill_paged
 
+    kernel = functools.partial(flash_prefill_paged, interpret=interpret)
     if mesh is None:
-        return flash_prefill_paged(
+        return kernel(
             q, k, v, k_pages_l, v_pages_l, block_tables, ctx_lens, n_valid
         )
     from jax.sharding import PartitionSpec as P
 
-    from ..parallel.mesh import shard_map_compat
-
-    fn = shard_map_compat(
-        flash_prefill_paged,
+    fn = jax.shard_map(
+        kernel,
         mesh=mesh,
         in_specs=(
             P(None, None, "tp"), P(None, None, "tp"), P(None, None, "tp"),
             P(None, None, "tp"), P(None, None, "tp"), P(), P(), P(),
         ),
         out_specs=P(None, None, "tp"),
+        check_vma=False,
     )
     return fn(q, k, v, k_pages_l, v_pages_l, block_tables, ctx_lens, n_valid)
 
@@ -271,11 +273,12 @@ class LlamaConfig:
     # einsum over ALL experts — the numerics oracle, and the layout that
     # GSPMD expert-parallel sharding partitions today).
     moe_dispatch: str = "routed"
-    # Grouped-matmul backend for the routed dispatch: "auto" (Pallas gmm
-    # kernel on TPU — megablox for bf16, in-VMEM-dequant kernel for int8
-    # experts — XLA ragged_dot elsewhere), "kernel" (force the Pallas
-    # path; interpret-mode off-TPU), or "xla" (force ragged_dot — the
-    # parity oracle). See ops/gmm.py and results/moe_dispatch.md.
+    # Grouped-matmul backend for the routed dispatch: "auto" (the Pallas
+    # gmm kernel — megablox for bf16, in-VMEM-dequant kernel for int8
+    # experts — unless the caller runs with ``interpret=True``, where it
+    # is XLA ragged_dot: CPU tests and dry runs), "kernel" (the Pallas
+    # path always; interpreted when the caller says so), or "xla"
+    # (ragged_dot — the parity oracle). Never chosen from the backend.
     moe_gmm: str = "auto"
     # Gemma-style variations: gated-GELU FFN ("gelu_tanh"), (1+w) RMSNorm
     # scaling (norm_offset=1.0), embeddings scaled by sqrt(hidden_size).
@@ -482,9 +485,8 @@ def init_params(
     created, so the full-precision tree is never resident — required to
     init 8B-class models on a single chip (16 GB bf16 + 8 GB int8 would
     not fit; see models/quant.py). MoE expert stacks stay in model dtype
-    unless ``quantize_experts=True`` (opt-in; with the gmm kernel's
-    in-VMEM dequant int8 experts run ≈ bf16 speed while halving expert
-    HBM — results/moe_dispatch.md).
+    unless ``quantize_experts=True`` (opt-in; the gmm kernel dequantizes
+    int8 experts in VMEM while halving expert HBM).
     """
     if quantize not in (None, "int8"):
         raise ValueError(f"unknown quantize mode {quantize!r}")
@@ -554,27 +556,36 @@ def init_kv_pages(
     total_pages: int,
     page_size: int,
     kv_quant_hbm: Optional[str] = None,
+    sharding=None,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Zeroed K and V page pools:
     ``[n_layers, total_pages, page_size, n_kv_heads, head_dim]``.
 
     With ``kv_quant_hbm="int8"`` the pools hold int8 codes (half the HBM
     bytes per page — 2× pages per chip at the same budget); the matching
-    per-page scale pools come from :func:`init_kv_scales`."""
+    per-page scale pools come from :func:`init_kv_scales`. ``sharding``
+    (a ``Sharding`` or ``Device``) creates the pools in place there;
+    default: the process default device."""
     shape = (cfg.n_layers, total_pages, page_size, cfg.n_kv_heads, cfg.hd)
     dtype = jnp.int8 if kv_quant_hbm == "int8" else cfg.dtype
-    return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
+    return (
+        jnp.zeros(shape, dtype, device=sharding),
+        jnp.zeros(shape, dtype, device=sharding),
+    )
 
 
 def init_kv_scales(
-    cfg: LlamaConfig, total_pages: int
+    cfg: LlamaConfig, total_pages: int, sharding=None
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Zeroed per-page-per-(layer, kv_head) f32 scale pools
     ``[n_layers, total_pages, n_kv_heads]`` for an int8 HBM KV pool
     (``KV_QUANT_HBM=int8``). Zero scales dequantize to exact zeros, so a
     fresh quantized pool reads identically to the legacy zeroed bf16 pool."""
     shape = (cfg.n_layers, total_pages, cfg.n_kv_heads)
-    return jnp.zeros(shape, jnp.float32), jnp.zeros(shape, jnp.float32)
+    return (
+        jnp.zeros(shape, jnp.float32, device=sharding),
+        jnp.zeros(shape, jnp.float32, device=sharding),
+    )
 
 
 def _qkv(layer: Params, cfg: LlamaConfig, x: jnp.ndarray):
@@ -640,21 +651,22 @@ def _moe_mlp_dense(layer: Params, cfg: LlamaConfig, x: jnp.ndarray) -> jnp.ndarr
     )
 
 
-def _grouped_dot(cfg: LlamaConfig, row_group_ids: jnp.ndarray):
+def _grouped_dot(cfg: LlamaConfig, row_group_ids: jnp.ndarray, interpret: bool):
     """Grouped-matmul dispatcher for the routed MoE paths.
 
     Returns ``gdot(lhs, w, group_sizes)`` routing to the Pallas gmm kernel
     (``ops/gmm.py`` — megablox for bf16, in-VMEM-dequant for int8 expert
     stacks) per ``cfg.moe_gmm``, with ``jax.lax.ragged_dot`` as the XLA
-    fallback/oracle. ``row_group_ids`` is the sorted expert id per row —
+    path/oracle. ``row_group_ids`` is the sorted expert id per row —
     needed to apply per-output-channel int8 scales on the kernel output.
     """
     from ..ops.gmm import grouped_matmul
 
     if cfg.moe_gmm not in ("auto", "kernel", "xla"):
         raise ValueError(f"unknown moe_gmm {cfg.moe_gmm!r}")
-    on_tpu = jax.default_backend() == "tpu"
-    use_kernel = cfg.moe_gmm == "kernel" or (cfg.moe_gmm == "auto" and on_tpu)
+    use_kernel = cfg.moe_gmm == "kernel" or (
+        cfg.moe_gmm == "auto" and not interpret
+    )
 
     def gdot(lhs, w, group_sizes):
         if not isinstance(w, QuantizedTensor):
@@ -664,14 +676,16 @@ def _grouped_dot(cfg: LlamaConfig, row_group_ids: jnp.ndarray):
             w,
             group_sizes,
             row_group_ids=row_group_ids,
-            interpret=not on_tpu,
+            interpret=interpret,
             use_kernel=use_kernel,
         )
 
     return gdot
 
 
-def _moe_mlp_routed(layer: Params, cfg: LlamaConfig, x: jnp.ndarray) -> jnp.ndarray:
+def _moe_mlp_routed(
+    layer: Params, cfg: LlamaConfig, x: jnp.ndarray, interpret: bool = False
+) -> jnp.ndarray:
     """Routed sparse-MoE SwiGLU FFN: grouped top-k gather dispatch.
 
     Per-token expert FLOPs scale with ``top-k``, not ``n_experts`` — the
@@ -699,7 +713,7 @@ def _moe_mlp_routed(layer: Params, cfg: LlamaConfig, x: jnp.ndarray) -> jnp.ndar
     src_tok = token_ids[order]  # [n*k] token each sorted row came from
     xs = xf[src_tok]  # [n*k, d] gathered inputs, expert-contiguous
     group_sizes = jnp.bincount(expert_ids, length=cfg.n_experts)
-    gdot = _grouped_dot(cfg, expert_ids[order])
+    gdot = _grouped_dot(cfg, expert_ids[order], interpret)
 
     gate = cfg.act_fn(gdot(xs, layer["w_gate"], group_sizes).astype(jnp.float32))
     up = gdot(xs, layer["w_up"], group_sizes).astype(jnp.float32)
@@ -712,7 +726,8 @@ def _moe_mlp_routed(layer: Params, cfg: LlamaConfig, x: jnp.ndarray) -> jnp.ndar
 
 
 def _moe_mlp_routed_ep(
-    layer: Params, cfg: LlamaConfig, x: jnp.ndarray, mesh
+    layer: Params, cfg: LlamaConfig, x: jnp.ndarray, mesh,
+    interpret: bool = False,
 ) -> jnp.ndarray:
     """Expert-parallel routed dispatch under ``shard_map`` over the tp axis.
 
@@ -734,8 +749,6 @@ def _moe_mlp_routed_ep(
     ``_moe_mlp`` auto-selects on.
     """
     from jax.sharding import PartitionSpec as P
-
-    from ..parallel.mesh import shard_map_compat
 
     tp = mesh.shape["tp"]
     e_local = cfg.n_experts // tp
@@ -766,7 +779,7 @@ def _moe_mlp_routed_ep(
         # QuantizedTensor expert shards flow into the gmm kernel as-is
         # (specs are pytree prefixes, so q and scale both shard on E);
         # the kernel dequantizes per-tile in VMEM.
-        gdot = _grouped_dot(cfg, expert_ids[order])
+        gdot = _grouped_dot(cfg, expert_ids[order], interpret)
 
         gate = cfg.act_fn(gdot(xg, w_gate, group_sizes).astype(jnp.float32))
         up = gdot(xg, w_up, group_sizes).astype(jnp.float32)
@@ -778,7 +791,7 @@ def _moe_mlp_routed_ep(
         combined = jax.lax.psum(combined, "tp")
         return combined.reshape(b, s, d).astype(xs.dtype)
 
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(
@@ -789,11 +802,15 @@ def _moe_mlp_routed_ep(
             P(batch_axis),
         ),
         out_specs=P(batch_axis),
+        check_vma=False,
     )
     return fn(layer["router"], layer["w_gate"], layer["w_up"], layer["w_down"], x)
 
 
-def _moe_mlp(layer: Params, cfg: LlamaConfig, x: jnp.ndarray, mesh=None) -> jnp.ndarray:
+def _moe_mlp(
+    layer: Params, cfg: LlamaConfig, x: jnp.ndarray, mesh=None,
+    interpret: bool = False,
+) -> jnp.ndarray:
     if cfg.moe_dispatch not in ("routed", "dense"):
         raise ValueError(f"unknown moe_dispatch {cfg.moe_dispatch!r}")
     tp = mesh.shape.get("tp", 1) if mesh is not None else 1
@@ -806,7 +823,7 @@ def _moe_mlp(layer: Params, cfg: LlamaConfig, x: jnp.ndarray, mesh=None) -> jnp.
                 cfg.moe_dispatch == "routed"
                 and cfg.n_experts_per_tok * tp < cfg.n_experts
             ):
-                return _moe_mlp_routed_ep(layer, cfg, x, mesh)
+                return _moe_mlp_routed_ep(layer, cfg, x, mesh, interpret)
             return _moe_mlp_dense(layer, cfg, x)
         # E % tp != 0: weights use the Megatron intermediate-dim fallback
         # (sharding.py). The global routed path would make GSPMD all-gather
@@ -814,13 +831,16 @@ def _moe_mlp(layer: Params, cfg: LlamaConfig, x: jnp.ndarray, mesh=None) -> jnp.
         # GSPMD partitions it along the f dimension.
         return _moe_mlp_dense(layer, cfg, x)
     if cfg.moe_dispatch == "routed":
-        return _moe_mlp_routed(layer, cfg, x)
+        return _moe_mlp_routed(layer, cfg, x, interpret)
     return _moe_mlp_dense(layer, cfg, x)
 
 
-def _mlp(layer: Params, cfg: LlamaConfig, x: jnp.ndarray, mesh=None) -> jnp.ndarray:
+def _mlp(
+    layer: Params, cfg: LlamaConfig, x: jnp.ndarray, mesh=None,
+    interpret: bool = False,
+) -> jnp.ndarray:
     if cfg.n_experts:
-        return _moe_mlp(layer, cfg, x, mesh=mesh)
+        return _moe_mlp(layer, cfg, x, mesh=mesh, interpret=interpret)
     gate = cfg.act_fn((x @ _w(layer["w_gate"], x.dtype)).astype(jnp.float32))
     up = (x @ _w(layer["w_up"], x.dtype)).astype(jnp.float32)
     return ((gate * up).astype(x.dtype)) @ _w(layer["w_down"], x.dtype)
@@ -950,6 +970,7 @@ def _prefill_body(
     ctx_lens: jnp.ndarray,  # [b]
     mesh,
     attn_impl: str,
+    interpret: bool,
     k_scales=None,  # [L, P, n_kv] f32 when KV_QUANT_HBM=int8
     v_scales=None,
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, Any, Any]:
@@ -990,7 +1011,7 @@ def _prefill_body(
             # consecutive chunk positions, right-padded valid mask.
             attn = _flash_prefill_tp(
                 q, k, v, k_pages[li], v_pages[li], block_tables, ctx_lens,
-                n_valid, mesh=mesh,
+                n_valid, interpret=interpret, mesh=mesh,
             )
         else:
             attn = prefill_with_paged_context(
@@ -1003,7 +1024,7 @@ def _prefill_body(
         h = h + attn.reshape(b, s, -1) @ _w(layer["wo"], h.dtype)
 
         x = rms_norm(h, layer["mlp_norm"], cfg.rms_norm_eps, cfg.norm_offset)
-        h = h + _mlp(layer, cfg, x, mesh=mesh)
+        h = h + _mlp(layer, cfg, x, mesh=mesh, interpret=interpret)
 
         fresh_k.append(k)
         fresh_v.append(v)
@@ -1035,7 +1056,9 @@ def _prefill_body(
 
 @functools.partial(
     jax.jit,
-    static_argnames=("cfg", "mesh", "attn_impl", "return_all_logits"),
+    static_argnames=(
+        "cfg", "mesh", "attn_impl", "return_all_logits", "interpret",
+    ),
     donate_argnames=("k_pages", "v_pages", "k_scales", "v_scales"),
 )
 def prefill(
@@ -1055,6 +1078,7 @@ def prefill(
     return_all_logits: bool = False,  # [b, s, vocab] for spec-decode verify
     k_scales=None,  # [L, P, n_kv] f32 — int8 pools (KV_QUANT_HBM)
     v_scales=None,
+    interpret: bool = False,  # Pallas kernels interpreted (CPU tests)
 ) -> tuple[jnp.ndarray, ...]:
     """Process a prompt chunk: returns (logits at last valid position per
     sequence [b, vocab], updated k_pages, v_pages).
@@ -1098,7 +1122,7 @@ def prefill(
     h, k_pages, v_pages, k_scales, v_scales = _prefill_body(
         params, cfg, tokens, positions, valid, k_pages, v_pages,
         page_ids, slot_ids, block_tables, ctx_lens, mesh, attn_impl,
-        k_scales, v_scales,
+        interpret, k_scales, v_scales,
     )
 
     # Knob-off callers keep the legacy 3-tuple; quantized callers get the
@@ -1175,7 +1199,7 @@ def _decode_body(
         h = h + (attn.reshape(b, -1) @ _w(layer["wo"], h.dtype))[:, None, :]
 
         x = rms_norm(h, layer["mlp_norm"], cfg.rms_norm_eps, cfg.norm_offset)
-        h = h + _mlp(layer, cfg, x, mesh=mesh)
+        h = h + _mlp(layer, cfg, x, mesh=mesh, interpret=interpret)
 
         fresh_k.append(k)
         fresh_v.append(v)
@@ -1320,7 +1344,7 @@ def decode_steps(
     jax.jit,
     static_argnames=(
         "cfg", "page_size", "num_rounds", "s_chunk", "ngram", "spec_k",
-        "max_scan", "table_w", "mesh", "attn_impl",
+        "max_scan", "table_w", "mesh", "attn_impl", "interpret",
     ),
     donate_argnames=("k_pages", "v_pages"),
 )
@@ -1342,6 +1366,7 @@ def spec_decode_steps(
     table_w: int,  # block-table width inside packed_i32
     mesh=None,
     attn_impl: str = "xla",
+    interpret: bool = False,
 ) -> tuple[jnp.ndarray, ...]:
     """``num_rounds`` fused speculative-decode rounds with ON-DEVICE
     prompt-lookup proposals — one host sync per burst instead of one per
@@ -1457,6 +1482,7 @@ def spec_decode_steps(
         h, k_pages, v_pages, _, _ = _prefill_body(
             params, cfg, chunk, positions, valid, k_pages, v_pages,
             page_ids, slot_ids, block_tables, start, mesh, attn_impl,
+            interpret,
         )
         logits = _logits(params, cfg, h)  # [b, s_chunk, vocab] f32
 

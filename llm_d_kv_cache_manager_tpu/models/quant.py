@@ -95,10 +95,9 @@ def quantize_params(
     MoE expert stacks (3-D ``[E, in, out]`` weights) are SKIPPED by
     default (conservative — expert numerics are routing-sensitive). With
     ``quantize_experts=True`` they run through the Pallas grouped-matmul
-    kernel's in-VMEM dequant at ≈ bf16 speed while halving expert HBM
-    (round 4; benchmarking/results/moe_dispatch.md — the round-3 2.5×
-    ragged_dot penalty no longer applies when ``moe_gmm`` selects the
-    kernel, which is the TPU default).
+    kernel's in-VMEM dequant while halving expert HBM (``ragged_dot``
+    cannot fuse the dequant; ``moe_gmm="auto"`` selects the kernel
+    whenever the engine is not interpreting).
     """
 
     def convert(d: dict) -> dict:

@@ -56,35 +56,29 @@ def _load() -> Optional[ctypes.CDLL]:
         ]
         lib.lruidx_size.restype = ctypes.c_uint64
         lib.lruidx_size.argtypes = [ctypes.c_void_p]
-        try:  # PR-3 symbol: absent in pre-self-healing builds of the .so
-            lib.lruidx_evict_pod.restype = ctypes.c_uint64
-            lib.lruidx_evict_pod.argtypes = [ctypes.c_void_p, ctypes.c_uint32]
-        except AttributeError:
-            pass
-        try:  # PR-11 symbol: shared-lock read-side lookup (no LRU promote)
-            lib.lruidx_lookup_ro.restype = ctypes.c_uint64
-            lib.lruidx_lookup_ro.argtypes = [
-                ctypes.c_void_p, ctypes.c_uint32, _u64p, ctypes.c_uint64,
-                _u32p, ctypes.c_uint64, _u32p, _u8p, _u32p,
-            ]
-        except AttributeError:
-            pass
-        try:  # PR-11 symbol: exact distinct-pod occupancy walk
-            lib.lruidx_distinct_pods.restype = ctypes.c_uint64
-            lib.lruidx_distinct_pods.argtypes = [
-                ctypes.c_void_p, _u32p, ctypes.c_uint64,
-            ]
-        except AttributeError:
-            pass
-        try:  # PR-11 symbol: one-call cross-shard fused scoring
-            lib.lruidx_score_sharded.restype = ctypes.c_uint64
-            lib.lruidx_score_sharded.argtypes = [
-                ctypes.POINTER(ctypes.c_void_p), ctypes.c_uint64,
-                ctypes.c_uint32, _u64p, _u32p, ctypes.c_uint64,
-                _u32p, ctypes.c_uint64, _u32p, _u32p, _u64p,
-            ]
-        except AttributeError:
-            pass
+        # A library built from today's lruindex.cpp has every symbol; a
+        # stale one fails HERE with AttributeError — rebuild it
+        # (`python -m llm_d_kv_cache_manager_tpu.native.build`).
+        lib.lruidx_evict_pod.restype = ctypes.c_uint64
+        lib.lruidx_evict_pod.argtypes = [ctypes.c_void_p, ctypes.c_uint32]
+        # shared-lock read-side lookup (no LRU promote)
+        lib.lruidx_lookup_ro.restype = ctypes.c_uint64
+        lib.lruidx_lookup_ro.argtypes = [
+            ctypes.c_void_p, ctypes.c_uint32, _u64p, ctypes.c_uint64,
+            _u32p, ctypes.c_uint64, _u32p, _u8p, _u32p,
+        ]
+        # exact distinct-pod occupancy walk
+        lib.lruidx_distinct_pods.restype = ctypes.c_uint64
+        lib.lruidx_distinct_pods.argtypes = [
+            ctypes.c_void_p, _u32p, ctypes.c_uint64,
+        ]
+        # one-call cross-shard fused scoring
+        lib.lruidx_score_sharded.restype = ctypes.c_uint64
+        lib.lruidx_score_sharded.argtypes = [
+            ctypes.POINTER(ctypes.c_void_p), ctypes.c_uint64,
+            ctypes.c_uint32, _u64p, _u32p, ctypes.c_uint64,
+            _u32p, ctypes.c_uint64, _u32p, _u32p, _u64p,
+        ]
         _lib = lib
     except OSError:
         _lib = None
@@ -156,20 +150,10 @@ class NativeLru:
             r += c
         return processed, result
 
-    @property
-    def has_lookup_ro(self) -> bool:
-        return hasattr(self._lib, "lruidx_lookup_ro")
-
     def lookup_ro(self, model: int, hashes, filter_ids):
         """Read-side lookup: same outputs and early-stop semantics as
         ``lookup``, but under the C++ shared lock with NO recency
-        promotion — safe (and concurrent) against in-flight applies.
-        Raises when the loaded library predates the symbol."""
-        if not self.has_lookup_ro:
-            raise RuntimeError(
-                "liblruindex.so predates lruidx_lookup_ro — rebuild with "
-                "`python -m llm_d_kv_cache_manager_tpu.native.build`"
-            )
+        promotion — safe (and concurrent) against in-flight applies."""
         n_keys = len(hashes)
         n_filter = len(filter_ids)
         cap = n_keys * self.pods_per_key
@@ -210,13 +194,7 @@ class NativeLru:
         return [(out_pods[i], out_scores[i]) for i in range(n)], int(out_hits[0])
 
     def evict_pod(self, pod_id: int) -> int:
-        """Remove every entry of ``pod_id``; returns entries removed. Raises
-        when the loaded library predates the symbol (rebuild required)."""
-        if not hasattr(self._lib, "lruidx_evict_pod"):
-            raise RuntimeError(
-                "liblruindex.so predates lruidx_evict_pod — rebuild with "
-                "`python -m llm_d_kv_cache_manager_tpu.native.build`"
-            )
+        """Remove every entry of ``pod_id``; returns entries removed."""
         return int(self._lib.lruidx_evict_pod(self._h, pod_id))
 
     def size(self) -> int:
@@ -224,20 +202,11 @@ class NativeLru:
 
     def distinct_pods(self, cap: int):
         """Exact distinct pod ids currently holding >= 1 entry (shared-lock
-        O(entries) walk — scrape-driven callers only). Returns None when
-        the loaded library predates the symbol (caller falls back to the
-        ever-interned approximation)."""
-        if not hasattr(self._lib, "lruidx_distinct_pods"):
-            return None
+        O(entries) walk — scrape-driven callers only)."""
         cap = max(int(cap), 1)
         out = (ctypes.c_uint32 * cap)()
         n = int(self._lib.lruidx_distinct_pods(self._h, out, cap))
         return [out[i] for i in range(min(n, cap))]
-
-
-def score_sharded_available() -> bool:
-    lib = _load()
-    return lib is not None and hasattr(lib, "lruidx_score_sharded")
 
 
 def score_sharded(lrus, model: int, hashes, owners, filter_ids):
@@ -247,12 +216,7 @@ def score_sharded(lrus, model: int, hashes, owners, filter_ids):
     applies), no LRU promotion, one GIL release round trip total. Pod ids
     MUST be interned in one table shared by all shards. Returns
     ``([(pod_id, score)], hits)`` like ``NativeLru.score``."""
-    lib = _load()
-    if lib is None or not hasattr(lib, "lruidx_score_sharded"):
-        raise RuntimeError(
-            "liblruindex.so predates lruidx_score_sharded — rebuild with "
-            "`python -m llm_d_kv_cache_manager_tpu.native.build`"
-        )
+    lib = lrus[0]._lib
     n_keys = len(hashes)
     n_filter = len(filter_ids)
     handles = (ctypes.c_void_p * len(lrus))(*[lru._h for lru in lrus])

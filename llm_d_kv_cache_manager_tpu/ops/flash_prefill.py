@@ -29,8 +29,8 @@ Contract (what the serving engine guarantees):
   count; fully-padded query rows produce zeros.
 
 `prefill_with_paged_context` (attention.py) is the numerics oracle; parity
-is tested across GQA/MHA/MQA in interpret mode and on real TPU via
-benchmarking/bench_engine.py (round-1 lesson: Mosaic can miscompile —
+is tested across GQA/MHA/MQA in interpret mode and, compiled, on the chip
+by ``chip_smoke.py``'s kernel phase (round-1 lesson: Mosaic can miscompile —
 always check numerics on the chip).
 """
 
@@ -44,9 +44,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# JAX renamed pltpu.TPUMemorySpace -> pltpu.MemorySpace (~0.5); resolve
-# whichever spelling this install has so the kernel runs on both.
-_MemorySpace = getattr(pltpu, "MemorySpace", None) or pltpu.TPUMemorySpace
+from ._mosaic import require_tpu_unless_interpret
 
 # Finite: a fully-masked score row must yield exp(-1e30 - -1e30) = 1,
 # zeroed by the mask multiply — float('-inf') would produce inf-inf = NaN.
@@ -428,8 +426,7 @@ def flash_prefill_paged(
     group = n_q // n_kv
     if scale is None:
         scale = d**-0.5
-    if not interpret and jax.default_backend() == "cpu":
-        interpret = True
+    require_tpu_unless_interpret("flash_prefill_paged", interpret)
     if ctx_mode not in ("gather", "dma"):
         raise ValueError(f"unknown ctx_mode {ctx_mode!r}")
 
@@ -602,8 +599,8 @@ def _flash_prefill_dma(
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, bq, group, d), q_index),
-            pl.BlockSpec(memory_space=_MemorySpace.ANY),
-            pl.BlockSpec(memory_space=_MemorySpace.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec((1, 1, bk_chunk, d), chunk_index),
             pl.BlockSpec((1, 1, bk_chunk, d), chunk_index),
         ],

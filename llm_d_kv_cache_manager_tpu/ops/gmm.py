@@ -4,11 +4,9 @@ The routed MoE pipeline (``models/llama._moe_mlp_routed``) sorts the
 ``n*k`` (token, slot) rows by expert so each expert's rows form one
 contiguous segment, then needs ``out[r] = lhs[r] @ rhs[g(r)]`` where
 ``g(r)`` is the expert owning row ``r``. ``jax.lax.ragged_dot`` expresses
-this but runs far below MXU utilization at our shapes (~19 TFLOP/s
-effective vs the dense einsum's ~141 at Qwen3-30B geometry —
-``benchmarking/results/moe_dispatch.md``), and XLA does not fuse int8
-dequantization into its group-streamed operand, making int8 experts 2.5×
-SLOWER than bf16 there.
+this but ran far below MXU utilization at our shapes in rounds 3–4 (on
+today's chip: not measured), and XLA does not fuse int8 dequantization
+into its group-streamed operand.
 
 Two kernels, one wrapper:
 
@@ -40,10 +38,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..models.quant import QuantizedTensor
-
-# JAX renamed pltpu.TPUCompilerParams -> pltpu.CompilerParams (~0.5);
-# resolve whichever spelling this install has so the kernel runs on both.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
+from ._mosaic import require_tpu_unless_interpret
 
 #: (tm, tk, tn) tile-size ceilings, from the on-chip sweep at Qwen3-30B
 #: geometry (128 experts, d=2048, f=768, 16k rows): 256-row tiles balance
@@ -96,19 +91,20 @@ def grouped_matmul(
     ``use_kernel=False`` falls back to ``jax.lax.ragged_dot`` with
     whole-stack dequantization — the parity oracle for tests.
     """
-    if isinstance(rhs, QuantizedTensor):
-        if row_group_ids is None:
-            raise ValueError("row_group_ids required for quantized rhs")
-        q, scale = rhs.q, rhs.scale  # [E, d, f] int8, [E, 1, f] f32
-        if not use_kernel:
-            w = q.astype(lhs.dtype) * scale.astype(lhs.dtype)
-            return jax.lax.ragged_dot(lhs, w, group_sizes)
-        out = _gmm_int8(lhs, q, group_sizes, interpret=interpret)  # f32
-        # Per-row scale: scale[g(r), 0, :] — fuses downstream.
-        row_scale = scale[row_group_ids, 0, :]  # [rows, f]
-        return (out * row_scale).astype(lhs.dtype)
+    quantized = isinstance(rhs, QuantizedTensor)
+    if quantized and row_group_ids is None:
+        raise ValueError("row_group_ids required for quantized rhs")
     if not use_kernel:
+        if quantized:  # whole-stack dequantization
+            rhs = rhs.q.astype(lhs.dtype) * rhs.scale.astype(lhs.dtype)
         return jax.lax.ragged_dot(lhs, rhs, group_sizes)
+    require_tpu_unless_interpret("grouped_matmul", interpret)
+    if quantized:
+        # rhs.q [E, d, f] int8, rhs.scale [E, 1, f] f32
+        out = _gmm_int8(lhs, rhs.q, group_sizes, interpret=interpret)  # f32
+        # Per-row scale: scale[g(r), 0, :] — fuses downstream.
+        row_scale = rhs.scale[row_group_ids, 0, :]  # [rows, f]
+        return (out * row_scale).astype(lhs.dtype)
     return _gmm_library(lhs, rhs, group_sizes, interpret=interpret)
 
 
@@ -241,7 +237,7 @@ def _gmm_int8(lhs, q, group_sizes, *, interpret: bool):
             grid=(tiles_n, num_active_tiles, tiles_k),
             scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)],
         ),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")
         ),
         cost_estimate=pl.CostEstimate(

@@ -20,7 +20,8 @@ it is a TPU kernel designed for the hardware:
   in float32; GQA handled by blocking query heads [group, head_dim] against
   one KV head.
 
-CPU tests run the same kernel with ``interpret=True``;
+CPU tests run the same kernel with ``interpret=True`` (the caller's choice —
+asking for the compiled kernel off-TPU raises);
 ``paged_attention_reference`` is the numerics oracle.
 """
 
@@ -34,7 +35,38 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ._mosaic import require_tpu_unless_interpret
+
 _NEG_INF = float("-inf")
+
+#: pages per scale block: the int8 pool's scale operand ``[L, pages, n_kv]``
+#: is tiled (8, 128) over its last two dims, and Mosaic wants a block's
+#: second-minor dim divisible by 8 — so a program fetches the 8-page group
+#: holding its page's scale row and picks the row in-kernel.
+_SCALE_ROWS = 8
+
+
+def _page_scale_column(scale_ref, page):
+    """This page's per-kv-head scales as a ``[n_kv, 1]`` column.
+
+    ``scale_ref`` is the ``[1, 8, n_kv]`` block of 8 consecutive pages'
+    scale rows. The row arrives with heads on lanes; the page tile
+    ``[page_size, n_kv, d]`` has heads on sublanes, so the row is turned
+    into a column by a masked reduce over an identity mask — plain 2-D
+    select/reduce ops, no lane→sublane relayout for Mosaic to refuse."""
+    blk = scale_ref[0]  # [8, n_kv] f32
+    n_kv = blk.shape[1]
+    rows = jax.lax.broadcasted_iota(jnp.int32, blk.shape, 0)
+    row = jnp.sum(
+        jnp.where(rows == page % _SCALE_ROWS, blk, 0.0), axis=0, keepdims=True
+    )  # [1, n_kv]
+    eye = jax.lax.broadcasted_iota(
+        jnp.int32, (n_kv, n_kv), 0
+    ) == jax.lax.broadcasted_iota(jnp.int32, (n_kv, n_kv), 1)
+    return jnp.sum(
+        jnp.where(eye, jnp.broadcast_to(row, (n_kv, n_kv)), 0.0),
+        axis=1, keepdims=True,
+    )  # [n_kv, 1]
 
 
 def _decode_kernel(
@@ -66,13 +98,14 @@ def _decode_kernel(
     and the pipeline DMAs HALF the HBM→VMEM bytes per page — the decode
     hot loop is DMA-bound, so this is a bandwidth win on top of the 2×
     capacity win. Per-page-per-(layer, kv_head) f32 scales ride as two
-    extra pipelined operands (same block-table index map, so each program
-    sees exactly its page's scales) and the codes dequantize IN-REGISTER
+    extra pipelined operands (same block-table deref: each program sees
+    the 8-page group holding its page's scale row, ``_SCALE_ROWS``) and
+    the codes dequantize IN-REGISTER
     to f32 before the online softmax — full-width pages never exist
     anywhere. The ``has_fresh`` current-token path stays full-precision:
     fresh K/V arrive unquantized and never round-trip through int8."""
     if quantized:
-        k_scale_ref, v_scale_ref = refs[0], refs[1]  # [1, 1, n_kv] f32
+        k_scale_ref, v_scale_ref = refs[0], refs[1]  # [1, 8, n_kv] f32
         refs = refs[2:]
     if has_fresh:
         fresh_k_ref, fresh_v_ref, out_ref, m_ref, l_ref, acc_ref = refs
@@ -96,13 +129,16 @@ def _decode_kernel(
         q = q_ref[0].astype(jnp.float32)  # [n_kv, group, d]
         # Page tile arrives [page_size, n_kv, d] (one fully-contiguous
         # block); swap to head-major for the batched dot.
-        k = jnp.swapaxes(k_ref[0, 0].astype(jnp.float32), 0, 1)  # [n_kv, ps, d]
-        v = jnp.swapaxes(v_ref[0, 0].astype(jnp.float32), 0, 1)
+        k = k_ref[0, 0].astype(jnp.float32)
+        v = v_ref[0, 0].astype(jnp.float32)
         if quantized:
             # int8 codes → f32, per-(layer, kv_head) page scale broadcast
             # over slots and lanes. Registers only; VMEM holds the codes.
-            k = k * k_scale_ref[0, 0][:, None, None]
-            v = v * v_scale_ref[0, 0][:, None, None]
+            page = block_tables_ref[b, p]
+            k = k * _page_scale_column(k_scale_ref, page)[None]
+            v = v * _page_scale_column(v_scale_ref, page)[None]
+        k = jnp.swapaxes(k, 0, 1)  # [n_kv, ps, d]
+        v = jnp.swapaxes(v, 0, 1)
 
         # Batched over kv heads: [n_kv, group, page_size]
         scores = jax.lax.dot_general(
@@ -194,10 +230,9 @@ def paged_attention(
     ``[n_layers, pages, ps, n_kv, hd]`` with ``layer`` selecting the
     layer inside the kernel's index map. This matters: slicing
     ``k_pages[li]`` outside would make XLA materialize a full per-layer
-    pool copy per call (custom calls cannot take slice views — measured
-    as the decode pool-size throughput cliff, benchmarking/
-    bench_decode_poolsize.py); with the 5-D operand the custom call
-    reads the carry buffer in place and DMAs only the block-table pages.
+    pool copy per call (custom calls cannot take slice views); with the
+    5-D operand the custom call reads the carry buffer in place and DMAs
+    only the block-table pages.
 
     With ``k_scale``/``v_scale`` (``KV_QUANT_HBM=int8``), the pools hold
     int8 codes and the per-page-per-(layer, kv_head) f32 scales ride as
@@ -221,10 +256,7 @@ def paged_attention(
     page_size = ps if page_size is None else page_size
     if scale is None:
         scale = head_dim**-0.5
-    if not interpret and jax.default_backend() == "cpu":
-        # Mosaic-compiled kernels need a TPU; CPU (tests, dry-runs) falls
-        # back to the interpreter transparently.
-        interpret = True
+    require_tpu_unless_interpret("paged_attention", interpret)
     group = n_heads // n_kv_heads
     max_pages = block_tables.shape[1]
     if (fresh_k is None) != (fresh_v is None):
@@ -254,14 +286,16 @@ def paged_attention(
     inputs = [block_tables, seq_lens, q_blocked, k_pages, v_pages]
     if quantized:
         # Same block-table deref as the page tiles, so each program's
-        # pipeline stage carries its page's [n_kv] scale row alongside
-        # the codes. Appended after v_pages, before fresh operands —
-        # order is part of the kernel ABI (tools/kvlint/kernel_abi.json).
+        # pipeline stage carries its page's 8-page group of [n_kv] scale
+        # rows alongside the codes (_SCALE_ROWS). Appended after v_pages,
+        # before fresh operands — order is part of the kernel ABI
+        # (tools/kvlint/kernel_abi.json).
         def scale_index(b, p, bt, sl):
-            return (layer, bt[b, p], 0)
+            return (layer, bt[b, p] // _SCALE_ROWS, 0)
 
-        in_specs.append(pl.BlockSpec((1, 1, n_kv_heads), scale_index))
-        in_specs.append(pl.BlockSpec((1, 1, n_kv_heads), scale_index))
+        scale_block = (1, _SCALE_ROWS, n_kv_heads)
+        in_specs.append(pl.BlockSpec(scale_block, scale_index))
+        in_specs.append(pl.BlockSpec(scale_block, scale_index))
         inputs.append(k_scale)
         inputs.append(v_scale)
     if has_fresh:

@@ -4,7 +4,7 @@ from .checkpoint import (
     save_params,
     save_train_state,
 )
-from .mesh import make_mesh, MeshConfig, shard_map_compat
+from .mesh import make_mesh, MeshConfig
 from .ring_attention import ring_attention, ring_attention_shard
 from .sharding import param_shardings, batch_sharding, shard_params
 from .train import train_step, make_train_state, loss_fn
@@ -16,7 +16,6 @@ __all__ = [
     "save_train_state",
     "make_mesh",
     "MeshConfig",
-    "shard_map_compat",
     "ring_attention",
     "ring_attention_shard",
     "param_shardings",

@@ -37,23 +37,6 @@ class MeshConfig:
         return self.dp * self.sp * self.tp
 
 
-def shard_map_compat(f, *, mesh, in_specs, out_specs, check: bool = False):
-    """``shard_map`` across jax versions (``check_vma`` replaced
-    ``check_rep`` in 0.8; the experimental module is deprecated)."""
-    try:
-        from jax import shard_map
-
-        return shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check
-        )
-    except (ImportError, TypeError):  # pragma: no cover - older jax
-        from jax.experimental.shard_map import shard_map as _sm
-
-        return _sm(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=check
-        )
-
-
 def make_mesh(config: Optional[MeshConfig] = None, devices=None) -> Mesh:
     """Build a (dp, sp, tp) mesh over the given devices (default: all)."""
     cfg = config or MeshConfig()

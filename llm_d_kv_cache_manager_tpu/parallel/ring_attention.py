@@ -158,8 +158,6 @@ def ring_attention(
     composable with tp sharding on the head dimension of the surrounding
     projections.
     """
-    from .mesh import shard_map_compat
-
     n = mesh.shape[axis_name]
     if q.shape[1] % n != 0:
         raise ValueError(
@@ -167,10 +165,11 @@ def ring_attention(
             f"{axis_name!r} of size {n}"
         )
     spec = P(None, axis_name, None, None)
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         partial(ring_attention_shard, axis_name=axis_name, scale=scale),
         mesh=mesh,
         in_specs=(spec, spec, spec),
         out_specs=spec,
+        check_vma=False,
     )
     return fn(q, k, v)

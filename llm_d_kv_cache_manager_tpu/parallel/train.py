@@ -29,12 +29,14 @@ class TrainState(NamedTuple):
 
 
 def _forward_logits(
-    params: Params, cfg: LlamaConfig, tokens: jnp.ndarray, mesh=None
+    params: Params, cfg: LlamaConfig, tokens: jnp.ndarray, mesh=None,
+    interpret: bool = False,
 ) -> jnp.ndarray:
     """Full-sequence forward for training (no KV cache): returns
     [b, s, vocab] float32 logits. ``mesh`` enables the expert-parallel
     routed MoE dispatch (shard_map); dense layers need no mesh — GSPMD
-    partitions them from the param shardings alone."""
+    partitions them from the param shardings alone. ``interpret`` is the
+    caller's word that this is a CPU run (``LlamaConfig.moe_gmm``)."""
     b, s = tokens.shape
     positions = jnp.broadcast_to(jnp.arange(s)[None, :], (b, s))
     inv_freq = jnp.asarray(rope_frequencies(cfg.hd, cfg.rope_theta, cfg.rope_scaling))
@@ -47,7 +49,7 @@ def _forward_logits(
         attn = causal_prefill_attention(q, k, v)
         h = h + attn.reshape(b, s, -1) @ llama._w(layer["wo"], h.dtype)
         x = rms_norm(h, layer["mlp_norm"], cfg.rms_norm_eps, cfg.norm_offset)
-        h = h + llama._mlp(layer, cfg, x, mesh=mesh)
+        h = h + llama._mlp(layer, cfg, x, mesh=mesh, interpret=interpret)
     h = rms_norm(h, params["final_norm"], cfg.rms_norm_eps, cfg.norm_offset)
     head = (
         llama._w(params["embed"], h.dtype).T
@@ -58,10 +60,13 @@ def _forward_logits(
 
 
 def loss_fn(
-    params: Params, cfg: LlamaConfig, tokens: jnp.ndarray, mesh=None
+    params: Params, cfg: LlamaConfig, tokens: jnp.ndarray, mesh=None,
+    interpret: bool = False,
 ) -> jnp.ndarray:
     """Next-token cross-entropy over the sequence (mean, f32)."""
-    logits = _forward_logits(params, cfg, tokens, mesh=mesh)  # [b, s, v]
+    logits = _forward_logits(
+        params, cfg, tokens, mesh=mesh, interpret=interpret
+    )  # [b, s, v]
     targets = tokens[:, 1:]
     logprobs = jax.nn.log_softmax(logits[:, :-1], axis=-1)
     nll = -jnp.take_along_axis(logprobs, targets[..., None], axis=-1)[..., 0]
@@ -79,13 +84,16 @@ def make_train_state(cfg: LlamaConfig, rng: jax.Array, lr: float = 1e-4) -> Trai
 
 
 @functools.partial(
-    jax.jit, static_argnames=("cfg", "lr", "mesh"), donate_argnums=(0,)
+    jax.jit, static_argnames=("cfg", "lr", "mesh", "interpret"),
+    donate_argnums=(0,),
 )
 def train_step(
     state: TrainState, cfg: LlamaConfig, tokens: jnp.ndarray, lr: float = 1e-4,
-    mesh=None,
+    mesh=None, interpret: bool = False,
 ) -> tuple[TrainState, jnp.ndarray]:
-    loss, grads = jax.value_and_grad(loss_fn)(state.params, cfg, tokens, mesh=mesh)
+    loss, grads = jax.value_and_grad(loss_fn)(
+        state.params, cfg, tokens, mesh=mesh, interpret=interpret
+    )
     updates, opt_state = make_optimizer(lr).update(
         grads, state.opt_state, state.params
     )

@@ -1125,7 +1125,8 @@ class PodServerConfig:
         )
         # Weight quantization ("int8" halves weight HBM; models/quant.py).
         eng.quantize = os.environ.get("QUANTIZE") or None
-        # CPU smoke runs (Pallas interpreter mode); never set on real TPU.
+        # CPU runs only (Pallas interpreter + XLA prefill); the engine
+        # refuses it on TPU devices and nothing infers it from the backend.
         eng.interpret = _env_bool("INTERPRET", "0")
         # Remote tier reaches the engine (demotion hooks, store, import
         # eviction ladder) through its own config.
@@ -1153,8 +1154,13 @@ class PodServer:
         tokenizer=None,
         publisher: Optional[ZMQPublisher] = None,
         transfer_cost_model=None,
+        mesh=None,
     ):
-        """``transfer_cost_model``: the router's shared
+        """``mesh``: the device(s) this pod's engine owns, forwarded to
+        ``Engine(mesh=)`` — how several replicas in one process each land
+        on their own chip. Default: the first tp*sp visible devices.
+
+        ``transfer_cost_model``: the router's shared
         ``kvcache/transfer.TransferCostModel``, when this pod participates
         in transfer-aware routing. The pod feeds it the two measured rates
         the decide() arms need — transfer bytes/s from every fetch this
@@ -1198,7 +1204,9 @@ class PodServer:
             )
 
         on_events = self._publisher.publish if self._publisher is not None else None
-        self.engine = engine or Engine(self.config.engine, on_events=on_events)
+        self.engine = engine or Engine(
+            self.config.engine, on_events=on_events, mesh=mesh
+        )
         if engine is not None and on_events is not None:
             # Injected engine: attach the publisher to its block manager.
             self.engine.block_manager.on_events = on_events
@@ -3903,8 +3911,24 @@ def _resolve_model(name: str) -> LlamaConfig:
 def main() -> None:
     from aiohttp import web
 
+    import jax
+
+    from ..utils.compile_cache import enable_compile_cache
+
     config = PodServerConfig.from_env()
     config.engine.model = _resolve_model(config.model_name)
+    cache_dir = enable_compile_cache()
+    # Says which device this pod really got: with JAX_PLATFORMS unset JAX
+    # falls back to the CPU when the TPU fails to initialise, and an
+    # engine asked for compiled kernels then refuses to start below.
+    dev = jax.devices()[0]
+    log.info(
+        "devices",
+        platform=dev.platform,
+        device_kind=dev.device_kind,
+        count=len(jax.devices()),
+        compile_cache=cache_dir,
+    )
 
     tokenizer = None
     if _env_bool("LOAD_TOKENIZER", "0"):

@@ -3,9 +3,9 @@
 Oracle: `prefill_with_paged_context` (the XLA scan flash). Runs the kernel
 in interpreter mode on CPU across GQA/MHA/MQA geometries, cold and warm
 context, padding, multi-block shapes, and through `llama.prefill` /
-the engine end to end. On-chip numerics are re-checked by
-benchmarking/bench_engine.py (round-1 lesson: Mosaic can miscompile what
-the interpreter gets right).
+the engine end to end. On-chip numerics are re-checked, compiled, by
+``chip_smoke.py``'s kernel phase (round-1 lesson: Mosaic can miscompile
+what the interpreter gets right).
 """
 
 import numpy as np
@@ -158,7 +158,7 @@ class TestPrefillIntegration:
             kp, vp = llama.init_kv_pages(cfg, total_pages, page)
             return llama.prefill(
                 params, cfg, tokens, positions, valid, kp, vp,
-                page_ids, slot_ids, bt, cl, attn_impl=impl,
+                page_ids, slot_ids, bt, cl, attn_impl=impl, interpret=True,
             )
 
         logits_x, kpx, vpx = run("xla")
@@ -220,7 +220,7 @@ class TestPrefillIntegration:
         from llm_d_kv_cache_manager_tpu.server import Engine, EngineConfig
 
         with pytest.raises(ValueError, match="prefill_attn"):
-            Engine(EngineConfig(prefill_attn="cuda"))
+            Engine(EngineConfig(prefill_attn="cuda", interpret=True))
 
 
 class TestMaskContract:
@@ -248,7 +248,7 @@ class TestMaskContract:
         kp, vp = llama.init_kv_pages(cfg, total_pages, page)
         out = llama.prefill(
             params, cfg, tokens, positions, jnp.asarray(valid), kp, vp,
-            page_ids, slot_ids, bt, cl, attn_impl="pallas",
+            page_ids, slot_ids, bt, cl, attn_impl="pallas", interpret=True,
         )
         jax.block_until_ready(out)
 

@@ -2,9 +2,9 @@
 
 Oracle = ``jax.lax.ragged_dot`` (the XLA path the kernels replace,
 ``ops/gmm.py use_kernel=False``). Kernels run in Pallas interpret mode on
-CPU; on-chip numerics are re-checked by ``benchmarking/bench_moe.py``
-(BENCH_GMM_PARITY=1) per the repo's Mosaic lesson — interpret mode does
-not catch Mosaic miscompiles.
+CPU; on-chip numerics are re-checked, compiled, by ``chip_smoke.py``'s
+kernel phase per the repo's Mosaic lesson — interpret mode does not catch
+Mosaic miscompiles.
 """
 
 import numpy as np
@@ -102,8 +102,8 @@ class TestRoutedDispatchWithKernel:
         rng = np.random.default_rng(5)
         x = jnp.asarray(rng.normal(size=(2, 16, 128)), jnp.float32)
         layer = params["layers"][0]
-        out_x = llama._moe_mlp_routed(layer, cfg_x, x)
-        out_k = llama._moe_mlp_routed(layer, cfg_k, x)
+        out_x = llama._moe_mlp_routed(layer, cfg_x, x, interpret=True)
+        out_k = llama._moe_mlp_routed(layer, cfg_k, x, interpret=True)
         np.testing.assert_allclose(
             np.asarray(out_k), np.asarray(out_x), atol=2e-5, rtol=2e-4
         )
@@ -116,9 +116,9 @@ class TestRoutedDispatchWithKernel:
         rng = np.random.default_rng(6)
         x = jnp.asarray(rng.normal(size=(1, 16, 128)), jnp.float32)
         layer = params["layers"][0]
-        out_k = llama._moe_mlp_routed(layer, cfg_k, x)
+        out_k = llama._moe_mlp_routed(layer, cfg_k, x, interpret=True)
         cfg_x = self._cfg(moe_gmm="xla")
-        out_x = llama._moe_mlp_routed(layer, cfg_x, x)
+        out_x = llama._moe_mlp_routed(layer, cfg_x, x, interpret=True)
         np.testing.assert_allclose(
             np.asarray(out_k), np.asarray(out_x), atol=5e-3, rtol=5e-2
         )
@@ -158,7 +158,7 @@ class TestExpertParallelWithKernel:
             cfg = replace(base, moe_gmm=impl)
             sharded = shard_params(params, mesh, cfg)
             outs[impl] = llama._moe_mlp_routed_ep(
-                sharded["layers"][0], cfg, x, mesh
+                sharded["layers"][0], cfg, x, mesh, interpret=True
             )
         np.testing.assert_allclose(
             np.asarray(outs["kernel"]), np.asarray(outs["xla"]),

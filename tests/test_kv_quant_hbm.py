@@ -248,17 +248,54 @@ class TestEngineGreedyParity:
             outs.append(s.output_tokens)
         return eng, outs
 
-    def test_quantized_matches_fp_baseline(self):
-        # Pinned workload: prefill + multi-step decode + a prefix-cache
-        # hit (repeat of prompt 0). Greedy tokens on a tiny model CAN
-        # legitimately flip under quantization noise; this workload is
-        # deterministic and verified stable — the rigorous exactness pin
-        # is the kernel-vs-dequantized-oracle suite above.
-        prompts = [_prompt(70 + i, 16) for i in range(3)]
-        prompts.append(prompts[0])
-        _, ref = self._run(prompts)
-        eng, qt = self._run(prompts, kv_quant_hbm="int8")
-        assert qt == ref
+    def test_quantized_logits_within_quantization_bound(self):
+        """What the int8 pool promises: a warm prefill's first-token
+        logits — the ones that READ quantized context pages — stay within
+        the quantization bound of the full-width engine's, and a cold
+        prefill (which reads no pool) is bit-identical. Greedy-token
+        equality is NOT promised: on random tiny weights an argmax flips
+        on noise this small (the exactness pin is the
+        kernel-vs-dequantized-oracle suite above).
+
+        The bound: symmetric int8 with per-page-per-(layer, head) scales
+        puts each stored K/V element within scale/2 = 1/254 of its page's
+        largest magnitude (~0.4%); through two layers of attention that
+        measured 0.8-1.5% of the logit range on this workload. 5% of the
+        full-width logit range leaves ~3x margin and still fails on a
+        wrong scale row, a skipped dequant or a page mix-up (those move
+        logits by the range itself)."""
+
+        def first_token_logits(**kw):
+            eng = _engine(**kw)
+            seen = []
+            sample = eng._sample
+
+            def spy(logits, seqs):
+                seen.append(np.asarray(logits, np.float32)[: len(seqs)])
+                return sample(logits, seqs)
+
+            eng._sample = spy
+            base = _prompt(70, 32)
+            cached = []
+            for p in (
+                base,  # cold
+                base + _prompt(71, 8),  # warm: whole prompt 0 from cache
+                base[:16] + _prompt(72, 16),  # warm: half of it
+            ):
+                s = eng.add_request(p, SamplingParams(max_new_tokens=5))
+                eng.run_until_complete()
+                assert len(s.output_tokens) == 5
+                cached.append(s.num_cached_prompt)
+            return eng, seen, cached
+
+        _, ref, ref_cached = first_token_logits()
+        eng, qt, qt_cached = first_token_logits(kv_quant_hbm="int8")
+        assert qt_cached == ref_cached == [0, 32, 16]
+        np.testing.assert_array_equal(qt[0], ref[0])  # cold: no pool read
+        for got, want in zip(qt[1:], ref[1:]):
+            assert np.isfinite(got).all()
+            bound = 0.05 * np.abs(want).max()
+            assert 0 < np.abs(got - want).max() <= bound
         assert eng.k_pages.dtype == jnp.int8
         assert eng.k_scales.shape == (
             TINY_LLAMA.n_layers, 64, TINY_LLAMA.n_kv_heads

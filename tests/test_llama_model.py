@@ -6,6 +6,8 @@
   must give the same next-token logits as prefilling n-1 and decoding one.
 """
 
+import functools
+
 import numpy as np
 import pytest
 import jax
@@ -17,10 +19,14 @@ from llm_d_kv_cache_manager_tpu.models import (
     decode_step,
     init_kv_pages,
     init_params,
-    prefill,
 )
+from llm_d_kv_cache_manager_tpu.models import prefill as _prefill
 
 PAGE_SIZE = 4
+
+# These tests run on the CPU and say so: without ``interpret`` the model
+# asks for compiled Pallas kernels (MoE ``moe_gmm="auto"``) and raises.
+prefill = functools.partial(_prefill, interpret=True)
 
 
 def _alloc(cfg, batch, max_tokens):
@@ -655,7 +661,7 @@ class TestMixtralMoE:
         x = jnp.asarray(rng.standard_normal((1, 5, cfg.hidden_size)), jnp.float32)
 
         router_logits = np.asarray(x @ layer["router"])  # [1, 5, E]
-        ref = np.asarray(_moe_mlp(layer, cfg, x))
+        ref = np.asarray(_moe_mlp(layer, cfg, x, interpret=True))
 
         # For each expert, zero its weights; if it was never in any token's
         # top-2, the output must be identical.
@@ -664,7 +670,7 @@ class TestMixtralMoE:
             mutated = dict(layer)
             for w in ("w_gate", "w_up", "w_down"):
                 mutated[w] = layer[w].at[e].set(0.0)
-            got = np.asarray(_moe_mlp(mutated, cfg, x))
+            got = np.asarray(_moe_mlp(mutated, cfg, x, interpret=True))
             if e not in topk:
                 np.testing.assert_allclose(got, ref, rtol=1e-6, atol=1e-6)
             else:
@@ -689,8 +695,8 @@ class TestRoutedDispatch:
         layer = params["layers"][0]
         rng = np.random.default_rng(11)
         x = jnp.asarray(rng.standard_normal((*shape, cfg.hidden_size)), jnp.float32)
-        routed = np.asarray(_moe_mlp(layer, cfg, x))
-        dense = np.asarray(_moe_mlp(layer, dense_cfg, x))
+        routed = np.asarray(_moe_mlp(layer, cfg, x, interpret=True))
+        dense = np.asarray(_moe_mlp(layer, dense_cfg, x, interpret=True))
         np.testing.assert_allclose(routed, dense, rtol=1e-5, atol=1e-5)
 
     def test_unknown_dispatch_rejected(self):
@@ -703,7 +709,7 @@ class TestRoutedDispatch:
         params = init_params(jax.random.PRNGKey(0), cfg)
         x = jnp.zeros((1, 2, cfg.hidden_size), jnp.float32)
         with pytest.raises(ValueError, match="moe_dispatch"):
-            _moe_mlp(params["layers"][0], cfg, x)
+            _moe_mlp(params["layers"][0], cfg, x, interpret=True)
 
     def _a3b_shaped(self):
         """Qwen3-30B-A3B expert geometry (128 experts, top-8) at reduced
@@ -738,7 +744,7 @@ class TestRoutedDispatch:
         n, k, f = 64, cfg.n_experts_per_tok, cfg.moe_inter
         x = jnp.zeros((1, n, cfg.hidden_size), jnp.float32)
 
-        jaxpr = jax.make_jaxpr(lambda p, v: _moe_mlp(p, cfg, v))(layer, x)
+        jaxpr = jax.make_jaxpr(lambda p, v: _moe_mlp(p, cfg, v, interpret=True))(layer, x)
         prims = {e.primitive.name for e in jaxpr.eqns}
         assert "ragged_dot" in prims or "ragged_dot_general" in prims, prims
         dense_inter = cfg.n_experts * n * f

@@ -44,7 +44,7 @@ class TestPagedAttentionKernel:
         """Passing the full multi-layer pool with `layer=li` must equal
         attention over the sliced per-layer pool — the 5-D operand is the
         form the decode body uses so XLA never materializes a per-layer
-        pool copy around the custom call (results/decode_poolsize.md)."""
+        pool copy around the custom call (the pool-size decode cliff)."""
         rng = np.random.default_rng(7)
         L, B, NH, NKV, D, PS, MAXP = 3, 2, 4, 2, 64, 8, 3
         NPAGES = B * MAXP + 1
@@ -127,8 +127,10 @@ class TestFreshKV:
             kp_w = kp_w.at[page, slot].set(fk[i])
             vp_w = vp_w.at[page, slot].set(fv[i])
 
-        written = paged_attention(q, kp_w, vp_w, bt, seq_lens)
-        fresh = paged_attention(q, kp, vp, bt, seq_lens, fk, fv)
+        written = paged_attention(q, kp_w, vp_w, bt, seq_lens, interpret=True)
+        fresh = paged_attention(
+            q, kp, vp, bt, seq_lens, fk, fv, interpret=True
+        )
         np.testing.assert_allclose(
             np.asarray(fresh), np.asarray(written), atol=2e-5
         )
@@ -149,6 +151,6 @@ class TestFreshKV:
         seq_lens = jnp.asarray([3, 0], jnp.int32)  # lane 1 inactive
         fk = jnp.asarray(rng.standard_normal((b, nkv, d)), jnp.float32)
         fv = jnp.asarray(rng.standard_normal((b, nkv, d)), jnp.float32)
-        out = paged_attention(q, kp, vp, bt, seq_lens, fk, fv)
+        out = paged_attention(q, kp, vp, bt, seq_lens, fk, fv, interpret=True)
         assert bool(jnp.all(out[1] == 0.0))
         assert bool(jnp.any(out[0] != 0.0))

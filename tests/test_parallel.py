@@ -112,13 +112,13 @@ class TestMoEExpertParallel:
         tokens = jnp.asarray(
             rng.integers(0, TINY_MOE.vocab_size, (4, 16)), jnp.int32
         )
-        ref = _forward_logits(params, TINY_MOE, tokens)
+        ref = _forward_logits(params, TINY_MOE, tokens, interpret=True)
 
         mesh = make_mesh(MeshConfig(dp=2, tp=4))  # 4 experts / 4-way tp
         sharded = shard_params(params, mesh, TINY_MOE)
         tok_sharded = jax.device_put(tokens, batch_sharding(mesh))
-        out = jax.jit(_forward_logits, static_argnames=("cfg",))(
-            sharded, TINY_MOE, tok_sharded
+        out = jax.jit(_forward_logits, static_argnames=("cfg", "interpret"))(
+            sharded, TINY_MOE, tok_sharded, interpret=True
         )
         np.testing.assert_allclose(
             np.asarray(out), np.asarray(ref), rtol=1e-4, atol=1e-4
@@ -146,7 +146,7 @@ class TestMoEExpertParallel:
         params = init_params(jax.random.PRNGKey(2), cfg)
         rng = np.random.default_rng(12)
         tokens = jnp.asarray(rng.integers(0, cfg.vocab_size, (2, 8)), jnp.int32)
-        ref = _forward_logits(params, cfg, tokens)
+        ref = _forward_logits(params, cfg, tokens, interpret=True)
 
         mesh = make_mesh(MeshConfig(dp=2, tp=2))  # 3 % 2 != 0 → fallback
         sharded = shard_params(params, mesh, cfg)
@@ -156,8 +156,8 @@ class TestMoEExpertParallel:
             (3, cfg.hidden_size, cfg.intermediate_size // 2)
         }
         tok_sharded = jax.device_put(tokens, batch_sharding(mesh))
-        out = jax.jit(_forward_logits, static_argnames=("cfg",))(
-            sharded, cfg, tok_sharded
+        out = jax.jit(_forward_logits, static_argnames=("cfg", "interpret"))(
+            sharded, cfg, tok_sharded, interpret=True
         )
         np.testing.assert_allclose(
             np.asarray(out), np.asarray(ref), rtol=1e-4, atol=1e-4
@@ -181,14 +181,14 @@ class TestMoEExpertParallel:
         params = init_params(jax.random.PRNGKey(5), cfg)
         rng = np.random.default_rng(15)
         tokens = jnp.asarray(rng.integers(0, cfg.vocab_size, (4, 16)), jnp.int32)
-        ref = _forward_logits(params, cfg, tokens)
+        ref = _forward_logits(params, cfg, tokens, interpret=True)
 
         mesh = make_mesh(MeshConfig(dp=2, tp=4))
         sharded = shard_params(params, mesh, cfg)
         tok_sharded = jax.device_put(tokens, batch_sharding(mesh))
-        out = jax.jit(_forward_logits, static_argnames=("cfg", "mesh"))(
-            sharded, cfg, tok_sharded, mesh=mesh
-        )
+        out = jax.jit(
+            _forward_logits, static_argnames=("cfg", "mesh", "interpret")
+        )(sharded, cfg, tok_sharded, mesh=mesh, interpret=True)
         np.testing.assert_allclose(
             np.asarray(out), np.asarray(ref), rtol=1e-4, atol=1e-4
         )
@@ -206,7 +206,9 @@ class TestMoEExpertParallel:
         layer = params["layers"][0]
         x = jnp.zeros((2, 8, cfg.hidden_size), jnp.float32)
 
-        jaxpr = jax.make_jaxpr(lambda p, v: _moe_mlp(p, cfg, v, mesh=mesh))(layer, x)
+        jaxpr = jax.make_jaxpr(
+            lambda p, v: _moe_mlp(p, cfg, v, mesh=mesh, interpret=True)
+        )(layer, x)
         sm = [e for e in jaxpr.eqns if e.primitive.name == "shard_map"]
         assert sm, {e.primitive.name for e in jaxpr.eqns}
         inner = sm[0].params["jaxpr"]
@@ -235,7 +237,7 @@ class TestMoEExpertParallel:
         layer = params["layers"][0]
         x = jnp.zeros((2, 8, TINY_MOE.hidden_size), jnp.float32)
         jaxpr = jax.make_jaxpr(
-            lambda p, v: _moe_mlp(p, TINY_MOE, v, mesh=mesh)
+            lambda p, v: _moe_mlp(p, TINY_MOE, v, mesh=mesh, interpret=True)
         )(layer, x)
         prims = {e.primitive.name for e in jaxpr.eqns}
         assert "ragged_dot" not in prims and "ragged_dot_general" not in prims
@@ -255,7 +257,9 @@ class TestMoEExpertParallel:
         )
         losses = []
         for _ in range(4):
-            state, loss = train_step(state, cfg, tokens, mesh=mesh)
+            state, loss = train_step(
+                state, cfg, tokens, mesh=mesh, interpret=True
+            )
             losses.append(float(loss))
         assert losses[-1] < losses[0]
 
@@ -275,7 +279,7 @@ class TestMoEExpertParallel:
         )
         losses = []
         for _ in range(4):
-            state, loss = train_step(state, TINY_MOE, tokens)
+            state, loss = train_step(state, TINY_MOE, tokens, interpret=True)
             losses.append(float(loss))
         assert losses[-1] < losses[0]
 

@@ -68,7 +68,7 @@ class TestQuantizedModel:
     def _logits(self, cfg, params, tokens):
         from llm_d_kv_cache_manager_tpu.parallel.train import _forward_logits
 
-        return np.asarray(_forward_logits(params, cfg, tokens))
+        return np.asarray(_forward_logits(params, cfg, tokens, interpret=True))
 
     def _fidelity(self, cfg, seed=0):
         rng = np.random.default_rng(seed)
@@ -123,7 +123,7 @@ class TestQuantizedModel:
     def test_router_and_experts_stay_full_precision(self):
         """MoE: router (precision-sensitive) AND expert stacks (int8
         dequant does not fuse into ragged_dot — measured slower, see
-        results/moe_dispatch.md) stay in model dtype; the attention
+        round-3 measurement) stay in model dtype; the attention
         weights still quantize."""
         params = init_params(jax.random.PRNGKey(0), TINY_MOE, quantize="int8")
         layer = params["layers"][0]
@@ -199,7 +199,10 @@ class TestQuantizedEngine:
 
         params = init_params(jax.random.PRNGKey(0), TINY_LLAMA)
         with pytest.raises(ValueError, match="quantize"):
-            Engine(EngineConfig(model=TINY_LLAMA, quantize="fp4"), params=params)
+            Engine(
+                EngineConfig(model=TINY_LLAMA, quantize="fp4", interpret=True),
+                params=params,
+            )
 
 
 @pytest.mark.skipif(jax.device_count() < 8, reason="needs 8 virtual devices")
@@ -224,14 +227,16 @@ class TestQuantizedSharding:
         assert not isinstance(params["layers"][0]["w_gate"], QuantizedTensor)
         rng = np.random.default_rng(17)
         tokens = jnp.asarray(rng.integers(0, cfg.vocab_size, (4, 16)), jnp.int32)
-        ref = np.asarray(_forward_logits(params, cfg, tokens))
+        ref = np.asarray(_forward_logits(params, cfg, tokens, interpret=True))
 
         mesh = make_mesh(MeshConfig(dp=2, tp=4))  # k*tp = 8 < 16 → routed-EP
         sharded = shard_params(params, mesh, cfg)
         out = np.asarray(
-            jax.jit(_forward_logits, static_argnames=("cfg", "mesh"))(
+            jax.jit(
+                _forward_logits, static_argnames=("cfg", "mesh", "interpret")
+            )(
                 sharded, cfg, jax.device_put(tokens, batch_sharding(mesh)),
-                mesh=mesh,
+                mesh=mesh, interpret=True,
             )
         )
         np.testing.assert_allclose(out, ref, rtol=1e-4, atol=1e-4)

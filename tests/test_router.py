@@ -1,6 +1,6 @@
 """BlendedRouter / PrefixAffinityTracker: the fleet-routing blend.
 
-Pins the round-4 scheduling contract (results/routing_capacity.md): index
+Pins the round-4 scheduling contract: index
 score dominates whenever real KV events exist; routed-affinity memory
 breaks cold ties (load-aware first placement, then sticky); load breaks
 the rest. The tracker is also bench.py's `estimated` comparator, so its
