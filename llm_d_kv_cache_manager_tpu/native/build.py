@@ -1,7 +1,9 @@
 """Build the native kernels: ``python -m llm_d_kv_cache_manager_tpu.native.build``.
 
 Produces ``libhashcore.so`` (chained sha256-CBOR block hashing) and
-``liblruindex.so`` (two-level LRU block index)."""
+``liblruindex.so`` (two-level LRU block index). Each library is compiled
+under a temporary name and renamed into place, so a process that loads
+one while another builds never sees half a file."""
 
 from __future__ import annotations
 
@@ -22,10 +24,16 @@ def build(verbose: bool = True) -> list[str]:
     for src_name, lib_name in LIBS.items():
         src = os.path.join(HERE, src_name)
         out = os.path.join(HERE, lib_name)
-        cmd = ["g++", "-O3", "-std=c++17", "-shared", "-fPIC", src, "-o", out]
+        tmp = f"{out}.{os.getpid()}.tmp"
+        cmd = ["g++", "-O3", "-std=c++17", "-shared", "-fPIC", src, "-o", tmp]
         if verbose:
             print("+", " ".join(cmd), file=sys.stderr)
-        subprocess.run(cmd, check=True)
+        try:
+            subprocess.run(cmd, check=True)
+            os.replace(tmp, out)
+        finally:
+            if os.path.exists(tmp):
+                os.remove(tmp)
         outs.append(out)
     return outs
 

@@ -2,9 +2,8 @@
 
 Design constraints (matching the PR 1-4 convention of zero-cost-when-off):
 
-- **No hard deps.** Only stdlib. When ``opentelemetry-sdk`` happens to be
-  installed AND ``OBS_OTLP_ENDPOINT`` is set, finished spans are mirrored
-  to an OTLP exporter; otherwise that path is a no-op.
+- **No hard deps.** Only stdlib. Finished spans are read back through
+  ``GET /debug/traces``; nothing is exported.
 - **Off = free.** A disabled ``Tracer`` hands out one shared ``NOOP_SPAN``
   singleton: no allocation, no clock reads, no lock. Callers never branch
   on enablement — they branch (at most) on ``span.context is None`` when
@@ -30,13 +29,6 @@ import time
 from collections import deque
 from dataclasses import dataclass
 from typing import Optional
-
-from ..utils import RateLimitedWarn, get_logger
-
-log = get_logger("obs.tracing")
-#: exporter faults repeat per span once a collector misbehaves; keep them
-#: visible without the log scaling with span volume.
-_warn = RateLimitedWarn(log)
 
 _HEX = set("0123456789abcdef")
 
@@ -108,6 +100,7 @@ class Span:
         context: SpanContext,
         parent_span_id: Optional[str],
         attrs: Optional[dict] = None,
+        start_mono: Optional[float] = None,
     ):
         self._tracer = tracer
         self.name = name
@@ -117,7 +110,7 @@ class Span:
         # Wall clock on purpose: start_unix_s is a cross-host display/export
         # timestamp; durations below use the monotonic pair.
         self.start_wall = time.time()  # kvlint: disable=monotonic-time
-        self.start_mono = time.monotonic()
+        self.start_mono = time.monotonic() if start_mono is None else start_mono
         self.end_mono: Optional[float] = None
         self._ended = False
 
@@ -179,7 +172,6 @@ class Tracer:
         enabled: bool = False,
         max_spans: int = 2048,
         service: str = "",
-        otlp_endpoint: Optional[str] = None,
     ):
         self.enabled = bool(enabled)
         self.service = service
@@ -187,16 +179,19 @@ class Tracer:
         self._spans: deque = deque(maxlen=max(int(max_spans), 16))  # guarded_by: _mu
         self.spans_recorded = 0  # guarded_by: _mu
         self.spans_dropped = 0  # guarded_by: _mu
-        self._otlp = None
-        if self.enabled:
-            endpoint = otlp_endpoint or os.environ.get("OBS_OTLP_ENDPOINT")
-            if endpoint:
-                self._otlp = _make_otlp_exporter(endpoint)
 
     # -- recording -----------------------------------------------------------
-    def start_span(self, name: str, parent=None, attrs: Optional[dict] = None):
+    def start_span(
+        self,
+        name: str,
+        parent=None,
+        attrs: Optional[dict] = None,
+        start_mono: Optional[float] = None,
+    ):
         """Start a span. ``parent`` is a ``SpanContext``, a ``Span``, or
-        None (mint a fresh trace). Disabled tracers return ``NOOP_SPAN``."""
+        None (mint a fresh trace). ``start_mono``: a ``time.monotonic()``
+        stamp the caller already took for the span's start. Disabled
+        tracers return ``NOOP_SPAN``."""
         if not self.enabled:
             return NOOP_SPAN
         pctx = getattr(parent, "context", parent)  # Span -> its context
@@ -206,7 +201,7 @@ class Tracer:
         else:
             ctx = SpanContext(trace_id=gen_trace_id(), span_id=gen_span_id())
             parent_id = None
-        return Span(self, name, ctx, parent_id, attrs)
+        return Span(self, name, ctx, parent_id, attrs, start_mono)
 
     def record_span(
         self,
@@ -245,19 +240,6 @@ class Tracer:
                 self.spans_dropped += 1
             self._spans.append(rec)
             self.spans_recorded += 1
-        if self._otlp is not None:
-            try:
-                self._otlp(rec)
-            except Exception:
-                # Broad by necessity (the OTLP SDK's fault surface is not
-                # enumerable); a broken exporter must not tax serving, but
-                # disabling the mirror silently left operators staring at
-                # an empty collector — say so, once.
-                log.warning(
-                    "OTLP span mirror failed; disabling for this process",
-                    exc_info=True,
-                )
-                self._otlp = None
 
     # -- reading -------------------------------------------------------------
     def traces(
@@ -323,68 +305,3 @@ def debug_traces_payload(tracer: Tracer, query) -> tuple[int, dict]:
             limit=limit,
         ),
     }
-
-
-def _make_otlp_exporter(endpoint: str):
-    """Optional OTLP mirror: returns a ``span_dict -> None`` callable when
-    the opentelemetry SDK is importable, else None (pure no-op path — the
-    container does not bake the SDK in).
-
-    Trace identity is preserved by parenting each mirrored span on a
-    ``NonRecordingSpan`` carrying the record's trace id (and its recorded
-    parent span id), so spans from the scorer, pod, and transfer peer land
-    in ONE collector trace. The SDK generates the mirrored span's own id,
-    so internal ids additionally ride as attributes for exact matching."""
-    try:
-        from opentelemetry import trace as otel_trace
-        from opentelemetry.exporter.otlp.proto.http.trace_exporter import (
-            OTLPSpanExporter,
-        )
-        from opentelemetry.sdk.resources import Resource
-        from opentelemetry.sdk.trace import TracerProvider
-        from opentelemetry.sdk.trace.export import BatchSpanProcessor
-    except ImportError:
-        return None
-    provider = TracerProvider(resource=Resource.create({}))
-    provider.add_span_processor(
-        BatchSpanProcessor(OTLPSpanExporter(endpoint=endpoint))
-    )
-    otel_tracer = provider.get_tracer("llm_d_kv_cache_manager_tpu")
-
-    def export(rec: dict) -> None:
-        start_ns = int(rec["start_unix_s"] * 1e9)
-        parent_ctx = otel_trace.SpanContext(
-            trace_id=int(rec["trace_id"], 16),
-            span_id=int(rec["parent_span_id"] or rec["span_id"], 16),
-            is_remote=True,
-            trace_flags=otel_trace.TraceFlags(otel_trace.TraceFlags.SAMPLED),
-        )
-        context = otel_trace.set_span_in_context(
-            otel_trace.NonRecordingSpan(parent_ctx)
-        )
-        span = otel_tracer.start_span(
-            rec["name"], context=context, start_time=start_ns
-        )
-        for k, v in {
-            **rec["attrs"],
-            "internal.span_id": rec["span_id"],
-            "internal.parent_span_id": rec["parent_span_id"] or "",
-            "service": rec["service"],
-        }.items():
-            try:
-                span.set_attribute(k, v)
-            except Exception:
-                # Attribute values come from user-supplied request fields
-                # and the SDK's fault surface is not enumerable; any escape
-                # here would hit _finish's handler and disable the WHOLE
-                # mirror. Drop THAT attribute, not the span — visibly, and
-                # rate-limited per attribute key.
-                _warn.warning(
-                    f"otlp-attr:{k}",
-                    "dropping unserializable span attribute",
-                    attr=k,
-                    value_type=type(v).__name__,
-                )
-        span.end(end_time=start_ns + int(rec["duration_s"] * 1e9))
-
-    return export

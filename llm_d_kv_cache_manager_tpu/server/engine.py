@@ -39,6 +39,119 @@ from .sequence import SamplingParams, Sequence, SequenceStatus
 
 log = get_logger("server.engine")
 
+#: The phases of one trip round the engine loop, in the order they run: the
+#: names ``Engine.phase`` takes, the ``step_stats[<phase>_s]`` keys, and the
+#: ``engine.<phase>`` spans on the profiler's timeline (readers and docs
+#: quote this tuple). ``*_build`` is host work in Python and numpy alone
+#: (slot reservation and preemption, block tables, input arrays);
+#: ``*_put`` hands the inputs to the device (queued page moves, the rng
+#: split, every ``device_put``); ``*_dispatch`` is the jitted call, which
+#: returns when the program is enqueued; ``*_fetch`` is the ``np.asarray``
+#: of the sampled tokens — the only phases that wait for the device;
+#: ``*_commit`` appends tokens, accounts pages and registers blocks.
+#: ``schedule`` is deadline shed, QoS preemption, host prefetch and the
+#: scheduler; ``publish`` is finish detection and the KV-event flush;
+#: ``loop`` belongs to the serving loop around ``step()`` (``PodServer``):
+#: everything between one step and the next while work is pending.
+STEP_PHASES = (
+    "schedule",
+    "prefill_build", "prefill_put", "prefill_dispatch", "prefill_fetch",
+    "prefill_commit",
+    "decode_build", "decode_put", "decode_dispatch", "decode_fetch",
+    "decode_commit",
+    "publish",
+    "loop",
+)
+
+
+def _phase_keys(name: str) -> tuple[str, ...]:
+    """The ``step_stats`` keys a phase's seconds go to: its own, and the
+    sums that were there before the split — ``prefill_s``/``decode_s`` =
+    their five, ``sample_s`` = the two fetches."""
+    keys = [f"{name}_s"]
+    half, _, part = name.partition("_")
+    if part:
+        keys.append(f"{half}_s")
+        if part == "fetch":
+            keys.append("sample_s")
+    return tuple(keys)
+
+
+_PHASE_KEYS = {name: _phase_keys(name) for name in STEP_PHASES}
+
+
+class _NoPhase:
+    """What ``Engine.phase`` hands out with ``obs_step_timing`` off: one
+    shared object, no clock read, no allocation."""
+
+    __slots__ = ()
+
+    def __enter__(self) -> None:
+        pass
+
+    def __exit__(self, *_exc) -> None:
+        pass
+
+
+NO_PHASE = _NoPhase()
+
+
+class _Phase:
+    """One timed phase (``Engine.phase``). While it runs its wall time goes
+    to ``step_stats`` and a ``jax.profiler.TraceAnnotation`` named
+    ``engine.<name>`` is open on the calling thread, carrying the step's
+    number and the replica, so the span sits on the profiler's own clock
+    under the device's timeline.
+
+    Phases run one after another and never contain one another. The one
+    thing that can start inside another phase is the drain of a pipelined
+    burst (``decode_pipeline``: its ``decode_fetch`` and ``decode_commit``),
+    which a reservation out of pages, a QoS preemption or an abort forces
+    where it stands. Such a phase SUSPENDS the one it starts in — the
+    outer span ends where the inner begins, and a new span of the outer's
+    name starts where the inner ends — so a reader that names an idle gap
+    after the span that overlaps it most never finds an enclosing span
+    winning every gap. Without a burst in flight no phase is suspended."""
+
+    __slots__ = ("_engine", "_name", "_outer", "_t0", "_span")
+
+    def __init__(self, engine: "Engine", name: str):
+        self._engine = engine
+        self._name = name
+
+    def __enter__(self) -> None:
+        engine = self._engine
+        self._outer = engine._open_phase
+        if self._outer is not None:
+            self._outer._stop()
+        engine._open_phase = self
+        self._start()
+
+    def __exit__(self, *_exc) -> None:
+        self._stop()
+        self._engine._open_phase = self._outer
+        if self._outer is not None:
+            self._outer._start()
+
+    def _start(self) -> None:
+        # the clock is read outside the span on both sides, so that what
+        # the span itself costs is inside the phase and not between two
+        engine = self._engine
+        self._t0 = time.perf_counter()
+        self._span = jax.profiler.TraceAnnotation(
+            f"engine.{self._name}",
+            step=engine._step_count,
+            replica=engine.replica,
+        )
+        self._span.__enter__()
+
+    def _stop(self) -> None:
+        self._span.__exit__(None, None, None)
+        elapsed = time.perf_counter() - self._t0
+        stats = self._engine.step_stats
+        for key in _PHASE_KEYS[self._name]:
+            stats[key] += elapsed
+
 
 def _round_up(n: int, multiple: int) -> int:
     return -(-n // multiple) * multiple
@@ -330,6 +443,11 @@ class Engine:
         #: engine's own devices, never the process default device (which
         #: on a multi-replica host is another replica's chip)
         self._replicated = NamedSharding(mesh, PartitionSpec())
+        #: which replica this is, as the profiler names its device plane
+        #: (``/device:TPU:0`` -> ``tpu:0``): the phase spans carry it, so
+        #: with several replicas in one process a chip's idle gaps are
+        #: matched to its own loop
+        self.replica = f"{platform}:{self.devices[0].id}"
         with jax.default_device(self.devices[0]):
             # Tiny key computations and (without a checkpoint) the random
             # weights are made on the engine's first device: a tp=1
@@ -623,30 +741,36 @@ class Engine:
             "deadline_expired": 0,
             "aborted": 0,
         }
-        #: engine-step telemetry (PR 5, ``OBS_METRICS``): cumulative wall
-        #: seconds per step phase — schedule (deadline shed + scheduler),
-        #: prefill (dispatch + sampling), decode (dispatch + commit),
-        #: sample (host-side blocking fetch of sampled tokens — the
-        #: device_get the fused fast path overlaps; a slice of the
-        #: prefill/decode phases, broken out so fusion is visible),
-        #: gather (host<->device page moves, overlaps prefill/decode),
-        #: demote (remote-tier demotion payload builds — quantize +
-        #: serialize, folded into the flush gather since PR 12 but its
-        #: own label so REMOTE_TIER cost is visible), publish (finish
-        #: detection + KV-event flush). Off by default:
-        #: ``obs_step_timing=False`` skips every clock read, so the legacy
-        #: step path is untouched.
+        #: engine-step telemetry (``OBS_METRICS``, ``OBS_FLIGHT``, the
+        #: benchmark's traced run): cumulative wall seconds of each of
+        #: ``STEP_PHASES`` (``<phase>_s``, through ``phase``), and beside
+        #: them the sums that predate the split: ``prefill_s`` /
+        #: ``decode_s`` (their five phases), ``sample_s`` (the two
+        #: fetches: the blocking share of the sampled-token device_get,
+        #: near zero where the fused fast path's async copy already landed
+        #: the bytes). ``gather_s`` (host<->device page moves) and
+        #: ``demote_s`` (remote-tier demotion payload builds) are slices
+        #: INSIDE other phases and get no span. Counters: ``steps``,
+        #: ``decode_dispatches``, ``decode_rows`` (real lanes of each
+        #: decode dispatch, summed); prefill dispatches are counted, always,
+        #: in ``prefill_stats``.
+        #: Off by default: ``obs_step_timing=False`` skips every clock
+        #: read and every count, so the legacy step path is untouched.
         self.obs_step_timing = False
         self.step_stats = {
             "steps": 0,
-            "schedule_s": 0.0,
+            "decode_dispatches": 0,
+            "decode_rows": 0,
             "prefill_s": 0.0,
             "decode_s": 0.0,
             "sample_s": 0.0,
             "gather_s": 0.0,
             "demote_s": 0.0,
-            "publish_s": 0.0,
+            **{f"{name}_s": 0.0 for name in STEP_PHASES},
         }
+        #: the phase open right now (a pipelined burst drained inside it
+        #: suspends and resumes it: ``_Phase``)
+        self._open_phase: Optional[_Phase] = None
         #: in-flight fused decode burst (decode_pipeline): toks device
         #: array, lane-ordered active list, and the np position/len arrays
         #: the NEXT burst derives from.
@@ -655,6 +779,13 @@ class Engine:
     def _dev(self, x, dtype=None) -> jax.Array:
         """Stage a host value on the device(s) this engine owns."""
         return jax.device_put(np.asarray(x, dtype), self._replicated)
+
+    def phase(self, name: str):
+        """Context manager for one of ``STEP_PHASES``. Off (the default):
+        the shared ``NO_PHASE``. On: see ``_Phase``."""
+        if not self.obs_step_timing:
+            return NO_PHASE
+        return _Phase(self, name)
 
     # -- host-DRAM tier movers (batched) ------------------------------------
     #
@@ -1557,8 +1688,12 @@ class Engine:
         tenant: str = "",
         priority: int = 0,
         qos_weight: float = 1.0,
+        submit_time: Optional[float] = None,
     ) -> Sequence:
-        """``deadline``: absolute ``time.monotonic()`` deadline. Expired
+        """``submit_time``: when the serving layer took the request
+        (``time.monotonic()``); None = now.
+
+        ``deadline``: absolute ``time.monotonic()`` deadline. Expired
         waiting sequences are shed before prefill; running sequences past
         it finish early with ``finish_reason="deadline"``. None (default)
         = no deadline, bit-identical legacy behavior.
@@ -1587,6 +1722,7 @@ class Engine:
             tenant=tenant,
             priority=priority,
             qos_weight=qos_weight,
+            submit_time=submit_time,
         )
         if deadline is not None:
             self._deadlines_used = True
@@ -1765,42 +1901,41 @@ class Engine:
         and both dispatch in the same iteration, so a long prompt's ingest
         never stalls running decodes for more than one chunk's compute."""
         timed = self.obs_step_timing
-        t0 = time.perf_counter() if timed else 0.0
-        shed: list[Sequence] = []
-        if self._deadlines_used:
-            # Deadline shedding BEFORE scheduling: an expired waiting seq
-            # must never reach prefill, and an expired mid-prefill seq
-            # releases its pages for work that can still meet its SLO.
-            now = time.monotonic()
-            shed = self.scheduler.shed_expired(now)
-            for seq in shed:
-                seq.finish_time = now
-                self.lifecycle_stats["deadline_shed"] += 1
-                self.finished.append(seq)
-        if self.scheduler.qos_enabled:
-            # TENANT_QOS priority preemption BEFORE scheduling: when the
-            # highest-class waiting prefill cannot allocate, free pages by
-            # preempting one strictly lower-class active sequence so the
-            # schedule below can admit it.
-            self._preempt_for_priority()
-        if self.config.host_prefetch and self.config.block_manager.host_pages:
-            # Host-tier prefetch AHEAD of the scheduler: waiting sequences'
-            # host-cached prefixes start their device↔host copies now, so
-            # they batch into this step's flush (overlapping the dispatch)
-            # instead of blocking inside a later allocate.
-            self._prefetch_host_pages()
-        out = self.scheduler.schedule()
-        if timed:
-            t1 = time.perf_counter()
-            self.step_stats["schedule_s"] += t1 - t0
+        with self.phase("schedule"):
+            shed: list[Sequence] = []
+            if self._deadlines_used:
+                # Deadline shedding BEFORE scheduling: an expired waiting
+                # seq must never reach prefill, and an expired mid-prefill
+                # seq releases its pages for work that can still meet its
+                # SLO.
+                now = time.monotonic()
+                shed = self.scheduler.shed_expired(now)
+                for seq in shed:
+                    seq.finish_time = now
+                    self.lifecycle_stats["deadline_shed"] += 1
+                    self.finished.append(seq)
+            if self.scheduler.qos_enabled:
+                # TENANT_QOS priority preemption BEFORE scheduling: when
+                # the highest-class waiting prefill cannot allocate, free
+                # pages by preempting one strictly lower-class active
+                # sequence so the schedule below can admit it.
+                self._preempt_for_priority()
+            if (
+                self.config.host_prefetch
+                and self.config.block_manager.host_pages
+            ):
+                # Host-tier prefetch AHEAD of the scheduler: waiting
+                # sequences' host-cached prefixes start their device↔host
+                # copies now, so they batch into this step's flush
+                # (overlapping the dispatch) instead of blocking inside a
+                # later allocate.
+                self._prefetch_host_pages()
+            out = self.scheduler.schedule()
         if out.prefill:
             # Prefill must see committed decode state (page accounting,
             # finish detection) — never overlaps an in-flight burst.
             self._drain_inflight()
             self._run_prefill(out.prefill, out.chunks)
-        if timed:
-            t2 = time.perf_counter()
-            self.step_stats["prefill_s"] += t2 - t1
         if out.decode:
             # Mixed step: decode lanes snapshotted at schedule time — a
             # final-chunk sequence published above joins NEXT step (same
@@ -1810,21 +1945,17 @@ class Engine:
             self._run_decode(out.decode)
         elif not out.prefill:
             self._drain_inflight()
-        if timed:
-            t3 = time.perf_counter()
-            self.step_stats["decode_s"] += t3 - t2
 
-        newly_finished = list(shed)
-        for seq in list(self.scheduler.running):
-            if self._should_finish(seq):
-                seq.finish_time = time.monotonic()
-                self.scheduler.on_finished(seq)
-                self.finished.append(seq)
-                newly_finished.append(seq)
-
-        self.block_manager.flush_events()
+        with self.phase("publish"):
+            newly_finished = list(shed)
+            for seq in list(self.scheduler.running):
+                if self._should_finish(seq):
+                    seq.finish_time = time.monotonic()
+                    self.scheduler.on_finished(seq)
+                    self.finished.append(seq)
+                    newly_finished.append(seq)
+            self.block_manager.flush_events()
         if timed:
-            self.step_stats["publish_s"] += time.perf_counter() - t3
             self.step_stats["steps"] += 1
         self._step_count += 1
         return newly_finished
@@ -1866,105 +1997,114 @@ class Engine:
         prefix-cache hits for chunk 0, plus the pages written by chunks
         0..N-1 for later chunks. Only a sequence's FINAL chunk samples a
         first token and publishes it to the decode lanes."""
-        ps = self.page_size
-        if chunks is None:
-            chunks = [s.prompt_remaining for s in seqs]
-        # Static shapes for jit-cache stability: batch padded to the
-        # configured prefill width, chunk length and context pages bucketed.
-        chunk = _round_up(max(chunks), self.config.prefill_bucket)
-        b = self.config.scheduler.max_prefill_batch
+        with self.phase("prefill_build"):
+            ps = self.page_size
+            if chunks is None:
+                chunks = [s.prompt_remaining for s in seqs]
+            # Static shapes for jit-cache stability: batch padded to the
+            # configured prefill width, chunk length and context pages bucketed.
+            chunk = _round_up(max(chunks), self.config.prefill_bucket)
+            b = self.config.scheduler.max_prefill_batch
 
-        tokens = np.zeros((b, chunk), np.int32)
-        positions = np.zeros((b, chunk), np.int32)
-        valid = np.zeros((b, chunk), bool)
-        page_ids = np.zeros((b, chunk), np.int32)
-        slot_ids = np.zeros((b, chunk), np.int32)
-        # Zero-width context when the whole batch is cache-cold: skips the
-        # per-layer context gather/score entirely (its own jit trace).
-        max_ctx = max(s.num_prefilled // ps for s in seqs)
-        ctx_pages = _round_up(max_ctx, self.config.prefill_ctx_bucket)
-        ctx_bt = np.zeros((b, ctx_pages), np.int32)
-        ctx_lens = np.zeros((b,), np.int32)
+            tokens = np.zeros((b, chunk), np.int32)
+            positions = np.zeros((b, chunk), np.int32)
+            valid = np.zeros((b, chunk), bool)
+            page_ids = np.zeros((b, chunk), np.int32)
+            slot_ids = np.zeros((b, chunk), np.int32)
+            # Zero-width context when the whole batch is cache-cold: skips the
+            # per-layer context gather/score entirely (its own jit trace).
+            max_ctx = max(s.num_prefilled // ps for s in seqs)
+            ctx_pages = _round_up(max_ctx, self.config.prefill_ctx_bucket)
+            ctx_bt = np.zeros((b, ctx_pages), np.int32)
+            ctx_lens = np.zeros((b,), np.int32)
 
-        # Queue→compute boundary for the latency decomposition: one clock
-        # read per batch, stamped only on each sequence's FIRST chunk.
-        t_prefill_start = time.monotonic()
-        for i, (seq, n) in enumerate(zip(seqs, chunks)):
-            if seq.prefill_start_time is None:
-                seq.prefill_start_time = t_prefill_start
-            start = seq.num_prefilled
-            tokens[i, :n] = seq.prompt_tokens[start : start + n]
-            pos = np.arange(start, start + n)
-            positions[i, :n] = pos
-            valid[i, :n] = True
-            page_ids[i, :n] = np.asarray(seq.block_table, np.int32)[pos // ps]
-            slot_ids[i, :n] = pos % ps
-            n_ctx_pages = start // ps
-            ctx_bt[i, :n_ctx_pages] = seq.block_table[:n_ctx_pages]
-            ctx_lens[i] = start
+            # Queue→compute boundary for the latency decomposition: one clock
+            # read per batch, stamped only on each sequence's FIRST chunk.
+            t_prefill_start = time.monotonic()
+            for i, (seq, n) in enumerate(zip(seqs, chunks)):
+                if seq.prefill_start_time is None:
+                    seq.prefill_start_time = t_prefill_start
+                start = seq.num_prefilled
+                tokens[i, :n] = seq.prompt_tokens[start : start + n]
+                pos = np.arange(start, start + n)
+                positions[i, :n] = pos
+                valid[i, :n] = True
+                page_ids[i, :n] = np.asarray(seq.block_table, np.int32)[pos // ps]
+                slot_ids[i, :n] = pos % ps
+                n_ctx_pages = start // ps
+                ctx_bt[i, :n_ctx_pages] = seq.block_table[:n_ctx_pages]
+                ctx_lens[i] = start
 
-        # Flush queued page moves LAST before the dispatch (restores must
-        # land before attention reads; spilled pages must be snapshotted
-        # before this prefill overwrites them).
-        self._flush_page_moves()
-        t0 = time.perf_counter()
-        out = llama.prefill(
-            self.params,
-            self.model_cfg,
-            self._dev(tokens),
-            self._dev(positions),
-            self._dev(valid),
-            self.k_pages,
-            self.v_pages,
-            self._dev(page_ids),
-            self._dev(slot_ids),
-            self._dev(ctx_bt),
-            self._dev(ctx_lens),
-            mesh=self.mesh,
-            attn_impl=self.prefill_attn,
-            k_scales=self.k_scales,
-            v_scales=self.v_scales,
-            interpret=self.config.interpret,
-        )
-        if self.k_scales is None:
-            logits, self.k_pages, self.v_pages = out
-        else:
-            (
-                logits, self.k_pages, self.v_pages,
-                self.k_scales, self.v_scales,
-            ) = out
+        with self.phase("prefill_put"):
+            # Flush queued page moves LAST before the dispatch (restores must
+            # land before attention reads; spilled pages must be snapshotted
+            # before this prefill overwrites them).
+            self._flush_page_moves()
+            t0 = time.perf_counter()
+            tokens_d, positions_d, valid_d = (
+                self._dev(tokens), self._dev(positions), self._dev(valid)
+            )
+            page_ids_d, slot_ids_d = self._dev(page_ids), self._dev(slot_ids)
+            ctx_bt_d, ctx_lens_d = self._dev(ctx_bt), self._dev(ctx_lens)
+        with self.phase("prefill_dispatch"):
+            out = llama.prefill(
+                self.params,
+                self.model_cfg,
+                tokens_d,
+                positions_d,
+                valid_d,
+                self.k_pages,
+                self.v_pages,
+                page_ids_d,
+                slot_ids_d,
+                ctx_bt_d,
+                ctx_lens_d,
+                mesh=self.mesh,
+                attn_impl=self.prefill_attn,
+                k_scales=self.k_scales,
+                v_scales=self.v_scales,
+                interpret=self.config.interpret,
+            )
+            if self.k_scales is None:
+                logits, self.k_pages, self.v_pages = out
+            else:
+                (
+                    logits, self.k_pages, self.v_pages,
+                    self.k_scales, self.v_scales,
+                ) = out
         first_tokens = self._sample(logits, seqs)  # syncs the dispatch
-        # Online prefill-rate sample for the recompute-vs-restore model
-        # (chunk tokens over the synced dispatch wall time).
-        self._prefill_rate = self._ema(
-            self._prefill_rate,
-            float(valid.sum()) / max(time.perf_counter() - t0, 1e-6),
-        )
-        self.prefill_stats["tokens_computed"] += int(valid.sum())
-        self.prefill_stats["dispatches"] += 1
-        now = time.monotonic()
-        finals = [
-            seq
-            for seq, n in zip(seqs, chunks)
-            if seq.num_prefilled + n >= len(seq.prompt_tokens)
-        ]
-        # Admit to running BEFORE appending slots: batchmates must be
-        # preemption candidates if page growth exhausts the pool here.
-        self.scheduler.on_prefill_done(finals)
-        for (seq, n), tok in zip(zip(seqs, chunks), first_tokens):
-            if not seq.block_table:
-                continue  # preempted by an earlier seq in this very batch
-            seq.num_prefilled += n
-            seq.num_computed = seq.num_prefilled
-            if seq.prompt_remaining == 0:
-                # Final chunk: the last-position logits are the first-token
-                # logits of the whole prompt — sample and publish.
-                seq.output_tokens.append(int(tok))
-                seq.num_generated += 1
-                if seq.first_token_time is None:
-                    seq.first_token_time = now
-                self._append_slot_or_preempt(seq)
-            self.block_manager.register_full_pages(seq)
+        with self.phase("prefill_commit"):
+            # Online prefill-rate sample for the recompute-vs-restore model
+            # (chunk tokens over the synced dispatch wall time).
+            self._prefill_rate = self._ema(
+                self._prefill_rate,
+                float(valid.sum()) / max(time.perf_counter() - t0, 1e-6),
+            )
+            self.prefill_stats["tokens_computed"] += int(valid.sum())
+            self.prefill_stats["dispatches"] += 1
+            now = time.monotonic()
+            finals = [
+                seq
+                for seq, n in zip(seqs, chunks)
+                if seq.num_prefilled + n >= len(seq.prompt_tokens)
+            ]
+            # Admit to running BEFORE appending slots: batchmates must be
+            # preemption candidates if page growth exhausts the pool here.
+            self.scheduler.on_prefill_done(finals)
+            for (seq, n), tok in zip(zip(seqs, chunks), first_tokens):
+                if not seq.block_table:
+                    continue  # preempted by an earlier seq in this very batch
+                seq.num_prefilled += n
+                seq.num_computed = seq.num_prefilled
+                if seq.prompt_remaining == 0:
+                    # Final chunk: the last-position logits are the first-token
+                    # logits of the whole prompt — sample and publish.
+                    seq.output_tokens.append(int(tok))
+                    seq.num_generated += 1
+                    if seq.first_token_time is None:
+                        seq.first_token_time = now
+                    self._append_slot_or_preempt(seq)
+                self.block_manager.register_full_pages(seq)
 
     def _decode_table_width(self, seqs: list[Sequence]) -> int:
         """Block-table width for this decode call: longest active context in
@@ -2048,116 +2188,132 @@ class Engine:
         if not seqs:
             return
 
-        # Reserve capacity for the burst's growth per sequence (× 2 when a
-        # previous burst is still in flight); preemption inside reservation
-        # may knock batchmates out of `seqs` — or the in-flight set.
-        reserve = k * (2 if self._pipeline else 1)
-        for seq in seqs:
-            # The finished re-check matters after a mid-loop degrade-drain
-            # (below): committing the lagged burst can finish any lane, and
-            # reserving (worse: preempting a batchmate, or aborting) for a
-            # sequence that already completed is the unpipelined engine's
-            # never-happens case.
-            if not seq.block_table or self._should_finish(seq):
-                continue
-            if reserve > k:
-                # Double-burst headroom is an optimization, not a
-                # requirement: when the pool is too tight for it, drain and
-                # degrade to the unpipelined reservation rather than
-                # preempting/aborting lanes the unpipelined engine would
-                # complete. (Preemption stays reserved for genuine
-                # single-burst pressure below, keeping behavior identical
-                # to decode_pipeline=False under the same pool.)
-                try:
-                    self.block_manager.reserve_slots(seq, reserve)
+        with self.phase("decode_build"):
+            # Reserve capacity for the burst's growth per sequence (× 2 when a
+            # previous burst is still in flight); preemption inside reservation
+            # may knock batchmates out of `seqs` — or the in-flight set.
+            reserve = k * (2 if self._pipeline else 1)
+            for seq in seqs:
+                # The finished re-check matters after a mid-loop degrade-drain
+                # (below): committing the lagged burst can finish any lane, and
+                # reserving (worse: preempting a batchmate, or aborting) for a
+                # sequence that already completed is the unpipelined engine's
+                # never-happens case.
+                if not seq.block_table or self._should_finish(seq):
                     continue
-                except AllocationError:
+                if reserve > k:
+                    # Double-burst headroom is an optimization, not a
+                    # requirement: when the pool is too tight for it, drain and
+                    # degrade to the unpipelined reservation rather than
+                    # preempting/aborting lanes the unpipelined engine would
+                    # complete. (Preemption stays reserved for genuine
+                    # single-burst pressure below, keeping behavior identical
+                    # to decode_pipeline=False under the same pool.)
+                    try:
+                        self.block_manager.reserve_slots(seq, reserve)
+                        continue
+                    except AllocationError:
+                        self._drain_inflight()
+                        prev = None
+                        reserve = k
+                        if self._should_finish(seq):
+                            continue  # the drain just finished this lane
+                self._reserve_slots_or_preempt(seq, reserve)
+            # A degrade-drain above may also have finished lanes.
+            active = [
+                s for s in seqs if s.block_table and not self._should_finish(s)
+            ]
+            if prev is not None:
+                same = len(prev["active"]) == len(active) and all(
+                    a is b for a, b in zip(prev["active"], active)
+                )
+                if not same:  # reservation preempted an in-flight lane
                     self._drain_inflight()
                     prev = None
-                    reserve = k
-                    if self._should_finish(seq):
-                        continue  # the drain just finished this lane
-            self._reserve_slots_or_preempt(seq, reserve)
-        # A degrade-drain above may also have finished lanes.
-        active = [
-            s for s in seqs if s.block_table and not self._should_finish(s)
-        ]
-        if prev is not None:
-            same = len(prev["active"]) == len(active) and all(
-                a is b for a, b in zip(prev["active"], active)
-            )
-            if not same:  # reservation preempted an in-flight lane
+                    active = [s for s in active if not self._should_finish(s)]
+            if not active:
                 self._drain_inflight()
-                prev = None
-                active = [s for s in active if not self._should_finish(s)]
-        if not active:
-            self._drain_inflight()
-            return
+                return
 
-        positions = np.zeros((lanes,), np.int32)
-        seq_lens = np.zeros((lanes,), np.int32)  # 0 = inactive lane
-        block_tables = np.zeros((lanes, self._decode_table_width(active)), np.int32)
-        temperature = np.zeros((lanes,), np.float32)
-        top_k = np.zeros((lanes,), np.int32)
-        top_p = np.ones((lanes,), np.float32)
+            positions = np.zeros((lanes,), np.int32)
+            seq_lens = np.zeros((lanes,), np.int32)  # 0 = inactive lane
+            block_tables = np.zeros((lanes, self._decode_table_width(active)), np.int32)
+            temperature = np.zeros((lanes,), np.float32)
+            top_k = np.zeros((lanes,), np.int32)
+            top_p = np.ones((lanes,), np.float32)
 
-        for i, seq in enumerate(active):
-            bt = seq.block_table
-            block_tables[i, : len(bt)] = bt
-            temperature[i] = seq.sampling.temperature
-            top_k[i] = seq.sampling.top_k
-            top_p[i] = seq.sampling.top_p
-
-        if prev is not None:
-            # Chain from the in-flight burst: last sampled token stays on
-            # device; positions/lengths advance by k without a host sync.
-            # Inactive padded lanes keep their 0 = inactive sentinel — they
-            # must not run garbage attention or write KV into reserved
-            # page 0 just because the active lanes advanced.
-            tokens_dev = prev["toks"][:, -1]
-            was_active = prev["seq_lens"] > 0
-            positions = np.where(was_active, prev["positions"] + k, 0)
-            seq_lens = np.where(was_active, prev["seq_lens"] + k, 0)
-        else:
-            tokens = np.zeros((lanes,), np.int32)
             for i, seq in enumerate(active):
-                tokens[i] = seq.all_tokens[-1]
-                positions[i] = seq.num_tokens - 1
-                seq_lens[i] = seq.num_tokens
-            tokens_dev = self._dev(tokens)
+                bt = seq.block_table
+                block_tables[i, : len(bt)] = bt
+                temperature[i] = seq.sampling.temperature
+                top_k[i] = seq.sampling.top_k
+                top_p[i] = seq.sampling.top_p
 
-        # Flush AFTER burst reservation (which can preempt + recycle pages,
-        # queueing offloads whose content this dispatch overwrites) and
-        # immediately before the device call.
-        self._flush_page_moves()
-        self._rng, key = jax.random.split(self._rng)
-        out = llama.decode_steps(
-            self.params,
-            self.model_cfg,
-            tokens_dev,
-            self._dev(positions),
-            self.k_pages,
-            self.v_pages,
-            self._dev(block_tables),
-            self._dev(seq_lens),
-            self._dev(temperature),
-            self._dev(top_k),
-            self._dev(top_p),
-            key,
-            page_size=self.page_size,
-            num_steps=k,
-            interpret=self.config.interpret,
-            mesh=self.mesh,
-            k_scales=self.k_scales,
-            v_scales=self.v_scales,
-        )
-        if self.k_scales is None:
-            toks, self.k_pages, self.v_pages = out
-        else:
-            (
-                toks, self.k_pages, self.v_pages,
-                self.k_scales, self.v_scales,
-            ) = out
+            if prev is not None:
+                # Chain from the in-flight burst: positions/lengths advance
+                # by k without a host sync. Inactive padded lanes keep their
+                # 0 = inactive sentinel — they must not run garbage attention
+                # or write KV into reserved page 0 just because the active
+                # lanes advanced.
+                was_active = prev["seq_lens"] > 0
+                positions = np.where(was_active, prev["positions"] + k, 0)
+                seq_lens = np.where(was_active, prev["seq_lens"] + k, 0)
+            else:
+                tokens = np.zeros((lanes,), np.int32)
+                for i, seq in enumerate(active):
+                    tokens[i] = seq.all_tokens[-1]
+                    positions[i] = seq.num_tokens - 1
+                    seq_lens[i] = seq.num_tokens
+
+        with self.phase("decode_put"):
+            # Flush AFTER burst reservation (which can preempt + recycle
+            # pages, queueing offloads whose content this dispatch
+            # overwrites) and immediately before the device call.
+            self._flush_page_moves()
+            self._rng, key = jax.random.split(self._rng)
+            if prev is not None:
+                # chained: the burst's last sampled token stays on device
+                tokens_dev = prev["toks"][:, -1]
+            else:
+                tokens_dev = self._dev(tokens)
+            positions_d, block_tables_d = (
+                self._dev(positions), self._dev(block_tables)
+            )
+            seq_lens_d, temperature_d = (
+                self._dev(seq_lens), self._dev(temperature)
+            )
+            top_k_d, top_p_d = self._dev(top_k), self._dev(top_p)
+        with self.phase("decode_dispatch"):
+            out = llama.decode_steps(
+                self.params,
+                self.model_cfg,
+                tokens_dev,
+                positions_d,
+                self.k_pages,
+                self.v_pages,
+                block_tables_d,
+                seq_lens_d,
+                temperature_d,
+                top_k_d,
+                top_p_d,
+                key,
+                page_size=self.page_size,
+                num_steps=k,
+                interpret=self.config.interpret,
+                mesh=self.mesh,
+                k_scales=self.k_scales,
+                v_scales=self.v_scales,
+            )
+            if self.k_scales is None:
+                toks, self.k_pages, self.v_pages = out
+            else:
+                (
+                    toks, self.k_pages, self.v_pages,
+                    self.k_scales, self.v_scales,
+                ) = out
+        if self.obs_step_timing:
+            self.step_stats["decode_dispatches"] += 1
+            self.step_stats["decode_rows"] += len(active)
         if self.config.decode_fused_sampling:
             # Start the batched D2H copy of this burst's sampled ids NOW,
             # overlapped with whatever dispatches next — by the time the
@@ -2169,8 +2325,8 @@ class Engine:
             "toks": toks,
             "active": active,
             "k": k,
-            "positions": np.asarray(positions),
-            "seq_lens": np.asarray(seq_lens),
+            "positions": positions,
+            "seq_lens": seq_lens,
         }
         if prev is not None:
             # Commit burst N while burst N+1 executes on device.
@@ -2283,159 +2439,165 @@ class Engine:
         b = self.config.decode_batch_size
         assert len(seqs) <= b
 
-        # Round-1 proposals are recomputed on device; this host pass (same
-        # algorithm) only decides entry — an all-empty round must cost
-        # nothing (caller falls back to plain decode) — and sizes the
-        # exact single-round reservation.
-        prop_by_id = {s.seq_id: self._propose_prompt_lookup(s) for s in seqs}
-        if not any(prop_by_id.values()):
-            return False
+        with self.phase("decode_build"):
+            # Round-1 proposals are recomputed on device; this host pass (same
+            # algorithm) only decides entry — an all-empty round must cost
+            # nothing (caller falls back to plain decode) — and sizes the
+            # exact single-round reservation.
+            prop_by_id = {s.seq_id: self._propose_prompt_lookup(s) for s in seqs}
+            if not any(prop_by_id.values()):
+                return False
 
-        if rounds > 1:
-            # Multi-round bursts reserve the budget-capped worst case
-            # (later rounds' proposals are decided on device), which under
-            # pool pressure can preempt batchmates for capacity that is
-            # mostly unused at low acceptance. When the worst case doesn't
-            # fit the free pool, degrade THIS burst to a single round: its
-            # reservation is exact (the host proposal), so speculation
-            # never evicts a batchmate for headroom it may not use. Shapes
-            # stay static per dispatch — the degraded burst uses the
-            # spec_rounds=1 executable family (one extra compile the first
-            # time pressure hits).
-            need = 0
+            if rounds > 1:
+                # Multi-round bursts reserve the budget-capped worst case
+                # (later rounds' proposals are decided on device), which under
+                # pool pressure can preempt batchmates for capacity that is
+                # mostly unused at low acceptance. When the worst case doesn't
+                # fit the free pool, degrade THIS burst to a single round: its
+                # reservation is exact (the host proposal), so speculation
+                # never evicts a batchmate for headroom it may not use. Shapes
+                # stay static per dispatch — the degraded burst uses the
+                # spec_rounds=1 executable family (one extra compile the first
+                # time pressure hits).
+                need = 0
+                for seq in seqs:
+                    if not seq.block_table:
+                        continue
+                    worst = 1 + min(rounds * (k + 1), self._spec_budget(seq))
+                    need += max(
+                        0,
+                        -(-(seq.num_tokens + worst - 1) // ps)
+                        - len(seq.block_table),
+                    )
+                if need > self.block_manager.num_free:
+                    rounds = 1
+
+            # Reserve before building tables (can preempt batchmates — or
+            # abort; both leave block_table empty). Single-round bursts
+            # reserve the sequence's exact growth (1 committed + its clamped
+            # proposals — NOT the lane-aligned/lcm-inflated s_chunk: the KV
+            # scatter drops invalid positions, so padding needs no pages);
+            # multi-round bursts reserve the budget-capped worst case, since
+            # later rounds' proposals are decided on device.
             for seq in seqs:
                 if not seq.block_table:
                     continue
-                worst = 1 + min(rounds * (k + 1), self._spec_budget(seq))
-                need += max(
-                    0,
-                    -(-(seq.num_tokens + worst - 1) // ps)
-                    - len(seq.block_table),
-                )
-            if need > self.block_manager.num_free:
-                rounds = 1
+                if rounds == 1:
+                    n_res = 1 + len(prop_by_id[seq.seq_id])
+                else:
+                    n_res = 1 + min(rounds * (k + 1), self._spec_budget(seq))
+                self._reserve_slots_or_preempt(seq, n_res)
+            active = [s for s in seqs if s.block_table]
+            if not active:
+                return True
 
-        # Reserve before building tables (can preempt batchmates — or
-        # abort; both leave block_table empty). Single-round bursts
-        # reserve the sequence's exact growth (1 committed + its clamped
-        # proposals — NOT the lane-aligned/lcm-inflated s_chunk: the KV
-        # scatter drops invalid positions, so padding needs no pages);
-        # multi-round bursts reserve the budget-capped worst case, since
-        # later rounds' proposals are decided on device.
-        for seq in seqs:
-            if not seq.block_table:
-                continue
-            if rounds == 1:
-                n_res = 1 + len(prop_by_id[seq.seq_id])
-            else:
-                n_res = 1 + min(rounds * (k + 1), self._spec_budget(seq))
-            self._reserve_slots_or_preempt(seq, n_res)
-        active = [s for s in seqs if s.block_table]
-        if not active:
-            return True
-
-        # Device-resident token window: the last `scan_need` committed
-        # tokens (everything prompt lookup may match against) plus room
-        # for the burst's growth. All int32 inputs ship as ONE packed
-        # upload ([window | block_tables | 5 per-lane scalars]) and the
-        # f32 sampling params as another, not nine separate small uploads.
-        scan_need = min(
-            self.config.spec_max_scan + self.config.spec_ngram + 1,
-            self.config.max_model_len,
-        )
-        W = scan_need + rounds * (k + 1)
-        table_w = self._decode_table_width(active)
-        packed_i32 = np.zeros((b, W + table_w + 5), np.int32)
-        fparams = np.zeros((b, 2), np.float32)
-        fparams[:, 1] = 1.0  # top_p disabled default for padded lanes
-
-        for i, seq in enumerate(active):
-            toks = seq.all_tokens
-            n_win = min(len(toks), scan_need)
-            packed_i32[i, :n_win] = toks[-n_win:]
-            packed_i32[i, W : W + len(seq.block_table)] = seq.block_table
-            packed_i32[i, W + table_w] = n_win  # wlen
-            packed_i32[i, W + table_w + 1] = seq.num_tokens
-            packed_i32[i, W + table_w + 2] = self._spec_budget(seq)
-            packed_i32[i, W + table_w + 3] = int(self._gate_open(seq))
-            packed_i32[i, W + table_w + 4] = seq.sampling.top_k
-            fparams[i, 0] = seq.sampling.temperature
-            fparams[i, 1] = seq.sampling.top_p
-
-        self._flush_page_moves()
-        if (fparams[:, 0] > 0).any():
-            self._rng, key = jax.random.split(self._rng)
-        else:
-            # All-greedy burst: the device cond never reads the key —
-            # leave the engine rng untouched (sampled streams elsewhere in
-            # the run must not shift because a greedy lane speculated).
-            key = self._greedy_key
-        packed, self.k_pages, self.v_pages = (
-            llama.spec_decode_steps(
-                self.params,
-                self.model_cfg,
-                self._dev(packed_i32),
-                self._dev(fparams),
-                self.k_pages,
-                self.v_pages,
-                key,
-                page_size=ps,
-                num_rounds=rounds,
-                s_chunk=s_chunk,
-                ngram=self.config.spec_ngram,
-                spec_k=k,
-                max_scan=self.config.spec_max_scan,
-                table_w=table_w,
-                mesh=self.mesh,
-                attn_impl=self.prefill_attn,
-                interpret=self.config.interpret,
+            # Device-resident token window: the last `scan_need` committed
+            # tokens (everything prompt lookup may match against) plus room
+            # for the burst's growth. All int32 inputs ship as ONE packed
+            # upload ([window | block_tables | 5 per-lane scalars]) and the
+            # f32 sampling params as another, not nine separate small uploads.
+            scan_need = min(
+                self.config.spec_max_scan + self.config.spec_ngram + 1,
+                self.config.max_model_len,
             )
-        )
+            W = scan_need + rounds * (k + 1)
+            table_w = self._decode_table_width(active)
+            packed_i32 = np.zeros((b, W + table_w + 5), np.int32)
+            fparams = np.zeros((b, 2), np.float32)
+            fparams[:, 1] = 1.0  # top_p disabled default for padded lanes
+
+            for i, seq in enumerate(active):
+                toks = seq.all_tokens
+                n_win = min(len(toks), scan_need)
+                packed_i32[i, :n_win] = toks[-n_win:]
+                packed_i32[i, W : W + len(seq.block_table)] = seq.block_table
+                packed_i32[i, W + table_w] = n_win  # wlen
+                packed_i32[i, W + table_w + 1] = seq.num_tokens
+                packed_i32[i, W + table_w + 2] = self._spec_budget(seq)
+                packed_i32[i, W + table_w + 3] = int(self._gate_open(seq))
+                packed_i32[i, W + table_w + 4] = seq.sampling.top_k
+                fparams[i, 0] = seq.sampling.temperature
+                fparams[i, 1] = seq.sampling.top_p
+
+        with self.phase("decode_put"):
+            self._flush_page_moves()
+            if (fparams[:, 0] > 0).any():
+                self._rng, key = jax.random.split(self._rng)
+            else:
+                # All-greedy burst: the device cond never reads the key —
+                # leave the engine rng untouched (sampled streams elsewhere in
+                # the run must not shift because a greedy lane speculated).
+                key = self._greedy_key
+            packed_i32_d, fparams_d = self._dev(packed_i32), self._dev(fparams)
+        with self.phase("decode_dispatch"):
+            packed, self.k_pages, self.v_pages = (
+                llama.spec_decode_steps(
+                    self.params,
+                    self.model_cfg,
+                    packed_i32_d,
+                    fparams_d,
+                    self.k_pages,
+                    self.v_pages,
+                    key,
+                    page_size=ps,
+                    num_rounds=rounds,
+                    s_chunk=s_chunk,
+                    ngram=self.config.spec_ngram,
+                    spec_k=k,
+                    max_scan=self.config.spec_max_scan,
+                    table_w=table_w,
+                    mesh=self.mesh,
+                    attn_impl=self.prefill_attn,
+                    interpret=self.config.interpret,
+                )
+            )
+        if self.obs_step_timing:
+            self.step_stats["decode_dispatches"] += 1
+            self.step_stats["decode_rows"] += len(active)
         # The one host sync of the burst: ONE packed fetch (emit tokens +
         # per-round counters in a single array — separate fetches would
         # serialize several blocking round-trips on high-latency links).
-        t_fetch = time.perf_counter() if self.obs_step_timing else 0.0
-        packed = np.asarray(packed)  # [rounds, b, k+4]
-        if self.obs_step_timing:
-            self.step_stats["sample_s"] += time.perf_counter() - t_fetch
-        emit = packed[..., : k + 1]
-        emit_len = packed[..., k + 1]
-        prop_len = packed[..., k + 2]
-        acc = packed[..., k + 3]
+        with self.phase("decode_fetch"):
+            packed = np.asarray(packed)  # [rounds, b, k+4]
+        with self.phase("decode_commit"):
+            emit = packed[..., : k + 1]
+            emit_len = packed[..., k + 1]
+            prop_len = packed[..., k + 2]
+            acc = packed[..., k + 3]
 
-        self.spec_stats["verify_steps"] += rounds
-        self.spec_stats["bursts"] += 1
-        for i, seq in enumerate(active):
-            if not seq.block_table:
-                continue  # preempted by a batchmate's reservation
-            for r in range(rounds):
-                if self._should_finish(seq):
-                    break  # later rounds are surplus (discarded)
-                # Stats/gate updates only for rounds whose emissions are
-                # (at least partly) committed: a discarded surplus round
-                # would inflate the reported acceptance rate and mutate
-                # gate state for a finished sequence.
-                pl = int(prop_len[r, i])
-                ac = int(acc[r, i])
-                self.spec_stats["proposed"] += pl
-                self.spec_stats["accepted"] += ac
-                seq.spec_proposed += pl
-                seq.spec_accepted += ac
-                for j in range(int(emit_len[r, i])):
+            self.spec_stats["verify_steps"] += rounds
+            self.spec_stats["bursts"] += 1
+            for i, seq in enumerate(active):
+                if not seq.block_table:
+                    continue  # preempted by a batchmate's reservation
+                for r in range(rounds):
                     if self._should_finish(seq):
-                        break
-                    seq.num_computed = seq.num_tokens
-                    seq.output_tokens.append(int(emit[r, i, j]))
-                    seq.num_generated += 1
-            # The burst reservation covered exactly the burst's writes; a
-            # full acceptance in the last committed round advances
-            # num_tokens past them, so the NEXT dispatch's input token
-            # (written at the new num_tokens - 1) needs its slot ensured
-            # here — same post-emit append every other decode path does;
-            # without it the write lands in padding page 0.
-            if not self._should_finish(seq):
-                self._append_slot_or_preempt(seq)
-            self.block_manager.register_full_pages(seq)
+                        break  # later rounds are surplus (discarded)
+                    # Stats/gate updates only for rounds whose emissions are
+                    # (at least partly) committed: a discarded surplus round
+                    # would inflate the reported acceptance rate and mutate
+                    # gate state for a finished sequence.
+                    pl = int(prop_len[r, i])
+                    ac = int(acc[r, i])
+                    self.spec_stats["proposed"] += pl
+                    self.spec_stats["accepted"] += ac
+                    seq.spec_proposed += pl
+                    seq.spec_accepted += ac
+                    for j in range(int(emit_len[r, i])):
+                        if self._should_finish(seq):
+                            break
+                        seq.num_computed = seq.num_tokens
+                        seq.output_tokens.append(int(emit[r, i, j]))
+                        seq.num_generated += 1
+                # The burst reservation covered exactly the burst's writes; a
+                # full acceptance in the last committed round advances
+                # num_tokens past them, so the NEXT dispatch's input token
+                # (written at the new num_tokens - 1) needs its slot ensured
+                # here — same post-emit append every other decode path does;
+                # without it the write lands in padding page 0.
+                if not self._should_finish(seq):
+                    self._append_slot_or_preempt(seq)
+                self.block_manager.register_full_pages(seq)
         return True
 
     def _drain_inflight(self) -> None:
@@ -2445,26 +2607,24 @@ class Engine:
         self._commit_burst(burst)
 
     def _commit_burst(self, burst: dict) -> None:
-        timed = self.obs_step_timing
-        t0 = time.perf_counter() if timed else 0.0
-        toks = np.asarray(burst["toks"])  # [lanes, k] — the one host sync
-        if timed:
-            # The blocking share of the sampled-token fetch: near-zero when
-            # the fused fast path's async copy already landed the bytes.
-            self.step_stats["sample_s"] += time.perf_counter() - t0
-        for i, seq in enumerate(burst["active"]):
-            if not seq.block_table:
-                continue  # preempted after this burst was dispatched
-            for j in range(burst["k"]):
-                # Pre-check keeps the num_generated <= max_new_tokens
-                # invariant even when a reservation abort clamped the cap
-                # before the burst ran.
-                if self._should_finish(seq):
-                    break
-                seq.num_computed = seq.num_tokens
-                seq.output_tokens.append(int(toks[i, j]))
-                seq.num_generated += 1
-            self.block_manager.register_full_pages(seq)
+        with self.phase("decode_fetch"):
+            # The one host sync; its blocking share is near zero when the
+            # fused fast path's async copy already landed the bytes.
+            toks = np.asarray(burst["toks"])  # [lanes, k]
+        with self.phase("decode_commit"):
+            for i, seq in enumerate(burst["active"]):
+                if not seq.block_table:
+                    continue  # preempted after this burst was dispatched
+                for j in range(burst["k"]):
+                    # Pre-check keeps the num_generated <= max_new_tokens
+                    # invariant even when a reservation abort clamped the
+                    # cap before the burst ran.
+                    if self._should_finish(seq):
+                        break
+                    seq.num_computed = seq.num_tokens
+                    seq.output_tokens.append(int(toks[i, j]))
+                    seq.num_generated += 1
+                self.block_manager.register_full_pages(seq)
 
     def _reserve_slots_or_preempt(self, seq: Sequence, n: int) -> None:
         """Ensure ``seq`` can grow by ``n`` tokens (KV slots for positions
@@ -2614,25 +2774,27 @@ class Engine:
         )
 
     def _sample(self, logits: jnp.ndarray, seqs: list[Sequence]) -> np.ndarray:
-        b = logits.shape[0]
-        temperature = np.zeros((b,), np.float32)
-        top_k = np.zeros((b,), np.int32)
-        top_p = np.ones((b,), np.float32)
-        for i, seq in enumerate(seqs[:b]):
-            temperature[i] = seq.sampling.temperature
-            top_k[i] = seq.sampling.top_k
-            top_p[i] = seq.sampling.top_p
-        self._rng, key = jax.random.split(self._rng)
-        timed = self.obs_step_timing
-        t0 = time.perf_counter() if timed else 0.0
-        out = sample_tokens(
-            logits.astype(jnp.float32),
-            self._dev(temperature),
-            self._dev(top_k),
-            self._dev(top_p),
-            key,
-        )
-        out = np.asarray(out)
-        if timed:
-            self.step_stats["sample_s"] += time.perf_counter() - t0
-        return out
+        """First tokens of a prefill batch (decode samples on the device,
+        inside its own dispatch). The sampler's inputs go up while the
+        prefill runs; the fetch waits for the prefill dispatch too."""
+        with self.phase("prefill_build"):
+            b = logits.shape[0]
+            temperature = np.zeros((b,), np.float32)
+            top_k = np.zeros((b,), np.int32)
+            top_p = np.ones((b,), np.float32)
+            for i, seq in enumerate(seqs[:b]):
+                temperature[i] = seq.sampling.temperature
+                top_k[i] = seq.sampling.top_k
+                top_p[i] = seq.sampling.top_p
+        with self.phase("prefill_put"):
+            self._rng, key = jax.random.split(self._rng)
+            temperature_d, top_k_d, top_p_d = (
+                self._dev(temperature), self._dev(top_k), self._dev(top_p)
+            )
+        with self.phase("prefill_fetch"):
+            return np.asarray(
+                sample_tokens(
+                    logits.astype(jnp.float32),
+                    temperature_d, top_k_d, top_p_d, key,
+                )
+            )

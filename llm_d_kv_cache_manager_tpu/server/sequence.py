@@ -54,7 +54,14 @@ class Sequence:
     #: prefix-cache registration bookkeeping (incremental hashing)
     num_registered_pages: int = 0
     last_chain_hash: Optional[int] = None
+    #: when the ENGINE LOOP made this Sequence (between two steps), which
+    #: is later than when the request reached the pod: see ``submit_time``
     arrival_time: float = field(default_factory=time.monotonic)
+    #: when the pod took the request (``PodServer.submit``'s one clock
+    #: read, riding the staging tuple here); ``arrival_time`` for a
+    #: sequence added to the engine directly. ``staged_s`` and ``queue_s``
+    #: count from it; ``ttft`` still counts from ``arrival_time``.
+    submit_time: Optional[float] = None
     first_token_time: Optional[float] = None
     finish_time: Optional[float] = None
     #: when the first prefill chunk for this sequence dispatched — the
@@ -126,6 +133,8 @@ class Sequence:
     def __post_init__(self):
         if self.user_prompt_len < 0:
             self.user_prompt_len = len(self.prompt_tokens)
+        if self.submit_time is None:
+            self.submit_time = self.arrival_time
 
     @property
     def all_tokens(self) -> list[int]:
@@ -168,6 +177,22 @@ class Sequence:
         if self.first_token_time is None:
             return None
         return self.first_token_time - self.arrival_time
+
+    @property
+    def staged_s(self) -> float:
+        """Submitted -> taken by the engine loop: the wait behind the step
+        in progress, which ``ttft`` leaves out."""
+        return max(self.arrival_time - self.submit_time, 0.0)
+
+    @property
+    def queue_s(self) -> Optional[float]:
+        """Submitted -> first prefill dispatch, staging included (what the
+        ``pod.queue`` span covers); the whole life of a request that
+        finished without reaching prefill; None while it still waits."""
+        for end in (self.prefill_start_time, self.finish_time):
+            if end is not None:
+                return max(end - self.submit_time, 0.0)
+        return None
 
     @property
     def mean_itl(self) -> Optional[float]:

@@ -67,7 +67,7 @@ from ..models import LlamaConfig
 from ..obs import lifecycle as lifecycle_mod
 from ..obs.tracing import Tracer, format_traceparent, parse_traceparent
 from ..utils import get_logger, log_context
-from .engine import Engine, EngineConfig
+from .engine import NO_PHASE, Engine, EngineConfig
 from .block_manager import BlockManagerConfig
 from .sequence import SamplingParams, Sequence
 
@@ -1696,7 +1696,7 @@ class PodServer:
                 self.qos.reset_pending()
         for job in jobs:
             job["cancel"].set()
-        for _, _, _, _, fut, span, _, _, _ in staged:
+        for _, _, _, _, fut, span, *_ in staged:
             span.set_attr("error", str(exc))
             span.end()
             if not fut.done():
@@ -1888,6 +1888,18 @@ class PodServer:
             self.flight.record_event(kind, **attrs)
 
     def _engine_loop(self) -> None:
+        # The engine's ``loop`` phase: open from the end of one
+        # ``engine.step()`` to the start of the next while work is pending
+        # (draining what was staged, resolving futures, metric syncs),
+        # closed before the loop parks — an idle wait is not loop time.
+        # ``NO_PHASE`` with step timing off.
+        between = NO_PHASE
+
+        def close_between() -> None:
+            nonlocal between
+            between.__exit__(None, None, None)
+            between = NO_PHASE
+
         try:
             while True:
                 with self._work:
@@ -1909,6 +1921,7 @@ class PodServer:
                         or self._controller_reads
                         or self.engine.has_ready_work
                     ):
+                        close_between()
                         self._work.wait(timeout=0.1)
                     if not self._running:
                         return
@@ -2002,7 +2015,7 @@ class PodServer:
                         fut.set_exception(e)
                 for (
                     tokens, sampling, deadline, rid, fut, span, action,
-                    pull, tenant,
+                    pull, tenant, submit_time,
                 ) in staged:
                     try:
                         if self.qos is not None:
@@ -2014,11 +2027,12 @@ class PodServer:
                                 deadline=deadline, tenant=tenant,
                                 priority=pol.priority,
                                 qos_weight=pol.weight,
+                                submit_time=submit_time,
                             )
                         else:
                             seq = self.engine.add_request(
                                 tokens, sampling, request_id=rid,
-                                deadline=deadline,
+                                deadline=deadline, submit_time=submit_time,
                             )
                     except ValueError as e:
                         self._forget_pending(len(tokens), tenant)
@@ -2071,7 +2085,10 @@ class PodServer:
                                 if self._loop_lag_s is None
                                 else 0.7 * self._loop_lag_s + 0.3 * sample
                             )
+                    close_between()
                     finished = self.engine.step()
+                    between = self.engine.phase("loop")
+                    between.__enter__()
                     if self.flight is not None:
                         # Per-step telemetry onto the flight ring: phase
                         # deltas (engine step timing is forced on by the
@@ -2155,6 +2172,8 @@ class PodServer:
             log.error("engine loop died", error=repr(e))
             self._failed = f"{type(e).__name__}: {e}"
             self._fail_outstanding(RuntimeError(f"engine failed: {self._failed}"))
+        finally:
+            close_between()
 
     # -- fleet self-healing --------------------------------------------------
     def _self_heal_loop(self) -> None:
@@ -3170,6 +3189,10 @@ class PodServer:
         and drives per-tenant admission budgets, priority scheduling,
         cache accounting and observability slices; with the knob off
         (the default) the argument is ignored."""
+        # The door: the one clock read of a request's admission. Queue wait
+        # (``queue_s``, ``staged_s``, the ``pod.queue`` span) counts from
+        # here, whether or not anything is switched on.
+        submit_time = time.monotonic()
         # Surface obviously-bad requests synchronously with the same checks
         # add_request applies (the rest raise through the Future).
         if not prompt_tokens:
@@ -3232,6 +3255,7 @@ class PodServer:
                     "pod": self.config.pod_identifier,
                     "prompt_tokens": len(prompt_tokens),
                 },
+                start_mono=submit_time,
             )
             fut.trace_context = span.context
             self._pending += 1
@@ -3245,7 +3269,7 @@ class PodServer:
             )
             self._staging.append(
                 (list(prompt_tokens), sampling, deadline, rid, fut, span,
-                 route_action, pull, tkey)
+                 route_action, pull, tkey, submit_time)
             )
             self._work.notify()
         return fut
@@ -3453,6 +3477,8 @@ class PodServer:
                         "cached_prompt_tokens": seq.num_cached_prompt,
                     },
                     "ttft_s": seq.ttft,
+                    "queue_s": seq.queue_s,
+                    "staged_s": seq.staged_s,
                 },
                 headers=headers,
             )

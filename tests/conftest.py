@@ -49,7 +49,29 @@ def free_tcp_port() -> int:
     return port
 
 
+def _build_native_once() -> None:
+    """``*.so`` is git-ignored, so a fresh checkout has no native library:
+    suites then decide ``skipif(not native_available())`` at collection and
+    the loaders remember the miss for the life of a worker, and the count
+    of passing tests depends on what was run in the tree before. Build
+    them here, before any worker collects. No compiler: the skips stand."""
+    import subprocess
+
+    from llm_d_kv_cache_manager_tpu.native import build
+
+    if all(os.path.isfile(os.path.join(build.HERE, lib))
+           for lib in build.LIBS.values()):
+        return
+    try:
+        build.build(verbose=False)
+    except (OSError, subprocess.CalledProcessError) as e:
+        print(f"native libraries not built ({e!r}): their tests skip",
+              file=sys.stderr)
+
+
 def pytest_configure(config):
+    if not hasattr(config, "workerinput"):  # the controller, or no xdist
+        _build_native_once()
     # Lock-order/race harness: LOCKTRACE=1 routes every lock created from
     # here on through utils.locktrace's TracingLock, so the concurrency
     # hammer and chaos suites run under cycle + guarded-attribute checking

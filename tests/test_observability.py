@@ -494,7 +494,8 @@ class TestKnobsOffParity:
             assert resp.status == 200
             data = await resp.json()
             assert set(data) == {
-                "id", "object", "model", "choices", "usage", "ttft_s"
+                "id", "object", "model", "choices", "usage", "ttft_s",
+                "queue_s", "staged_s",
             }
             assert set(data["choices"][0]) == {
                 "index", "text", "token_ids", "finish_reason"

@@ -593,7 +593,8 @@ class TestKnobsOffParity:
             assert resp.status == 200
             data = await resp.json()
             assert set(data) == {
-                "id", "object", "model", "choices", "usage", "ttft_s"
+                "id", "object", "model", "choices", "usage", "ttft_s",
+                "queue_s", "staged_s",
             }
             resp = await c.get("/stats")
             stats = await resp.json()
