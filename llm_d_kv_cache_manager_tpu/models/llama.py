@@ -46,6 +46,7 @@ from ..ops import (
     rope_frequencies,
 )
 from ..ops.rope import RopeScalingConfig
+from ..ops.sampling import sample_tokens, spec_sample
 from .quant import QuantizedTensor, materialize as _w
 
 
@@ -1303,9 +1304,10 @@ def decode_steps(
     early keep decoding into their reserved pages and the host discards the
     surplus tokens.
     """
-    from ..ops.sampling import sample_tokens
-
     quantized = k_scales is not None
+    # The sampler's gate: the lanes' parameters do not change inside the
+    # burst, so whether any lane samples is decided once, out here.
+    any_sampled = jnp.any(temperature > 0)
 
     def body(carry, key):
         tokens, positions, seq_lens, k_pages, v_pages, k_sc, v_sc = carry
@@ -1314,7 +1316,10 @@ def decode_steps(
             block_tables, seq_lens, page_size, interpret, mesh,
             k_sc, v_sc,
         )
-        nxt = sample_tokens(logits.astype(jnp.float32), temperature, top_k, top_p, key)
+        nxt = sample_tokens(
+            logits.astype(jnp.float32), temperature, top_k, top_p, key,
+            any_sampled,
+        )
         return (nxt, positions + 1, seq_lens + 1, k_pages, v_pages, k_sc, v_sc), nxt
 
     # None scales are valid (empty) scan-carry leaves, so the knob-off
@@ -1493,21 +1498,10 @@ def spec_decode_steps(
             [chunk[:, 1:], jnp.zeros((b, 1), jnp.int32)], axis=1
         )
 
-        def verify_greedy(logits, drafts_s, key):
-            g = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            return g == drafts_s, g, g
-
-        def verify_sampled(logits, drafts_s, key):
-            from ..ops.sampling import spec_sample
-
-            return spec_sample(
-                logits, drafts_s, temperature, top_k, top_p, key
-            )
-
-        # All-greedy bursts skip the filtered-distribution sorts entirely.
-        accept, replacement, free = jax.lax.cond(
-            jnp.any(temperature > 0), verify_sampled, verify_greedy,
-            logits, drafts_shift, key,
+        # ``spec_sample`` gates itself: an all-greedy burst skips the
+        # filtered-distribution sorts entirely.
+        accept, replacement, free = spec_sample(
+            logits, drafts_shift, temperature, top_k, top_p, key
         )
         lead = jnp.cumprod(accept[:, :k].astype(jnp.int32), axis=1)  # [b, k]
         acc = jnp.sum(

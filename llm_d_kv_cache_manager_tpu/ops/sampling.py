@@ -9,11 +9,21 @@ probability ``P(d)``; on rejection sample from the residual ``P`` with
 ``d`` removed (for a delta-function proposal the standard
 speculative-sampling residual ``(p - q)_+`` is exactly that) — the emitted
 stream is an exact sample of the target distribution per position.
+
+The gate. The filter costs two full sorts, a softmax and a cumsum over
+``[rows, vocab]``; a dispatch whose lanes are all greedy needs none of it.
+Both entry points are a ``lax.cond`` on ``jnp.any(temperature > 0)``, read
+on the device from the lanes' own ``temperature`` array: with no sampled
+lane the program executes the ``argmax`` alone, with one or more it runs
+the whole filter for EVERY row of the batch (a mixed batch pays the full
+price; its greedy rows still return their ``argmax``). One compiled
+program per shape either way, no static flag; results are bit-identical
+to the ungated functions for the same key. ``Engine.step_stats
+["decode_sampled_dispatches"]`` counts the decode dispatches that take the
+sampled branch.
 """
 
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -52,47 +62,44 @@ def _filtered_logits(
     return jnp.where(masked >= threshold, masked, -jnp.inf)
 
 
-@functools.partial(jax.jit, static_argnames=())
-def sample_tokens(
-    logits: jnp.ndarray,  # [batch, vocab] f32
-    temperature: jnp.ndarray,  # [batch] f32; 0 = greedy
-    top_k: jnp.ndarray,  # [batch] int32; 0 = disabled
-    top_p: jnp.ndarray,  # [batch] f32; 1 = disabled
-    rng_key: jax.Array,
-) -> jnp.ndarray:
-    """Returns sampled token ids [batch] int32."""
+def _sample_filtered(logits, temperature, top_k, top_p, rng_key):
+    """The sampled branch: every row filtered, greedy rows keep argmax."""
     greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     masked = _filtered_logits(logits, temperature, top_k, top_p)
     sampled = jax.random.categorical(rng_key, masked, axis=-1).astype(jnp.int32)
     return jnp.where(temperature > 0, sampled, greedy)
 
 
-@functools.partial(jax.jit, static_argnames=())
-def spec_sample(
-    logits: jnp.ndarray,  # [batch, s, vocab] f32 — verify logits per position
-    drafts: jnp.ndarray,  # [batch, s] int32 — proposed token per position
+def _sample_greedy(logits, temperature, top_k, top_p, rng_key):
+    """The branch of a dispatch in which no lane samples."""
+    return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+
+@jax.jit
+def sample_tokens(
+    logits: jnp.ndarray,  # [batch, vocab] f32
     temperature: jnp.ndarray,  # [batch] f32; 0 = greedy
-    top_k: jnp.ndarray,  # [batch] int32
-    top_p: jnp.ndarray,  # [batch] f32
+    top_k: jnp.ndarray,  # [batch] int32; 0 = disabled
+    top_p: jnp.ndarray,  # [batch] f32; 1 = disabled
     rng_key: jax.Array,
-) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """Speculative verification for deterministic drafts.
+    any_sampled: jnp.ndarray | None = None,
+) -> jnp.ndarray:
+    """Returns sampled token ids [batch] int32.
 
-    Per position ``j`` with filtered target distribution ``P_j``:
-
-    - ``accept[b, j]``: draft accepted — sampled lanes with probability
-      ``P_j(draft)``, greedy lanes iff ``draft == argmax``;
-    - ``replacement[b, j]``: the token to emit at the FIRST rejection —
-      sampled from ``P_j`` with the draft removed and renormalized (the
-      ``(p - q)_+`` residual for a delta proposal; never equals the
-      draft), greedy lanes the plain argmax;
-    - ``free[b, j]``: an unconditioned sample from ``P_j`` — used for the
-      bonus position after all drafts accept (and for empty-proposal
-      lanes, where position 0 is a plain decode sample).
-
-    The host walks accept[] to the first False per lane; everything after
-    is discarded (those positions were scored under a rejected context).
+    ``any_sampled`` is the gate's predicate, ``jnp.any(temperature > 0)``,
+    for a caller that has it already (``decode_steps`` computes it once
+    outside its scan); left out, it is computed here.
     """
+    if any_sampled is None:
+        any_sampled = jnp.any(temperature > 0)
+    return jax.lax.cond(
+        any_sampled, _sample_filtered, _sample_greedy,
+        logits, temperature, top_k, top_p, rng_key,
+    )
+
+
+def _spec_filtered(logits, drafts, temperature, top_k, top_p, rng_key):
+    """The sampled branch of ``spec_sample``: every row filtered."""
     b, s, vocab = logits.shape
     flat = logits.reshape(b * s, vocab)
     rep = lambda x: jnp.repeat(x, s)
@@ -123,4 +130,42 @@ def spec_sample(
         accept.reshape(b, s),
         replacement.reshape(b, s),
         free.reshape(b, s),
+    )
+
+
+def _spec_greedy(logits, drafts, temperature, top_k, top_p, rng_key):
+    """The branch of a burst in which no lane samples."""
+    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    return drafts.astype(jnp.int32) == greedy, greedy, greedy
+
+
+@jax.jit
+def spec_sample(
+    logits: jnp.ndarray,  # [batch, s, vocab] f32 — verify logits per position
+    drafts: jnp.ndarray,  # [batch, s] int32 — proposed token per position
+    temperature: jnp.ndarray,  # [batch] f32; 0 = greedy
+    top_k: jnp.ndarray,  # [batch] int32
+    top_p: jnp.ndarray,  # [batch] f32
+    rng_key: jax.Array,
+) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """Speculative verification for deterministic drafts.
+
+    Per position ``j`` with filtered target distribution ``P_j``:
+
+    - ``accept[b, j]``: draft accepted — sampled lanes with probability
+      ``P_j(draft)``, greedy lanes iff ``draft == argmax``;
+    - ``replacement[b, j]``: the token to emit at the FIRST rejection —
+      sampled from ``P_j`` with the draft removed and renormalized (the
+      ``(p - q)_+`` residual for a delta proposal; never equals the
+      draft), greedy lanes the plain argmax;
+    - ``free[b, j]``: an unconditioned sample from ``P_j`` — used for the
+      bonus position after all drafts accept (and for empty-proposal
+      lanes, where position 0 is a plain decode sample).
+
+    The host walks accept[] to the first False per lane; everything after
+    is discarded (those positions were scored under a rejected context).
+    """
+    return jax.lax.cond(
+        jnp.any(temperature > 0), _spec_filtered, _spec_greedy,
+        logits, drafts, temperature, top_k, top_p, rng_key,
     )

@@ -752,8 +752,10 @@ class Engine:
         #: ``demote_s`` (remote-tier demotion payload builds) are slices
         #: INSIDE other phases and get no span. Counters: ``steps``,
         #: ``decode_dispatches``, ``decode_rows`` (real lanes of each
-        #: decode dispatch, summed); prefill dispatches are counted, always,
-        #: in ``prefill_stats``.
+        #: decode dispatch, summed), ``decode_sampled_dispatches`` (those
+        #: with a ``temperature > 0`` lane: the sampler's gate runs its
+        #: vocabulary filter in these and in no other); prefill dispatches
+        #: are counted, always, in ``prefill_stats``.
         #: Off by default: ``obs_step_timing=False`` skips every clock
         #: read and every count, so the legacy step path is untouched.
         self.obs_step_timing = False
@@ -761,6 +763,7 @@ class Engine:
             "steps": 0,
             "decode_dispatches": 0,
             "decode_rows": 0,
+            "decode_sampled_dispatches": 0,
             "prefill_s": 0.0,
             "decode_s": 0.0,
             "sample_s": 0.0,
@@ -2311,9 +2314,7 @@ class Engine:
                     toks, self.k_pages, self.v_pages,
                     self.k_scales, self.v_scales,
                 ) = out
-        if self.obs_step_timing:
-            self.step_stats["decode_dispatches"] += 1
-            self.step_stats["decode_rows"] += len(active)
+        self._count_decode_dispatch(len(active), temperature)
         if self.config.decode_fused_sampling:
             # Start the batched D2H copy of this burst's sampled ids NOW,
             # overlapped with whatever dispatches next — by the time the
@@ -2551,9 +2552,7 @@ class Engine:
                     interpret=self.config.interpret,
                 )
             )
-        if self.obs_step_timing:
-            self.step_stats["decode_dispatches"] += 1
-            self.step_stats["decode_rows"] += len(active)
+        self._count_decode_dispatch(len(active), fparams[:, 0])
         # The one host sync of the burst: ONE packed fetch (emit tokens +
         # per-round counters in a single array — separate fetches would
         # serialize several blocking round-trips on high-latency links).
@@ -2772,6 +2771,17 @@ class Engine:
         self.lifecycle_stats["priority_preempted"] = (
             self.lifecycle_stats.get("priority_preempted", 0) + 1
         )
+
+    def _count_decode_dispatch(self, rows: int, temperature: np.ndarray) -> None:
+        """``step_stats``' counters of one decode dispatch: its real lanes,
+        and whether any of them samples (``temperature`` is the host-side
+        array the dispatch was given)."""
+        if self.obs_step_timing:
+            self.step_stats["decode_dispatches"] += 1
+            self.step_stats["decode_rows"] += rows
+            self.step_stats["decode_sampled_dispatches"] += bool(
+                (temperature > 0).any()
+            )
 
     def _sample(self, logits: jnp.ndarray, seqs: list[Sequence]) -> np.ndarray:
         """First tokens of a prefill batch (decode samples on the device,

@@ -169,6 +169,81 @@ def test_decode_rows_are_the_lanes_that_ran():
     ) == 2
 
 
+SAMPLED = dict(temperature=0.8, top_k=8)
+
+
+@pytest.mark.parametrize(
+    "kw",
+    [{}, dict(spec_decode="prompt_lookup", spec_k=4, spec_ngram=2)],
+    ids=["fused", "spec"],
+)
+def test_sampled_dispatches_are_those_with_a_sampled_lane(kw):
+    """``decode_sampled_dispatches`` counts the dispatches in which the
+    sampler's gate runs its vocabulary filter: those that carry a lane with
+    ``temperature > 0``, and no dispatch of greedy lanes."""
+    eng = Engine(_engine_cfg(**kw))
+    eng.obs_step_timing = True
+    st = eng.step_stats
+    _run(eng, [_prompt(20, 9), _prompt(21, 9)], max_new_tokens=6)
+    assert st["decode_dispatches"] > 0
+    assert st["decode_sampled_dispatches"] == 0
+    # one sampled lane beside a greedy one that outlives it: every dispatch
+    # while it runs is counted, those after it has finished are not
+    before = dict(st)
+    eng.add_request(_prompt(22, 9), SamplingParams(max_new_tokens=12))
+    sampled = eng.add_request(
+        _prompt(23, 9), SamplingParams(max_new_tokens=4, **SAMPLED)
+    )
+    eng.run_until_complete()
+    assert len(sampled.generated_tokens) == 4
+    took = st["decode_dispatches"] - before["decode_dispatches"]
+    took_sampled = (
+        st["decode_sampled_dispatches"] - before["decode_sampled_dispatches"]
+    )
+    assert 0 < took_sampled < took
+    if not kw:  # one token a dispatch, the first from the prefill
+        assert (took, took_sampled) == (11, 3)
+    # and alone: every dispatch
+    before = dict(st)
+    eng.add_request(_prompt(24, 9), SamplingParams(max_new_tokens=5, **SAMPLED))
+    eng.run_until_complete()
+    assert (
+        st["decode_sampled_dispatches"] - before["decode_sampled_dispatches"]
+        == st["decode_dispatches"] - before["decode_dispatches"] > 0
+    )
+
+
+def test_sampled_dispatches_are_in_stats_with_the_switch_on():
+    import asyncio
+
+    from aiohttp.test_utils import TestClient, TestServer
+
+    def stats_after(server, requests):
+        server.start()
+
+        async def scenario():
+            async with TestClient(TestServer(server.build_app())) as c:
+                for extra in requests:
+                    resp = await c.post("/v1/completions", json={
+                        "prompt_token_ids": _prompt(25, 10), "max_tokens": 4,
+                        **extra,
+                    })
+                    assert resp.status == 200
+                return await (await c.get("/stats")).json()
+
+        try:
+            return asyncio.run(scenario())
+        finally:
+            server.shutdown()
+
+    on = stats_after(
+        _pod("sampled-pod", obs_metrics=True), [{}, {"temperature": 0.9}]
+    )["obs"]["step_stats"]
+    assert on["decode_dispatches"] == 6 and on["decode_sampled_dispatches"] == 3
+    # off: the counter is not fed and /stats has no such block
+    assert "obs" not in stats_after(_pod("quiet-pod"), [{"temperature": 0.9}])
+
+
 def test_a_burst_drained_ahead_of_a_prefill_is_decode_time():
     """With ``decode_pipeline`` a prefill first commits the burst in flight:
     that fetch and commit come between ``schedule`` and ``prefill_build``

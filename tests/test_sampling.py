@@ -6,10 +6,14 @@ below are what make the emitted stream an exact sample of the target
 distribution, so they are pinned as pure-function tests.
 """
 
+import functools
+
 import numpy as np
+import pytest
 import jax
 import jax.numpy as jnp
 
+from llm_d_kv_cache_manager_tpu.models import llama
 from llm_d_kv_cache_manager_tpu.ops.sampling import sample_tokens, spec_sample
 
 V = 16
@@ -114,3 +118,265 @@ class TestSampleTokensStillIntact:
         argmax = np.asarray(jnp.argmax(logits, -1))
         assert toks[0] == argmax[0] and toks[1] == argmax[1]
         assert all(0 <= t < V for t in toks)
+
+
+# -- the gate: no vocabulary sort in a dispatch where no lane samples ---------------
+#
+# The oracle is the sampler as it was before the gate, copied here whole:
+# the gated functions must return what these return, bit for bit, for the
+# same key.
+
+
+def _oracle_filtered_logits(logits, temperature, top_k, top_p):
+    vocab = logits.shape[-1]
+    safe_t = jnp.where(temperature > 0, temperature, 1.0)[:, None]
+    scaled = logits / safe_t
+    sorted_desc = jnp.sort(scaled, axis=-1)[:, ::-1]
+    k = jnp.where(top_k > 0, top_k, vocab).astype(jnp.int32)
+    kth_val = jnp.take_along_axis(
+        sorted_desc, jnp.clip(k - 1, 0, vocab - 1)[:, None], axis=-1
+    )
+    masked = jnp.where(scaled >= kth_val, scaled, -jnp.inf)
+    sorted_masked = jnp.sort(masked, axis=-1)[:, ::-1]
+    probs_sorted = jax.nn.softmax(sorted_masked, axis=-1)
+    cumprobs = jnp.cumsum(probs_sorted, axis=-1)
+    cutoff_mask = (cumprobs - probs_sorted) < top_p[:, None]
+    threshold = jnp.min(
+        jnp.where(cutoff_mask, sorted_masked, jnp.inf), axis=-1, keepdims=True
+    )
+    return jnp.where(masked >= threshold, masked, -jnp.inf)
+
+
+@jax.jit
+def _oracle_sample_tokens(logits, temperature, top_k, top_p, rng_key):
+    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    masked = _oracle_filtered_logits(logits, temperature, top_k, top_p)
+    sampled = jax.random.categorical(rng_key, masked, axis=-1).astype(jnp.int32)
+    return jnp.where(temperature > 0, sampled, greedy)
+
+
+@jax.jit
+def _oracle_spec_sample(logits, drafts, temperature, top_k, top_p, rng_key):
+    b, s, vocab = logits.shape
+    flat = logits.reshape(b * s, vocab)
+    rep = lambda x: jnp.repeat(x, s)
+    masked = _oracle_filtered_logits(
+        flat, rep(temperature), rep(top_k), rep(top_p)
+    )
+    greedy = jnp.argmax(flat, axis=-1).astype(jnp.int32)
+    d = drafts.reshape(-1).astype(jnp.int32)
+    probs = jax.nn.softmax(masked, axis=-1)
+    p_draft = jnp.take_along_axis(probs, d[:, None], axis=-1)[:, 0]
+    k_u, k_repl, k_free = jax.random.split(rng_key, 3)
+    u = jax.random.uniform(k_u, (b * s,))
+    accept = jnp.where(rep(temperature) > 0, u < p_draft, d == greedy)
+    draft_hot = jax.nn.one_hot(d, vocab, dtype=bool)
+    masked_no_draft = jnp.where(draft_hot, -jnp.inf, masked)
+    repl_sampled = jax.random.categorical(k_repl, masked_no_draft, axis=-1)
+    replacement = jnp.where(
+        rep(temperature) > 0, repl_sampled, greedy
+    ).astype(jnp.int32)
+    free_sampled = jax.random.categorical(k_free, masked, axis=-1)
+    free = jnp.where(rep(temperature) > 0, free_sampled, greedy).astype(
+        jnp.int32
+    )
+    return accept.reshape(b, s), replacement.reshape(b, s), free.reshape(b, s)
+
+
+LANES, WIDE = 16, 512
+SAMPLED_LANES = {"all_greedy": (), "one_of_16": (5,), "all_sampled": range(LANES)}
+
+
+def _lane_params(lanes, filtered):
+    """Per-lane sampling parameters: ``lanes`` sample, the rest are greedy."""
+    temperature = np.zeros((LANES,), np.float32)
+    temperature[list(lanes)] = 0.7 + 0.05 * np.arange(len(lanes))
+    top_k = np.zeros((LANES,), np.int32)
+    top_p = np.ones((LANES,), np.float32)
+    if filtered:
+        top_k[::2] = 7  # greedy lanes carry filters too: they must stay inert
+        top_p[1::3] = 0.8
+    return jnp.asarray(temperature), jnp.asarray(top_k), jnp.asarray(top_p)
+
+
+@pytest.mark.parametrize("filtered", [False, True], ids=["plain", "topk_topp"])
+@pytest.mark.parametrize("lanes", SAMPLED_LANES)
+class TestGateIsBitIdenticalToTheUngatedSampler:
+    def test_sample_tokens(self, lanes, filtered):
+        rng = np.random.default_rng(11)
+        logits = jnp.asarray(rng.standard_normal((LANES, WIDE)) * 3, jnp.float32)
+        params = _lane_params(SAMPLED_LANES[lanes], filtered)
+        for seed in (0, 1, 2**31 + 7):
+            key = jax.random.PRNGKey(seed)
+            np.testing.assert_array_equal(
+                np.asarray(sample_tokens(logits, *params, key)),
+                np.asarray(_oracle_sample_tokens(logits, *params, key)),
+            )
+
+    def test_spec_sample(self, lanes, filtered):
+        rng = np.random.default_rng(12)
+        logits = jnp.asarray(
+            rng.standard_normal((LANES, 3, WIDE)) * 3, jnp.float32
+        )
+        # half the drafts are the argmax, so greedy lanes accept and reject
+        drafts = np.array(jnp.argmax(logits, -1))
+        drafts[:, 1] = (drafts[:, 1] + 1) % WIDE
+        drafts = jnp.asarray(drafts, jnp.int32)
+        params = _lane_params(SAMPLED_LANES[lanes], filtered)
+        for seed in (0, 3):
+            key = jax.random.PRNGKey(seed)
+            got = spec_sample(logits, drafts, *params, key)
+            want = _oracle_spec_sample(logits, drafts, *params, key)
+            for g, w in zip(got, want):
+                assert g.dtype == w.dtype
+                np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+# What the filter is made of, and nothing else in these programs is: a
+# program that holds one of these outside the sampled branch pays for the
+# whole vocabulary in every greedy dispatch.
+FILTER_PRIMITIVES = {"sort", "cumsum"}
+
+
+def _walk(jaxpr, where=()):
+    """Every equation of ``jaxpr`` and of the programs nested in it, each
+    with the cond branches it sits under (a tuple of branch indices; 0 is
+    the false branch)."""
+    for eqn in jaxpr.eqns:
+        yield eqn, where
+        if eqn.primitive.name == "cond":
+            for i, branch in enumerate(eqn.params["branches"]):
+                yield from _walk(branch.jaxpr, where + (i,))
+            continue
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _walk(inner, where)
+
+
+def _assert_filter_is_gated(closed_jaxpr):
+    found = list(_walk(closed_jaxpr.jaxpr))
+    assert any(eqn.primitive.name == "cond" for eqn, _ in found)
+    filters = [
+        (eqn.primitive.name, where) for eqn, where in found
+        if eqn.primitive.name in FILTER_PRIMITIVES
+    ]
+    assert {name for name, _ in filters} == FILTER_PRIMITIVES
+    # inside the sampled (true) branch of the one cond, none elsewhere
+    assert all(where == (1,) for _, where in filters), filters
+    return found
+
+
+class TestTheFilterSitsInsideTheSampledBranch:
+    def test_sample_tokens(self):
+        params = _lane_params((), True)
+        _assert_filter_is_gated(
+            jax.make_jaxpr(sample_tokens)(
+                jnp.zeros((LANES, WIDE)), *params, jax.random.PRNGKey(0)
+            )
+        )
+
+    def test_spec_sample(self):
+        params = _lane_params((), True)
+        _assert_filter_is_gated(
+            jax.make_jaxpr(spec_sample)(
+                jnp.zeros((LANES, 3, WIDE)), jnp.zeros((LANES, 3), jnp.int32),
+                *params, jax.random.PRNGKey(0),
+            )
+        )
+
+    @pytest.mark.parametrize("num_steps", [1, 4])
+    def test_decode_steps(self, num_steps):
+        args, kw = _decode_args((), num_steps)
+        found = _assert_filter_is_gated(
+            jax.make_jaxpr(
+                functools.partial(llama.decode_steps, **kw),
+                static_argnums=(1,),
+            )(*args)
+        )
+        # the burst's keys are split where they were: outside the gate
+        splits = [w for eqn, w in found if eqn.primitive.name == "random_split"]
+        assert splits and all(where == () for where in splits)
+        # and the KV pools do not pass through it
+        pool = args[4].shape
+        for eqn, _ in found:
+            if eqn.primitive.name == "cond":
+                assert all(v.aval.shape != pool for v in eqn.invars)
+
+    def test_a_sort_outside_the_gate_is_found(self):
+        def leaky(logits, *rest):
+            floor = jnp.sort(logits, axis=-1)[:, 0]
+            return sample_tokens(logits, *rest) + (floor > 0)
+
+        params = _lane_params((), True)
+        with pytest.raises(AssertionError, match="sort"):
+            _assert_filter_is_gated(
+                jax.make_jaxpr(leaky)(
+                    jnp.zeros((LANES, WIDE)), *params, jax.random.PRNGKey(0)
+                )
+            )
+
+
+DECODE_LANES, DECODE_PAGE = 4, 4
+
+
+def _decode_args(sampled_lanes, num_steps, seed=0):
+    """``llama.decode_steps``' arguments on the tiny model: four lanes, each
+    with a context of a few random tokens' worth of (zero) KV."""
+    cfg = llama.TINY_LLAMA
+    params = _tiny_params()
+    pages_per_seq = 4
+    k_pages, v_pages = llama.init_kv_pages(
+        cfg, DECODE_LANES * pages_per_seq + 1, DECODE_PAGE
+    )
+    block_tables = (
+        np.arange(DECODE_LANES * pages_per_seq).reshape(DECODE_LANES, -1) + 1
+    )
+    temperature = np.zeros((DECODE_LANES,), np.float32)
+    temperature[list(sampled_lanes)] = 1.0
+    args = (
+        params, cfg,
+        jnp.asarray([3, 5, 7, 11], jnp.int32),  # tokens
+        jnp.asarray([2, 3, 4, 5], jnp.int32),  # positions
+        k_pages, v_pages,
+        jnp.asarray(block_tables, jnp.int32),
+        jnp.asarray([3, 4, 5, 6], jnp.int32),  # seq_lens
+        jnp.asarray(temperature),
+        jnp.zeros((DECODE_LANES,), jnp.int32),
+        jnp.ones((DECODE_LANES,), jnp.float32),
+        jax.random.PRNGKey(seed),
+    )
+    return args, dict(page_size=DECODE_PAGE, num_steps=num_steps, interpret=True)
+
+
+@functools.lru_cache(maxsize=1)
+def _tiny_params():
+    return llama.init_params(jax.random.PRNGKey(0), llama.TINY_LLAMA)
+
+
+@pytest.mark.parametrize(
+    "sampled_lanes", [(), (2,), (0, 1, 2, 3)],
+    ids=["all_greedy", "one_sampled", "all_sampled"],
+)
+def test_decode_steps_one_and_four_start_the_same_stream(sampled_lanes):
+    """``decode_steps``' promise at its ``num_steps == 1`` branch: the plain
+    body call consumes the key the scan's first slice would, so for one key
+    the burst of one emits the first token of the burst of four."""
+    for seed in (0, 1, 2):
+        args1, kw1 = _decode_args(sampled_lanes, 1, seed)
+        args4, kw4 = _decode_args(sampled_lanes, 4, seed)
+        one = np.asarray(llama.decode_steps(*args1, **kw1)[0])
+        four = np.asarray(llama.decode_steps(*args4, **kw4)[0])
+        assert one.shape == (DECODE_LANES, 1) and four.shape == (DECODE_LANES, 4)
+        np.testing.assert_array_equal(one[:, 0], four[:, 0])
+    # a sampled lane does sample: over the seeds its stream is not one token
+    if sampled_lanes:
+        lane = sampled_lanes[0]
+        firsts = {
+            int(np.asarray(
+                llama.decode_steps(*a, **k)[0]
+            )[lane, 0])
+            for a, k in (_decode_args(sampled_lanes, 1, s) for s in range(8))
+        }
+        assert len(firsts) > 1
