@@ -3,7 +3,9 @@
     generator (due time) -> gateway: POST /score_completions on the scorer
       -> kvcache.router.BlendedRouter (index score -> routed affinity ->
          least load; no cost model, predictor, auditor or remote arm)
-      -> POST /v1/completions on the picked pod (temperature 0)
+      -> POST /v1/completions on the picked pod (prompt, max_tokens,
+         temperature 0, then the parameters the traffic mix states under
+         ``request``, if any)
 
 With one pod the pick is trivial and its cost is still paid. One asyncio
 loop thread carries every in-flight request of the generator.
@@ -93,7 +95,7 @@ class Gateway:
             status, body = await self._post(
                 f"{self.pods[i].url}/v1/completions",
                 {"prompt": req.prompt, "max_tokens": req.max_tokens,
-                 "temperature": 0.0},
+                 "temperature": 0.0, **(req.params or {})},
             )
             rec["status"], rec["body"] = status, body
             if status != 200:

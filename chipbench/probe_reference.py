@@ -1,19 +1,25 @@
 #!/usr/bin/env python3
 """How far the reference check moves under a change of precision: the runs
-behind the tolerances in ``reference.py``. Not part of a benchmark run.
+behind the tolerances each ``references/<name>.py`` states. Not part of a
+benchmark run.
 
     python3 chipbench/probe_reference.py --config qwen3-30b-a3b --seeds 1,2,3
         [--layers N] [--moe-gmm xla] [--pool int8] [--weights int8]
+        [--order 1,0,2,...]
 
 Builds the configuration's weights from each seed the way a run does (no
-engine, no pool, no server), runs ``reference.check`` on them and prints one
-JSON line per seed. ``--pool int8`` sends prefill and decode through an int8
-scratch pool (``KV_QUANT_HBM=int8``: the XLA prefill, as the engine chooses
-then); ``--weights int8`` gives the system int8 matmul weights and experts
-made from the same seed while the reference reads the bf16 ones (both trees
-are resident, so take fewer ``--layers``); ``--moe-gmm xla`` takes
-``ragged_dot`` instead of the megablox kernel. ``--rehearse`` is the CPU
-rehearsal of the same path at the tiny preset.
+engine, no pool, no server), runs the check of the reference the
+configuration names (``reference.check`` finds ``references/<name>.py``: its
+``forward``, its tolerances, its system side where it has one) on them and
+prints one JSON line per seed. ``--pool int8`` sends prefill and decode
+through an int8 scratch pool (``KV_QUANT_HBM=int8``: the XLA prefill, as the
+engine chooses then); ``--weights int8`` gives the system int8 matmul weights
+and experts made from the same seed while the reference reads the bf16 ones
+(both trees are resident, so take fewer ``--layers``); ``--moe-gmm xla``
+takes ``ragged_dot`` instead of the megablox kernel; ``--order`` gives the
+system the seed's layers in another order or one of them twice (a program
+that is not the model: what the whole-model bounds are there for).
+``--rehearse`` is the CPU rehearsal of the same path at the tiny preset.
 """
 
 from __future__ import annotations
@@ -40,6 +46,7 @@ def main(argv=None) -> int:
     ap.add_argument("--moe-gmm", default=None)
     ap.add_argument("--pool", choices=("int8",), default=None)
     ap.add_argument("--weights", choices=("int8",), default=None)
+    ap.add_argument("--order", default=None)
     ap.add_argument("--rehearse", action="store_true")
     args = ap.parse_args(argv)
     if args.rehearse and "jax" not in sys.modules:
@@ -76,6 +83,9 @@ def main(argv=None) -> int:
         params = truth
         if args.weights:
             params = weights(seed, quantize=args.weights, quantize_experts=True)
+        if args.order:
+            params = {**params, "layers": [
+                params["layers"][int(i)] for i in args.order.split(",")]}
         view = types.SimpleNamespace(
             params=params, model_cfg=cfg,
             page_size=int(config["env"]["BLOCK_SIZE"]), mesh=None,
@@ -88,7 +98,8 @@ def main(argv=None) -> int:
                               interpret=args.rehearse, truth=truth, **small)
         print(json.dumps({"config": args.config, "seed": seed,
                           "layers": cfg.n_layers, "moe_gmm": args.moe_gmm,
-                          "pool": args.pool, "weights": args.weights, **out}),
+                          "pool": args.pool, "weights": args.weights,
+                          "order": args.order, **out}),
               flush=True)
         del truth, params, view
     return 0

@@ -5,12 +5,13 @@
 
 Finds the cell in ``BENCHMARK.json`` and everything that belongs to it by
 name — ``configs/<config>.json``, ``traffic/<traffic>.json``,
-``layer_metrics/<metric>.py`` — and holds no table of them. Starts the
-scorer and the cell's 1 or 4 in-process pods, makes weights on the device
-from ``--seed``, checks the system against the float32 reference, fills the
-cache the traffic shares, warms exactly the cell's shape set, measures for
-``--seconds`` and prints the contract's one last line. Everything else it
-says goes on earlier lines (``[chipbench] ...``) or into ``chipbench/out/``.
+``layer_metrics/<metric>.py``, ``references/<reference>.py`` — and holds no
+table of them. Starts the scorer and the cell's 1 or 4 in-process pods,
+makes weights on the device from ``--seed``, checks the system against the
+float32 reference, fills the cache the traffic shares, warms exactly the
+cell's shape set, measures for ``--seconds`` and prints the contract's one
+last line. Everything else it says goes on earlier lines (``[chipbench]
+...``) or into ``chipbench/out/``.
 
 ``--rehearse`` is the CPU rehearsal for tests only (tiny presets, Pallas
 interpreter, virtual devices): it says ``platform: cpu``, writes no device
@@ -85,7 +86,10 @@ def load_config(name: str, rehearse: bool = False) -> dict:
 
 def model_config(config: dict, rehearse: bool):
     """The program's preset with the configuration's ``replace`` keys; at
-    full size every published width must equal the preset's."""
+    full size every published width must equal the preset's: the keys below
+    and those the configuration lists itself under ``widths`` ({published
+    key: attribute of the preset}; a listed key that either side lacks
+    fails the run)."""
     from llm_d_kv_cache_manager_tpu import models
 
     cfg = dataclasses.replace(
@@ -106,8 +110,15 @@ def model_config(config: dict, rehearse: bool):
                         num_experts_per_tok=cfg.n_experts_per_tok,
                         moe_intermediate_size=cfg.moe_inter,
                         norm_topk_prob=cfg.norm_topk_prob)
-        wrong = {k: (pub.get(k), v) for k, v in want.items()
-                 if pub.get(k) != v}
+        for key, attr in config.get("widths", {}).items():
+            if not hasattr(cfg, attr):
+                raise BenchFailure(
+                    f"widths: {key!r} is checked against {attr!r}, which "
+                    f"the program's preset {config['preset']} does not have"
+                )
+            want[key] = getattr(cfg, attr)
+        wrong = {k: (pub.get(k, "missing"), v) for k, v in want.items()
+                 if k not in pub or pub[k] != v}
         if wrong:
             raise BenchFailure(
                 f"configuration file and program preset disagree: {wrong}"
@@ -224,9 +235,11 @@ def fill_cache(client, gateway, schedule, spec, seed, pods, scorer_url,
     from chipbench.gateway import send_all
 
     n = len(pods)
+    params = traffic.request_params(spec, len(schedule.prefixes), 7)
     for k, round_ in enumerate(traffic.fill_plan(schedule, spec, seed)):
         reqs = [traffic.Request(index=g, due_s=None, group=g, prefix_len=0,
-                                prompt=p, max_tokens=1) for g, p in round_]
+                                prompt=p, max_tokens=1, params=params[g])
+                for g, p in round_]
         records = client.run(
             send_all(gateway, reqs, pods=[g % n for g, _ in round_])
         )
@@ -549,6 +562,11 @@ def main(argv=None) -> int:
                 flags + " --xla_force_host_platform_device_count=4"
             ).strip()
     line = run(args)
+    # each number compared beside its limit (``tol``), last on standard error
+    print("[chipbench] compared: " + json.dumps({
+        "reference": line["reference"], "failed_requests": line["failed"],
+        "failed_requests_limit": 0, "completed": line["attempted"] - line["failed"],
+        "completed_at_least": 1}), file=sys.stderr, flush=True)
     print(json.dumps(line), flush=True)
     return 0
 

@@ -1,6 +1,7 @@
 """The one general traffic generator. A traffic mix is a data file under
 ``chipbench/traffic/`` whose ``kind`` names an arrival process here
-(``open_poisson``, ``closed``); everything else in the file is parameters.
+(``open_poisson``, ``closed``); everything else in the file is parameters,
+``request`` (what every request adds to its body) among them.
 
 Every seed does the same work at the same times: lengths, prefix groups and
 arrival times are drawn from the file's own ``sizes_seed``; the run's
@@ -21,6 +22,8 @@ from typing import Optional
 
 import numpy as np
 
+from chipbench.fleet import BenchFailure
+
 HERE = os.path.dirname(os.path.abspath(__file__))
 KINDS = ("open_poisson", "closed")
 
@@ -33,6 +36,7 @@ class Request:
     prefix_len: int  # shared with the group (0 = nothing shared)
     prompt: str
     max_tokens: int
+    params: Optional[dict] = None  # the mix's ``request``, where it carries it
 
     @property
     def prompt_len(self) -> int:
@@ -75,6 +79,30 @@ def draw_lengths(rng: np.random.Generator, dist: dict, n: int) -> np.ndarray:
     else:
         raise ValueError(f"unknown length distribution {dist['dist']!r}")
     return np.clip(np.floor(x), lo, hi).astype(int)
+
+
+#: keys of the body that belong to the schedule, never to a mix's ``request``
+SCHEDULED_KEYS = frozenset({"prompt", "max_tokens"})
+
+
+def request_params(spec: dict, n: int, stream: int) -> list:
+    """What each of n requests adds to its ``POST /v1/completions`` body: the
+    mix's ``request`` dict (``temperature``, ``top_k``, ``top_p``, whatever
+    the pod's API reads), or None (the body as it is without the key). With
+    ``request_share`` that fraction of the requests carries it, drawn from
+    ``sizes_seed`` on a stream of its own, so every seed sends the same.
+    ``prompt`` and ``max_tokens`` are the schedule's (the warmed shapes and
+    ``out_tokens_per_s`` follow them): a mix that states either is refused."""
+    params = spec.get("request")
+    if not params:
+        return [None] * n
+    taken = sorted(SCHEDULED_KEYS & set(params))
+    if taken:
+        raise BenchFailure(f"traffic: request may not state {taken}: the "
+                           "schedule draws them")
+    rng = np.random.default_rng([int(spec["sizes_seed"]), stream])
+    carries = rng.random(n) < float(spec.get("request_share", 1.0))
+    return [params if c else None for c in carries]
 
 
 def zipf_weights(n: int, s: float) -> np.ndarray:
@@ -135,6 +163,7 @@ def build_schedule(spec: dict, seed: int, seconds: float, *, pods: int,
     else:
         groups = np.full(n, -1)
     prefixes = [ascii_text(text, m) for m in prefix_lens]
+    params = request_params(spec, n, 6)
     requests = []
     for r in range(n):
         g = int(groups[r])
@@ -146,6 +175,7 @@ def build_schedule(spec: dict, seed: int, seconds: float, *, pods: int,
             prefix_len=len(head),
             prompt=head + ascii_text(text, int(unique[r])),
             max_tokens=int(output[r]),
+            params=params[r],
         ))
     return Schedule(kind=kind, rate_rps=rate, callers=callers,
                     prefixes=prefixes, requests=requests)
