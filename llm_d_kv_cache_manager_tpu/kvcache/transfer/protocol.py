@@ -358,6 +358,10 @@ class MigrationPayload:
     #: seconds of request-deadline budget left at freeze; None = none set.
     deadline_remaining_s: Optional[float]
     blocks: list[BlockPayload] = field(default_factory=list)
+    #: block-diffusion requests only (None = the model's defaults; the
+    #: frame then carries neither and is the frame it always was)
+    denoising_steps: Optional[int] = None
+    confidence_threshold: Optional[float] = None
 
     @property
     def wire_bytes(self) -> int:
@@ -387,6 +391,10 @@ def encode_migrate(
         m.deadline_remaining_s,
         [encode_block_row(b) for b in m.blocks],
     ]
+    if (m.denoising_steps, m.confidence_threshold) != (None, None):
+        # optional trailing fields: a block-diffusion request's own
+        # schedule; without them the frame is the one it always was
+        arr.extend([m.denoising_steps, m.confidence_threshold])
     return msgpack.packb(arr, use_bin_type=True)
 
 
@@ -429,6 +437,9 @@ def decode_migrate(
         top_k = int(samp[2])
         top_p = float(samp[3])
         stop_token_ids = tuple(int(t) for t in (samp[4] or ()))
+        steps, threshold = (list(arr[10:12]) + [None, None])[:2]
+        denoising_steps = None if steps is None else int(steps)
+        confidence_threshold = None if threshold is None else float(threshold)
     except (TypeError, ValueError):
         return None
     deadline_remaining_s = arr[8]
@@ -458,6 +469,8 @@ def decode_migrate(
             stop_token_ids=stop_token_ids,
             deadline_remaining_s=deadline_remaining_s,
             blocks=blocks,
+            denoising_steps=denoising_steps,
+            confidence_threshold=confidence_threshold,
         ),
     )
 

@@ -122,6 +122,11 @@ def config_from_hf(hf_config) -> LlamaConfig:
         raise NotImplementedError(
             f"{cls_name} is not supported yet (Gemma-1 only)"
         )
+    # SDAR (``model_type: sdar_moe``): Qwen3-MoE's decoder, its weights
+    # named as ``qwen3_moe``'s, generating by diffusion over blocks. The
+    # published config gives neither block length nor mask id: the
+    # family's released generation script's (4, 151669) unless it does.
+    is_sdar = getattr(hf_config, "model_type", "") == "sdar_moe"
     hidden_act = getattr(hf_config, "hidden_activation", None) or getattr(
         hf_config, "hidden_act", "silu"
     )
@@ -140,7 +145,7 @@ def config_from_hf(hf_config) -> LlamaConfig:
         rms_norm_eps=hf_config.rms_norm_eps,
         qkv_bias=getattr(hf_config, "attention_bias", False)
         or hf_config.__class__.__name__.startswith("Qwen2"),
-        qk_norm=hf_config.__class__.__name__.startswith("Qwen3"),
+        qk_norm=hf_config.__class__.__name__.startswith("Qwen3") or is_sdar,
         tie_word_embeddings=getattr(hf_config, "tie_word_embeddings", False),
         n_experts=getattr(hf_config, "num_local_experts", 0)
         or getattr(hf_config, "num_experts", 0),
@@ -152,6 +157,10 @@ def config_from_hf(hf_config) -> LlamaConfig:
         hidden_act=hidden_act,
         norm_offset=1.0 if is_gemma else 0.0,
         scale_embeddings=is_gemma,
+        block_length=getattr(hf_config, "block_length", 4) if is_sdar else 0,
+        mask_token_id=(
+            getattr(hf_config, "mask_token_id", 151_669) if is_sdar else 0
+        ),
     )
     cfg.act_fn  # raises ValueError for unsupported activations
     # Qwen3-MoE variants with partially-dense layers change the layer

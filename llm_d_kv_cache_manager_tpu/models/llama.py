@@ -29,6 +29,7 @@ embeddings, decoupled head_dim).
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import os
 from dataclasses import dataclass
@@ -46,7 +47,12 @@ from ..ops import (
     rope_frequencies,
 )
 from ..ops.rope import RopeScalingConfig
-from ..ops.sampling import sample_tokens, spec_sample
+from ..ops.sampling import (
+    block_candidates,
+    block_transfer,
+    sample_tokens,
+    spec_sample,
+)
 from .quant import QuantizedTensor, materialize as _w
 
 
@@ -211,7 +217,7 @@ def _check_right_padded_mask(ok) -> None:
 
 def _flash_prefill_tp(
     q, k, v, k_pages_l, v_pages_l, block_tables, ctx_lens, n_valid, *,
-    interpret, mesh,
+    interpret, mesh, block_length=0,
 ):
     """Pallas flash prefill, head-parallel over the ``tp`` mesh axis.
 
@@ -223,7 +229,9 @@ def _flash_prefill_tp(
     """
     from ..ops.flash_prefill import flash_prefill_paged
 
-    kernel = functools.partial(flash_prefill_paged, interpret=interpret)
+    kernel = functools.partial(
+        flash_prefill_paged, interpret=interpret, block_length=block_length
+    )
     if mesh is None:
         return kernel(
             q, k, v, k_pages_l, v_pages_l, block_tables, ctx_lens, n_valid
@@ -286,6 +294,15 @@ class LlamaConfig:
     hidden_act: str = "silu"
     norm_offset: float = 0.0
     scale_embeddings: bool = False
+    # Generation by diffusion over blocks (SDAR): 0 = autoregressive. With
+    # B > 0 attention is causal between blocks of B absolute positions and
+    # full inside one (position i sees j iff j // B <= i // B; B = 1 is the
+    # causal model), a block of ``mask_token_id`` rows is denoised against
+    # the paged context (``denoise_steps``) and its keys and values are
+    # stored by the forward that finds no row masked. The serving engine
+    # picks that path from this field alone.
+    block_length: int = 0
+    mask_token_id: int = 0
     dtype: Any = jnp.bfloat16
 
     @property
@@ -456,6 +473,20 @@ TINY_QWEN3_MOE = LlamaConfig(
     moe_intermediate_size=48,
     norm_topk_prob=True,
     dtype=jnp.float32,
+)
+
+#: JetLM/SDAR-30B-A3B-Chat (``model_type: sdar_moe``): Qwen3-30B-A3B's
+#: decoder, every width the same, generating by diffusion over blocks. The
+#: published config gives neither block length nor schedule; 4 and mask id
+#: 151669 are the family's released ``generate.py``'s.
+SDAR_30B_A3B = dataclasses.replace(
+    QWEN3_30B_A3B, block_length=4, mask_token_id=151_669
+)
+
+#: Tiny SDAR-MoE-shaped config (block diffusion over qk-norm + MoE) for
+#: tests / CPU dry-runs; the mask id is the vocabulary's last.
+TINY_SDAR_MOE = dataclasses.replace(
+    TINY_QWEN3_MOE, block_length=4, mask_token_id=255
 )
 
 #: Tiny MoE config (Mixtral-shaped) for tests / CPU dry-runs.
@@ -685,7 +716,8 @@ def _grouped_dot(cfg: LlamaConfig, row_group_ids: jnp.ndarray, interpret: bool):
 
 
 def _moe_mlp_routed(
-    layer: Params, cfg: LlamaConfig, x: jnp.ndarray, interpret: bool = False
+    layer: Params, cfg: LlamaConfig, x: jnp.ndarray, interpret: bool = False,
+    touched: Optional[list] = None,
 ) -> jnp.ndarray:
     """Routed sparse-MoE SwiGLU FFN: grouped top-k gather dispatch.
 
@@ -701,6 +733,10 @@ def _moe_mlp_routed(
     each expert's tokens form one contiguous segment, run the three FFN
     matmuls as ragged (grouped) dots over those segments, then weight by
     the gate values and scatter-add back per token.
+
+    ``touched``: a list the caller hands in while tracing, to which this
+    layer's number of distinct experts chosen is appended (the experts
+    whose weights the grouped dots read); None adds no operation.
     """
     b, s, d = x.shape
     n = b * s
@@ -714,6 +750,8 @@ def _moe_mlp_routed(
     src_tok = token_ids[order]  # [n*k] token each sorted row came from
     xs = xf[src_tok]  # [n*k, d] gathered inputs, expert-contiguous
     group_sizes = jnp.bincount(expert_ids, length=cfg.n_experts)
+    if touched is not None:
+        touched.append(jnp.sum(group_sizes > 0, dtype=jnp.int32))
     gdot = _grouped_dot(cfg, expert_ids[order], interpret)
 
     gate = cfg.act_fn(gdot(xs, layer["w_gate"], group_sizes).astype(jnp.float32))
@@ -810,7 +848,7 @@ def _moe_mlp_routed_ep(
 
 def _moe_mlp(
     layer: Params, cfg: LlamaConfig, x: jnp.ndarray, mesh=None,
-    interpret: bool = False,
+    interpret: bool = False, touched: Optional[list] = None,
 ) -> jnp.ndarray:
     if cfg.moe_dispatch not in ("routed", "dense"):
         raise ValueError(f"unknown moe_dispatch {cfg.moe_dispatch!r}")
@@ -832,16 +870,18 @@ def _moe_mlp(
         # GSPMD partitions it along the f dimension.
         return _moe_mlp_dense(layer, cfg, x)
     if cfg.moe_dispatch == "routed":
-        return _moe_mlp_routed(layer, cfg, x, interpret)
+        return _moe_mlp_routed(layer, cfg, x, interpret, touched)
     return _moe_mlp_dense(layer, cfg, x)
 
 
 def _mlp(
     layer: Params, cfg: LlamaConfig, x: jnp.ndarray, mesh=None,
-    interpret: bool = False,
+    interpret: bool = False, touched: Optional[list] = None,
 ) -> jnp.ndarray:
     if cfg.n_experts:
-        return _moe_mlp(layer, cfg, x, mesh=mesh, interpret=interpret)
+        return _moe_mlp(
+            layer, cfg, x, mesh=mesh, interpret=interpret, touched=touched
+        )
     gate = cfg.act_fn((x @ _w(layer["w_gate"], x.dtype)).astype(jnp.float32))
     up = (x @ _w(layer["w_up"], x.dtype)).astype(jnp.float32)
     return ((gate * up).astype(x.dtype)) @ _w(layer["w_down"], x.dtype)
@@ -974,6 +1014,7 @@ def _prefill_body(
     interpret: bool,
     k_scales=None,  # [L, P, n_kv] f32 when KV_QUANT_HBM=int8
     v_scales=None,
+    experts_touched: Optional[list] = None,  # see ``_moe_mlp_routed``
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, Any, Any]:
     """Traced prefill layer loop shared by ``prefill`` and the fused
     speculative-decode scan (``spec_decode_steps``): chunk forward with
@@ -983,8 +1024,14 @@ def _prefill_body(
     untouched) unless the pools are int8 (``KV_QUANT_HBM``), in which
     case the scatter quantizes at write time and the paged-context gather
     dequantizes chunk-locally — the engine restricts the quantized path
-    to the ``xla`` single-shard prefill."""
+    to the ``xla`` single-shard prefill.
+
+    ``cfg.block_length`` > 1 makes the chunk block-causal (full inside a
+    block of that many absolute positions). Callers start the chunk on a
+    block boundary: the Pallas kernel reads blocks off chunk indices."""
     sp = mesh.shape.get("sp", 1) if mesh is not None else 1
+    if cfg.block_length > 1 and sp > 1:
+        raise ValueError("block_length > 1: the sp ring masks causally")
     inv_freq = jnp.asarray(rope_frequencies(cfg.hd, cfg.rope_theta, cfg.rope_scaling))
     h = _embed(params, cfg, tokens)  # [b, s, d]
     if attn_impl == "pallas":
@@ -1013,6 +1060,7 @@ def _prefill_body(
             attn = _flash_prefill_tp(
                 q, k, v, k_pages[li], v_pages[li], block_tables, ctx_lens,
                 n_valid, interpret=interpret, mesh=mesh,
+                block_length=cfg.block_length,
             )
         else:
             attn = prefill_with_paged_context(
@@ -1020,12 +1068,16 @@ def _prefill_body(
                 positions=positions, valid=valid,
                 k_scales=None if k_scales is None else k_scales[li],
                 v_scales=None if v_scales is None else v_scales[li],
+                block_length=cfg.block_length,
             )
         b, s, _, _ = attn.shape
         h = h + attn.reshape(b, s, -1) @ _w(layer["wo"], h.dtype)
 
         x = rms_norm(h, layer["mlp_norm"], cfg.rms_norm_eps, cfg.norm_offset)
-        h = h + _mlp(layer, cfg, x, mesh=mesh, interpret=interpret)
+        h = h + _mlp(
+            layer, cfg, x, mesh=mesh, interpret=interpret,
+            touched=experts_touched,
+        )
 
         fresh_k.append(k)
         fresh_v.append(v)
@@ -1551,5 +1603,154 @@ def spec_decode_steps(
     packed = jnp.concatenate(
         [emit, emit_len[..., None], prop_len[..., None], acc[..., None]],
         axis=-1,
+    )
+    return packed, k_pages, v_pages
+
+
+def _denoise_body(
+    params: Params,
+    cfg: LlamaConfig,
+    tokens: jnp.ndarray,  # [b, B] int32 — each lane's block, masks included
+    seq_lens: jnp.ndarray,  # [b] int32 — final context (a multiple of B)
+    active: jnp.ndarray,  # [b] bool — False: a padded lane, writes nothing
+    block_tables: jnp.ndarray,  # [b, pages] int32, covering the block too
+    k_pages: jnp.ndarray,
+    v_pages: jnp.ndarray,
+    page_size: int,
+    mesh,
+    attn_impl: str,
+    interpret: bool,
+    experts_touched: Optional[list] = None,
+) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """One forward of a block of ``B = cfg.block_length`` rows a lane at
+    positions ``seq_lens .. seq_lens + B - 1`` against the paged context
+    (``_prefill_body``, the forward ``spec_decode_steps``' verify makes):
+    every row sees the whole context and the whole block. Returns (logits
+    at every row [b, B, vocab] f32, k_pages, v_pages) with the block's keys
+    and values written at its positions. Those of a block that still has
+    masked rows lie beyond ``seq_lens`` in pages the sequence owns and are
+    bookkeeping: nothing reads them, and the forward that finds no row
+    masked writes the final ones over them."""
+    b, width = tokens.shape
+    positions = seq_lens[:, None] + jnp.arange(width)[None, :]
+    valid = jnp.broadcast_to(active[:, None], (b, width))
+    page_ids = jnp.take_along_axis(
+        block_tables,
+        jnp.clip(positions // page_size, 0, block_tables.shape[1] - 1),
+        axis=1,
+    )
+    h, k_pages, v_pages, _, _ = _prefill_body(
+        params, cfg, tokens, positions, valid, k_pages, v_pages,
+        page_ids, positions % page_size, block_tables, seq_lens, mesh,
+        attn_impl, interpret, experts_touched=experts_touched,
+    )
+    return _logits(params, cfg, h), k_pages, v_pages
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("cfg", "page_size", "mesh", "attn_impl", "interpret"),
+    donate_argnames=("k_pages", "v_pages"),
+)
+def denoise_step(
+    params: Params,
+    cfg: LlamaConfig,
+    tokens: jnp.ndarray,  # [b, block_length] int32
+    seq_lens: jnp.ndarray,  # [b] int32 — final context length
+    k_pages: jnp.ndarray,
+    v_pages: jnp.ndarray,
+    block_tables: jnp.ndarray,  # [b, pages] int32
+    *,
+    page_size: int,
+    mesh=None,
+    attn_impl: str = "xla",
+    interpret: bool = False,
+) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """The single denoising / committing forward as a logits API (what
+    ``decode_step`` is to ``decode_steps``): (logits [b, block_length,
+    vocab], k_pages, v_pages). Confidence and transfer stay with the
+    caller; ``denoise_steps`` is the served program over the same body."""
+    return _denoise_body(
+        params, cfg, tokens, seq_lens, jnp.ones(tokens.shape[:1], bool),
+        block_tables, k_pages, v_pages, page_size, mesh, attn_impl,
+        interpret,
+    )
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=(
+        "cfg", "page_size", "table_w", "mesh", "attn_impl", "interpret",
+    ),
+    donate_argnames=("k_pages", "v_pages"),
+)
+def denoise_steps(
+    params: Params,
+    cfg: LlamaConfig,
+    packed_i32: jnp.ndarray,  # [b, 2 * B + table_w + 5] int32 — see below
+    fparams: jnp.ndarray,  # [b, 3] f32 — (threshold, temperature, top_p)
+    k_pages: jnp.ndarray,
+    v_pages: jnp.ndarray,
+    rng_key: jax.Array,
+    *,
+    page_size: int,
+    table_w: int,  # block-table width inside packed_i32
+    mesh=None,
+    attn_impl: str = "xla",
+    interpret: bool = False,
+) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """One step of generation by diffusion over blocks for every lane: the
+    served program (``B = cfg.block_length``).
+
+    Per lane: one forward of its block against the paged context
+    (``_denoise_body``), then, on the device, each row's candidate token
+    and its probability under the row's own softmax (``block_candidates``)
+    and the rows this step fixes (``block_transfer``: every masked row over
+    the lane's threshold if they are at least the number the schedule owes,
+    else that many of the most confident). A fixed row is never masked
+    again. A lane whose block has no masked row fixes nothing: its forward
+    is the COMMITTING one, whose keys and values are final; the host then
+    opens the lane's next block.
+
+    Everything a request can set is data, so one program serves every mix
+    of steps, thresholds and sampling per (lanes, table width). Packed as
+    ``spec_decode_steps`` packs: ``packed_i32 = [tokens (B) | masked (B) |
+    block_tables | seq_len, step, denoising_steps, top_k, active]``, one
+    f32 ``fparams``. Returns ``(packed [b, 2 * B + 1] int32, k_pages,
+    v_pages)``: the block's tokens after the step, its rows still masked,
+    and in the last column the distinct experts this forward's rows chose,
+    summed over the layers (the same number in every lane; 0 where the FFN
+    is not the routed one) — one array, one fetch."""
+    width = cfg.block_length
+    tokens = packed_i32[:, :width]
+    masked = packed_i32[:, width : 2 * width] != 0
+    tail = 2 * width + table_w
+    block_tables = packed_i32[:, 2 * width : tail]
+    seq_lens = packed_i32[:, tail]
+    step = packed_i32[:, tail + 1]
+    steps = packed_i32[:, tail + 2]
+    top_k = packed_i32[:, tail + 3]
+    active = packed_i32[:, tail + 4] != 0
+    threshold, temperature, top_p = fparams[:, 0], fparams[:, 1], fparams[:, 2]
+
+    touched = []
+    logits, k_pages, v_pages = _denoise_body(
+        params, cfg, tokens, seq_lens, active, block_tables, k_pages,
+        v_pages, page_size, mesh, attn_impl, interpret,
+        experts_touched=touched,
+    )
+    candidate, prob = block_candidates(
+        logits, temperature, top_k, top_p, rng_key
+    )
+    fix = block_transfer(prob, masked, step, steps, threshold)
+    fix = fix & active[:, None]
+    n_touched = sum(touched, jnp.zeros((), jnp.int32))
+    packed = jnp.concatenate(
+        [
+            jnp.where(fix, candidate, tokens),
+            (masked & ~fix).astype(jnp.int32),
+            jnp.broadcast_to(n_touched, (tokens.shape[0], 1)),
+        ],
+        axis=1,
     )
     return packed, k_pages, v_pages

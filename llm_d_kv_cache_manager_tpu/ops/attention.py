@@ -78,6 +78,7 @@ def _flash_over_keys(
     block: int,
     return_accumulators: bool = False,
     init_state=None,
+    block_length: int = 0,
 ) -> jnp.ndarray:
     """Online-softmax (flash) attention over a virtual key sequence, scanned
     in key blocks so the [s, T] score matrix is never materialized — the
@@ -89,9 +90,18 @@ def _flash_over_keys(
     the scan from prior accumulators — together they let a caller chain
     exact partial attentions over disjoint key ranges (the ring-attention
     body scans each rotating payload this way, one blocked flash pass per
-    ring step)."""
+    ring step).
+
+    ``block_length`` > 1 (static) widens the visibility from causal to
+    block-causal: a query sees every key up to the end of its own block of
+    ``block_length`` absolute positions (``models/llama.py``: generation
+    by diffusion over blocks). 0 and 1 are the causal program, op for
+    op."""
     b, s, n_kv, group, d = qf.shape
     T = k_all.shape[2]
+    if block_length > 1:
+        # the last position of each query's block: k_pos <= that
+        q_pos = (q_pos // block_length + 1) * block_length - 1
     # Short key sequences (cache-cold short prompts) shrink the block to a
     # lane-aligned size instead of padding up to a full block of masked work.
     block = min(block, -(-T // 128) * 128)
@@ -157,6 +167,7 @@ def prefill_with_paged_context(
     scale: Optional[float] = None,
     k_scales: Optional[jnp.ndarray] = None,  # [total_pages, n_kv] f32
     v_scales: Optional[jnp.ndarray] = None,  # (KV_QUANT_HBM: int8 pools)
+    block_length: int = 0,  # > 1: full inside a block, causal between
 ) -> jnp.ndarray:
     """Chunked prefill attending to prefix-cached pages *and* causally within
     the fresh chunk.
@@ -166,6 +177,10 @@ def prefill_with_paged_context(
     computed them — RoPE is absolute so they are position-correct), and the
     request only prefills its suffix. Context tokens all precede the chunk,
     so cross-attention to them needs only the ctx_len mask, not a causal one.
+
+    With ``block_length`` > 1 the chunk is causal between blocks of that
+    many absolute positions and full inside one (position ``i`` sees ``j``
+    iff ``j // B <= i // B``); 0 and 1 are the causal program.
 
     One online softmax over the virtual key sequence [context ++ chunk],
     flash-scanned in ``FLASH_KEY_BLOCK``-sized key blocks (memory stays
@@ -214,6 +229,6 @@ def prefill_with_paged_context(
 
     out = _flash_over_keys(
         qf, k_all, v_all, k_valid, k_pos, positions.astype(jnp.int32),
-        scale, FLASH_KEY_BLOCK,
+        scale, FLASH_KEY_BLOCK, block_length=block_length,
     )
     return out.reshape(b, s, n_q, d).astype(q.dtype)

@@ -26,7 +26,14 @@ Contract (what the serving engine guarantees):
 - chunk queries occupy CONSECUTIVE positions (`positions[b, i] = start + i`)
   so in-chunk causality is index order;
 - ``valid`` is a right-padding mask (True prefix), reduced to a per-seq
-  count; fully-padded query rows produce zeros.
+  count; fully-padded query rows produce zeros;
+- with a static ``block_length`` B > 1 (generation by diffusion over
+  blocks, ``models/llama.py``) the chunk is causal between blocks of B
+  positions and full inside one, and the chunk STARTS on a block boundary
+  (``positions[b, 0] % B == 0``), so a block is B consecutive chunk
+  indices: key ``k`` is visible to query ``q`` when
+  ``k < (q // B + 1) * B``. That kernel is named ``block_attention`` in
+  the trace. B of 0 or 1 is the causal program, op for op.
 
 `prefill_with_paged_context` (attention.py) is the numerics oracle; parity
 is tested across GQA/MHA/MQA in interpret mode and, compiled, on the chip
@@ -61,6 +68,14 @@ QUERY_BLOCK = 256
 MAX_SCORE_ROWS = 1024
 
 
+def _visible_through(q_idx, block_length: int):
+    """The last chunk index a query at chunk index ``q_idx`` sees: itself
+    (causal), or the end of its block of ``block_length`` indices."""
+    if block_length > 1:
+        return (q_idx // block_length + 1) * block_length - 1
+    return q_idx
+
+
 def _flash_prefill_kernel(
     # scalar prefetch
     ctx_lens_ref,  # [batch] int32
@@ -83,6 +98,7 @@ def _flash_prefill_kernel(
     group: int,
     n_ctx_blocks: int,
     scale: float,
+    block_length: int = 0,
 ):
     b = pl.program_id(0)
     qb = pl.program_id(2)
@@ -152,9 +168,10 @@ def _flash_prefill_kernel(
             flash_update(jnp.where(mask, scores, _NEG_INF), mask, v)
 
     # ---- chunk phase: causal within the chunk (consecutive positions →
-    # index order), bounded by the per-sequence valid count.
+    # index order; through the end of the query's block when block_length
+    # > 1), bounded by the per-sequence valid count.
     cks = ks - n_ctx_blocks
-    q_end = qb * bq + bq - 1
+    q_end = _visible_through(qb * bq + bq - 1, block_length)
 
     @pl.when(
         jnp.logical_and(
@@ -175,7 +192,8 @@ def _flash_prefill_kernel(
         k_idx = cks * bk_chunk + jax.lax.broadcasted_iota(
             jnp.int32, scores.shape, 1
         )
-        q_pos = qb * bq + q_idx  # [rows, 1], broadcasts over lanes
+        # [rows, 1], broadcasts over lanes
+        q_pos = _visible_through(qb * bq + q_idx, block_length)
         mask = (k_idx <= q_pos) & (k_idx < n_valid) & (q_idx < n_valid - qb * bq)
         flash_update(jnp.where(mask, scores, _NEG_INF), mask, v)
 
@@ -214,6 +232,7 @@ def _flash_prefill_kernel_dma(
     n_ctx_blocks: int,
     scale: float,
     page_size: int,
+    block_length: int = 0,
 ):
     """Direct-paged-DMA variant: context K/V pages are copied from the
     HBM pool into double-buffered VMEM by in-kernel ``make_async_copy``
@@ -333,7 +352,7 @@ def _flash_prefill_kernel_dma(
             flash_update(jnp.where(mask, scores, _NEG_INF), mask, v)
 
     cks = ks - n_ctx_blocks
-    q_end = qb * bq + bq - 1
+    q_end = _visible_through(qb * bq + bq - 1, block_length)
 
     @pl.when(
         jnp.logical_and(
@@ -354,7 +373,7 @@ def _flash_prefill_kernel_dma(
         k_idx = cks * bk_chunk + jax.lax.broadcasted_iota(
             jnp.int32, scores.shape, 1
         )
-        q_pos = qb * bq + q_idx
+        q_pos = _visible_through(qb * bq + q_idx, block_length)
         mask = (k_idx <= q_pos) & (k_idx < n_valid) & (q_idx < n_valid - qb * bq)
         flash_update(jnp.where(mask, scores, _NEG_INF), mask, v)
 
@@ -370,9 +389,19 @@ def _round_up(x: int, m: int) -> int:
     return -(-x // m) * m
 
 
+def _kernel_name(block_length: int):
+    """The kernel's name in the trace: a query block with a non-causal tail
+    is ``block_attention``; the causal kernel keeps the name it has (that
+    of its function)."""
+    return "block_attention" if block_length > 1 else None
+
+
 @functools.partial(
     jax.jit,
-    static_argnames=("scale", "interpret", "q_block", "key_block", "ctx_mode"),
+    static_argnames=(
+        "scale", "interpret", "q_block", "key_block", "ctx_mode",
+        "block_length",
+    ),
 )
 def flash_prefill_paged(
     q: jnp.ndarray,  # [batch, seq, n_heads, head_dim] — fresh chunk
@@ -389,12 +418,15 @@ def flash_prefill_paged(
     q_block: int = QUERY_BLOCK,
     key_block: int = KEY_BLOCK,
     ctx_mode: str = "gather",
+    block_length: int = 0,
 ) -> jnp.ndarray:
     """Pallas flash prefill over [paged context ++ fresh chunk].
 
     Drop-in for `prefill_with_paged_context` under the engine's contract
     (consecutive chunk positions, right-padding); `n_valid` replaces the
     boolean `valid` mask. Returns [batch, seq, n_heads, head_dim].
+    ``block_length`` > 1: block-causal chunk (module docstring; the chunk
+    starts on a block boundary).
 
     ``ctx_mode`` picks how context K/V reach the kernel:
 
@@ -454,6 +486,7 @@ def flash_prefill_paged(
             q, k, v, k_pages, v_pages, block_tables, ctx_lens, n_valid,
             scale=scale, interpret=interpret, q_block=q_block,
             bk_ctx=bk_ctx, n_ctx_blocks=n_ctx_blocks, key_block=key_block,
+            block_length=block_length,
         )
 
     # Gather the cached context once (page-major pool → per-seq contiguous)
@@ -510,7 +543,7 @@ def flash_prefill_paged(
     def chunk_index(b_, h, qb, ks, cl, nv):
         cks = jnp.maximum(ks - n_ctx_blocks, 0)
         # causal frontier: blocks past this q-block's last row are clamped
-        causal_last = (qb * bq + bq - 1) // bk_chunk
+        causal_last = _visible_through(qb * bq + bq - 1, block_length) // bk_chunk
         needed = jnp.maximum(-(-nv[b_] // bk_chunk), 1)
         return (b_, h, jnp.minimum(jnp.minimum(cks, causal_last), needed - 1), 0)
 
@@ -540,12 +573,14 @@ def flash_prefill_paged(
         group=group,
         n_ctx_blocks=n_ctx_blocks,
         scale=scale,
+        block_length=block_length,
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, n_kv, s_padq, group, d), q.dtype),
         interpret=interpret,
+        name=_kernel_name(block_length),
     )(ctx_lens, n_valid, qp, ctx_k, ctx_v, kp, vp)
     # [b, n_kv, s_pad, g, d] -> [b, s, n_q, d]
     return jnp.moveaxis(out, 1, 2)[:, :s].reshape(b, s, n_q, d)
@@ -554,6 +589,7 @@ def flash_prefill_paged(
 def _flash_prefill_dma(
     q, k, v, k_pages, v_pages, block_tables, ctx_lens, n_valid,
     *, scale, interpret, q_block, bk_ctx, n_ctx_blocks, key_block,
+    block_length=0,
 ):
     """Direct-paged-DMA dispatch path of ``flash_prefill_paged``: the
     FULL pools enter the kernel in HBM (ANY memory space) and page tiles
@@ -590,7 +626,7 @@ def _flash_prefill_dma(
 
     def chunk_index(b_, h, qb, ks, cl, nv, bt):
         cks = jnp.maximum(ks - n_ctx_blocks, 0)
-        causal_last = (qb * bq + bq - 1) // bk_chunk
+        causal_last = _visible_through(qb * bq + bq - 1, block_length) // bk_chunk
         needed = jnp.maximum(-(-nv[b_] // bk_chunk), 1)
         return (b_, h, jnp.minimum(jnp.minimum(cks, causal_last), needed - 1), 0)
 
@@ -625,12 +661,14 @@ def _flash_prefill_dma(
         n_ctx_blocks=n_ctx_blocks,
         scale=scale,
         page_size=page_size,
+        block_length=block_length,
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, n_kv, s_padq, group, d), q.dtype),
         interpret=interpret,
+        name=_kernel_name(block_length),
     )(
         ctx_lens.astype(jnp.int32),
         n_valid.astype(jnp.int32),

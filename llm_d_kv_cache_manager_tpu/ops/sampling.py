@@ -21,6 +21,13 @@ program per shape either way, no static flag; results are bit-identical
 to the ungated functions for the same key. ``Engine.step_stats
 ["decode_sampled_dispatches"]`` counts the decode dispatches that take the
 sampled branch.
+
+Generation by diffusion over blocks (``llama.denoise_steps``) asks two
+things of every row of a block instead of one token of a lane:
+``block_candidates`` gives the row's candidate token and the probability
+the row's own softmax gives it (behind the same gate: an all-greedy
+dispatch computes ``argmax`` and ``exp(max - logsumexp)``, no sort), and
+``block_transfer`` says which masked rows a step fixes.
 """
 
 from __future__ import annotations
@@ -169,3 +176,75 @@ def spec_sample(
         jnp.any(temperature > 0), _spec_filtered, _spec_greedy,
         logits, drafts, temperature, top_k, top_p, rng_key,
     )
+
+
+def _block_greedy(logits, temperature, top_k, top_p, rng_key):
+    """The branch of a dispatch in which no lane samples: the argmax of
+    each row and its probability under the row's softmax, with no sort."""
+    top = jnp.max(logits, axis=-1)
+    lse = jax.scipy.special.logsumexp(logits, axis=-1)
+    return jnp.argmax(logits, axis=-1).astype(jnp.int32), jnp.exp(top - lse)
+
+
+def _block_filtered(logits, temperature, top_k, top_p, rng_key):
+    """The sampled branch: every row filtered; a sampled lane's rows draw
+    from their filtered distribution and read the draw's probability under
+    it, greedy lanes' rows keep ``_block_greedy``'s."""
+    b, s, vocab = logits.shape
+    flat = logits.reshape(b * s, vocab)
+    rep = lambda x: jnp.repeat(x, s)
+    masked = _filtered_logits(flat, rep(temperature), rep(top_k), rep(top_p))
+    drawn = jax.random.categorical(rng_key, masked, axis=-1).astype(jnp.int32)
+    logp = jax.nn.log_softmax(masked, axis=-1)
+    p_drawn = jnp.exp(jnp.take_along_axis(logp, drawn[:, None], axis=-1)[:, 0])
+    greedy, p_greedy = _block_greedy(logits, temperature, top_k, top_p, rng_key)
+    sampled = (temperature > 0)[:, None]
+    return (
+        jnp.where(sampled, drawn.reshape(b, s), greedy),
+        jnp.where(sampled, p_drawn.reshape(b, s), p_greedy),
+    )
+
+
+@jax.jit
+def block_candidates(
+    logits: jnp.ndarray,  # [batch, rows, vocab] f32 — a block's logits
+    temperature: jnp.ndarray,  # [batch] f32; 0 = greedy
+    top_k: jnp.ndarray,  # [batch] int32; 0 = disabled
+    top_p: jnp.ndarray,  # [batch] f32; 1 = disabled
+    rng_key: jax.Array,
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Per row of a block: (candidate token [batch, rows] int32, its
+    probability under the softmax AT THAT ROW [batch, rows] f32). No
+    shift: the logits at a masked position are that position's token.
+    Greedy lanes: the argmax and ``exp(max - logsumexp)``; ``temperature
+    > 0`` lanes: a draw after temperature/top-k/top-p and its probability
+    under that filtered distribution. Gated like ``sample_tokens``."""
+    return jax.lax.cond(
+        jnp.any(temperature > 0), _block_filtered, _block_greedy,
+        logits, temperature, top_k, top_p, rng_key,
+    )
+
+
+def block_transfer(
+    prob: jnp.ndarray,  # [batch, rows] f32 — ``block_candidates``' second
+    masked: jnp.ndarray,  # [batch, rows] bool — rows still to fix
+    step: jnp.ndarray,  # [batch] int32 — denoising steps this block has had
+    steps: jnp.ndarray,  # [batch] int32 — denoising steps a block gets
+    threshold: jnp.ndarray,  # [batch] f32 — confidence threshold
+) -> jnp.ndarray:
+    """Which masked rows this step fixes (``low_confidence_dynamic``):
+    with ``n = rows // steps`` (+1 for the first ``rows % steps`` steps)
+    rows owed, every masked row whose probability clears ``threshold`` if
+    there are at least ``n`` of them, else the ``n`` masked rows of highest
+    probability (all of them where fewer are left; ties to the lower
+    row). Never a row that is not masked. [batch, rows] bool."""
+    rows = masked.shape[1]
+    steps = jnp.clip(steps, 1, rows)
+    owed = rows // steps + (step < rows % steps).astype(jnp.int32)  # [b]
+    conf = jnp.where(masked, prob, -jnp.inf)
+    high = masked & (prob > threshold[:, None])
+    order = jnp.argsort(-conf, axis=1, stable=True)
+    rank = jnp.argsort(order, axis=1)  # 0 = the most confident row
+    top = masked & (rank < owed[:, None])
+    enough = jnp.sum(high, axis=1) >= owed
+    return jnp.where(enough[:, None], high, top)

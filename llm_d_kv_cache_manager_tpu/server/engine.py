@@ -11,6 +11,12 @@ iteration (Sarathi-style stall-free ingest) — then publishes
 ``BlockStored``/``BlockRemoved`` events so the routing indexer tracks this
 replica's cache (SURVEY §3.2 write path).
 
+A model whose configuration has ``block_length`` > 0 generates by diffusion
+over blocks, and the engine takes that path from the configuration alone:
+the prefill commits the whole blocks of the prompt and samples nothing, a
+running lane holds a block in progress, and a decode dispatch
+(``_run_decode_block``) advances each lane by 0..``block_length`` tokens.
+
 XLA discipline: all jitted entry points see bucketed static shapes
 (prefill length rounded up to a bucket, decode batch padded to a fixed
 lane count), so steady-state serving replays cached executables.
@@ -35,7 +41,13 @@ from ..utils import get_logger
 from .block_manager import AllocationError, BlockManager, BlockManagerConfig
 from ..ops.sampling import sample_tokens
 from .scheduler import Scheduler, SchedulerConfig
-from .sequence import SamplingParams, Sequence, SequenceStatus
+from .sequence import (
+    DEFAULT_CONFIDENCE_THRESHOLD,
+    SamplingParams,
+    Sequence,
+    SequenceStatus,
+    check_block_sampling,
+)
 
 log = get_logger("server.engine")
 
@@ -386,10 +398,15 @@ class Engine:
         # pages of its own row, never into another sequence's pages.
         # Pipelining keeps up to TWO bursts in flight.
         bursts_in_flight = 2 if self._pipeline else 1
+        # ... and, for block diffusion, the block a sequence that stops at
+        # max_model_len had opened past it.
         self.max_pages_per_seq = -(
             -(
                 config.max_model_len
-                + max(config.decode_steps_per_iter * bursts_in_flight - 1, 0)
+                + max(
+                    config.decode_steps_per_iter * bursts_in_flight - 1,
+                    cfg.block_length,
+                )
             )
             // ps
         )
@@ -483,6 +500,38 @@ class Engine:
                 raise ValueError("spec_ngram must be >= 1")
             if config.spec_rounds < 1:
                 raise ValueError("spec_rounds must be >= 1")
+        if cfg.block_length > 0:
+            # Generation by diffusion over blocks: what is not done for it
+            # is refused here by name, not found at the first dispatch.
+            refused = {
+                "sp > 1 (the ring masks causally)": config.sp > 1,
+                "kv_quant_hbm (the block forward reads pages full-width)":
+                    config.kv_quant_hbm is not None,
+                "spec_decode (a block is not one drafted token)":
+                    config.spec_decode != "off",
+                "decode_steps_per_iter > 1, decode_pipeline and "
+                "decode_fused_sampling (one forward of a block a dispatch)":
+                    config.decode_steps_per_iter > 1 or self._pipeline,
+            }
+            for what, on in refused.items():
+                if on:
+                    raise ValueError(
+                        f"block_length={cfg.block_length} (generation by "
+                        f"diffusion over blocks) is incompatible with {what}"
+                    )
+            if ps % cfg.block_length:
+                # a page then holds whole blocks: its keys and values are a
+                # function of the tokens up to its end, which is what the
+                # prefix cache, the index and the scorer take a page for
+                raise ValueError(
+                    f"page_size={ps} must be a multiple of "
+                    f"block_length={cfg.block_length}"
+                )
+            if not 0 <= cfg.mask_token_id < cfg.vocab_size:
+                raise ValueError(
+                    f"mask_token_id={cfg.mask_token_id} outside the "
+                    f"vocabulary of {cfg.vocab_size}"
+                )
         if config.kv_quant_hbm is not None:
             if config.kv_quant_hbm not in quant.KV_QUANT_HBM_MODES:
                 raise ValueError(
@@ -755,7 +804,14 @@ class Engine:
         #: decode dispatch, summed), ``decode_sampled_dispatches`` (those
         #: with a ``temperature > 0`` lane: the sampler's gate runs its
         #: vocabulary filter in these and in no other); prefill dispatches
-        #: are counted, always, in ``prefill_stats``.
+        #: are counted, always, in ``prefill_stats``. Block diffusion
+        #: (``_run_decode_block``, beside those three):
+        #: ``denoise_lane_forwards`` (lanes x dispatches in which the lane
+        #: had a masked row), ``commit_lane_forwards`` (in which it had none:
+        #: the forward that stores the block's keys and values),
+        #: ``block_tokens_fixed`` (rows fixed), ``blocks_final``,
+        #: ``experts_touched`` (distinct experts a dispatch's rows chose,
+        #: summed over the layers and the dispatches: counted on the device).
         #: Off by default: ``obs_step_timing=False`` skips every clock
         #: read and every count, so the legacy step path is untouched.
         self.obs_step_timing = False
@@ -764,6 +820,11 @@ class Engine:
             "decode_dispatches": 0,
             "decode_rows": 0,
             "decode_sampled_dispatches": 0,
+            "denoise_lane_forwards": 0,
+            "commit_lane_forwards": 0,
+            "block_tokens_fixed": 0,
+            "blocks_final": 0,
+            "experts_touched": 0,
             "prefill_s": 0.0,
             "decode_s": 0.0,
             "sample_s": 0.0,
@@ -1711,6 +1772,9 @@ class Engine:
             raise ValueError("prompt exceeds max_model_len")
         # A prompt whose pages can never all fit would wait forever and
         # starve the FCFS queue behind it; reject it up front.
+        check_block_sampling(
+            sampling or SamplingParams(), self.model_cfg.block_length
+        )
         prompt_pages = -(-(len(prompt_tokens) + 1) // self.page_size)
         if prompt_pages > self.config.block_manager.total_pages - 1:
             raise ValueError(
@@ -1726,6 +1790,7 @@ class Engine:
             priority=priority,
             qos_weight=qos_weight,
             submit_time=submit_time,
+            block_length=self.model_cfg.block_length,
         )
         if deadline is not None:
             self._deadlines_used = True
@@ -1999,14 +2064,42 @@ class Engine:
         fresh slice attending over the paged context already resident —
         prefix-cache hits for chunk 0, plus the pages written by chunks
         0..N-1 for later chunks. Only a sequence's FINAL chunk samples a
-        first token and publishes it to the decode lanes."""
+        first token and publishes it to the decode lanes.
+
+        Block diffusion (``block_length`` > 0): the rows are the prompt's
+        whole blocks under the block mask, chunks are cut at block
+        boundaries, and the final chunk samples nothing — the prompt's
+        tail opens the first generated block, in the decode lanes."""
+        diffusion = self.model_cfg.block_length > 0
         with self.phase("prefill_build"):
             ps = self.page_size
             if chunks is None:
                 chunks = [s.prompt_remaining for s in seqs]
+            # Queue→compute boundary for the latency decomposition: one clock
+            # read per batch, stamped only on each sequence's FIRST chunk.
+            t_prefill_start = time.monotonic()
+            for seq in seqs:
+                if seq.prefill_start_time is None:
+                    seq.prefill_start_time = t_prefill_start
+            if not any(chunks):
+                # Block diffusion only: every whole block of these prompts
+                # is cached already (or the prompt is shorter than a block),
+                # so there is nothing to forward.
+                self.scheduler.on_prefill_done(seqs)
+                return
             # Static shapes for jit-cache stability: batch padded to the
-            # configured prefill width, chunk length and context pages bucketed.
-            chunk = _round_up(max(chunks), self.config.prefill_bucket)
+            # configured prefill width, chunk length and context pages
+            # bucketed. The width is bucketed on what is left of the whole
+            # prompt, the tail a final block-diffusion chunk leaves out
+            # included, so a prompt compiles the shapes it would for an
+            # autoregressive model.
+            chunk = _round_up(
+                max(
+                    n + (s.prompt_tail if n >= s.prompt_remaining else 0)
+                    for s, n in zip(seqs, chunks)
+                ),
+                self.config.prefill_bucket,
+            )
             b = self.config.scheduler.max_prefill_batch
 
             tokens = np.zeros((b, chunk), np.int32)
@@ -2021,12 +2114,7 @@ class Engine:
             ctx_bt = np.zeros((b, ctx_pages), np.int32)
             ctx_lens = np.zeros((b,), np.int32)
 
-            # Queue→compute boundary for the latency decomposition: one clock
-            # read per batch, stamped only on each sequence's FIRST chunk.
-            t_prefill_start = time.monotonic()
             for i, (seq, n) in enumerate(zip(seqs, chunks)):
-                if seq.prefill_start_time is None:
-                    seq.prefill_start_time = t_prefill_start
                 start = seq.num_prefilled
                 tokens[i, :n] = seq.prompt_tokens[start : start + n]
                 pos = np.arange(start, start + n)
@@ -2075,7 +2163,14 @@ class Engine:
                     logits, self.k_pages, self.v_pages,
                     self.k_scales, self.v_scales,
                 ) = out
-        first_tokens = self._sample(logits, seqs)  # syncs the dispatch
+        if diffusion:
+            # nothing is sampled from a block-diffusion prefill; the wait
+            # for the dispatch keeps ``prefill_fetch`` what it is
+            with self.phase("prefill_fetch"):
+                jax.block_until_ready(logits)
+            first_tokens = [None] * len(seqs)
+        else:
+            first_tokens = self._sample(logits, seqs)  # syncs the dispatch
         with self.phase("prefill_commit"):
             # Online prefill-rate sample for the recompute-vs-restore model
             # (chunk tokens over the synced dispatch wall time).
@@ -2087,9 +2182,7 @@ class Engine:
             self.prefill_stats["dispatches"] += 1
             now = time.monotonic()
             finals = [
-                seq
-                for seq, n in zip(seqs, chunks)
-                if seq.num_prefilled + n >= len(seq.prompt_tokens)
+                seq for seq, n in zip(seqs, chunks) if n >= seq.prompt_remaining
             ]
             # Admit to running BEFORE appending slots: batchmates must be
             # preemption candidates if page growth exhausts the pool here.
@@ -2099,7 +2192,7 @@ class Engine:
                     continue  # preempted by an earlier seq in this very batch
                 seq.num_prefilled += n
                 seq.num_computed = seq.num_prefilled
-                if seq.prompt_remaining == 0:
+                if seq.prompt_remaining == 0 and not diffusion:
                     # Final chunk: the last-position logits are the first-token
                     # logits of the whole prompt — sample and publish.
                     seq.output_tokens.append(int(tok))
@@ -2119,6 +2212,9 @@ class Engine:
         return min(self.max_pages_per_seq, _round_up(used, bucket))
 
     def _run_decode(self, seqs: list[Sequence]) -> None:
+        if self.model_cfg.block_length > 0:
+            self._run_decode_block(seqs)
+            return
         if self.config.spec_decode == "prompt_lookup":
             # Commit lag: the drain can finish lanes — never reserve for or
             # dispatch a finished sequence (same rule as the fused path).
@@ -2598,6 +2694,139 @@ class Engine:
                     self._append_slot_or_preempt(seq)
                 self.block_manager.register_full_pages(seq)
         return True
+
+    def _run_decode_block(self, seqs: list[Sequence]) -> None:
+        """One step of generation by diffusion over blocks for every lane:
+        one forward of each lane's block in progress against its paged
+        context (``llama.denoise_steps``), confidence and transfer on the
+        device, one packed fetch.
+
+        A lane whose block still has masked rows gets between one and
+        ``block_length`` of them fixed (its ``denoising_steps`` and
+        ``confidence_threshold`` ride the dispatch as data); a lane whose
+        block has none left runs the COMMITTING forward, which alone
+        stores final keys and values: the block's tokens then become
+        output (``num_generated``, ``first_token_time``: the earliest a
+        client could be shown them in order), its pages become registrable
+        and the next dispatch opens the next block. So a dispatch advances
+        a lane by 0..``block_length`` tokens, and lanes sit at different
+        points of their blocks.
+
+        What a denoising forward writes lies beyond ``num_computed`` in
+        pages reserved a whole block ahead, which nothing reads and no
+        event names (registration stops at ``num_computed``: the argument
+        ``_run_decode_spec`` makes for rejected drafts). A lane preempted or
+        aborted in the middle of a block loses the block, not its final
+        tokens: ``fold_for_preemption`` closes it and it starts again from
+        masks."""
+        cfg = self.model_cfg
+        width = cfg.block_length
+        lanes = self.config.decode_batch_size
+        assert len(seqs) <= lanes
+
+        with self.phase("decode_build"):
+            # Pages for the whole block ahead (positions below num_computed +
+            # width); reserving can preempt batchmates, or abort.
+            for seq in seqs:
+                if seq.block_table and not self._should_finish(seq):
+                    self._reserve_slots_or_preempt(
+                        seq, seq.num_computed + width + 1 - seq.num_tokens
+                    )
+            active = [
+                s for s in seqs if s.block_table and not self._should_finish(s)
+            ]
+            if not active:
+                return
+            table_w = self._decode_table_width(active)
+            # ONE int32 and ONE f32 upload, as ``spec_decode_steps`` packs:
+            # [tokens | masked | block_table | seq_len, step, steps, top_k,
+            # active] and (threshold, temperature, top_p).
+            packed_i32 = np.zeros((lanes, 2 * width + table_w + 5), np.int32)
+            fparams = np.zeros((lanes, 3), np.float32)
+            fparams[:, 2] = 1.0  # top_p disabled default for padded lanes
+            tail = 2 * width + table_w
+            for i, seq in enumerate(active):
+                if seq.block_tokens is None:
+                    seq.open_block(cfg.mask_token_id)
+                sp = seq.sampling
+                packed_i32[i, :width] = seq.block_tokens
+                packed_i32[i, width : 2 * width] = seq.block_masked
+                packed_i32[i, 2 * width : 2 * width + len(seq.block_table)] = (
+                    seq.block_table
+                )
+                packed_i32[i, tail:] = (
+                    seq.num_computed, seq.block_step,
+                    sp.denoising_steps or width, sp.top_k, 1,
+                )
+                fparams[i] = (
+                    DEFAULT_CONFIDENCE_THRESHOLD
+                    if sp.confidence_threshold is None
+                    else sp.confidence_threshold,
+                    sp.temperature, sp.top_p,
+                )
+
+        with self.phase("decode_put"):
+            self._flush_page_moves()
+            if (fparams[:, 1] > 0).any():
+                self._rng, key = jax.random.split(self._rng)
+            else:
+                # all greedy: the sampler's gate never reads the key
+                key = self._greedy_key
+            packed_i32_d, fparams_d = self._dev(packed_i32), self._dev(fparams)
+        with self.phase("decode_dispatch"):
+            packed, self.k_pages, self.v_pages = llama.denoise_steps(
+                self.params,
+                cfg,
+                packed_i32_d,
+                fparams_d,
+                self.k_pages,
+                self.v_pages,
+                key,
+                page_size=self.page_size,
+                table_w=table_w,
+                mesh=self.mesh,
+                attn_impl=self.prefill_attn,
+                interpret=self.config.interpret,
+            )
+        self._count_decode_dispatch(len(active), fparams[:, 1])
+        with self.phase("decode_fetch"):
+            packed = np.asarray(packed)  # [lanes, 2 * width + 1]
+        with self.phase("decode_commit"):
+            now = time.monotonic()
+            n_commit = n_fixed = 0
+            for i, seq in enumerate(active):
+                if any(seq.block_masked):
+                    # a denoising forward: some of its masked rows are fixed
+                    still = packed[i, width : 2 * width] != 0
+                    n_fixed += sum(seq.block_masked) - int(still.sum())
+                    seq.block_tokens = packed[i, :width].tolist()
+                    seq.block_masked = still.tolist()
+                    seq.block_step += 1
+                    continue
+                # the committing forward: the block is final
+                n_commit += 1
+                fresh = seq.block_tokens[seq.num_tokens - seq.num_computed :]
+                seq.block_tokens = seq.block_masked = None
+                for tok in fresh:
+                    # the last block's surplus rows (max_new_tokens, a stop
+                    # token inside the block) are dropped
+                    if self._should_finish(seq):
+                        break
+                    seq.output_tokens.append(tok)
+                    seq.num_generated += 1
+                # final keys and values stand for every kept token; a block
+                # cut short ends the sequence, and its page is never full
+                seq.num_computed = seq.num_tokens
+                if seq.first_token_time is None:
+                    seq.first_token_time = now
+                self.block_manager.register_full_pages(seq)
+            if self.obs_step_timing:
+                stats = self.step_stats
+                stats["denoise_lane_forwards"] += len(active) - n_commit
+                stats["commit_lane_forwards"] += n_commit
+                stats["block_tokens_fixed"] += n_fixed
+                stats["blocks_final"] += n_commit
+                stats["experts_touched"] += int(packed[0, 2 * width])
 
     def _drain_inflight(self) -> None:
         if self._inflight is None:

@@ -251,7 +251,11 @@ class Scheduler:
         """Chunk size for a sequence with ``remaining`` fresh prompt tokens
         under ``budget``: the whole remainder when it fits (final chunk),
         else the largest align-multiple that fits (0 = budget exhausted for
-        a non-final chunk — the caller stops packing)."""
+        a non-final chunk — the caller stops packing; or nothing remains: a
+        block-diffusion prompt whose whole blocks are all cached, or shorter
+        than a block, is admitted with an empty final chunk). ``align`` is a
+        multiple of the page size and so of the model's block length:
+        chunks are cut at block boundaries."""
         if remaining <= budget:
             return remaining
         return (budget // align) * align
@@ -279,7 +283,7 @@ class Scheduler:
             if budget <= 0 or len(prefill) >= self.config.max_prefill_batch:
                 break
             take = self._take_chunk(seq.prompt_remaining, budget, align)
-            if take == 0:
+            if take == 0 and seq.prompt_remaining:
                 break
             prefill.append(seq)
             chunks.append(take)
@@ -305,7 +309,7 @@ class Scheduler:
             except AllocationError:
                 break
             take = self._take_chunk(seq.prompt_remaining, budget, align)
-            if take == 0:
+            if take == 0 and seq.prompt_remaining:
                 # Not even one aligned chunk fits the leftover budget: roll
                 # back rather than hold pages for a sequence doing nothing
                 # this step.

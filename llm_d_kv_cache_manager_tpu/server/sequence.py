@@ -24,6 +24,54 @@ class SamplingParams:
     top_k: int = 0  # 0 = disabled
     top_p: float = 1.0
     stop_token_ids: tuple[int, ...] = ()
+    #: generation by diffusion over blocks (a model with ``block_length``
+    #: > 0; the engine refuses either on another model). None = the
+    #: default: ``block_length`` denoising steps a block, a confidence
+    #: threshold of ``DEFAULT_CONFIDENCE_THRESHOLD``.
+    denoising_steps: Optional[int] = None
+    confidence_threshold: Optional[float] = None
+
+
+#: the family's released generation script's (``generate.py``)
+DEFAULT_CONFIDENCE_THRESHOLD = 0.9
+REMASKING_STRATEGY = "low_confidence_dynamic"
+
+
+def check_block_sampling(sampling: SamplingParams, block_length: int) -> None:
+    """Raise ``ValueError`` (a 400 at the API) for block-diffusion
+    parameters the model cannot honour: either of them on an autoregressive
+    model, ``denoising_steps`` outside 1..``block_length``."""
+    steps = sampling.denoising_steps
+    if block_length <= 0:
+        if (steps, sampling.confidence_threshold) != (None, None):
+            raise ValueError(
+                "denoising_steps and confidence_threshold need a model that "
+                "generates by diffusion over blocks; this one is autoregressive"
+            )
+        return
+    if steps is not None and not 1 <= steps <= block_length:
+        raise ValueError(
+            f"denoising_steps {steps} outside 1..{block_length} "
+            "(the model's block_length)"
+        )
+
+
+def check_remasking_strategy(strategy, block_length: int) -> None:
+    """The API's ``remasking_strategy``: one value is served, so nothing is
+    kept of it; any other, or the key on an autoregressive model, raises
+    ``ValueError`` (a 400)."""
+    if strategy is None:
+        return
+    if block_length <= 0:
+        raise ValueError(
+            "remasking_strategy needs a model that generates by diffusion "
+            "over blocks; this one is autoregressive"
+        )
+    if strategy != REMASKING_STRATEGY:
+        raise ValueError(
+            f"unknown remasking_strategy {strategy!r}: only "
+            f"{REMASKING_STRATEGY!r} is served"
+        )
 
 
 @dataclass
@@ -129,6 +177,20 @@ class Sequence:
     #: successful allocation has been counted (same first-prefill-only
     #: rationale as ``mrc_observed``).
     qos_observed: bool = False
+    #: the model's ``block_length`` (0 = autoregressive; the engine sets
+    #: it). With B > 0 the prefill covers whole blocks of the prompt only,
+    #: ``num_computed`` counts tokens of FINAL blocks (a multiple of B while
+    #: the sequence runs) and ``output_tokens`` / ``num_generated`` /
+    #: ``first_token_time`` advance when a block is final.
+    block_length: int = 0
+    #: the block in progress: its B tokens (mask ids where not fixed yet),
+    #: which rows are still masked, and the denoising steps it has had.
+    #: None = no block open (before the first decode dispatch, after a
+    #: block was committed, after preemption: a block interrupted half way
+    #: starts again from masks).
+    block_tokens: Optional[list[int]] = None
+    block_masked: Optional[list[bool]] = None
+    block_step: int = 0
 
     def __post_init__(self):
         if self.user_prompt_len < 0:
@@ -150,9 +212,27 @@ class Sequence:
         return self.all_tokens[self.user_prompt_len :]
 
     @property
+    def prompt_tail(self) -> int:
+        """Prompt tokens the prefill leaves to the first generated block:
+        ``len(prompt) % block_length`` (0 for an autoregressive model)."""
+        if not self.block_length:
+            return 0
+        return len(self.prompt_tokens) % self.block_length
+
+    @property
     def prompt_remaining(self) -> int:
         """Prompt tokens still to prefill (chunked-prefill progress)."""
-        return len(self.prompt_tokens) - self.num_prefilled
+        return len(self.prompt_tokens) - self.prompt_tail - self.num_prefilled
+
+    def open_block(self, mask_token_id: int) -> None:
+        """Open the block after the final ones: the tokens that already
+        stand in it (the prompt's tail, for the first block) and masks."""
+        tail = self.all_tokens[self.num_computed :]
+        n_mask = self.block_length - len(tail)
+        assert n_mask > 0, "a running sequence's context is whole blocks"
+        self.block_tokens = tail + [mask_token_id] * n_mask
+        self.block_masked = [False] * len(tail) + [True] * n_mask
+        self.block_step = 0
 
     def reset_allocation(self) -> None:
         """Clear all page/prefix-cache bookkeeping (single source of truth
@@ -162,6 +242,9 @@ class Sequence:
         self.num_prefilled = 0
         self.num_registered_pages = 0
         self.last_chain_hash = None
+        self.block_tokens = None
+        self.block_masked = None
+        self.block_step = 0
 
     def fold_for_preemption(self) -> None:
         """Recompute-preemption: all tokens become the new 'prompt'; the

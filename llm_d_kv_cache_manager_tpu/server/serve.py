@@ -69,7 +69,7 @@ from ..obs.tracing import Tracer, format_traceparent, parse_traceparent
 from ..utils import get_logger, log_context
 from .engine import NO_PHASE, Engine, EngineConfig
 from .block_manager import BlockManagerConfig
-from .sequence import SamplingParams, Sequence
+from .sequence import SamplingParams, Sequence, check_remasking_strategy
 
 log = get_logger("server.serve")
 
@@ -332,6 +332,23 @@ class _ServingMetrics:
                 0.0,
             )
             self._steps_seen = 0
+            self.engine_block = prom.Counter(
+                "kvcache_engine_block_diffusion_total",
+                "Generation by diffusion over blocks (a model with "
+                "block_length > 0), by count: denoise_lane_forwards / "
+                "commit_lane_forwards (lanes x dispatches with / without a "
+                "masked row), block_tokens_fixed (rows fixed), blocks_final, "
+                "experts_touched (distinct experts a dispatch's rows chose, "
+                "summed over the layers)",
+                ["count"], registry=self.registry,
+            )
+            self._block_seen = dict.fromkeys(
+                (
+                    "denoise_lane_forwards", "commit_lane_forwards",
+                    "block_tokens_fixed", "blocks_final", "experts_touched",
+                ),
+                0,
+            )
             # Host-DRAM tier + prefetch (ISSUE 6): tier occupancy, pages
             # served back from host DRAM (by path: ahead-of-scheduler
             # prefetch vs blocking allocate), and prefetch-round wall time.
@@ -541,6 +558,11 @@ class _ServingMetrics:
             if delta > 0:
                 self.engine_phase_s.labels(phase=key[:-2]).inc(delta)
                 self._step_seen[key] = step_stats[key]
+        for key, seen in self._block_seen.items():
+            delta = step_stats.get(key, 0) - seen
+            if delta > 0:
+                self.engine_block.labels(count=key).inc(delta)
+                self._block_seen[key] = step_stats[key]
         if lag_s is not None:
             self.engine_loop_lag.set(lag_s)
 
@@ -2619,6 +2641,8 @@ class PodServer:
                 else None
             ),
             blocks=blocks,
+            denoising_steps=seq.sampling.denoising_steps,
+            confidence_threshold=seq.sampling.confidence_threshold,
         )
         return seq, payload
 
@@ -2676,6 +2700,8 @@ class PodServer:
             top_k=migration.top_k,
             top_p=migration.top_p,
             stop_token_ids=tuple(migration.stop_token_ids),
+            denoising_steps=migration.denoising_steps,
+            confidence_threshold=migration.confidence_threshold,
         )
         try:
             seq = self.engine.add_request(
@@ -3345,6 +3371,17 @@ class PodServer:
                     top_k=int(body.get("top_k", 0)),
                     top_p=float(body.get("top_p", 1.0)),
                     stop_token_ids=tuple(stop_ids),
+                    # generation by diffusion over blocks; the engine's
+                    # admission check answers 400 for what the model cannot
+                    # honour (``check_block_sampling``)
+                    denoising_steps=_optional(body, "denoising_steps", int),
+                    confidence_threshold=_optional(
+                        body, "confidence_threshold", float
+                    ),
+                )
+                check_remasking_strategy(
+                    body.get("remasking_strategy"),
+                    self.engine.model_cfg.block_length,
                 )
                 token_ids = [int(t) for t in token_ids]
             except (TypeError, ValueError) as e:
@@ -3909,6 +3946,12 @@ class PodServer:
         return app
 
 
+def _optional(body: dict, key: str, cast):
+    """``cast(body[key])``, or None where the body does not state it."""
+    value = body.get(key)
+    return None if value is None else cast(value)
+
+
 def _resolve_model(name: str) -> LlamaConfig:
     from .. import models
 
@@ -3925,6 +3968,8 @@ def _resolve_model(name: str) -> LlamaConfig:
         "tiny-gemma": models.TINY_GEMMA,
         "Qwen/Qwen3-30B-A3B": models.QWEN3_30B_A3B,
         "tiny-qwen3-moe": models.TINY_QWEN3_MOE,
+        "JetLM/SDAR-30B-A3B-Chat": models.SDAR_30B_A3B,
+        "tiny-sdar-moe": models.TINY_SDAR_MOE,
     }
     if name in presets:
         return presets[name]
