@@ -12,9 +12,16 @@ Design (TPU-first, not a torch translation):
   ``[n_layers, total_pages, page_size, n_kv_heads, head_dim]`` — page-major
   with (n_kv, head_dim) minor-contiguous, so a page's full KV tile is one
   contiguous block for the decode kernel AND the per-token write slice is
-  contiguous for the scatter (XLA keeps the default layout end to end; a
-  head-major pool forced full-pool layout-conversion copies around the
-  Pallas call).
+  contiguous for the scatter (a head-major pool forced full-pool
+  layout-conversion copies around the Pallas call). Whether the compiled
+  program keeps the pool in that default layout end to end is the TPU
+  compiler's decision, not this file's: on a v5e it did not while the
+  all-layer scatter had the layer axis in its window (four whole-pool
+  copies a program with fewer than 8 KV heads: PERF_LEDGER.jsonl, PR 28).
+  ``_scatter_kv_pages_all_layers`` now indexes flat token rows, and
+  ``tests/test_pool_layout.py`` reads the program compiled for the chip;
+  ``python -m tools.aot_pool_copies`` lists what is left (the per-layer
+  ``k_pages[li]`` slices ``_prefill_body`` hands the prefill kernel).
 - Weights default to bfloat16 (MXU-native); attention/softmax accumulate in
   float32.
 
@@ -911,26 +918,45 @@ def _logits(params: Params, cfg: LlamaConfig, h: jnp.ndarray) -> jnp.ndarray:
 
 def _scatter_kv_pages_all_layers(
     pages: jnp.ndarray,  # [n_layers, total_pages, page_size, n_kv, hd]
-    fresh: jnp.ndarray,  # [n_layers, b, s, n_kv, hd]
+    fresh: jnp.ndarray,  # [n_layers, b, s, n_kv, hd] (or [n_layers, b*s, ...])
     page_ids: jnp.ndarray,  # [b, s]
     slot_ids: jnp.ndarray,  # [b, s]
     valid: jnp.ndarray,  # [b, s]
 ) -> jnp.ndarray:
     """Scatter every layer's fresh K or V into the pool with ONE update op
-    (aliased into the donated buffer; invalid positions dropped).
+    (aliased into the donated buffer; invalid positions dropped): the one
+    write path of ``prefill``, ``decode_step(s)``, ``spec_decode_steps``
+    and ``denoise_step(s)``.
 
-    The pool's page-major layout keeps the written [n_kv, hd] slice
-    minor-contiguous, so this one scatter serves prefill AND decode in the
-    default XLA layout — the compiled graphs carry zero full-pool
-    layout-conversion copies around the Pallas attention call."""
+    The pool is written as flat token rows ``[n_layers * total_pages *
+    page_size, n_kv, hd]``, one index a (layer, token), layer-major like
+    the stacked fresh K/V, so that the scatter's window is the ``[n_kv,
+    hd]`` slice alone. With the layer axis in the window (``pages.at[:,
+    page, slot]``, as this was until PR 29) and fewer than 8 KV heads, the
+    TPU compiler lays the operand out with the layers on the sublanes
+    (``{4,0,3,2,1:T(8,128)}``) and copies the whole pool into that layout
+    and back around the scatter: four copies of a 512 MiB pool, 6.5-6.7 ms
+    of every MoE decode step and denoising forward on a v5e
+    (PERF_LEDGER.jsonl, PR 28: ``copy_bf16_8_4096_16_4_128_``;
+    ``%copy.2295``/``.2305`` in, ``%copy.2306``/``.2307`` out). No CPU test
+    can see that: the compiled program is read in
+    ``tests/test_pool_layout.py``, which fails if any instruction but this
+    scatter's fusion produces an array of the pool's shape."""
     L, total_pages, page_size, n_kv, hd = pages.shape
-    pidx = page_ids.reshape(-1)
-    sidx = slot_ids.reshape(-1)
-    # Invalid positions: redirect the page index out of range → mode="drop".
-    pidx = jnp.where(valid.reshape(-1), pidx, total_pages)
-    # [L, b, s, n_kv, hd] -> [L, b*s, n_kv, hd]
-    updates = fresh.reshape(L, -1, n_kv, hd)
-    return pages.at[:, pidx, sidx].set(updates, mode="drop")
+    layer_rows = total_pages * page_size
+    # A token that is not valid, or whose page or slot lies past the pool's
+    # (as a flat row it would be a real row of the next page or layer), gets
+    # the first row past the pool, which mode="drop" discards. (int32: a
+    # pool of 2**31 token rows is beyond any HBM.)
+    keep = valid & (page_ids < total_pages) & (slot_ids < page_size)
+    rows = (page_ids * page_size + slot_ids).reshape(-1)
+    layer_base = jnp.arange(L, dtype=rows.dtype) * layer_rows
+    rows = jnp.where(
+        keep.reshape(1, -1), layer_base[:, None] + rows[None, :], L * layer_rows
+    )
+    flat = pages.reshape(L * layer_rows, n_kv, hd)
+    flat = flat.at[rows.reshape(-1)].set(fresh.reshape(-1, n_kv, hd), mode="drop")
+    return flat.reshape(pages.shape)
 
 
 def _quantized_scatter_kv_all_layers(
@@ -955,7 +981,14 @@ def _quantized_scatter_kv_all_layers(
     its scale resets to zero before the scatter-max, so a previous tenant's
     scale can never inflate the new resolution. The carry page's resident
     codes are requantized under the grown scale with the exact ratio
-    ``s_old / s_new`` — a bit-exact no-op when the scale is unchanged."""
+    ``s_old / s_new`` — a bit-exact no-op when the scale is unchanged.
+
+    The fresh codes are written as flat token rows, by the bf16 pool's own
+    ``_scatter_kv_pages_all_layers``; the carry page's rewrite still has the layer
+    axis in its scatter window, so with fewer than 8 KV heads the program
+    compiled for a v5e still copies the whole pool around it (read with
+    the recipe of ``tools/aot_pool_copies.py``; no benchmark cell runs an
+    int8 pool, so not measured)."""
     L, P, ps, n_kv, hd = pages_q.shape
     b, s = page_ids.shape
     pidx = jnp.where(valid.reshape(-1), page_ids.reshape(-1), P)
@@ -993,7 +1026,7 @@ def _quantized_scatter_kv_all_layers(
     q = jnp.clip(
         jnp.round(x / jnp.maximum(s_tok, 1e-30)[..., None]), -127, 127
     ).astype(jnp.int8)
-    pages_q = pages_q.at[:, pidx, sidx].set(q, mode="drop")
+    pages_q = _scatter_kv_pages_all_layers(pages_q, q, page_ids, slot_ids, valid)
     return pages_q, new_scales
 
 
@@ -1234,7 +1267,10 @@ def _decode_body(
         # The kernel takes the current token's K/V as arguments (pages hold
         # only history), so the pool write happens ONCE for all layers after
         # the loop — a single aliased scatter instead of a per-layer pool
-        # rebuild (which cost 2×pool bytes of HBM traffic per token).
+        # rebuild (which cost 2×pool bytes of HBM traffic per token). The
+        # kernel reads the pool in the default layout; that the write does
+        # too (it did not until PR 29: see _scatter_kv_pages_all_layers) is
+        # what tests/test_pool_layout.py holds on the compiled program.
         attn = _paged_attention_tp(
             q[:, 0],  # [b, n_heads, hd]
             k_pages,  # FULL [L, P, ps, n_kv, hd] pool; layer via index map

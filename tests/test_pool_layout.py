@@ -1,0 +1,355 @@
+"""The KV pool is written in the layout it is kept in.
+
+Two halves:
+
+- **What the chip's compiler makes of the write** (``TestCompiledForTheChip``):
+  ``_scatter_kv_pages_all_layers`` is compiled for a DESCRIBED v5e chip (the
+  TPU compiler is installed here and needs no chip: on-chip-measurement guide,
+  section 2) and the optimised HLO is read: besides the scatter's own fusion
+  no instruction that moves bytes may have the pool's shape. Until PR 29 the scatter's window
+  held the layer axis, and with 4 KV heads the compiler copied both whole
+  pools into a layer-inward layout and back in every MoE decode step,
+  denoising forward and prefill dispatch: 6.5-6.7 ms a step on the chip
+  (PERF_LEDGER.jsonl, PR 28: ``copy_bf16_8_4096_16_4_128_``), which no CPU
+  test and no docstring could see. Every case loads libtpu, which one process
+  at a time may hold: they all stay in this one file (one xdist worker) and
+  the topology is described in a fixture, never at import.
+- **What it writes** (``TestValues``, CPU): the flat-row scatter against the
+  five-dimensional expression it replaced.
+"""
+
+import faulthandler
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+from llm_d_kv_cache_manager_tpu.models.llama import _scatter_kv_pages_all_layers
+from llm_d_kv_cache_manager_tpu.parallel.sharding import kv_pages_sharding
+from tools import aot_pool_copies
+
+#: This file's own limit: a case compiles in under a second, the first pays
+#: the compiler's start (a few seconds), a whole served program (the slow
+#: cases) takes 20-60 s. What can go wrong is a compile
+#: that hangs in native code, where no Python signal handler runs: the
+#: watchdog dumps every thread's stack and ends the process (an xdist worker
+#: is replaced and the case reported as crashed) instead of letting one case
+#: eat tier-1's budget.
+_CASE_LIMIT_S = 240
+
+
+@pytest.fixture(autouse=True)
+def _case_limit():
+    faulthandler.dump_traceback_later(_CASE_LIMIT_S, exit=True)
+    try:
+        yield
+    finally:
+        faulthandler.cancel_dump_traceback_later()
+
+
+def _old_scatter(pages, fresh, page_ids, slot_ids, valid):
+    """The write as it was until PR 29 (the oracle; layer axis in the
+    scatter's window)."""
+    L, total_pages, _, n_kv, hd = pages.shape
+    pidx = jnp.where(valid.reshape(-1), page_ids.reshape(-1), total_pages)
+    return pages.at[:, pidx, slot_ids.reshape(-1)].set(
+        fresh.reshape(L, -1, n_kv, hd), mode="drop"
+    )
+
+
+def _whole_pool_moves(hlo, pool_shape):
+    """Instructions that produce an array of the pool's shape and are
+    neither free nor a fusion around the scatter."""
+    inside = aot_pool_copies.fusion_opcodes(hlo)
+    return [
+        (i.opcode, i.name, i.result)
+        for i in aot_pool_copies.pool_instructions(hlo, pool_shape)
+        if i.moves_bytes and "scatter" not in inside.get(i.name, ())
+    ]
+
+
+# (pool shape, (b, s) of the write): the cells' pools at the row counts the
+# served programs write — 16 (a decode step), 64 (a denoising forward: 16
+# lanes x a block of 4), 1024 (a prefill dispatch: 8 x 128).
+_MOE_POOL = (8, 4096, 16, 4, 128)  # qwen3-30b-a3b, sdar-30b-a3b
+_DENSE_POOL = (5, 8192, 16, 8, 128)  # qwen3-32b
+_CASES = [
+    pytest.param(_MOE_POOL, (16, 1), 1, id="kv4-decode16"),
+    pytest.param(_MOE_POOL, (16, 4), 1, id="kv4-block64"),
+    pytest.param(_MOE_POOL, (8, 128), 1, id="kv4-prefill1024"),
+    pytest.param(_DENSE_POOL, (16, 1), 1, id="kv8-decode16"),
+    pytest.param(_DENSE_POOL, (8, 128), 1, id="kv8-prefill1024"),
+    # A tp=4 slice: the pool is sharded on the KV-head axis, which the flat
+    # view leaves alone (it merges the three replicated leading axes).
+    pytest.param(_MOE_POOL, (16, 1), 4, id="kv4-tp4-decode16"),
+    pytest.param(_DENSE_POOL, (8, 128), 4, id="kv8-tp4-prefill1024"),
+]
+
+
+@pytest.fixture(scope="module")
+def topo():
+    try:
+        return aot_pool_copies.describe_v5e()
+    except Exception as e:  # no libtpu, or another process holds it
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+class TestCompiledForTheChip:
+    @pytest.mark.parametrize("pool_shape, write, tp", _CASES)
+    def test_only_the_scatter_touches_the_pool(self, topo, pool_shape, write, tp):
+        mesh = Mesh(np.array(topo.devices[:tp]).reshape(1, tp), ("dp", "tp"))
+        pool_sharding = kv_pages_sharding(mesh)
+        replicated = NamedSharding(mesh, P())
+        L, _, _, n_kv, hd = pool_shape
+        b, s = write
+
+        def shaped(shape, dtype, sharding=replicated):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+        hlo = aot_pool_copies.compile_text(
+            jax.jit(
+                _scatter_kv_pages_all_layers,
+                donate_argnums=0,
+                out_shardings=pool_sharding,
+            ),
+            shaped(pool_shape, jnp.bfloat16, pool_sharding),
+            shaped((L, b, s, n_kv, hd), jnp.bfloat16, pool_sharding),
+            shaped(write, jnp.int32),
+            shaped(write, jnp.int32),
+            shaped(write, jnp.bool_),
+        )
+        per_device = (*pool_shape[:3], n_kv // tp, hd)
+        found = aot_pool_copies.pool_instructions(hlo, per_device)
+        assert found, "the pool is nowhere in the module: the reader is blind"
+        # Nothing but the scatter's own fusion (in place: its operand is the
+        # donated pool) may produce a pool; where the compiler keeps the flat
+        # view inside that fusion, nothing at all does.
+        assert _whole_pool_moves(hlo, per_device) == [], (
+            "the compiled write moves the whole pool besides scattering into it"
+        )
+        inside = aot_pool_copies.fusion_opcodes(hlo)
+        everything = list(aot_pool_copies.instructions(hlo))
+        scatters = [
+            name for _, name, op, _ in everything
+            if op == "scatter" or "scatter" in inside.get(name, ())
+        ]
+        assert len(scatters) == 1, scatters
+        collectives = ("all-gather", "all-reduce", "all-to-all", "collective-permute")
+        assert not [name for _, name, op, _ in everything if op.startswith(collectives)]
+
+
+_SERVED = [
+    ("qwen3-30b-a3b", "decode_steps"),
+    ("qwen3-30b-a3b", "prefill"),
+    ("qwen3-32b", "decode_steps"),
+    ("qwen3-32b", "prefill"),
+    ("sdar-30b-a3b", "denoise_steps"),
+    ("sdar-30b-a3b", "prefill"),
+]
+
+
+@pytest.mark.slow  # 20-60 s a program
+class TestServedPrograms:
+    """The whole served programs at the cells' shapes, as
+    ``python -m tools.aot_pool_copies`` compiles them: inside a whole
+    program another consumer can ask for another layout than the helper
+    alone gets. The per-layer slices ``_prefill_body`` makes are allowed
+    (ROADMAP S2, open): only a whole pool is held here."""
+
+    @pytest.mark.parametrize("config, program", _SERVED)
+    def test_no_whole_pool_copy(self, topo, config, program):
+        one_chip = SingleDeviceSharding(topo.devices[0])
+        fn, args, kwargs, pool_shape = aot_pool_copies.served_program(
+            config, program, one_chip
+        )
+        hlo = aot_pool_copies.compile_text(fn, *args, **kwargs)
+        assert aot_pool_copies.pool_instructions(hlo, pool_shape)
+        assert _whole_pool_moves(hlo, pool_shape) == []
+
+    def test_a_program_the_configuration_does_not_serve(self, topo):
+        one_chip = SingleDeviceSharding(topo.devices[0])
+        assert aot_pool_copies.served_program("qwen3-32b", "denoise_steps", one_chip) is None
+        assert aot_pool_copies.served_program("sdar-30b-a3b", "decode_steps", one_chip) is None
+
+
+_RECORDED = """\
+HloModule jit_step, is_scheduled=true
+
+%fused_computation.1 (param_0: bf16[2,8,4,2,128], param_1: s32[6]) -> bf16[64,2,128] {
+  %param_0 = bf16[2,8,4,2,128]{4,3,2,1,0:T(2,128)(2,1)} parameter(0)
+  %bitcast.1 = bf16[64,2,128]{2,1,0:T(2,128)(2,1)} bitcast(%param_0)
+  ROOT %scatter.1 = bf16[64,2,128]{2,1,0:T(2,128)(2,1)} scatter(%bitcast.1, %param_1), to_apply=%assign
+}
+
+%fused_computation.2 (param_0.1: bf16[2,8,4,2,128]) -> (bf16[1,8,4,2,128], bf16[1,8,4,2,128]) {
+  %param_0.1 = bf16[2,8,4,2,128]{4,3,2,1,0:T(2,128)(2,1)} parameter(0)
+  %slice.1 = bf16[1,8,4,2,128]{4,3,2,1,0:T(2,128)(2,1)} slice(%param_0.1), slice={[0:1], [0:8], [0:4], [0:2], [0:128]}
+  %slice.2 = bf16[1,8,4,2,128]{4,3,2,1,0:T(2,128)(2,1)} slice(%param_0.1), slice={[1:2], [0:8], [0:4], [0:2], [0:128]}
+  ROOT %tuple.1 = (bf16[1,8,4,2,128]{4,3,2,1,0:T(2,128)(2,1)}, bf16[1,8,4,2,128]{4,3,2,1,0:T(2,128)(2,1)}) tuple(%slice.1, %slice.2)
+}
+
+ENTRY %main.9 (k_pages.1: bf16[2,8,4,2,128], rows.1: s32[6]) -> (f32[3], bf16[2,8,4,2,128]) {
+  %k_pages.1 = bf16[2,8,4,2,128]{4,3,2,1,0:T(2,128)(2,1)} parameter(0)
+  %rows.1 = s32[6]{0:T(128)} parameter(1)
+  %copy.7 = bf16[2,8,4,2,128]{4,0,3,2,1:T(8,128)(2,1)} copy(bf16[2,8,4,2,128]{4,3,2,1,0:T(2,128)(2,1)} %k_pages.1)
+  %fusion.2 = (bf16[1,8,4,2,128]{4,3,2,1,0:T(2,128)(2,1)}, /*index=1*/bf16[1,8,4,2,128]{4,3,2,1,0:T(2,128)(2,1)S(1)}) fusion(%k_pages.1), kind=kLoop, calls=%fused_computation.2
+  %fusion.1 = bf16[64,2,128]{2,1,0:T(2,128)(2,1)} fusion(%k_pages.1, %rows.1), kind=kInput, calls=%fused_computation.1
+  %bitcast.4 = bf16[2,8,4,2,128]{4,3,2,1,0:T(2,128)(2,1)} bitcast(%fusion.1)
+  %logits.1 = f32[3]{0:T(128)} constant({0, 0, 0})
+  ROOT %tuple.9 = (f32[3]{0:T(128)}, bf16[2,8,4,2,128]{4,3,2,1,0:T(2,128)(2,1)}) tuple(%logits.1, %bitcast.4)
+}
+"""
+
+
+class TestTheReader:
+    """``tools/aot_pool_copies``' reading of optimised HLO text, on a small
+    recorded module (no compiler): fusion bodies are skipped, tuple results
+    are searched member by member, free opcodes are told from those that
+    move bytes."""
+
+    POOL = (2, 8, 4, 2, 128)
+
+    def test_whole_pools(self):
+        found = aot_pool_copies.pool_instructions(_RECORDED, self.POOL)
+        assert [(i.name, i.opcode, i.moves_bytes) for i in found] == [
+            ("k_pages.1", "parameter", False),
+            ("copy.7", "copy", True),
+            ("bitcast.4", "bitcast", False),
+            ("tuple.9", "tuple", False),
+        ]
+        assert all(i.computation == "main.9" for i in found)
+        assert found[1].result == "bf16[2,8,4,2,128]{4,0,3,2,1:T(8,128)(2,1)}"
+
+    def test_layer_slices_inside_a_tuple_result(self):
+        found = aot_pool_copies.pool_instructions(
+            _RECORDED, self.POOL, layer_slices=True
+        )
+        sliced = [i for i in found if i.name == "fusion.2"]
+        assert len(sliced) == 1 and sliced[0].moves_bytes
+        assert aot_pool_copies.tuple_members(sliced[0].result) == (
+            "1 x bf16[1,8,4,2,128]{4,3,2,1,0:T(2,128)(2,1)}, "
+            "1 x bf16[1,8,4,2,128]{4,3,2,1,0:T(2,128)(2,1)S(1)}"
+        )
+
+    def test_what_a_fusion_holds(self):
+        inside = aot_pool_copies.fusion_opcodes(_RECORDED)
+        assert inside == {
+            "fusion.2": {"parameter", "slice", "tuple"},
+            "fusion.1": {"parameter", "bitcast", "scatter"},
+        }
+        assert _whole_pool_moves(_RECORDED, self.POOL) == [
+            ("copy", "copy.7", "bf16[2,8,4,2,128]{4,0,3,2,1:T(8,128)(2,1)}")
+        ]
+
+
+def _random_write(rng, pool_shape, b, s, dtype=jnp.float32):
+    L, total_pages, page_size, n_kv, hd = pool_shape
+    pages = jnp.asarray(rng.standard_normal(pool_shape), dtype)
+    fresh = jnp.asarray(rng.standard_normal((L, b, s, n_kv, hd)), dtype)
+    # Distinct (page, slot) per token: a scatter with duplicate rows may keep
+    # either, in both forms.
+    token_rows = rng.choice(total_pages * page_size, size=b * s, replace=False)
+    page_ids = (token_rows // page_size).reshape(b, s).astype(np.int32)
+    slot_ids = (token_rows % page_size).reshape(b, s).astype(np.int32)
+    return pages, fresh, page_ids, slot_ids
+
+
+class TestValues:
+    POOL = (3, 12, 4, 2, 8)
+
+    @pytest.mark.parametrize(
+        "b, s, n_valid",
+        [
+            pytest.param(5, 1, [1, 1, 0, 1, 0], id="decode"),
+            pytest.param(3, 8, [8, 5, 0], id="prefill-right-padded"),
+            pytest.param(4, 4, [4, 0, 4, 0], id="block-inactive-lanes"),
+        ],
+    )
+    def test_matches_the_five_dimensional_scatter(self, b, s, n_valid):
+        rng = np.random.default_rng(b * 100 + s)
+        pages, fresh, page_ids, slot_ids = _random_write(rng, self.POOL, b, s)
+        valid = np.arange(s)[None, :] < np.asarray(n_valid)[:, None]
+        # Pad rows point at real places (page 0 is the engine's padding
+        # page): only ``valid`` may keep them out.
+        got = _scatter_kv_pages_all_layers(pages, fresh, page_ids, slot_ids, valid)
+        want = _old_scatter(pages, fresh, page_ids, slot_ids, valid)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        assert got.dtype == pages.dtype and got.shape == pages.shape
+        # And it wrote what it should, where it should, in every layer.
+        bi, si = np.nonzero(valid)
+        np.testing.assert_array_equal(
+            np.asarray(got)[:, page_ids[bi, si], slot_ids[bi, si]],
+            np.asarray(fresh)[:, bi, si],
+        )
+
+    def test_a_page_is_written_in_every_layer_with_that_layers_rows(self):
+        # The same page and slot ids serve all layers; a row index that lost
+        # its layer would write every layer's rows into one.
+        L, total_pages, page_size, n_kv, hd = self.POOL
+        pages = jnp.zeros(self.POOL, jnp.float32)
+        fresh = jnp.broadcast_to(
+            jnp.arange(1, L + 1, dtype=jnp.float32)[:, None, None, None, None],
+            (L, 2, 1, n_kv, hd),
+        )
+        page_ids = np.array([[7], [7]], np.int32)
+        slot_ids = np.array([[0], [3]], np.int32)
+        got = np.asarray(_scatter_kv_pages_all_layers(
+            pages, fresh, page_ids, slot_ids, np.ones((2, 1), bool)))
+        for layer in range(L):
+            assert (got[layer, 7, [0, 3]] == layer + 1).all()
+            assert got[layer].sum() == (layer + 1) * 2 * n_kv * hd
+
+    def test_the_last_row_of_the_pool_and_the_sentinel_past_it(self):
+        L, total_pages, page_size, n_kv, hd = self.POOL
+        rng = np.random.default_rng(7)
+        pages, fresh, _, _ = _random_write(rng, self.POOL, 2, 1)
+        last = np.array([[total_pages - 1], [total_pages - 1]], np.int32)
+        slots = np.array([[page_size - 1], [page_size - 2]], np.int32)
+        # Lane 0 writes the pool's very last row; lane 1 is invalid, so it
+        # goes to the sentinel: it may land nowhere, least of all there.
+        valid = np.array([[True], [False]])
+        got = np.asarray(_scatter_kv_pages_all_layers(pages, fresh, last, slots, valid))
+        want = np.asarray(pages).copy()
+        want[:, -1, -1] = np.asarray(fresh)[:, 0, 0]
+        np.testing.assert_array_equal(got, want)
+
+    def test_nothing_valid_writes_nothing(self):
+        rng = np.random.default_rng(11)
+        pages, fresh, page_ids, slot_ids = _random_write(rng, self.POOL, 3, 4)
+        got = _scatter_kv_pages_all_layers(
+            pages, fresh, page_ids, slot_ids, np.zeros((3, 4), bool))
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(pages))
+
+    @pytest.mark.parametrize(
+        "page, slot", [pytest.param(12, 0, id="page"), pytest.param(3, 4, id="slot")]
+    )
+    def test_a_place_past_the_pool_is_dropped_not_another_layers(self, page, slot):
+        # The five-dimensional scatter dropped an out-of-range page or slot;
+        # as a flat row it would be a real row of the next page or layer.
+        rng = np.random.default_rng(13)
+        pages, fresh, page_ids, slot_ids = _random_write(rng, self.POOL, 2, 1)
+        page_ids[1, 0], slot_ids[1, 0] = page, slot
+        valid = np.ones((2, 1), bool)
+        got = _scatter_kv_pages_all_layers(pages, fresh, page_ids, slot_ids, valid)
+        want = _old_scatter(pages, fresh, page_ids, slot_ids, valid)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+    def test_a_pool_sharded_on_kv_heads_stays_so(self):
+        # tp=4 on the virtual CPU devices: the flat view merges the three
+        # leading (replicated) axes only, so the head sharding carries
+        # through the write and the values are the unsharded ones.
+        pool_shape = (2, 6, 4, 4, 8)
+        mesh = Mesh(np.array(jax.devices()[:4]).reshape(1, 4), ("dp", "tp"))
+        sharding = kv_pages_sharding(mesh)
+        rng = np.random.default_rng(17)
+        pages, fresh, page_ids, slot_ids = _random_write(rng, pool_shape, 3, 2)
+        valid = np.array([[True, True], [True, False], [False, False]])
+        want = _old_scatter(pages, fresh, page_ids, slot_ids, valid)
+        got = jax.jit(_scatter_kv_pages_all_layers)(
+            jax.device_put(pages, sharding), jax.device_put(fresh, sharding),
+            page_ids, slot_ids, valid,
+        )
+        assert got.sharding.is_equivalent_to(sharding, got.ndim)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))

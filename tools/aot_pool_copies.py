@@ -1,0 +1,270 @@
+"""What the compiled programs do to the KV pool, read without a chip.
+
+The TPU's compiler is installed beside JAX and compiles for a chip that is
+described, not attached (``jax.experimental.topologies``). This tool
+compiles the served programs of the benchmark's configurations — the fused
+``decode_steps`` or ``denoise_steps`` and one ``prefill`` dispatch, at the
+shapes ``chipbench/configs/<name>.json`` pins — for one v5e chip and lists
+every instruction outside a fusion's body whose result has the shape of a
+whole K or V pool ``[L, P, page, n_kv, hd]`` or of one layer's slice of it.
+
+A pool is hundreds of MiB: any such instruction that is not free (a
+``bitcast``, a ``parameter``, tuple plumbing) reads and writes that much
+HBM each time the program runs. PR 29 found four ``copy`` of the whole
+pool in every MoE program this way (the scatter's window held the layer
+axis); the per-layer slices ``_prefill_body`` hands the prefill kernel are
+what is left (ROADMAP S2).
+
+    python -m tools.aot_pool_copies                      # every program
+    python -m tools.aot_pool_copies --config qwen3-32b --program prefill
+
+Nothing runs: no time comes out of this, only the compiler's plan. A
+compile takes 20-60 s a program on a CPU host.
+"""
+
+from __future__ import annotations
+
+import argparse
+import dataclasses
+import importlib
+import json
+import os
+import re
+import sys
+from pathlib import Path
+from typing import Iterator, NamedTuple
+
+CONFIGS = Path(__file__).resolve().parent.parent / "chipbench" / "configs"
+PROGRAMS = ("decode_steps", "denoise_steps", "prefill")
+PREFILL_ROWS, PREFILL_CHUNK = 8, 128  # a dispatch of the cells: 1024 rows
+
+#: Opcodes that move no bytes.
+FREE = frozenset({"parameter", "bitcast", "get-tuple-element", "tuple"})
+
+
+class PoolInstruction(NamedTuple):
+    computation: str
+    name: str
+    opcode: str
+    result: str  # the result type as printed, layouts included
+    moves_bytes: bool
+
+
+def _split_type(rest: str) -> tuple[str, str]:
+    """``rest`` is an instruction after ``" = "``: (result type, remainder).
+    A tuple type is parenthesised and layouts carry parentheses of their
+    own (``T(8,128)(2,1)``), so a tuple is closed by depth, a plain type by
+    its first blank."""
+    if not rest.startswith("("):
+        head, _, tail = rest.partition(" ")
+        return head, tail
+    depth = 0
+    for i, ch in enumerate(rest):
+        depth += (ch == "(") - (ch == ")")
+        if depth == 0:
+            return rest[: i + 1], rest[i + 1 :].lstrip()
+    raise ValueError(f"unbalanced result type: {rest[:80]!r}")
+
+
+_HEADER = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s*\(.*\)\s*->.*\{\s*$")
+_INSTRUCTION = re.compile(r"^\s+(?:ROOT\s+)?%?([\w.\-]+) = (.*)$")
+_CALLS = re.compile(r"\bcalls=%?([\w.\-]+)")
+
+
+def _parse(hlo: str) -> tuple[dict[str, list[tuple[str, str, str]]], dict[str, str]]:
+    """An optimised HLO module's text as ({computation: [(name, opcode,
+    result type)]}, {fusion instruction: the computation that is its body})."""
+    computations: dict[str, list[tuple[str, str, str]]] = {}
+    bodies: dict[str, str] = {}
+    current: list[tuple[str, str, str]] = []
+    for line in hlo.splitlines():
+        header = _HEADER.match(line)
+        if header:
+            current = computations.setdefault(header.group(1), [])
+            continue
+        found = _INSTRUCTION.match(line)
+        if not found:
+            continue
+        result, tail = _split_type(found.group(2))
+        opcode = tail.partition("(")[0]
+        current.append((found.group(1), opcode, result))
+        if opcode == "fusion":
+            bodies[found.group(1)] = _CALLS.search(tail).group(1)
+    return computations, bodies
+
+
+def instructions(hlo: str) -> Iterator[tuple[str, str, str, str]]:
+    """(computation, name, opcode, result type) of every instruction that is
+    not inside a fusion's body."""
+    computations, bodies = _parse(hlo)
+    fused = set(bodies.values())
+    for computation, members in computations.items():
+        if computation not in fused:
+            for name, opcode, result in members:
+                yield computation, name, opcode, result
+
+
+def fusion_opcodes(hlo: str) -> dict[str, set[str]]:
+    """The opcodes inside each fusion's body, by the fusion instruction's
+    name: what tells a scatter's fusion from a relayout's."""
+    computations, bodies = _parse(hlo)
+    return {
+        fusion: {opcode for _, opcode, _ in computations.get(body, ())}
+        for fusion, body in bodies.items()
+    }
+
+
+def pool_instructions(
+    hlo: str, pool_shape: tuple[int, ...], *, layer_slices: bool = False
+) -> list[PoolInstruction]:
+    """The instructions whose result (or a member of whose tuple result)
+    has ``pool_shape``; with ``layer_slices`` also those shaped like one
+    layer of it, ``[P, page, n_kv, hd]`` or ``[1, P, page, n_kv, hd]``."""
+    shapes = [pool_shape]
+    if layer_slices:
+        shapes += [pool_shape[1:], (1, *pool_shape[1:])]
+    wanted = re.compile(
+        "|".join(r"\[" + ",".join(map(str, s)) + r"\]" for s in shapes)
+    )
+    return [
+        PoolInstruction(comp, name, op, result, op not in FREE)
+        for comp, name, op, result in instructions(hlo)
+        if wanted.search(result)
+    ]
+
+
+def tuple_members(result: str) -> str:
+    """A result type for a line of output: a tuple as its distinct members,
+    each with its count."""
+    if not result.startswith("("):
+        return result
+    members = re.sub(r"/\*index=\d+\*/", "", result[1:-1]).split(", ")
+    return ", ".join(
+        f"{members.count(m)} x {m}" for m in dict.fromkeys(members)
+    )
+
+
+def describe_v5e():
+    """A v5e host of four chips (2x2), described, not attached: its
+    ``.devices`` make shardings for shapes. Loads libtpu (a compile-only
+    client; no device is opened), which one process at a time may hold."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+
+
+def compile_text(fn, *args, **kwargs) -> str:
+    """Optimised HLO of ``fn`` (a ``jax.jit``) lowered for the shardings its
+    ``ShapeDtypeStruct`` arguments carry. Compiled Pallas kernels refuse a
+    host whose default backend is no TPU (``ops/_mosaic.py``); nothing runs
+    here, so the refusal is lifted for the lowering alone."""
+    modules = [
+        importlib.import_module(f"llm_d_kv_cache_manager_tpu.ops.{name}")
+        for name in ("flash_prefill", "gmm", "paged_attention")
+    ]
+    kept = [m.require_tpu_unless_interpret for m in modules]
+    for m in modules:
+        m.require_tpu_unless_interpret = lambda kernel, interpret: None
+    try:
+        return fn.lower(*args, **kwargs).compile().as_text()
+    finally:
+        for m, guard in zip(modules, kept):
+            m.require_tpu_unless_interpret = guard
+
+
+def served_program(config: str, program: str, one_chip):
+    """(jitted function, args, kwargs, pool shape) of a configuration's
+    served ``program`` at the shapes its cell pins, or None where the
+    configuration does not serve it (``decode_steps`` under a block mask,
+    ``denoise_steps`` without one)."""
+    import jax
+    import jax.numpy as jnp
+
+    from llm_d_kv_cache_manager_tpu.models import llama
+
+    spec = json.loads((CONFIGS / f"{config}.json").read_text())["chipbench"]
+    cfg = dataclasses.replace(getattr(llama, spec["preset"]), **spec["replace"])
+    env, engine = spec["env"], spec["engine"]
+    lanes, page = env["DECODE_BATCH_SIZE"], env["BLOCK_SIZE"]
+    table_w = engine["decode_pages_bucket"]
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    i32, f32 = jnp.int32, jnp.float32
+    params = jax.tree.map(
+        lambda x: S(x.shape, x.dtype),
+        jax.eval_shape(lambda k: llama.init_params(k, cfg), jax.random.PRNGKey(0)),
+    )
+    pool_shape = (cfg.n_layers, env["TOTAL_PAGES"], page, cfg.n_kv_heads, cfg.hd)
+    pool = S(pool_shape, jnp.bfloat16)
+    key = S((2,), jnp.uint32)
+    if program == "decode_steps" and cfg.block_length == 0:
+        args = (
+            params, cfg, S((lanes,), i32), S((lanes,), i32), pool, pool,
+            S((lanes, table_w), i32), S((lanes,), i32), S((lanes,), f32),
+            S((lanes,), i32), S((lanes,), f32), key,
+        )
+        kwargs = dict(page_size=page, num_steps=1, interpret=False, mesh=None)
+        return llama.decode_steps, args, kwargs, pool_shape
+    if program == "denoise_steps" and cfg.block_length > 0:
+        width = 2 * cfg.block_length + table_w + 5
+        args = (params, cfg, S((lanes, width), i32), S((lanes, 3), f32), pool, pool, key)
+        kwargs = dict(
+            page_size=page, table_w=table_w, mesh=None, attn_impl="pallas",
+            interpret=False,
+        )
+        return llama.denoise_steps, args, kwargs, pool_shape
+    if program == "prefill":
+        rows = (PREFILL_ROWS, PREFILL_CHUNK)
+        args = (
+            params, cfg, S(rows, i32), S(rows, i32), S(rows, jnp.bool_), pool,
+            pool, S(rows, i32), S(rows, i32),
+            S((PREFILL_ROWS, engine["prefill_ctx_bucket"]), i32),
+            S((PREFILL_ROWS,), i32),
+        )
+        kwargs = dict(mesh=None, attn_impl="pallas", interpret=False)
+        return llama.prefill, args, kwargs, pool_shape
+    return None
+
+
+def main(argv=None) -> int:
+    names = sorted(p.stem for p in CONFIGS.glob("*.json"))
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--config", action="append", choices=names)
+    ap.add_argument("--program", action="append", choices=PROGRAMS)
+    ap.add_argument("--dump", help="directory for each program's whole HLO text")
+    opts = ap.parse_args(argv)
+    os.environ["JAX_PLATFORMS"] = "cpu"  # the chip is described, never opened
+    from jax.sharding import SingleDeviceSharding
+
+    one_chip = SingleDeviceSharding(describe_v5e().devices[0])
+    for config in opts.config or names:
+        for program in opts.program or PROGRAMS:
+            served = served_program(config, program, one_chip)
+            if served is None:
+                continue
+            fn, args, kwargs, pool_shape = served
+            hlo = compile_text(fn, *args, **kwargs)
+            if opts.dump:
+                os.makedirs(opts.dump, exist_ok=True)
+                Path(opts.dump, f"{config}.{program}.hlo.txt").write_text(hlo)
+            found = pool_instructions(hlo, pool_shape, layer_slices=True)
+            moving = [i for i in found if i.moves_bytes]
+            print(f"{config} {program} pool {list(pool_shape)}: "
+                  f"{len(moving)} of {len(found)} instructions move bytes")
+            # Eight layers make eight lines that differ in a suffix: one
+            # line for each (opcode, result), with the first name.
+            alike: dict[tuple, list[str]] = {}
+            for i in found:
+                key = (i.moves_bytes, i.opcode, tuple_members(i.result))
+                alike.setdefault(key, []).append(i.name)
+            for (moves, opcode, result), group in alike.items():
+                print(f"  {'*' if moves else ' '} {len(group):>2} x {opcode:<18} "
+                      f"%{group[0]:<24} {result}")
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
