@@ -70,7 +70,7 @@ TOL_ATTN_F32_ON_CHIP = 5e-3
 TOL_ATTN_INT8_POOL = 3e-2
 #: Grouped matmul: max|kernel - ragged_dot| / max|ragged_dot|. bf16 output
 #: rounding over a 2048-long contraction; the int8 side adds the f32-vs-
-#: bf16 dequantization order (bench_moe's on-chip bounds).
+#: bf16 dequantization order (bounds first set on a rig that is gone).
 TOL_GMM_BF16 = 2e-2
 TOL_GMM_INT8 = 5e-2
 #: tp=4 vs tp=1 first-step logits, max|Δ| / max|logit|: row-parallel

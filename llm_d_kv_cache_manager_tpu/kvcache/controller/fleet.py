@@ -33,7 +33,7 @@ controller, starts no thread, and every pod behaves — and speaks on
 every wire — bit-identically to the legacy fleet. The controller talks
 to its fleet through the small ``FleetAdapter`` surface below, so the
 decision logic is identical whether the pods are in-process
-(``InProcessFleet``: tests, bench, single-host) or a deployment
+(``InProcessFleet``: tests, single-host) or a deployment
 environment's replica set.
 """
 
@@ -187,8 +187,8 @@ def fleet_burn(pods: list[PodSignals]) -> Optional[float]:
 class FleetController:
     """The reconcile loop: observe → decide → act, with hysteresis.
 
-    ``reconcile()`` is one synchronous pass (what the tests and the bench
-    co-sim drive directly); ``start()`` runs it on a daemon thread every
+    ``reconcile()`` is one synchronous pass (what the tests drive
+    directly); ``start()`` runs it on a daemon thread every
     ``reconcile_interval_s``. ``flight`` (an ``obs.flight.FlightRecorder``,
     optional) receives one ``scale_up``/``scale_down`` event per scaling
     action — the postmortem trail for "why did the fleet resize".

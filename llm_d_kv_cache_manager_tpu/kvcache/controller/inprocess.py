@@ -1,6 +1,6 @@
 """``FleetAdapter`` over real in-process ``PodServer``s.
 
-The deployment surface the chaos tests and the bench co-sim drive — and
+The deployment surface the chaos tests drive — and
 the single-host answer for real: one process owns N pods (one per
 accelerator slice), and the controller resizes that set. Everything the
 controller needs already exists on ``PodServer``: signals come from the

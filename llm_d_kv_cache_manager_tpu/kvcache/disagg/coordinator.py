@@ -30,7 +30,7 @@ whole request is ONE trace: ``disagg.request`` parents both pods'
 gap between the prefill pod's first token and the decode admission.
 
 This coordinator runs in-process over ``PodServer`` objects (the form
-the tests, chaos harness, and bench fleet use). An HTTP deployment
+the tests and the chaos harness use). An HTTP deployment
 embeds the same logic at the router: the planner inputs are all carried
 by heartbeats and ``/stats``, and both hops are plain ``/v1/completions``
 calls (the decode hop adding ``X-Pull-Source``).
@@ -114,7 +114,7 @@ def views_from_pods(pods: Dict[str, "object"]) -> list[PodView]:
 class DisaggCoordinator:
     """Serving-plane driver for two-hop (prefill pod → decode pod)
     requests, with single-pod fallback. Thread-safe: ``generate`` may be
-    called concurrently (bench load generators, chaos harness)."""
+    called concurrently (load generators, chaos harness)."""
 
     def __init__(
         self,

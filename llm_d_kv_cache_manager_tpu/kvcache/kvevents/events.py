@@ -42,7 +42,7 @@ Disaggregated serving extensions (ISSUE 9; on the wire only when
   a prefill-role pod finished a request's ingest (stopped at the first
   token) and the prompt's block chain is registered and exportable over
   the transfer fabric. The handoff itself rides the serving plane; this
-  event lets the fleet (and the bench/chaos harnesses) observe handoff
+  event lets the fleet (and the chaos harness) observe handoff
   supply without polling pods, and proves liveness like any message.
 
 Remote-tier extension (ISSUE 13; on the wire only when a pod sets

@@ -1,11 +1,11 @@
 """Predicted-TTFT routing model: route on modeled latency, not score-max.
 
-BENCH_r03-r11 showed warmth-first routing hitting a ceiling the audit
-plane (PR 10) made legible: once every pod holds *some* warmth, the
+The design argument (not a chip measurement; the CPU co-simulations it
+came from were retired in PR 30): once every pod holds *some* warmth, the
 residual TTFT is QUEUE time, and a router that always picks the warmest
-pod piles requests onto it — paying more in queue delay than the cache
-hits save (the r11 blended headline went NEGATIVE vs round-robin on the
-saturated ramp). Every input the fix needs already rides the PR 3/4/9
+pod piles requests onto it — it can pay more in queue delay than the cache
+hits save. Whether it does on a real fleet is open (ROADMAP.md S7: no
+cell turns `ROUTE_PREDICT` on). Every input already rides the PR 3/4/9
 heartbeats and in-process telemetry: per-pod queue depth, the engine's
 measured prefill-rate EMA, and draining/admission state.
 
@@ -119,7 +119,7 @@ class TTFTPredictorConfig:
     #: the fleet's heartbeat cadence; signals older than
     #: ``staleness_factor x heartbeat_interval_s`` decay to conservative
     #: defaults. 0 (default) = signals are live attribute reads, never
-    #: stale (the in-process co-sim / single-binary case)
+    #: stale (the in-process / single-binary case)
     heartbeat_interval_s: float = 0.0
     #: staleness multiple of the heartbeat interval (2 = one missed beat
     #: plus slack — the satellite contract)

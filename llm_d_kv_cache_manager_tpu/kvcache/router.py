@@ -22,8 +22,8 @@ not measured).
    is cold;
 3. **load** — fewest outstanding requests, supplied by the caller.
 
-Measured at a thrash-sized pool: p90 TTFT 2.51 s vs 5.66 s for pure
-index routing, and −17 % vs the strongest index-free baseline.
+That account is a CPU co-simulation's (records retired in PR 30): none of
+its timings is a result; ROADMAP.md S7 and W3 own the question on the chip.
 
 Routing toward warmth has a hard limit this module hit in round 4: when
 the warmest pod is overloaded (or a replica joins cold), the best options
@@ -52,7 +52,7 @@ class PrefixAffinityTracker:
     plausibly still hold it" WITHOUT observing KV events: capacity should
     approximate the pod's pool (HBM pages + host-tier slots, in blocks);
     an optional TTL additionally expires stale affinity. This is also the
-    strongest index-free comparator (``bench.py``'s ``estimated`` policy).
+    strongest index-free comparator (an "estimated" routing policy).
     """
 
     def __init__(

@@ -65,7 +65,7 @@ class RemoteBlockStore:
     """LRU store of demoted KV blocks, keyed by chain hash.
 
     Single-threaded by contract: lives on the engine loop (the pod's
-    push/export staging already serializes there) or a bench arm's
+    push/export staging already serializes there) or a test's
     driver. ``on_events`` receives ``BlockStored``/``BlockRemoved``
     events with ``medium="remote"`` — the holder's locality truth.
     """

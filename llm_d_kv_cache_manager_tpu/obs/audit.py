@@ -50,7 +50,7 @@ just a dashboard.
 
 Wall clock on purpose throughout: event publish timestamps cross the wire
 and are compared across hosts, so the comparison clock must be the same
-wall clock (injectable for tests and the bench's virtual clocks).
+wall clock (injectable: tests hand in virtual clocks).
 """
 
 from __future__ import annotations
@@ -121,7 +121,7 @@ class StalenessTracker:
     (the subscriber-facing edge), ``observe_batch`` when a worker applies
     the batch. Unattached (the default) the pool touches nothing here.
     ``clock`` must be the same wall clock the publishers stamp batches
-    with (``time.time`` in production; the bench injects its virtual
+    with (``time.time`` in production; a test injects its virtual
     clock).
     """
 

@@ -13,7 +13,7 @@ with a bounded delta ring for history and one derived
 
 Two pod-registration modes share one join path:
 
-- **in-process** (product fleets, tests, bench): ``register_pod(name,
+- **in-process** (product fleets, tests): ``register_pod(name,
   fetch=fn)`` where ``fn(path) -> dict | None`` returns the pod's own
   payload for ``/stats`` / ``/debug/mrc`` / ... without HTTP;
 - **HTTP** (deployed fleets): ``register_pod(name, url=base)`` — each

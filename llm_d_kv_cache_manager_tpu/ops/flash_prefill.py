@@ -58,9 +58,9 @@ from ._mosaic import require_tpu_unless_interpret
 _NEG_INF = -1e30
 
 #: default key-block (lane-tiled) and query-block (sublane-tiled) sizes.
-#: (256, 1024) won the on-chip sweep (benchmarking/
-#: bench_flash_prefill_blocks.py) by ~35% over (256, 512): fewer, larger
-#: k-steps amortize per-step overhead and keep the MXU fed.
+#: (256, 1024) comes from a sweep on a rig that is gone (its script was
+#: retired in PR 30) and has no TPU v5e measurement: ROADMAP.md S5. The
+#: reasoning then: fewer, larger k-steps amortize per-step overhead.
 KEY_BLOCK = 1024
 QUERY_BLOCK = 256
 #: cap on bq*group score rows — bounds the [rows, bk] f32 score tile and

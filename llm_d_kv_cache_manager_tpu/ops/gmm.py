@@ -40,12 +40,12 @@ from jax.experimental.pallas import tpu as pltpu
 from ..models.quant import QuantizedTensor
 from ._mosaic import require_tpu_unless_interpret
 
-#: (tm, tk, tn) tile-size ceilings, from the on-chip sweep at Qwen3-30B
-#: geometry (128 experts, d=2048, f=768, 16k rows): 256-row tiles balance
+#: (tm, tk, tn) tile-size ceilings, from a sweep on a rig that is gone, at
+#: Qwen3-30B geometry (128 experts, d=2048, f=768, 16k rows); it has no TPU
+#: v5e measurement (ROADMAP.md S5). The reasoning then: 256-row tiles balance
 #: boundary-visit waste (visits ≈ max(m_tiles, nonempty groups) whatever
-#: tm is) against MXU pipeline depth, and large tk/tn cut grid steps —
-#: (256,1024,768) measured 5.0 ms vs 7.1 ms at (256,512,512) and 7.5 ms
-#: at (512,512,512) for one 16k-row grouped matmul. Tiles stay well
+#: tm is) against MXU pipeline depth, and large tk/tn cut grid steps
+#: (it beat (256,512,512) and (512,512,512) there). Tiles stay well
 #: under VMEM (rhs tile 1.5 MB bf16).
 DEFAULT_TILING = (256, 1024, 768)
 

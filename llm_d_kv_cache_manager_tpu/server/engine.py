@@ -723,7 +723,7 @@ class Engine:
             )
         # -- remote tier (REMOTE_TIER; off = none of this exists) ----------
         #: demotion payload sink, set by the serving layer (PodServer's
-        #: background pusher) or the bench arm; None drops demotions on
+        #: background pusher) or a test; None drops demotions on
         #: the floor = plain eviction.
         self.on_demotion: Optional[Callable[[list], None]] = None
         #: queued (info, src) demotions, resolved at the page-move flush

@@ -249,8 +249,8 @@ class _ServingMetrics:
             )
             # TTFT/ITL get a denser grid: a full sub-100 ms decade plus
             # 0.15/0.2 splits of the old 0.1–0.25 gap. The default
-            # buckets aliased the CPU-smoke serving regime — the r12
-            # burst-arm p50 (≈ 0.17 s) and the precise/predicted race it
+            # buckets aliased the CPU-smoke serving regime — a burst
+            # arm's p50 (≈ 0.17 s) and the precise/predicted race it
             # decided both lived inside ONE 2.5x-wide bucket, so the
             # quantile estimate moved more with bucket placement than
             # with routing policy. queue/e2e/pull keep the legacy grid.

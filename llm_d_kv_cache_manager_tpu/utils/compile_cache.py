@@ -1,7 +1,7 @@
 """Where the persistent XLA compile cache lives.
 
-Every entry point that compiles (``server.serve.main``, ``bench.py``,
-``chip_smoke.py``) calls :func:`enable_compile_cache` once, before its
+Every entry point that compiles (``server.serve.main``, ``chip_smoke.py``,
+``chipbench/run.py``) calls :func:`enable_compile_cache` once, before its
 first compilation. The directory is part of the cache key's lookup, so it
 must not move between runs: it is either the one the deployment names or
 one fixed path in the checkout — never built from a temp name, a pid or

@@ -8,7 +8,7 @@ write path (msgpack EventBatch → sharded KVEventsPool → block index) and
 the real read path (KVCacheIndexer.score_tokens).
 
 Reference parity: events.go:42 (DataParallelRank), the multi-pod regime of
-benchmarking/37-capacity.
+the reference project's 37-capacity benchmark.
 """
 
 import threading

@@ -3,8 +3,8 @@
 Pins the round-4 scheduling contract: index
 score dominates whenever real KV events exist; routed-affinity memory
 breaks cold ties (load-aware first placement, then sticky); load breaks
-the rest. The tracker is also bench.py's `estimated` comparator, so its
-LRU/TTL semantics are product code, not bench-only logic.
+the rest. The tracker is also the index-free `estimated` comparator, so
+its LRU/TTL semantics are product code, not test-only logic.
 """
 
 

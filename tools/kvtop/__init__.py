@@ -12,7 +12,7 @@ Two data sources, same renderer:
 - ``--url http://scorer:8080`` — poll a deployed scorer's
   ``GET /debug/fleet`` (the scorer must run with ``OBS_FED=1``);
 - an in-process ``FleetFederator`` handed to :func:`fetch_snapshot` —
-  how the tests and bench drive the console without sockets.
+  how the tests drive the console without sockets.
 
 ``python -m tools.kvtop --url ... [--interval 2] [--plain] [--once]``.
 ``--plain`` skips curses (CI/pipes); ``--once`` renders one frame and
