@@ -421,8 +421,11 @@ def kernel_phase(dry: bool) -> dict:
         main_path=False)
 
     # -- prefill: flash_prefill_paged vs the XLA scan ---------------------
+    # The kernel reads its context from the five-dimensional pool in place
+    # (layer 1 of 2 here; every table entry past a row's context names page
+    # 0, which like the other layer holds large values that would show).
     def flash_case(*, b, s, n_q, n_kv, d, ps, max_ctx_pages, ctx_lens,
-                   n_valid, dt, seed):
+                   n_valid, dt, seed, block_length=0):
         def fn():
             rng = np.random.default_rng(seed)
             total = max(b * max_ctx_pages + 1, 2)
@@ -432,16 +435,24 @@ def kernel_phase(dry: bool) -> dict:
             kp = jnp.asarray(rng.standard_normal((total, ps, n_kv, d)), dt)
             vp = jnp.asarray(rng.standard_normal((total, ps, n_kv, d)), dt)
             perm = rng.permutation(total - 1)[: b * max_ctx_pages] + 1
-            bt = jnp.asarray(perm.reshape(b, max_ctx_pages), jnp.int32)
+            bt = perm.reshape(b, max_ctx_pages).astype(np.int32)
             cl = jnp.asarray(ctx_lens, jnp.int32)
             nv = jnp.asarray(n_valid, jnp.int32)
             positions = cl[:, None] + jnp.arange(s)[None, :]
             valid = jnp.arange(s)[None, :] < nv[:, None]
             ref = prefill_with_paged_context(
-                q, k, v, kp, vp, bt, cl, positions=positions, valid=valid
+                q, k, v, kp, vp, jnp.asarray(bt), cl, positions=positions,
+                valid=valid, block_length=block_length,
             )
+            live = (
+                np.arange(max_ctx_pages)[None, :]
+                < -(-np.asarray(ctx_lens) // ps)[:, None]
+            )
+            k5 = jnp.stack([jnp.full_like(kp, 1e4), kp.at[0].set(1e4)])
+            v5 = jnp.stack([jnp.full_like(vp, 1e4), vp.at[0].set(1e4)])
             got = flash_prefill_paged(
-                q, k, v, kp, vp, bt, cl, nv, interpret=interpret
+                q, k, v, k5, v5, jnp.asarray(np.where(live, bt, 0)), cl, nv,
+                interpret=interpret, block_length=block_length, layer=1,
             )
             return _max_err(got, ref, np.asarray(valid)[:, :, None, None])
 
@@ -455,19 +466,35 @@ def kernel_phase(dry: bool) -> dict:
             "gqa-cold": dict(b=2, s=32, n_q=4, n_kv=2, d=16, ps=4,
                              max_ctx_pages=1, ctx_lens=[0, 0],
                              n_valid=[32, 20], dt=jnp.float32, seed=2),
+            "block-warm": dict(b=3, s=4, n_q=8, n_kv=4, d=16, ps=4,
+                               max_ctx_pages=6, ctx_lens=[20, 8, 0],
+                               n_valid=[4, 4, 0], dt=jnp.float32, seed=3,
+                               block_length=4),
         }
         tols = {name: TOL_DRY for name in flash}
     else:
         bf = jnp.bfloat16
         flash = {
-            # Qwen3-32B GQA 64/8: the served warm shape (64-token suffix
-            # over a 2k-token paged context) and the served cold chunk
-            "qwen3-32b-warm": dict(b=8, s=64, n_q=64, n_kv=8, d=128, ps=16,
-                                   max_ctx_pages=128,
-                                   ctx_lens=[2048, 2048, 1234, 0,
-                                             2048, 16, 2047, 2048],
-                                   n_valid=[12, 64, 64, 48, 1, 64, 33, 12],
-                                   dt=bf, seed=1),
+            # Qwen3-32B GQA 64/8, `sessions`' prefill dispatch: 8 rows of a
+            # 128-token chunk over 1k-3k tokens of paged context in a table
+            # of 256 pages, most rows padding
+            "qwen3-32b-sessions": dict(b=8, s=128, n_q=64, n_kv=8, d=128,
+                                       ps=16, max_ctx_pages=256,
+                                       ctx_lens=[3072, 2048, 1024, 0,
+                                                 1040, 16, 4096, 0],
+                                       n_valid=[128, 97, 33, 128, 1, 64, 12, 0],
+                                       dt=bf, seed=1),
+            # SDAR-30B-A3B 32/4, `blockgen`'s forward: 16 lanes of one
+            # block of 4 rows over 128-1536 tokens of context in a table of
+            # 128 pages, block-causal
+            "sdar-30b-a3b-blockgen": dict(b=16, s=4, n_q=32, n_kv=4, d=128,
+                                          ps=16, max_ctx_pages=128,
+                                          ctx_lens=[128, 1536, 516, 0, 1028,
+                                                    2048, 132, 640, 1532, 256,
+                                                    4, 772, 1280, 900, 384, 8],
+                                          n_valid=[4, 4, 4, 4, 4, 4, 0, 4,
+                                                   4, 4, 4, 0, 4, 4, 4, 4],
+                                          dt=bf, seed=7, block_length=4),
             "qwen3-32b-cold": dict(b=2, s=2112, n_q=64, n_kv=8, d=128,
                                    ps=16, max_ctx_pages=1, ctx_lens=[0, 0],
                                    n_valid=[2060, 1536], dt=bf, seed=2),
@@ -479,9 +506,6 @@ def kernel_phase(dry: bool) -> dict:
             "mha": dict(b=2, s=512, n_q=16, n_kv=16, d=128, ps=16,
                         max_ctx_pages=16, ctx_lens=[256, 9],
                         n_valid=[512, 500], dt=bf, seed=4),
-            "mqa": dict(b=2, s=512, n_q=16, n_kv=1, d=128, ps=16,
-                        max_ctx_pages=16, ctx_lens=[100, 256],
-                        n_valid=[512, 512], dt=bf, seed=5),
             "f32": dict(b=2, s=256, n_q=8, n_kv=2, d=128, ps=16,
                         max_ctx_pages=8, ctx_lens=[128, 77],
                         n_valid=[256, 200], dt=jnp.float32, seed=6),

@@ -18,10 +18,12 @@ Design (TPU-first, not a torch translation):
   compiler's decision, not this file's: on a v5e it did not while the
   all-layer scatter had the layer axis in its window (four whole-pool
   copies a program with fewer than 8 KV heads: PERF_LEDGER.jsonl, PR 28).
-  ``_scatter_kv_pages_all_layers`` now indexes flat token rows, and
-  ``tests/test_pool_layout.py`` reads the program compiled for the chip;
-  ``python -m tools.aot_pool_copies`` lists what is left (the per-layer
-  ``k_pages[li]`` slices ``_prefill_body`` hands the prefill kernel).
+  ``_scatter_kv_pages_all_layers`` now indexes flat token rows, both
+  attention kernels take the five-dimensional pool with the layer in their
+  own page addressing (no ``k_pages[li]`` slice on the Pallas path), and
+  ``tests/test_pool_layout.py`` reads the programs compiled for the chip;
+  ``python -m tools.aot_pool_copies`` lists every instruction shaped like
+  the pool or a layer of it.
 - Weights default to bfloat16 (MXU-native); attention/softmax accumulate in
   float32.
 
@@ -223,39 +225,47 @@ def _check_right_padded_mask(ok) -> None:
 
 
 def _flash_prefill_tp(
-    q, k, v, k_pages_l, v_pages_l, block_tables, ctx_lens, n_valid, *,
-    interpret, mesh, block_length=0,
+    q, k, v, k_pages, v_pages, block_tables, ctx_lens, n_valid, *,
+    layer, interpret, mesh, block_length=0,
 ):
     """Pallas flash prefill, head-parallel over the ``tp`` mesh axis.
 
     Same shard_map story as `_paged_attention_tp`: the kernel is a custom
     call GSPMD cannot partition, and attention is embarrassingly parallel
     over heads — each shard runs the kernel on its slice of query/KV heads
-    and its head-slice of the page pool; no collectives (the row-parallel
-    ``wo`` right after carries the reduction).
+    and its head-slice of the five-dimensional page pool (``layer`` picks
+    the layer inside the kernel: no slice of the pool exists outside it);
+    no collectives (the row-parallel ``wo`` right after carries the
+    reduction).
     """
     from ..ops.flash_prefill import flash_prefill_paged
 
-    kernel = functools.partial(
-        flash_prefill_paged, interpret=interpret, block_length=block_length
+    def kernel(q, k, v, k_pages, v_pages, block_tables, ctx_lens, n_valid, layer):
+        return flash_prefill_paged(
+            q, k, v, k_pages, v_pages, block_tables, ctx_lens, n_valid,
+            interpret=interpret, block_length=block_length, layer=layer,
+        )
+
+    # ``layer`` goes in as an operand: every layer of a program then shares
+    # one trace and one lowering of the kernel.
+    operands = (
+        q, k, v, k_pages, v_pages, block_tables, ctx_lens, n_valid,
+        jnp.int32(layer),
     )
     if mesh is None:
-        return kernel(
-            q, k, v, k_pages_l, v_pages_l, block_tables, ctx_lens, n_valid
-        )
+        return kernel(*operands)
     from jax.sharding import PartitionSpec as P
 
+    heads = P(None, None, "tp")
+    pool = P(None, None, None, "tp")
     fn = jax.shard_map(
         kernel,
         mesh=mesh,
-        in_specs=(
-            P(None, None, "tp"), P(None, None, "tp"), P(None, None, "tp"),
-            P(None, None, "tp"), P(None, None, "tp"), P(), P(), P(),
-        ),
-        out_specs=P(None, None, "tp"),
+        in_specs=(heads, heads, heads, pool, pool, P(), P(), P(), P()),
+        out_specs=heads,
         check_vma=False,
     )
-    return fn(q, k, v, k_pages_l, v_pages_l, block_tables, ctx_lens, n_valid)
+    return fn(*operands)
 
 
 Params = dict[str, Any]
@@ -1088,11 +1098,12 @@ def _prefill_body(
                 positions, valid, mesh,
             )
         elif attn_impl == "pallas":
-            # Flash kernel (ops/flash_prefill.py). Engine contract:
-            # consecutive chunk positions, right-padded valid mask.
+            # Flash kernel (ops/flash_prefill.py), which reads the whole
+            # pools' pages where they lie. Engine contract: consecutive
+            # chunk positions, right-padded valid mask.
             attn = _flash_prefill_tp(
-                q, k, v, k_pages[li], v_pages[li], block_tables, ctx_lens,
-                n_valid, interpret=interpret, mesh=mesh,
+                q, k, v, k_pages, v_pages, block_tables, ctx_lens,
+                n_valid, layer=li, interpret=interpret, mesh=mesh,
                 block_length=cfg.block_length,
             )
         else:

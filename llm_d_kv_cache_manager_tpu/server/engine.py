@@ -565,21 +565,26 @@ class Engine:
         self.spec_stats = {
             "proposed": 0, "accepted": 0, "verify_steps": 0, "bursts": 0,
         }
-        # ONE stated rule, never the backend: the flash kernel serves
-        # prefill unless the engine was told to interpret (CPU tests take
-        # the XLA scan — the interpreted kernel is minutes per chunk) or
-        # the pool is int8 (the kernel reads pages full-width).
-        self.prefill_attn = config.prefill_attn
-        if self.prefill_attn == "auto":
-            self.prefill_attn = (
-                "xla"
-                if config.interpret or config.kv_quant_hbm is not None
-                else "pallas"
-            )
         if cfg.n_heads % config.tp or cfg.n_kv_heads % config.tp:
             raise ValueError(
                 f"tp={config.tp} must divide n_heads={cfg.n_heads} and "
                 f"n_kv_heads={cfg.n_kv_heads}"
+            )
+        # ONE stated rule, never the backend: the flash kernel serves
+        # prefill unless the engine was told to interpret (CPU tests take
+        # the XLA scan — the interpreted kernel is minutes per chunk), the
+        # pool is int8 (the kernel reads pages full-width), or a shard
+        # holds ONE KV head (tp = n_kv_heads: Mosaic copies no page tile
+        # out of a 16-bit pool tiled two rows deep over an axis of one).
+        # (Pinned to "pallas" there, the kernel's wrapper refuses by name.)
+        self.prefill_attn = config.prefill_attn
+        if self.prefill_attn == "auto":
+            self.prefill_attn = (
+                "xla"
+                if config.interpret
+                or config.kv_quant_hbm is not None
+                or cfg.n_kv_heads // config.tp == 1
+                else "pallas"
             )
         if config.sp > 1 and config.prefill_bucket % config.sp:
             raise ValueError(

@@ -1,6 +1,6 @@
-"""The KV pool is written in the layout it is kept in.
+"""The KV pool is written in the layout it is kept in, and read where it lies.
 
-Two halves:
+Three parts:
 
 - **What the chip's compiler makes of the write** (``TestCompiledForTheChip``):
   ``_scatter_kv_pages_all_layers`` is compiled for a DESCRIBED v5e chip (the
@@ -14,11 +14,21 @@ Two halves:
   test and no docstring could see. Every case loads libtpu, which one process
   at a time may hold: they all stay in this one file (one xdist worker) and
   the topology is described in a fixture, never at import.
-- **What it writes** (``TestValues``, CPU): the flat-row scatter against the
-  five-dimensional expression it replaced.
+- **What it makes of the read** (``TestTheContextIsReadInPlace``): the prefill
+  and block-attention kernel's call, as ``_prefill_body`` makes it for one
+  layer of the five-dimensional pool, at both cells' shapes and under ``tp``.
+  Until PR 31 the caller sliced the pool by layer, gathered every table page
+  and copied the gather head-major (``fusion_bf16_1_4096_16_4_128_``,
+  ``fusion_bf16_2048_16_4_128_``, ``copy_bitcast_fusion_bf16_16_4_2048_128_``
+  and two more: 1.33 s of a 4.26 s window in ``blockgen``, PERF_LEDGER.jsonl,
+  PR 30); the kernel now lowers for the chip (Mosaic included) with nothing
+  of those shapes beside it.
+- **What the write writes** (``TestValues``, CPU): the flat-row scatter
+  against the five-dimensional expression it replaced.
 """
 
 import faulthandler
+import re
 
 import jax
 import jax.numpy as jnp
@@ -26,6 +36,7 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
+from llm_d_kv_cache_manager_tpu.models import llama
 from llm_d_kv_cache_manager_tpu.models.llama import _scatter_kv_pages_all_layers
 from llm_d_kv_cache_manager_tpu.parallel.sharding import kv_pages_sharding
 from tools import aot_pool_copies
@@ -140,6 +151,95 @@ class TestCompiledForTheChip:
         assert not [name for _, name, op, _ in everything if op.startswith(collectives)]
 
 
+def _elements(result):
+    """The most elements any array of a printed result type holds (a tuple's
+    members one by one)."""
+    return max(
+        (int(np.prod([int(n) for n in dims.split(",")]))
+         for dims in re.findall(r"\w+\[([\d,]+)\]", result)),
+        default=0,
+    )
+
+
+# (pool shape, (lanes, query rows), table pages, block length, tp): the two
+# cells' calls — `blockgen`'s forward, 16 lanes x one block of 4 rows at 4 KV
+# heads over a table of 128 pages, and `sessions`' prefill dispatch, 8 rows x
+# a 128-token chunk at 8 KV heads over a table of 256 pages — and each under
+# a `tp` mesh that leaves a shard two KV heads.
+_READS = [
+    pytest.param(_MOE_POOL, (16, 4), 128, 4, 1, id="kv4-block4x16"),
+    pytest.param(_DENSE_POOL, (8, 128), 256, 0, 1, id="kv8-chunk128x8"),
+    pytest.param(_MOE_POOL, (16, 4), 128, 4, 2, id="kv4-tp2-block4x16"),
+    pytest.param(_DENSE_POOL, (8, 128), 256, 0, 4, id="kv8-tp4-chunk128x8"),
+]
+
+
+class TestTheContextIsReadInPlace:
+    def _compile(self, topo, pool_shape, rows, table_pages, block_length, tp):
+        mesh = Mesh(np.array(topo.devices[:tp]).reshape(1, tp), ("dp", "tp"))
+        replicated = NamedSharding(mesh, P())
+        heads = NamedSharding(mesh, P(None, None, "tp"))
+        L, _, _, n_kv, hd = pool_shape
+        b, s = rows
+
+        def shaped(shape, dtype, sharding=replicated):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+        def one_layer(q, k, v, k_pages, v_pages, block_tables, ctx_lens, n_valid):
+            # the call `_prefill_body` makes for its last layer
+            return llama._flash_prefill_tp(
+                q, k, v, k_pages, v_pages, block_tables, ctx_lens, n_valid,
+                layer=L - 1, interpret=False, mesh=mesh if tp > 1 else None,
+                block_length=block_length,
+            )
+
+        pool = shaped(pool_shape, jnp.bfloat16, kv_pages_sharding(mesh))
+        return aot_pool_copies.compile_text(
+            jax.jit(one_layer),
+            shaped((b, s, 8 * n_kv, hd), jnp.bfloat16, heads),
+            shaped((b, s, n_kv, hd), jnp.bfloat16, heads),
+            shaped((b, s, n_kv, hd), jnp.bfloat16, heads),
+            pool, pool,
+            shaped((b, table_pages), jnp.int32),
+            shaped((b,), jnp.int32), shaped((b,), jnp.int32),
+        )
+
+    @pytest.mark.parametrize("pool_shape, rows, table_pages, block_length, tp", _READS)
+    def test_no_slice_of_the_pool_and_no_gathered_context(
+        self, topo, pool_shape, rows, table_pages, block_length, tp
+    ):
+        hlo = self._compile(topo, pool_shape, rows, table_pages, block_length, tp)
+        _, total_pages, page, n_kv, hd = pool_shape
+        per_device = (*pool_shape[:3], n_kv // tp, hd)
+        found = aot_pool_copies.pool_instructions(hlo, per_device, layer_slices=True)
+        assert found, "the pool is nowhere in the module: the reader is blind"
+        # The pool comes in as a parameter and goes to the kernel as it is:
+        # nothing shaped like it, or like one layer of it, moves bytes.
+        assert [(i.opcode, i.name, i.result) for i in found if i.moves_bytes] == []
+        # A context gathered for the kernel (all of a row's table pages, in
+        # whatever order of axes) is the largest array such a program could
+        # make, and the smallest that is too large: the queries, the fresh
+        # keys and values and the output are 4 to 64 times smaller.
+        gathered = rows[0] * table_pages * page * (n_kv // tp) * hd
+        everything = list(aot_pool_copies.instructions(hlo))
+        large = [
+            (op, name, result) for _, name, op, result in everything
+            if op not in aot_pool_copies.FREE and _elements(result) >= gathered
+        ]
+        assert large == [], "something of a gathered context's size is made"
+        kernels = [name for _, name, op, _ in everything if op == "custom-call"]
+        assert len(kernels) == 1, kernels
+        collectives = ("all-gather", "all-reduce", "all-to-all", "collective-permute")
+        assert not [name for _, name, op, _ in everything if op.startswith(collectives)]
+
+    def test_one_kv_head_a_shard_is_refused_by_name(self, topo):
+        # tp = n_kv_heads: a 16-bit pool is tiled two rows deep over an axis
+        # of one and Mosaic cuts no page tile out of it. The wrapper says so
+        # instead of Mosaic, and the engine's rule takes the XLA prefill.
+        with pytest.raises(NotImplementedError, match="one KV head a shard"):
+            self._compile(topo, _MOE_POOL, (16, 4), 128, 4, 4)
+
+
 _SERVED = [
     ("qwen3-30b-a3b", "decode_steps"),
     ("qwen3-30b-a3b", "prefill"),
@@ -154,19 +254,25 @@ _SERVED = [
 class TestServedPrograms:
     """The whole served programs at the cells' shapes, as
     ``python -m tools.aot_pool_copies`` compiles them: inside a whole
-    program another consumer can ask for another layout than the helper
-    alone gets. The per-layer slices ``_prefill_body`` makes are allowed
-    (ROADMAP S2, open): only a whole pool is held here."""
+    program another consumer can ask for another layout than a helper or a
+    kernel alone gets. Nothing shaped like the pool, or like one layer of
+    it, may move bytes (until PR 31 ``prefill`` and ``denoise_steps`` sliced
+    both pools by layer for the prefill kernel)."""
 
     @pytest.mark.parametrize("config, program", _SERVED)
-    def test_no_whole_pool_copy(self, topo, config, program):
+    def test_no_copy_of_the_pool_or_of_a_layer_of_it(self, topo, config, program):
         one_chip = SingleDeviceSharding(topo.devices[0])
         fn, args, kwargs, pool_shape = aot_pool_copies.served_program(
             config, program, one_chip
         )
         hlo = aot_pool_copies.compile_text(fn, *args, **kwargs)
-        assert aot_pool_copies.pool_instructions(hlo, pool_shape)
-        assert _whole_pool_moves(hlo, pool_shape) == []
+        found = aot_pool_copies.pool_instructions(hlo, pool_shape, layer_slices=True)
+        assert found
+        inside = aot_pool_copies.fusion_opcodes(hlo)
+        assert [
+            (i.opcode, i.name, i.result) for i in found
+            if i.moves_bytes and "scatter" not in inside.get(i.name, ())
+        ] == []
 
     def test_a_program_the_configuration_does_not_serve(self, topo):
         one_chip = SingleDeviceSharding(topo.devices[0])

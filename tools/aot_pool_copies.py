@@ -12,8 +12,10 @@ A pool is hundreds of MiB: any such instruction that is not free (a
 ``bitcast``, a ``parameter``, tuple plumbing) reads and writes that much
 HBM each time the program runs. PR 29 found four ``copy`` of the whole
 pool in every MoE program this way (the scatter's window held the layer
-axis); the per-layer slices ``_prefill_body`` hands the prefill kernel are
-what is left (ROADMAP S2).
+axis), and PR 31 the per-layer slices ``_prefill_body`` handed the prefill
+kernel (it now takes the five-dimensional pool): no served program of the
+cells lists an instruction that moves bytes, and
+``tests/test_pool_layout.py`` holds that.
 
     python -m tools.aot_pool_copies                      # every program
     python -m tools.aot_pool_copies --config qwen3-32b --program prefill
