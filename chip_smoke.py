@@ -298,6 +298,7 @@ def _max_err(got, ref, mask=None) -> float:
 def kernel_phase(dry: bool) -> dict:
     """Every kernel case runs (one failure must not hide the next); the
     phase fails if any case failed."""
+    import jax
     import jax.numpy as jnp
     import numpy as np
 
@@ -515,6 +516,67 @@ def kernel_phase(dry: bool) -> dict:
     for name, kw in flash.items():
         run(f"flash_prefill_paged[{name}]", flash_case(**kw), tols[name],
             main_path=True)
+
+    # -- latent (MLA) attention: mla_decode / mla_prefill over the pool in
+    # place, against the jax.numpy oracle, at the docqa cell's shapes -------
+    from llm_d_kv_cache_manager_tpu.ops.mla_attention import (
+        mla_paged_attention,
+        mla_paged_attention_reference,
+    )
+
+    def mla_case(*, b, s, heads, dk, dv, ps, width, ctx_lens, n_valid, dt, seed):
+        def fn():
+            rng = np.random.default_rng(seed)
+            pages = sum(-(-c // ps) for c in ctx_lens) + 1
+            pool = rng.standard_normal((2, pages, ps, dk)).astype(np.float32)
+            pool[..., dv + (dk - dv) // 2:] = 0.0  # a row's padding
+            pool[:, 0] = 1e4  # the dead tail of every table points here
+            pool[0] *= 1e3  # another layer's rows would be seen
+            bt = np.zeros((b, width), np.int32)
+            order, at = rng.permutation(np.arange(1, pages)), 0
+            for i, c in enumerate(ctx_lens):
+                n = -(-c // ps)
+                bt[i, :n] = order[at:at + n]
+                at += n
+            q = jnp.asarray(rng.standard_normal((b, s, heads, dk)), dt)
+            fresh = jnp.asarray(rng.standard_normal((b, s, dk)), dt)
+            pool = jnp.asarray(pool, dt)
+            args = (jnp.asarray(bt), jnp.asarray(ctx_lens, jnp.int32),
+                    jnp.asarray(n_valid, jnp.int32))
+            scale = (dk * 0.3) ** -0.5
+            got = mla_paged_attention(
+                q, fresh, pool, *args, dv=dv, scale=scale,
+                interpret=interpret, layer=jnp.int32(1),
+            )
+            with jax.default_matmul_precision("highest"):
+                ref = mla_paged_attention_reference(
+                    q, fresh, pool[1], *args, dv=dv, scale=scale)
+            return _max_err(got, ref)
+
+        return fn
+
+    if dry:
+        mla = {
+            "decode": dict(b=4, s=1, heads=4, dk=128, dv=32, ps=4, width=24,
+                           ctx_lens=[9, 90, 0, 37], n_valid=[1, 1, 0, 1],
+                           dt=jnp.float32, seed=7),
+            "question": dict(b=2, s=20, heads=4, dk=128, dv=32, ps=4, width=16,
+                             ctx_lens=[40, 0], n_valid=[20, 7],
+                             dt=jnp.float32, seed=8),
+        }
+    else:  # kanana-2-30b-a3b: 32 heads over rows of 576 values held in 640
+        mla = {
+            "decode": dict(b=32, s=1, heads=32, dk=640, dv=512, ps=16,
+                           width=2048,
+                           ctx_lens=[128, 12288, 28700, 0] * 8,
+                           n_valid=[1, 1, 1, 0] * 8, dt=jnp.bfloat16, seed=7),
+            "question": dict(b=2, s=128, heads=32, dk=640, dv=512, ps=16,
+                             width=1792, ctx_lens=[28672, 12288],
+                             n_valid=[128, 77], dt=jnp.bfloat16, seed=8),
+        }
+    for name, kw in mla.items():
+        run(f"mla_paged_attention[{name}]", mla_case(**kw),
+            TOL_DRY if dry else TOL_ATTN_BF16, main_path=True)
 
     # -- MoE: grouped_matmul vs ragged_dot at one Qwen3-30B-A3B layer -----
     if dry:

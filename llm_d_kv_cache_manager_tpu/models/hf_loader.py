@@ -1,6 +1,6 @@
 """HuggingFace → native parameter conversion for Llama-family checkpoints.
 
-Maps a transformers Llama/Qwen2/Qwen3/Mixtral state dict onto the pytree layout of
+Maps a transformers Llama/Qwen2/Qwen3/Mixtral/DeepSeek-V3 state dict onto the pytree layout of
 ``models/llama.py``. torch ``Linear`` stores ``[out, in]`` and computes
 ``x @ W.T``; our params store ``[in, out]``, so every projection transposes.
 The RoPE convention (half-split rotate) matches HF Llama, so no permutation
@@ -35,22 +35,42 @@ def load_hf_state_dict(
     def linear(name: str) -> jnp.ndarray:
         return jnp.asarray(get(name).T, cfg.dtype)  # [out,in] -> [in,out]
 
+    def stacked(prefix: str, name: str) -> jnp.ndarray:
+        return jnp.stack(
+            [linear(f"{prefix}experts.{j}.{name}") for j in range(cfg.n_experts)]
+        )
+
     layers = []
     for i in range(cfg.n_layers):
         p = f"model.layers.{i}."
         layer = {
             "attn_norm": jnp.asarray(get(p + "input_layernorm.weight"), cfg.dtype),
             "wq": linear(p + "self_attn.q_proj.weight"),
-            "wk": linear(p + "self_attn.k_proj.weight"),
-            "wv": linear(p + "self_attn.v_proj.weight"),
             "wo": linear(p + "self_attn.o_proj.weight"),
             "mlp_norm": jnp.asarray(get(p + "post_attention_layernorm.weight"), cfg.dtype),
         }
-        if cfg.n_experts:
+        if cfg.kv_lora_rank:
+            # DeepSeek-V3's latent attention: the down-projection carries
+            # the shared rope key (``_with_mqa``), its norm is the latent's
+            # alone; ``q_proj`` and ``kv_b_proj`` are laid out a head as
+            # ``[nope | rope]`` and ``[k_nope | v]``, as ``_mla_project``
+            # and ``_mla_kvb`` split them.
+            layer["wkv_a"] = linear(p + "self_attn.kv_a_proj_with_mqa.weight")
+            layer["kv_norm"] = jnp.asarray(
+                get(p + "self_attn.kv_a_layernorm.weight"), cfg.dtype
+            )
+            layer["wkv_b"] = linear(p + "self_attn.kv_b_proj.weight")
+        else:
+            layer["wk"] = linear(p + "self_attn.k_proj.weight")
+            layer["wv"] = linear(p + "self_attn.v_proj.weight")
+        if cfg.n_experts and i >= cfg.first_k_dense:
             # Expert weights stacked to [E, d, f] / [E, f, d] for the
-            # masked-dense expert einsum. Two checkpoint namings:
+            # masked-dense expert einsum. Three checkpoint namings:
             # - Mixtral: block_sparse_moe.gate + experts.j.{w1,w3,w2}
             # - Qwen3-MoE: mlp.gate + mlp.experts.j.{gate,up,down}_proj
+            # - DeepSeek-V3: Qwen3-MoE's names, and beside them the router's
+            #   correction bias (float32, never cast) and
+            #   mlp.shared_experts.{gate,up,down}_proj
             if f"{p}block_sparse_moe.gate.weight" in sd:
                 moe = p + "block_sparse_moe."
                 names = ("w1.weight", "w3.weight", "w2.weight")
@@ -58,15 +78,16 @@ def load_hf_state_dict(
                 moe = p + "mlp."
                 names = ("gate_proj.weight", "up_proj.weight", "down_proj.weight")
             layer["router"] = linear(moe + "gate.weight")
-            layer["w_gate"] = jnp.stack(
-                [linear(f"{moe}experts.{j}.{names[0]}") for j in range(cfg.n_experts)]
-            )
-            layer["w_up"] = jnp.stack(
-                [linear(f"{moe}experts.{j}.{names[1]}") for j in range(cfg.n_experts)]
-            )
-            layer["w_down"] = jnp.stack(
-                [linear(f"{moe}experts.{j}.{names[2]}") for j in range(cfg.n_experts)]
-            )
+            layer["w_gate"] = stacked(moe, names[0])
+            layer["w_up"] = stacked(moe, names[1])
+            layer["w_down"] = stacked(moe, names[2])
+            if cfg.moe_scoring == "sigmoid":
+                layer["router_bias"] = jnp.asarray(
+                    get(moe + "gate.e_score_correction_bias"), jnp.float32
+                )
+            if cfg.n_shared_experts:
+                for ours, theirs in zip(("gate", "up", "down"), names):
+                    layer[f"ws_{ours}"] = linear(f"{moe}shared_experts.{theirs}")
         else:
             layer["w_gate"] = linear(p + "mlp.gate_proj.weight")
             layer["w_up"] = linear(p + "mlp.up_proj.weight")
@@ -127,6 +148,9 @@ def config_from_hf(hf_config) -> LlamaConfig:
     # published config gives neither block length nor mask id: the
     # family's released generation script's (4, 151669) unless it does.
     is_sdar = getattr(hf_config, "model_type", "") == "sdar_moe"
+    latent = {}
+    if getattr(hf_config, "model_type", "") == "deepseek_v3":
+        latent = _deepseek_v3_fields(hf_config)
     hidden_act = getattr(hf_config, "hidden_activation", None) or getattr(
         hf_config, "hidden_act", "silu"
     )
@@ -148,7 +172,8 @@ def config_from_hf(hf_config) -> LlamaConfig:
         qk_norm=hf_config.__class__.__name__.startswith("Qwen3") or is_sdar,
         tie_word_embeddings=getattr(hf_config, "tie_word_embeddings", False),
         n_experts=getattr(hf_config, "num_local_experts", 0)
-        or getattr(hf_config, "num_experts", 0),
+        or getattr(hf_config, "num_experts", 0)
+        or getattr(hf_config, "n_routed_experts", 0),
         n_experts_per_tok=getattr(hf_config, "num_experts_per_tok", 2),
         moe_intermediate_size=getattr(hf_config, "moe_intermediate_size", None),
         norm_topk_prob=getattr(hf_config, "norm_topk_prob", True),
@@ -161,6 +186,7 @@ def config_from_hf(hf_config) -> LlamaConfig:
         mask_token_id=(
             getattr(hf_config, "mask_token_id", 151_669) if is_sdar else 0
         ),
+        **latent,
     )
     cfg.act_fn  # raises ValueError for unsupported activations
     # Qwen3-MoE variants with partially-dense layers change the layer
@@ -182,3 +208,45 @@ def config_from_hf(hf_config) -> LlamaConfig:
                 "shared-expert MoE (Qwen2-MoE style) is not supported"
             )
     return cfg
+
+
+def _deepseek_v3_fields(hf_config) -> dict:
+    """The fields of a ``deepseek_v3`` config that ``LlamaConfig`` carries
+    beyond the common ones: latent attention, sigmoid routing with a
+    correction bias, shared experts, leading dense layers. What the program
+    does not run is refused here by name, not loaded as something else."""
+    def has(key, default=None):
+        return getattr(hf_config, key, default)
+
+    if has("q_lora_rank") is not None:
+        raise NotImplementedError(
+            f"q_lora_rank={has('q_lora_rank')}: a low-rank query path is "
+            "not supported yet (q_lora_rank must be null)"
+        )
+    if has("n_group", 1) != 1 or has("topk_group", 1) != 1:
+        raise NotImplementedError(
+            f"group-limited routing is not supported yet (n_group="
+            f"{has('n_group')}, topk_group={has('topk_group')})"
+        )
+    if has("scoring_func", "sigmoid") != "sigmoid" or has(
+        "topk_method", "noaux_tc"
+    ) != "noaux_tc":
+        raise NotImplementedError(
+            f"scoring_func={has('scoring_func')!r} with topk_method="
+            f"{has('topk_method')!r} is not supported yet (sigmoid, noaux_tc)"
+        )
+    if has("moe_layer_freq", 1) != 1:
+        raise NotImplementedError(
+            f"moe_layer_freq={has('moe_layer_freq')} is not supported yet"
+        )
+    return dict(
+        kv_lora_rank=hf_config.kv_lora_rank,
+        qk_nope_head_dim=hf_config.qk_nope_head_dim,
+        qk_rope_head_dim=hf_config.qk_rope_head_dim,
+        v_head_dim=hf_config.v_head_dim,
+        rope_interleave=bool(has("rope_interleave", True)),
+        n_shared_experts=has("n_shared_experts", 0) or 0,
+        moe_scoring="sigmoid",
+        routed_scaling_factor=float(has("routed_scaling_factor", 1.0)),
+        first_k_dense=has("first_k_dense_replace", 0),
+    )

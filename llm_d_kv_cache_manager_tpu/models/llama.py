@@ -33,7 +33,13 @@ Mixtral-style sparse-MoE decoders (``n_experts > 0``: softmax-top-k routed
 SwiGLU experts replacing the dense FFN; attention/KV paths are identical,
 so paged serving and prefix-cache routing work unchanged), and the Gemma
 family (gated-GELU FFN, ``(1+w)`` RMSNorm scaling, sqrt(d)-scaled tied
-embeddings, decoupled head_dim).
+embeddings, decoupled head_dim), and DeepSeek-V3-style decoders
+(``kv_lora_rank > 0``: latent attention over ONE pool of latent rows
+``[n_layers, total_pages, page_size, row]`` and no value pool, absorbed
+for decode and warm prefill, ``ops/mla_attention.py``; shared experts, a
+sigmoid router with a correction bias, leading dense layers). The page
+manager, KV events and routing know tokens, not heads, and serve all of
+them alike.
 """
 
 from __future__ import annotations
@@ -320,11 +326,63 @@ class LlamaConfig:
     # picks that path from this field alone.
     block_length: int = 0
     mask_token_id: int = 0
+    # Latent attention (MLA, DeepSeek-V2/V3 style): ``kv_lora_rank`` > 0
+    # replaces per-head keys and values by ONE row a token a layer, the
+    # normed latent (``kv_lora_rank`` wide) beside a rotated key shared by
+    # every head (``qk_rope_head_dim``). The pool holds that row and
+    # nothing else (``kv_row_shape``; no value pool), decode attends in the
+    # absorbed form over it (``ops/mla_attention.py``). 0 = the attention
+    # above. ``head_dim`` / ``n_kv_heads`` keep what the published config
+    # says and size nothing of a latent pool. ``q_lora_rank`` is carried
+    # for the loader's refusal: a low-rank query path is not run.
+    kv_lora_rank: int = 0
+    q_lora_rank: Optional[int] = None
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # rotate the pairs (2i, 2i+1) (the published code de-interleaves, then
+    # rotates halves: the same scores); False = halves, as everywhere else
+    rope_interleave: bool = False
+    # Expert layers beyond softmax top-k (DeepSeek-V3 style): shared
+    # experts every token takes (one SwiGLU of n_shared_experts x
+    # moe_inter), the router's scoring function ("softmax" | "sigmoid":
+    # with "sigmoid" a per-expert correction bias chooses the experts and
+    # does not weigh them), a factor on the routed sum, group-limited
+    # routing (only n_group = topk_group = 1 is run), and the number of
+    # leading layers whose FFN is the dense one. ``first_k_dense`` decides
+    # only which parameters ``init_params`` and the loader make: a layer's
+    # FFN is read from the layer itself (it has a ``router`` or not).
+    n_shared_experts: int = 0
+    moe_scoring: str = "softmax"
+    routed_scaling_factor: float = 1.0
+    n_group: int = 1
+    topk_group: int = 1
+    first_k_dense: int = 0
     dtype: Any = jnp.bfloat16
 
     @property
     def hd(self) -> int:
         return self.head_dim or self.hidden_size // self.n_heads
+
+    @property
+    def latent_width(self) -> int:
+        """Values of one token's latent row (0: per-head keys and values)."""
+        return self.kv_lora_rank + self.qk_rope_head_dim if self.kv_lora_rank else 0
+
+    @property
+    def kv_row_shape(self) -> tuple:
+        """Minor dimensions of one token's row in a page pool: what every
+        pool, page and wire size follows (never ``n_kv_heads x hd`` alone).
+        A latent row is held in whole tiles of 128 lanes, zeros past its
+        values (576 values in 640): the TPU compiler lays a minor dimension
+        of 576 out as 640 whatever the array says (``bf16[8,16384,16,576]``
+        is ``memref<8x16384x16x640>`` in HBM), and Mosaic cuts no page tile
+        out of a padded one ("slice shape must be aligned to tiling (128)").
+        So the padding costs no byte that was not already there, and the
+        array's own ``nbytes`` is what the device holds."""
+        if self.kv_lora_rank:
+            return (-(-self.latent_width // 128) * 128,)
+        return (self.n_kv_heads, self.hd)
 
     @property
     def moe_inter(self) -> int:
@@ -506,6 +564,64 @@ TINY_SDAR_MOE = dataclasses.replace(
     TINY_QWEN3_MOE, block_length=4, mask_token_id=255
 )
 
+#: kakaocorp/kanana-2-30b-a3b-instruct-2601 (``model_type: deepseek_v3``):
+#: latent attention without a low-rank query path, 128 routed experts top-6
+#: with sigmoid scores and a correction bias, two shared experts, one
+#: leading dense layer. ``head_dim`` 64 and 32 KV heads are the published
+#: file's (its ``head_dim`` is the rope part); the pool is 576 wide.
+KANANA_2_30B_A3B = LlamaConfig(
+    vocab_size=128_256,
+    hidden_size=2_048,
+    intermediate_size=6_144,
+    n_layers=48,
+    n_heads=32,
+    n_kv_heads=32,
+    head_dim=64,
+    rope_theta=1_000_000.0,
+    rms_norm_eps=1e-6,
+    n_experts=128,
+    n_experts_per_tok=6,
+    moe_intermediate_size=768,
+    norm_topk_prob=True,
+    kv_lora_rank=512,
+    qk_nope_head_dim=128,
+    qk_rope_head_dim=64,
+    v_head_dim=128,
+    rope_interleave=True,
+    n_shared_experts=2,
+    moe_scoring="sigmoid",
+    routed_scaling_factor=2.448,
+    first_k_dense=1,
+)
+
+#: Tiny latent-attention MoE (a dense layer, 3 expert layers, 8 experts
+#: top-2, a shared expert, latent 32 + rope 8) for tests / CPU dry-runs.
+TINY_MLA_MOE = LlamaConfig(
+    vocab_size=256,
+    hidden_size=64,
+    intermediate_size=128,
+    n_layers=4,
+    n_heads=4,
+    n_kv_heads=4,
+    head_dim=8,
+    rope_theta=10_000.0,
+    rms_norm_eps=1e-6,
+    n_experts=8,
+    n_experts_per_tok=2,
+    moe_intermediate_size=48,
+    norm_topk_prob=True,
+    kv_lora_rank=32,
+    qk_nope_head_dim=16,
+    qk_rope_head_dim=8,
+    v_head_dim=16,
+    rope_interleave=True,
+    n_shared_experts=1,
+    moe_scoring="sigmoid",
+    routed_scaling_factor=2.448,
+    first_k_dense=1,
+    dtype=jnp.float32,
+)
+
 #: Tiny MoE config (Mixtral-shaped) for tests / CPU dry-runs.
 TINY_MOE = LlamaConfig(
     vocab_size=256,
@@ -560,15 +676,30 @@ def init_params(
     layers = []
     for i in range(cfg.n_layers):
         k = jax.random.split(keys[i], 8)
-        layer = {
-            "attn_norm": norm_init((d,)),
-            "wq": dense(k[0], (d, n_q * hd), d),
-            "wk": dense(k[1], (d, n_kv * hd), d),
-            "wv": dense(k[2], (d, n_kv * hd), d),
-            "wo": dense(k[3], (n_q * hd, d), n_q * hd),
-            "mlp_norm": norm_init((d,)),
-        }
-        if cfg.n_experts:
+        if cfg.kv_lora_rank:
+            dc, dn, dr, dv = (
+                cfg.kv_lora_rank, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                cfg.v_head_dim,
+            )
+            layer = {
+                "attn_norm": norm_init((d,)),
+                "wq": dense(k[0], (d, n_q * (dn + dr)), d),
+                "wkv_a": dense(k[1], (d, dc + dr), d),
+                "kv_norm": norm_init((dc,)),
+                "wkv_b": dense(k[2], (dc, n_q * (dn + dv)), dc),
+                "wo": dense(k[3], (n_q * dv, d), n_q * dv),
+                "mlp_norm": norm_init((d,)),
+            }
+        else:
+            layer = {
+                "attn_norm": norm_init((d,)),
+                "wq": dense(k[0], (d, n_q * hd), d),
+                "wk": dense(k[1], (d, n_kv * hd), d),
+                "wv": dense(k[2], (d, n_kv * hd), d),
+                "wo": dense(k[3], (n_q * hd, d), n_q * hd),
+                "mlp_norm": norm_init((d,)),
+            }
+        if cfg.n_experts and i >= cfg.first_k_dense:
             e, f = cfg.n_experts, cfg.moe_inter
             # Router stays full precision: tiny, and routing decisions are
             # the most quantization-sensitive computation in an MoE.
@@ -576,6 +707,23 @@ def init_params(
             layer["w_gate"] = dense(k[4], (e, d, f), d, quantizable=quantize_experts)
             layer["w_up"] = dense(k[5], (e, d, f), d, quantizable=quantize_experts)
             layer["w_down"] = dense(k[6], (e, f, d), f, quantizable=quantize_experts)
+            # (keys folded in, not split off: the trees of the models
+            # without these parts stay bit for bit what they were)
+            extra = jax.random.split(jax.random.fold_in(keys[i], 1), 4)
+            if cfg.moe_scoring == "sigmoid":
+                # The correction bias chooses the experts and does not weigh
+                # them; float32, not trained by gradient. Drawn here (a
+                # checkpoint's is near zero) with about half the spread of
+                # the scores of a random router, so that a program that
+                # weighs with it, or chooses without it, is not this model.
+                layer["router_bias"] = 0.1 * jax.random.normal(
+                    extra[0], (e,), jnp.float32
+                )
+            if cfg.n_shared_experts:
+                fs = cfg.n_shared_experts * f
+                layer["ws_gate"] = dense(extra[1], (d, fs), d)
+                layer["ws_up"] = dense(extra[2], (d, fs), d)
+                layer["ws_down"] = dense(extra[3], (fs, d), fs)
         else:
             layer["w_gate"] = dense(k[4], (d, inter), d)
             layer["w_up"] = dense(k[5], (d, inter), d)
@@ -608,15 +756,31 @@ def init_kv_pages(
     sharding=None,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Zeroed K and V page pools:
-    ``[n_layers, total_pages, page_size, n_kv_heads, head_dim]``.
+    ``[n_layers, total_pages, page_size, n_kv_heads, head_dim]``; for a
+    latent model (``cfg.kv_lora_rank``) one pool of latent rows
+    ``[n_layers, total_pages, page_size, row]`` and an array of no pages.
 
     With ``kv_quant_hbm="int8"`` the pools hold int8 codes (half the HBM
     bytes per page — 2× pages per chip at the same budget); the matching
     per-page scale pools come from :func:`init_kv_scales`. ``sharding``
     (a ``Sharding`` or ``Device``) creates the pools in place there;
     default: the process default device."""
-    shape = (cfg.n_layers, total_pages, page_size, cfg.n_kv_heads, cfg.hd)
     dtype = jnp.int8 if kv_quant_hbm == "int8" else cfg.dtype
+    if cfg.kv_lora_rank:
+        # One pool of latent rows ``[n_layers, total_pages, page_size,
+        # row]`` (``kv_row_shape``); the second of the pair every signature
+        # carries is an array of no pages: it holds no byte and nothing
+        # reads or writes it.
+        if kv_quant_hbm is not None:
+            raise ValueError("a latent pool has no quantised form")
+        row = cfg.kv_row_shape
+        return (
+            jnp.zeros((cfg.n_layers, total_pages, page_size, *row), dtype,
+                      device=sharding),
+            jnp.zeros((cfg.n_layers, 0, page_size, *row), dtype,
+                      device=sharding),
+        )
+    shape = (cfg.n_layers, total_pages, page_size, cfg.n_kv_heads, cfg.hd)
     return (
         jnp.zeros(shape, dtype, device=sharding),
         jnp.zeros(shape, dtype, device=sharding),
@@ -655,6 +819,89 @@ def _qkv(layer: Params, cfg: LlamaConfig, x: jnp.ndarray):
     return q, k, v
 
 
+# -- latent attention (MLA) ---------------------------------------------------
+def _deinterleave(x: jnp.ndarray) -> jnp.ndarray:
+    """``[x0, x1, x2, ...] -> [x0, x2, ..., x1, x3, ...]`` on the last axis:
+    what the published code does before it rotates halves, so that the pairs
+    ``(2i, 2i+1)`` are the ones rotated (``rope_interleave``). Applied to
+    queries and keys alike, the permutation leaves every score unchanged."""
+    return jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1)
+
+
+def _mla_project(layer: Params, cfg: LlamaConfig, x, positions, inv_freq):
+    """A latent layer's projections of ``x [b, s, d]`` at ``positions``:
+    ``(q_n [b, s, H, d_n], q_r [b, s, H, d_r] rotated, row [b, s, width])``.
+    ``row`` is the token's whole cache entry, ``[RMSNorm(c) | rotated k_r |
+    zeros to the row's width]`` (``kv_row_shape``): after the norm and after
+    the rotation, nothing else is kept of a token."""
+    b, s, _ = x.shape
+    dc, dn, dr = cfg.kv_lora_rank, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    q = (x @ _w(layer["wq"], x.dtype)).reshape(b, s, cfg.n_heads, dn + dr)
+    q_n, q_r = q[..., :dn], q[..., dn:]
+    a = x @ _w(layer["wkv_a"], x.dtype)  # [b, s, dc + dr]
+    c = rms_norm(a[..., :dc], layer["kv_norm"], cfg.rms_norm_eps, cfg.norm_offset)
+    k_r = a[..., None, dc:]  # one key, shared by every head
+    if cfg.rope_interleave:
+        q_r, k_r = _deinterleave(q_r), _deinterleave(k_r)
+    q_r = apply_rope(q_r, positions, inv_freq)
+    k_r = apply_rope(k_r, positions, inv_freq)[:, :, 0]
+    pad = cfg.kv_row_shape[0] - dc - dr
+    row = jnp.concatenate(
+        [c, k_r, jnp.zeros((b, s, pad), c.dtype)], axis=-1
+    )
+    return q_n, q_r, row
+
+
+def _mla_kvb(layer: Params, cfg: LlamaConfig, dtype):
+    """``W_kvb`` split a head: ``(W_kb [d_c, H, d_n], W_vb [d_c, H, d_v])``."""
+    w = _w(layer["wkv_b"], dtype).reshape(
+        cfg.kv_lora_rank, cfg.n_heads, cfg.qk_nope_head_dim + cfg.v_head_dim
+    )
+    return w[..., : cfg.qk_nope_head_dim], w[..., cfg.qk_nope_head_dim :]
+
+
+def _mla_scale(cfg: LlamaConfig) -> float:
+    return (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+
+
+def _mla_absorbed(
+    layer: Params, cfg: LlamaConfig, q_n, q_r, row, pool, block_tables,
+    ctx_lens, n_valid, *, layer_index, interpret: bool, kernel: bool = True,
+) -> jnp.ndarray:
+    """Absorbed form over the pool (``ops/mla_attention.py``): ``q_c = q_n
+    W_kb^T``, every head scores ``[q_c | q_r]`` against the same row ``[c |
+    k_r]`` and sums the rows' latents; ``W_vb`` after. No key or value of a
+    head is ever made for a context token. ``kernel``: the Pallas kernel
+    over the pool in place; else its ``jax.numpy`` oracle over the layer's
+    gathered pages (the ``attn_impl="xla"`` prefill of CPU tests and dry
+    runs: its temporaries grow with rows x context x heads, so it is no
+    serving path at long contexts). Both take the chunk as consecutive
+    positions, right-padded. Returns the heads' outputs ``[b, s, H, d_v]``."""
+    from ..ops.mla_attention import (
+        mla_paged_attention,
+        mla_paged_attention_reference,
+    )
+
+    w_kb, w_vb = _mla_kvb(layer, cfg, q_n.dtype)
+    q_c = jnp.einsum("bshn,chn->bshc", q_n, w_kb)
+    pad = row.shape[-1] - q_c.shape[-1] - q_r.shape[-1]
+    q = jnp.concatenate(
+        [q_c, q_r, jnp.zeros((*q_r.shape[:-1], pad), q_r.dtype)], axis=-1
+    )
+    if kernel:
+        o_c = mla_paged_attention(
+            q, row, pool, block_tables, ctx_lens, n_valid,
+            dv=cfg.kv_lora_rank, scale=_mla_scale(cfg), interpret=interpret,
+            layer=jnp.int32(layer_index),
+        )  # [b, s, H, d_c]
+    else:
+        o_c = mla_paged_attention_reference(
+            q, row, pool[layer_index], block_tables, ctx_lens, n_valid,
+            dv=cfg.kv_lora_rank, scale=_mla_scale(cfg),
+        )
+    return jnp.einsum("bshc,chv->bshv", o_c, w_vb)
+
+
 def _moe_gates(layer: Params, cfg: LlamaConfig, x: jnp.ndarray):
     """Top-k routing shared by both dispatch strategies.
 
@@ -663,6 +910,22 @@ def _moe_gates(layer: Params, cfg: LlamaConfig, x: jnp.ndarray):
     (top values [..., k] f32, top indices [..., k] int32).
     """
     router_logits = (x @ layer["router"]).astype(jnp.float32)  # [..., E]
+    if cfg.moe_scoring == "sigmoid":
+        # DeepSeek-V3's ``noaux_tc`` with one group: the k largest of score
+        # + correction bias are CHOSEN; the gates are the scores alone
+        # (never the bias), renormalised, times the scaling factor.
+        if cfg.n_group != 1 or cfg.topk_group != 1:
+            raise ValueError("group-limited routing (n_group > 1) is not run")
+        scores = jax.nn.sigmoid(router_logits)
+        _, topi = jax.lax.top_k(
+            scores + layer["router_bias"], cfg.n_experts_per_tok
+        )
+        topv = jnp.take_along_axis(scores, topi, axis=-1)
+        if cfg.norm_topk_prob:
+            topv = topv / (jnp.sum(topv, axis=-1, keepdims=True) + 1e-20)
+        return topv * cfg.routed_scaling_factor, topi
+    if cfg.moe_scoring != "softmax":
+        raise ValueError(f"unknown moe_scoring {cfg.moe_scoring!r}")
     weights = jax.nn.softmax(router_logits, axis=-1)
     topv, topi = jax.lax.top_k(weights, cfg.n_experts_per_tok)
     if cfg.norm_topk_prob:
@@ -895,13 +1158,27 @@ def _mlp(
     layer: Params, cfg: LlamaConfig, x: jnp.ndarray, mesh=None,
     interpret: bool = False, touched: Optional[list] = None,
 ) -> jnp.ndarray:
-    if cfg.n_experts:
-        return _moe_mlp(
+    # The layer says what its FFN is (it has a router or it has not), never
+    # its index: a layer run alone (the benchmark's comparison) or a model
+    # with leading dense layers is served by what its parameters hold.
+    if "router" in layer:
+        out = _moe_mlp(
             layer, cfg, x, mesh=mesh, interpret=interpret, touched=touched
         )
-    gate = cfg.act_fn((x @ _w(layer["w_gate"], x.dtype)).astype(jnp.float32))
-    up = (x @ _w(layer["w_up"], x.dtype)).astype(jnp.float32)
-    return ((gate * up).astype(x.dtype)) @ _w(layer["w_down"], x.dtype)
+        if "ws_gate" in layer:
+            # the shared experts: one SwiGLU every token takes, a plain
+            # matmul beside the grouped ones
+            out = out + _swiglu(
+                cfg, x, layer["ws_gate"], layer["ws_up"], layer["ws_down"]
+            )
+        return out
+    return _swiglu(cfg, x, layer["w_gate"], layer["w_up"], layer["w_down"])
+
+
+def _swiglu(cfg: LlamaConfig, x, w_gate, w_up, w_down) -> jnp.ndarray:
+    gate = cfg.act_fn((x @ _w(w_gate, x.dtype)).astype(jnp.float32))
+    up = (x @ _w(w_up, x.dtype)).astype(jnp.float32)
+    return ((gate * up).astype(x.dtype)) @ _w(w_down, x.dtype)
 
 
 def _embed(params: Params, cfg: LlamaConfig, tokens: jnp.ndarray) -> jnp.ndarray:
@@ -952,7 +1229,7 @@ def _scatter_kv_pages_all_layers(
     can see that: the compiled program is read in
     ``tests/test_pool_layout.py``, which fails if any instruction but this
     scatter's fusion produces an array of the pool's shape."""
-    L, total_pages, page_size, n_kv, hd = pages.shape
+    L, total_pages, page_size, *row = pages.shape  # row: [n_kv, hd] or [width]
     layer_rows = total_pages * page_size
     # A token that is not valid, or whose page or slot lies past the pool's
     # (as a flat row it would be a real row of the next page or layer), gets
@@ -964,8 +1241,8 @@ def _scatter_kv_pages_all_layers(
     rows = jnp.where(
         keep.reshape(1, -1), layer_base[:, None] + rows[None, :], L * layer_rows
     )
-    flat = pages.reshape(L * layer_rows, n_kv, hd)
-    flat = flat.at[rows.reshape(-1)].set(fresh.reshape(-1, n_kv, hd), mode="drop")
+    flat = pages.reshape(L * layer_rows, *row)
+    flat = flat.at[rows.reshape(-1)].set(fresh.reshape(-1, *row), mode="drop")
     return flat.reshape(pages.shape)
 
 
@@ -1075,45 +1352,63 @@ def _prefill_body(
     sp = mesh.shape.get("sp", 1) if mesh is not None else 1
     if cfg.block_length > 1 and sp > 1:
         raise ValueError("block_length > 1: the sp ring masks causally")
-    inv_freq = jnp.asarray(rope_frequencies(cfg.hd, cfg.rope_theta, cfg.rope_scaling))
+    latent = cfg.kv_lora_rank > 0
+    if latent and (sp > 1 or cfg.block_length > 1 or k_scales is not None):
+        raise ValueError("a latent pool: sp, block_length and int8 are not run")
+    inv_freq = jnp.asarray(rope_frequencies(
+        cfg.qk_rope_head_dim if latent else cfg.hd, cfg.rope_theta,
+        cfg.rope_scaling,
+    ))
     h = _embed(params, cfg, tokens)  # [b, s, d]
-    if attn_impl == "pallas":
+    if attn_impl == "pallas" or latent:
         n_valid = jnp.sum(valid.astype(jnp.int32), axis=1)
 
     fresh_k = []  # per-layer [b, s, n_kv, hd] — written to pages in one go
     fresh_v = []
     for li, layer in enumerate(params["layers"]):
         x = rms_norm(h, layer["attn_norm"], cfg.rms_norm_eps, cfg.norm_offset)
-        q, k, v = _qkv(layer, cfg, x)
-        q = apply_rope(q, positions, inv_freq)
-        k = apply_rope(k, positions, inv_freq)
-
-        if sp > 1:
-            # Sequence-parallel chunk: ring attention over the sp axis,
-            # merged exactly with the paged context (see
-            # _sp_prefill_attention). Takes precedence over attn_impl —
-            # the ring is the sharded equivalent of the xla flash scan.
-            attn = _sp_prefill_attention(
-                q, k, v, k_pages[li], v_pages[li], block_tables, ctx_lens,
-                positions, valid, mesh,
-            )
-        elif attn_impl == "pallas":
-            # Flash kernel (ops/flash_prefill.py), which reads the whole
-            # pools' pages where they lie. Engine contract: consecutive
-            # chunk positions, right-padded valid mask.
-            attn = _flash_prefill_tp(
-                q, k, v, k_pages, v_pages, block_tables, ctx_lens,
-                n_valid, layer=li, interpret=interpret, mesh=mesh,
-                block_length=cfg.block_length,
+        if latent:
+            # One row a token, absorbed: the kernel over the pool in place
+            # or (``xla``) its oracle over gathered pages; the rows go to
+            # the pool after the loop, through the same flat-row scatter.
+            q_n, q_r, k = _mla_project(layer, cfg, x, positions, inv_freq)
+            v = None
+            attn = _mla_absorbed(
+                layer, cfg, q_n, q_r, k, k_pages, block_tables, ctx_lens,
+                n_valid, layer_index=li, interpret=interpret,
+                kernel=attn_impl == "pallas",
             )
         else:
-            attn = prefill_with_paged_context(
-                q, k, v, k_pages[li], v_pages[li], block_tables, ctx_lens,
-                positions=positions, valid=valid,
-                k_scales=None if k_scales is None else k_scales[li],
-                v_scales=None if v_scales is None else v_scales[li],
-                block_length=cfg.block_length,
-            )
+            q, k, v = _qkv(layer, cfg, x)
+            q = apply_rope(q, positions, inv_freq)
+            k = apply_rope(k, positions, inv_freq)
+
+            if sp > 1:
+                # Sequence-parallel chunk: ring attention over the sp axis,
+                # merged exactly with the paged context (see
+                # _sp_prefill_attention). Takes precedence over attn_impl —
+                # the ring is the sharded equivalent of the xla flash scan.
+                attn = _sp_prefill_attention(
+                    q, k, v, k_pages[li], v_pages[li], block_tables, ctx_lens,
+                    positions, valid, mesh,
+                )
+            elif attn_impl == "pallas":
+                # Flash kernel (ops/flash_prefill.py), which reads the whole
+                # pools' pages where they lie. Engine contract: consecutive
+                # chunk positions, right-padded valid mask.
+                attn = _flash_prefill_tp(
+                    q, k, v, k_pages, v_pages, block_tables, ctx_lens,
+                    n_valid, layer=li, interpret=interpret, mesh=mesh,
+                    block_length=cfg.block_length,
+                )
+            else:
+                attn = prefill_with_paged_context(
+                    q, k, v, k_pages[li], v_pages[li], block_tables, ctx_lens,
+                    positions=positions, valid=valid,
+                    k_scales=None if k_scales is None else k_scales[li],
+                    v_scales=None if v_scales is None else v_scales[li],
+                    block_length=cfg.block_length,
+                )
         b, s, _, _ = attn.shape
         h = h + attn.reshape(b, s, -1) @ _w(layer["wo"], h.dtype)
 
@@ -1144,10 +1439,11 @@ def _prefill_body(
             k_pages, jnp.stack(fresh_k).astype(k_pages.dtype), page_ids,
             slot_ids, valid
         )
-        v_pages = _scatter_kv_pages_all_layers(
-            v_pages, jnp.stack(fresh_v).astype(v_pages.dtype), page_ids,
-            slot_ids, valid
-        )
+        if not latent:  # a latent pool is the one array
+            v_pages = _scatter_kv_pages_all_layers(
+                v_pages, jnp.stack(fresh_v).astype(v_pages.dtype), page_ids,
+                slot_ids, valid
+            )
     return h, k_pages, v_pages, k_scales, v_scales
 
 
@@ -1187,7 +1483,8 @@ def prefill(
 
     Mask contract: ``valid`` must be a RIGHT-PADDED prefix mask — per row,
     ``valid[i] == (arange(s) < n_valid[i])``. The ``xla`` path honors an
-    arbitrary mask exactly, but the ``pallas`` kernel collapses it to a
+    arbitrary mask exactly (for a latent model it does not: both of its
+    paths take the count), but the ``pallas`` kernel collapses it to a
     per-sequence count, so a mask with interior holes silently computes
     wrong attention on ``attn_impl="pallas"``. The engine always satisfies
     this; non-engine callers can set ``LLMD_CHECK_PREFILL_MASK=1`` to
@@ -1257,7 +1554,13 @@ def _decode_body(
     slot, runs paged attention over the full context, returns
     (logits [b, vocab], k_pages, v_pages, k_scales, v_scales) — scales are
     None pass-throughs unless the pools are int8 (``KV_QUANT_HBM``)."""
-    inv_freq = jnp.asarray(rope_frequencies(cfg.hd, cfg.rope_theta, cfg.rope_scaling))
+    latent = cfg.kv_lora_rank > 0
+    if latent and (mesh is not None or k_scales is not None):
+        raise ValueError("a latent pool: tp, sp and int8 are not run")
+    inv_freq = jnp.asarray(rope_frequencies(
+        cfg.qk_rope_head_dim if latent else cfg.hd, cfg.rope_theta,
+        cfg.rope_scaling,
+    ))
     b = tokens.shape[0]
     h = _embed(params, cfg, tokens)[:, None, :]  # [b, 1, d]
 
@@ -1271,31 +1574,46 @@ def _decode_body(
     fresh_v = []
     for li, layer in enumerate(params["layers"]):
         x = rms_norm(h, layer["attn_norm"], cfg.rms_norm_eps, cfg.norm_offset)
-        q, k, v = _qkv(layer, cfg, x)
-        q = apply_rope(q, positions[:, None], inv_freq)
-        k = apply_rope(k, positions[:, None], inv_freq)
+        if latent:
+            # Absorbed decode (kernel ``mla_decode``): every head reads the
+            # lane's latent rows where they lie, once, as key and as value;
+            # the token's own row rides as an argument and is written after
+            # the loop like every other model's.
+            q_n, q_r, k = _mla_project(
+                layer, cfg, x, positions[:, None], inv_freq
+            )
+            v = None
+            attn = _mla_absorbed(
+                layer, cfg, q_n, q_r, k, k_pages, block_tables,
+                jnp.maximum(seq_lens - 1, 0), (seq_lens > 0).astype(jnp.int32),
+                layer_index=li, interpret=interpret,
+            )  # [b, 1, H, d_v]
+        else:
+            q, k, v = _qkv(layer, cfg, x)
+            q = apply_rope(q, positions[:, None], inv_freq)
+            k = apply_rope(k, positions[:, None], inv_freq)
 
-        # The kernel takes the current token's K/V as arguments (pages hold
-        # only history), so the pool write happens ONCE for all layers after
-        # the loop — a single aliased scatter instead of a per-layer pool
-        # rebuild (which cost 2×pool bytes of HBM traffic per token). The
-        # kernel reads the pool in the default layout; that the write does
-        # too (it did not until PR 29: see _scatter_kv_pages_all_layers) is
-        # what tests/test_pool_layout.py holds on the compiled program.
-        attn = _paged_attention_tp(
-            q[:, 0],  # [b, n_heads, hd]
-            k_pages,  # FULL [L, P, ps, n_kv, hd] pool; layer via index map
-            v_pages,
-            block_tables,
-            seq_lens,
-            k[:, 0],  # [b, n_kv, hd]
-            v[:, 0],
-            interpret=interpret,
-            mesh=mesh,
-            layer=li,
-            k_scale=k_scales,
-            v_scale=v_scales,
-        )  # [b, n_heads, hd]
+            # The kernel takes the current token's K/V as arguments (pages hold
+            # only history), so the pool write happens ONCE for all layers after
+            # the loop — a single aliased scatter instead of a per-layer pool
+            # rebuild (which cost 2×pool bytes of HBM traffic per token). The
+            # kernel reads the pool in the default layout; that the write does
+            # too (it did not until PR 29: see _scatter_kv_pages_all_layers) is
+            # what tests/test_pool_layout.py holds on the compiled program.
+            attn = _paged_attention_tp(
+                q[:, 0],  # [b, n_heads, hd]
+                k_pages,  # FULL [L, P, ps, n_kv, hd] pool; layer via index map
+                v_pages,
+                block_tables,
+                seq_lens,
+                k[:, 0],  # [b, n_kv, hd]
+                v[:, 0],
+                interpret=interpret,
+                mesh=mesh,
+                layer=li,
+                k_scale=k_scales,
+                v_scale=v_scales,
+            )  # [b, n_heads, hd]
         h = h + (attn.reshape(b, -1) @ _w(layer["wo"], h.dtype))[:, None, :]
 
         x = rms_norm(h, layer["mlp_norm"], cfg.rms_norm_eps, cfg.norm_offset)
@@ -1318,10 +1636,11 @@ def _decode_body(
             k_pages, jnp.stack(fresh_k).astype(k_pages.dtype),
             my_page[:, None], my_slot[:, None], valid,
         )
-        v_pages = _scatter_kv_pages_all_layers(
-            v_pages, jnp.stack(fresh_v).astype(v_pages.dtype),
-            my_page[:, None], my_slot[:, None], valid,
-        )
+        if not latent:
+            v_pages = _scatter_kv_pages_all_layers(
+                v_pages, jnp.stack(fresh_v).astype(v_pages.dtype),
+                my_page[:, None], my_slot[:, None], valid,
+            )
     return (
         _logits(params, cfg, h)[:, 0],
         k_pages,

@@ -27,7 +27,9 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..models.llama import LlamaConfig, Params
 
 
-def _layer_specs(cfg: LlamaConfig, tp: int = 1) -> dict[str, P]:
+def _layer_specs(cfg: LlamaConfig, tp: int = 1, routed: bool = True) -> dict[str, P]:
+    """``routed``: the layer's FFN is the routed one (False for the leading
+    dense layers of a model with ``first_k_dense``)."""
     specs = {
         "attn_norm": P(),
         "wq": P(None, "tp"),
@@ -39,7 +41,13 @@ def _layer_specs(cfg: LlamaConfig, tp: int = 1) -> dict[str, P]:
         "w_up": P(None, "tp"),
         "w_down": P("tp", None),
     }
-    if cfg.n_experts:
+    if cfg.kv_lora_rank:
+        # Latent attention: the down-projection and its norm are shared by
+        # every head (replicated); the up-projection is column-parallel
+        # over heads. (The engine refuses tp > 1 for a latent pool.)
+        del specs["wk"], specs["wv"]
+        specs.update(wkv_a=P(), kv_norm=P(), wkv_b=P(None, "tp"))
+    if cfg.n_experts and routed:
         # MoE FFN: expert-parallel when the expert count divides the tp
         # axis (each device holds E/tp whole experts; the combine's
         # contraction over E becomes a psum over ICI), else fall back to
@@ -53,6 +61,12 @@ def _layer_specs(cfg: LlamaConfig, tp: int = 1) -> dict[str, P]:
             specs["w_gate"] = P(None, None, "tp")
             specs["w_up"] = P(None, None, "tp")
             specs["w_down"] = P(None, "tp", None)
+        if cfg.moe_scoring == "sigmoid":
+            specs["router_bias"] = P()
+        if cfg.n_shared_experts:
+            specs.update(
+                ws_gate=P(None, "tp"), ws_up=P(None, "tp"), ws_down=P("tp", None)
+            )
     if cfg.qkv_bias:
         specs["bq"] = P("tp")
         specs["bk"] = P("tp")
@@ -69,7 +83,10 @@ def param_specs(cfg: LlamaConfig, tp: int = 1) -> dict[str, Any]:
     specs: dict[str, Any] = {
         "embed": P("tp", None),  # vocab-sharded; gather rides ICI
         "final_norm": P(),
-        "layers": [_layer_specs(cfg, tp) for _ in range(cfg.n_layers)],
+        "layers": [
+            _layer_specs(cfg, tp, routed=i >= cfg.first_k_dense)
+            for i in range(cfg.n_layers)
+        ],
     }
     if not cfg.tie_word_embeddings:
         specs["lm_head"] = P(None, "tp")

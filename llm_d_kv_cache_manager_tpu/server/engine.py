@@ -532,6 +532,37 @@ class Engine:
                     f"mask_token_id={cfg.mask_token_id} outside the "
                     f"vocabulary of {cfg.vocab_size}"
                 )
+        if cfg.kv_lora_rank > 0:
+            # A latent pool (one row a token a layer, no value pool): what
+            # is not done for it is refused here by name. The prefix cache,
+            # KV events, the index and the scorer know tokens, not heads,
+            # and serve it as they serve every model.
+            refused = {
+                "kv_quant_hbm (a latent pool has no int8 form or scales)":
+                    config.kv_quant_hbm is not None,
+                "host_pages > 0 (the host tier moves K and V pages)":
+                    config.block_manager.host_pages > 0,
+                "remote_tier (demotion payloads are K and V pages)":
+                    config.remote_tier,
+                "sp > 1 (the ring rotates per-head keys and values)":
+                    config.sp > 1,
+                "tp > 1 (every head reads the one row: nothing to shard "
+                "on heads)": config.tp > 1,
+                "spec_decode (the verify scan is not run over latent rows)":
+                    config.spec_decode != "off",
+                "block_length > 0 (no block mask in the latent kernel)":
+                    cfg.block_length > 0,
+                "q_lora_rank (a low-rank query path is not run)":
+                    bool(cfg.q_lora_rank),
+                "n_group, topk_group > 1 (group-limited routing is not run)":
+                    cfg.n_group != 1 or cfg.topk_group != 1,
+            }
+            for what, on in refused.items():
+                if on:
+                    raise ValueError(
+                        f"kv_lora_rank={cfg.kv_lora_rank} (a latent KV "
+                        f"pool) is incompatible with {what}"
+                    )
         if config.kv_quant_hbm is not None:
             if config.kv_quant_hbm not in quant.KV_QUANT_HBM_MODES:
                 raise ValueError(
@@ -604,8 +635,18 @@ class Engine:
         self.k_pages, self.v_pages = llama.init_kv_pages(
             cfg, config.block_manager.total_pages, ps,
             kv_quant_hbm=config.kv_quant_hbm,
-            sharding=kv_pages_sharding(mesh),
+            # a latent pool has no head axis to shard (tp > 1 is refused)
+            sharding=self._replicated if cfg.kv_lora_rank
+            else kv_pages_sharding(mesh),
         )
+        #: what a token costs in the pools, all layers: the arrays' bytes
+        #: over the pool's token slots (a latent row says its held width
+        #: itself, ``LlamaConfig.kv_row_shape``, so ``nbytes`` is what the
+        #: device holds). ``/stats`` reports it beside ``total_pages``;
+        #: ``kv_block_bytes`` follows it for a latent pool.
+        self.kv_bytes_per_token = (
+            self.k_pages.nbytes + self.v_pages.nbytes
+        ) // (config.block_manager.total_pages * ps)
         # Scale pools ride alongside the int8 page pools (None when the
         # knob is off — every scale-threading call site keys off this).
         self.k_scales: Optional[jnp.ndarray] = None
@@ -653,7 +694,7 @@ class Engine:
         )
         hp = config.block_manager.host_pages
         if hp > 0:
-            slot_shape = (hp, cfg.n_layers, ps, cfg.n_kv_heads, cfg.hd)
+            slot_shape = (hp, cfg.n_layers, ps, *cfg.kv_row_shape)
             np_dtype = np.dtype(jnp.dtype(cfg.dtype).name)
             if self._host_int8:
                 self._host_k = np.zeros(slot_shape, np.int8)
@@ -753,7 +794,7 @@ class Engine:
                 if sink is not None:
                     sink(events)
 
-            shape = (cfg.n_layers, ps, cfg.n_kv_heads, cfg.hd)
+            shape = (cfg.n_layers, ps, *cfg.kv_row_shape)
             self.remote_store = RemoteBlockStore(
                 RemoteStoreConfig(
                     capacity_pages=config.remote_store_pages,
@@ -817,6 +858,9 @@ class Engine:
         #: ``block_tokens_fixed`` (rows fixed), ``blocks_final``,
         #: ``experts_touched`` (distinct experts a dispatch's rows chose,
         #: summed over the layers and the dispatches: counted on the device).
+        #: A latent pool: ``latent_ctx_tokens`` (the real lanes' context
+        #: lengths, summed over the decode dispatches: the rows the
+        #: ``mla_decode`` kernel must read, a layer).
         #: Off by default: ``obs_step_timing=False`` skips every clock
         #: read and every count, so the legacy step path is untouched.
         self.obs_step_timing = False
@@ -830,6 +874,7 @@ class Engine:
             "block_tokens_fixed": 0,
             "blocks_final": 0,
             "experts_touched": 0,
+            "latent_ctx_tokens": 0,
             "prefill_s": 0.0,
             "decode_s": 0.0,
             "sample_s": 0.0,
@@ -1047,7 +1092,7 @@ class Engine:
 
         cfg = self.model_cfg
         ps = self.page_size
-        shape = (cfg.n_layers, ps, cfg.n_kv_heads, cfg.hd)
+        shape = (cfg.n_layers, ps, *cfg.kv_row_shape)
         sc_shape = quant.kv_scale_shape(shape)
         np_dtype = np.dtype(jnp.dtype(cfg.dtype).name)
         hbmq = self.config.kv_quant_hbm == "int8"
@@ -1426,6 +1471,9 @@ class Engine:
         bytes, so a full-width figure here would overestimate pull cost
         ~2x and wrongly decline break-even pulls."""
         cfg = self.model_cfg
+        if cfg.kv_lora_rank:
+            # one pool of latent rows: a block is one page of it, all layers
+            return self.page_size * self.kv_bytes_per_token
         elems = cfg.n_layers * self.page_size * cfg.n_kv_heads * cfg.hd
         if (
             self.config.kv_quant == "int8"
@@ -1433,6 +1481,19 @@ class Engine:
         ):
             return 2 * (elems + cfg.n_layers * cfg.n_kv_heads * 4)
         return 2 * elems * jnp.dtype(cfg.dtype).itemsize
+
+    def _refuse_latent_page_moves(self, what: str) -> None:
+        """Pages leave and enter an engine as a K and a V page of KV heads
+        (the wire's payload, the digests, the int8 form): not done for a
+        latent pool, whose second pool holds no page. ``PodServer`` refuses
+        ``transfer_endpoint`` for such a model at construction; this holds
+        any other caller."""
+        if self.model_cfg.kv_lora_rank:
+            raise ValueError(
+                f"kv_lora_rank={self.model_cfg.kv_lora_rank} (a latent KV "
+                f"pool) is incompatible with {what} (export and import move "
+                f"K and V pages)"
+            )
 
     def export_kv_blocks(self, hashes: list, max_blocks: Optional[int] = None):
         """Serve a peer's prefix fetch: the longest consecutive resident
@@ -1442,6 +1503,7 @@ class Engine:
         exported bytes reflect committed state, not in-flight snapshots."""
         from ..kvcache.transfer.protocol import BlockPayload
 
+        self._refuse_latent_page_moves("export_kv_blocks")
         self._flush_page_moves()
         chain = self.block_manager.lookup_chain(hashes, max_blocks)
         # Remote-store continuation: a kvstore pod (or a peer holding
@@ -1486,8 +1548,7 @@ class Engine:
             (
                 self.model_cfg.n_layers,
                 self.page_size,
-                self.model_cfg.n_kv_heads,
-                self.model_cfg.hd,
+                *self.model_cfg.kv_row_shape,
             )
         )
         blocks = []
@@ -1615,12 +1676,13 @@ class Engine:
         peer's corrupt export is the one that revokes it fleet-wide."""
         from ..kvcache.kvblock.token_processor import hash_block
 
+        self._refuse_latent_page_moves("import_kv_blocks")
         if allow_evict is None:
             allow_evict = self.config.remote_tier
 
         cfg = self.model_cfg
         ps = self.page_size
-        expected_shape = (cfg.n_layers, ps, cfg.n_kv_heads, cfg.hd)
+        expected_shape = (cfg.n_layers, ps, *cfg.kv_row_shape)
         np_dtype = np.dtype(jnp.dtype(cfg.dtype).name)
         page_bytes = int(np.prod(expected_shape)) * np_dtype.itemsize
         # Quantized frames ship int8 payloads + f32 scales of the page's
@@ -2415,7 +2477,7 @@ class Engine:
                     toks, self.k_pages, self.v_pages,
                     self.k_scales, self.v_scales,
                 ) = out
-        self._count_decode_dispatch(len(active), temperature)
+        self._count_decode_dispatch(len(active), temperature, seq_lens, k)
         if self.config.decode_fused_sampling:
             # Start the batched D2H copy of this burst's sampled ids NOW,
             # overlapped with whatever dispatches next — by the time the
@@ -3006,16 +3068,27 @@ class Engine:
             self.lifecycle_stats.get("priority_preempted", 0) + 1
         )
 
-    def _count_decode_dispatch(self, rows: int, temperature: np.ndarray) -> None:
+    def _count_decode_dispatch(
+        self, rows: int, temperature: np.ndarray,
+        seq_lens: Optional[np.ndarray] = None, steps: int = 1,
+    ) -> None:
         """``step_stats``' counters of one decode dispatch: its real lanes,
-        and whether any of them samples (``temperature`` is the host-side
-        array the dispatch was given)."""
+        whether any of them samples (``temperature`` is the host-side
+        array the dispatch was given) and, for a latent pool, the context
+        rows its ``steps`` fused steps read a layer (``seq_lens``: the
+        host-side lengths of the dispatch, 0 for a lane that is not real;
+        a lane's context grows by one a step)."""
         if self.obs_step_timing:
             self.step_stats["decode_dispatches"] += 1
             self.step_stats["decode_rows"] += rows
             self.step_stats["decode_sampled_dispatches"] += bool(
                 (temperature > 0).any()
             )
+            if seq_lens is not None and self.model_cfg.kv_lora_rank:
+                self.step_stats["latent_ctx_tokens"] += int(
+                    steps * seq_lens.sum()
+                    + rows * steps * (steps - 1) // 2
+                )
 
     def _sample(self, logits: jnp.ndarray, seqs: list[Sequence]) -> np.ndarray:
         """First tokens of a prefill batch (decode samples on the device,

@@ -349,6 +349,19 @@ class _ServingMetrics:
                 ),
                 0,
             )
+            self.engine_latent_ctx = prom.Counter(
+                "kvcache_engine_latent_ctx_tokens_total",
+                "Context rows the decode dispatches of a latent (MLA) pool "
+                "read a layer: the real lanes' context lengths, summed",
+                registry=self.registry,
+            )
+            self._latent_ctx_seen = 0
+            self.kv_bytes_per_token_g = prom.Gauge(
+                "kvcache_kv_bytes_per_token",
+                "Bytes one token holds in the KV pools, all layers, as held "
+                "on the device",
+                registry=self.registry,
+            )
             # Host-DRAM tier + prefetch (ISSUE 6): tier occupancy, pages
             # served back from host DRAM (by path: ahead-of-scheduler
             # prefetch vs blocking allocate), and prefetch-round wall time.
@@ -563,14 +576,21 @@ class _ServingMetrics:
             if delta > 0:
                 self.engine_block.labels(count=key).inc(delta)
                 self._block_seen[key] = step_stats[key]
+        latent = step_stats.get("latent_ctx_tokens", 0)
+        if latent > self._latent_ctx_seen:
+            self.engine_latent_ctx.inc(latent - self._latent_ctx_seen)
+            self._latent_ctx_seen = latent
         if lag_s is not None:
             self.engine_loop_lag.set(lag_s)
 
-    def set_engine_gauges(self, occupancy: float, free_pages: int) -> None:
+    def set_engine_gauges(
+        self, occupancy: float, free_pages: int, kv_bytes_per_token: int
+    ) -> None:
         if self._prom is None or not self._obs:
             return
         self.engine_occupancy.set(occupancy)
         self.engine_free_pages.set(free_pages)
+        self.kv_bytes_per_token_g.set(kv_bytes_per_token)
 
     def observe_host_prefetch(self, seconds: float) -> None:
         if self._prom is None or not self._obs:
@@ -1193,6 +1213,16 @@ class PodServer:
             raise ValueError(
                 f"POD_ROLE must be mixed/prefill/decode/kvstore, got "
                 f"{self.config.pod_role!r}"
+            )
+        model = engine.model_cfg if engine is not None else self.config.engine.model
+        if model.kv_lora_rank and self.config.transfer_endpoint:
+            # refused here by name, beside the engine's own refusals for a
+            # latent pool: the service would gather pages of a pool that
+            # holds none on the engine thread
+            raise ValueError(
+                f"kv_lora_rank={model.kv_lora_rank} (a latent KV pool) is "
+                f"incompatible with transfer_endpoint (TRANSFER_ENDPOINT: "
+                f"export and import move K and V pages)"
             )
         if self.config.remote_tier and engine is None:
             # Thread the knob family into the engine config BEFORE the
@@ -2182,6 +2212,7 @@ class PodServer:
                             len(sch.running)
                             / max(self.config.engine.decode_batch_size, 1),
                             self.engine.block_manager.num_free,
+                            self.engine.kv_bytes_per_token,
                         )
                         if self.config.engine.block_manager.host_pages:
                             bm = self.engine.block_manager
@@ -3607,6 +3638,7 @@ class PodServer:
                 "running": len(self.engine.scheduler.running),
                 "free_pages": bm.num_free,
                 "total_pages": bm.config.total_pages,
+                "kv_bytes_per_token": self.engine.kv_bytes_per_token,
                 "prefill": dict(self.engine.prefill_stats),
                 "transfer": {
                     **self.engine.transfer_stats,
@@ -3970,6 +4002,8 @@ def _resolve_model(name: str) -> LlamaConfig:
         "tiny-qwen3-moe": models.TINY_QWEN3_MOE,
         "JetLM/SDAR-30B-A3B-Chat": models.SDAR_30B_A3B,
         "tiny-sdar-moe": models.TINY_SDAR_MOE,
+        "kakaocorp/kanana-2-30b-a3b-instruct-2601": models.KANANA_2_30B_A3B,
+        "tiny-mla-moe": models.TINY_MLA_MOE,
     }
     if name in presets:
         return presets[name]

@@ -4,7 +4,12 @@ Parity with reference ``pkg/tokenization/pool.go``: N workers (default 5)
 consume a queue of (prompt, model) tasks; each task first consults the
 prefix store and only runs the full tokenizer when the cached overlap ratio
 is below the threshold (default 0.8, ``pool.go:161-191``), writing fresh
-tokenizations back to the store. ``tokenize`` blocks for the result;
+tokenizations back to the store. One departure: the ratio alone lets the
+uncovered tail grow with the prompt (a fifth of a 28k-character document is
+350 blocks of 16 tokens the scorer would never see, and a document that grows
+piece by piece never crosses the threshold again), so a prompt whose uncovered
+tail reaches ``MAX_UNCOVERED_BYTES`` (1024: four store blocks) is tokenized
+fully whatever the ratio. ``tokenize`` blocks for the result;
 ``enqueue_tokenization`` is fire-and-forget. Failed tasks are retried with
 exponential backoff, mirroring the rate-limited workqueue (``:150-155``).
 """
@@ -25,6 +30,9 @@ log = get_logger("tokenization.pool")
 
 DEFAULT_WORKERS = 5
 DEFAULT_MIN_PREFIX_OVERLAP_RATIO = 0.8
+#: a cached prefix is taken for the prompt only while the bytes it leaves
+#: uncovered stay under this many, whatever the ratio
+MAX_UNCOVERED_BYTES = 1024
 _MAX_RETRIES = 5
 _BASE_RETRY_DELAY = 0.005  # 5ms, doubling per attempt (workqueue default style)
 
@@ -185,7 +193,13 @@ class TokenizationPool:
             task.prompt, task.model_name
         )
 
-        if overlap_ratio < self.config.min_prefix_overlap_ratio:
+        # whole bytes: the store's ratio is covered bytes over all bytes
+        n_bytes = len(task.prompt.encode("utf-8"))
+        uncovered = n_bytes - round(overlap_ratio * n_bytes)
+        if (
+            overlap_ratio < self.config.min_prefix_overlap_ratio
+            or uncovered >= MAX_UNCOVERED_BYTES
+        ):
             tokens, offsets = self.tokenizer.encode(task.prompt, task.model_name)
             self.indexer.add_tokenization(task.model_name, task.prompt, tokens, offsets)
             token_ids = tokens

@@ -506,7 +506,8 @@ class TestKnobsOffParity:
             stats = await resp.json()
             assert set(stats) == {
                 "pod", "model", "data_parallel_rank", "staged", "waiting",
-                "running", "free_pages", "total_pages", "prefill",
+                "running", "free_pages", "total_pages", "kv_bytes_per_token",
+                "prefill",
                 "transfer", "self_heal", "admission", "drain",
             }
 

@@ -240,7 +240,102 @@ class TestTheContextIsReadInPlace:
             self._compile(topo, _MOE_POOL, (16, 4), 128, 4, 4)
 
 
+_LATENT_POOL = (8, 16384, 16, 640)  # kanana-2-30b-a3b: one row a token
+
+
+class TestTheLatentPool:
+    """A latent model's one pool ``[L, P, page, row]`` (PR 32): the write
+    through the same flat-row scatter, the ``mla_decode`` / ``mla_prefill``
+    kernel's call as ``_mla_absorbed`` makes it, at the cell's shapes (32
+    lanes over a table of 2048 pages; 8 rows x a 128-token question over
+    1792), and the layout the compiler gives the pool."""
+
+    @pytest.mark.parametrize("write", [(32, 1), (8, 128)], ids=["decode32", "prefill1024"])
+    def test_only_the_scatter_touches_the_pool(self, topo, write):
+        one_chip = SingleDeviceSharding(topo.devices[0])
+        L, row = _LATENT_POOL[0], _LATENT_POOL[3]
+
+        def shaped(shape, dtype):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+        hlo = aot_pool_copies.compile_text(
+            jax.jit(_scatter_kv_pages_all_layers, donate_argnums=0),
+            shaped(_LATENT_POOL, jnp.bfloat16),
+            shaped((L, *write, row), jnp.bfloat16),
+            shaped(write, jnp.int32), shaped(write, jnp.int32),
+            shaped(write, jnp.bool_),
+        )
+        assert aot_pool_copies.pool_instructions(hlo, _LATENT_POOL)
+        assert _whole_pool_moves(hlo, _LATENT_POOL) == []
+        # 576 values are held in 640: the array says so itself, so that
+        # ``nbytes`` is what the device holds and Mosaic can cut page tiles
+        layout = aot_pool_copies.pool_layout(hlo, _LATENT_POOL)
+        assert layout.startswith("bf16[8,16384,16,640]{3,2,1,0:T(8,128)(2,1)"), layout
+
+    @pytest.mark.parametrize("rows, table_pages, kernel", [
+        ((32, 1), 2048, "mla_decode"), ((8, 128), 1792, "mla_prefill"),
+    ], ids=["decode", "question"])
+    def test_the_kernel_reads_the_pool_in_place(self, topo, rows, table_pages, kernel):
+        one_chip = SingleDeviceSharding(topo.devices[0])
+        cfg = llama.KANANA_2_30B_A3B
+        b, s = rows
+
+        def shaped(shape, dtype=jnp.bfloat16):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+        def one_layer(wkv_b, q_n, q_r, row, pool, block_tables, ctx_lens, n_valid):
+            return llama._mla_absorbed(
+                {"wkv_b": wkv_b}, cfg, q_n, q_r, row, pool, block_tables,
+                ctx_lens, n_valid, layer_index=7, interpret=False,
+            )
+
+        hlo = aot_pool_copies.compile_text(
+            jax.jit(one_layer),
+            shaped((512, 32 * 256)), shaped((b, s, 32, 128)),
+            shaped((b, s, 32, 64)), shaped((b, s, 640)), shaped(_LATENT_POOL),
+            shaped((b, table_pages), jnp.int32), shaped((b,), jnp.int32),
+            shaped((b,), jnp.int32),
+        )
+        found = aot_pool_copies.pool_instructions(hlo, _LATENT_POOL, layer_slices=True)
+        assert found
+        assert [(i.opcode, i.name, i.result) for i in found if i.moves_bytes] == []
+        everything = list(aot_pool_copies.instructions(hlo))
+        # nothing the size of a gathered context (rows x table x page x row)
+        gathered = b * table_pages * 16 * 640
+        assert [
+            (op, name) for _, name, op, result in everything
+            if op not in aot_pool_copies.FREE and _elements(result) >= gathered
+        ] == []
+        calls = [name for _, name, op, _ in everything if op == "custom-call"]
+        assert len([name for name in calls if kernel in name]) == 1, calls
+
+    def test_a_row_of_576_values_has_no_page_tile(self, topo):
+        # what the pool's width of 640 is for: the compiler holds 576 as 640
+        # and Mosaic cuts no page tile out of the padded minor dimension
+        from llm_d_kv_cache_manager_tpu.ops.mla_attention import mla_paged_attention
+
+        one_chip = SingleDeviceSharding(topo.devices[0])
+
+        def shaped(shape, dtype=jnp.bfloat16):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+        def call(q, fresh, pool, block_tables, ctx_lens, n_valid):
+            return mla_paged_attention(
+                q, fresh, pool, block_tables, ctx_lens, n_valid, dv=512,
+                scale=0.07, layer=jnp.int32(0),
+            )
+
+        with pytest.raises(Exception, match="aligned to tiling"):
+            aot_pool_copies.compile_text(
+                jax.jit(call), shaped((32, 1, 32, 576)), shaped((32, 1, 576)),
+                shaped((8, 1024, 16, 576)), shaped((32, 64), jnp.int32),
+                shaped((32,), jnp.int32), shaped((32,), jnp.int32),
+            )
+
+
 _SERVED = [
+    ("kanana-2-30b-a3b", "decode_steps"),
+    ("kanana-2-30b-a3b", "prefill"),
     ("qwen3-30b-a3b", "decode_steps"),
     ("qwen3-30b-a3b", "prefill"),
     ("qwen3-32b", "decode_steps"),

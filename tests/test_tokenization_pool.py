@@ -73,6 +73,31 @@ class TestTokenizationPool:
         finally:
             p.shutdown()
 
+    @pytest.mark.parametrize("cap, calls", [(1024, 1), (24, 2), (25, 1)])
+    def test_a_long_uncovered_tail_is_tokenized_whatever_the_ratio(
+        self, cap, calls, monkeypatch
+    ):
+        # 96 of 120 bytes cached: the ratio (0.8) takes the cached prefix;
+        # the 24 bytes it leaves uncovered do not when the cap is that low
+        from llm_d_kv_cache_manager_tpu.tokenization import pool as pool_module
+
+        monkeypatch.setattr(pool_module, "MAX_UNCOVERED_BYTES", cap)
+        tok = MockTokenizer()
+        p = TokenizationPool(
+            TokenizationPoolConfig(workers_count=1),
+            store=LRUTokenStore(Config(block_size=4)),
+            tokenizer=tok,
+        )
+        p.run()
+        try:
+            document = "abcd" * 24
+            p.tokenize(document, "m")
+            grown = p.tokenize(document + "wxyz" * 6, "m")
+            assert tok.calls == calls
+            assert len(grown) == (120 if calls == 2 else 96)
+        finally:
+            p.shutdown()
+
     def test_async_enqueue(self, pool):
         pool.enqueue_tokenization("abcdefgh", "m")
         deadline = time.time() + 5

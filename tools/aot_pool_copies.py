@@ -6,7 +6,9 @@ compiles the served programs of the benchmark's configurations — the fused
 ``decode_steps`` or ``denoise_steps`` and one ``prefill`` dispatch, at the
 shapes ``chipbench/configs/<name>.json`` pins — for one v5e chip and lists
 every instruction outside a fusion's body whose result has the shape of a
-whole K or V pool ``[L, P, page, n_kv, hd]`` or of one layer's slice of it.
+whole K or V pool ``[L, P, page, n_kv, hd]`` (a latent model's one pool:
+``[L, P, page, row]``) or of one layer's slice of it, and the layout the
+compiler gives the pool.
 
 A pool is hundreds of MiB: any such instruction that is not free (a
 ``bitcast``, a ``parameter``, tuple plumbing) reads and writes that much
@@ -135,6 +137,16 @@ def pool_instructions(
     ]
 
 
+def pool_layout(hlo: str, pool_shape: tuple[int, ...]) -> str:
+    """The pool parameter's type as the compiled module prints it, layout
+    and tiling included (``bf16[8,16384,16,640]{3,2,1,0:T(8,128)(2,1)}``):
+    what the device holds, which a docstring can only guess."""
+    for i in pool_instructions(hlo, pool_shape):
+        if i.opcode == "parameter":
+            return i.result
+    return "not a parameter of the module"
+
+
 def tuple_members(result: str) -> str:
     """A result type for a line of output: a tuple as its distinct members,
     each with its count."""
@@ -163,7 +175,7 @@ def compile_text(fn, *args, **kwargs) -> str:
     here, so the refusal is lifted for the lowering alone."""
     modules = [
         importlib.import_module(f"llm_d_kv_cache_manager_tpu.ops.{name}")
-        for name in ("flash_prefill", "gmm", "paged_attention")
+        for name in ("flash_prefill", "gmm", "mla_attention", "paged_attention")
     ]
     kept = [m.require_tpu_unless_interpret for m in modules]
     for m in modules:
@@ -199,12 +211,17 @@ def served_program(config: str, program: str, one_chip):
         lambda x: S(x.shape, x.dtype),
         jax.eval_shape(lambda k: llama.init_params(k, cfg), jax.random.PRNGKey(0)),
     )
-    pool_shape = (cfg.n_layers, env["TOTAL_PAGES"], page, cfg.n_kv_heads, cfg.hd)
-    pool = S(pool_shape, jnp.bfloat16)
+    # the pools as ``init_kv_pages`` makes them: K and V, or a latent pool
+    # and the array of no pages that stands in the second place
+    pool_shape, second_shape = (
+        p.shape for p in jax.eval_shape(
+            lambda: llama.init_kv_pages(cfg, env["TOTAL_PAGES"], page))
+    )
+    pool, second = S(pool_shape, jnp.bfloat16), S(second_shape, jnp.bfloat16)
     key = S((2,), jnp.uint32)
     if program == "decode_steps" and cfg.block_length == 0:
         args = (
-            params, cfg, S((lanes,), i32), S((lanes,), i32), pool, pool,
+            params, cfg, S((lanes,), i32), S((lanes,), i32), pool, second,
             S((lanes, table_w), i32), S((lanes,), i32), S((lanes,), f32),
             S((lanes,), i32), S((lanes,), f32), key,
         )
@@ -212,7 +229,7 @@ def served_program(config: str, program: str, one_chip):
         return llama.decode_steps, args, kwargs, pool_shape
     if program == "denoise_steps" and cfg.block_length > 0:
         width = 2 * cfg.block_length + table_w + 5
-        args = (params, cfg, S((lanes, width), i32), S((lanes, 3), f32), pool, pool, key)
+        args = (params, cfg, S((lanes, width), i32), S((lanes, 3), f32), pool, second, key)
         kwargs = dict(
             page_size=page, table_w=table_w, mesh=None, attn_impl="pallas",
             interpret=False,
@@ -222,7 +239,7 @@ def served_program(config: str, program: str, one_chip):
         rows = (PREFILL_ROWS, PREFILL_CHUNK)
         args = (
             params, cfg, S(rows, i32), S(rows, i32), S(rows, jnp.bool_), pool,
-            pool, S(rows, i32), S(rows, i32),
+            second, S(rows, i32), S(rows, i32),
             S((PREFILL_ROWS, engine["prefill_ctx_bucket"]), i32),
             S((PREFILL_ROWS,), i32),
         )
@@ -255,7 +272,8 @@ def main(argv=None) -> int:
             found = pool_instructions(hlo, pool_shape, layer_slices=True)
             moving = [i for i in found if i.moves_bytes]
             print(f"{config} {program} pool {list(pool_shape)}: "
-                  f"{len(moving)} of {len(found)} instructions move bytes")
+                  f"{len(moving)} of {len(found)} instructions move bytes; "
+                  f"held as {pool_layout(hlo, pool_shape)}")
             # Eight layers make eight lines that differ in a suffix: one
             # line for each (opcode, result), with the first name.
             alike: dict[tuple, list[str]] = {}
