@@ -1692,7 +1692,8 @@ def decode_step(
 def decode_steps(
     params: Params,
     cfg: LlamaConfig,
-    tokens: jnp.ndarray,  # [b] int32 — last sampled token per sequence
+    tokens: jnp.ndarray,  # [b] int32 — last sampled token per sequence,
+    # or [b, n]: a burst's own output, whose last column is taken here
     positions: jnp.ndarray,  # [b] int32 — position of `tokens`
     k_pages: jnp.ndarray,
     v_pages: jnp.ndarray,
@@ -1720,9 +1721,13 @@ def decode_steps(
     [b, num_steps] int32, k_pages, v_pages). The caller must pre-extend
     ``block_tables`` to cover ``num_steps`` of growth; lanes that finish
     early keep decoding into their reserved pages and the host discards the
-    surplus tokens.
+    surplus tokens. ``tokens`` may be the ``[b, n]`` ids a burst returned:
+    the next burst then starts from them on the device, with no program
+    between the two and none beside this one.
     """
     quantized = k_scales is not None
+    if tokens.ndim == 2:
+        tokens = tokens[:, -1]
     # The sampler's gate: the lanes' parameters do not change inside the
     # burst, so whether any lane samples is decided once, out here.
     any_sampled = jnp.any(temperature > 0)
@@ -1745,10 +1750,10 @@ def decode_steps(
     carry0 = (tokens, positions, seq_lens, k_pages, v_pages, k_scales, v_scales)
     keys = jax.random.split(rng_key, num_steps)
     if num_steps == 1:
-        # The device-resident step-per-token loop (decode_fused_sampling
-        # at k=1) lands here every iteration: skip the scan machinery for
-        # a plain body call. Consumes keys[0] exactly like the scan's
-        # first slice, so sampled streams are bit-identical across paths.
+        # The step-per-token loop lands here every iteration: skip the
+        # scan machinery for a plain body call. Consumes keys[0] exactly
+        # like the scan's first slice, so sampled streams are
+        # bit-identical across paths.
         (_, _, _, k_pages, v_pages, k_scales, v_scales), nxt = body(
             carry0, keys[0]
         )

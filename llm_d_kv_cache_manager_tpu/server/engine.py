@@ -116,14 +116,16 @@ class _Phase:
     under the device's timeline.
 
     Phases run one after another and never contain one another. The one
-    thing that can start inside another phase is the drain of a pipelined
-    burst (``decode_pipeline``: its ``decode_fetch`` and ``decode_commit``),
-    which a reservation out of pages, a QoS preemption or an abort forces
-    where it stands. Such a phase SUSPENDS the one it starts in — the
-    outer span ends where the inner begins, and a new span of the outer's
-    name starts where the inner ends — so a reader that names an idle gap
-    after the span that overlaps it most never finds an enclosing span
-    winning every gap. Without a burst in flight no phase is suspended."""
+    thing that can start inside another phase is the drain of the decode
+    burst in flight (``Engine._inflight``: its ``decode_fetch`` and
+    ``decode_commit``), which a reservation out of pages, a QoS preemption
+    or an abort forces where it stands. Such a phase SUSPENDS the one it
+    starts in — the outer span ends where the inner begins, and a new span
+    of the outer's name starts where the inner ends — so a reader that
+    names an idle gap after the span that overlaps it most never finds an
+    enclosing span winning every gap. A burst that is chained from
+    suspends nothing: its fetch and commit follow the next burst's
+    ``decode_dispatch``."""
 
     __slots__ = ("_engine", "_name", "_outer", "_t0", "_span")
 
@@ -167,6 +169,11 @@ class _Phase:
 
 def _round_up(n: int, multiple: int) -> int:
     return -(-n // multiple) * multiple
+
+
+def _same_lanes(a: list, b: list) -> bool:
+    """The same sequences in the same lanes (identity, not equality)."""
+    return len(a) == len(b) and all(x is y for x, y in zip(a, b))
 
 
 @jax.jit
@@ -226,29 +233,6 @@ class EngineConfig:
     #: per lane has nothing to shard). Composes with tp (mesh is sp × tp);
     #: requires sp | prefill_bucket.
     sp: int = 1
-    #: pipeline fused decode bursts: dispatch burst N+1 (input tokens
-    #: chained on-device from burst N's last sampled token) BEFORE
-    #: fetching/committing burst N, hiding per-iteration host work
-    #: (dispatch, fetch, commit bookkeeping) under device execution.
-    #: Needs decode_steps_per_iter > 1. Commit bookkeeping lags one burst;
-    #: any lane-set change (prefill scheduled, preemption, finish) drains
-    #: first, so greedy results are bit-identical to the unpipelined
-    #: engine. (temperature>0 streams are identically DISTRIBUTED but not
-    #: bit-identical across the two modes: discarded surplus bursts
-    #: consume extra splits of the engine rng.)
-    decode_pipeline: bool = False
-    #: device-resident decode fast path (``DECODE_FUSED_SAMPLING``): keep
-    #: per-sequence last-token ids and positions/lengths ON DEVICE across
-    #: engine iterations at ANY ``decode_steps_per_iter`` (the pipelined
-    #: double-buffering above, extended down to k=1 — every steady-state
-    #: decode step chains from the previous dispatch's on-device sample
-    #: instead of a host round-trip), and start the batched D2H copy of
-    #: each burst's sampled tokens ASYNC right after the dispatch, so the
-    #: bytes land while the next step executes instead of blocking the
-    #: commit. Greedy outputs are bit-identical to the unfused engine
-    #: (same drain rules as decode_pipeline; the same temperature>0
-    #: rng-split caveat applies). Off by default = legacy behavior.
-    decode_fused_sampling: bool = False
     #: prefill attention implementation: "pallas" (flash kernel), "xla"
     #: (scan), or "auto" — the flash kernel, except that ``interpret``
     #: (CPU tests) and ``kv_quant_hbm`` (the kernel reads pages
@@ -387,26 +371,19 @@ class Engine:
         self.model_cfg = cfg
         ps = config.block_manager.page_size
         self.page_size = ps
-        # decode_fused_sampling keeps the burst machinery live at any k
-        # (k=1 pipelining is exactly the device-resident step-per-token
-        # loop); decode_pipeline alone still needs k > 1 to pay off.
-        self._pipeline = (
-            config.decode_pipeline and config.decode_steps_per_iter > 1
-        ) or config.decode_fused_sampling
         # Width includes fused-burst headroom: a sequence finishing at
         # max_model_len mid-burst keeps writing its surplus KV into reserved
-        # pages of its own row, never into another sequence's pages.
-        # Pipelining keeps up to TWO bursts in flight.
-        bursts_in_flight = 2 if self._pipeline else 1
+        # pages of its own row, never into another sequence's pages. A
+        # burst chained from one in flight needs no more: it is enqueued
+        # only where no lane comes within a burst of max_model_len
+        # (``_next_schedule_decided``), so its last write lies no further
+        # out than a lone burst's.
         # ... and, for block diffusion, the block a sequence that stops at
         # max_model_len had opened past it.
         self.max_pages_per_seq = -(
             -(
                 config.max_model_len
-                + max(
-                    config.decode_steps_per_iter * bursts_in_flight - 1,
-                    cfg.block_length,
-                )
+                + max(config.decode_steps_per_iter - 1, cfg.block_length)
             )
             // ps
         )
@@ -509,9 +486,8 @@ class Engine:
                     config.kv_quant_hbm is not None,
                 "spec_decode (a block is not one drafted token)":
                     config.spec_decode != "off",
-                "decode_steps_per_iter > 1, decode_pipeline and "
-                "decode_fused_sampling (one forward of a block a dispatch)":
-                    config.decode_steps_per_iter > 1 or self._pipeline,
+                "decode_steps_per_iter > 1 (one forward of a block a "
+                "dispatch)": config.decode_steps_per_iter > 1,
             }
             for what, on in refused.items():
                 if on:
@@ -841,15 +817,19 @@ class Engine:
         #: ``STEP_PHASES`` (``<phase>_s``, through ``phase``), and beside
         #: them the sums that predate the split: ``prefill_s`` /
         #: ``decode_s`` (their five phases), ``sample_s`` (the two
-        #: fetches: the blocking share of the sampled-token device_get,
-        #: near zero where the fused fast path's async copy already landed
-        #: the bytes). ``gather_s`` (host<->device page moves) and
+        #: fetches: the blocking share of the sampled-token device_get;
+        #: for a burst that was chained from, the wait for that burst
+        #: while the next is queued behind it). ``gather_s`` (host<->device
+        #: page moves) and
         #: ``demote_s`` (remote-tier demotion payload builds) are slices
         #: INSIDE other phases and get no span. Counters: ``steps``,
         #: ``decode_dispatches``, ``decode_rows`` (real lanes of each
         #: decode dispatch, summed), ``decode_sampled_dispatches`` (those
         #: with a ``temperature > 0`` lane: the sampler's gate runs its
-        #: vocabulary filter in these and in no other); prefill dispatches
+        #: vocabulary filter in these and in no other),
+        #: ``decode_chained_dispatches`` (those whose input ids came from
+        #: the burst in flight, on the device: enqueued before that
+        #: burst's tokens were fetched); prefill dispatches
         #: are counted, always, in ``prefill_stats``. Block diffusion
         #: (``_run_decode_block``, beside those three):
         #: ``denoise_lane_forwards`` (lanes x dispatches in which the lane
@@ -869,6 +849,7 @@ class Engine:
             "decode_dispatches": 0,
             "decode_rows": 0,
             "decode_sampled_dispatches": 0,
+            "decode_chained_dispatches": 0,
             "denoise_lane_forwards": 0,
             "commit_lane_forwards": 0,
             "block_tokens_fixed": 0,
@@ -882,12 +863,13 @@ class Engine:
             "demote_s": 0.0,
             **{f"{name}_s": 0.0 for name in STEP_PHASES},
         }
-        #: the phase open right now (a pipelined burst drained inside it
-        #: suspends and resumes it: ``_Phase``)
+        #: the phase open right now (the burst in flight, drained inside
+        #: it, suspends and resumes it: ``_Phase``)
         self._open_phase: Optional[_Phase] = None
-        #: in-flight fused decode burst (decode_pipeline): toks device
-        #: array, lane-ordered active list, and the np position/len arrays
-        #: the NEXT burst derives from.
+        #: the decode burst left on the device over the end of a step
+        #: (``_next_schedule_decided``): toks device array, lane-ordered
+        #: active list, and the np position/len arrays the NEXT burst
+        #: derives from. None whenever a lane is free.
         self._inflight: Optional[dict] = None
 
     def _dev(self, x, dtype=None) -> jax.Array:
@@ -1277,6 +1259,10 @@ class Engine:
             and not self._pending_demotions
         ):
             return
+        # The rate samples below time fenced copies; a decode burst still
+        # on the device (``_inflight``) would be waited for inside the
+        # first of them and read as a slow link by the cost model.
+        jax.block_until_ready(self.k_pages)
         t_flush = time.perf_counter() if self.obs_step_timing else 0.0
         # One batched gather for every device page any queued move reads
         # (demotion snapshots ride the same gather as offloads/restores).
@@ -1885,7 +1871,7 @@ class Engine:
                 break
         if seq is None:
             return None
-        # An in-flight pipelined burst may hold this lane on device: commit
+        # The burst in flight may hold this lane on device: commit
         # it first so batchmates keep their tokens and the lane set the
         # next dispatch sees matches scheduler state.
         if self._inflight is not None and any(
@@ -2109,7 +2095,7 @@ class Engine:
             return False
         if seq.num_generated >= seq.sampling.max_new_tokens:
             return True
-        if seq.all_tokens[-1] in seq.sampling.stop_token_ids:
+        if seq.last_token in seq.sampling.stop_token_ids:
             return True
         if seq.deadline is not None and time.monotonic() >= seq.deadline:
             # Past-deadline running lane: finish with what it has — the
@@ -2308,6 +2294,38 @@ class Engine:
         # logits API for tests and external callers.
         self._run_decode_fused(seqs)
 
+    def _next_schedule_decided(self, active: list[Sequence], k: int) -> bool:
+        """The rule for running one decode dispatch ahead, read when the
+        burst over ``active`` has been enqueued: may it stay on the device
+        over the end of this step, so that the next step enqueues its
+        successor before fetching it? Only where the next schedule is
+        already decided, the same lanes decoding again:
+
+        - the scheduler could admit nothing (``admission_closed``) and
+          every running lane is in this burst. With a lane free an arrival
+          must find no burst in the way of its prefill;
+        - no lane reaches its token budget (``max_new_tokens``,
+          ``max_model_len``) within this burst, which the host knows before
+          the burst returns: a successor's prefill must not wait a step,
+          and no surplus row is computed. (A lane the commit just before
+          found finished, by a stop token or a deadline, leaves the same
+          way.)
+
+        What it cannot foresee — a stop token inside the burst, a deadline,
+        an abort, a preemption, a migration — meets the drains."""
+        if not (
+            _same_lanes(active, self.scheduler.running)
+            and self.scheduler.admission_closed()
+        ):
+            return False
+        limit = self.config.max_model_len
+        return not any(
+            seq.num_generated + k >= seq.sampling.max_new_tokens
+            or seq.num_tokens + k >= limit
+            or self._should_finish(seq)
+            for seq in active
+        )
+
     def _run_decode_fused(self, seqs: list[Sequence]) -> None:
         """Fused multi-token decode: reserve page capacity for the whole
         burst up front, run ``decode_steps`` (on-device sampling, single
@@ -2316,65 +2334,59 @@ class Engine:
         sequence owns (or reserved page 0 for padded lanes) and are never
         registered in the prefix cache, so discarding them is safe.
 
-        With ``decode_pipeline``, burst N+1 is dispatched BEFORE burst N
-        is fetched: its input tokens are chained on-device from burst N's
-        last sampled token, so host work (fetch, commit, next dispatch)
-        overlaps device execution. The pipeline only continues while the
-        lane set is unchanged and no lane is about to finish; anything
-        else drains first, making greedy results identical to the
-        unpipelined engine (a finished/preempted lane's surplus burst is
-        discarded by the same rules as surplus tokens within a burst).
-        temperature>0 streams are identically distributed but not
-        bit-identical across modes — discarded surplus bursts consume
-        extra engine-rng splits."""
+        One dispatch ahead where that is free: a burst that
+        ``_next_schedule_decided`` left in flight is not fetched before its
+        successor is enqueued. The successor's input ids are that burst's
+        own sampled ids, still on the device, so the host's work of a step
+        (commit, publish, schedule, build, uploads, dispatch) runs while
+        the device does. The chain only continues while the lane set is
+        unchanged; anything else drains first, making greedy results
+        identical to the engine that never runs ahead (a finished or
+        preempted lane's surplus burst is discarded by the same rules as
+        surplus tokens within a burst). temperature>0 streams are
+        identically distributed but not bit-identical — discarded surplus
+        bursts consume extra engine-rng splits."""
         k = self.config.decode_steps_per_iter
         lanes = self.config.decode_batch_size
         assert len(seqs) <= lanes
 
         prev = self._inflight
         if prev is not None:
-            # Drain when the pipeline cannot (or should not) continue:
-            # different lane set, or every lane reaches its token budget
-            # within the in-flight burst (pipelining then only produces a
-            # surplus burst that gets discarded).
-            same_lanes = len(prev["active"]) == len(seqs) and all(
-                a is b for a, b in zip(prev["active"], seqs)
-            )
-            all_done_after_prev = all(
-                s.num_generated + k >= s.sampling.max_new_tokens for s in seqs
-            )
-            if not same_lanes or all_done_after_prev:
+            # What the rule could not foresee changed the lane set (a stop
+            # token, a deadline, an abort, a preemption): commit first.
+            if not _same_lanes(prev["active"], seqs):
                 self._drain_inflight()
                 prev = None
 
         # Commit lag means any drain can finish lanes mid-call; never
         # reserve pages for (or redispatch) a finished sequence — the
-        # unpipelined engine would have finished it a step() ago.
+        # engine that never runs ahead would have finished it a step() ago.
         seqs = [s for s in seqs if not self._should_finish(s)]
         if not seqs:
             return
 
         with self.phase("decode_build"):
-            # Reserve capacity for the burst's growth per sequence (× 2 when a
-            # previous burst is still in flight); preemption inside reservation
-            # may knock batchmates out of `seqs` — or the in-flight set.
-            reserve = k * (2 if self._pipeline else 1)
+            # Reserve capacity for the burst's growth per sequence (× 2 over
+            # a burst still in flight, whose tokens are not yet counted);
+            # preemption inside reservation may knock batchmates out of
+            # `seqs` — or the in-flight set.
+            reserve = k * (2 if prev is not None else 1)
             for seq in seqs:
                 # The finished re-check matters after a mid-loop degrade-drain
                 # (below): committing the lagged burst can finish any lane, and
                 # reserving (worse: preempting a batchmate, or aborting) for a
-                # sequence that already completed is the unpipelined engine's
+                # sequence that already completed is the waiting engine's
                 # never-happens case.
                 if not seq.block_table or self._should_finish(seq):
                     continue
                 if reserve > k:
                     # Double-burst headroom is an optimization, not a
                     # requirement: when the pool is too tight for it, drain and
-                    # degrade to the unpipelined reservation rather than
-                    # preempting/aborting lanes the unpipelined engine would
+                    # degrade to the single reservation rather than
+                    # preempting/aborting lanes the waiting engine would
                     # complete. (Preemption stays reserved for genuine
                     # single-burst pressure below, keeping behavior identical
-                    # to decode_pipeline=False under the same pool.)
+                    # to the engine that never runs ahead under the same pool.)
                     try:
                         self.block_manager.reserve_slots(seq, reserve)
                         continue
@@ -2390,10 +2402,8 @@ class Engine:
                 s for s in seqs if s.block_table and not self._should_finish(s)
             ]
             if prev is not None:
-                same = len(prev["active"]) == len(active) and all(
-                    a is b for a, b in zip(prev["active"], active)
-                )
-                if not same:  # reservation preempted an in-flight lane
+                # reservation preempted an in-flight lane
+                if not _same_lanes(prev["active"], active):
                     self._drain_inflight()
                     prev = None
                     active = [s for s in active if not self._should_finish(s)]
@@ -2425,9 +2435,12 @@ class Engine:
                 positions = np.where(was_active, prev["positions"] + k, 0)
                 seq_lens = np.where(was_active, prev["seq_lens"] + k, 0)
             else:
-                tokens = np.zeros((lanes,), np.int32)
+                # The program takes its input ids from the last column of a
+                # burst's [lanes, k] output; an unchained dispatch hands it
+                # the same shape, so both are one compiled program.
+                tokens = np.zeros((lanes, k), np.int32)
                 for i, seq in enumerate(active):
-                    tokens[i] = seq.all_tokens[-1]
+                    tokens[i, -1] = seq.last_token
                     positions[i] = seq.num_tokens - 1
                     seq_lens[i] = seq.num_tokens
 
@@ -2437,11 +2450,12 @@ class Engine:
             # overwrites) and immediately before the device call.
             self._flush_page_moves()
             self._rng, key = jax.random.split(self._rng)
-            if prev is not None:
-                # chained: the burst's last sampled token stays on device
-                tokens_dev = prev["toks"][:, -1]
-            else:
-                tokens_dev = self._dev(tokens)
+            # chained: the burst's sampled ids stay on the device, placed
+            # as an upload is (no copy where they already lie so)
+            tokens_dev = (
+                jax.device_put(prev["toks"], self._replicated)
+                if prev is not None else self._dev(tokens)
+            )
             positions_d, block_tables_d = (
                 self._dev(positions), self._dev(block_tables)
             )
@@ -2477,14 +2491,14 @@ class Engine:
                     toks, self.k_pages, self.v_pages,
                     self.k_scales, self.v_scales,
                 ) = out
-        self._count_decode_dispatch(len(active), temperature, seq_lens, k)
-        if self.config.decode_fused_sampling:
-            # Start the batched D2H copy of this burst's sampled ids NOW,
-            # overlapped with whatever dispatches next — by the time the
-            # lagged commit calls np.asarray the bytes are already on the
-            # host, collapsing the per-step device_get to ~zero exposed
-            # time. Purely a transfer hint: results are unchanged.
-            toks.copy_to_host_async()
+        self._count_decode_dispatch(
+            len(active), temperature, seq_lens, k, chained=prev is not None
+        )
+        # Start the D2H copy of the sampled ids now: the bytes land while
+        # the host goes on (with a burst chained behind this one, while
+        # that one runs), so the fetch finds them on the host. A transfer
+        # hint: results are unchanged.
+        toks.copy_to_host_async()
         burst = {
             "toks": toks,
             "active": active,
@@ -2496,7 +2510,7 @@ class Engine:
             # Commit burst N while burst N+1 executes on device.
             self._inflight = None
             self._commit_burst(prev)
-        if self._pipeline:
+        if self._next_schedule_decided(active, k):
             self._inflight = burst
         else:
             self._commit_burst(burst)
@@ -2585,7 +2599,7 @@ class Engine:
         — outputs remain exact samples of the verify logits, but
         cross-path bit-equality is not guaranteed on TPU. Sampled lanes
         consume the engine rng differently from plain decode (identically
-        DISTRIBUTED, not bit-identical — the pipelined-burst caveat).
+        DISTRIBUTED, not bit-identical — the caveat of a burst run ahead).
 
         Rejected drafts leave stale K/V in slots the sequence already owns
         beyond ``num_computed``; nothing ever attends past ``seq_len`` and
@@ -3071,10 +3085,12 @@ class Engine:
     def _count_decode_dispatch(
         self, rows: int, temperature: np.ndarray,
         seq_lens: Optional[np.ndarray] = None, steps: int = 1,
+        chained: bool = False,
     ) -> None:
         """``step_stats``' counters of one decode dispatch: its real lanes,
         whether any of them samples (``temperature`` is the host-side
-        array the dispatch was given) and, for a latent pool, the context
+        array the dispatch was given), whether its input ids came from the
+        burst in flight (``chained``) and, for a latent pool, the context
         rows its ``steps`` fused steps read a layer (``seq_lens``: the
         host-side lengths of the dispatch, 0 for a lane that is not real;
         a lane's context grows by one a step)."""
@@ -3084,6 +3100,7 @@ class Engine:
             self.step_stats["decode_sampled_dispatches"] += bool(
                 (temperature > 0).any()
             )
+            self.step_stats["decode_chained_dispatches"] += chained
             if seq_lens is not None and self.model_cfg.kv_lora_rank:
                 self.step_stats["latent_ctx_tokens"] += int(
                     steps * seq_lens.sum()

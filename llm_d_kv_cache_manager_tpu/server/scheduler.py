@@ -203,6 +203,23 @@ class Scheduler:
             )
         return shed
 
+    def admission_closed(self) -> bool:
+        """True when the next ``schedule()`` can hand out no prefill work,
+        whatever arrives before it: no chunk is owed and either no lane is
+        free or the head of ``waiting`` cannot allocate (FCFS: nothing
+        passes it) — the two tests the admission walks below make. With a
+        lane free and nobody waiting an arrival would be admitted: open.
+        The engine reads this to decide whether the next decode dispatch
+        may be enqueued before this one's tokens are fetched."""
+        if self.prefilling:
+            return False
+        if len(self.running) >= self.config.max_running:
+            return True
+        if self.qos_enabled:
+            return False  # an arrival of a higher class becomes the head
+        head = next((s for s in self.waiting if not s.importing), None)
+        return head is not None and not self.block_manager.can_allocate(head)
+
     def schedule(self) -> ScheduleOutput:
         """Pick the work for one engine step."""
         if self.config.chunked_prefill_tokens is not None:

@@ -203,6 +203,12 @@ class Sequence:
         return self.prompt_tokens + self.output_tokens
 
     @property
+    def last_token(self) -> int:
+        """``all_tokens[-1]`` without building the list: the engine asks
+        several times a lane a step, and a prompt may be 30k tokens."""
+        return (self.output_tokens or self.prompt_tokens)[-1]
+
+    @property
     def num_tokens(self) -> int:
         return len(self.prompt_tokens) + len(self.output_tokens)
 

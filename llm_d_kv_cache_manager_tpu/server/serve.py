@@ -27,7 +27,7 @@ cross-pod KV transfer plane
 blocking submission), the remote capacity tier (``REMOTE_TIER`` demotes
 last-copy evictions to ``REMOTE_PEERS`` / accepts pushes into a
 ``REMOTE_STORE_PAGES``-sized store; ``POD_ROLE=kvstore`` is a dedicated
-holder) and the decode fast path (``DECODE_FUSED_SAMPLING``).
+holder).
 
 Run: ``python -m llm_d_kv_cache_manager_tpu.server.serve``
 """
@@ -356,6 +356,14 @@ class _ServingMetrics:
                 registry=self.registry,
             )
             self._latent_ctx_seen = 0
+            self.engine_chained = prom.Counter(
+                "kvcache_engine_decode_chained_dispatches_total",
+                "Decode dispatches enqueued one ahead: their input ids came "
+                "from the burst in flight, on the device, before its "
+                "tokens were fetched",
+                registry=self.registry,
+            )
+            self._chained_seen = 0
             self.kv_bytes_per_token_g = prom.Gauge(
                 "kvcache_kv_bytes_per_token",
                 "Bytes one token holds in the KV pools, all layers, as held "
@@ -580,6 +588,10 @@ class _ServingMetrics:
         if latent > self._latent_ctx_seen:
             self.engine_latent_ctx.inc(latent - self._latent_ctx_seen)
             self._latent_ctx_seen = latent
+        chained = step_stats.get("decode_chained_dispatches", 0)
+        if chained > self._chained_seen:
+            self.engine_chained.inc(chained - self._chained_seen)
+            self._chained_seen = chained
         if lag_s is not None:
             self.engine_loop_lag.set(lag_s)
 
@@ -1139,14 +1151,6 @@ class PodServerConfig:
         eng.decode_steps_per_iter = int(
             os.environ.get("DECODE_STEPS_PER_ITER", eng.decode_steps_per_iter)
         )
-        # Pipeline fused-decode bursts (host/device overlap); needs
-        # DECODE_STEPS_PER_ITER > 1 to take effect.
-        eng.decode_pipeline = _env_bool("DECODE_PIPELINE", "0")
-        # Device-resident decode fast path: last-token ids/lengths stay on
-        # device across steps at any burst width, and the sampled-token
-        # device_get becomes one async transfer overlapping the next
-        # dispatch. Off = bit-identical legacy decode.
-        eng.decode_fused_sampling = _env_bool("DECODE_FUSED_SAMPLING", "0")
         # Speculative decoding ("off" | "prompt_lookup") + its knobs.
         eng.spec_decode = os.environ.get("SPEC_DECODE", eng.spec_decode)
         eng.spec_k = int(os.environ.get("SPEC_K", eng.spec_k))

@@ -485,7 +485,7 @@ def test_block_mask_in_the_attention_paths(name):
 # -- refusals, the loader, the API ---------------------------------------------
 @pytest.mark.parametrize("what", [
     dict(sp=2), dict(kv_quant_hbm="int8"), dict(spec_decode="prompt_lookup"),
-    dict(decode_steps_per_iter=2), dict(decode_fused_sampling=True),
+    dict(decode_steps_per_iter=2), dict(decode_steps_per_iter=4),
     dict(block_manager=BlockManagerConfig(total_pages=16, page_size=6)),
 ])
 def test_engine_refuses_by_name(what):

@@ -279,14 +279,16 @@ class TestChunkedInterference:
         "kw",
         [
             dict(decode_steps_per_iter=3),
-            dict(decode_steps_per_iter=3, decode_pipeline=True),
+            # three lanes for the three requests: with lanes full the
+            # engine runs one dispatch ahead, beside the chunks too
+            dict(decode_steps_per_iter=3, decode_batch=3),
             dict(spec_decode="prompt_lookup", spec_k=3, spec_ngram=2),
             dict(spec_decode="prompt_lookup", spec_k=3, spec_ngram=2,
                  spec_rounds=3),
         ],
     )
     def test_parity_with_other_decode_paths(self, kw):
-        # Chunked ingest composes with fused/pipelined/speculative decode:
+        # Chunked ingest composes with fused/run-ahead/speculative decode:
         # token streams stay identical to the unchunked engine running the
         # same decode config.
         rep = _prompt(20, 3) * 6  # repetition-heavy lane (exercises spec)

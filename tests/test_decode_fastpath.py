@@ -3,10 +3,10 @@ decode loop.
 
 The acceptance pins of the subsystem:
 
-- ``DECODE_FUSED_SAMPLING`` off (default) = bit-identical legacy decode;
-  on = greedy outputs identical to the unfused engine at every burst
-  width, including k=1 (the device-resident step-per-token loop) and
-  composed with ``decode_pipeline``.
+- the device-resident decode loop (one dispatch ahead wherever lanes are
+  full and no budget is near: ``Engine._next_schedule_decided``) gives
+  greedy outputs identical to the engine that never runs ahead at every
+  burst width, including k=1 (the step-per-token loop).
 - ``ASYNC_PULL`` off = the legacy blocking pull flow untouched; on = a
   pull-routed request imports its warm prefix on a worker thread while
   queued ``importing``, the scheduler admits it only once the blocks
@@ -39,11 +39,11 @@ MODEL = "tiny-llama"
 
 def _engine_cfg(total_pages=64, **kw):
     kw.setdefault("scheduler", SchedulerConfig(max_prefill_batch=4))
+    kw.setdefault("decode_batch_size", 4)
     return EngineConfig(
         model=TINY_LLAMA,
         block_manager=BlockManagerConfig(total_pages=total_pages, page_size=PS),
         max_model_len=64,
-        decode_batch_size=4,
         prefill_bucket=8,
         interpret=True,
         **kw,
@@ -77,70 +77,71 @@ def _wait_until(cond, timeout=30.0, interval=0.01):
 
 
 class TestFusedSampling:
-    """Device-resident decode loop: greedy parity at every knob setting."""
+    """Device-resident decode loop: greedy parity, with the engine that
+    never runs ahead, at every burst width."""
 
     PROMPTS = [(0, 10), (1, 17), (2, 5)]
 
-    def _run(self, **kw):
-        eng = Engine(_engine_cfg(**kw))
-        seqs = [
-            eng.add_request(_prompt(s, n), SamplingParams(max_new_tokens=8))
-            for s, n in self.PROMPTS
-        ]
-        eng.run_until_complete()
-        assert all(s.error is None for s in seqs)
-        return [s.generated_tokens for s in seqs]
+    def _both(self, drive, monkeypatch, **kw):
+        from test_run_ahead import both
 
-    def test_greedy_parity_all_modes(self):
-        base = self._run()
-        for kw in (
-            dict(decode_fused_sampling=True),
-            dict(decode_fused_sampling=True, decode_steps_per_iter=2),
-            dict(
-                decode_fused_sampling=True,
-                decode_steps_per_iter=4,
-                decode_pipeline=True,
-            ),
-        ):
-            assert self._run(**kw) == base, kw
+        return both(lambda: Engine(_engine_cfg(**kw)), drive, monkeypatch)
 
-    def test_fused_k1_enables_pipeline(self):
-        eng = Engine(_engine_cfg(decode_fused_sampling=True))
-        assert eng._pipeline  # device-resident loop live at k=1
-        legacy = Engine(_engine_cfg())
-        assert not legacy._pipeline
-
-    def test_parity_under_pool_pressure_with_preemption(self):
-        # A pool too small for every lane forces preemption mid-burst;
-        # the fused path must recover to the same greedy outputs.
-        base = []
-        for fused in (False, True):
-            eng = Engine(
-                _engine_cfg(total_pages=14, decode_fused_sampling=fused)
-            )
+    @pytest.mark.parametrize("k", [1, 2, 4])
+    def test_greedy_parity_all_widths(self, k, monkeypatch):
+        def drive(eng):
             seqs = [
-                eng.add_request(_prompt(s, 9), SamplingParams(max_new_tokens=10))
+                eng.add_request(_prompt(s, n), SamplingParams(max_new_tokens=18))
+                for s, n in self.PROMPTS
+            ]
+            eng.run_until_complete()
+            assert all(s.error is None for s in seqs)
+            return [s.generated_tokens for s in seqs]
+
+        # three lanes for three requests: lanes full, so the rule holds
+        self._both(
+            drive, monkeypatch, decode_batch_size=3, decode_steps_per_iter=k
+        )
+
+    def test_k1_runs_ahead_with_lanes_full_and_not_with_one_free(self):
+        for lanes, ahead in ((1, True), (4, False)):
+            eng = Engine(_engine_cfg(decode_batch_size=lanes))
+            eng.add_request(_prompt(0, 10), SamplingParams(max_new_tokens=12))
+            eng.step()
+            eng.step()
+            assert (eng._inflight is not None) == ahead
+            eng.run_until_complete()
+            assert eng._inflight is None
+
+    def test_parity_under_pool_pressure_with_preemption(self, monkeypatch):
+        # A pool too small for every lane forces preemption mid-burst;
+        # running ahead must recover to the same greedy outputs.
+        def drive(eng):
+            seqs = [
+                eng.add_request(_prompt(s, 9), SamplingParams(max_new_tokens=16))
                 for s in (3, 4)
             ]
             eng.run_until_complete()
             assert all(s.error is None for s in seqs)
-            base.append([s.generated_tokens for s in seqs])
-        assert base[0] == base[1]
+            return [s.generated_tokens for s in seqs]
 
-    def test_warm_cache_hit_parity(self):
-        # Second request shares a prefix: the fused engine must serve the
-        # hit identically (register_full_pages lags one burst on commit).
+        self._both(drive, monkeypatch, total_pages=10, decode_batch_size=2)
+
+    def test_warm_cache_hit_parity(self, monkeypatch):
+        # Second request shares a prefix: the engine that runs ahead must
+        # serve the hit identically (register_full_pages lags one burst on
+        # commit).
         prefix = _prompt(5, 12)
-        outs = []
-        for fused in (False, True):
-            eng = Engine(_engine_cfg(decode_fused_sampling=fused))
-            a = eng.add_request(prefix + _prompt(6, 4), SamplingParams(max_new_tokens=6))
+
+        def drive(eng):
+            a = eng.add_request(prefix + _prompt(6, 4), SamplingParams(max_new_tokens=12))
             eng.run_until_complete()
-            b = eng.add_request(prefix + _prompt(7, 4), SamplingParams(max_new_tokens=6))
+            b = eng.add_request(prefix + _prompt(7, 4), SamplingParams(max_new_tokens=12))
             eng.run_until_complete()
             assert b.num_cached_prompt >= PS
-            outs.append((a.generated_tokens, b.generated_tokens))
-        assert outs[0] == outs[1]
+            return (a.generated_tokens, b.generated_tokens)
+
+        self._both(drive, monkeypatch, decode_batch_size=1)
 
     def test_sample_phase_recorded(self):
         eng = Engine(_engine_cfg())

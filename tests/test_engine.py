@@ -488,26 +488,25 @@ class TestFusedDecode:
             assert len(s.output_tokens) == 8
 
 
-class TestDecodePipeline:
-    """decode_pipeline=True: burst N+1 dispatched before burst N commits.
+class TestRunAhead:
+    """One decode dispatch ahead (``Engine._next_schedule_decided``): burst
+    N+1 is enqueued before burst N commits wherever lanes are full and no
+    budget is near.
 
     Invariant under test (engine.py ``_run_decode_fused`` docstring): the
-    pipelined token streams are IDENTICAL to the unpipelined fused engine
-    across every drain edge — staggered arrivals (lane-set change),
-    preemption inside reservation, stop tokens, and max-token truncation
-    that is not a multiple of the burst.
+    token streams under the rule are IDENTICAL to those of the same engine
+    whose rule is patched to "never", across every drain edge — staggered
+    arrivals (lane-set change), preemption inside reservation, stop
+    tokens, and max-token truncation that is not a multiple of the burst.
     """
 
-    def _outputs(self, drive, **kw):
-        outs = []
-        for pipelined in (False, True):
-            eng = _engine(
-                decode_steps_per_iter=4, decode_pipeline=pipelined, **kw
-            )
-            outs.append(drive(eng))
-        return outs
+    def _outputs(self, drive, monkeypatch, **kw):
+        from test_run_ahead import both
 
-    def test_pipelined_greedy_matches_unpipelined(self):
+        kw.setdefault("decode_steps_per_iter", 4)
+        return both(lambda: _engine(**kw), drive, monkeypatch)
+
+    def test_ahead_greedy_matches_waiting(self, monkeypatch):
         prompts = [_prompt(20 + i, 9 + i) for i in range(3)]
 
         def drive(eng):
@@ -518,34 +517,32 @@ class TestDecodePipeline:
             eng.run_until_complete()
             return [s.generated_tokens for s in seqs]
 
-        base, piped = self._outputs(drive)
-        assert base == piped
-        # 13 % 4 != 0: the final partial burst (and any surplus pipelined
-        # burst) must be truncated identically.
-        assert all(len(toks) == 13 for toks in base)
+        toks = self._outputs(drive, monkeypatch, decode_batch=3)
+        # 13 % 4 != 0: the final partial burst must be truncated
+        # identically, and no surplus burst is enqueued behind it.
+        assert all(len(t) == 13 for t in toks)
 
-    def test_staggered_arrival_lane_change_drains(self):
+    def test_staggered_arrival_lane_change_drains(self, monkeypatch):
         # A second request arriving mid-generation forces a prefill (and
-        # thus a pipeline drain + lane-set change) between decode bursts.
+        # thus a lane-set change) between decode bursts; with both lanes
+        # taken the engine then runs ahead.
         def drive(eng):
-            a = eng.add_request(_prompt(30, 8), SamplingParams(max_new_tokens=12))
+            a = eng.add_request(_prompt(30, 8), SamplingParams(max_new_tokens=24))
             for _ in range(3):
                 eng.step()
-            b = eng.add_request(_prompt(31, 10), SamplingParams(max_new_tokens=12))
+                assert eng._inflight is None  # a lane is free
+            b = eng.add_request(_prompt(31, 10), SamplingParams(max_new_tokens=24))
             eng.run_until_complete()
             return [a.generated_tokens, b.generated_tokens]
 
-        base, piped = self._outputs(drive)
-        assert base == piped
-        assert all(len(toks) == 12 for toks in base)
+        toks = self._outputs(drive, monkeypatch, decode_batch=2)
+        assert all(len(t) == 24 for t in toks)
 
-    def test_pipelined_preemption_tiny_pool(self):
+    def test_ahead_preemption_tiny_pool(self, monkeypatch):
         # Pool sized to force preemption during burst reservation — the
-        # in-flight burst's lane may be knocked out, and the 2x pipelined
-        # headroom must degrade to the unpipelined reservation instead of
-        # aborting lanes the unpipelined engine completes.
-        from llm_d_kv_cache_manager_tpu.server.block_manager import AllocationError
-
+        # in-flight burst's lane may be knocked out, and the 2x headroom
+        # of a chained dispatch must degrade to the single reservation
+        # instead of aborting lanes the waiting engine completes.
         def drive(eng):
             bm = eng.block_manager
             orig = bm.reserve_slots
@@ -560,7 +557,7 @@ class TestDecodePipeline:
 
             bm.reserve_slots = spy
             seqs = [
-                eng.add_request(_prompt(10 + i, 8), SamplingParams(max_new_tokens=8))
+                eng.add_request(_prompt(10 + i, 8), SamplingParams(max_new_tokens=16))
                 for i in range(3)
             ]
             eng.run_until_complete()
@@ -568,50 +565,53 @@ class TestDecodePipeline:
             assert all(s.error is None for s in seqs)
             return [s.generated_tokens for s in seqs]
 
-        base, piped = self._outputs(drive, total_pages=12, decode_batch=3)
-        assert base == piped
-        assert all(len(toks) == 8 for toks in base)
+        toks = self._outputs(drive, monkeypatch, total_pages=14, decode_batch=3)
+        assert all(len(t) == 16 for t in toks)
 
-    def test_pipelined_stop_token_truncates(self):
+    def test_ahead_stop_token_truncates(self, monkeypatch):
+        # The stop token lies in the second burst of a chain: the third is
+        # on the device when it is found, and is discarded whole.
         probe_eng = _engine(decode_steps_per_iter=4)
-        probe = probe_eng.add_request(_prompt(2, 8), SamplingParams(max_new_tokens=3))
+        probe = probe_eng.add_request(_prompt(2, 8), SamplingParams(max_new_tokens=12))
         probe_eng.run_until_complete()
-        stop = probe.output_tokens[1]
+        out = probe.output_tokens
+        at = next(i for i in range(5, 9) if out[i] not in out[:i])
+        stop = out[at]
 
         def drive(eng):
             seq = eng.add_request(
                 _prompt(2, 8),
-                SamplingParams(max_new_tokens=8, stop_token_ids=(stop,)),
+                SamplingParams(max_new_tokens=40, stop_token_ids=(stop,)),
             )
             eng.run_until_complete()
             return seq.generated_tokens
 
-        base, piped = self._outputs(drive)
-        assert base == piped
-        assert piped[-1] == stop and len(piped) == 2
+        toks = self._outputs(drive, monkeypatch, decode_batch=1)
+        assert toks[-1] == stop and len(toks) == at + 1
 
-    def test_pipelined_prefix_cache_still_consistent(self):
+    def test_ahead_prefix_cache_still_consistent(self, monkeypatch):
         # Pages registered while a burst is in flight must only cover
         # committed tokens; a same-prefix follow-up must reproduce tokens.
         p = _prompt(3, 16)
 
         def drive(eng):
-            a = eng.add_request(p, SamplingParams(max_new_tokens=6))
+            a = eng.add_request(p, SamplingParams(max_new_tokens=14))
             eng.run_until_complete()
-            b = eng.add_request(p, SamplingParams(max_new_tokens=6))
+            b = eng.add_request(p, SamplingParams(max_new_tokens=14))
             eng.run_until_complete()
             assert b.num_cached_prompt > 0
             return [a.generated_tokens, b.generated_tokens]
 
-        base, piped = self._outputs(drive)
-        assert base == piped
+        self._outputs(drive, monkeypatch, decode_batch=1)
 
     def test_inactive_lane_sentinel_preserved_when_chaining(self):
         # White-box: when burst N+1 chains on-device from burst N, only
         # previously-active lanes advance; padded lanes keep the
         # documented 0 = inactive sentinel (no garbage attention, no KV
-        # writes into reserved page 0).
-        eng = _engine(decode_batch=4, decode_steps_per_iter=2, decode_pipeline=True)
+        # writes into reserved page 0). Two of four lanes are free, so the
+        # rule itself would wait: the predicate is made to say yes.
+        eng = _engine(decode_batch=4, decode_steps_per_iter=2)
+        eng._next_schedule_decided = lambda active, k: True
         seqs = [
             eng.add_request(_prompt(40 + i, 8), SamplingParams(max_new_tokens=20))
             for i in range(2)
@@ -626,16 +626,19 @@ class TestDecodePipeline:
         assert (burst["seq_lens"][:2] > 0).all()
         eng._drain_inflight()
 
-    def test_env_knob_wires_decode_pipeline(self, monkeypatch):
+    def test_no_switch_decides_it(self, monkeypatch):
+        # The rule reads the engine's own state: no field of the
+        # configuration and no environment name turns it on or off.
+        import dataclasses
+
         from llm_d_kv_cache_manager_tpu.server.serve import PodServerConfig
 
-        monkeypatch.setenv("DECODE_PIPELINE", "1")
+        names = {f.name for f in dataclasses.fields(EngineConfig)}
+        assert not {n for n in names if "pipeline" in n or "fused" in n}
         monkeypatch.setenv("DECODE_STEPS_PER_ITER", "4")
         cfg = PodServerConfig.from_env()
-        assert cfg.engine.decode_pipeline is True
         assert cfg.engine.decode_steps_per_iter == 4
-        monkeypatch.setenv("DECODE_PIPELINE", "0")
-        assert PodServerConfig.from_env().engine.decode_pipeline is False
+        assert not hasattr(_engine(), "_pipeline")
 
 
 class TestTensorParallelServing:
@@ -1198,7 +1201,8 @@ class TestSpeculativeDecode:
 
 class TestDecodePathParityFuzz:
     """Randomized cross-path parity: for random prompts/arrival patterns
-    and pool sizes, the four decode paths (plain, fused, pipelined, spec)
+    and pool sizes, the decode paths (plain, fused, with and without
+    running ahead, spec)
     must produce IDENTICAL greedy token streams — the edges the targeted
     tests don't enumerate (odd prompt lengths, mixed finish times, pool
     sizes near the preemption boundary) get swept here."""
@@ -1206,17 +1210,16 @@ class TestDecodePathParityFuzz:
     CONFIGS = [
         dict(),  # plain
         dict(decode_steps_per_iter=3),  # fused, odd burst
-        dict(decode_steps_per_iter=3, decode_pipeline=True),
+        dict(decode_steps_per_iter=3, never_ahead=True),
         dict(spec_decode="prompt_lookup", spec_k=3, spec_ngram=2),
         dict(host_pages=16),  # host-DRAM offload tier in the loop
         dict(sp=2),  # sequence-parallel prefill on the virtual mesh
         # interaction: spec verify dispatches through an sp-sharded prefill
         dict(sp=2, spec_decode="prompt_lookup", spec_k=3, spec_ngram=2),
-        # interaction: spec's empty-proposal fallback lands in the
-        # PIPELINED fused path (drain-before-spec + chained bursts)
+        # interaction: spec's empty-proposal fallback lands in the fused
+        # path, which may leave its burst in flight (drain-before-spec)
         dict(
             decode_steps_per_iter=3,
-            decode_pipeline=True,
             spec_decode="prompt_lookup",
             spec_k=3,
             spec_ngram=2,
@@ -1232,8 +1235,8 @@ class TestDecodePathParityFuzz:
         dict(host_pages=16, spec_decode="prompt_lookup", spec_k=3,
              spec_ngram=2, spec_rounds=3),
         # interaction: fused spec rounds with the empty-proposal fallback
-        # landing in pipelined fused bursts
-        dict(decode_steps_per_iter=3, decode_pipeline=True,
+        # landing in fused bursts
+        dict(decode_steps_per_iter=3,
              spec_decode="prompt_lookup", spec_k=3, spec_ngram=2,
              spec_rounds=3),
     ]
@@ -1255,7 +1258,11 @@ class TestDecodePathParityFuzz:
 
         streams = []
         for kw in self.CONFIGS:
+            kw = dict(kw)
+            never = kw.pop("never_ahead", False)
             eng = _engine(total_pages=pages, decode_batch=3, **kw)
+            if never:
+                eng._next_schedule_decided = lambda active, k: False
             seqs = []
             for i, (p, m) in enumerate(zip(prompts, max_new)):
                 seqs.append(eng.add_request(p, SamplingParams(max_new_tokens=m)))

@@ -35,13 +35,13 @@ IN_STEP = [p for p in STEP_PHASES if p != "loop"]
 PARTS = ("build", "put", "dispatch", "fetch", "commit")
 
 
-def _engine_cfg(**kw):
+def _engine_cfg(total_pages=64, **kw):
     kw.setdefault("scheduler", SchedulerConfig(max_prefill_batch=4))
+    kw.setdefault("decode_batch_size", 4)
     return EngineConfig(
         model=TINY_LLAMA,
-        block_manager=BlockManagerConfig(total_pages=64, page_size=PS),
+        block_manager=BlockManagerConfig(total_pages=total_pages, page_size=PS),
         max_model_len=64,
-        decode_batch_size=4,
         prefill_bucket=8,
         interpret=True,
         **kw,
@@ -103,11 +103,11 @@ def test_off_hands_out_the_one_shared_object_and_counts_nothing(monkeypatch):
 
 
 @pytest.mark.parametrize("kw", [
-    {}, {"decode_fused_sampling": True},
-    {"decode_pipeline": True, "decode_steps_per_iter": 2},
+    {}, {"decode_batch_size": 3},
+    {"decode_batch_size": 2, "total_pages": 10, "decode_steps_per_iter": 2},
     {"spec_decode": "prompt_lookup"},
     {"scheduler": SchedulerConfig(max_prefill_batch=4, chunked_prefill_tokens=8)},
-], ids=["plain", "fused", "pipelined", "spec", "chunked"])
+], ids=["plain", "ahead", "ahead-tight-pool", "spec", "chunked"])
 def test_on_the_phases_tile_the_step(kw, monkeypatch):
     eng = Engine(_engine_cfg(**kw))
     _run(eng, [_prompt(2, 9)], max_new_tokens=2)  # compile outside the clock
@@ -144,11 +144,18 @@ def test_on_the_phases_tile_the_step(kw, monkeypatch):
     assert st["sample_s"] > 0 and st["prefill_dispatch_s"] > 0
     assert all(st[f"{half}_put_s"] > 0 for half in ("prefill", "decode"))
     assert eng._open_phase is None
-    # one span a phase: only a pipelined burst, drained where it stands,
-    # ever suspends the phase it lands in and so opens a span more
+    # one span a phase: only the burst in flight, drained where it stands
+    # (a reservation out of pages), ever suspends the phase it lands in
+    # and so opens a span more; a burst that is chained from suspends none
     assert opened["spans"] >= opened["entered"] > 0
-    if not kw.get("decode_pipeline"):
+    if "total_pages" in kw:
+        assert st["decode_chained_dispatches"] > 0
+        assert opened["spans"] > opened["entered"]
+    else:
         assert opened["spans"] == opened["entered"]
+        assert (st["decode_chained_dispatches"] > 0) == (
+            kw.get("decode_batch_size") == 3
+        )
 
 
 def test_decode_rows_are_the_lanes_that_ran():
@@ -245,16 +252,21 @@ def test_sampled_dispatches_are_in_stats_with_the_switch_on():
 
 
 def test_a_burst_drained_ahead_of_a_prefill_is_decode_time():
-    """With ``decode_pipeline`` a prefill first commits the burst in flight:
-    that fetch and commit come between ``schedule`` and ``prefill_build``
-    and are booked to ``decode_s`` and ``sample_s``, not to ``prefill_s``."""
-    eng = Engine(_engine_cfg(decode_pipeline=True, decode_steps_per_iter=2))
+    """What the rule cannot foresee, here a deadline that passes, frees a
+    lane while a burst is in flight; the prefill of the one who waited
+    first commits that burst: its fetch and commit come between
+    ``schedule`` and ``prefill_build`` and are booked to ``decode_s`` and
+    ``sample_s``, not to ``prefill_s``."""
+    eng = Engine(_engine_cfg(decode_batch_size=1))
     _run(eng, [_prompt(19, 9)], max_new_tokens=2)
     eng.obs_step_timing = True
-    eng.add_request(_prompt(20, 9), SamplingParams(max_new_tokens=24))
+    first = eng.add_request(_prompt(20, 9), SamplingParams(max_new_tokens=24))
+    eng.add_request(_prompt(21, 9), SamplingParams(max_new_tokens=2))
     while eng._inflight is None:
         eng.step()
-    eng.add_request(_prompt(21, 9), SamplingParams(max_new_tokens=2))
+    first.deadline = 0.0  # long past: the lane leaves at the next step
+    eng.step()
+    assert first.finish_reason == "deadline" and eng._inflight is not None
     names, real = [], eng.phase
     eng.phase = lambda name: names.append(name) or real(name)
     before = dict(eng.step_stats)
@@ -273,6 +285,30 @@ def test_a_burst_drained_ahead_of_a_prefill_is_decode_time():
     )
     assert took["sample_s"] == pytest.approx(
         took["decode_fetch_s"] + took["prefill_fetch_s"]
+    )
+    eng.run_until_complete()
+
+
+def test_a_chained_step_fetches_the_burst_after_the_next_dispatch():
+    """With lanes full the phases of a step follow one another in a new
+    order and still never nest: ``decode_dispatch`` of burst N+1, then
+    ``decode_fetch`` and ``decode_commit`` of burst N."""
+    eng = Engine(_engine_cfg(decode_batch_size=1))
+    _run(eng, [_prompt(19, 9)], max_new_tokens=2)
+    eng.obs_step_timing = True
+    eng.add_request(_prompt(22, 9), SamplingParams(max_new_tokens=24))
+    while eng._inflight is None:
+        eng.step()
+    names, real = [], eng.phase
+    eng.phase = lambda name: names.append(name) or real(name)
+    before = dict(eng.step_stats)
+    eng.step()
+    assert names == [
+        "schedule", "decode_build", "decode_put", "decode_dispatch",
+        "decode_fetch", "decode_commit", "publish",
+    ]
+    assert eng.step_stats["decode_chained_dispatches"] == (
+        before["decode_chained_dispatches"] + 1
     )
     eng.run_until_complete()
 
