@@ -1,7 +1,7 @@
 """HuggingFace → native parameter conversion for Llama-family checkpoints.
 
-Maps a transformers Llama/Qwen2/Qwen3/Mixtral/DeepSeek-V3 state dict onto the pytree layout of
-``models/llama.py``. torch ``Linear`` stores ``[out, in]`` and computes
+Maps a transformers Llama/Qwen2/Qwen3/Mixtral/DeepSeek-V3/LFM2-MoE state dict
+onto the pytree layout of ``models/llama.py``. torch ``Linear`` stores ``[out, in]`` and computes
 ``x @ W.T``; our params store ``[in, out]``, so every projection transposes.
 The RoPE convention (half-split rotate) matches HF Llama, so no permutation
 of head channels is needed.
@@ -28,6 +28,8 @@ def load_hf_state_dict(
     state_dict: Mapping[str, Any], cfg: LlamaConfig
 ) -> Params:
     sd = state_dict
+    if cfg.layer_types is not None:
+        return _load_lfm2_moe(sd, cfg)
 
     def get(name: str) -> np.ndarray:
         return _to_np(sd[name])
@@ -111,6 +113,127 @@ def load_hf_state_dict(
     return params
 
 
+def _load_lfm2_moe(sd: Mapping[str, Any], cfg: LlamaConfig) -> Params:
+    """``model_type: lfm2_moe`` (LFM2-8B-A1B). The checkpoint's names are
+    written here AS THE AUTHOR REMEMBERS the published modelling code
+    (``modeling_lfm2_moe.py``), with no network at hand to read it again:
+    ``operator_norm`` / ``ffn_norm`` a layer; ``conv.in_proj`` (``[B | C |
+    x]``, ``[3d, d]``), ``conv.conv`` (a depthwise ``Conv1d`` weight ``[d,
+    1, K]``, cross-correlated, so tap ``K - 1`` weighs the newest token:
+    ``conv_w`` is its transpose) and ``conv.out_proj``, or ``self_attn.{q,
+    k, v, out}_proj`` with ``q_layernorm`` / ``k_layernorm``;
+    ``feed_forward.w1 / w3 / w2`` (gate, up, down) in the leading dense
+    layers, else ``feed_forward.gate`` (the router), ``feed_forward.
+    expert_bias`` and ``feed_forward.experts.N.w1 / w3 / w2``;
+    ``embedding_norm`` after the last layer; the head is the embedding. A
+    checkpoint that names them otherwise fails on the missing key, named."""
+    def get(name: str) -> np.ndarray:
+        return _to_np(sd[name])
+
+    def vector(name: str) -> jnp.ndarray:
+        return jnp.asarray(get(name), cfg.dtype)
+
+    def linear(name: str) -> jnp.ndarray:
+        return jnp.asarray(get(name).T, cfg.dtype)  # [out,in] -> [in,out]
+
+    layers = []
+    for i in range(cfg.n_layers):
+        p = f"model.layers.{i}."
+        layer = {
+            "attn_norm": vector(p + "operator_norm.weight"),
+            "mlp_norm": vector(p + "ffn_norm.weight"),
+        }
+        if cfg.layer_kind(i) == "conv":
+            layer["conv_in"] = linear(p + "conv.in_proj.weight")
+            # [d, 1, K] -> [K, d]
+            layer["conv_w"] = jnp.asarray(
+                get(p + "conv.conv.weight")[:, 0, :].T, cfg.dtype
+            )
+            layer["conv_out"] = linear(p + "conv.out_proj.weight")
+        else:
+            for ours, theirs in (("wq", "q_proj"), ("wk", "k_proj"),
+                                 ("wv", "v_proj"), ("wo", "out_proj")):
+                layer[ours] = linear(f"{p}self_attn.{theirs}.weight")
+            layer["q_norm"] = vector(p + "self_attn.q_layernorm.weight")
+            layer["k_norm"] = vector(p + "self_attn.k_layernorm.weight")
+        ff = p + "feed_forward."
+        names = (("w_gate", "w1"), ("w_up", "w3"), ("w_down", "w2"))
+        if i >= cfg.first_k_dense:
+            layer["router"] = linear(ff + "gate.weight")
+            layer["router_bias"] = jnp.asarray(
+                get(ff + "expert_bias"), jnp.float32
+            )
+            for ours, theirs in names:
+                layer[ours] = jnp.stack([
+                    linear(f"{ff}experts.{j}.{theirs}.weight")
+                    for j in range(cfg.n_experts)
+                ])
+        else:
+            for ours, theirs in names:
+                layer[ours] = linear(f"{ff}{theirs}.weight")
+        layers.append(layer)
+    return {
+        "embed": vector("model.embed_tokens.weight"),
+        "final_norm": vector("model.embedding_norm.weight"),
+        "layers": layers,
+    }
+
+
+def _lfm2_moe_config(hf_config, rope_scaling) -> LlamaConfig:
+    """``model_type: lfm2_moe``: gated short convolutions with some layers
+    of GQA (``layer_types``), leading dense layers, sigmoid-routed experts
+    with a bias that chooses. What the program does not run is refused here
+    by name."""
+    def has(key, default=None):
+        return getattr(hf_config, key, default)
+
+    if has("conv_bias", False):
+        raise NotImplementedError(
+            "conv_bias=true: a biased convolution is not supported yet"
+        )
+    if has("conv_L_cache", 3) < 2:
+        raise NotImplementedError(
+            f"conv_L_cache={has('conv_L_cache')}: a convolution needs at "
+            "least two taps to keep state"
+        )
+    if not has("use_expert_bias", True):
+        raise NotImplementedError(
+            "use_expert_bias=false is not supported yet (the sigmoid router "
+            "chooses by score + bias)"
+        )
+    kinds = tuple(has("layer_types"))
+    unknown = sorted(set(kinds) - {"conv", "full_attention"})
+    if unknown or len(kinds) != hf_config.num_hidden_layers:
+        raise NotImplementedError(
+            f"layer_types {unknown or len(kinds)}: one of 'conv' / "
+            "'full_attention' a layer is supported"
+        )
+    return LlamaConfig(
+        vocab_size=hf_config.vocab_size,
+        hidden_size=hf_config.hidden_size,
+        intermediate_size=hf_config.intermediate_size,
+        n_layers=hf_config.num_hidden_layers,
+        n_heads=hf_config.num_attention_heads,
+        n_kv_heads=hf_config.num_key_value_heads,
+        head_dim=hf_config.hidden_size // hf_config.num_attention_heads,
+        rope_theta=float(has("rope_theta", 1_000_000.0)),
+        rope_scaling=rope_scaling,
+        rms_norm_eps=has("norm_eps", 1e-5),
+        qk_norm=True,
+        tie_word_embeddings=bool(has("tie_word_embeddings", True)),
+        n_experts=hf_config.num_experts,
+        n_experts_per_tok=hf_config.num_experts_per_tok,
+        moe_intermediate_size=hf_config.moe_intermediate_size,
+        norm_topk_prob=bool(has("norm_topk_prob", True)),
+        moe_scoring="sigmoid",
+        routed_scaling_factor=float(has("routed_scaling_factor", 1.0)),
+        router_norm_eps=1e-6,
+        first_k_dense=has("num_dense_layers", 0),
+        layer_types=kinds,
+        conv_L_cache=has("conv_L_cache", 3),
+    )
+
+
 def config_from_hf(hf_config) -> LlamaConfig:
     """transformers LlamaConfig/Qwen2Config → native config."""
     rope_scaling = None
@@ -134,6 +257,8 @@ def config_from_hf(hf_config) -> LlamaConfig:
             raise NotImplementedError(
                 f"rope_scaling type {rope_type!r} is not supported yet"
             )
+    if getattr(hf_config, "model_type", "") == "lfm2_moe":
+        return _lfm2_moe_config(hf_config, rope_scaling)
     cls_name = hf_config.__class__.__name__
     is_gemma = cls_name == "GemmaConfig"
     if cls_name.startswith("Gemma") and not is_gemma:

@@ -73,7 +73,7 @@ from .quant import QuantizedTensor, materialize as _w
 
 def _paged_attention_tp(
     q, kp, vp, block_tables, seq_lens, fresh_k, fresh_v, *, interpret, mesh,
-    layer: int = 0, k_scale=None, v_scale=None,
+    layer: int = 0, k_scale=None, v_scale=None, scale=None,
 ):
     """Decode attention, head-parallel over the ``tp`` mesh axis.
 
@@ -94,7 +94,7 @@ def _paged_attention_tp(
         return paged_attention(
             q, kp, vp, block_tables, seq_lens, fresh_k, fresh_v,
             k_scale=k_scale, v_scale=v_scale,
-            interpret=interpret, layer=layer,
+            interpret=interpret, layer=layer, scale=scale,
         )
     from jax.sharding import PartitionSpec as P
 
@@ -116,7 +116,7 @@ def _paged_attention_tp(
         def call(q, kp, vp, bt, sl, fk, fv, ks, vs):
             return paged_attention(
                 q, kp, vp, bt, sl, fk, fv, k_scale=ks, v_scale=vs,
-                interpret=interpret, layer=layer,
+                interpret=interpret, layer=layer, scale=scale,
             )
 
         fn = jax.shard_map(
@@ -128,7 +128,9 @@ def _paged_attention_tp(
         )
         return fn(*args, k_scale, v_scale)
     fn = jax.shard_map(
-        functools.partial(paged_attention, interpret=interpret, layer=layer),
+        functools.partial(
+            paged_attention, interpret=interpret, layer=layer, scale=scale
+        ),
         mesh=mesh,
         in_specs=tuple(in_specs),
         out_specs=P(None, "tp"),
@@ -232,7 +234,7 @@ def _check_right_padded_mask(ok) -> None:
 
 def _flash_prefill_tp(
     q, k, v, k_pages, v_pages, block_tables, ctx_lens, n_valid, *,
-    layer, interpret, mesh, block_length=0,
+    layer, interpret, mesh, block_length=0, scale=None,
 ):
     """Pallas flash prefill, head-parallel over the ``tp`` mesh axis.
 
@@ -250,6 +252,7 @@ def _flash_prefill_tp(
         return flash_prefill_paged(
             q, k, v, k_pages, v_pages, block_tables, ctx_lens, n_valid,
             interpret=interpret, block_length=block_length, layer=layer,
+            scale=scale,
         )
 
     # ``layer`` goes in as an operand: every layer of a program then shares
@@ -358,11 +361,75 @@ class LlamaConfig:
     n_group: int = 1
     topk_group: int = 1
     first_k_dense: int = 0
+    # What the renormalised sigmoid gates' sum is kept from zero by: the
+    # published modelling code's own constant (1e-20 DeepSeek-V3's, 1e-6
+    # LFM2's).
+    router_norm_eps: float = 1e-20
+    # Layers whose operator is a gated short convolution instead of
+    # attention (LFM2): ``layer_types[i]`` is "conv" or "full_attention"
+    # (None: every layer attends). Such a layer keeps ``conv_L_cache - 1``
+    # rows of ``hidden_size`` values as its state, whatever the context,
+    # and no key or value: the key/value pools' layer axis counts the
+    # attention layers alone and a state pool beside them holds, a page, the
+    # state after the last token written in it (``init_state_pages``).
+    # ``layer_types`` decides only which parameters ``init_params`` and the
+    # loader make and how many layers each pool has: a layer's operator is
+    # read from the layer itself (it has ``conv_in`` or not). ``conv_bias``
+    # is carried for the loader's refusal: a biased convolution is not run.
+    layer_types: Optional[tuple] = None
+    conv_L_cache: int = 0
+    conv_bias: bool = False
     dtype: Any = jnp.bfloat16
 
     @property
     def hd(self) -> int:
         return self.head_dim or self.hidden_size // self.n_heads
+
+    def layer_kind(self, i: int) -> str:
+        """"conv" or "attention", as ``layer_types`` publishes layer ``i``."""
+        return (
+            "conv" if self.layer_types and self.layer_types[i] == "conv"
+            else "attention"
+        )
+
+    @property
+    def n_conv_layers(self) -> int:
+        return sum(self.layer_kind(i) == "conv" for i in range(self.n_layers))
+
+    @property
+    def n_attn_layers(self) -> int:
+        """Layers of the key/value pools."""
+        return self.n_layers - self.n_conv_layers
+
+    @property
+    def layer_types_published(self) -> Optional[list]:
+        """``layer_types`` as a published file's JSON gives it: a list (a
+        tuple here, so that a preset hashes), whole, whatever ``n_layers``
+        is run of it."""
+        return None if self.layer_types is None else list(self.layer_types)
+
+    @property
+    def use_expert_bias(self) -> bool:
+        """A sigmoid router's experts are chosen by score + bias."""
+        return self.n_experts > 0 and self.moe_scoring == "sigmoid"
+
+    @property
+    def kv_heads_per_row(self) -> int:
+        """KV heads sharing one 128-lane row of a key/value pool. The TPU
+        compiler pads a minor dimension under 128 up to 128 lanes in HBM
+        whatever the array says (twice the bytes at head size 64), so where
+        heads are narrower than a row the pool's row says what is stored:
+        ``128 // hd`` neighbouring heads side by side (``kv_row_shape``).
+        Taken only by a model with convolution layers, for which ``tp`` > 1,
+        the int8 pool and the page movers are refused: those paths read a
+        row as one head (PERF.md, Open questions)."""
+        g = 128 // self.hd if self.hd < 128 and 128 % self.hd == 0 else 1
+        return g if self.layer_types and self.n_kv_heads % g == 0 else 1
+
+    @property
+    def state_row(self) -> int:
+        """Values of one page's state slot in one convolution layer."""
+        return max(self.conv_L_cache - 1, 0) * self.hidden_size
 
     @property
     def latent_width(self) -> int:
@@ -382,7 +449,8 @@ class LlamaConfig:
         array's own ``nbytes`` is what the device holds."""
         if self.kv_lora_rank:
             return (-(-self.latent_width // 128) * 128,)
-        return (self.n_kv_heads, self.hd)
+        g = self.kv_heads_per_row
+        return (self.n_kv_heads // g, g * self.hd)
 
     @property
     def moe_inter(self) -> int:
@@ -622,6 +690,70 @@ TINY_MLA_MOE = LlamaConfig(
     dtype=jnp.float32,
 )
 
+_CONV, _ATTN = "conv", "full_attention"
+
+#: LiquidAI/LFM2-8B-A1B (``model_type: lfm2_moe``): 18 gated short
+#: convolutions (three taps) and 6 GQA layers of head size 64, two leading
+#: dense layers, then 32 experts top-4 with sigmoid scores and an expert
+#: bias that chooses and does not weigh. The published file says
+#: ``norm_eps`` and gives no head size (2048 / 32); the tied head, the
+#: scoring function and the 1e-6 are the family's modelling code's.
+LFM2_8B_A1B = LlamaConfig(
+    vocab_size=65_536,
+    hidden_size=2_048,
+    intermediate_size=7_168,
+    n_layers=24,
+    n_heads=32,
+    n_kv_heads=8,
+    head_dim=64,
+    rope_theta=1_000_000.0,
+    rms_norm_eps=1e-5,
+    qk_norm=True,
+    tie_word_embeddings=True,
+    n_experts=32,
+    n_experts_per_tok=4,
+    moe_intermediate_size=1_792,
+    norm_topk_prob=True,
+    moe_scoring="sigmoid",
+    routed_scaling_factor=1.0,
+    router_norm_eps=1e-6,
+    first_k_dense=2,
+    layer_types=(
+        _CONV, _CONV, _ATTN, _CONV, _CONV, _CONV, _ATTN, _CONV, _CONV, _CONV,
+        _ATTN, _CONV, _CONV, _CONV, _ATTN, _CONV, _CONV, _CONV, _ATTN, _CONV,
+        _CONV, _ATTN, _CONV, _CONV,
+    ),
+    conv_L_cache=3,
+)
+
+#: Tiny LFM2-MoE-shaped config (two dense layers, then a period and a half:
+#: conv, conv, attention, conv x3, attention, conv; 8 experts top-2; head
+#: size 64, two KV heads a pool row) for tests / CPU dry-runs.
+TINY_LFM2_MOE = LlamaConfig(
+    vocab_size=256,
+    hidden_size=64,
+    intermediate_size=128,
+    n_layers=8,
+    n_heads=8,
+    n_kv_heads=4,
+    head_dim=64,
+    rope_theta=10_000.0,
+    rms_norm_eps=1e-5,
+    qk_norm=True,
+    tie_word_embeddings=True,
+    n_experts=8,
+    n_experts_per_tok=2,
+    moe_intermediate_size=48,
+    norm_topk_prob=True,
+    moe_scoring="sigmoid",
+    routed_scaling_factor=1.0,
+    router_norm_eps=1e-6,
+    first_k_dense=2,
+    layer_types=(_CONV, _CONV, _ATTN, _CONV, _CONV, _CONV, _ATTN, _CONV),
+    conv_L_cache=3,
+    dtype=jnp.float32,
+)
+
 #: Tiny MoE config (Mixtral-shaped) for tests / CPU dry-runs.
 TINY_MOE = LlamaConfig(
     vocab_size=256,
@@ -676,7 +808,22 @@ def init_params(
     layers = []
     for i in range(cfg.n_layers):
         k = jax.random.split(keys[i], 8)
-        if cfg.kv_lora_rank:
+        if cfg.layer_kind(i) == "conv":
+            # ``[B | C | x] = u conv_in``; one ``conv_L_cache``-tap filter a
+            # channel (row j weighs ``z`` of ``conv_L_cache - 1 - j`` tokens
+            # back; kept full precision: it is tiny); ``conv_out`` after the
+            # gate.
+            layer = {
+                "attn_norm": norm_init((d,)),
+                "conv_in": dense(k[0], (d, 3 * d), d),
+                "conv_w": dense(
+                    k[1], (cfg.conv_L_cache, d), cfg.conv_L_cache,
+                    quantizable=False,
+                ),
+                "conv_out": dense(k[3], (d, d), d),
+                "mlp_norm": norm_init((d,)),
+            }
+        elif cfg.kv_lora_rank:
             dc, dn, dr, dv = (
                 cfg.kv_lora_rank, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
                 cfg.v_head_dim,
@@ -728,11 +875,11 @@ def init_params(
             layer["w_gate"] = dense(k[4], (d, inter), d)
             layer["w_up"] = dense(k[5], (d, inter), d)
             layer["w_down"] = dense(k[6], (inter, d), inter)
-        if cfg.qkv_bias:
+        if cfg.qkv_bias and "wq" in layer:
             layer["bq"] = jnp.zeros((n_q * hd,), cfg.dtype)
             layer["bk"] = jnp.zeros((n_kv * hd,), cfg.dtype)
             layer["bv"] = jnp.zeros((n_kv * hd,), cfg.dtype)
-        if cfg.qk_norm:
+        if cfg.qk_norm and "wq" in layer:
             layer["q_norm"] = norm_init((hd,))
             layer["k_norm"] = norm_init((hd,))
         layers.append(layer)
@@ -780,10 +927,35 @@ def init_kv_pages(
             jnp.zeros((cfg.n_layers, 0, page_size, *row), dtype,
                       device=sharding),
         )
-    shape = (cfg.n_layers, total_pages, page_size, cfg.n_kv_heads, cfg.hd)
+    # the layer axis counts the layers that attend (every one, unless the
+    # model has convolution layers); a row is ``kv_row_shape``
+    shape = (cfg.n_attn_layers, total_pages, page_size, *cfg.kv_row_shape)
     return (
         jnp.zeros(shape, dtype, device=sharding),
         jnp.zeros(shape, dtype, device=sharding),
+    )
+
+
+def init_state_pages(
+    cfg: LlamaConfig, total_pages: int, sharding=None
+) -> Optional[jnp.ndarray]:
+    """The zeroed state pool of a model with convolution layers, ``[conv
+    layers, total_pages, (conv_L_cache - 1) * hidden]``, addressed by the
+    key/value pools' page ids; None for a model without such layers.
+
+    Slot ``p`` of a layer holds that layer's state after the last token
+    written in page ``p``: the ``conv_L_cache - 1`` newest rows of ``z = B *
+    x``, oldest first. A full page's slot is therefore the snapshot at the
+    page's end: the first token of the next page reads it through its block
+    table, whether the page was written by this sequence or is a prefix-
+    cache hit, and a page id that is evicted and reused takes its state with
+    it. The rows of a slot lie side by side in one pool row: a ``[..., 2,
+    2048]`` minor shape would be padded to whole sublane tiles in HBM."""
+    if not cfg.n_conv_layers:
+        return None
+    return jnp.zeros(
+        (cfg.n_conv_layers, total_pages, cfg.state_row), cfg.dtype,
+        device=sharding,
     )
 
 
@@ -799,6 +971,50 @@ def init_kv_scales(
         jnp.zeros(shape, jnp.float32, device=sharding),
         jnp.zeros(shape, jnp.float32, device=sharding),
     )
+
+
+def _own_part(cfg: LlamaConfig):
+    """``[n_heads, kv_heads_per_row]`` bool: which part of its KV head's
+    pool row a query head's own KV head lies in."""
+    g = cfg.kv_heads_per_row
+    kv_head = jnp.arange(cfg.n_heads) // (cfg.n_heads // cfg.n_kv_heads)
+    return (kv_head % g)[:, None] == jnp.arange(g)[None, :]
+
+
+def _pack_heads(cfg: LlamaConfig, q, k, v):
+    """Queries, keys and values ``[..., heads, hd]`` as the kernels take
+    them over a pool whose row holds ``g = kv_heads_per_row`` KV heads
+    (``kv_row_shape``): ``n_kv / g`` KV heads of ``g * hd`` lanes, and each
+    query head its ``hd`` values in the part of the row where its own KV
+    head lies, zeros in the others. Its scores are then its own head's (at
+    the caller's ``scale``, ``hd ** -0.5``), and its own part of the
+    kernel's output its result (``_unpack_heads``): ``g`` times the score
+    FLOPs, no byte more, both kernels as they are."""
+    g = cfg.kv_heads_per_row
+    if g == 1:
+        return q, k, v
+    own = _own_part(cfg)[:, :, None]
+    q = jnp.where(own, q[..., None, :], 0).reshape(*q.shape[:-1], g * cfg.hd)
+    row = cfg.kv_row_shape
+    return q, k.reshape(*k.shape[:-2], *row), v.reshape(*v.shape[:-2], *row)
+
+
+def _unpack_heads(cfg: LlamaConfig, out):
+    """A packed kernel's output ``[..., n_heads, g * hd]``: each query
+    head's own part, ``[..., n_heads, hd]``."""
+    g = cfg.kv_heads_per_row
+    if g == 1:
+        return out
+    out = out.reshape(*out.shape[:-1], g, cfg.hd)
+    return jnp.sum(jnp.where(_own_part(cfg)[:, :, None], out, 0), axis=-2)
+
+
+def _head_pool(cfg: LlamaConfig, pool_l):
+    """One layer of a key/value pool with a row a KV head: ``[pages,
+    page_size, n_kv, hd]`` (the ``xla`` prefill reads it so)."""
+    if cfg.kv_heads_per_row == 1:
+        return pool_l
+    return pool_l.reshape(*pool_l.shape[:2], cfg.n_kv_heads, cfg.hd)
 
 
 def _qkv(layer: Params, cfg: LlamaConfig, x: jnp.ndarray):
@@ -902,6 +1118,84 @@ def _mla_absorbed(
     return jnp.einsum("bshc,chv->bshv", o_c, w_vb)
 
 
+# -- gated short convolution (LFM2) ------------------------------------------
+def _conv_state_plan(start, n_valid, page_ids, page_size: int):
+    """Where a chunk of consecutive positions leaves convolution state:
+    ``(last [b, n], page [b, n], ok [b, n])``, one entry a page the chunk can
+    touch. ``last`` is the chunk index of the last VALID token in the row's
+    n-th touched page (right-padded rows leave the state of their last real
+    token, not of their padding), ``page`` that page's id and ``ok`` whether
+    the row has a valid token there. ``start [b]`` is the position of chunk
+    index 0 (it may lie inside a page), ``n_valid [b]`` the real tokens."""
+    s = page_ids.shape[1]
+    n_touched = (s + page_size - 2) // page_size + 1
+    first_page = start // page_size
+    j = jnp.arange(n_touched)[None, :]
+    page_end = (first_page[:, None] + j + 1) * page_size - 1 - start[:, None]
+    page_begin = page_end - (page_size - 1)
+    last = jnp.clip(jnp.minimum(page_end, n_valid[:, None] - 1), 0, s - 1)
+    ok = page_begin < n_valid[:, None]
+    return last, jnp.take_along_axis(page_ids, last, axis=1), ok
+
+
+def _conv_prev_state(state_pages, cfg: LlamaConfig, prev_page, has_prev):
+    """Every convolution layer's state before a chunk's first token: the
+    slot of the page holding the token before it (``prev_page [b]``), zeros
+    where there is none (position 0). ``[conv layers, b, K - 1, d]``."""
+    # read as flat slots, one index a (layer, lane), as the write is: with
+    # the layer axis in the gather's window (``state_pages[:, prev_page]``)
+    # the TPU compiler copies the whole pool into a layer-inward layout
+    # first (``python -m tools.aot_pool_copies --config lfm2-8b-a1b``)
+    n_layers, pages, row = state_pages.shape
+    slots = (
+        jnp.arange(n_layers, dtype=prev_page.dtype)[:, None] * pages
+        + jnp.clip(prev_page, 0, pages - 1)[None, :]
+    )
+    got = state_pages.reshape(n_layers * pages, row)[slots]
+    got = jnp.where(has_prev[None, :, None], got, 0)
+    return got.reshape(*got.shape[:2], cfg.conv_L_cache - 1, cfg.hidden_size)
+
+
+def _conv_operator(layer: Params, cfg: LlamaConfig, x, state):
+    """The gated short convolution over a chunk ``x [b, s, d]`` that follows
+    ``state [b, K - 1, d]`` (the newest rows of ``z`` before it, oldest
+    first; ``K = conv_L_cache``): ``[B | C | x'] = x conv_in``, ``z = B *
+    x'``, ``c_t = sum_j conv_w[j] * z_{t - (K - 1) + j}``, ``y = C * c``,
+    ``y conv_out``. No position enters. Returns (output ``[b, s, d]``, ``z``
+    with the state before it ``[b, K - 1 + s, d]``: the state after chunk
+    index ``i`` is its rows ``i + 1 .. i + K - 1``)."""
+    s = x.shape[1]
+    gate_b, gate_c, xp = jnp.split(x @ _w(layer["conv_in"], x.dtype), 3, axis=-1)
+    z = jnp.concatenate([state.astype(x.dtype), gate_b * xp], axis=1)
+    taps = layer["conv_w"].astype(jnp.float32)  # [K, d]
+    conv = sum(
+        taps[j] * z[:, j : j + s].astype(jnp.float32)
+        for j in range(taps.shape[0])
+    )
+    y = gate_c * conv.astype(x.dtype)
+    return y @ _w(layer["conv_out"], x.dtype), z
+
+
+def _scatter_state_pages(state_pages, fresh, page, ok):
+    """Write every convolution layer's new slots with one update (aliased
+    into the donated pool, as ``_scatter_kv_pages_all_layers``): ``fresh
+    [conv layers, b, n, row]`` to the flat slots ``layer * pages + page[b,
+    n]``, dropped where not ``ok``."""
+    n_layers, pages, row = state_pages.shape
+    keep = ok & (page < pages)
+    slots = jnp.where(
+        keep.reshape(1, -1),
+        jnp.arange(n_layers, dtype=page.dtype)[:, None] * pages
+        + page.reshape(1, -1),
+        n_layers * pages,
+    )
+    flat = state_pages.reshape(n_layers * pages, row)
+    flat = flat.at[slots.reshape(-1)].set(
+        fresh.reshape(-1, row).astype(flat.dtype), mode="drop"
+    )
+    return flat.reshape(state_pages.shape)
+
+
 def _moe_gates(layer: Params, cfg: LlamaConfig, x: jnp.ndarray):
     """Top-k routing shared by both dispatch strategies.
 
@@ -922,7 +1216,9 @@ def _moe_gates(layer: Params, cfg: LlamaConfig, x: jnp.ndarray):
         )
         topv = jnp.take_along_axis(scores, topi, axis=-1)
         if cfg.norm_topk_prob:
-            topv = topv / (jnp.sum(topv, axis=-1, keepdims=True) + 1e-20)
+            topv = topv / (
+                jnp.sum(topv, axis=-1, keepdims=True) + cfg.router_norm_eps
+            )
         return topv * cfg.routed_scaling_factor, topi
     if cfg.moe_scoring != "softmax":
         raise ValueError(f"unknown moe_scoring {cfg.moe_scoring!r}")
@@ -1335,14 +1631,20 @@ def _prefill_body(
     k_scales=None,  # [L, P, n_kv] f32 when KV_QUANT_HBM=int8
     v_scales=None,
     experts_touched: Optional[list] = None,  # see ``_moe_mlp_routed``
-) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, Any, Any]:
+    state_pages=None,  # ``init_state_pages``: a model with conv layers
+) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, Any, Any, Any]:
     """Traced prefill layer loop shared by ``prefill`` and the fused
     speculative-decode scan (``spec_decode_steps``): chunk forward with
     paged-context attention + one batched KV scatter. Returns (hidden
-    states [b, s, d], k_pages, v_pages, k_scales, v_scales); logits
-    selection stays with the caller. Scales are None (and pass through
-    untouched) unless the pools are int8 (``KV_QUANT_HBM``), in which
-    case the scatter quantizes at write time and the paged-context gather
+    states [b, s, d], k_pages, v_pages, k_scales, v_scales, state_pages);
+    logits selection stays with the caller. A convolution layer (one that
+    has ``conv_in``) takes its state before the chunk from the slot of the
+    page that holds the token before ``ctx_lens`` (``page_ids[:, 0]`` where
+    the chunk starts inside a page, else the last page of ``block_tables``'
+    context; zeros at position 0) and leaves, in one write after the loop,
+    the state after the last valid token of every page the chunk touches.
+    Scales are None (and pass through untouched) unless the pools are int8
+    (``KV_QUANT_HBM``), in which case the scatter quantizes at write time and the paged-context gather
     dequantizes chunk-locally — the engine restricts the quantized path
     to the ``xla`` single-shard prefill.
 
@@ -1360,14 +1662,54 @@ def _prefill_body(
         cfg.rope_scaling,
     ))
     h = _embed(params, cfg, tokens)  # [b, s, d]
-    if attn_impl == "pallas" or latent:
+    has_conv = any("conv_in" in layer for layer in params["layers"])
+    if attn_impl == "pallas" or latent or has_conv:
         n_valid = jnp.sum(valid.astype(jnp.int32), axis=1)
+    if has_conv:
+        if state_pages is None or sp > 1 or k_scales is not None:
+            raise ValueError(
+                "convolution layers: the state pool is needed; sp and int8 "
+                "are not run"
+            )
+        page_size = k_pages.shape[2]
+        before = jnp.zeros_like(ctx_lens)
+        if block_tables.shape[1]:
+            before = jnp.take_along_axis(
+                block_tables,
+                jnp.clip(
+                    ctx_lens // page_size - 1, 0, block_tables.shape[1] - 1
+                )[:, None],
+                axis=1,
+            )[:, 0]
+        states = _conv_prev_state(
+            state_pages, cfg,
+            jnp.where(ctx_lens % page_size != 0, page_ids[:, 0], before),
+            ctx_lens > 0,
+        )
+        last, state_page, state_ok = _conv_state_plan(
+            ctx_lens, n_valid, page_ids, page_size
+        )
+        # rows of ``z`` (the state before the chunk, then the chunk) that
+        # are the state after chunk index ``last``
+        state_rows = (
+            last[:, :, None] + 1 + jnp.arange(cfg.conv_L_cache - 1)[None, None]
+        )
+    head_scale = cfg.hd**-0.5 if cfg.kv_heads_per_row > 1 else None
 
     fresh_k = []  # per-layer [b, s, n_kv, hd] — written to pages in one go
     fresh_v = []
-    for li, layer in enumerate(params["layers"]):
+    fresh_state = []  # per conv layer [b, pages touched, state row]
+    for layer in params["layers"]:
+        li = len(fresh_k)  # the layer's index in the key/value pools
         x = rms_norm(h, layer["attn_norm"], cfg.rms_norm_eps, cfg.norm_offset)
-        if latent:
+        if "conv_in" in layer:
+            out, z = _conv_operator(layer, cfg, x, states[len(fresh_state)])
+            fresh_state.append(
+                z[jnp.arange(z.shape[0])[:, None, None], state_rows].reshape(
+                    *state_rows.shape[:2], -1
+                )
+            )
+        elif latent:
             # One row a token, absorbed: the kernel over the pool in place
             # or (``xla``) its oracle over gathered pages; the rows go to
             # the pool after the loop, through the same flat-row scatter.
@@ -1396,21 +1738,27 @@ def _prefill_body(
                 # Flash kernel (ops/flash_prefill.py), which reads the whole
                 # pools' pages where they lie. Engine contract: consecutive
                 # chunk positions, right-padded valid mask.
-                attn = _flash_prefill_tp(
+                q, k, v = _pack_heads(cfg, q, k, v)
+                attn = _unpack_heads(cfg, _flash_prefill_tp(
                     q, k, v, k_pages, v_pages, block_tables, ctx_lens,
                     n_valid, layer=li, interpret=interpret, mesh=mesh,
-                    block_length=cfg.block_length,
-                )
+                    block_length=cfg.block_length, scale=head_scale,
+                ))
             else:
                 attn = prefill_with_paged_context(
-                    q, k, v, k_pages[li], v_pages[li], block_tables, ctx_lens,
+                    q, k, v, _head_pool(cfg, k_pages[li]),
+                    _head_pool(cfg, v_pages[li]), block_tables, ctx_lens,
                     positions=positions, valid=valid,
                     k_scales=None if k_scales is None else k_scales[li],
                     v_scales=None if v_scales is None else v_scales[li],
                     block_length=cfg.block_length,
                 )
-        b, s, _, _ = attn.shape
-        h = h + attn.reshape(b, s, -1) @ _w(layer["wo"], h.dtype)
+        if "conv_in" not in layer:
+            b, s, _, _ = attn.shape
+            out = attn.reshape(b, s, -1) @ _w(layer["wo"], h.dtype)
+            fresh_k.append(k)
+            fresh_v.append(v)
+        h = h + out
 
         x = rms_norm(h, layer["mlp_norm"], cfg.rms_norm_eps, cfg.norm_offset)
         h = h + _mlp(
@@ -1418,9 +1766,12 @@ def _prefill_body(
             touched=experts_touched,
         )
 
-        fresh_k.append(k)
-        fresh_v.append(v)
-
+    if fresh_state:
+        state_pages = _scatter_state_pages(
+            state_pages, jnp.stack(fresh_state), state_page, state_ok
+        )
+    if not fresh_k:  # a tree of convolution layers alone: no key or value
+        return h, k_pages, v_pages, k_scales, v_scales, state_pages
     # One batched scatter over all layers into the donated pools. In-chunk
     # attention never reads these pages (fresh K/V ride function arguments),
     # so deferring the writes is exact — and a single aliased update avoids
@@ -1444,7 +1795,7 @@ def _prefill_body(
                 v_pages, jnp.stack(fresh_v).astype(v_pages.dtype), page_ids,
                 slot_ids, valid
             )
-    return h, k_pages, v_pages, k_scales, v_scales
+    return h, k_pages, v_pages, k_scales, v_scales, state_pages
 
 
 @functools.partial(
@@ -1452,7 +1803,9 @@ def _prefill_body(
     static_argnames=(
         "cfg", "mesh", "attn_impl", "return_all_logits", "interpret",
     ),
-    donate_argnames=("k_pages", "v_pages", "k_scales", "v_scales"),
+    donate_argnames=(
+        "k_pages", "v_pages", "k_scales", "v_scales", "state_pages",
+    ),
 )
 def prefill(
     params: Params,
@@ -1472,9 +1825,14 @@ def prefill(
     k_scales=None,  # [L, P, n_kv] f32 — int8 pools (KV_QUANT_HBM)
     v_scales=None,
     interpret: bool = False,  # Pallas kernels interpreted (CPU tests)
+    *,
+    state_pages=None,  # ``init_state_pages``: a model with conv layers
 ) -> tuple[jnp.ndarray, ...]:
     """Process a prompt chunk: returns (logits at last valid position per
-    sequence [b, vocab], updated k_pages, v_pages).
+    sequence [b, vocab], updated k_pages, v_pages), then the updated scale
+    pools where the pools are int8, then the updated ``state_pages`` where
+    one was given (a model with convolution layers; absent, in arguments
+    and results, for every other).
 
     The chunk attends causally within itself AND to ``ctx_lens`` tokens of
     prefix-cached context already resident in the page pool — this is how a
@@ -1513,15 +1871,18 @@ def prefill(
         raise ValueError(
             "KV_QUANT_HBM prefill requires the xla single-shard path"
         )
-    h, k_pages, v_pages, k_scales, v_scales = _prefill_body(
+    stateful = state_pages is not None
+    h, k_pages, v_pages, k_scales, v_scales, state_pages = _prefill_body(
         params, cfg, tokens, positions, valid, k_pages, v_pages,
         page_ids, slot_ids, block_tables, ctx_lens, mesh, attn_impl,
-        interpret, k_scales, v_scales,
+        interpret, k_scales, v_scales, state_pages=state_pages,
     )
 
     # Knob-off callers keep the legacy 3-tuple; quantized callers get the
-    # updated scale pools appended.
+    # updated scale pools appended, a model with state its state pool.
     extra = (k_scales, v_scales) if quantized else ()
+    if stateful:
+        extra += (state_pages,)
     if return_all_logits:
         # Every chunk position's next-token logits [b, s, vocab] — the
         # speculative-decode verify step scores all k+1 proposed tokens in
@@ -1548,12 +1909,19 @@ def _decode_body(
     mesh=None,
     k_scales=None,  # [L, P, n_kv] f32 when KV_QUANT_HBM=int8
     v_scales=None,
-) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, Any, Any]:
+    state_pages=None,  # ``init_state_pages``: a model with conv layers
+) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, Any, Any, Any]:
     """Single decode step (traced body shared by ``decode_step`` and the
     fused ``decode_steps`` scan). Writes this token's K/V into its page
     slot, runs paged attention over the full context, returns
-    (logits [b, vocab], k_pages, v_pages, k_scales, v_scales) — scales are
-    None pass-throughs unless the pools are int8 (``KV_QUANT_HBM``)."""
+    (logits [b, vocab], k_pages, v_pages, k_scales, v_scales, state_pages)
+    — scales are None pass-throughs unless the pools are int8
+    (``KV_QUANT_HBM``), ``state_pages`` unless the model has convolution
+    layers: such a layer reads the slot of the page that holds the token
+    before this one (through the block table: the page before, at a page's
+    first slot) and writes the new state to this token's page's slot, so a
+    lane that crosses a page boundary inside a burst leaves the finished
+    page's snapshot behind on the device."""
     latent = cfg.kv_lora_rank > 0
     if latent and (mesh is not None or k_scales is not None):
         raise ValueError("a latent pool: tp, sp and int8 are not run")
@@ -1569,12 +1937,33 @@ def _decode_body(
     my_page = jnp.take_along_axis(block_tables, page_of_pos[:, None], axis=1)[:, 0]
     my_slot = positions % page_size
     valid = jnp.ones((b, 1), bool)
+    has_conv = any("conv_in" in layer for layer in params["layers"])
+    if has_conv:
+        if state_pages is None or mesh is not None or k_scales is not None:
+            raise ValueError(
+                "convolution layers: the state pool is needed; tp, sp and "
+                "int8 are not run"
+            )
+        states = _conv_prev_state(
+            state_pages, cfg,
+            jnp.take_along_axis(
+                block_tables,
+                (jnp.maximum(positions - 1, 0) // page_size)[:, None], axis=1,
+            )[:, 0],
+            positions > 0,
+        )
+    head_scale = cfg.hd**-0.5 if cfg.kv_heads_per_row > 1 else None
 
     fresh_k = []  # per-layer [b, 1, n_kv, hd]; written to pages in one go
     fresh_v = []
-    for li, layer in enumerate(params["layers"]):
+    fresh_state = []  # per conv layer [b, 1, state row]
+    for layer in params["layers"]:
+        li = len(fresh_k)  # the layer's index in the key/value pools
         x = rms_norm(h, layer["attn_norm"], cfg.rms_norm_eps, cfg.norm_offset)
-        if latent:
+        if "conv_in" in layer:
+            out, z = _conv_operator(layer, cfg, x, states[len(fresh_state)])
+            fresh_state.append(z[:, 1:].reshape(b, 1, -1))
+        elif latent:
             # Absorbed decode (kernel ``mla_decode``): every head reads the
             # lane's latent rows where they lie, once, as key and as value;
             # the token's own row rides as an argument and is written after
@@ -1592,6 +1981,7 @@ def _decode_body(
             q, k, v = _qkv(layer, cfg, x)
             q = apply_rope(q, positions[:, None], inv_freq)
             k = apply_rope(k, positions[:, None], inv_freq)
+            q, k, v = _pack_heads(cfg, q, k, v)
 
             # The kernel takes the current token's K/V as arguments (pages hold
             # only history), so the pool write happens ONCE for all layers after
@@ -1600,7 +1990,7 @@ def _decode_body(
             # kernel reads the pool in the default layout; that the write does
             # too (it did not until PR 29: see _scatter_kv_pages_all_layers) is
             # what tests/test_pool_layout.py holds on the compiled program.
-            attn = _paged_attention_tp(
+            attn = _unpack_heads(cfg, _paged_attention_tp(
                 q[:, 0],  # [b, n_heads, hd]
                 k_pages,  # FULL [L, P, ps, n_kv, hd] pool; layer via index map
                 v_pages,
@@ -1613,15 +2003,26 @@ def _decode_body(
                 layer=li,
                 k_scale=k_scales,
                 v_scale=v_scales,
-            )  # [b, n_heads, hd]
-        h = h + (attn.reshape(b, -1) @ _w(layer["wo"], h.dtype))[:, None, :]
+                scale=head_scale,
+            ))  # [b, n_heads, hd]
+        if "conv_in" not in layer:
+            out = (attn.reshape(b, -1) @ _w(layer["wo"], h.dtype))[:, None, :]
+            fresh_k.append(k)
+            fresh_v.append(v)
+        h = h + out
 
         x = rms_norm(h, layer["mlp_norm"], cfg.rms_norm_eps, cfg.norm_offset)
         h = h + _mlp(layer, cfg, x, mesh=mesh, interpret=interpret)
 
-        fresh_k.append(k)
-        fresh_v.append(v)
-
+    if fresh_state:
+        state_pages = _scatter_state_pages(
+            state_pages, jnp.stack(fresh_state), my_page[:, None], valid
+        )
+    if not fresh_k:  # a tree of convolution layers alone: no key or value
+        return (
+            _logits(params, cfg, h)[:, 0], k_pages, v_pages, k_scales,
+            v_scales, state_pages,
+        )
     if k_scales is not None:
         k_pages, k_scales = _quantized_scatter_kv_all_layers(
             k_pages, k_scales, jnp.stack(fresh_k),
@@ -1647,13 +2048,16 @@ def _decode_body(
         v_pages,
         k_scales,
         v_scales,
+        state_pages,
     )
 
 
 @functools.partial(
     jax.jit,
     static_argnames=("cfg", "page_size", "interpret", "mesh"),
-    donate_argnames=("k_pages", "v_pages", "k_scales", "v_scales"),
+    donate_argnames=(
+        "k_pages", "v_pages", "k_scales", "v_scales", "state_pages",
+    ),
 )
 def decode_step(
     params: Params,
@@ -1670,24 +2074,30 @@ def decode_step(
     mesh=None,  # tp mesh for head-parallel decode attention
     k_scales=None,  # [L, P, n_kv] f32 — int8 pools (KV_QUANT_HBM)
     v_scales=None,
+    state_pages=None,  # ``init_state_pages``: a model with conv layers
 ) -> tuple[jnp.ndarray, ...]:
     """One decode step; sampling stays with the caller (host or jit).
     Returns the legacy 3-tuple, with updated scale pools appended when
-    the pools are quantized."""
-    logits, k_pages, v_pages, k_scales, v_scales = _decode_body(
+    the pools are quantized and the updated state pool when one was given
+    (a model with convolution layers)."""
+    stateful = state_pages is not None
+    logits, k_pages, v_pages, k_scales, v_scales, state_pages = _decode_body(
         params, cfg, tokens, positions, k_pages, v_pages,
         block_tables, seq_lens, page_size, interpret, mesh,
-        k_scales, v_scales,
+        k_scales, v_scales, state_pages,
     )
-    if k_scales is None:
-        return logits, k_pages, v_pages
-    return logits, k_pages, v_pages, k_scales, v_scales
+    extra = () if k_scales is None else (k_scales, v_scales)
+    if stateful:
+        extra += (state_pages,)
+    return (logits, k_pages, v_pages) + extra
 
 
 @functools.partial(
     jax.jit,
     static_argnames=("cfg", "page_size", "num_steps", "interpret", "mesh"),
-    donate_argnames=("k_pages", "v_pages", "k_scales", "v_scales"),
+    donate_argnames=(
+        "k_pages", "v_pages", "k_scales", "v_scales", "state_pages",
+    ),
 )
 def decode_steps(
     params: Params,
@@ -1710,6 +2120,7 @@ def decode_steps(
     mesh=None,  # tp mesh for head-parallel decode attention
     k_scales=None,  # [L, P, n_kv] f32 — int8 pools (KV_QUANT_HBM)
     v_scales=None,
+    state_pages=None,  # ``init_state_pages``: a model with conv layers
 ) -> tuple[jnp.ndarray, ...]:
     """``num_steps`` fused decode iterations with on-device sampling.
 
@@ -1718,7 +2129,9 @@ def decode_steps(
     ``num_steps`` tokens instead of once per token. This is the TPU-native
     answer to per-dispatch host latency (the reference never runs a model;
     its vLLM pods solve this on the GPU side). Returns (sampled tokens
-    [b, num_steps] int32, k_pages, v_pages). The caller must pre-extend
+    [b, num_steps] int32, k_pages, v_pages), then the scale pools where
+    the pools are int8, then ``state_pages`` where one was given (carried
+    through the scan as the pools are). The caller must pre-extend
     ``block_tables`` to cover ``num_steps`` of growth; lanes that finish
     early keep decoding into their reserved pages and the host discards the
     surplus tokens. ``tokens`` may be the ``[b, n]`` ids a burst returned:
@@ -1726,6 +2139,7 @@ def decode_steps(
     between the two and none beside this one.
     """
     quantized = k_scales is not None
+    stateful = state_pages is not None
     if tokens.ndim == 2:
         tokens = tokens[:, -1]
     # The sampler's gate: the lanes' parameters do not change inside the
@@ -1733,39 +2147,45 @@ def decode_steps(
     any_sampled = jnp.any(temperature > 0)
 
     def body(carry, key):
-        tokens, positions, seq_lens, k_pages, v_pages, k_sc, v_sc = carry
-        logits, k_pages, v_pages, k_sc, v_sc = _decode_body(
+        tokens, positions, seq_lens, k_pages, v_pages, k_sc, v_sc, st = carry
+        logits, k_pages, v_pages, k_sc, v_sc, st = _decode_body(
             params, cfg, tokens, positions, k_pages, v_pages,
             block_tables, seq_lens, page_size, interpret, mesh,
-            k_sc, v_sc,
+            k_sc, v_sc, st,
         )
         nxt = sample_tokens(
             logits.astype(jnp.float32), temperature, top_k, top_p, key,
             any_sampled,
         )
-        return (nxt, positions + 1, seq_lens + 1, k_pages, v_pages, k_sc, v_sc), nxt
+        return (
+            nxt, positions + 1, seq_lens + 1, k_pages, v_pages, k_sc, v_sc, st
+        ), nxt
 
-    # None scales are valid (empty) scan-carry leaves, so the knob-off
-    # trace is unchanged apart from the tuple arity.
-    carry0 = (tokens, positions, seq_lens, k_pages, v_pages, k_scales, v_scales)
+    # None scales (and a None state pool) are valid (empty) scan-carry
+    # leaves, so the knob-off trace is unchanged apart from the tuple arity.
+    carry0 = (
+        tokens, positions, seq_lens, k_pages, v_pages, k_scales, v_scales,
+        state_pages,
+    )
     keys = jax.random.split(rng_key, num_steps)
     if num_steps == 1:
         # The step-per-token loop lands here every iteration: skip the
         # scan machinery for a plain body call. Consumes keys[0] exactly
         # like the scan's first slice, so sampled streams are
         # bit-identical across paths.
-        (_, _, _, k_pages, v_pages, k_scales, v_scales), nxt = body(
-            carry0, keys[0]
+        (_, _, _, k_pages, v_pages, k_scales, v_scales, state_pages), nxt = (
+            body(carry0, keys[0])
         )
         toks = nxt[:, None]
     else:
-        (_, _, _, k_pages, v_pages, k_scales, v_scales), toks = jax.lax.scan(
-            body, carry0, keys
-        )
+        (
+            _, _, _, k_pages, v_pages, k_scales, v_scales, state_pages
+        ), toks = jax.lax.scan(body, carry0, keys)
         toks = toks.T
-    if quantized:
-        return toks, k_pages, v_pages, k_scales, v_scales
-    return toks, k_pages, v_pages
+    extra = (k_scales, v_scales) if quantized else ()
+    if stateful:
+        extra += (state_pages,)
+    return (toks, k_pages, v_pages) + extra
 
 
 @functools.partial(
@@ -1907,7 +2327,7 @@ def spec_decode_steps(
         )
         slot_ids = positions % page_size
         # Scales stay None: the engine rejects spec_decode + KV_QUANT_HBM.
-        h, k_pages, v_pages, _, _ = _prefill_body(
+        h, k_pages, v_pages, *_ = _prefill_body(
             params, cfg, chunk, positions, valid, k_pages, v_pages,
             page_ids, slot_ids, block_tables, start, mesh, attn_impl,
             interpret,
@@ -2010,7 +2430,7 @@ def _denoise_body(
         jnp.clip(positions // page_size, 0, block_tables.shape[1] - 1),
         axis=1,
     )
-    h, k_pages, v_pages, _, _ = _prefill_body(
+    h, k_pages, v_pages, *_ = _prefill_body(
         params, cfg, tokens, positions, valid, k_pages, v_pages,
         page_ids, positions % page_size, block_tables, seq_lens, mesh,
         attn_impl, interpret, experts_touched=experts_touched,

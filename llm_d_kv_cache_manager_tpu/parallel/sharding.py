@@ -27,9 +27,13 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..models.llama import LlamaConfig, Params
 
 
-def _layer_specs(cfg: LlamaConfig, tp: int = 1, routed: bool = True) -> dict[str, P]:
+def _layer_specs(
+    cfg: LlamaConfig, tp: int = 1, routed: bool = True, conv: bool = False
+) -> dict[str, P]:
     """``routed``: the layer's FFN is the routed one (False for the leading
-    dense layers of a model with ``first_k_dense``)."""
+    dense layers of a model with ``first_k_dense``). ``conv``: its operator
+    is a gated short convolution (replicated: the engine refuses tp > 1 for
+    a model with such layers)."""
     specs = {
         "attn_norm": P(),
         "wq": P(None, "tp"),
@@ -75,6 +79,10 @@ def _layer_specs(cfg: LlamaConfig, tp: int = 1, routed: bool = True) -> dict[str
         # Per-head-dim scale, identical across heads → replicated.
         specs["q_norm"] = P()
         specs["k_norm"] = P()
+    if conv:
+        for name in ("wq", "wk", "wv", "wo", "bq", "bk", "bv", "q_norm", "k_norm"):
+            specs.pop(name, None)
+        specs.update(conv_in=P(), conv_w=P(), conv_out=P())
     return specs
 
 
@@ -84,7 +92,10 @@ def param_specs(cfg: LlamaConfig, tp: int = 1) -> dict[str, Any]:
         "embed": P("tp", None),  # vocab-sharded; gather rides ICI
         "final_norm": P(),
         "layers": [
-            _layer_specs(cfg, tp, routed=i >= cfg.first_k_dense)
+            _layer_specs(
+                cfg, tp, routed=i >= cfg.first_k_dense,
+                conv=cfg.layer_kind(i) == "conv",
+            )
             for i in range(cfg.n_layers)
         ],
     }

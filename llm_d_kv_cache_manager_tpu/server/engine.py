@@ -539,6 +539,41 @@ class Engine:
                         f"kv_lora_rank={cfg.kv_lora_rank} (a latent KV "
                         f"pool) is incompatible with {what}"
                     )
+        if cfg.n_conv_layers:
+            # Convolution layers keep state beside the keys and values of
+            # the layers that attend, a slot a page (``llama.
+            # init_state_pages``): what does not carry that state is refused
+            # here by name. The prefix cache, KV events, the index and the
+            # scorer know pages, and a page's slot is written by the program
+            # that writes its last token: they serve it as every model.
+            refused = {
+                "spec_decode (rejected drafts would have advanced the state)":
+                    config.spec_decode != "off",
+                "block_length > 0 (a block is not forwarded token by token)":
+                    cfg.block_length > 0,
+                "kv_quant_hbm (the state has no int8 form)":
+                    config.kv_quant_hbm is not None,
+                "host_pages > 0 (the host tier moves K and V pages)":
+                    config.block_manager.host_pages > 0,
+                "remote_tier (demotion payloads are K and V pages)":
+                    config.remote_tier,
+                "sp > 1 (the ring carries no state across shards)":
+                    config.sp > 1,
+                "tp > 1 (the state and a pool row of two KV heads are not "
+                "sharded)": config.tp > 1,
+                "conv_bias (a biased convolution is not run)": cfg.conv_bias,
+                "kv_lora_rank > 0 (no latent pool beside a state pool)":
+                    cfg.kv_lora_rank > 0,
+                "conv_L_cache < 2 (a convolution of one tap keeps no state)":
+                    cfg.conv_L_cache < 2,
+            }
+            for what, on in refused.items():
+                if on:
+                    raise ValueError(
+                        f"layer_types with {cfg.n_conv_layers} conv layers "
+                        f"(convolution state beside the KV pool) is "
+                        f"incompatible with {what}"
+                    )
         if config.kv_quant_hbm is not None:
             if config.kv_quant_hbm not in quant.KV_QUANT_HBM_MODES:
                 raise ValueError(
@@ -622,6 +657,15 @@ class Engine:
         #: ``kv_block_bytes`` follows it for a latent pool.
         self.kv_bytes_per_token = (
             self.k_pages.nbytes + self.v_pages.nbytes
+        ) // (config.block_manager.total_pages * ps)
+        #: the convolution layers' state pool (None: the model has no such
+        #: layer), addressed by the same page ids, and what it costs a
+        #: token slot: ``/stats`` reports it beside ``kv_bytes_per_token``
+        self.state_pages: Optional[jnp.ndarray] = llama.init_state_pages(
+            cfg, config.block_manager.total_pages, sharding=self._replicated
+        )
+        self.state_bytes_per_token = (
+            0 if self.state_pages is None else self.state_pages.nbytes
         ) // (config.block_manager.total_pages * ps)
         # Scale pools ride alongside the int8 page pools (None when the
         # knob is off — every scale-threading call site keys off this).
@@ -840,7 +884,9 @@ class Engine:
         #: summed over the layers and the dispatches: counted on the device).
         #: A latent pool: ``latent_ctx_tokens`` (the real lanes' context
         #: lengths, summed over the decode dispatches: the rows the
-        #: ``mla_decode`` kernel must read, a layer).
+        #: ``mla_decode`` kernel must read, a layer). Every model:
+        #: ``attn_ctx_tokens``, the same sum (what each layer that attends
+        #: reads of its pool in the fused decode dispatches).
         #: Off by default: ``obs_step_timing=False`` skips every clock
         #: read and every count, so the legacy step path is untouched.
         self.obs_step_timing = False
@@ -856,6 +902,7 @@ class Engine:
             "blocks_final": 0,
             "experts_touched": 0,
             "latent_ctx_tokens": 0,
+            "attn_ctx_tokens": 0,
             "prefill_s": 0.0,
             "decode_s": 0.0,
             "sample_s": 0.0,
@@ -1457,9 +1504,13 @@ class Engine:
         bytes, so a full-width figure here would overestimate pull cost
         ~2x and wrongly decline break-even pulls."""
         cfg = self.model_cfg
-        if cfg.kv_lora_rank:
-            # one pool of latent rows: a block is one page of it, all layers
-            return self.page_size * self.kv_bytes_per_token
+        if cfg.kv_lora_rank or cfg.n_conv_layers:
+            # one pool of latent rows, or the attention layers' K and V
+            # beside the convolution layers' state: a block is one page of
+            # every pool the model has
+            return self.page_size * (
+                self.kv_bytes_per_token + self.state_bytes_per_token
+            )
         elems = cfg.n_layers * self.page_size * cfg.n_kv_heads * cfg.hd
         if (
             self.config.kv_quant == "int8"
@@ -1471,14 +1522,22 @@ class Engine:
     def _refuse_latent_page_moves(self, what: str) -> None:
         """Pages leave and enter an engine as a K and a V page of KV heads
         (the wire's payload, the digests, the int8 form): not done for a
-        latent pool, whose second pool holds no page. ``PodServer`` refuses
-        ``transfer_endpoint`` for such a model at construction; this holds
-        any other caller."""
+        latent pool, whose second pool holds no page, nor for a model with
+        convolution layers, whose pages have a state slot beside them.
+        ``PodServer`` refuses ``transfer_endpoint`` for such a model at
+        construction; this holds any other caller."""
         if self.model_cfg.kv_lora_rank:
             raise ValueError(
                 f"kv_lora_rank={self.model_cfg.kv_lora_rank} (a latent KV "
                 f"pool) is incompatible with {what} (export and import move "
                 f"K and V pages)"
+            )
+        if self.model_cfg.n_conv_layers:
+            raise ValueError(
+                f"layer_types with {self.model_cfg.n_conv_layers} conv "
+                f"layers (convolution state beside the KV pool) is "
+                f"incompatible with {what} (export, import and migration "
+                f"move K and V pages and no state)"
             )
 
     def export_kv_blocks(self, hashes: list, max_blocks: Optional[int] = None):
@@ -1942,6 +2001,8 @@ class Engine:
         the sequence (migration committed) or clear ``importing``
         (fallback: local recompute, pages back to baseline). Engine
         thread only."""
+        if self.model_cfg.n_conv_layers:
+            self._refuse_latent_page_moves("freeze_for_migration")
         seq = None
         for cand in (
             list(self.scheduler.waiting)
@@ -2208,14 +2269,9 @@ class Engine:
                 k_scales=self.k_scales,
                 v_scales=self.v_scales,
                 interpret=self.config.interpret,
+                **self._state_arg(),
             )
-            if self.k_scales is None:
-                logits, self.k_pages, self.v_pages = out
-            else:
-                (
-                    logits, self.k_pages, self.v_pages,
-                    self.k_scales, self.v_scales,
-                ) = out
+            logits = self._keep_pools(out)
         if diffusion:
             # nothing is sampled from a block-diffusion prefill; the wait
             # for the dispatch keeps ``prefill_fetch`` what it is
@@ -2254,6 +2310,24 @@ class Engine:
                         seq.first_token_time = now
                     self._append_slot_or_preempt(seq)
                 self.block_manager.register_full_pages(seq)
+
+    def _state_arg(self) -> dict:
+        """The state pool as ``llama.prefill`` / ``decode_steps`` take it:
+        a keyword a model without convolution layers never sees."""
+        if self.state_pages is None:
+            return {}
+        return {"state_pages": self.state_pages}
+
+    def _keep_pools(self, out: tuple):
+        """Take back the donated pools a model program returned after its
+        first result, ``(first, k_pages, v_pages[, k_scales, v_scales][,
+        state_pages])``; returns the first."""
+        first, self.k_pages, self.v_pages, *rest = out
+        if self.k_scales is not None:
+            self.k_scales, self.v_scales, *rest = rest
+        if self.state_pages is not None:
+            (self.state_pages,) = rest
+        return first
 
     def _decode_table_width(self, seqs: list[Sequence]) -> int:
         """Block-table width for this decode call: longest active context in
@@ -2483,14 +2557,9 @@ class Engine:
                 mesh=self.mesh,
                 k_scales=self.k_scales,
                 v_scales=self.v_scales,
+                **self._state_arg(),
             )
-            if self.k_scales is None:
-                toks, self.k_pages, self.v_pages = out
-            else:
-                (
-                    toks, self.k_pages, self.v_pages,
-                    self.k_scales, self.v_scales,
-                ) = out
+            toks = self._keep_pools(out)
         self._count_decode_dispatch(
             len(active), temperature, seq_lens, k, chained=prev is not None
         )
@@ -3090,8 +3159,9 @@ class Engine:
         """``step_stats``' counters of one decode dispatch: its real lanes,
         whether any of them samples (``temperature`` is the host-side
         array the dispatch was given), whether its input ids came from the
-        burst in flight (``chained``) and, for a latent pool, the context
-        rows its ``steps`` fused steps read a layer (``seq_lens``: the
+        burst in flight (``chained``) and the context rows its ``steps``
+        fused steps read a layer that attends (``attn_ctx_tokens``; for a
+        latent pool also under ``latent_ctx_tokens``) (``seq_lens``: the
         host-side lengths of the dispatch, 0 for a lane that is not real;
         a lane's context grows by one a step)."""
         if self.obs_step_timing:
@@ -3101,11 +3171,14 @@ class Engine:
                 (temperature > 0).any()
             )
             self.step_stats["decode_chained_dispatches"] += chained
-            if seq_lens is not None and self.model_cfg.kv_lora_rank:
-                self.step_stats["latent_ctx_tokens"] += int(
+            if seq_lens is not None:
+                ctx = int(
                     steps * seq_lens.sum()
                     + rows * steps * (steps - 1) // 2
                 )
+                self.step_stats["attn_ctx_tokens"] += ctx
+                if self.model_cfg.kv_lora_rank:
+                    self.step_stats["latent_ctx_tokens"] += ctx
 
     def _sample(self, logits: jnp.ndarray, seqs: list[Sequence]) -> np.ndarray:
         """First tokens of a prefill batch (decode samples on the device,

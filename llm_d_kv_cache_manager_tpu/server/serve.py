@@ -356,6 +356,13 @@ class _ServingMetrics:
                 registry=self.registry,
             )
             self._latent_ctx_seen = 0
+            self.engine_attn_ctx = prom.Counter(
+                "kvcache_engine_attn_ctx_tokens_total",
+                "Context rows the fused decode dispatches read a layer that "
+                "attends: the real lanes' context lengths, summed",
+                registry=self.registry,
+            )
+            self._attn_ctx_seen = 0
             self.engine_chained = prom.Counter(
                 "kvcache_engine_decode_chained_dispatches_total",
                 "Decode dispatches enqueued one ahead: their input ids came "
@@ -368,6 +375,12 @@ class _ServingMetrics:
                 "kvcache_kv_bytes_per_token",
                 "Bytes one token holds in the KV pools, all layers, as held "
                 "on the device",
+                registry=self.registry,
+            )
+            self.state_bytes_per_token_g = prom.Gauge(
+                "kvcache_state_bytes_per_token",
+                "Bytes one token slot holds in the convolution layers' state "
+                "pool, all such layers (0: the model has none)",
                 registry=self.registry,
             )
             # Host-DRAM tier + prefetch (ISSUE 6): tier occupancy, pages
@@ -588,6 +601,10 @@ class _ServingMetrics:
         if latent > self._latent_ctx_seen:
             self.engine_latent_ctx.inc(latent - self._latent_ctx_seen)
             self._latent_ctx_seen = latent
+        attn_ctx = step_stats.get("attn_ctx_tokens", 0)
+        if attn_ctx > self._attn_ctx_seen:
+            self.engine_attn_ctx.inc(attn_ctx - self._attn_ctx_seen)
+            self._attn_ctx_seen = attn_ctx
         chained = step_stats.get("decode_chained_dispatches", 0)
         if chained > self._chained_seen:
             self.engine_chained.inc(chained - self._chained_seen)
@@ -596,13 +613,15 @@ class _ServingMetrics:
             self.engine_loop_lag.set(lag_s)
 
     def set_engine_gauges(
-        self, occupancy: float, free_pages: int, kv_bytes_per_token: int
+        self, occupancy: float, free_pages: int, kv_bytes_per_token: int,
+        state_bytes_per_token: int = 0,
     ) -> None:
         if self._prom is None or not self._obs:
             return
         self.engine_occupancy.set(occupancy)
         self.engine_free_pages.set(free_pages)
         self.kv_bytes_per_token_g.set(kv_bytes_per_token)
+        self.state_bytes_per_token_g.set(state_bytes_per_token)
 
     def observe_host_prefetch(self, seconds: float) -> None:
         if self._prom is None or not self._obs:
@@ -1227,6 +1246,13 @@ class PodServer:
                 f"kv_lora_rank={model.kv_lora_rank} (a latent KV pool) is "
                 f"incompatible with transfer_endpoint (TRANSFER_ENDPOINT: "
                 f"export and import move K and V pages)"
+            )
+        if model.n_conv_layers and self.config.transfer_endpoint:
+            raise ValueError(
+                f"layer_types with {model.n_conv_layers} conv layers "
+                f"(convolution state beside the KV pool) is incompatible "
+                f"with transfer_endpoint (TRANSFER_ENDPOINT: export, import "
+                f"and migration move K and V pages and no state)"
             )
         if self.config.remote_tier and engine is None:
             # Thread the knob family into the engine config BEFORE the
@@ -2217,6 +2243,7 @@ class PodServer:
                             / max(self.config.engine.decode_batch_size, 1),
                             self.engine.block_manager.num_free,
                             self.engine.kv_bytes_per_token,
+                            self.engine.state_bytes_per_token,
                         )
                         if self.config.engine.block_manager.host_pages:
                             bm = self.engine.block_manager
@@ -3643,6 +3670,7 @@ class PodServer:
                 "free_pages": bm.num_free,
                 "total_pages": bm.config.total_pages,
                 "kv_bytes_per_token": self.engine.kv_bytes_per_token,
+                "state_bytes_per_token": self.engine.state_bytes_per_token,
                 "prefill": dict(self.engine.prefill_stats),
                 "transfer": {
                     **self.engine.transfer_stats,
@@ -4008,6 +4036,8 @@ def _resolve_model(name: str) -> LlamaConfig:
         "tiny-sdar-moe": models.TINY_SDAR_MOE,
         "kakaocorp/kanana-2-30b-a3b-instruct-2601": models.KANANA_2_30B_A3B,
         "tiny-mla-moe": models.TINY_MLA_MOE,
+        "LiquidAI/LFM2-8B-A1B": models.LFM2_8B_A1B,
+        "tiny-lfm2-moe": models.TINY_LFM2_MOE,
     }
     if name in presets:
         return presets[name]

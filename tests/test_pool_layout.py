@@ -27,6 +27,7 @@ Three parts:
   against the five-dimensional expression it replaced.
 """
 
+import dataclasses
 import faulthandler
 import re
 
@@ -86,12 +87,17 @@ def _whole_pool_moves(hlo, pool_shape):
 # lanes x a block of 4), 1024 (a prefill dispatch: 8 x 128).
 _MOE_POOL = (8, 4096, 16, 4, 128)  # qwen3-30b-a3b, sdar-30b-a3b
 _DENSE_POOL = (5, 8192, 16, 8, 128)  # qwen3-32b
+# lfm2-8b-a1b: 3 attention layers of 14; 8 KV heads of 64, two a 128-lane row
+_HYBRID_POOL = (3, 16384, 16, 4, 128)
+_STATE_POOL = (11, 16384, 2 * 2048)  # its 11 convolution layers' slots
 _CASES = [
     pytest.param(_MOE_POOL, (16, 1), 1, id="kv4-decode16"),
     pytest.param(_MOE_POOL, (16, 4), 1, id="kv4-block64"),
     pytest.param(_MOE_POOL, (8, 128), 1, id="kv4-prefill1024"),
     pytest.param(_DENSE_POOL, (16, 1), 1, id="kv8-decode16"),
     pytest.param(_DENSE_POOL, (8, 128), 1, id="kv8-prefill1024"),
+    pytest.param(_HYBRID_POOL, (32, 1), 1, id="kv8x64-decode32"),
+    pytest.param(_HYBRID_POOL, (8, 128), 1, id="kv8x64-prefill1024"),
     # A tp=4 slice: the pool is sharded on the KV-head axis, which the flat
     # view leaves alone (it merges the three replicated leading axes).
     pytest.param(_MOE_POOL, (16, 1), 4, id="kv4-tp4-decode16"),
@@ -333,9 +339,76 @@ class TestTheLatentPool:
             )
 
 
+class TestTheStatePoolAndTheRowOfTwoHeads:
+    """A model with convolution layers (PR 34): heads of 64 lie two a
+    128-lane row in the key/value pools, so that the compiler pads nothing
+    (``LlamaConfig.kv_row_shape``), and the convolution layers' state lies a
+    page a row in a pool of its own, read and written as flat slots."""
+
+    def test_a_row_of_two_heads_is_held_unpadded(self, topo):
+        one_chip = SingleDeviceSharding(topo.devices[0])
+        cfg = llama.LFM2_8B_A1B
+        assert cfg.kv_row_shape == _HYBRID_POOL[3:]
+
+        def shaped(shape, dtype):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+        write = (32, 1)
+        hlo = aot_pool_copies.compile_text(
+            jax.jit(_scatter_kv_pages_all_layers, donate_argnums=0),
+            shaped(_HYBRID_POOL, jnp.bfloat16),
+            shaped((3, *write, *cfg.kv_row_shape), jnp.bfloat16),
+            shaped(write, jnp.int32), shaped(write, jnp.int32),
+            shaped(write, jnp.bool_),
+        )
+        layout = aot_pool_copies.pool_layout(hlo, _HYBRID_POOL)
+        assert layout.startswith("bf16[3,16384,16,4,128]{4,3,2,1,0:T(4,128)(2,1)"), layout
+        # the same heads a row each would be held in twice the bytes: the
+        # minor dimension of 64 is laid out on 128 lanes
+        padded = (3, 16384, 16, 8, 64)
+        hlo = aot_pool_copies.compile_text(
+            jax.jit(_scatter_kv_pages_all_layers, donate_argnums=0),
+            shaped(padded, jnp.bfloat16), shaped((3, *write, 8, 64), jnp.bfloat16),
+            shaped(write, jnp.int32), shaped(write, jnp.int32),
+            shaped(write, jnp.bool_),
+        )
+        assert "T(8,128)" in aot_pool_copies.pool_layout(hlo, padded)
+
+    @pytest.mark.parametrize("touched", [(32, 1), (8, 9)], ids=["decode32", "prefill8x9pages"])
+    def test_the_state_is_read_and_written_as_flat_slots(self, topo, touched):
+        one_chip = SingleDeviceSharding(topo.devices[0])
+        cfg = dataclasses.replace(llama.LFM2_8B_A1B, n_layers=14)
+        assert jax.eval_shape(
+            lambda: llama.init_state_pages(cfg, 16384)).shape == _STATE_POOL
+
+        def shaped(shape, dtype):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+        def step(state, prev_page, has_prev, fresh, page, ok):
+            before = llama._conv_prev_state(state, cfg, prev_page, has_prev)
+            return before, llama._scatter_state_pages(state, fresh, page, ok)
+
+        b = touched[0]
+        hlo = aot_pool_copies.compile_text(
+            jax.jit(step, donate_argnums=0),
+            shaped(_STATE_POOL, jnp.bfloat16), shaped((b,), jnp.int32),
+            shaped((b,), jnp.bool_),
+            shaped((11, *touched, _STATE_POOL[2]), jnp.bfloat16),
+            shaped(touched, jnp.int32), shaped(touched, jnp.bool_),
+        )
+        assert aot_pool_copies.pool_instructions(hlo, _STATE_POOL)
+        # (with the layer axis in the gather's window the compiler copied
+        # the whole pool into a layer-inward layout before every read)
+        assert _whole_pool_moves(hlo, _STATE_POOL) == []
+        layout = aot_pool_copies.pool_layout(hlo, _STATE_POOL)
+        assert layout.startswith("bf16[11,16384,4096]{2,1,0:T(8,128)(2,1)"), layout
+
+
 _SERVED = [
     ("kanana-2-30b-a3b", "decode_steps"),
     ("kanana-2-30b-a3b", "prefill"),
+    ("lfm2-8b-a1b", "decode_steps"),
+    ("lfm2-8b-a1b", "prefill"),
     ("qwen3-30b-a3b", "decode_steps"),
     ("qwen3-30b-a3b", "prefill"),
     ("qwen3-32b", "decode_steps"),
@@ -368,6 +441,11 @@ class TestServedPrograms:
             (i.opcode, i.name, i.result) for i in found
             if i.moves_bytes and "scatter" not in inside.get(i.name, ())
         ] == []
+        state_shape = aot_pool_copies.state_pool_shape(kwargs)
+        assert (state_shape is not None) == (config == "lfm2-8b-a1b")
+        if state_shape:  # the convolution layers' state pool beside them
+            assert aot_pool_copies.pool_instructions(hlo, state_shape)
+            assert _whole_pool_moves(hlo, state_shape) == []
 
     def test_a_program_the_configuration_does_not_serve(self, topo):
         one_chip = SingleDeviceSharding(topo.devices[0])

@@ -8,7 +8,9 @@ shapes ``chipbench/configs/<name>.json`` pins — for one v5e chip and lists
 every instruction outside a fusion's body whose result has the shape of a
 whole K or V pool ``[L, P, page, n_kv, hd]`` (a latent model's one pool:
 ``[L, P, page, row]``) or of one layer's slice of it, and the layout the
-compiler gives the pool.
+compiler gives the pool. A model with convolution layers has a state pool
+beside them (``[conv layers, P, row]``, ``state_pool_shape``): it is listed
+the same way.
 
 A pool is hundreds of MiB: any such instruction that is not free (a
 ``bitcast``, a ``parameter``, tuple plumbing) reads and writes that much
@@ -218,6 +220,11 @@ def served_program(config: str, program: str, one_chip):
             lambda: llama.init_kv_pages(cfg, env["TOTAL_PAGES"], page))
     )
     pool, second = S(pool_shape, jnp.bfloat16), S(second_shape, jnp.bfloat16)
+    # ... and, for a model with convolution layers, the state pool beside them
+    state = jax.eval_shape(
+        lambda: llama.init_state_pages(cfg, env["TOTAL_PAGES"]))
+    stateful = {} if state is None else {
+        "state_pages": S(state.shape, state.dtype)}
     key = S((2,), jnp.uint32)
     if program == "decode_steps" and cfg.block_length == 0:
         args = (
@@ -225,7 +232,8 @@ def served_program(config: str, program: str, one_chip):
             S((lanes, table_w), i32), S((lanes,), i32), S((lanes,), f32),
             S((lanes,), i32), S((lanes,), f32), key,
         )
-        kwargs = dict(page_size=page, num_steps=1, interpret=False, mesh=None)
+        kwargs = dict(page_size=page, num_steps=1, interpret=False, mesh=None,
+                      **stateful)
         return llama.decode_steps, args, kwargs, pool_shape
     if program == "denoise_steps" and cfg.block_length > 0:
         width = 2 * cfg.block_length + table_w + 5
@@ -243,9 +251,16 @@ def served_program(config: str, program: str, one_chip):
             S((PREFILL_ROWS, engine["prefill_ctx_bucket"]), i32),
             S((PREFILL_ROWS,), i32),
         )
-        kwargs = dict(mesh=None, attn_impl="pallas", interpret=False)
+        kwargs = dict(mesh=None, attn_impl="pallas", interpret=False, **stateful)
         return llama.prefill, args, kwargs, pool_shape
     return None
+
+
+def state_pool_shape(kwargs: dict):
+    """The state pool's shape among a served program's keyword arguments
+    (``served_program``), or None: the model has no convolution layers."""
+    state = kwargs.get("state_pages")
+    return None if state is None else tuple(state.shape)
 
 
 def main(argv=None) -> int:
@@ -269,21 +284,29 @@ def main(argv=None) -> int:
             if opts.dump:
                 os.makedirs(opts.dump, exist_ok=True)
                 Path(opts.dump, f"{config}.{program}.hlo.txt").write_text(hlo)
-            found = pool_instructions(hlo, pool_shape, layer_slices=True)
-            moving = [i for i in found if i.moves_bytes]
-            print(f"{config} {program} pool {list(pool_shape)}: "
-                  f"{len(moving)} of {len(found)} instructions move bytes; "
-                  f"held as {pool_layout(hlo, pool_shape)}")
-            # Eight layers make eight lines that differ in a suffix: one
-            # line for each (opcode, result), with the first name.
-            alike: dict[tuple, list[str]] = {}
-            for i in found:
-                key = (i.moves_bytes, i.opcode, tuple_members(i.result))
-                alike.setdefault(key, []).append(i.name)
-            for (moves, opcode, result), group in alike.items():
-                print(f"  {'*' if moves else ' '} {len(group):>2} x {opcode:<18} "
-                      f"%{group[0]:<24} {result}")
+            pools = {"pool": pool_shape}
+            if state_pool_shape(kwargs):
+                pools["state pool"] = state_pool_shape(kwargs)
+            for what, shape in pools.items():
+                report(f"{config} {program} {what}", hlo, shape)
     return 0
+
+
+def report(title: str, hlo: str, pool_shape: tuple[int, ...]) -> None:
+    found = pool_instructions(hlo, pool_shape, layer_slices=True)
+    moving = [i for i in found if i.moves_bytes]
+    print(f"{title} {list(pool_shape)}: "
+          f"{len(moving)} of {len(found)} instructions move bytes; "
+          f"held as {pool_layout(hlo, pool_shape)}")
+    # Eight layers make eight lines that differ in a suffix: one
+    # line for each (opcode, result), with the first name.
+    alike: dict[tuple, list[str]] = {}
+    for i in found:
+        key = (i.moves_bytes, i.opcode, tuple_members(i.result))
+        alike.setdefault(key, []).append(i.name)
+    for (moves, opcode, result), group in alike.items():
+        print(f"  {'*' if moves else ' '} {len(group):>2} x {opcode:<18} "
+              f"%{group[0]:<24} {result}")
 
 
 if __name__ == "__main__":
