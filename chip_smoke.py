@@ -796,23 +796,19 @@ def prove_device_not_hidden(server, sizing: Sizing) -> dict:
     width = -(-pages // bucket) * bucket
     decode = llama.decode_steps.lower(
         eng.params, eng.model_cfg, arr((lanes,), np.int32),
-        arr((lanes,), np.int32), like(eng.k_pages), like(eng.v_pages),
-        arr((lanes, width), np.int32), arr((lanes,), np.int32),
-        arr((lanes,), np.float32), arr((lanes,), np.int32),
-        arr((lanes,), np.float32), like(eng._rng),
+        arr((lanes, width + llama.DECODE_PACKED_TAIL), np.int32),
+        like(eng.k_pages), like(eng.v_pages), like(eng._rng),
         page_size=eng.page_size, num_steps=eng.config.decode_steps_per_iter,
         interpret=False, mesh=eng.mesh,
     ).compile().as_text()
     b = eng.config.scheduler.max_prefill_batch
     chunk = eng.config.prefill_bucket
     ctx_pages = sizing.prefix_len // eng.page_size
-    prefill = llama.prefill.lower(
-        eng.params, eng.model_cfg, arr((b, chunk), np.int32),
-        arr((b, chunk), np.int32), arr((b, chunk), np.bool_),
-        like(eng.k_pages), like(eng.v_pages), arr((b, chunk), np.int32),
-        arr((b, chunk), np.int32), arr((b, ctx_pages), np.int32),
-        arr((b,), np.int32), mesh=eng.mesh, attn_impl=eng.prefill_attn,
-        interpret=False,
+    prefill = llama.prefill_packed.lower(
+        eng.params, eng.model_cfg,
+        arr((b, 5 * chunk + ctx_pages + 1), np.int32),
+        like(eng.k_pages), like(eng.v_pages), chunk=chunk, mesh=eng.mesh,
+        attn_impl=eng.prefill_attn, interpret=False,
     ).compile().as_text()
     check("tpu_custom_call" in decode,
           "compiled decode step contains a Mosaic custom call")

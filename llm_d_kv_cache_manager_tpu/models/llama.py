@@ -65,8 +65,10 @@ from ..ops.rope import RopeScalingConfig
 from ..ops.sampling import (
     block_candidates,
     block_transfer,
+    pack_sampling_params,
     sample_tokens,
     spec_sample,
+    unpack_sampling_params,
 )
 from .quant import QuantizedTensor, materialize as _w
 
@@ -1895,6 +1897,58 @@ def prefill(
     return (_logits(params, cfg, h_last[:, None, :])[:, 0], k_pages, v_pages) + extra
 
 
+def pack_prefill_inputs(
+    tokens, positions, valid, page_ids, slot_ids, block_tables, ctx_lens
+) -> np.ndarray:
+    """``prefill``'s seven host arrays as ONE, so that a dispatch costs one
+    upload: int32 ``[b, 5 * chunk + ctx_pages + 1]`` = ``[tokens | positions
+    | valid | page_ids | slot_ids | block_tables | ctx_len]``, a width the
+    two buckets that key the program fix. ``prefill_packed`` takes it."""
+    return np.concatenate(
+        [tokens, positions, valid, page_ids, slot_ids, block_tables,
+         np.asarray(ctx_lens)[:, None]],
+        axis=1, dtype=np.int32,
+    )
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("cfg", "chunk", "mesh", "attn_impl", "interpret"),
+    donate_argnames=(
+        "k_pages", "v_pages", "k_scales", "v_scales", "state_pages",
+    ),
+)
+def prefill_packed(
+    params: Params,
+    cfg: LlamaConfig,
+    packed: jnp.ndarray,  # [b, 5 * chunk + ctx_pages + 1]: ``pack_prefill_inputs``
+    k_pages: jnp.ndarray,
+    v_pages: jnp.ndarray,
+    *,
+    chunk: int,  # the chunk bucket: where ``packed``'s columns divide
+    mesh=None,
+    attn_impl: str = "xla",
+    k_scales=None,
+    v_scales=None,
+    interpret: bool = False,
+    state_pages=None,
+) -> tuple[jnp.ndarray, ...]:
+    """``prefill`` as the engine dispatches it: the same program (the one
+    ``_prefill_body``, last-position logits) behind one packed operand that
+    is sliced apart here. ``chunk`` and the operand's width are the two
+    buckets that key ``prefill``, so the set of programs is the same."""
+    tokens, positions, valid, page_ids, slot_ids = (
+        packed[:, i * chunk : (i + 1) * chunk] for i in range(5)
+    )
+    # the function ``prefill`` jits, traced into this program
+    return prefill.__wrapped__(
+        params, cfg, tokens, positions, valid != 0, k_pages, v_pages,
+        page_ids, slot_ids, packed[:, 5 * chunk : -1], packed[:, -1],
+        mesh=mesh, attn_impl=attn_impl, k_scales=k_scales, v_scales=v_scales,
+        interpret=interpret, state_pages=state_pages,
+    )
+
+
 def _decode_body(
     params: Params,
     cfg: LlamaConfig,
@@ -2052,6 +2106,44 @@ def _decode_body(
     )
 
 
+#: columns behind the block table in ``decode_steps``' packed operand
+DECODE_PACKED_TAIL = 5
+
+
+def pack_decode_inputs(
+    positions,  # [b] int32 — position of each lane's input token
+    block_tables,  # [b, max_pages] int32 (covers num_steps growth)
+    seq_lens,  # [b] int32 — context length INCLUDING the input token; 0 = idle
+    temperature,  # [b] f32; 0 = greedy
+    top_k,  # [b] int32; 0 = disabled
+    top_p,  # [b] f32; 1 = disabled
+) -> np.ndarray:
+    """The host side of ``decode_steps``' packed operand: int32
+    ``[b, max_pages + DECODE_PACKED_TAIL]`` = ``[block table | position,
+    seq_len | top_k, temperature, top_p]`` (``pack_sampling_params``: the
+    two floats ride as their bits)."""
+    return np.concatenate(
+        [
+            np.asarray(block_tables, np.int32),
+            np.stack([positions, seq_lens], axis=1).astype(np.int32),
+            pack_sampling_params(temperature, top_k, top_p),
+        ],
+        axis=1,
+    )
+
+
+def _unpack_decode_inputs(packed: jnp.ndarray):
+    """``pack_decode_inputs`` undone inside the program (slices of one
+    operand): block tables, positions, seq_lens, temperature, top_k,
+    top_p."""
+    table_w = packed.shape[1] - DECODE_PACKED_TAIL
+    temperature, top_k, top_p = unpack_sampling_params(packed[:, table_w + 2 :])
+    return (
+        packed[:, :table_w], packed[:, table_w], packed[:, table_w + 1],
+        temperature, top_k, top_p,
+    )
+
+
 @functools.partial(
     jax.jit,
     static_argnames=("cfg", "page_size", "interpret", "mesh"),
@@ -2104,14 +2196,9 @@ def decode_steps(
     cfg: LlamaConfig,
     tokens: jnp.ndarray,  # [b] int32 — last sampled token per sequence,
     # or [b, n]: a burst's own output, whose last column is taken here
-    positions: jnp.ndarray,  # [b] int32 — position of `tokens`
+    packed: jnp.ndarray,  # [b, max_pages + 5] int32: ``pack_decode_inputs``
     k_pages: jnp.ndarray,
     v_pages: jnp.ndarray,
-    block_tables: jnp.ndarray,  # [b, max_pages] int32 (covers num_steps growth)
-    seq_lens: jnp.ndarray,  # [b] int32 — context length INCLUDING `tokens`
-    temperature: jnp.ndarray,  # [b] f32; 0 = greedy
-    top_k: jnp.ndarray,  # [b] int32; 0 = disabled
-    top_p: jnp.ndarray,  # [b] f32; 1 = disabled
     rng_key: jax.Array,
     *,
     page_size: int,
@@ -2137,11 +2224,21 @@ def decode_steps(
     surplus tokens. ``tokens`` may be the ``[b, n]`` ids a burst returned:
     the next burst then starts from them on the device, with no program
     between the two and none beside this one.
+
+    Every other per-lane input arrives as ONE array (``pack_decode_inputs``:
+    one upload a dispatch, not six) and is sliced apart here, inside the
+    program: the benchmark's step rooflines divide by the mean time of every
+    traced module whose name carries ``decode_steps``, so no second program
+    of that name may run beside this one. ``tokens`` stays an operand of its
+    own: a chained dispatch hands them over where they lie on the device.
     """
     quantized = k_scales is not None
     stateful = state_pages is not None
     if tokens.ndim == 2:
         tokens = tokens[:, -1]
+    block_tables, positions, seq_lens, temperature, top_k, top_p = (
+        _unpack_decode_inputs(packed)
+    )
     # The sampler's gate: the lanes' parameters do not change inside the
     # burst, so whether any lane samples is decided once, out here.
     any_sampled = jnp.any(temperature > 0)

@@ -34,6 +34,28 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+
+
+def pack_sampling_params(temperature, top_k, top_p) -> np.ndarray:
+    """The lanes' sampling parameters as ONE host array, so that they cost
+    one upload (or none of their own, as columns of a larger one): int32
+    ``[rows, 3]`` = (top_k, temperature, top_p), the two floats as their
+    bits. ``unpack_sampling_params`` reads them back inside a program."""
+    return np.stack(
+        [
+            np.asarray(top_k, np.int32),
+            np.asarray(temperature, np.float32).view(np.int32),
+            np.asarray(top_p, np.float32).view(np.int32),
+        ],
+        axis=1,
+    )
+
+
+def unpack_sampling_params(packed: jnp.ndarray):
+    """(temperature, top_k, top_p) of ``pack_sampling_params``' columns."""
+    as_f32 = lambda x: jax.lax.bitcast_convert_type(x, jnp.float32)
+    return as_f32(packed[:, 1]), packed[:, 0], as_f32(packed[:, 2])
 
 
 def _filtered_logits(
@@ -102,6 +124,20 @@ def sample_tokens(
     return jax.lax.cond(
         any_sampled, _sample_filtered, _sample_greedy,
         logits, temperature, top_k, top_p, rng_key,
+    )
+
+
+@jax.jit
+def sample_tokens_packed(
+    logits: jnp.ndarray,  # [batch, vocab], any float dtype
+    packed: jnp.ndarray,  # [batch, 3] int32: ``pack_sampling_params``
+    rng_key: jax.Array,
+) -> jnp.ndarray:
+    """``sample_tokens`` over float32 logits with the lanes' parameters in
+    one operand: what a prefill's first tokens are sampled by."""
+    temperature, top_k, top_p = unpack_sampling_params(packed)
+    return sample_tokens(
+        logits.astype(jnp.float32), temperature, top_k, top_p, rng_key
     )
 
 

@@ -39,7 +39,7 @@ from ..models import llama, quant
 from ..models.llama import LlamaConfig
 from ..utils import get_logger
 from .block_manager import AllocationError, BlockManager, BlockManagerConfig
-from ..ops.sampling import sample_tokens
+from ..ops.sampling import pack_sampling_params, sample_tokens_packed
 from .scheduler import Scheduler, SchedulerConfig
 from .sequence import (
     DEFAULT_CONFIDENCE_THRESHOLD,
@@ -873,8 +873,10 @@ class Engine:
         #: vocabulary filter in these and in no other),
         #: ``decode_chained_dispatches`` (those whose input ids came from
         #: the burst in flight, on the device: enqueued before that
-        #: burst's tokens were fetched); prefill dispatches
-        #: are counted, always, in ``prefill_stats``. Block diffusion
+        #: burst's tokens were fetched), ``decode_uploads`` /
+        #: ``prefill_uploads`` (host arrays a decode / prefill dispatch
+        #: staged on the device, ``_stage``: one or two a dispatch); prefill
+        #: dispatches are counted, always, in ``prefill_stats``. Block diffusion
         #: (``_run_decode_block``, beside those three):
         #: ``denoise_lane_forwards`` (lanes x dispatches in which the lane
         #: had a masked row), ``commit_lane_forwards`` (in which it had none:
@@ -896,6 +898,8 @@ class Engine:
             "decode_rows": 0,
             "decode_sampled_dispatches": 0,
             "decode_chained_dispatches": 0,
+            "decode_uploads": 0,
+            "prefill_uploads": 0,
             "denoise_lane_forwards": 0,
             "commit_lane_forwards": 0,
             "block_tokens_fixed": 0,
@@ -922,6 +926,31 @@ class Engine:
     def _dev(self, x, dtype=None) -> jax.Array:
         """Stage a host value on the device(s) this engine owns."""
         return jax.device_put(np.asarray(x, dtype), self._replicated)
+
+    def _stage(self, kind: str, *host: np.ndarray) -> list[jax.Array]:
+        """How a dispatch's host inputs reach the device, last thing before
+        the device call: queued page moves first (restores must land before
+        attention reads them, spilled pages be snapshotted before the
+        dispatch overwrites them, and a reservation may have queued more),
+        then every array of ``host`` in one upload each. A dispatch packs
+        what it can into one array, which its program slices apart: an
+        upload costs the host about as much for forty bytes as for forty
+        thousand. ``kind`` ("decode" | "prefill") names the counter."""
+        self._flush_page_moves()
+        if self.obs_step_timing:
+            self.step_stats[kind + "_uploads"] += len(host)
+        return [self._dev(x) for x in host]
+
+    def _draw_key(self, temperature: np.ndarray) -> jax.Array:
+        """The key of a dispatch that samples on the device. With a sampled
+        lane, a split of the engine's rng. All greedy: the sampler's gate
+        never reads the key, so the engine's rng stays where it is (a
+        device program less, and sampled streams elsewhere in the run do
+        not shift because greedy lanes ran)."""
+        if (temperature > 0).any():
+            self._rng, key = jax.random.split(self._rng)
+            return key
+        return self._greedy_key
 
     def phase(self, name: str):
         """Context manager for one of ``STEP_PHASES``. Off (the default):
@@ -2240,30 +2269,21 @@ class Engine:
                 ctx_bt[i, :n_ctx_pages] = seq.block_table[:n_ctx_pages]
                 ctx_lens[i] = start
 
-        with self.phase("prefill_put"):
-            # Flush queued page moves LAST before the dispatch (restores must
-            # land before attention reads; spilled pages must be snapshotted
-            # before this prefill overwrites them).
-            self._flush_page_moves()
-            t0 = time.perf_counter()
-            tokens_d, positions_d, valid_d = (
-                self._dev(tokens), self._dev(positions), self._dev(valid)
+            packed = llama.pack_prefill_inputs(
+                tokens, positions, valid, page_ids, slot_ids, ctx_bt, ctx_lens
             )
-            page_ids_d, slot_ids_d = self._dev(page_ids), self._dev(slot_ids)
-            ctx_bt_d, ctx_lens_d = self._dev(ctx_bt), self._dev(ctx_lens)
+
+        with self.phase("prefill_put"):
+            (packed_d,) = self._stage("prefill", packed)
+            t0 = time.perf_counter()
         with self.phase("prefill_dispatch"):
-            out = llama.prefill(
+            out = llama.prefill_packed(
                 self.params,
                 self.model_cfg,
-                tokens_d,
-                positions_d,
-                valid_d,
+                packed_d,
                 self.k_pages,
                 self.v_pages,
-                page_ids_d,
-                slot_ids_d,
-                ctx_bt_d,
-                ctx_lens_d,
+                chunk=chunk,
                 mesh=self.mesh,
                 attn_impl=self.prefill_attn,
                 k_scales=self.k_scales,
@@ -2518,38 +2538,27 @@ class Engine:
                     positions[i] = seq.num_tokens - 1
                     seq_lens[i] = seq.num_tokens
 
+            packed = llama.pack_decode_inputs(
+                positions, block_tables, seq_lens, temperature, top_k, top_p
+            )
+
         with self.phase("decode_put"):
-            # Flush AFTER burst reservation (which can preempt + recycle
-            # pages, queueing offloads whose content this dispatch
-            # overwrites) and immediately before the device call.
-            self._flush_page_moves()
-            self._rng, key = jax.random.split(self._rng)
-            # chained: the burst's sampled ids stay on the device, placed
-            # as an upload is (no copy where they already lie so)
-            tokens_dev = (
-                jax.device_put(prev["toks"], self._replicated)
-                if prev is not None else self._dev(tokens)
-            )
-            positions_d, block_tables_d = (
-                self._dev(positions), self._dev(block_tables)
-            )
-            seq_lens_d, temperature_d = (
-                self._dev(seq_lens), self._dev(temperature)
-            )
-            top_k_d, top_p_d = self._dev(top_k), self._dev(top_p)
+            key = self._draw_key(temperature)
+            if prev is not None:
+                # chained: the burst's sampled ids stay on the device, placed
+                # as an upload is (no copy where they already lie so)
+                (packed_d,) = self._stage("decode", packed)
+                tokens_d = jax.device_put(prev["toks"], self._replicated)
+            else:
+                packed_d, tokens_d = self._stage("decode", packed, tokens)
         with self.phase("decode_dispatch"):
             out = llama.decode_steps(
                 self.params,
                 self.model_cfg,
-                tokens_dev,
-                positions_d,
+                tokens_d,
+                packed_d,
                 self.k_pages,
                 self.v_pages,
-                block_tables_d,
-                seq_lens_d,
-                temperature_d,
-                top_k_d,
-                top_p_d,
                 key,
                 page_size=self.page_size,
                 num_steps=k,
@@ -2767,15 +2776,8 @@ class Engine:
                 fparams[i, 1] = seq.sampling.top_p
 
         with self.phase("decode_put"):
-            self._flush_page_moves()
-            if (fparams[:, 0] > 0).any():
-                self._rng, key = jax.random.split(self._rng)
-            else:
-                # All-greedy burst: the device cond never reads the key —
-                # leave the engine rng untouched (sampled streams elsewhere in
-                # the run must not shift because a greedy lane speculated).
-                key = self._greedy_key
-            packed_i32_d, fparams_d = self._dev(packed_i32), self._dev(fparams)
+            key = self._draw_key(fparams[:, 0])
+            packed_i32_d, fparams_d = self._stage("decode", packed_i32, fparams)
         with self.phase("decode_dispatch"):
             packed, self.k_pages, self.v_pages = (
                 llama.spec_decode_steps(
@@ -2916,13 +2918,8 @@ class Engine:
                 )
 
         with self.phase("decode_put"):
-            self._flush_page_moves()
-            if (fparams[:, 1] > 0).any():
-                self._rng, key = jax.random.split(self._rng)
-            else:
-                # all greedy: the sampler's gate never reads the key
-                key = self._greedy_key
-            packed_i32_d, fparams_d = self._dev(packed_i32), self._dev(fparams)
+            key = self._draw_key(fparams[:, 1])
+            packed_i32_d, fparams_d = self._stage("decode", packed_i32, fparams)
         with self.phase("decode_dispatch"):
             packed, self.k_pages, self.v_pages = llama.denoise_steps(
                 self.params,
@@ -3194,14 +3191,9 @@ class Engine:
                 top_k[i] = seq.sampling.top_k
                 top_p[i] = seq.sampling.top_p
         with self.phase("prefill_put"):
-            self._rng, key = jax.random.split(self._rng)
-            temperature_d, top_k_d, top_p_d = (
-                self._dev(temperature), self._dev(top_k), self._dev(top_p)
+            key = self._draw_key(temperature)
+            (sampling_d,) = self._stage(
+                "prefill", pack_sampling_params(temperature, top_k, top_p)
             )
         with self.phase("prefill_fetch"):
-            return np.asarray(
-                sample_tokens(
-                    logits.astype(jnp.float32),
-                    temperature_d, top_k_d, top_p_d, key,
-                )
-            )
+            return np.asarray(sample_tokens_packed(logits, sampling_d, key))

@@ -371,6 +371,14 @@ class _ServingMetrics:
                 registry=self.registry,
             )
             self._chained_seen = 0
+            self.engine_uploads = prom.Counter(
+                "kvcache_engine_dispatch_uploads_total",
+                "Host arrays staged on the device for model dispatches, by "
+                "dispatch (decode / prefill): a dispatch packs its inputs, "
+                "so one or two each",
+                ["dispatch"], registry=self.registry,
+            )
+            self._uploads_seen = {"decode": 0, "prefill": 0}
             self.kv_bytes_per_token_g = prom.Gauge(
                 "kvcache_kv_bytes_per_token",
                 "Bytes one token holds in the KV pools, all layers, as held "
@@ -609,6 +617,11 @@ class _ServingMetrics:
         if chained > self._chained_seen:
             self.engine_chained.inc(chained - self._chained_seen)
             self._chained_seen = chained
+        for kind, seen in self._uploads_seen.items():
+            uploads = step_stats.get(kind + "_uploads", 0)
+            if uploads > seen:
+                self.engine_uploads.labels(dispatch=kind).inc(uploads - seen)
+                self._uploads_seen[kind] = uploads
         if lag_s is not None:
             self.engine_loop_lag.set(lag_s)
 

@@ -432,11 +432,13 @@ def _run_program(name: str, params, block_length: int):
     if name == "prefill":
         return logits, k_pages
     toks, k_pages, _ = llama.decode_steps(
-        params, cfg, np.asarray([7], np.int32), np.asarray([12], np.int32),
-        k_pages, v_pages, np.asarray([[1, 2, 3, 4]], np.int32),
-        np.asarray([13], np.int32), np.zeros((1,), np.float32),
-        np.zeros((1,), np.int32), np.ones((1,), np.float32),
-        jax.random.PRNGKey(0), page_size=PS, num_steps=3, interpret=True)
+        params, cfg, np.asarray([7], np.int32),
+        llama.pack_decode_inputs(
+            np.asarray([12]), np.asarray([[1, 2, 3, 4]]), np.asarray([13]),
+            np.zeros((1,), np.float32), np.zeros((1,), np.int32),
+            np.ones((1,), np.float32)),
+        k_pages, v_pages, jax.random.PRNGKey(0), page_size=PS, num_steps=3,
+        interpret=True)
     return toks, k_pages
 
 

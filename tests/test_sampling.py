@@ -338,13 +338,15 @@ def _decode_args(sampled_lanes, num_steps, seed=0):
     args = (
         params, cfg,
         jnp.asarray([3, 5, 7, 11], jnp.int32),  # tokens
-        jnp.asarray([2, 3, 4, 5], jnp.int32),  # positions
+        jnp.asarray(llama.pack_decode_inputs(
+            np.asarray([2, 3, 4, 5]),  # positions
+            block_tables,
+            np.asarray([3, 4, 5, 6]),  # seq_lens
+            temperature,
+            np.zeros((DECODE_LANES,), np.int32),
+            np.ones((DECODE_LANES,), np.float32),
+        )),
         k_pages, v_pages,
-        jnp.asarray(block_tables, jnp.int32),
-        jnp.asarray([3, 4, 5, 6], jnp.int32),  # seq_lens
-        jnp.asarray(temperature),
-        jnp.zeros((DECODE_LANES,), jnp.int32),
-        jnp.ones((DECODE_LANES,), jnp.float32),
         jax.random.PRNGKey(seed),
     )
     return args, dict(page_size=DECODE_PAGE, num_steps=num_steps, interpret=True)

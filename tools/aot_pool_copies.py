@@ -227,11 +227,9 @@ def served_program(config: str, program: str, one_chip):
         "state_pages": S(state.shape, state.dtype)}
     key = S((2,), jnp.uint32)
     if program == "decode_steps" and cfg.block_length == 0:
-        args = (
-            params, cfg, S((lanes,), i32), S((lanes,), i32), pool, second,
-            S((lanes, table_w), i32), S((lanes,), i32), S((lanes,), f32),
-            S((lanes,), i32), S((lanes,), f32), key,
-        )
+        # ids, then ``llama.pack_decode_inputs``' one array
+        packed = S((lanes, table_w + llama.DECODE_PACKED_TAIL), i32)
+        args = (params, cfg, S((lanes,), i32), packed, pool, second, key)
         kwargs = dict(page_size=page, num_steps=1, interpret=False, mesh=None,
                       **stateful)
         return llama.decode_steps, args, kwargs, pool_shape
@@ -244,15 +242,12 @@ def served_program(config: str, program: str, one_chip):
         )
         return llama.denoise_steps, args, kwargs, pool_shape
     if program == "prefill":
-        rows = (PREFILL_ROWS, PREFILL_CHUNK)
-        args = (
-            params, cfg, S(rows, i32), S(rows, i32), S(rows, jnp.bool_), pool,
-            second, S(rows, i32), S(rows, i32),
-            S((PREFILL_ROWS, engine["prefill_ctx_bucket"]), i32),
-            S((PREFILL_ROWS,), i32),
-        )
-        kwargs = dict(mesh=None, attn_impl="pallas", interpret=False, **stateful)
-        return llama.prefill, args, kwargs, pool_shape
+        # as the engine dispatches it: ``llama.pack_prefill_inputs``' one array
+        width = 5 * PREFILL_CHUNK + engine["prefill_ctx_bucket"] + 1
+        args = (params, cfg, S((PREFILL_ROWS, width), i32), pool, second)
+        kwargs = dict(chunk=PREFILL_CHUNK, mesh=None, attn_impl="pallas",
+                      interpret=False, **stateful)
+        return llama.prefill_packed, args, kwargs, pool_shape
     return None
 
 
