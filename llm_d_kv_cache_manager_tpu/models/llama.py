@@ -73,6 +73,38 @@ from ..ops.sampling import (
 from .quant import QuantizedTensor, materialize as _w
 
 
+#: The parts of a forward, as the device's timeline names them: every
+#: operation the model traces lies under ``jax.named_scope("model.<part>")``
+#: for one of these (``_scope``), in every program (``prefill`` /
+#: ``prefill_packed``, ``decode_step(s)``, ``spec_decode_steps``,
+#: ``denoise_step(s)``), because the scopes stand in the helpers and the two
+#: layer loops the programs share. A scope is trace-time metadata (the
+#: ``op_name`` of the HLO instruction, which the profiler carries as the
+#: ``tf_op`` of the device event's metadata): no operation, always on.
+#: ``attn`` / ``conv``: a layer's operator from its norm to its output
+#: projection and residual; ``ffn``: ``mlp_norm`` and the dense SwiGLU;
+#: ``moe_router``: a routed layer's ``mlp_norm``, ``_moe_gates`` and the
+#: sort / permutation of rows by expert; ``moe_experts``: the grouped
+#: matmuls, the gate weighting and the un-permutation; ``moe_shared``: the
+#: shared experts; ``cache_write``: the all-layer scatters into the pools;
+#: ``head``: the final norm and the logits; ``sample``:
+#: ``ops/sampling.py``'s entry points. The Pallas kernels keep their own
+#: names inside ``attn`` / ``moe_experts``. What lies under none (the
+#: embedding gather, a burst's bookkeeping) a reader calls ``unscoped``.
+#: Readers and documents quote this tuple, as they do ``server/engine.py``'s
+#: ``STEP_PHASES`` for the host's side.
+MODEL_SCOPES = (
+    "attn", "conv", "ffn", "moe_router", "moe_experts", "moe_shared",
+    "cache_write", "head", "sample",
+)
+
+
+def _scope(part: str):
+    """``jax.named_scope("model.<part>")`` for one of ``MODEL_SCOPES``: a
+    context manager, and a decorator for a helper that is one part."""
+    return jax.named_scope("model." + part)
+
+
 def _paged_attention_tp(
     q, kp, vp, block_tables, seq_lens, fresh_k, fresh_v, *, interpret, mesh,
     layer: int = 0, k_scale=None, v_scale=None, scale=None,
@@ -1140,6 +1172,7 @@ def _conv_state_plan(start, n_valid, page_ids, page_size: int):
     return last, jnp.take_along_axis(page_ids, last, axis=1), ok
 
 
+@_scope("conv")
 def _conv_prev_state(state_pages, cfg: LlamaConfig, prev_page, has_prev):
     """Every convolution layer's state before a chunk's first token: the
     slot of the page holding the token before it (``prev_page [b]``), zeros
@@ -1158,6 +1191,7 @@ def _conv_prev_state(state_pages, cfg: LlamaConfig, prev_page, has_prev):
     return got.reshape(*got.shape[:2], cfg.conv_L_cache - 1, cfg.hidden_size)
 
 
+@_scope("conv")
 def _conv_operator(layer: Params, cfg: LlamaConfig, x, state):
     """The gated short convolution over a chunk ``x [b, s, d]`` that follows
     ``state [b, K - 1, d]`` (the newest rows of ``z`` before it, oldest
@@ -1178,6 +1212,7 @@ def _conv_operator(layer: Params, cfg: LlamaConfig, x, state):
     return y @ _w(layer["conv_out"], x.dtype), z
 
 
+@_scope("cache_write")
 def _scatter_state_pages(state_pages, fresh, page, ok):
     """Write every convolution layer's new slots with one update (aliased
     into the donated pool, as ``_scatter_kv_pages_all_layers``): ``fresh
@@ -1198,6 +1233,7 @@ def _scatter_state_pages(state_pages, fresh, page, ok):
     return flat.reshape(state_pages.shape)
 
 
+@_scope("moe_router")
 def _moe_gates(layer: Params, cfg: LlamaConfig, x: jnp.ndarray):
     """Top-k routing shared by both dispatch strategies.
 
@@ -1246,19 +1282,26 @@ def _moe_mlp_dense(layer: Params, cfg: LlamaConfig, x: jnp.ndarray) -> jnp.ndarr
     what the routed dispatch below avoids.
     """
     topv, topi = _moe_gates(layer, cfg, x)  # [b, s, k]
-    # Scatter the renormalized top-k gates back to a dense [b, s, E] mask.
-    gates = jnp.sum(
-        jax.nn.one_hot(topi, cfg.n_experts, dtype=jnp.float32) * topv[..., None],
-        axis=-2,
-    )
-    gate = cfg.act_fn(
-        jnp.einsum("bsd,edf->ebsf", x, _w(layer["w_gate"], x.dtype)).astype(jnp.float32)
-    )
-    up = jnp.einsum("bsd,edf->ebsf", x, _w(layer["w_up"], x.dtype)).astype(jnp.float32)
-    act = (gate * up).astype(x.dtype)
-    return jnp.einsum(
-        "ebsf,efd,bse->bsd", act, _w(layer["w_down"], x.dtype), gates.astype(x.dtype)
-    )
+    with _scope("moe_experts"):
+        # Scatter the renormalized top-k gates back to a dense [b, s, E] mask.
+        gates = jnp.sum(
+            jax.nn.one_hot(topi, cfg.n_experts, dtype=jnp.float32)
+            * topv[..., None],
+            axis=-2,
+        )
+        gate = cfg.act_fn(
+            jnp.einsum(
+                "bsd,edf->ebsf", x, _w(layer["w_gate"], x.dtype)
+            ).astype(jnp.float32)
+        )
+        up = jnp.einsum(
+            "bsd,edf->ebsf", x, _w(layer["w_up"], x.dtype)
+        ).astype(jnp.float32)
+        act = (gate * up).astype(x.dtype)
+        return jnp.einsum(
+            "ebsf,efd,bse->bsd", act, _w(layer["w_down"], x.dtype),
+            gates.astype(x.dtype),
+        )
 
 
 def _grouped_dot(cfg: LlamaConfig, row_group_ids: jnp.ndarray, interpret: bool):
@@ -1322,24 +1365,29 @@ def _moe_mlp_routed(
     xf = x.reshape(n, d)
     topv, topi = _moe_gates(layer, cfg, xf)  # [n, k]
 
-    expert_ids = topi.reshape(-1)  # [n*k]
-    token_ids = jnp.arange(n * k, dtype=jnp.int32) // k
-    order = jnp.argsort(expert_ids, stable=True)
-    src_tok = token_ids[order]  # [n*k] token each sorted row came from
-    xs = xf[src_tok]  # [n*k, d] gathered inputs, expert-contiguous
-    group_sizes = jnp.bincount(expert_ids, length=cfg.n_experts)
-    if touched is not None:
-        touched.append(jnp.sum(group_sizes > 0, dtype=jnp.int32))
-    gdot = _grouped_dot(cfg, expert_ids[order], interpret)
+    with _scope("moe_router"):
+        expert_ids = topi.reshape(-1)  # [n*k]
+        token_ids = jnp.arange(n * k, dtype=jnp.int32) // k
+        order = jnp.argsort(expert_ids, stable=True)
+        src_tok = token_ids[order]  # [n*k] token each sorted row came from
+        xs = xf[src_tok]  # [n*k, d] gathered inputs, expert-contiguous
+        group_sizes = jnp.bincount(expert_ids, length=cfg.n_experts)
+        if touched is not None:
+            touched.append(jnp.sum(group_sizes > 0, dtype=jnp.int32))
+        sorted_ids = expert_ids[order]
 
-    gate = cfg.act_fn(gdot(xs, layer["w_gate"], group_sizes).astype(jnp.float32))
-    up = gdot(xs, layer["w_up"], group_sizes).astype(jnp.float32)
-    act = (gate * up).astype(x.dtype)
-    out = gdot(act, layer["w_down"], group_sizes)  # [n*k, d]
+    with _scope("moe_experts"):
+        gdot = _grouped_dot(cfg, sorted_ids, interpret)
+        gate = cfg.act_fn(
+            gdot(xs, layer["w_gate"], group_sizes).astype(jnp.float32)
+        )
+        up = gdot(xs, layer["w_up"], group_sizes).astype(jnp.float32)
+        act = (gate * up).astype(x.dtype)
+        out = gdot(act, layer["w_down"], group_sizes)  # [n*k, d]
 
-    out = out.astype(jnp.float32) * topv.reshape(-1)[order][:, None]
-    combined = jnp.zeros((n, d), jnp.float32).at[src_tok].add(out)
-    return combined.reshape(b, s, d).astype(x.dtype)
+        out = out.astype(jnp.float32) * topv.reshape(-1)[order][:, None]
+        combined = jnp.zeros((n, d), jnp.float32).at[src_tok].add(out)
+        return combined.reshape(b, s, d).astype(x.dtype)
 
 
 def _moe_mlp_routed_ep(
@@ -1383,30 +1431,36 @@ def _moe_mlp_routed_ep(
         # the router is replicated), then keep only this shard's experts.
         topv, topi = _moe_gates({"router": router}, cfg, xf)
         lo = ep * e_local
-        local = (topi >= lo) & (topi < lo + e_local)  # [n, k]
-        gate_w = jnp.where(local, topv, 0.0)
-        local_expert = jnp.clip(topi - lo, 0, e_local - 1)
+        with _scope("moe_router"):
+            local = (topi >= lo) & (topi < lo + e_local)  # [n, k]
+            gate_w = jnp.where(local, topv, 0.0)
+            local_expert = jnp.clip(topi - lo, 0, e_local - 1)
 
-        expert_ids = local_expert.reshape(-1)  # [n*k]
-        token_ids = jnp.arange(n * k, dtype=jnp.int32) // k
-        order = jnp.argsort(expert_ids, stable=True)
-        src_tok = token_ids[order]
-        xg = xf[src_tok]  # [n*k, d] expert-contiguous
-        group_sizes = jnp.bincount(expert_ids, length=e_local)
-        # QuantizedTensor expert shards flow into the gmm kernel as-is
-        # (specs are pytree prefixes, so q and scale both shard on E);
-        # the kernel dequantizes per-tile in VMEM.
-        gdot = _grouped_dot(cfg, expert_ids[order], interpret)
+        with _scope("moe_router"):
+            expert_ids = local_expert.reshape(-1)  # [n*k]
+            token_ids = jnp.arange(n * k, dtype=jnp.int32) // k
+            order = jnp.argsort(expert_ids, stable=True)
+            src_tok = token_ids[order]
+            xg = xf[src_tok]  # [n*k, d] expert-contiguous
+            group_sizes = jnp.bincount(expert_ids, length=e_local)
+            sorted_ids = expert_ids[order]
+        with _scope("moe_experts"):
+            # QuantizedTensor expert shards flow into the gmm kernel as-is
+            # (specs are pytree prefixes, so q and scale both shard on E);
+            # the kernel dequantizes per-tile in VMEM.
+            gdot = _grouped_dot(cfg, sorted_ids, interpret)
 
-        gate = cfg.act_fn(gdot(xg, w_gate, group_sizes).astype(jnp.float32))
-        up = gdot(xg, w_up, group_sizes).astype(jnp.float32)
-        act = (gate * up).astype(xs.dtype)
-        out = gdot(act, w_down, group_sizes)  # [n*k, d]
+            gate = cfg.act_fn(
+                gdot(xg, w_gate, group_sizes).astype(jnp.float32)
+            )
+            up = gdot(xg, w_up, group_sizes).astype(jnp.float32)
+            act = (gate * up).astype(xs.dtype)
+            out = gdot(act, w_down, group_sizes)  # [n*k, d]
 
-        out = out.astype(jnp.float32) * gate_w.reshape(-1)[order][:, None]
-        combined = jnp.zeros((n, d), jnp.float32).at[src_tok].add(out)
-        combined = jax.lax.psum(combined, "tp")
-        return combined.reshape(b, s, d).astype(xs.dtype)
+            out = out.astype(jnp.float32) * gate_w.reshape(-1)[order][:, None]
+            combined = jnp.zeros((n, d), jnp.float32).at[src_tok].add(out)
+            combined = jax.lax.psum(combined, "tp")
+            return combined.reshape(b, s, d).astype(xs.dtype)
 
     fn = jax.shard_map(
         body,
@@ -1466,11 +1520,31 @@ def _mlp(
         if "ws_gate" in layer:
             # the shared experts: one SwiGLU every token takes, a plain
             # matmul beside the grouped ones
-            out = out + _swiglu(
-                cfg, x, layer["ws_gate"], layer["ws_up"], layer["ws_down"]
-            )
+            with _scope("moe_shared"):
+                out = out + _swiglu(
+                    cfg, x, layer["ws_gate"], layer["ws_up"], layer["ws_down"]
+                )
         return out
-    return _swiglu(cfg, x, layer["w_gate"], layer["w_up"], layer["w_down"])
+    with _scope("ffn"):
+        return _swiglu(cfg, x, layer["w_gate"], layer["w_up"], layer["w_down"])
+
+
+def _ffn(
+    layer: Params, cfg: LlamaConfig, h: jnp.ndarray, mesh=None,
+    interpret: bool = False, touched: Optional[list] = None,
+) -> jnp.ndarray:
+    """A layer's second half as every body runs it: ``h + _mlp(mlp_norm(h))``.
+    The norm lies under the scope of what reads it (``model.moe_router``
+    for a routed layer, else ``model.ffn``), the residual under that of what
+    it adds (``model.moe_experts``, else ``model.ffn``)."""
+    routed = "router" in layer
+    with _scope("moe_router" if routed else "ffn"):
+        x = rms_norm(h, layer["mlp_norm"], cfg.rms_norm_eps, cfg.norm_offset)
+    out = _mlp(
+        layer, cfg, x, mesh=mesh, interpret=interpret, touched=touched
+    )
+    with _scope("moe_experts" if routed else "ffn"):
+        return h + out
 
 
 def _swiglu(cfg: LlamaConfig, x, w_gate, w_up, w_down) -> jnp.ndarray:
@@ -1491,6 +1565,7 @@ def _embed(params: Params, cfg: LlamaConfig, tokens: jnp.ndarray) -> jnp.ndarray
     return h
 
 
+@_scope("head")
 def _logits(params: Params, cfg: LlamaConfig, h: jnp.ndarray) -> jnp.ndarray:
     h = rms_norm(h, params["final_norm"], cfg.rms_norm_eps, cfg.norm_offset)
     head = (
@@ -1501,6 +1576,7 @@ def _logits(params: Params, cfg: LlamaConfig, h: jnp.ndarray) -> jnp.ndarray:
     return (h @ head).astype(jnp.float32)
 
 
+@_scope("cache_write")
 def _scatter_kv_pages_all_layers(
     pages: jnp.ndarray,  # [n_layers, total_pages, page_size, n_kv, hd]
     fresh: jnp.ndarray,  # [n_layers, b, s, n_kv, hd] (or [n_layers, b*s, ...])
@@ -1544,6 +1620,7 @@ def _scatter_kv_pages_all_layers(
     return flat.reshape(pages.shape)
 
 
+@_scope("cache_write")
 def _quantized_scatter_kv_all_layers(
     pages_q: jnp.ndarray,  # [n_layers, total_pages, page_size, n_kv, hd] int8
     scales: jnp.ndarray,  # [n_layers, total_pages, n_kv] f32
@@ -1703,68 +1780,67 @@ def _prefill_body(
     fresh_state = []  # per conv layer [b, pages touched, state row]
     for layer in params["layers"]:
         li = len(fresh_k)  # the layer's index in the key/value pools
-        x = rms_norm(h, layer["attn_norm"], cfg.rms_norm_eps, cfg.norm_offset)
-        if "conv_in" in layer:
-            out, z = _conv_operator(layer, cfg, x, states[len(fresh_state)])
-            fresh_state.append(
-                z[jnp.arange(z.shape[0])[:, None, None], state_rows].reshape(
-                    *state_rows.shape[:2], -1
+        with _scope("conv" if "conv_in" in layer else "attn"):
+            x = rms_norm(h, layer["attn_norm"], cfg.rms_norm_eps, cfg.norm_offset)
+            if "conv_in" in layer:
+                out, z = _conv_operator(layer, cfg, x, states[len(fresh_state)])
+                fresh_state.append(
+                    z[jnp.arange(z.shape[0])[:, None, None], state_rows].reshape(
+                        *state_rows.shape[:2], -1
+                    )
                 )
-            )
-        elif latent:
-            # One row a token, absorbed: the kernel over the pool in place
-            # or (``xla``) its oracle over gathered pages; the rows go to
-            # the pool after the loop, through the same flat-row scatter.
-            q_n, q_r, k = _mla_project(layer, cfg, x, positions, inv_freq)
-            v = None
-            attn = _mla_absorbed(
-                layer, cfg, q_n, q_r, k, k_pages, block_tables, ctx_lens,
-                n_valid, layer_index=li, interpret=interpret,
-                kernel=attn_impl == "pallas",
-            )
-        else:
-            q, k, v = _qkv(layer, cfg, x)
-            q = apply_rope(q, positions, inv_freq)
-            k = apply_rope(k, positions, inv_freq)
-
-            if sp > 1:
-                # Sequence-parallel chunk: ring attention over the sp axis,
-                # merged exactly with the paged context (see
-                # _sp_prefill_attention). Takes precedence over attn_impl —
-                # the ring is the sharded equivalent of the xla flash scan.
-                attn = _sp_prefill_attention(
-                    q, k, v, k_pages[li], v_pages[li], block_tables, ctx_lens,
-                    positions, valid, mesh,
+            elif latent:
+                # One row a token, absorbed: the kernel over the pool in place
+                # or (``xla``) its oracle over gathered pages; the rows go to
+                # the pool after the loop, through the same flat-row scatter.
+                q_n, q_r, k = _mla_project(layer, cfg, x, positions, inv_freq)
+                v = None
+                attn = _mla_absorbed(
+                    layer, cfg, q_n, q_r, k, k_pages, block_tables, ctx_lens,
+                    n_valid, layer_index=li, interpret=interpret,
+                    kernel=attn_impl == "pallas",
                 )
-            elif attn_impl == "pallas":
-                # Flash kernel (ops/flash_prefill.py), which reads the whole
-                # pools' pages where they lie. Engine contract: consecutive
-                # chunk positions, right-padded valid mask.
-                q, k, v = _pack_heads(cfg, q, k, v)
-                attn = _unpack_heads(cfg, _flash_prefill_tp(
-                    q, k, v, k_pages, v_pages, block_tables, ctx_lens,
-                    n_valid, layer=li, interpret=interpret, mesh=mesh,
-                    block_length=cfg.block_length, scale=head_scale,
-                ))
             else:
-                attn = prefill_with_paged_context(
-                    q, k, v, _head_pool(cfg, k_pages[li]),
-                    _head_pool(cfg, v_pages[li]), block_tables, ctx_lens,
-                    positions=positions, valid=valid,
-                    k_scales=None if k_scales is None else k_scales[li],
-                    v_scales=None if v_scales is None else v_scales[li],
-                    block_length=cfg.block_length,
-                )
-        if "conv_in" not in layer:
-            b, s, _, _ = attn.shape
-            out = attn.reshape(b, s, -1) @ _w(layer["wo"], h.dtype)
-            fresh_k.append(k)
-            fresh_v.append(v)
-        h = h + out
+                q, k, v = _qkv(layer, cfg, x)
+                q = apply_rope(q, positions, inv_freq)
+                k = apply_rope(k, positions, inv_freq)
 
-        x = rms_norm(h, layer["mlp_norm"], cfg.rms_norm_eps, cfg.norm_offset)
-        h = h + _mlp(
-            layer, cfg, x, mesh=mesh, interpret=interpret,
+                if sp > 1:
+                    # Sequence-parallel chunk: ring attention over the sp axis,
+                    # merged exactly with the paged context (see
+                    # _sp_prefill_attention). Takes precedence over attn_impl —
+                    # the ring is the sharded equivalent of the xla flash scan.
+                    attn = _sp_prefill_attention(
+                        q, k, v, k_pages[li], v_pages[li], block_tables, ctx_lens,
+                        positions, valid, mesh,
+                    )
+                elif attn_impl == "pallas":
+                    # Flash kernel (ops/flash_prefill.py), which reads the whole
+                    # pools' pages where they lie. Engine contract: consecutive
+                    # chunk positions, right-padded valid mask.
+                    q, k, v = _pack_heads(cfg, q, k, v)
+                    attn = _unpack_heads(cfg, _flash_prefill_tp(
+                        q, k, v, k_pages, v_pages, block_tables, ctx_lens,
+                        n_valid, layer=li, interpret=interpret, mesh=mesh,
+                        block_length=cfg.block_length, scale=head_scale,
+                    ))
+                else:
+                    attn = prefill_with_paged_context(
+                        q, k, v, _head_pool(cfg, k_pages[li]),
+                        _head_pool(cfg, v_pages[li]), block_tables, ctx_lens,
+                        positions=positions, valid=valid,
+                        k_scales=None if k_scales is None else k_scales[li],
+                        v_scales=None if v_scales is None else v_scales[li],
+                        block_length=cfg.block_length,
+                    )
+            if "conv_in" not in layer:
+                b, s, _, _ = attn.shape
+                out = attn.reshape(b, s, -1) @ _w(layer["wo"], h.dtype)
+                fresh_k.append(k)
+                fresh_v.append(v)
+            h = h + out
+        h = _ffn(
+            layer, cfg, h, mesh=mesh, interpret=interpret,
             touched=experts_touched,
         )
 
@@ -1964,6 +2040,7 @@ def _decode_body(
     k_scales=None,  # [L, P, n_kv] f32 when KV_QUANT_HBM=int8
     v_scales=None,
     state_pages=None,  # ``init_state_pages``: a model with conv layers
+    experts_touched: Optional[list] = None,  # see ``_moe_mlp_routed``
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, Any, Any, Any]:
     """Single decode step (traced body shared by ``decode_step`` and the
     fused ``decode_steps`` scan). Writes this token's K/V into its page
@@ -2013,60 +2090,62 @@ def _decode_body(
     fresh_state = []  # per conv layer [b, 1, state row]
     for layer in params["layers"]:
         li = len(fresh_k)  # the layer's index in the key/value pools
-        x = rms_norm(h, layer["attn_norm"], cfg.rms_norm_eps, cfg.norm_offset)
-        if "conv_in" in layer:
-            out, z = _conv_operator(layer, cfg, x, states[len(fresh_state)])
-            fresh_state.append(z[:, 1:].reshape(b, 1, -1))
-        elif latent:
-            # Absorbed decode (kernel ``mla_decode``): every head reads the
-            # lane's latent rows where they lie, once, as key and as value;
-            # the token's own row rides as an argument and is written after
-            # the loop like every other model's.
-            q_n, q_r, k = _mla_project(
-                layer, cfg, x, positions[:, None], inv_freq
-            )
-            v = None
-            attn = _mla_absorbed(
-                layer, cfg, q_n, q_r, k, k_pages, block_tables,
-                jnp.maximum(seq_lens - 1, 0), (seq_lens > 0).astype(jnp.int32),
-                layer_index=li, interpret=interpret,
-            )  # [b, 1, H, d_v]
-        else:
-            q, k, v = _qkv(layer, cfg, x)
-            q = apply_rope(q, positions[:, None], inv_freq)
-            k = apply_rope(k, positions[:, None], inv_freq)
-            q, k, v = _pack_heads(cfg, q, k, v)
+        with _scope("conv" if "conv_in" in layer else "attn"):
+            x = rms_norm(h, layer["attn_norm"], cfg.rms_norm_eps, cfg.norm_offset)
+            if "conv_in" in layer:
+                out, z = _conv_operator(layer, cfg, x, states[len(fresh_state)])
+                fresh_state.append(z[:, 1:].reshape(b, 1, -1))
+            elif latent:
+                # Absorbed decode (kernel ``mla_decode``): every head reads the
+                # lane's latent rows where they lie, once, as key and as value;
+                # the token's own row rides as an argument and is written after
+                # the loop like every other model's.
+                q_n, q_r, k = _mla_project(
+                    layer, cfg, x, positions[:, None], inv_freq
+                )
+                v = None
+                attn = _mla_absorbed(
+                    layer, cfg, q_n, q_r, k, k_pages, block_tables,
+                    jnp.maximum(seq_lens - 1, 0), (seq_lens > 0).astype(jnp.int32),
+                    layer_index=li, interpret=interpret,
+                )  # [b, 1, H, d_v]
+            else:
+                q, k, v = _qkv(layer, cfg, x)
+                q = apply_rope(q, positions[:, None], inv_freq)
+                k = apply_rope(k, positions[:, None], inv_freq)
+                q, k, v = _pack_heads(cfg, q, k, v)
 
-            # The kernel takes the current token's K/V as arguments (pages hold
-            # only history), so the pool write happens ONCE for all layers after
-            # the loop — a single aliased scatter instead of a per-layer pool
-            # rebuild (which cost 2×pool bytes of HBM traffic per token). The
-            # kernel reads the pool in the default layout; that the write does
-            # too (it did not until PR 29: see _scatter_kv_pages_all_layers) is
-            # what tests/test_pool_layout.py holds on the compiled program.
-            attn = _unpack_heads(cfg, _paged_attention_tp(
-                q[:, 0],  # [b, n_heads, hd]
-                k_pages,  # FULL [L, P, ps, n_kv, hd] pool; layer via index map
-                v_pages,
-                block_tables,
-                seq_lens,
-                k[:, 0],  # [b, n_kv, hd]
-                v[:, 0],
-                interpret=interpret,
-                mesh=mesh,
-                layer=li,
-                k_scale=k_scales,
-                v_scale=v_scales,
-                scale=head_scale,
-            ))  # [b, n_heads, hd]
-        if "conv_in" not in layer:
-            out = (attn.reshape(b, -1) @ _w(layer["wo"], h.dtype))[:, None, :]
-            fresh_k.append(k)
-            fresh_v.append(v)
-        h = h + out
-
-        x = rms_norm(h, layer["mlp_norm"], cfg.rms_norm_eps, cfg.norm_offset)
-        h = h + _mlp(layer, cfg, x, mesh=mesh, interpret=interpret)
+                # The kernel takes the current token's K/V as arguments (pages hold
+                # only history), so the pool write happens ONCE for all layers after
+                # the loop — a single aliased scatter instead of a per-layer pool
+                # rebuild (which cost 2×pool bytes of HBM traffic per token). The
+                # kernel reads the pool in the default layout; that the write does
+                # too (it did not until PR 29: see _scatter_kv_pages_all_layers) is
+                # what tests/test_pool_layout.py holds on the compiled program.
+                attn = _unpack_heads(cfg, _paged_attention_tp(
+                    q[:, 0],  # [b, n_heads, hd]
+                    k_pages,  # FULL [L, P, ps, n_kv, hd] pool; layer via index map
+                    v_pages,
+                    block_tables,
+                    seq_lens,
+                    k[:, 0],  # [b, n_kv, hd]
+                    v[:, 0],
+                    interpret=interpret,
+                    mesh=mesh,
+                    layer=li,
+                    k_scale=k_scales,
+                    v_scale=v_scales,
+                    scale=head_scale,
+                ))  # [b, n_heads, hd]
+            if "conv_in" not in layer:
+                out = (attn.reshape(b, -1) @ _w(layer["wo"], h.dtype))[:, None, :]
+                fresh_k.append(k)
+                fresh_v.append(v)
+            h = h + out
+        h = _ffn(
+            layer, cfg, h, mesh=mesh, interpret=interpret,
+            touched=experts_touched,
+        )
 
     if fresh_state:
         state_pages = _scatter_state_pages(
@@ -2196,6 +2275,7 @@ def decode_steps(
     cfg: LlamaConfig,
     tokens: jnp.ndarray,  # [b] int32 — last sampled token per sequence,
     # or [b, n]: a burst's own output, whose last column is taken here
+    # (the count in its first column is never an id: ``n`` >= 2)
     packed: jnp.ndarray,  # [b, max_pages + 5] int32: ``pack_decode_inputs``
     k_pages: jnp.ndarray,
     v_pages: jnp.ndarray,
@@ -2215,15 +2295,21 @@ def decode_steps(
     bodies, sampling each next token on-device, so the host syncs once per
     ``num_steps`` tokens instead of once per token. This is the TPU-native
     answer to per-dispatch host latency (the reference never runs a model;
-    its vLLM pods solve this on the GPU side). Returns (sampled tokens
-    [b, num_steps] int32, k_pages, v_pages), then the scale pools where
-    the pools are int8, then ``state_pages`` where one was given (carried
-    through the scan as the pools are). The caller must pre-extend
-    ``block_tables`` to cover ``num_steps`` of growth; lanes that finish
-    early keep decoding into their reserved pages and the host discards the
-    surplus tokens. ``tokens`` may be the ``[b, n]`` ids a burst returned:
-    the next burst then starts from them on the device, with no program
-    between the two and none beside this one.
+    its vLLM pods solve this on the GPU side). Returns (``burst [b, 1 +
+    num_steps]`` int32, k_pages, v_pages), then the scale pools where the
+    pools are int8, then ``state_pages`` where one was given (carried
+    through the scan as the pools are). ``burst[:, 1:]`` are the sampled
+    tokens; ``burst[:, 0]`` is, in every lane, the number of distinct
+    experts the burst's rows chose, summed over the routed layers and the
+    steps (what ``_moe_mlp_routed`` counts on the device: the experts whose
+    weights the grouped matmuls read, padded lanes' rows included; 0 for a
+    model or a dispatch strategy without that count) — one array, so the
+    burst still costs one fetch, as ``denoise_steps`` packs its own. The
+    caller must pre-extend ``block_tables`` to cover ``num_steps`` of
+    growth; lanes that finish early keep decoding into their reserved pages
+    and the host discards the surplus tokens. ``tokens`` may be the ``burst``
+    a dispatch returned: the next one then starts from its last column on
+    the device, with no program between the two and none beside this one.
 
     Every other per-lane input arrives as ONE array (``pack_decode_inputs``:
     one upload a dispatch, not six) and is sliced apart here, inside the
@@ -2245,10 +2331,11 @@ def decode_steps(
 
     def body(carry, key):
         tokens, positions, seq_lens, k_pages, v_pages, k_sc, v_sc, st = carry
+        touched = []
         logits, k_pages, v_pages, k_sc, v_sc, st = _decode_body(
             params, cfg, tokens, positions, k_pages, v_pages,
             block_tables, seq_lens, page_size, interpret, mesh,
-            k_sc, v_sc, st,
+            k_sc, v_sc, st, experts_touched=touched,
         )
         nxt = sample_tokens(
             logits.astype(jnp.float32), temperature, top_k, top_p, key,
@@ -2256,7 +2343,7 @@ def decode_steps(
         )
         return (
             nxt, positions + 1, seq_lens + 1, k_pages, v_pages, k_sc, v_sc, st
-        ), nxt
+        ), (nxt, sum(touched, jnp.zeros((), jnp.int32)))
 
     # None scales (and a None state pool) are valid (empty) scan-carry
     # leaves, so the knob-off trace is unchanged apart from the tuple arity.
@@ -2270,19 +2357,22 @@ def decode_steps(
         # scan machinery for a plain body call. Consumes keys[0] exactly
         # like the scan's first slice, so sampled streams are
         # bit-identical across paths.
-        (_, _, _, k_pages, v_pages, k_scales, v_scales, state_pages), nxt = (
-            body(carry0, keys[0])
-        )
+        (
+            _, _, _, k_pages, v_pages, k_scales, v_scales, state_pages
+        ), (nxt, n_touched) = body(carry0, keys[0])
         toks = nxt[:, None]
     else:
         (
             _, _, _, k_pages, v_pages, k_scales, v_scales, state_pages
-        ), toks = jax.lax.scan(body, carry0, keys)
-        toks = toks.T
+        ), (toks, n_touched) = jax.lax.scan(body, carry0, keys)
+        toks, n_touched = toks.T, jnp.sum(n_touched)
+    burst = jnp.concatenate(
+        [jnp.broadcast_to(n_touched, (toks.shape[0], 1)), toks], axis=1
+    )
     extra = (k_scales, v_scales) if quantized else ()
     if stateful:
         extra += (state_pages,)
-    return (toks, k_pages, v_pages) + extra
+    return (burst, k_pages, v_pages) + extra
 
 
 @functools.partial(

@@ -36,6 +36,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+#: the name of this file's operations on the device's timeline: the last of
+#: ``models/llama.py``'s ``MODEL_SCOPES`` (``jax.named_scope``: metadata of
+#: the traced operations, no operation of its own)
+_scope = jax.named_scope("model.sample")
+
 
 def pack_sampling_params(temperature, top_k, top_p) -> np.ndarray:
     """The lanes' sampling parameters as ONE host array, so that they cost
@@ -105,6 +110,7 @@ def _sample_greedy(logits, temperature, top_k, top_p, rng_key):
 
 
 @jax.jit
+@_scope
 def sample_tokens(
     logits: jnp.ndarray,  # [batch, vocab] f32
     temperature: jnp.ndarray,  # [batch] f32; 0 = greedy
@@ -128,6 +134,7 @@ def sample_tokens(
 
 
 @jax.jit
+@_scope
 def sample_tokens_packed(
     logits: jnp.ndarray,  # [batch, vocab], any float dtype
     packed: jnp.ndarray,  # [batch, 3] int32: ``pack_sampling_params``
@@ -183,6 +190,7 @@ def _spec_greedy(logits, drafts, temperature, top_k, top_p, rng_key):
 
 
 @jax.jit
+@_scope
 def spec_sample(
     logits: jnp.ndarray,  # [batch, s, vocab] f32 — verify logits per position
     drafts: jnp.ndarray,  # [batch, s] int32 — proposed token per position
@@ -242,6 +250,7 @@ def _block_filtered(logits, temperature, top_k, top_p, rng_key):
 
 
 @jax.jit
+@_scope
 def block_candidates(
     logits: jnp.ndarray,  # [batch, rows, vocab] f32 — a block's logits
     temperature: jnp.ndarray,  # [batch] f32; 0 = greedy
@@ -261,6 +270,7 @@ def block_candidates(
     )
 
 
+@_scope
 def block_transfer(
     prob: jnp.ndarray,  # [batch, rows] f32 — ``block_candidates``' second
     masked: jnp.ndarray,  # [batch, rows] bool — rows still to fix

@@ -667,6 +667,14 @@ class Engine:
         self.state_bytes_per_token = (
             0 if self.state_pages is None else self.state_pages.nbytes
         ) // (config.block_manager.total_pages * ps)
+        #: how many of the model's layers route their rows to experts, read
+        #: from the layers' own parameters as ``llama._mlp`` reads them:
+        #: ``step_stats["experts_touched"]`` sums over these, so ``/stats``
+        #: states the number beside ``kv_bytes_per_token`` and no reader
+        #: keeps a table of which layers are dense
+        self.routed_layers = sum(
+            "router" in layer for layer in self.params["layers"]
+        )
         # Scale pools ride alongside the int8 page pools (None when the
         # knob is off — every scale-threading call site keys off this).
         self.k_scales: Optional[jnp.ndarray] = None
@@ -876,14 +884,20 @@ class Engine:
         #: burst's tokens were fetched), ``decode_uploads`` /
         #: ``prefill_uploads`` (host arrays a decode / prefill dispatch
         #: staged on the device, ``_stage``: one or two a dispatch); prefill
-        #: dispatches are counted, always, in ``prefill_stats``. Block diffusion
-        #: (``_run_decode_block``, beside those three):
+        #: dispatches are counted, always, in ``prefill_stats``. Every decode
+        #: path: ``decode_forwards`` (forwards of the model the decode
+        #: dispatches ran: dispatches x the steps fused in each) and
+        #: ``experts_touched`` (distinct experts a forward's rows chose,
+        #: summed over the ``routed_layers`` and the forwards: counted on
+        #: the device in ``llama._moe_mlp_routed`` and fetched with the
+        #: tokens, first column of a fused burst (``_commit_burst``), last
+        #: of a block dispatch; 0 for a model without routed layers, and
+        #: the speculative path does not count it). Block diffusion
+        #: (``_run_decode_block``, beside those):
         #: ``denoise_lane_forwards`` (lanes x dispatches in which the lane
         #: had a masked row), ``commit_lane_forwards`` (in which it had none:
         #: the forward that stores the block's keys and values),
-        #: ``block_tokens_fixed`` (rows fixed), ``blocks_final``,
-        #: ``experts_touched`` (distinct experts a dispatch's rows chose,
-        #: summed over the layers and the dispatches: counted on the device).
+        #: ``block_tokens_fixed`` (rows fixed), ``blocks_final``.
         #: A latent pool: ``latent_ctx_tokens`` (the real lanes' context
         #: lengths, summed over the decode dispatches: the rows the
         #: ``mla_decode`` kernel must read, a layer). Every model:
@@ -898,6 +912,7 @@ class Engine:
             "decode_rows": 0,
             "decode_sampled_dispatches": 0,
             "decode_chained_dispatches": 0,
+            "decode_forwards": 0,
             "decode_uploads": 0,
             "prefill_uploads": 0,
             "denoise_lane_forwards": 0,
@@ -2530,9 +2545,9 @@ class Engine:
                 seq_lens = np.where(was_active, prev["seq_lens"] + k, 0)
             else:
                 # The program takes its input ids from the last column of a
-                # burst's [lanes, k] output; an unchained dispatch hands it
-                # the same shape, so both are one compiled program.
-                tokens = np.zeros((lanes, k), np.int32)
+                # burst's [lanes, 1 + k] output; an unchained dispatch hands
+                # it the same shape, so both are one compiled program.
+                tokens = np.zeros((lanes, 1 + k), np.int32)
                 for i, seq in enumerate(active):
                     tokens[i, -1] = seq.last_token
                     positions[i] = seq.num_tokens - 1
@@ -2800,7 +2815,7 @@ class Engine:
                     interpret=self.config.interpret,
                 )
             )
-        self._count_decode_dispatch(len(active), fparams[:, 0])
+        self._count_decode_dispatch(len(active), fparams[:, 0], steps=rounds)
         # The one host sync of the burst: ONE packed fetch (emit tokens +
         # per-round counters in a single array — separate fetches would
         # serialize several blocking round-trips on high-latency links).
@@ -2985,8 +3000,12 @@ class Engine:
         with self.phase("decode_fetch"):
             # The one host sync; its blocking share is near zero when the
             # fused fast path's async copy already landed the bytes.
-            toks = np.asarray(burst["toks"])  # [lanes, k]
+            # [lanes, 1 + k]: the experts the burst read, then its tokens
+            fetched = np.asarray(burst["toks"])
         with self.phase("decode_commit"):
+            toks = fetched[:, 1:]
+            if self.obs_step_timing:
+                self.step_stats["experts_touched"] += int(fetched[0, 0])
             for i, seq in enumerate(burst["active"]):
                 if not seq.block_table:
                     continue  # preempted after this burst was dispatched
@@ -3160,9 +3179,11 @@ class Engine:
         fused steps read a layer that attends (``attn_ctx_tokens``; for a
         latent pool also under ``latent_ctx_tokens``) (``seq_lens``: the
         host-side lengths of the dispatch, 0 for a lane that is not real;
-        a lane's context grows by one a step)."""
+        a lane's context grows by one a step). ``decode_forwards`` grows by
+        ``steps``: the forwards ``experts_touched`` is summed over."""
         if self.obs_step_timing:
             self.step_stats["decode_dispatches"] += 1
+            self.step_stats["decode_forwards"] += steps
             self.step_stats["decode_rows"] += rows
             self.step_stats["decode_sampled_dispatches"] += bool(
                 (temperature > 0).any()

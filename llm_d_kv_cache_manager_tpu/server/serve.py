@@ -337,9 +337,13 @@ class _ServingMetrics:
                 "Generation by diffusion over blocks (a model with "
                 "block_length > 0), by count: denoise_lane_forwards / "
                 "commit_lane_forwards (lanes x dispatches with / without a "
-                "masked row), block_tokens_fixed (rows fixed), blocks_final, "
-                "experts_touched (distinct experts a dispatch's rows chose, "
-                "summed over the layers)",
+                "masked row), block_tokens_fixed (rows fixed), blocks_final; "
+                "and, on every decode path that counts it (block diffusion "
+                "and the fused decode burst), experts_touched (distinct "
+                "experts a forward's rows chose, summed over the routed "
+                "layers and the forwards: over "
+                "kvcache_engine_decode_forwards_total and /stats' "
+                "routed_layers it is the experts a layer a forward read)",
                 ["count"], registry=self.registry,
             )
             self._block_seen = dict.fromkeys(
@@ -371,6 +375,13 @@ class _ServingMetrics:
                 registry=self.registry,
             )
             self._chained_seen = 0
+            self.engine_forwards = prom.Counter(
+                "kvcache_engine_decode_forwards_total",
+                "Forwards of the model the decode dispatches ran: "
+                "dispatches x the steps fused in each",
+                registry=self.registry,
+            )
+            self._forwards_seen = 0
             self.engine_uploads = prom.Counter(
                 "kvcache_engine_dispatch_uploads_total",
                 "Host arrays staged on the device for model dispatches, by "
@@ -617,6 +628,10 @@ class _ServingMetrics:
         if chained > self._chained_seen:
             self.engine_chained.inc(chained - self._chained_seen)
             self._chained_seen = chained
+        forwards = step_stats.get("decode_forwards", 0)
+        if forwards > self._forwards_seen:
+            self.engine_forwards.inc(forwards - self._forwards_seen)
+            self._forwards_seen = forwards
         for kind, seen in self._uploads_seen.items():
             uploads = step_stats.get(kind + "_uploads", 0)
             if uploads > seen:
@@ -3684,6 +3699,7 @@ class PodServer:
                 "total_pages": bm.config.total_pages,
                 "kv_bytes_per_token": self.engine.kv_bytes_per_token,
                 "state_bytes_per_token": self.engine.state_bytes_per_token,
+                "routed_layers": self.engine.routed_layers,
                 "prefill": dict(self.engine.prefill_stats),
                 "transfer": {
                     **self.engine.transfer_stats,

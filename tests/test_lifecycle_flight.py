@@ -683,7 +683,7 @@ class TestKnobsOffParity:
             assert set(stats) == {
                 "pod", "model", "data_parallel_rank", "staged", "waiting",
                 "running", "free_pages", "total_pages", "kv_bytes_per_token",
-                "state_bytes_per_token",
+                "state_bytes_per_token", "routed_layers",
                 "prefill",
                 "transfer", "self_heal", "admission", "drain",
             }

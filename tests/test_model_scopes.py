@@ -1,0 +1,133 @@
+"""The device's timeline names the model's parts (``llama.MODEL_SCOPES``).
+
+Every operation the model traces lies under ``jax.named_scope("model.<part>")``
+in every program, because the scopes stand in the helpers and the two layer
+loops the programs share. A scope is the ``op_name`` of the lowered
+instruction, so what is held here is read from the lowered text: each program
+of each kind of model carries exactly the names its layers have, and no name
+outside the one tuple stands anywhere in the package."""
+
+import dataclasses
+import functools
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from llm_d_kv_cache_manager_tpu.models import (
+    TINY_LFM2_MOE,
+    TINY_LLAMA,
+    TINY_MLA_MOE,
+    TINY_QWEN3_MOE,
+    TINY_SDAR_MOE,
+    llama,
+)
+from llm_d_kv_cache_manager_tpu.ops import sampling
+
+PS, PAGES, LANES, TABLE_W, CHUNK = 4, 16, 2, 4, 8
+
+#: a GQA model whose first layer is dense and whose routed layers have a
+#: shared expert beside the routed ones
+TINY_ROUTED = dataclasses.replace(
+    TINY_QWEN3_MOE, n_layers=3, first_k_dense=1, n_shared_experts=1
+)
+
+EVERY = {"attn", "cache_write", "head"}
+ROUTED = {"ffn", "moe_router", "moe_experts", "moe_shared"}
+CONFIGS = {
+    "dense": (TINY_LLAMA, EVERY | {"ffn"}),
+    "routed": (TINY_ROUTED, EVERY | ROUTED),
+    "latent": (TINY_MLA_MOE, EVERY | ROUTED),
+    "conv": (TINY_LFM2_MOE, EVERY | ROUTED - {"moe_shared"} | {"conv"}),
+    "blocks": (TINY_SDAR_MOE, EVERY | {"moe_router", "moe_experts"}),
+}
+
+
+def _shapes(cfg):
+    params = jax.eval_shape(
+        lambda: llama.init_params(jax.random.PRNGKey(0), cfg)
+    )
+    k_pages, v_pages = jax.eval_shape(
+        lambda: llama.init_kv_pages(cfg, PAGES, PS)
+    )
+    state = jax.eval_shape(lambda: llama.init_state_pages(cfg, PAGES))
+    return params, k_pages, v_pages, (
+        {} if state is None else {"state_pages": state}
+    )
+
+
+def _scopes_in(lowered_text: str) -> frozenset:
+    # an op_name is a path of scopes; inside a scan's body it starts anew
+    return frozenset(re.findall(r'["/]model\.([a-z_]+)/', lowered_text))
+
+
+@functools.lru_cache(maxsize=None)
+def _lowered_scopes(cfg, program) -> frozenset:
+    params, k_pages, v_pages, state = _shapes(cfg)
+    key = jax.random.PRNGKey(0)
+    if program == "decode_steps":
+        lowered = llama.decode_steps.lower(
+            params, cfg, jnp.zeros((LANES, 3), jnp.int32),
+            jnp.zeros((LANES, TABLE_W + llama.DECODE_PACKED_TAIL), jnp.int32),
+            k_pages, v_pages, key, page_size=PS, num_steps=2, interpret=True,
+            **state,
+        )
+    elif program == "prefill":
+        lowered = llama.prefill_packed.lower(
+            params, cfg,
+            jnp.zeros((LANES, 5 * CHUNK + TABLE_W + 1), jnp.int32),
+            k_pages, v_pages, chunk=CHUNK, attn_impl="xla", interpret=True,
+            **state,
+        )
+    elif program == "denoise_steps":
+        width = cfg.block_length
+        lowered = llama.denoise_steps.lower(
+            params, cfg,
+            jnp.zeros((LANES, 2 * width + TABLE_W + 5), jnp.int32),
+            jnp.zeros((LANES, 3), jnp.float32), k_pages, v_pages, key,
+            page_size=PS, table_w=TABLE_W, attn_impl="xla", interpret=True,
+        )
+    else:
+        raise AssertionError(program)
+    return _scopes_in(lowered.as_text(debug_info=True))
+
+
+CASES = [
+    (kind, program)
+    for kind in ("dense", "routed", "latent", "conv")
+    for program in ("decode_steps", "prefill")
+] + [("blocks", "prefill"), ("blocks", "denoise_steps")]
+
+
+@pytest.mark.parametrize("kind,program", CASES,
+                         ids=[f"{k}-{p}" for k, p in CASES])
+def test_a_program_carries_the_scopes_its_layers_have_and_no_other(
+        kind, program):
+    cfg, want = CONFIGS[kind]
+    # the sampler runs inside the decode-side programs; a prefill's first
+    # tokens are sampled by a program of their own
+    if program != "prefill":
+        want = want | {"sample"}
+    got = _lowered_scopes(cfg, program)
+    assert got <= set(llama.MODEL_SCOPES), got - set(llama.MODEL_SCOPES)
+    assert got == want, (sorted(got - want), sorted(want - got))
+
+
+def test_the_first_tokens_sampler_is_under_the_sample_scope():
+    text = sampling.sample_tokens_packed.lower(
+        jnp.zeros((LANES, 64), jnp.bfloat16),
+        jnp.zeros((LANES, 3), jnp.int32), jax.random.PRNGKey(0),
+    ).as_text(debug_info=True)
+    assert _scopes_in(text) == {"sample"}
+
+
+def test_model_scopes_is_the_one_list_of_names():
+    """Every name of the tuple stands in some program, the programs carry no
+    other (each case above), and the sampler's file opens the tuple's last."""
+    carried = set().union(
+        *(_lowered_scopes(CONFIGS[kind][0], program) for kind, program in CASES)
+    )
+    assert carried == set(llama.MODEL_SCOPES)
+    assert len(set(llama.MODEL_SCOPES)) == len(llama.MODEL_SCOPES)
+    assert llama.MODEL_SCOPES[-1] == "sample"

@@ -178,7 +178,7 @@ def test_prefill_packed_is_prefill(kind, warm, weights):
 def test_decode_steps_is_decode_step_and_the_sampler(kind, ids, sampled, weights):
     """One fused step over the packed operand against the logits API over
     the unpacked ones and ``sample_tokens`` with the burst's first key.
-    ``burst``: the ids arrive as a burst's ``[lanes, k]`` output on the
+    ``burst``: the ids arrive as a burst's ``[lanes, 1 + k]`` output on the
     device (a chained dispatch), whose last column counts."""
     cfg, params = PRESETS[kind], weights(kind)
     _, tables, lens = _prefill_operands(cfg, warm=False)
@@ -203,7 +203,7 @@ def test_decode_steps_is_decode_step_and_the_sampler(kind, ids, sampled, weights
         llama.pack_decode_inputs(lens, tables, lens + 1, temperature, top_k, top_p),
         k_pages, v_pages, key, page_size=PS, num_steps=1, interpret=True,
         **state)
-    np.testing.assert_array_equal(np.asarray(got)[:, 0], np.asarray(want))
+    np.testing.assert_array_equal(np.asarray(got)[:, 1], np.asarray(want))
     for have, ref in zip(got_pools, want_pools):
         np.testing.assert_array_equal(np.asarray(have), np.asarray(ref))
     assert len(got_pools) == len(want_pools) == 2 + bool(state)

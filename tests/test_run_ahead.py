@@ -236,7 +236,7 @@ def test_a_chunk_owed_keeps_admission_open():
 
 def test_chaining_adds_no_program():
     """A chained dispatch runs the program an unchained one compiled: its
-    ids are a burst's own ``[lanes, k]`` output, which every dispatch
+    ids are a burst's own ``[lanes, 1 + k]`` output, which every dispatch
     hands over in that shape."""
     def run():
         eng = _engine(lanes=2, decode_steps_per_iter=2)
