@@ -1338,7 +1338,7 @@ def _grouped_dot(cfg: LlamaConfig, row_group_ids: jnp.ndarray, interpret: bool):
 
 def _moe_mlp_routed(
     layer: Params, cfg: LlamaConfig, x: jnp.ndarray, interpret: bool = False,
-    touched: Optional[list] = None,
+    touched: Optional[list] = None, valid: Optional[jnp.ndarray] = None,
 ) -> jnp.ndarray:
     """Routed sparse-MoE SwiGLU FFN: grouped top-k gather dispatch.
 
@@ -1358,6 +1358,14 @@ def _moe_mlp_routed(
     ``touched``: a list the caller hands in while tracing, to which this
     layer's number of distinct experts chosen is appended (the experts
     whose weights the grouped dots read); None adds no operation.
+
+    ``valid`` (``[b, s]`` bool, the mask a prefill body already has): a
+    slot that holds no token chooses no expert. Its ``k`` places get the id
+    ``n_experts``, so the stable sort puts them after every group, the
+    group sizes sum to ``k`` times the real tokens and the grouped dots
+    visit the real rows' tiles alone; what they leave past the last group
+    is undefined on the kernel path and is selected to zero below. None
+    (decode: every lane is a row) groups every row.
     """
     b, s, d = x.shape
     n = b * s
@@ -1366,15 +1374,22 @@ def _moe_mlp_routed(
     topv, topi = _moe_gates(layer, cfg, xf)  # [n, k]
 
     with _scope("moe_router"):
+        if valid is not None:
+            topi = jnp.where(valid.reshape(n, 1), topi, cfg.n_experts)
         expert_ids = topi.reshape(-1)  # [n*k]
         token_ids = jnp.arange(n * k, dtype=jnp.int32) // k
         order = jnp.argsort(expert_ids, stable=True)
         src_tok = token_ids[order]  # [n*k] token each sorted row came from
         xs = xf[src_tok]  # [n*k, d] gathered inputs, expert-contiguous
+        # bincount drops ids >= length: the padding is in no group
         group_sizes = jnp.bincount(expert_ids, length=cfg.n_experts)
         if touched is not None:
             touched.append(jnp.sum(group_sizes > 0, dtype=jnp.int32))
         sorted_ids = expert_ids[order]
+        if valid is not None:
+            row_real = sorted_ids < cfg.n_experts
+            # the int8 experts' scales are gathered by this id
+            sorted_ids = jnp.minimum(sorted_ids, cfg.n_experts - 1)
 
     with _scope("moe_experts"):
         gdot = _grouped_dot(cfg, sorted_ids, interpret)
@@ -1386,6 +1401,11 @@ def _moe_mlp_routed(
         out = gdot(act, layer["w_down"], group_sizes)  # [n*k, d]
 
         out = out.astype(jnp.float32) * topv.reshape(-1)[order][:, None]
+        if valid is not None:
+            # selected, not multiplied: megablox leaves these rows as it
+            # found them, and 0 x NaN in a padded row would reach real
+            # rows through attention's ``p @ V``
+            out = jnp.where(row_real[:, None], out, 0.0)
         combined = jnp.zeros((n, d), jnp.float32).at[src_tok].add(out)
         return combined.reshape(b, s, d).astype(x.dtype)
 
@@ -1481,7 +1501,11 @@ def _moe_mlp_routed_ep(
 def _moe_mlp(
     layer: Params, cfg: LlamaConfig, x: jnp.ndarray, mesh=None,
     interpret: bool = False, touched: Optional[list] = None,
+    valid: Optional[jnp.ndarray] = None,
 ) -> jnp.ndarray:
+    # ``valid`` reaches the single-shard routed dispatch alone: the
+    # expert-parallel one (``tp`` > 1) and the dense oracle compute every
+    # row, padding included, as they always have.
     if cfg.moe_dispatch not in ("routed", "dense"):
         raise ValueError(f"unknown moe_dispatch {cfg.moe_dispatch!r}")
     tp = mesh.shape.get("tp", 1) if mesh is not None else 1
@@ -1502,20 +1526,22 @@ def _moe_mlp(
         # GSPMD partitions it along the f dimension.
         return _moe_mlp_dense(layer, cfg, x)
     if cfg.moe_dispatch == "routed":
-        return _moe_mlp_routed(layer, cfg, x, interpret, touched)
+        return _moe_mlp_routed(layer, cfg, x, interpret, touched, valid)
     return _moe_mlp_dense(layer, cfg, x)
 
 
 def _mlp(
     layer: Params, cfg: LlamaConfig, x: jnp.ndarray, mesh=None,
     interpret: bool = False, touched: Optional[list] = None,
+    valid: Optional[jnp.ndarray] = None,
 ) -> jnp.ndarray:
     # The layer says what its FFN is (it has a router or it has not), never
     # its index: a layer run alone (the benchmark's comparison) or a model
     # with leading dense layers is served by what its parameters hold.
     if "router" in layer:
         out = _moe_mlp(
-            layer, cfg, x, mesh=mesh, interpret=interpret, touched=touched
+            layer, cfg, x, mesh=mesh, interpret=interpret, touched=touched,
+            valid=valid,
         )
         if "ws_gate" in layer:
             # the shared experts: one SwiGLU every token takes, a plain
@@ -1532,6 +1558,7 @@ def _mlp(
 def _ffn(
     layer: Params, cfg: LlamaConfig, h: jnp.ndarray, mesh=None,
     interpret: bool = False, touched: Optional[list] = None,
+    valid: Optional[jnp.ndarray] = None,
 ) -> jnp.ndarray:
     """A layer's second half as every body runs it: ``h + _mlp(mlp_norm(h))``.
     The norm lies under the scope of what reads it (``model.moe_router``
@@ -1541,7 +1568,8 @@ def _ffn(
     with _scope("moe_router" if routed else "ffn"):
         x = rms_norm(h, layer["mlp_norm"], cfg.rms_norm_eps, cfg.norm_offset)
     out = _mlp(
-        layer, cfg, x, mesh=mesh, interpret=interpret, touched=touched
+        layer, cfg, x, mesh=mesh, interpret=interpret, touched=touched,
+        valid=valid,
     )
     with _scope("moe_experts" if routed else "ffn"):
         return h + out
@@ -1841,7 +1869,7 @@ def _prefill_body(
             h = h + out
         h = _ffn(
             layer, cfg, h, mesh=mesh, interpret=interpret,
-            touched=experts_touched,
+            touched=experts_touched, valid=valid,
         )
 
     if fresh_state:

@@ -90,6 +90,15 @@ def grouped_matmul(
 
     ``use_kernel=False`` falls back to ``jax.lax.ragged_dot`` with
     whole-stack dequantization — the parity oracle for tests.
+
+    ``group_sizes`` may sum to FEWER than ``rows``: the rows past the last
+    group belong to no expert (a prefill dispatch's padding,
+    ``llama._moe_mlp_routed(valid=)``). Both kernels walk (row tile, group)
+    pairs of the groups alone, so the tiles that hold only such rows are
+    never visited; what the output holds there is UNDEFINED on the kernel
+    path (megablox leaves it as allocated; ``ragged_dot`` writes zeros) and
+    the caller selects it away, never multiplies it. ``row_group_ids`` of
+    such rows must still index ``rhs`` (the caller clips them).
     """
     quantized = isinstance(rhs, QuantizedTensor)
     if quantized and row_group_ids is None:

@@ -771,8 +771,13 @@ class Engine:
                 )
         #: prefill observability: tokens actually pushed through prefill
         #: dispatches (the FLOP proxy — prefix-cache hits and imported
-        #: blocks reduce it) and dispatch count.
-        self.prefill_stats = {"tokens_computed": 0, "dispatches": 0}
+        #: blocks reduce it), dispatch count, and the token slots those
+        #: dispatches computed (rows x bucketed width, padding included:
+        #: ``tokens_computed / token_slots`` is how full a dispatch is; the
+        #: rest is the padding the routed FFN leaves out of its groups).
+        self.prefill_stats = {
+            "tokens_computed": 0, "dispatches": 0, "token_slots": 0,
+        }
         #: cross-pod KV transfer observability (kvcache/transfer).
         self.transfer_stats = {
             "exported_blocks": 0,
@@ -2324,6 +2329,7 @@ class Engine:
             )
             self.prefill_stats["tokens_computed"] += int(valid.sum())
             self.prefill_stats["dispatches"] += 1
+            self.prefill_stats["token_slots"] += b * chunk
             now = time.monotonic()
             finals = [
                 seq for seq, n in zip(seqs, chunks) if n >= seq.prompt_remaining

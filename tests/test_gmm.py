@@ -62,6 +62,41 @@ class TestGroupedMatmul:
         err = float(jnp.max(jnp.abs(out - oracle))) / scale
         assert err < 2e-2, err
 
+    @pytest.mark.parametrize("kernel", ["megablox", "int8"])
+    @pytest.mark.parametrize(
+        "sizes",
+        [
+            [40, 0, 25, 60, 10, 30, 20, 15],  # 200 of 600 rows, tiles unvisited
+            [0, 0, 3, 0, 0, 0, 0, 0],  # three real rows
+            [0] * 8,  # no real row: no tile is visited
+        ],
+    )
+    def test_group_sizes_may_sum_to_fewer_than_the_rows(self, kernel, sizes):
+        """The rows past the last group are in no group (a prefill
+        dispatch's padding): the rows inside the groups read what
+        ``ragged_dot`` reads; the rest is undefined and not compared."""
+        rng = np.random.default_rng(7)
+        rows, real = 600, int(np.sum(sizes))
+        lhs = jnp.asarray(rng.normal(size=(rows, 256)), jnp.bfloat16)
+        w = jnp.asarray(rng.normal(size=(8, 256, 384)) * 0.1, jnp.bfloat16)
+        gs = jnp.asarray(sizes, jnp.int32)
+        rgi = jnp.asarray(
+            np.concatenate([np.repeat(np.arange(8), sizes),
+                            np.full(rows - real, 7)]), jnp.int32)
+        rhs = quantize_tensor(w) if kernel == "int8" else w
+        oracle = grouped_matmul(
+            lhs, rhs, gs, row_group_ids=rgi, use_kernel=False
+        ).astype(jnp.float32)
+        out = grouped_matmul(
+            lhs, rhs, gs, row_group_ids=rgi, interpret=True
+        ).astype(jnp.float32)
+        assert out.shape == (rows, 384)
+        assert not np.asarray(oracle)[real:].any()  # ragged_dot zeroes them
+        if real:  # with no real row the call runs and nothing is defined
+            scale = float(jnp.max(jnp.abs(oracle))) + 1e-9
+            err = float(jnp.max(jnp.abs(out[:real] - oracle[:real]))) / scale
+            assert err < 2e-2, err
+
     def test_int8_requires_row_group_ids(self):
         rng = np.random.default_rng(3)
         lhs, w, gs, _ = _problem(rng, 8, 256, 384, [32] * 8)
