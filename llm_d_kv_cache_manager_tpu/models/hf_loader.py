@@ -1,7 +1,7 @@
 """HuggingFace → native parameter conversion for Llama-family checkpoints.
 
-Maps a transformers Llama/Qwen2/Qwen3/Mixtral/DeepSeek-V3/LFM2-MoE state dict
-onto the pytree layout of ``models/llama.py``. torch ``Linear`` stores ``[out, in]`` and computes
+Maps a transformers Llama/Qwen2/Qwen3/Mixtral/DeepSeek-V3/LFM2-MoE/
+LongCat-Flash state dict onto the pytree layout of ``models/llama.py``. torch ``Linear`` stores ``[out, in]`` and computes
 ``x @ W.T``; our params store ``[in, out]``, so every projection transposes.
 The RoPE convention (half-split rotate) matches HF Llama, so no permutation
 of head channels is needed.
@@ -9,6 +9,7 @@ of head channels is needed.
 
 from __future__ import annotations
 
+import re
 from typing import Any, Mapping
 
 import jax.numpy as jnp
@@ -30,6 +31,8 @@ def load_hf_state_dict(
     sd = state_dict
     if cfg.layer_types is not None:
         return _load_lfm2_moe(sd, cfg)
+    if cfg.double_layer:
+        return _load_longcat_flash(sd, cfg)
 
     def get(name: str) -> np.ndarray:
         return _to_np(sd[name])
@@ -47,10 +50,18 @@ def load_hf_state_dict(
         p = f"model.layers.{i}."
         layer = {
             "attn_norm": jnp.asarray(get(p + "input_layernorm.weight"), cfg.dtype),
-            "wq": linear(p + "self_attn.q_proj.weight"),
             "wo": linear(p + "self_attn.o_proj.weight"),
             "mlp_norm": jnp.asarray(get(p + "post_attention_layernorm.weight"), cfg.dtype),
         }
+        if cfg.kv_lora_rank and cfg.q_lora_rank:
+            # DeepSeek-V3's low-rank query path: down, its norm, up
+            layer["wq_a"] = linear(p + "self_attn.q_a_proj.weight")
+            layer["q_a_norm"] = jnp.asarray(
+                get(p + "self_attn.q_a_layernorm.weight"), cfg.dtype
+            )
+            layer["wq_b"] = linear(p + "self_attn.q_b_proj.weight")
+        else:
+            layer["wq"] = linear(p + "self_attn.q_proj.weight")
         if cfg.kv_lora_rank:
             # DeepSeek-V3's latent attention: the down-projection carries
             # the shared rope key (``_with_mqa``), its norm is the latent's
@@ -111,6 +122,148 @@ def load_hf_state_dict(
     if not cfg.tie_word_embeddings:
         params["lm_head"] = linear("lm_head.weight")
     return params
+
+
+def _load_longcat_flash(sd: Mapping[str, Any], cfg: LlamaConfig) -> Params:
+    """``model_type: longcat_flash``: a published layer is two attentions,
+    two dense FFNs and one routed FFN (``llama.init_params``' double layer:
+    the first half at the top, ``moe``, ``second``). A process that holds a
+    range of the experts (``cfg.expert_first`` / ``expert_count``) loads its
+    own and the whole router. ASSUMED, with no network at hand to read the
+    checkpoint's index: the names below are those of the family's published
+    modelling code (``modeling_longcat_flash.py``: ``self_attn.{0,1}``,
+    ``input_layernorm.{0,1}``, ``post_attention_layernorm.{0,1}``,
+    ``mlps.{0,1}``, ``mlp.router.classifier`` with
+    ``mlp.router.e_score_correction_bias``, ``mlp.experts.N``); a checkpoint
+    that names them otherwise fails on the missing key, named. Every key of
+    the state dict that is not mapped (the audio and vision towers, the codec
+    decoder, a multi-token-prediction head, an expert this process does not
+    hold excepted) is refused by name."""
+    used = set()
+
+    def get(name: str) -> np.ndarray:
+        used.add(name)
+        return _to_np(sd[name])
+
+    def vector(name: str) -> jnp.ndarray:
+        return jnp.asarray(get(name), cfg.dtype)
+
+    def linear(name: str) -> jnp.ndarray:
+        return jnp.asarray(get(name).T, cfg.dtype)  # [out,in] -> [in,out]
+
+    ffn = (("w_gate", "gate_proj"), ("w_up", "up_proj"), ("w_down", "down_proj"))
+
+    def half(p: str, j: int) -> dict:
+        a = f"{p}self_attn.{j}."
+        part = {
+            "attn_norm": vector(f"{p}input_layernorm.{j}.weight"),
+            "wq_a": linear(a + "q_a_proj.weight"),
+            "q_a_norm": vector(a + "q_a_layernorm.weight"),
+            "wq_b": linear(a + "q_b_proj.weight"),
+            "wkv_a": linear(a + "kv_a_proj_with_mqa.weight"),
+            "kv_norm": vector(a + "kv_a_layernorm.weight"),
+            "wkv_b": linear(a + "kv_b_proj.weight"),
+            "wo": linear(a + "o_proj.weight"),
+            "mlp_norm": vector(f"{p}post_attention_layernorm.{j}.weight"),
+        }
+        for ours, theirs in ffn:
+            part[ours] = linear(f"{p}mlps.{j}.{theirs}.weight")
+        return part
+
+    held = range(cfg.expert_first, cfg.expert_first + cfg.experts_held)
+    layers = []
+    for i in range(cfg.n_layers):
+        p = f"model.layers.{i}."
+        moe = {
+            "router": linear(p + "mlp.router.classifier.weight"),
+            "router_bias": jnp.asarray(
+                get(p + "mlp.router.e_score_correction_bias"), jnp.float32
+            ),
+        }
+        for ours, theirs in ffn:
+            moe[ours] = jnp.stack([
+                linear(f"{p}mlp.experts.{e}.{theirs}.weight") for e in held
+            ])
+        layers.append({**half(p, 0), "moe": moe, "second": half(p, 1)})
+    params = {
+        "embed": vector("model.embed_tokens.weight"),
+        "final_norm": vector("model.norm.weight"),
+        "layers": layers,
+        "lm_head": linear("lm_head.weight"),
+    }
+    # an expert of a layer that is run, held by another process
+    elsewhere = re.compile(r"model\.layers\.(\d+)\.mlp\.experts\.(\d+)\.")
+
+    def held_elsewhere(key: str) -> bool:
+        m = elsewhere.match(key)
+        return bool(m) and int(m[1]) < cfg.n_layers and (
+            int(m[2]) < cfg.n_experts and int(m[2]) not in held
+        )
+
+    unmapped = sorted(
+        k for k in sd if k not in used and not held_elsewhere(k)
+    )
+    if unmapped:
+        raise NotImplementedError(
+            f"{len(unmapped)} keys of the checkpoint are not part of the "
+            f"language model that is run (towers, decoders and further "
+            f"heads are outside): {unmapped[:4]}"
+        )
+    return params
+
+
+def _longcat_flash_config(hf_config, rope_scaling) -> LlamaConfig:
+    """``model_type: longcat_flash``, from the keys its published
+    ``config.json`` has (``num_layers``, ``ffn_hidden_size``,
+    ``expert_ffn_hidden_size``, ``moe_topk``, ``zero_expert_num``...). What
+    the program does not run is refused here by name."""
+    def has(key, default=None):
+        return getattr(hf_config, key, default)
+
+    if has("zero_expert_num", 0) and has("zero_expert_type", "identity") != "identity":
+        raise NotImplementedError(
+            f"zero_expert_type={has('zero_expert_type')!r}: only identity "
+            "experts are supported"
+        )
+    if has("attention_method", "MLA") != "MLA":
+        raise NotImplementedError(
+            f"attention_method={has('attention_method')!r} is not supported "
+            "yet (MLA)"
+        )
+    if has("attention_bias", False) or has("router_bias", False):
+        raise NotImplementedError(
+            "attention_bias / router_bias (biased projections) are not "
+            "supported yet"
+        )
+    if rope_scaling is not None:
+        raise NotImplementedError("rope_scaling with latent attention")
+    return LlamaConfig(
+        vocab_size=hf_config.vocab_size,
+        hidden_size=hf_config.hidden_size,
+        intermediate_size=hf_config.ffn_hidden_size,
+        n_layers=hf_config.num_layers,
+        n_heads=hf_config.num_attention_heads,
+        n_kv_heads=hf_config.num_attention_heads,
+        head_dim=hf_config.qk_rope_head_dim,
+        rope_theta=float(has("rope_theta", 10_000_000.0)),
+        rms_norm_eps=has("rms_norm_eps", 1e-5),
+        n_experts=hf_config.n_routed_experts,
+        n_experts_per_tok=hf_config.moe_topk,
+        moe_intermediate_size=hf_config.expert_ffn_hidden_size,
+        norm_topk_prob=bool(has("norm_topk_prob", False)),
+        kv_lora_rank=hf_config.kv_lora_rank,
+        q_lora_rank=hf_config.q_lora_rank,
+        mla_scale_q_lora=bool(has("mla_scale_q_lora", False)),
+        mla_scale_kv_lora=bool(has("mla_scale_kv_lora", False)),
+        qk_nope_head_dim=hf_config.qk_nope_head_dim,
+        qk_rope_head_dim=hf_config.qk_rope_head_dim,
+        v_head_dim=hf_config.v_head_dim,
+        rope_interleave=bool(has("rope_interleave", True)),
+        routed_scaling_factor=float(has("routed_scaling_factor", 1.0)),
+        moe_router_bias=True,
+        n_zero_experts=has("zero_expert_num", 0) or 0,
+        double_layer=True,
+    )
 
 
 def _load_lfm2_moe(sd: Mapping[str, Any], cfg: LlamaConfig) -> Params:
@@ -259,6 +412,8 @@ def config_from_hf(hf_config) -> LlamaConfig:
             )
     if getattr(hf_config, "model_type", "") == "lfm2_moe":
         return _lfm2_moe_config(hf_config, rope_scaling)
+    if getattr(hf_config, "model_type", "") == "longcat_flash":
+        return _longcat_flash_config(hf_config, rope_scaling)
     cls_name = hf_config.__class__.__name__
     is_gemma = cls_name == "GemmaConfig"
     if cls_name.startswith("Gemma") and not is_gemma:
@@ -343,11 +498,6 @@ def _deepseek_v3_fields(hf_config) -> dict:
     def has(key, default=None):
         return getattr(hf_config, key, default)
 
-    if has("q_lora_rank") is not None:
-        raise NotImplementedError(
-            f"q_lora_rank={has('q_lora_rank')}: a low-rank query path is "
-            "not supported yet (q_lora_rank must be null)"
-        )
     if has("n_group", 1) != 1 or has("topk_group", 1) != 1:
         raise NotImplementedError(
             f"group-limited routing is not supported yet (n_group="
@@ -366,6 +516,7 @@ def _deepseek_v3_fields(hf_config) -> dict:
         )
     return dict(
         kv_lora_rank=hf_config.kv_lora_rank,
+        q_lora_rank=has("q_lora_rank"),
         qk_nope_head_dim=hf_config.qk_nope_head_dim,
         qk_rope_head_dim=hf_config.qk_rope_head_dim,
         v_head_dim=hf_config.v_head_dim,

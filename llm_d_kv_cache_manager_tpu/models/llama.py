@@ -85,7 +85,8 @@ from .quant import QuantizedTensor, materialize as _w
 #: projection and residual; ``ffn``: ``mlp_norm`` and the dense SwiGLU;
 #: ``moe_router``: a routed layer's ``mlp_norm``, ``_moe_gates`` and the
 #: sort / permutation of rows by expert; ``moe_experts``: the grouped
-#: matmuls, the gate weighting and the un-permutation; ``moe_shared``: the
+#: matmuls, the gate weighting and the un-permutation; ``moe_zero``: the
+#: identity (zero-compute) experts' multiply-add; ``moe_shared``: the
 #: shared experts; ``cache_write``: the all-layer scatters into the pools;
 #: ``head``: the final norm and the logits; ``sample``:
 #: ``ops/sampling.py``'s entry points. The Pallas kernels keep their own
@@ -94,8 +95,8 @@ from .quant import QuantizedTensor, materialize as _w
 #: Readers and documents quote this tuple, as they do ``server/engine.py``'s
 #: ``STEP_PHASES`` for the host's side.
 MODEL_SCOPES = (
-    "attn", "conv", "ffn", "moe_router", "moe_experts", "moe_shared",
-    "cache_write", "head", "sample",
+    "attn", "conv", "ffn", "moe_router", "moe_experts", "moe_zero",
+    "moe_shared", "cache_write", "head", "sample",
 )
 
 
@@ -370,10 +371,17 @@ class LlamaConfig:
     # nothing else (``kv_row_shape``; no value pool), decode attends in the
     # absorbed form over it (``ops/mla_attention.py``). 0 = the attention
     # above. ``head_dim`` / ``n_kv_heads`` keep what the published config
-    # says and size nothing of a latent pool. ``q_lora_rank`` is carried
-    # for the loader's refusal: a low-rank query path is not run.
+    # says and size nothing of a latent pool. ``q_lora_rank`` > 0: the
+    # query goes through a latent of that width and its norm (``wq_a``,
+    # ``q_a_norm``, ``wq_b``; a layer's projection is read from the layer:
+    # it has ``wq_a`` or ``wq``). ``mla_scale_q_lora`` / ``mla_scale_kv_lora``
+    # (LongCat-Flash): the normed query latent times sqrt(hidden /
+    # q_lora_rank), the normed key/value latent times sqrt(hidden /
+    # kv_lora_rank); the pool's row holds the scaled latent.
     kv_lora_rank: int = 0
     q_lora_rank: Optional[int] = None
+    mla_scale_q_lora: bool = False
+    mla_scale_kv_lora: bool = False
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
@@ -399,6 +407,29 @@ class LlamaConfig:
     # published modelling code's own constant (1e-20 DeepSeek-V3's, 1e-6
     # LFM2's).
     router_norm_eps: float = 1e-20
+    # A softmax router with a correction bias that chooses and does not
+    # weigh (LongCat-Flash); a sigmoid router always has one. Decides only
+    # what ``init_params`` makes: ``_moe_gates`` reads the layer.
+    moe_router_bias: bool = False
+    # Zero-compute experts (LongCat-Flash): the router scores ``n_experts +
+    # n_zero_experts`` outputs, and a chosen id >= ``n_experts`` is an
+    # identity expert whose part of the routed sum is ``gate x input``: a
+    # multiply-add, no matmul, no row of a grouped one.
+    n_zero_experts: int = 0
+    # One rank's share of the experts: this process holds the contiguous
+    # range ``[expert_first, expert_first + expert_count)`` of the
+    # ``n_experts`` the router scores (None: all of them). The router keeps
+    # its published width; a place whose expert is held elsewhere adds
+    # nothing here, and nothing stands in for the chip that holds it.
+    expert_first: int = 0
+    expert_count: Optional[int] = None
+    # The shortcut-connected double layer (LongCat-Flash): a published
+    # layer is two attentions and two dense FFNs in series with ONE routed
+    # FFN that reads the first FFN's input and is added after the second
+    # (``_ffn``). Decides what ``init_params`` and the loader make and how
+    # many layers the pool has (``n_attn_layers``); the bodies read the
+    # layer: it has a ``second`` half and a ``moe`` part or it has not.
+    double_layer: bool = False
     # Layers whose operator is a gated short convolution instead of
     # attention (LFM2): ``layer_types[i]`` is "conv" or "full_attention"
     # (None: every layer attends). Such a layer keeps ``conv_L_cache - 1``
@@ -432,8 +463,31 @@ class LlamaConfig:
 
     @property
     def n_attn_layers(self) -> int:
-        """Layers of the key/value pools."""
-        return self.n_layers - self.n_conv_layers
+        """Layers of the key/value pools: the attentions (two a published
+        layer of a ``double_layer`` model)."""
+        return (self.n_layers - self.n_conv_layers) * (1 + self.double_layer)
+
+    @property
+    def experts_held(self) -> int:
+        """Routed experts whose weights this process holds."""
+        return self.n_experts if self.expert_count is None else self.expert_count
+
+    @property
+    def router_outputs(self) -> int:
+        """Columns of the router: the routed experts, then the zero ones."""
+        return self.n_experts + self.n_zero_experts
+
+    @property
+    def holds_every_expert(self) -> bool:
+        """No zero expert and no expert held elsewhere: the routed layer is
+        the program it always was."""
+        return not self.n_zero_experts and self.experts_held == self.n_experts
+
+    @property
+    def zero_expert_type(self) -> Optional[str]:
+        """What a zero-compute expert computes, as a published file says it
+        (only the identity is run)."""
+        return "identity" if self.n_zero_experts else None
 
     @property
     def layer_types_published(self) -> Optional[list]:
@@ -724,6 +778,78 @@ TINY_MLA_MOE = LlamaConfig(
     dtype=jnp.float32,
 )
 
+#: meituan-longcat/LongCat-Flash-Omni's language model (``LongCat-Flash``'s
+#: decoder; the towers and the codec decoder are outside): 28 double layers
+#: (two latent attentions with a low-rank query path and the two
+#: ``mla_scale_*`` factors, two dense FFNs, one routed FFN beside the second
+#: attention and FFN), a softmax router over 512 routed + 256 identity
+#: experts with a correction bias that chooses, top-12, gates times 6 and
+#: not renormalised. ``head_dim`` / ``n_kv_heads`` restate the rope part and
+#: the head count (as ``KANANA_2_30B_A3B``'s): neither sizes the pool. No
+#: chip holds a layer's 512 experts: a configuration states its share
+#: (``expert_first`` / ``expert_count``) and its cut of depth and vocabulary.
+LONGCAT_FLASH_OMNI = LlamaConfig(
+    vocab_size=131_072,
+    hidden_size=6_144,
+    intermediate_size=12_288,
+    n_layers=28,
+    n_heads=64,
+    n_kv_heads=64,
+    head_dim=64,
+    rope_theta=10_000_000.0,
+    rms_norm_eps=1e-5,
+    n_experts=512,
+    n_experts_per_tok=12,
+    moe_intermediate_size=2_048,
+    norm_topk_prob=False,
+    kv_lora_rank=512,
+    q_lora_rank=1_536,
+    mla_scale_q_lora=True,
+    mla_scale_kv_lora=True,
+    qk_nope_head_dim=128,
+    qk_rope_head_dim=64,
+    v_head_dim=128,
+    rope_interleave=True,
+    routed_scaling_factor=6.0,
+    moe_router_bias=True,
+    n_zero_experts=256,
+    double_layer=True,
+)
+
+#: Tiny shortcut-connected MoE (two double layers; 16 routed + 8 identity
+#: router outputs, top-4, 4 of the 16 held; latent 32 + rope 8 behind a
+#: query latent of 24) for tests / CPU dry-runs.
+TINY_SCMOE = LlamaConfig(
+    vocab_size=256,
+    hidden_size=64,
+    intermediate_size=128,
+    n_layers=2,
+    n_heads=4,
+    n_kv_heads=4,
+    head_dim=8,
+    rope_theta=10_000.0,
+    rms_norm_eps=1e-5,
+    n_experts=16,
+    n_experts_per_tok=4,
+    moe_intermediate_size=48,
+    norm_topk_prob=False,
+    kv_lora_rank=32,
+    q_lora_rank=24,
+    mla_scale_q_lora=True,
+    mla_scale_kv_lora=True,
+    qk_nope_head_dim=16,
+    qk_rope_head_dim=8,
+    v_head_dim=16,
+    rope_interleave=True,
+    routed_scaling_factor=6.0,
+    moe_router_bias=True,
+    n_zero_experts=8,
+    expert_first=4,
+    expert_count=4,
+    double_layer=True,
+    dtype=jnp.float32,
+)
+
 _CONV, _ATTN = "conv", "full_attention"
 
 #: LiquidAI/LFM2-8B-A1B (``model_type: lfm2_moe``): 18 gated short
@@ -838,10 +964,101 @@ def init_params(
     def norm_init(shape):
         return (jnp.zeros if cfg.norm_offset else jnp.ones)(shape, cfg.dtype)
 
+    def latent_attention(k) -> Params:
+        """One latent attention with its norm (``k``: eight keys, the first
+        four used); the query's projection is the low-rank pair where the
+        model has ``q_lora_rank``."""
+        dc, dn, dr, dv = (
+            cfg.kv_lora_rank, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+            cfg.v_head_dim,
+        )
+        part = {"attn_norm": norm_init((d,))}
+        if cfg.q_lora_rank:
+            dq = cfg.q_lora_rank
+            ka, kb = jax.random.split(k[0])
+            part["wq_a"] = dense(ka, (d, dq), d)
+            part["q_a_norm"] = norm_init((dq,))
+            part["wq_b"] = dense(kb, (dq, n_q * (dn + dr)), dq)
+        else:
+            part["wq"] = dense(k[0], (d, n_q * (dn + dr)), d)
+        part.update(
+            wkv_a=dense(k[1], (d, dc + dr), d),
+            kv_norm=norm_init((dc,)),
+            wkv_b=dense(k[2], (dc, n_q * (dn + dv)), dc),
+            wo=dense(k[3], (n_q * dv, d), n_q * dv),
+            mlp_norm=norm_init((d,)),
+        )
+        return part
+
+    def dense_ffn(k) -> Params:
+        return {
+            "w_gate": dense(k[4], (d, inter), d),
+            "w_up": dense(k[5], (d, inter), d),
+            "w_down": dense(k[6], (inter, d), inter),
+        }
+
+    def routed_ffn(k, layer_key) -> Params:
+        """A routed FFN's parameters: the router over every output it
+        scores, the stacks of the experts this process holds."""
+        e, f = cfg.experts_held, cfg.moe_inter
+        # Router stays full precision: tiny, and routing decisions are
+        # the most quantization-sensitive computation in an MoE.
+        part = {
+            "router": dense(
+                k[7], (d, cfg.router_outputs), d, quantizable=False
+            ),
+            "w_gate": dense(k[4], (e, d, f), d, quantizable=quantize_experts),
+            "w_up": dense(k[5], (e, d, f), d, quantizable=quantize_experts),
+            "w_down": dense(k[6], (e, f, d), f, quantizable=quantize_experts),
+        }
+        # (keys folded in, not split off: the trees of the models
+        # without these parts stay bit for bit what they were)
+        extra = jax.random.split(jax.random.fold_in(layer_key, 1), 4)
+        if cfg.moe_scoring == "sigmoid" or cfg.moe_router_bias:
+            # The correction bias chooses the experts and does not weigh
+            # them; float32, not trained by gradient. Drawn here (a
+            # checkpoint's is near zero and balances the load), so that a
+            # program that weighs with it, or chooses without it, is not
+            # this model: half a sigmoid router's spread of scores (0.21);
+            # a fifth of a softmax router's over n outputs (sqrt(e - 1) / n:
+            # at the whole spread a drawn bias moved the load of a rank's 16
+            # experts by a half from seed to seed, PERF.md section 6, PR 41).
+            spread = (
+                0.1 if cfg.moe_scoring == "sigmoid"
+                else 0.25 / cfg.router_outputs
+            )
+            part["router_bias"] = spread * jax.random.normal(
+                extra[0], (cfg.router_outputs,), jnp.float32
+            )
+        if cfg.n_shared_experts:
+            fs = cfg.n_shared_experts * f
+            part["ws_gate"] = dense(extra[1], (d, fs), d)
+            part["ws_up"] = dense(extra[2], (d, fs), d)
+            part["ws_down"] = dense(extra[3], (fs, d), fs)
+        return part
+
     keys = jax.random.split(rng, cfg.n_layers + 2)
     layers = []
     for i in range(cfg.n_layers):
         k = jax.random.split(keys[i], 8)
+        if cfg.double_layer:
+            # One published layer: the first attention and dense FFN at the
+            # top, the routed FFN that reads the first FFN's input under
+            # ``moe`` (its stacks keep the names every routed layer has),
+            # the second attention and dense FFN under ``second``.
+            if not cfg.kv_lora_rank or not cfg.n_experts:
+                raise ValueError(
+                    "double_layer: latent attention and routed experts "
+                    "are what is run"
+                )
+            k2 = jax.random.split(jax.random.fold_in(keys[i], 2), 8)
+            k3 = jax.random.split(jax.random.fold_in(keys[i], 3), 8)
+            layers.append({
+                **latent_attention(k), **dense_ffn(k),
+                "moe": routed_ffn(k3, keys[i]),
+                "second": {**latent_attention(k2), **dense_ffn(k2)},
+            })
+            continue
         if cfg.layer_kind(i) == "conv":
             # ``[B | C | x] = u conv_in``; one ``conv_L_cache``-tap filter a
             # channel (row j weighs ``z`` of ``conv_L_cache - 1 - j`` tokens
@@ -858,19 +1075,7 @@ def init_params(
                 "mlp_norm": norm_init((d,)),
             }
         elif cfg.kv_lora_rank:
-            dc, dn, dr, dv = (
-                cfg.kv_lora_rank, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
-                cfg.v_head_dim,
-            )
-            layer = {
-                "attn_norm": norm_init((d,)),
-                "wq": dense(k[0], (d, n_q * (dn + dr)), d),
-                "wkv_a": dense(k[1], (d, dc + dr), d),
-                "kv_norm": norm_init((dc,)),
-                "wkv_b": dense(k[2], (dc, n_q * (dn + dv)), dc),
-                "wo": dense(k[3], (n_q * dv, d), n_q * dv),
-                "mlp_norm": norm_init((d,)),
-            }
+            layer = latent_attention(k)
         else:
             layer = {
                 "attn_norm": norm_init((d,)),
@@ -881,34 +1086,9 @@ def init_params(
                 "mlp_norm": norm_init((d,)),
             }
         if cfg.n_experts and i >= cfg.first_k_dense:
-            e, f = cfg.n_experts, cfg.moe_inter
-            # Router stays full precision: tiny, and routing decisions are
-            # the most quantization-sensitive computation in an MoE.
-            layer["router"] = dense(k[7], (d, e), d, quantizable=False)
-            layer["w_gate"] = dense(k[4], (e, d, f), d, quantizable=quantize_experts)
-            layer["w_up"] = dense(k[5], (e, d, f), d, quantizable=quantize_experts)
-            layer["w_down"] = dense(k[6], (e, f, d), f, quantizable=quantize_experts)
-            # (keys folded in, not split off: the trees of the models
-            # without these parts stay bit for bit what they were)
-            extra = jax.random.split(jax.random.fold_in(keys[i], 1), 4)
-            if cfg.moe_scoring == "sigmoid":
-                # The correction bias chooses the experts and does not weigh
-                # them; float32, not trained by gradient. Drawn here (a
-                # checkpoint's is near zero) with about half the spread of
-                # the scores of a random router, so that a program that
-                # weighs with it, or chooses without it, is not this model.
-                layer["router_bias"] = 0.1 * jax.random.normal(
-                    extra[0], (e,), jnp.float32
-                )
-            if cfg.n_shared_experts:
-                fs = cfg.n_shared_experts * f
-                layer["ws_gate"] = dense(extra[1], (d, fs), d)
-                layer["ws_up"] = dense(extra[2], (d, fs), d)
-                layer["ws_down"] = dense(extra[3], (fs, d), fs)
+            layer.update(routed_ffn(k, keys[i]))
         else:
-            layer["w_gate"] = dense(k[4], (d, inter), d)
-            layer["w_up"] = dense(k[5], (d, inter), d)
-            layer["w_down"] = dense(k[6], (inter, d), inter)
+            layer.update(dense_ffn(k))
         if cfg.qkv_bias and "wq" in layer:
             layer["bq"] = jnp.zeros((n_q * hd,), cfg.dtype)
             layer["bk"] = jnp.zeros((n_kv * hd,), cfg.dtype)
@@ -956,9 +1136,9 @@ def init_kv_pages(
             raise ValueError("a latent pool has no quantised form")
         row = cfg.kv_row_shape
         return (
-            jnp.zeros((cfg.n_layers, total_pages, page_size, *row), dtype,
-                      device=sharding),
-            jnp.zeros((cfg.n_layers, 0, page_size, *row), dtype,
+            jnp.zeros((cfg.n_attn_layers, total_pages, page_size, *row),
+                      dtype, device=sharding),
+            jnp.zeros((cfg.n_attn_layers, 0, page_size, *row), dtype,
                       device=sharding),
         )
     # the layer axis counts the layers that attend (every one, unless the
@@ -1082,14 +1262,32 @@ def _mla_project(layer: Params, cfg: LlamaConfig, x, positions, inv_freq):
     """A latent layer's projections of ``x [b, s, d]`` at ``positions``:
     ``(q_n [b, s, H, d_n], q_r [b, s, H, d_r] rotated, row [b, s, width])``.
     ``row`` is the token's whole cache entry, ``[RMSNorm(c) | rotated k_r |
-    zeros to the row's width]`` (``kv_row_shape``): after the norm and after
-    the rotation, nothing else is kept of a token."""
+    zeros to the row's width]`` (``kv_row_shape``): after the norm (and the
+    ``mla_scale_kv_lora`` factor, where the model has one) and after the
+    rotation, nothing else is kept of a token. The query is ``x wq``, or,
+    where the layer has the low-rank pair, ``RMSNorm(x wq_a) wq_b`` (times
+    the ``mla_scale_q_lora`` factor, which commutes with ``wq_b``)."""
     b, s, _ = x.shape
     dc, dn, dr = cfg.kv_lora_rank, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
-    q = (x @ _w(layer["wq"], x.dtype)).reshape(b, s, cfg.n_heads, dn + dr)
+    if "wq_a" in layer:
+        # the low-rank query path: down, norm (and its factor), up
+        q = rms_norm(
+            x @ _w(layer["wq_a"], x.dtype), layer["q_a_norm"],
+            cfg.rms_norm_eps, cfg.norm_offset,
+        )
+        if cfg.mla_scale_q_lora:
+            q = q * jnp.asarray(
+                (cfg.hidden_size / cfg.q_lora_rank) ** 0.5, q.dtype
+            )
+        q = q @ _w(layer["wq_b"], x.dtype)
+    else:
+        q = x @ _w(layer["wq"], x.dtype)
+    q = q.reshape(b, s, cfg.n_heads, dn + dr)
     q_n, q_r = q[..., :dn], q[..., dn:]
     a = x @ _w(layer["wkv_a"], x.dtype)  # [b, s, dc + dr]
     c = rms_norm(a[..., :dc], layer["kv_norm"], cfg.rms_norm_eps, cfg.norm_offset)
+    if cfg.mla_scale_kv_lora:
+        c = c * jnp.asarray((cfg.hidden_size / cfg.kv_lora_rank) ** 0.5, c.dtype)
     k_r = a[..., None, dc:]  # one key, shared by every head
     if cfg.rope_interleave:
         q_r, k_r = _deinterleave(q_r), _deinterleave(k_r)
@@ -1239,7 +1437,10 @@ def _moe_gates(layer: Params, cfg: LlamaConfig, x: jnp.ndarray):
 
     Gating matches HF Mixtral (`MixtralSparseMoeBlock`): softmax over ALL
     expert logits, take top-k, renormalize the survivors. Returns
-    (top values [..., k] f32, top indices [..., k] int32).
+    (top values [..., k] f32, top indices [..., k] int32). The indices are
+    the router's own, over every output it scores (``cfg.router_outputs``:
+    an id >= ``n_experts`` is a zero expert), whatever range of the experts
+    this process holds.
     """
     router_logits = (x @ layer["router"]).astype(jnp.float32)  # [..., E]
     if cfg.moe_scoring == "sigmoid":
@@ -1261,9 +1462,19 @@ def _moe_gates(layer: Params, cfg: LlamaConfig, x: jnp.ndarray):
     if cfg.moe_scoring != "softmax":
         raise ValueError(f"unknown moe_scoring {cfg.moe_scoring!r}")
     weights = jax.nn.softmax(router_logits, axis=-1)
-    topv, topi = jax.lax.top_k(weights, cfg.n_experts_per_tok)
+    if "router_bias" in layer:
+        # LongCat-Flash: chosen by probability + correction bias, weighed
+        # by the probability alone
+        _, topi = jax.lax.top_k(
+            weights + layer["router_bias"], cfg.n_experts_per_tok
+        )
+        topv = jnp.take_along_axis(weights, topi, axis=-1)
+    else:
+        topv, topi = jax.lax.top_k(weights, cfg.n_experts_per_tok)
     if cfg.norm_topk_prob:
         topv = topv / jnp.sum(topv, axis=-1, keepdims=True)
+    if cfg.routed_scaling_factor != 1.0:
+        topv = topv * cfg.routed_scaling_factor
     return topv, topi
 
 
@@ -1336,9 +1547,16 @@ def _grouped_dot(cfg: LlamaConfig, row_group_ids: jnp.ndarray, interpret: bool):
     return gdot
 
 
+#: sorted places a pass of the grouped matmuls of a layer that holds a share
+#: of the experts (``_moe_mlp_routed``: a dispatch of more places than this
+#: runs them a block at a time)
+ROUTED_ROW_BLOCK = 4096
+
+
 def _moe_mlp_routed(
     layer: Params, cfg: LlamaConfig, x: jnp.ndarray, interpret: bool = False,
     touched: Optional[list] = None, valid: Optional[jnp.ndarray] = None,
+    held: Optional[tuple] = None, psum_axis: Optional[str] = None,
 ) -> jnp.ndarray:
     """Routed sparse-MoE SwiGLU FFN: grouped top-k gather dispatch.
 
@@ -1357,56 +1575,158 @@ def _moe_mlp_routed(
 
     ``touched``: a list the caller hands in while tracing, to which this
     layer's number of distinct experts chosen is appended (the experts
-    whose weights the grouped dots read); None adds no operation.
+    whose weights the grouped dots read); None adds no operation. A layer
+    that is told what it holds (below) appends ``[experts read, places on
+    zero experts, places on held experts]`` instead.
 
     ``valid`` (``[b, s]`` bool, the mask a prefill body already has): a
     slot that holds no token chooses no expert. Its ``k`` places get the id
-    ``n_experts``, so the stable sort puts them after every group, the
-    group sizes sum to ``k`` times the real tokens and the grouped dots
-    visit the real rows' tiles alone; what they leave past the last group
-    is undefined on the kernel path and is selected to zero below. None
-    (decode: every lane is a row) groups every row.
+    one past the last held expert, so the stable sort puts them after every
+    group, the group sizes sum to ``k`` times the real tokens and the
+    grouped dots visit the real rows' tiles alone; what they leave past the
+    last group is undefined on the kernel path and is selected to zero
+    below. None (decode: every lane is a row) groups every row.
+
+    ``held`` = (first, count): the layer's stacks are the experts ``first
+    .. first + count - 1`` of the ``cfg.n_experts`` the router scores
+    (``first`` may be traced: a shard's, under ``_moe_mlp_routed_ep``).
+    Default: the configuration's own range, and None (no operation added)
+    where that is every expert and the model has no zero expert. A place
+    whose expert is held elsewhere takes the id past the last group, as a
+    slot without a token does, and adds nothing: what the absent experts
+    would add is left out. A place on a zero expert (id >= ``n_experts``)
+    adds ``gate x input`` by one multiply-add (``model.moe_zero``) and
+    reaches no grouped matmul.
+
+    ``psum_axis``: sum the float32 result over that mesh axis before it is
+    cast (the expert-parallel combine).
     """
     b, s, d = x.shape
     n = b * s
     k = cfg.n_experts_per_tok
     xf = x.reshape(n, d)
     topv, topi = _moe_gates(layer, cfg, xf)  # [n, k]
+    if held is None and not cfg.holds_every_expert:
+        if cfg.expert_first + cfg.experts_held > cfg.n_experts:
+            raise ValueError(
+                f"experts {cfg.expert_first}..+{cfg.experts_held} lie past "
+                f"the {cfg.n_experts} the router scores"
+            )
+        held = (cfg.expert_first, cfg.experts_held)
+    n_held = cfg.n_experts if held is None else held[1]
+    if layer["w_gate"].shape[0] != n_held:
+        raise ValueError(
+            f"the layer holds {layer['w_gate'].shape[0]} experts, the "
+            f"dispatch was told {n_held}"
+        )
 
     with _scope("moe_router"):
+        zero_gate = None
+        if held is not None:
+            local = topi - held[0]
+            # (the range lies inside the routed experts, so a zero
+            # expert's id, >= n_experts, is never in it)
+            here = (local >= 0) & (local < n_held)
+            if cfg.n_zero_experts:
+                on_zero = topi >= cfg.n_experts
+                if valid is not None:
+                    on_zero = on_zero & valid.reshape(n, 1)
+                zero_gate = jnp.sum(jnp.where(on_zero, topv, 0.0), axis=-1)
+            topi = jnp.where(here, local, n_held)
         if valid is not None:
-            topi = jnp.where(valid.reshape(n, 1), topi, cfg.n_experts)
+            topi = jnp.where(valid.reshape(n, 1), topi, n_held)
         expert_ids = topi.reshape(-1)  # [n*k]
         token_ids = jnp.arange(n * k, dtype=jnp.int32) // k
         order = jnp.argsort(expert_ids, stable=True)
-        src_tok = token_ids[order]  # [n*k] token each sorted row came from
-        xs = xf[src_tok]  # [n*k, d] gathered inputs, expert-contiguous
         # bincount drops ids >= length: the padding is in no group
-        group_sizes = jnp.bincount(expert_ids, length=cfg.n_experts)
+        group_sizes = jnp.bincount(expert_ids, length=n_held)
         if touched is not None:
-            touched.append(jnp.sum(group_sizes > 0, dtype=jnp.int32))
-        sorted_ids = expert_ids[order]
-        if valid is not None:
-            row_real = sorted_ids < cfg.n_experts
-            # the int8 experts' scales are gathered by this id
-            sorted_ids = jnp.minimum(sorted_ids, cfg.n_experts - 1)
+            n_touched = jnp.sum(group_sizes > 0, dtype=jnp.int32)
+            if held is not None:
+                n_touched = jnp.stack([
+                    n_touched,
+                    jnp.sum(on_zero, dtype=jnp.int32) if cfg.n_zero_experts
+                    else jnp.zeros((), jnp.int32),
+                    jnp.sum(group_sizes, dtype=jnp.int32),
+                ])
+            touched.append(n_touched)
+        grouped_all = valid is None and held is None
 
-    with _scope("moe_experts"):
-        gdot = _grouped_dot(cfg, sorted_ids, interpret)
-        gate = cfg.act_fn(
-            gdot(xs, layer["w_gate"], group_sizes).astype(jnp.float32)
+    def expert_rows(rows, sizes, row_real=None):
+        """The experts' part of the sorted places ``rows`` (indices into
+        the ``n*k`` places, expert-contiguous, ``sizes`` of them an expert):
+        (the token each came from, its weighted output [rows, d] f32).
+        ``row_real``: which rows are in a group, where the places' own ids
+        do not say (a block's padding)."""
+        with _scope("moe_router"):
+            src_tok = token_ids[rows]  # token each sorted row came from
+            xs = xf[src_tok]  # [rows, d] gathered inputs, expert-contiguous
+            sorted_ids = expert_ids[rows]
+            if not grouped_all:
+                if row_real is None:  # every row of a group is real
+                    row_real = sorted_ids < n_held
+                # the int8 experts' scales are gathered by this id
+                sorted_ids = jnp.minimum(sorted_ids, n_held - 1)
+        with _scope("moe_experts"):
+            gdot = _grouped_dot(cfg, sorted_ids, interpret)
+            gate = cfg.act_fn(
+                gdot(xs, layer["w_gate"], sizes).astype(jnp.float32)
+            )
+            up = gdot(xs, layer["w_up"], sizes).astype(jnp.float32)
+            act = (gate * up).astype(x.dtype)
+            out = gdot(act, layer["w_down"], sizes)  # [rows, d]
+
+            out = out.astype(jnp.float32) * topv.reshape(-1)[rows][:, None]
+            if row_real is not None:
+                # selected, not multiplied: megablox leaves these rows as it
+                # found them, and 0 x NaN in a padded row would reach real
+                # rows through attention's ``p @ V``
+                out = jnp.where(row_real[:, None], out, 0.0)
+            return src_tok, out
+
+    block = ROUTED_ROW_BLOCK
+    if cfg.holds_every_expert or n * k <= block:
+        src_tok, out = expert_rows(order, group_sizes)
+        with _scope("moe_experts"):
+            combined = jnp.zeros((n, d), jnp.float32).at[src_tok].add(out)
+    else:
+        # A layer that holds a share of the experts groups a small part of
+        # a prefill's places (16 of 768 outputs: a fiftieth), all of them
+        # first in the sorted order: the grouped matmuls run over blocks of
+        # sorted rows, as many as hold a row of a group, so the gathered
+        # inputs and the experts' outputs are a block's and not the ``n*k``
+        # places' (110 592 x 6144 values a fill dispatch at the published
+        # widths, which no chip holds beside the model). Exact: no place
+        # is dropped, whatever the router chose.
+        with _scope("moe_router"):
+            order_p = jnp.pad(order, (0, -(n * k) % block))
+            starts = jnp.cumsum(group_sizes) - group_sizes
+            total = jnp.sum(group_sizes)
+
+        def one_block(i, combined):
+            lo = i * block
+            with _scope("moe_router"):
+                rows = jax.lax.dynamic_slice(order_p, (lo,), (block,))
+                sizes = (
+                    jnp.clip(starts + group_sizes - lo, 0, block)
+                    - jnp.clip(starts - lo, 0, block)
+                )
+                row_real = lo + jnp.arange(block) < total
+            src_tok, out = expert_rows(rows, sizes, row_real)
+            with _scope("moe_experts"):
+                return combined.at[src_tok].add(out)
+
+        combined = jax.lax.fori_loop(
+            0, (total + block - 1) // block, one_block,
+            jnp.zeros((n, d), jnp.float32),
         )
-        up = gdot(xs, layer["w_up"], group_sizes).astype(jnp.float32)
-        act = (gate * up).astype(x.dtype)
-        out = gdot(act, layer["w_down"], group_sizes)  # [n*k, d]
-
-        out = out.astype(jnp.float32) * topv.reshape(-1)[order][:, None]
-        if valid is not None:
-            # selected, not multiplied: megablox leaves these rows as it
-            # found them, and 0 x NaN in a padded row would reach real
-            # rows through attention's ``p @ V``
-            out = jnp.where(row_real[:, None], out, 0.0)
-        combined = jnp.zeros((n, d), jnp.float32).at[src_tok].add(out)
+    if zero_gate is not None:
+        with _scope("moe_zero"):
+            # the identity experts: their gates' sum times the token itself
+            combined = combined + zero_gate[:, None] * xf.astype(jnp.float32)
+    with _scope("moe_experts"):
+        if psum_axis is not None:
+            combined = jax.lax.psum(combined, psum_axis)
         return combined.reshape(b, s, d).astype(x.dtype)
 
 
@@ -1421,66 +1741,36 @@ def _moe_mlp_routed_ep(
     ``[E, d, f]`` expert stacks — the exact HBM blow-up expert parallelism
     exists to avoid. Here each shard holds ``E/tp`` whole experts
     (matching ``parallel/sharding.py``'s ``P('tp', None, None)`` layout)
-    and runs the sort + ragged-dot pipeline over its LOCAL experts only;
-    the per-token combine is a psum over ICI.
-
-    Static-shape trick: every shard processes all ``n*k`` (token, slot)
-    rows — rows routed to remote experts have their expert id clamped into
-    the local range and their gate weight zeroed, so their (wasted) FFN
-    output cancels exactly in the combine. That keeps shapes static with
-    no capacity factor and NO dropped tokens. Per-shard expert FLOPs are
-    ``n*k`` rows vs dense-EP's ``n*E/tp`` rows — a win whenever
-    ``k*tp < E`` (Qwen3-MoE 128/8 at tp=8: 2x), which is the condition
-    ``_moe_mlp`` auto-selects on.
+    and runs the single-shard dispatch told its own range (``held``: every
+    shard gates over ALL experts with the replicated router and its bias,
+    a place held by another shard is in no group here and adds nothing);
+    the per-token combine is a psum over ICI. Shapes stay static with no
+    capacity factor and NO dropped tokens; the grouped dots visit the
+    tiles of the shard's own rows alone. ``_moe_mlp`` selects this path
+    whenever ``k*tp < E`` (Qwen3-MoE 128/8 at tp=8).
     """
     from jax.sharding import PartitionSpec as P
 
-    tp = mesh.shape["tp"]
-    e_local = cfg.n_experts // tp
-    k = cfg.n_experts_per_tok
+    if not cfg.holds_every_expert:
+        raise ValueError(
+            "tp > 1 over a held range of the experts, or with zero "
+            "experts, is not run"
+        )
+    e_local = cfg.n_experts // mesh.shape["tp"]
     # Batch stays sharded over dp when the mesh has a dp axis (training);
     # activations are replicated across tp either way.
     batch_axis = "dp" if "dp" in mesh.shape else None
 
-    def body(router, w_gate, w_up, w_down, xs):
-        ep = jax.lax.axis_index("tp")
-        b, s, d = xs.shape
-        n = b * s
-        xf = xs.reshape(n, d)
-        # Same gating as every other dispatch (softmax over ALL experts —
-        # the router is replicated), then keep only this shard's experts.
-        topv, topi = _moe_gates({"router": router}, cfg, xf)
-        lo = ep * e_local
-        with _scope("moe_router"):
-            local = (topi >= lo) & (topi < lo + e_local)  # [n, k]
-            gate_w = jnp.where(local, topv, 0.0)
-            local_expert = jnp.clip(topi - lo, 0, e_local - 1)
-
-        with _scope("moe_router"):
-            expert_ids = local_expert.reshape(-1)  # [n*k]
-            token_ids = jnp.arange(n * k, dtype=jnp.int32) // k
-            order = jnp.argsort(expert_ids, stable=True)
-            src_tok = token_ids[order]
-            xg = xf[src_tok]  # [n*k, d] expert-contiguous
-            group_sizes = jnp.bincount(expert_ids, length=e_local)
-            sorted_ids = expert_ids[order]
-        with _scope("moe_experts"):
-            # QuantizedTensor expert shards flow into the gmm kernel as-is
-            # (specs are pytree prefixes, so q and scale both shard on E);
-            # the kernel dequantizes per-tile in VMEM.
-            gdot = _grouped_dot(cfg, sorted_ids, interpret)
-
-            gate = cfg.act_fn(
-                gdot(xg, w_gate, group_sizes).astype(jnp.float32)
-            )
-            up = gdot(xg, w_up, group_sizes).astype(jnp.float32)
-            act = (gate * up).astype(xs.dtype)
-            out = gdot(act, w_down, group_sizes)  # [n*k, d]
-
-            out = out.astype(jnp.float32) * gate_w.reshape(-1)[order][:, None]
-            combined = jnp.zeros((n, d), jnp.float32).at[src_tok].add(out)
-            combined = jax.lax.psum(combined, "tp")
-            return combined.reshape(b, s, d).astype(xs.dtype)
+    def body(gates, w_gate, w_up, w_down, xs):
+        # QuantizedTensor expert shards flow into the gmm kernel as-is
+        # (specs are pytree prefixes, so q and scale both shard on E);
+        # the kernel dequantizes per-tile in VMEM.
+        shard = {**gates, "w_gate": w_gate, "w_up": w_up, "w_down": w_down}
+        return _moe_mlp_routed(
+            shard, cfg, xs, interpret,
+            held=(jax.lax.axis_index("tp") * e_local, e_local),
+            psum_axis="tp",
+        )
 
     fn = jax.shard_map(
         body,
@@ -1495,7 +1785,8 @@ def _moe_mlp_routed_ep(
         out_specs=P(batch_axis),
         check_vma=False,
     )
-    return fn(layer["router"], layer["w_gate"], layer["w_up"], layer["w_down"], x)
+    gates = {k: layer[k] for k in ("router", "router_bias") if k in layer}
+    return fn(gates, layer["w_gate"], layer["w_up"], layer["w_down"], x)
 
 
 def _moe_mlp(
@@ -1508,6 +1799,11 @@ def _moe_mlp(
     # row, padding included, as they always have.
     if cfg.moe_dispatch not in ("routed", "dense"):
         raise ValueError(f"unknown moe_dispatch {cfg.moe_dispatch!r}")
+    if cfg.moe_dispatch == "dense" and not cfg.holds_every_expert:
+        raise ValueError(
+            'moe_dispatch="dense" scores every expert against every token: '
+            "a held range of the experts, or zero experts, takes \"routed\""
+        )
     tp = mesh.shape.get("tp", 1) if mesh is not None else 1
     if tp > 1:
         if cfg.n_experts % tp == 0:
@@ -1555,24 +1851,49 @@ def _mlp(
         return _swiglu(cfg, x, layer["w_gate"], layer["w_up"], layer["w_down"])
 
 
+def _sublayers(layers):
+    """The (attention, FFN) pairs of ``layers`` in the order they run: a
+    layer itself, then the ``second`` half of one that has it (a double
+    layer). What each is is still read from its own parameters."""
+    for layer in layers:
+        yield layer
+        if "second" in layer:
+            yield layer["second"]
+
+
 def _ffn(
     layer: Params, cfg: LlamaConfig, h: jnp.ndarray, mesh=None,
     interpret: bool = False, touched: Optional[list] = None,
-    valid: Optional[jnp.ndarray] = None,
+    valid: Optional[jnp.ndarray] = None, aside: Optional[list] = None,
 ) -> jnp.ndarray:
     """A layer's second half as every body runs it: ``h + _mlp(mlp_norm(h))``.
     The norm lies under the scope of what reads it (``model.moe_router``
     for a routed layer, else ``model.ffn``), the residual under that of what
-    it adds (``model.moe_experts``, else ``model.ffn``)."""
+    it adds (``model.moe_experts``, else ``model.ffn``).
+
+    ``aside`` (a list the body keeps over its layer loop) is the one piece
+    that carries a double layer's routed sum: a layer with a ``moe`` part
+    (the first half) also runs that routed FFN on its normed input and puts
+    the result there; the next FFN that has none (the second half) adds it
+    after its own: ``out = c + FFN_1(y) + MoE(x)``."""
     routed = "router" in layer
     with _scope("moe_router" if routed else "ffn"):
         x = rms_norm(h, layer["mlp_norm"], cfg.rms_norm_eps, cfg.norm_offset)
+    if "moe" in layer:
+        aside.append(_mlp(
+            layer["moe"], cfg, x, mesh=mesh, interpret=interpret,
+            touched=touched, valid=valid,
+        ))
     out = _mlp(
         layer, cfg, x, mesh=mesh, interpret=interpret, touched=touched,
         valid=valid,
     )
     with _scope("moe_experts" if routed else "ffn"):
-        return h + out
+        h = h + out
+    if aside and "moe" not in layer:
+        with _scope("moe_experts"):
+            h = h + aside.pop()
+    return h
 
 
 def _swiglu(cfg: LlamaConfig, x, w_gate, w_up, w_down) -> jnp.ndarray:
@@ -1806,7 +2127,8 @@ def _prefill_body(
     fresh_k = []  # per-layer [b, s, n_kv, hd] — written to pages in one go
     fresh_v = []
     fresh_state = []  # per conv layer [b, pages touched, state row]
-    for layer in params["layers"]:
+    aside = []  # a double layer's routed sum, from its first FFN to its second
+    for layer in _sublayers(params["layers"]):
         li = len(fresh_k)  # the layer's index in the key/value pools
         with _scope("conv" if "conv_in" in layer else "attn"):
             x = rms_norm(h, layer["attn_norm"], cfg.rms_norm_eps, cfg.norm_offset)
@@ -1869,7 +2191,7 @@ def _prefill_body(
             h = h + out
         h = _ffn(
             layer, cfg, h, mesh=mesh, interpret=interpret,
-            touched=experts_touched, valid=valid,
+            touched=experts_touched, valid=valid, aside=aside,
         )
 
     if fresh_state:
@@ -2116,7 +2438,8 @@ def _decode_body(
     fresh_k = []  # per-layer [b, 1, n_kv, hd]; written to pages in one go
     fresh_v = []
     fresh_state = []  # per conv layer [b, 1, state row]
-    for layer in params["layers"]:
+    aside = []  # a double layer's routed sum, from its first FFN to its second
+    for layer in _sublayers(params["layers"]):
         li = len(fresh_k)  # the layer's index in the key/value pools
         with _scope("conv" if "conv_in" in layer else "attn"):
             x = rms_norm(h, layer["attn_norm"], cfg.rms_norm_eps, cfg.norm_offset)
@@ -2172,7 +2495,7 @@ def _decode_body(
             h = h + out
         h = _ffn(
             layer, cfg, h, mesh=mesh, interpret=interpret,
-            touched=experts_touched,
+            touched=experts_touched, aside=aside,
         )
 
     if fresh_state:
@@ -2215,6 +2538,19 @@ def _decode_body(
 
 #: columns behind the block table in ``decode_steps``' packed operand
 DECODE_PACKED_TAIL = 5
+
+#: what a burst's leading columns count for a model whose routed layers are
+#: told what they hold (``burst_counts`` of them; else the first alone)
+BURST_COUNTS_HELD = ("experts_touched", "zero_places", "held_places")
+
+
+def burst_counts(cfg: LlamaConfig) -> int:
+    """Columns of counts before the tokens of a ``decode_steps`` burst: the
+    experts read, and for a model with zero experts or a held range of the
+    experts also the places that fell on zero experts and on held ones
+    (``BURST_COUNTS_HELD``; ``_moe_mlp_routed`` counts all three)."""
+    routed = cfg.n_experts and cfg.moe_dispatch == "routed"
+    return 1 if not routed or cfg.holds_every_expert else len(BURST_COUNTS_HELD)
 
 
 def pack_decode_inputs(
@@ -2327,7 +2663,9 @@ def decode_steps(
     num_steps]`` int32, k_pages, v_pages), then the scale pools where the
     pools are int8, then ``state_pages`` where one was given (carried
     through the scan as the pools are). ``burst[:, 1:]`` are the sampled
-    tokens; ``burst[:, 0]`` is, in every lane, the number of distinct
+    tokens (behind ``burst_counts(cfg)`` columns of counts: one for every
+    model but one whose routed layers are told what they hold, which has
+    ``BURST_COUNTS_HELD``); ``burst[:, 0]`` is, in every lane, the number of distinct
     experts the burst's rows chose, summed over the routed layers and the
     steps (what ``_moe_mlp_routed`` counts on the device: the experts whose
     weights the grouped matmuls read, padded lanes' rows included; 0 for a
@@ -2393,9 +2731,12 @@ def decode_steps(
         (
             _, _, _, k_pages, v_pages, k_scales, v_scales, state_pages
         ), (toks, n_touched) = jax.lax.scan(body, carry0, keys)
-        toks, n_touched = toks.T, jnp.sum(n_touched)
+        toks, n_touched = toks.T, jnp.sum(n_touched, axis=0)
+    # one column, or ``BURST_COUNTS_HELD`` of them (``burst_counts``)
+    n_touched = n_touched.reshape(1, -1)
     burst = jnp.concatenate(
-        [jnp.broadcast_to(n_touched, (toks.shape[0], 1)), toks], axis=1
+        [jnp.broadcast_to(n_touched, (toks.shape[0], n_touched.shape[1])),
+         toks], axis=1,
     )
     extra = (k_scales, v_scales) if quantized else ()
     if stateful:
@@ -2751,6 +3092,8 @@ def denoise_steps(
     fix = block_transfer(prob, masked, step, steps, threshold)
     fix = fix & active[:, None]
     n_touched = sum(touched, jnp.zeros((), jnp.int32))
+    if n_touched.ndim:  # a routed layer that counts its places too
+        n_touched = n_touched[0]
     packed = jnp.concatenate(
         [
             jnp.where(fix, candidate, tokens),

@@ -106,6 +106,8 @@ def quantize_params(
         for name, v in d.items():
             if name == "layers":
                 out[name] = [convert(layer) for layer in v]
+            elif isinstance(v, dict):  # a double layer's ``moe`` / ``second``
+                out[name] = convert(v)
             elif name in QUANTIZABLE and (
                 getattr(v, "ndim", 2) == 2 or quantize_experts
             ):

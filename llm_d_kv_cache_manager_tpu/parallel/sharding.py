@@ -51,6 +51,9 @@ def _layer_specs(
         # over heads. (The engine refuses tp > 1 for a latent pool.)
         del specs["wk"], specs["wv"]
         specs.update(wkv_a=P(), kv_norm=P(), wkv_b=P(None, "tp"))
+        if cfg.q_lora_rank:  # the low-rank query pair instead of ``wq``
+            del specs["wq"]
+            specs.update(wq_a=P(), q_a_norm=P(), wq_b=P(None, "tp"))
     if cfg.n_experts and routed:
         # MoE FFN: expert-parallel when the expert count divides the tp
         # axis (each device holds E/tp whole experts; the combine's
@@ -65,7 +68,7 @@ def _layer_specs(
             specs["w_gate"] = P(None, None, "tp")
             specs["w_up"] = P(None, None, "tp")
             specs["w_down"] = P(None, "tp", None)
-        if cfg.moe_scoring == "sigmoid":
+        if cfg.moe_scoring == "sigmoid" or cfg.moe_router_bias:
             specs["router_bias"] = P()
         if cfg.n_shared_experts:
             specs.update(
@@ -86,13 +89,26 @@ def _layer_specs(
     return specs
 
 
+def _double_layer_specs(cfg: LlamaConfig, tp: int) -> dict[str, Any]:
+    """A published double layer: two dense halves and, under ``moe``, the
+    routed FFN's own parameters (``init_params``' tree)."""
+    half = _layer_specs(cfg, tp, routed=False)
+    routed = _layer_specs(cfg, tp, routed=True)
+    return {
+        **half, "second": dict(half),
+        "moe": {k: v for k, v in routed.items() if k not in half
+                or k in ("w_gate", "w_up", "w_down")},
+    }
+
+
 def param_specs(cfg: LlamaConfig, tp: int = 1) -> dict[str, Any]:
     """PartitionSpec pytree matching ``init_params``' structure."""
     specs: dict[str, Any] = {
         "embed": P("tp", None),  # vocab-sharded; gather rides ICI
         "final_norm": P(),
         "layers": [
-            _layer_specs(
+            _double_layer_specs(cfg, tp) if cfg.double_layer
+            else _layer_specs(
                 cfg, tp, routed=i >= cfg.first_k_dense,
                 conv=cfg.layer_kind(i) == "conv",
             )

@@ -528,8 +528,6 @@ class Engine:
                     config.spec_decode != "off",
                 "block_length > 0 (no block mask in the latent kernel)":
                     cfg.block_length > 0,
-                "q_lora_rank (a low-rank query path is not run)":
-                    bool(cfg.q_lora_rank),
                 "n_group, topk_group > 1 (group-limited routing is not run)":
                     cfg.n_group != 1 or cfg.topk_group != 1,
             }
@@ -538,6 +536,27 @@ class Engine:
                     raise ValueError(
                         f"kv_lora_rank={cfg.kv_lora_rank} (a latent KV "
                         f"pool) is incompatible with {what}"
+                    )
+        if cfg.n_experts and not cfg.holds_every_expert:
+            # Routed layers that are told what they hold (one rank's share
+            # of the experts, zero-compute experts among the router's
+            # outputs): the routed dispatch alone leaves out what is held
+            # elsewhere and adds the zero experts' part.
+            first, count = cfg.expert_first, cfg.experts_held
+            refused = {
+                'moe_dispatch="dense" (the masked-dense oracle scores every '
+                "expert against every token)": cfg.moe_dispatch == "dense",
+                "tp > 1 (a held range is not sharded again)": config.tp > 1,
+                f"n_experts={cfg.n_experts} (the held range lies past the "
+                "experts the router scores)":
+                    first < 0 or count < 1 or first + count > cfg.n_experts,
+            }
+            for what, on in refused.items():
+                if on:
+                    raise ValueError(
+                        f"experts {first}..{first + count - 1} held, "
+                        f"{cfg.n_zero_experts} zero experts: incompatible "
+                        f"with {what}"
                     )
         if cfg.n_conv_layers:
             # Convolution layers keep state beside the keys and values of
@@ -673,8 +692,12 @@ class Engine:
         #: states the number beside ``kv_bytes_per_token`` and no reader
         #: keeps a table of which layers are dense
         self.routed_layers = sum(
-            "router" in layer for layer in self.params["layers"]
+            "router" in layer or "moe" in layer
+            for layer in self.params["layers"]
         )
+        #: columns of counts before a fused burst's tokens
+        #: (``llama.burst_counts``: one, or ``llama.BURST_COUNTS_HELD``)
+        self._burst_counts = llama.burst_counts(cfg)
         # Scale pools ride alongside the int8 page pools (None when the
         # knob is off — every scale-threading call site keys off this).
         self.k_scales: Optional[jnp.ndarray] = None
@@ -897,7 +920,15 @@ class Engine:
         #: the device in ``llama._moe_mlp_routed`` and fetched with the
         #: tokens, first column of a fused burst (``_commit_burst``), last
         #: of a block dispatch; 0 for a model without routed layers, and
-        #: the speculative path does not count it). Block diffusion
+        #: the speculative path does not count it), and beside it, from the
+        #: same fetch, ``routed_places`` (rows x top-k x ``routed_layers``
+        #: of the fused bursts' forwards, padded lanes' rows included),
+        #: ``zero_places`` (those that fell on zero-compute experts) and
+        #: ``held_places`` (on experts this process holds: the rows its
+        #: grouped matmuls computed); the last two are counted on the
+        #: device for a model whose routed layers are told what they hold
+        #: (``llama.burst_counts``) and stay 0 for every other, whose
+        #: ``held_places`` is ``routed_places``. Block diffusion
         #: (``_run_decode_block``, beside those):
         #: ``denoise_lane_forwards`` (lanes x dispatches in which the lane
         #: had a masked row), ``commit_lane_forwards`` (in which it had none:
@@ -925,6 +956,9 @@ class Engine:
             "block_tokens_fixed": 0,
             "blocks_final": 0,
             "experts_touched": 0,
+            "routed_places": 0,
+            "zero_places": 0,
+            "held_places": 0,
             "latent_ctx_tokens": 0,
             "attn_ctx_tokens": 0,
             "prefill_s": 0.0,
@@ -2551,9 +2585,9 @@ class Engine:
                 seq_lens = np.where(was_active, prev["seq_lens"] + k, 0)
             else:
                 # The program takes its input ids from the last column of a
-                # burst's [lanes, 1 + k] output; an unchained dispatch hands
-                # it the same shape, so both are one compiled program.
-                tokens = np.zeros((lanes, 1 + k), np.int32)
+                # burst's [lanes, counts + k] output; an unchained dispatch
+                # hands it the same shape, so both are one compiled program.
+                tokens = np.zeros((lanes, self._burst_counts + k), np.int32)
                 for i, seq in enumerate(active):
                     tokens[i, -1] = seq.last_token
                     positions[i] = seq.num_tokens - 1
@@ -3009,9 +3043,18 @@ class Engine:
             # [lanes, 1 + k]: the experts the burst read, then its tokens
             fetched = np.asarray(burst["toks"])
         with self.phase("decode_commit"):
-            toks = fetched[:, 1:]
+            toks = fetched[:, self._burst_counts:]
             if self.obs_step_timing:
-                self.step_stats["experts_touched"] += int(fetched[0, 0])
+                counts = fetched[0, : self._burst_counts]
+                for name, count in zip(llama.BURST_COUNTS_HELD, counts):
+                    self.step_stats[name] += int(count)
+                if self.routed_layers:
+                    # every place a routed layer's rows took (padded
+                    # lanes' included, as the device counts them)
+                    self.step_stats["routed_places"] += (
+                        fetched.shape[0] * burst["k"] * self.routed_layers
+                        * self.model_cfg.n_experts_per_tok
+                    )
             for i, seq in enumerate(burst["active"]):
                 if not seq.block_table:
                     continue  # preempted after this burst was dispatched

@@ -353,6 +353,20 @@ class _ServingMetrics:
                 ),
                 0,
             )
+            self.engine_places = prom.Counter(
+                "kvcache_engine_routed_places_total",
+                "Places (a row's top-k choices) the routed layers of the "
+                "fused decode forwards took, by kind: routed (every one: "
+                "rows x top-k x routed layers), zero (on zero-compute "
+                "experts), held (on experts this process holds: the rows "
+                "its grouped matmuls computed); zero and held are counted "
+                "on the device for a model whose routed layers are told "
+                "what they hold (/stats' experts_held, zero_experts)",
+                ["kind"], registry=self.registry,
+            )
+            self._places_seen = dict.fromkeys(
+                ("routed_places", "zero_places", "held_places"), 0
+            )
             self.engine_latent_ctx = prom.Counter(
                 "kvcache_engine_latent_ctx_tokens_total",
                 "Context rows the decode dispatches of a latent (MLA) pool "
@@ -616,6 +630,11 @@ class _ServingMetrics:
             if delta > 0:
                 self.engine_block.labels(count=key).inc(delta)
                 self._block_seen[key] = step_stats[key]
+        for key, seen in self._places_seen.items():
+            delta = step_stats.get(key, 0) - seen
+            if delta > 0:
+                self.engine_places.labels(kind=key[:-7]).inc(delta)
+                self._places_seen[key] = step_stats[key]
         latent = step_stats.get("latent_ctx_tokens", 0)
         if latent > self._latent_ctx_seen:
             self.engine_latent_ctx.inc(latent - self._latent_ctx_seen)
@@ -3700,6 +3719,8 @@ class PodServer:
                 "kv_bytes_per_token": self.engine.kv_bytes_per_token,
                 "state_bytes_per_token": self.engine.state_bytes_per_token,
                 "routed_layers": self.engine.routed_layers,
+                "experts_held": self.engine.model_cfg.experts_held,
+                "zero_experts": self.engine.model_cfg.n_zero_experts,
                 "prefill": dict(self.engine.prefill_stats),
                 "transfer": {
                     **self.engine.transfer_stats,
@@ -4067,6 +4088,8 @@ def _resolve_model(name: str) -> LlamaConfig:
         "tiny-mla-moe": models.TINY_MLA_MOE,
         "LiquidAI/LFM2-8B-A1B": models.LFM2_8B_A1B,
         "tiny-lfm2-moe": models.TINY_LFM2_MOE,
+        "meituan-longcat/LongCat-Flash-Omni": models.LONGCAT_FLASH_OMNI,
+        "tiny-scmoe": models.TINY_SCMOE,
     }
     if name in presets:
         return presets[name]

@@ -379,7 +379,6 @@ def test_a_shared_document_hits_the_prefix_cache(params, prefill_attn):
     (dict(tp=2), "tp > 1"),
     (dict(spec_decode="prompt_lookup"), "spec_decode"),
     (dict(model=dataclasses.replace(CFG, block_length=4)), "block_length"),
-    (dict(model=dataclasses.replace(CFG, q_lora_rank=16)), "q_lora_rank"),
     (dict(model=dataclasses.replace(CFG, n_group=2, topk_group=2)), "n_group"),
 ])
 def test_engine_refuses_by_name(what, name):
@@ -389,6 +388,22 @@ def test_engine_refuses_by_name(what, name):
     config = dataclasses.replace(config, **what)
     with pytest.raises(ValueError, match="kv_lora_rank.*" + name):
         Engine(config)
+
+
+def test_a_low_rank_query_path_runs():
+    """What the engine refused until PR 41 (``q_lora_rank``): the same
+    model with the query through a latent of 16 is served, and is another
+    model than the full-rank one (``tests/test_scmoe.py`` holds the path to
+    its reference)."""
+    cfg = dataclasses.replace(CFG, q_lora_rank=16)
+    params = llama.init_params(jax.random.PRNGKey(11), cfg)
+    layer = params["layers"][1]
+    assert "wq" not in layer and layer["wq_a"].shape == (64, 16)
+    assert layer["wq_b"].shape == (16, 4 * 24) and layer["q_a_norm"].shape == (16,)
+    ask = prompt_of(31, 21)
+    seq = run_all(make_engine(params, cfg=cfg), [ask])[0]
+    alone, _ = served(params, [(ask, 8)], 5, "xla", cfg=cfg)
+    assert seq.generated_tokens == alone[0].argmax(-1).tolist()
 
 
 def test_page_export_and_import_are_refused_by_name(params):
@@ -454,8 +469,49 @@ def test_the_loader_reads_the_published_config():
     assert config_from_hf(_KananaConfig()) == KANANA_2_30B_A3B
 
 
+def test_the_loader_reads_a_low_rank_query_path():
+    """What the loader refused until PR 41: ``q_lora_rank`` is read, and a
+    state dict with ``q_a_proj`` / ``q_a_layernorm`` / ``q_b_proj`` loads to
+    the tree the program runs."""
+    from llm_d_kv_cache_manager_tpu.models.hf_loader import (
+        config_from_hf,
+        load_hf_state_dict,
+    )
+
+    hf = _KananaConfig()
+    hf.q_lora_rank = 1536
+    assert config_from_hf(hf) == dataclasses.replace(
+        KANANA_2_30B_A3B, q_lora_rank=1536)
+    cfg = dataclasses.replace(
+        CFG, q_lora_rank=16, n_layers=1, first_k_dense=1)
+    params = llama.init_params(jax.random.PRNGKey(2), cfg)
+    (layer,) = params["layers"]
+    names = {
+        "attn_norm": "input_layernorm.weight",
+        "mlp_norm": "post_attention_layernorm.weight",
+        "wq_a": "self_attn.q_a_proj.weight",
+        "q_a_norm": "self_attn.q_a_layernorm.weight",
+        "wq_b": "self_attn.q_b_proj.weight",
+        "wkv_a": "self_attn.kv_a_proj_with_mqa.weight",
+        "kv_norm": "self_attn.kv_a_layernorm.weight",
+        "wkv_b": "self_attn.kv_b_proj.weight", "wo": "self_attn.o_proj.weight",
+        "w_gate": "mlp.gate_proj.weight", "w_up": "mlp.up_proj.weight",
+        "w_down": "mlp.down_proj.weight",
+    }
+    assert set(names) == set(layer)
+    sd = {"model.embed_tokens.weight": params["embed"],
+          "model.norm.weight": params["final_norm"],
+          "lm_head.weight": params["lm_head"].T}
+    for ours, theirs in names.items():
+        w = np.asarray(layer[ours])
+        sd["model.layers.0." + theirs] = w.T if w.ndim == 2 else w
+    loaded = load_hf_state_dict(sd, cfg)
+    assert jax.tree.structure(loaded) == jax.tree.structure(params)
+    for a, b in zip(jax.tree.leaves(loaded), jax.tree.leaves(params)):
+        assert a.dtype == b.dtype and np.array_equal(a, b)
+
+
 @pytest.mark.parametrize("change, name", [
-    (dict(q_lora_rank=1536), "q_lora_rank"),
     (dict(n_group=8, topk_group=4), "group-limited"),
     (dict(scoring_func="softmax"), "scoring_func"),
     (dict(topk_method="greedy"), "topk_method"),

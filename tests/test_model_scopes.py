@@ -20,6 +20,7 @@ from llm_d_kv_cache_manager_tpu.models import (
     TINY_LLAMA,
     TINY_MLA_MOE,
     TINY_QWEN3_MOE,
+    TINY_SCMOE,
     TINY_SDAR_MOE,
     llama,
 )
@@ -41,6 +42,9 @@ CONFIGS = {
     "latent": (TINY_MLA_MOE, EVERY | ROUTED),
     "conv": (TINY_LFM2_MOE, EVERY | ROUTED - {"moe_shared"} | {"conv"}),
     "blocks": (TINY_SDAR_MOE, EVERY | {"moe_router", "moe_experts"}),
+    # the double layer: both attentions with the query's two matmuls under
+    # ``attn``, both dense FFNs under ``ffn``, the identity experts' own
+    "double": (TINY_SCMOE, EVERY | ROUTED - {"moe_shared"} | {"moe_zero"}),
 }
 
 
@@ -95,7 +99,7 @@ def _lowered_scopes(cfg, program) -> frozenset:
 
 CASES = [
     (kind, program)
-    for kind in ("dense", "routed", "latent", "conv")
+    for kind in ("dense", "routed", "latent", "conv", "double")
     for program in ("decode_steps", "prefill")
 ] + [("blocks", "prefill"), ("blocks", "denoise_steps")]
 

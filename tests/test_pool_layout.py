@@ -409,6 +409,8 @@ _SERVED = [
     ("kanana-2-30b-a3b", "prefill"),
     ("lfm2-8b-a1b", "decode_steps"),
     ("lfm2-8b-a1b", "prefill"),
+    ("longcat-flash-omni", "decode_steps"),
+    ("longcat-flash-omni", "prefill"),
     ("qwen3-30b-a3b", "decode_steps"),
     ("qwen3-30b-a3b", "prefill"),
     ("qwen3-32b", "decode_steps"),

@@ -602,7 +602,7 @@ class TestKnobsOffParity:
                 "pod", "model", "data_parallel_rank", "staged", "waiting",
                 "running", "free_pages", "total_pages", "kv_bytes_per_token",
                 "state_bytes_per_token", "routed_layers",
-                "prefill",
+                "experts_held", "zero_experts", "prefill",
                 "transfer", "self_heal", "admission", "drain",
             }
 
