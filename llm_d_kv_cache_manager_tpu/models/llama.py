@@ -2061,20 +2061,57 @@ def _prefill_body(
     experts_touched: Optional[list] = None,  # see ``_moe_mlp_routed``
     state_pages=None,  # ``init_state_pages``: a model with conv layers
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, Any, Any, Any]:
-    """Traced prefill layer loop shared by ``prefill`` and the fused
-    speculative-decode scan (``spec_decode_steps``): chunk forward with
-    paged-context attention + one batched KV scatter. Returns (hidden
-    states [b, s, d], k_pages, v_pages, k_scales, v_scales, state_pages);
-    logits selection stays with the caller. A convolution layer (one that
+    """Traced prefill shared by ``prefill``, the fused speculative-decode
+    scan (``spec_decode_steps``) and ``_denoise_body``: the chunk's forward
+    over the paged context (``_prefill_forward``), then its one write of
+    the pools (``_prefill_write``). Returns (hidden states [b, s, d],
+    k_pages, v_pages, k_scales, v_scales, state_pages); logits selection
+    stays with the caller."""
+    h, fresh = _prefill_forward(
+        params, cfg, tokens, positions, valid, k_pages, v_pages, page_ids,
+        block_tables, ctx_lens, mesh, attn_impl, interpret, k_scales,
+        v_scales, experts_touched, state_pages,
+    )
+    return (h,) + _prefill_write(
+        fresh, positions, valid, k_pages, v_pages, page_ids, slot_ids,
+        ctx_lens, k_scales, v_scales, state_pages,
+    )
+
+
+def _prefill_forward(
+    params: Params,
+    cfg: LlamaConfig,
+    tokens: jnp.ndarray,
+    positions: jnp.ndarray,
+    valid: jnp.ndarray,
+    k_pages: jnp.ndarray,
+    v_pages: jnp.ndarray,
+    page_ids: jnp.ndarray,
+    block_tables: jnp.ndarray,
+    ctx_lens: jnp.ndarray,
+    mesh,
+    attn_impl: str,
+    interpret: bool,
+    k_scales,
+    v_scales,
+    experts_touched: Optional[list],
+    state_pages,
+) -> tuple[jnp.ndarray, tuple]:
+    """The layer loop of ``_prefill_body`` (whose operands these are): it
+    reads the pools and writes none. Returns (hidden states [b, s, d], what
+    ``_prefill_write`` puts into the pools: the layers' keys ``[L, b, s,
+    ...]``, their values (None for a latent pool) and the convolution
+    layers' states ``[conv layers, b, pages touched, state row]``, each
+    None where the model has none). A convolution layer (one that
     has ``conv_in``) takes its state before the chunk from the slot of the
     page that holds the token before ``ctx_lens`` (``page_ids[:, 0]`` where
     the chunk starts inside a page, else the last page of ``block_tables``'
     context; zeros at position 0) and leaves, in one write after the loop,
     the state after the last valid token of every page the chunk touches.
-    Scales are None (and pass through untouched) unless the pools are int8
-    (``KV_QUANT_HBM``), in which case the scatter quantizes at write time and the paged-context gather
-    dequantizes chunk-locally — the engine restricts the quantized path
-    to the ``xla`` single-shard prefill.
+    Scales are None unless the pools are int8 (``KV_QUANT_HBM``), in which
+    case the write quantizes and the paged-context gather dequantizes
+    chunk-locally — the engine restricts the quantized path to the ``xla``
+    single-shard prefill.
 
     ``cfg.block_length`` > 1 makes the chunk block-causal (full inside a
     block of that many absolute positions). Callers start the chunk on a
@@ -2114,9 +2151,7 @@ def _prefill_body(
             jnp.where(ctx_lens % page_size != 0, page_ids[:, 0], before),
             ctx_lens > 0,
         )
-        last, state_page, state_ok = _conv_state_plan(
-            ctx_lens, n_valid, page_ids, page_size
-        )
+        last, _, _ = _conv_state_plan(ctx_lens, n_valid, page_ids, page_size)
         # rows of ``z`` (the state before the chunk, then the chunk) that
         # are the state after chunk index ``last``
         state_rows = (
@@ -2194,36 +2229,61 @@ def _prefill_body(
             touched=experts_touched, valid=valid, aside=aside,
         )
 
-    if fresh_state:
-        state_pages = _scatter_state_pages(
-            state_pages, jnp.stack(fresh_state), state_page, state_ok
+    return h, (
+        jnp.stack(fresh_k) if fresh_k else None,
+        jnp.stack(fresh_v) if fresh_k and not latent else None,
+        jnp.stack(fresh_state) if fresh_state else None,
+    )
+
+
+def _prefill_write(
+    fresh: tuple,  # ``_prefill_forward``'s
+    positions: jnp.ndarray,
+    valid: jnp.ndarray,
+    k_pages: jnp.ndarray,
+    v_pages: jnp.ndarray,
+    page_ids: jnp.ndarray,
+    slot_ids: jnp.ndarray,
+    ctx_lens: jnp.ndarray,
+    k_scales,
+    v_scales,
+    state_pages,
+) -> tuple[jnp.ndarray, jnp.ndarray, Any, Any, Any]:
+    """What a chunk leaves in the (donated) pools, each in one scatter over
+    all its layers: (k_pages, v_pages, k_scales, v_scales, state_pages). A
+    pool ``fresh`` has nothing for (a latent model's second, a tree of
+    convolution layers alone) passes through untouched, as do the scales
+    unless the pools are int8."""
+    fresh_k, fresh_v, fresh_state = fresh
+    if fresh_state is not None:
+        _, state_page, state_ok = _conv_state_plan(
+            ctx_lens, jnp.sum(valid.astype(jnp.int32), axis=1), page_ids,
+            k_pages.shape[2],
         )
-    if not fresh_k:  # a tree of convolution layers alone: no key or value
-        return h, k_pages, v_pages, k_scales, v_scales, state_pages
+        state_pages = _scatter_state_pages(
+            state_pages, fresh_state, state_page, state_ok
+        )
     # One batched scatter over all layers into the donated pools. In-chunk
     # attention never reads these pages (fresh K/V ride function arguments),
     # so deferring the writes is exact — and a single aliased update avoids
     # the full pool copy a per-layer rebuild costs.
     if k_scales is not None:
         k_pages, k_scales = _quantized_scatter_kv_all_layers(
-            k_pages, k_scales, jnp.stack(fresh_k), page_ids, slot_ids,
-            valid, positions,
+            k_pages, k_scales, fresh_k, page_ids, slot_ids, valid, positions,
         )
         v_pages, v_scales = _quantized_scatter_kv_all_layers(
-            v_pages, v_scales, jnp.stack(fresh_v), page_ids, slot_ids,
-            valid, positions,
+            v_pages, v_scales, fresh_v, page_ids, slot_ids, valid, positions,
         )
-    else:
+        return k_pages, v_pages, k_scales, v_scales, state_pages
+    if fresh_k is not None:
         k_pages = _scatter_kv_pages_all_layers(
-            k_pages, jnp.stack(fresh_k).astype(k_pages.dtype), page_ids,
-            slot_ids, valid
+            k_pages, fresh_k.astype(k_pages.dtype), page_ids, slot_ids, valid
         )
-        if not latent:  # a latent pool is the one array
-            v_pages = _scatter_kv_pages_all_layers(
-                v_pages, jnp.stack(fresh_v).astype(v_pages.dtype), page_ids,
-                slot_ids, valid
-            )
-    return h, k_pages, v_pages, k_scales, v_scales, state_pages
+    if fresh_v is not None:
+        v_pages = _scatter_kv_pages_all_layers(
+            v_pages, fresh_v.astype(v_pages.dtype), page_ids, slot_ids, valid
+        )
+    return k_pages, v_pages, k_scales, v_scales, state_pages
 
 
 @functools.partial(
@@ -2279,12 +2339,32 @@ def prefill(
     call of a given shape (or call ``prefill.clear_cache()``) — flipping it
     after a shape is compiled has no effect on that cached trace.
     """
+    _check_prefill(mesh, attn_impl, valid, k_scales, v_scales)
+    h, *pools = _prefill_body(
+        params, cfg, tokens, positions, valid, k_pages, v_pages,
+        page_ids, slot_ids, block_tables, ctx_lens, mesh, attn_impl,
+        interpret, k_scales, v_scales, state_pages=state_pages,
+    )
+    if return_all_logits:
+        # Every chunk position's next-token logits [b, s, vocab] — the
+        # speculative-decode verify step scores all k+1 proposed tokens in
+        # this one dispatch (chunks there are tiny, so the full-position
+        # lm_head stays cheap).
+        logits = _logits(params, cfg, h)
+    else:
+        logits = _last_logits(params, cfg, h, valid)
+    return (logits,) + _prefill_results(pools, k_scales, state_pages)
+
+
+def _check_prefill(mesh, attn_impl: str, valid, k_scales, v_scales) -> None:
+    """What ``prefill`` and ``prefill_packed`` refuse, and the mask check
+    that ``LLMD_CHECK_PREFILL_MASK`` asks for (see ``prefill``)."""
     if attn_impl not in ("xla", "pallas"):
         raise ValueError(f"unknown attn_impl {attn_impl!r}")
     sp = mesh.shape.get("sp", 1) if mesh is not None else 1
-    if sp > 1 and tokens.shape[1] % sp != 0:
+    if sp > 1 and valid.shape[1] % sp != 0:
         raise ValueError(
-            f"chunk length {tokens.shape[1]} not divisible by sp={sp}"
+            f"chunk length {valid.shape[1]} not divisible by sp={sp}"
         )
     if attn_impl == "pallas" and os.environ.get("LLMD_CHECK_PREFILL_MASK"):
         n_valid = jnp.sum(valid.astype(jnp.int32), axis=1)
@@ -2294,33 +2374,31 @@ def prefill(
         )
     if (k_scales is None) != (v_scales is None):
         raise ValueError("k_scales and v_scales must be passed together")
-    quantized = k_scales is not None
-    if quantized and (sp > 1 or attn_impl == "pallas"):
+    if k_scales is not None and (sp > 1 or attn_impl == "pallas"):
         raise ValueError(
             "KV_QUANT_HBM prefill requires the xla single-shard path"
         )
-    stateful = state_pages is not None
-    h, k_pages, v_pages, k_scales, v_scales, state_pages = _prefill_body(
-        params, cfg, tokens, positions, valid, k_pages, v_pages,
-        page_ids, slot_ids, block_tables, ctx_lens, mesh, attn_impl,
-        interpret, k_scales, v_scales, state_pages=state_pages,
-    )
 
-    # Knob-off callers keep the legacy 3-tuple; quantized callers get the
-    # updated scale pools appended, a model with state its state pool.
-    extra = (k_scales, v_scales) if quantized else ()
-    if stateful:
-        extra += (state_pages,)
-    if return_all_logits:
-        # Every chunk position's next-token logits [b, s, vocab] — the
-        # speculative-decode verify step scores all k+1 proposed tokens in
-        # this one dispatch (chunks there are tiny, so the full-position
-        # lm_head stays cheap).
-        return (_logits(params, cfg, h), k_pages, v_pages) + extra
-    # Logits at each sequence's last valid position.
+
+def _last_logits(params: Params, cfg: LlamaConfig, h, valid) -> jnp.ndarray:
+    """Logits [b, vocab] at each sequence's last valid position."""
     last_idx = jnp.maximum(jnp.sum(valid.astype(jnp.int32), axis=1) - 1, 0)  # [b]
     h_last = jnp.take_along_axis(h, last_idx[:, None, None], axis=1)[:, 0]  # [b, d]
-    return (_logits(params, cfg, h_last[:, None, :])[:, 0], k_pages, v_pages) + extra
+    return _logits(params, cfg, h_last[:, None, :])[:, 0]
+
+
+def _prefill_results(pools, k_scales, state_pages) -> tuple:
+    """``_prefill_write``'s five as ``prefill`` returns them: knob-off
+    callers keep the legacy (k_pages, v_pages); quantized callers get the
+    updated scale pools appended, a model with state its state pool
+    (``k_scales`` / ``state_pages``: the caller's own, None without)."""
+    k_pages, v_pages, new_k_scales, new_v_scales, new_state = pools
+    out = (k_pages, v_pages)
+    if k_scales is not None:
+        out += (new_k_scales, new_v_scales)
+    if state_pages is not None:
+        out += (new_state,)
+    return out
 
 
 def pack_prefill_inputs(
@@ -2335,6 +2413,38 @@ def pack_prefill_inputs(
          np.asarray(ctx_lens)[:, None]],
         axis=1, dtype=np.int32,
     )
+
+
+@functools.partial(
+    jax.jit, static_argnames=("cfg", "mesh", "attn_impl", "interpret")
+)
+def _prefill_rows(
+    params: Params,
+    cfg: LlamaConfig,
+    rows: tuple,  # (tokens, positions, valid, page_ids, block_tables, ctx_lens)
+    k_pages,
+    v_pages,
+    k_scales,
+    v_scales,
+    state_pages,
+    *,
+    mesh,
+    attn_impl: str,
+    interpret: bool,
+) -> tuple[jnp.ndarray, tuple]:
+    """``prefill_packed``'s forward over some rows of its operand: (their
+    last-position logits ``[1, rows, vocab]``, ``_prefill_forward``'s fresh
+    keys, values and states), every array with its rows on the SECOND axis.
+    It reads the pools and writes none. A jit of its own so that
+    ``prefill_packed`` traces it ONCE for the shapes of its results and for
+    its loop's body."""
+    tokens, positions, valid, page_ids, block_tables, ctx_lens = rows
+    h, fresh = _prefill_forward(
+        params, cfg, tokens, positions, valid, k_pages, v_pages, page_ids,
+        block_tables, ctx_lens, mesh, attn_impl, interpret, k_scales,
+        v_scales, None, state_pages,
+    )
+    return _last_logits(params, cfg, h, valid)[None], fresh
 
 
 @functools.partial(
@@ -2359,20 +2469,64 @@ def prefill_packed(
     interpret: bool = False,
     state_pages=None,
 ) -> tuple[jnp.ndarray, ...]:
-    """``prefill`` as the engine dispatches it: the same program (the one
-    ``_prefill_body``, last-position logits) behind one packed operand that
-    is sliced apart here. ``chunk`` and the operand's width are the two
-    buckets that key ``prefill``, so the set of programs is the same."""
+    """``prefill`` as the engine dispatches it: the same forward, logits
+    and write behind one packed operand that is sliced apart here. ``chunk``
+    and the operand's width are the two buckets that key ``prefill``, so the
+    set of programs is the same.
+
+    The program holds the forward (``_prefill_forward``, last-position
+    logits) for ONE row of the bucketed width, inside a loop that runs as
+    many times as the rows reach that hold a sequence, which it reads from
+    ``packed`` on the device (the engine fills rows from 0): a dispatch of
+    one sequence computes one row, not the operand's ``b``, and the program
+    is no larger than the one body of ``b`` rows was (a program that holds a
+    body a row count loads as many times slower from the compile cache:
+    ``PERF.md`` section 6, PR 42). Each turn reads the weights again, which
+    a row of a few hundred tokens pays for and a full operand of short rows
+    would not. A turn READS the pools and leaves its row's logits, keys,
+    values and states in arrays of ``b`` rows (zero in the rows no turn
+    computed); the one write of the donated pools comes after the loop,
+    over ``b`` rows with their invalid slots masked as ever. Under a mesh
+    the program is the one body of ``b`` rows."""
+    b = packed.shape[0]
     tokens, positions, valid, page_ids, slot_ids = (
         packed[:, i * chunk : (i + 1) * chunk] for i in range(5)
     )
-    # the function ``prefill`` jits, traced into this program
-    return prefill.__wrapped__(
-        params, cfg, tokens, positions, valid != 0, k_pages, v_pages,
-        page_ids, slot_ids, packed[:, 5 * chunk : -1], packed[:, -1],
-        mesh=mesh, attn_impl=attn_impl, k_scales=k_scales, v_scales=v_scales,
-        interpret=interpret, state_pages=state_pages,
+    valid, ctx_lens = valid != 0, packed[:, -1]
+    _check_prefill(mesh, attn_impl, valid, k_scales, v_scales)
+    rows = (tokens, positions, valid, page_ids, packed[:, 5 * chunk : -1], ctx_lens)
+    forward = functools.partial(
+        _prefill_rows, params, cfg, k_pages=k_pages, v_pages=v_pages,
+        k_scales=k_scales, v_scales=v_scales, state_pages=state_pages,
+        mesh=mesh, attn_impl=attn_impl, interpret=interpret,
     )
+    if mesh is not None or b == 1:
+        logits, fresh = forward(rows)
+    else:
+        def row(i):
+            return tuple(jax.lax.dynamic_slice_in_dim(x, i, 1) for x in rows)
+
+        def turn(i, out):
+            return jax.tree.map(
+                lambda whole, one: jax.lax.dynamic_update_slice_in_dim(
+                    whole, one, i, 1),
+                out, forward(row(i)),
+            )
+
+        held = jnp.any(valid, axis=1)
+        logits, fresh = jax.lax.fori_loop(
+            0, jnp.max(jnp.where(held, jnp.arange(1, b + 1), 0)), turn,
+            jax.tree.map(
+                lambda one: jnp.zeros(
+                    (one.shape[0], b) + one.shape[2:], one.dtype),
+                jax.eval_shape(forward, row(0)),
+            ),
+        )
+    pools = _prefill_write(
+        fresh, positions, valid, k_pages, v_pages, page_ids, slot_ids,
+        ctx_lens, k_scales, v_scales, state_pages,
+    )
+    return (logits[0],) + _prefill_results(pools, k_scales, state_pages)
 
 
 def _decode_body(

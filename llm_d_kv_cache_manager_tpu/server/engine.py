@@ -795,9 +795,10 @@ class Engine:
         #: prefill observability: tokens actually pushed through prefill
         #: dispatches (the FLOP proxy — prefix-cache hits and imported
         #: blocks reduce it), dispatch count, and the token slots those
-        #: dispatches computed (rows x bucketed width, padding included:
-        #: ``tokens_computed / token_slots`` is how full a dispatch is; the
-        #: rest is the padding the routed FFN leaves out of its groups).
+        #: dispatches computed (rows the program computed x bucketed
+        #: width, padding included: ``tokens_computed / token_slots`` is how
+        #: full a dispatch is; the rest is the width's bucket and the
+        #: shorter rows' tails).
         self.prefill_stats = {
             "tokens_computed": 0, "dispatches": 0, "token_slots": 0,
         }
@@ -2363,7 +2364,13 @@ class Engine:
             )
             self.prefill_stats["tokens_computed"] += int(valid.sum())
             self.prefill_stats["dispatches"] += 1
-            self.prefill_stats["token_slots"] += b * chunk
+            # the rows the program computed (``llama.prefill_packed`` reads
+            # the same mask on the device): as far as the last that holds a
+            # sequence; under a mesh the one body of all ``b``
+            held = int(np.flatnonzero(valid.any(axis=1))[-1]) + 1
+            self.prefill_stats["token_slots"] += chunk * (
+                held if self.mesh is None else b
+            )
             now = time.monotonic()
             finals = [
                 seq for seq, n in zip(seqs, chunks) if n >= seq.prompt_remaining

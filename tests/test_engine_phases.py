@@ -501,3 +501,27 @@ def test_the_serving_loop_times_what_lies_between_two_steps():
     total = sum(st[f"{p}_s"] for p in STEP_PHASES)
     assert total <= wall * 1.02
     assert st["loop_s"] < 0.25  # the 0.3 s of idleness is in no phase
+
+
+# -- what a prefill dispatch counts --------------------------------------------
+@pytest.mark.parametrize("n_seqs, rows, tp", [
+    (1, 1, 1), (2, 2, 1), (3, 3, 1), (5, 5, 1), (8, 8, 1), (1, 8, 2),
+], ids=["1", "2", "3", "5", "8", "tp2:1->8"])
+def test_a_prefill_dispatch_counts_the_slots_of_the_rows_it_computed(n_seqs, rows, tp):
+    """``token_slots`` grows by the rows ``llama.prefill_packed`` computed
+    (as many as hold an admitted sequence; under a mesh the one body of the
+    operand's 8) times the bucketed width; ``tokens_computed`` and
+    ``dispatches`` are what they were."""
+    eng = Engine(_engine_cfg(
+        scheduler=SchedulerConfig(max_prefill_batch=8), decode_batch_size=8,
+        tp=tp,
+    ))
+    prompts = [_prompt(40 + i, 5 + i) for i in range(n_seqs)]
+    for p in prompts:
+        eng.add_request(p, SamplingParams(max_new_tokens=2))
+    eng.step()
+    chunk = -(-len(prompts[-1]) // 8) * 8  # the longest, in buckets of 8
+    assert eng.prefill_stats == {
+        "tokens_computed": sum(map(len, prompts)), "dispatches": 1,
+        "token_slots": rows * chunk,
+    }

@@ -118,6 +118,30 @@ def test_a_program_carries_the_scopes_its_layers_have_and_no_other(
     assert got == want, (sorted(got - want), sorted(want - got))
 
 
+@pytest.mark.parametrize("kind", list(CONFIGS))
+def test_the_rows_loop_of_a_prefill_program_carries_its_scopes(kind):
+    """An eight-row ``prefill_packed`` holds its forward in a loop over the
+    rows: the loop's body carries every part the layers have, the one write
+    of the pools comes after it, and the module keeps ``prefill`` in its
+    name (the benchmark's readers of ``prefill_scope_ms`` find it by that)."""
+    cfg, want = CONFIGS[kind]
+    params, k_pages, v_pages, state = _shapes(cfg)
+    lowered = llama.prefill_packed.lower(
+        params, cfg, jnp.zeros((8, 5 * CHUNK + TABLE_W + 1), jnp.int32),
+        k_pages, v_pages, chunk=CHUNK, attn_impl="xla", interpret=True,
+        **state,
+    )
+    text = lowered.as_text(debug_info=True)
+    assert "@jit_prefill_packed" in text
+    assert "stablehlo.while" in text and "call @_prefill_rows" in text
+    # the row's forward is a function of its own (``llama._prefill_rows``),
+    # called from the loop's body: its operations' paths start anew
+    names = set(re.findall(r'"([^"]*?)model\.([a-z_]+)/', text))
+    assert {part for path, part in names if not path} == want - {"cache_write"}
+    assert {part for path, part in names if path} == {"cache_write"}
+    assert {path for path, _ in names} == {"", "jit(prefill_packed)/"}
+
+
 def test_the_first_tokens_sampler_is_under_the_sample_scope():
     text = sampling.sample_tokens_packed.lower(
         jnp.zeros((LANES, 64), jnp.bfloat16),

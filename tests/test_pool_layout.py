@@ -455,6 +455,70 @@ class TestServedPrograms:
         assert aot_pool_copies.served_program("sdar-30b-a3b", "decode_steps", one_chip) is None
 
 
+class TestThePrefillLoopReadsThePools:
+    """``prefill_packed`` holds its forward for one row, in a loop over the
+    rows that hold a sequence; the loop reads the pools and the one write
+    comes after it (branches of a conditional that RETURNED a pool copied
+    it whole on the way in and on the way out: the TPU compiler, PR 42).
+    The slow cases above hold that for the six cells' programs at their
+    depth; these are three of them cut to the fewest layers that keep every
+    kind of layer (a K/V pool, a state pool beside one, a latent pool),
+    13-28 s each."""
+
+    @pytest.mark.parametrize("config, n_layers", [
+        ("qwen3-32b", 1), ("lfm2-8b-a1b", 3), ("longcat-flash-omni", 1),
+    ])
+    def test_the_loop_copies_no_pool(self, topo, config, n_layers):
+        one_chip = SingleDeviceSharding(topo.devices[0])
+        fn, args, kwargs, pool_shape = aot_pool_copies.served_program(
+            config, "prefill", one_chip, n_layers=n_layers
+        )
+        hlo = aot_pool_copies.compile_text(fn, *args, **kwargs)
+        assert " while(" in hlo  # the rows' loop is there to be read
+        assert aot_pool_copies.pool_instructions(hlo, pool_shape)
+        assert _whole_pool_moves(hlo, pool_shape) == []
+        state_shape = aot_pool_copies.state_pool_shape(kwargs)
+        assert (state_shape is not None) == (config == "lfm2-8b-a1b")
+        if state_shape:
+            assert _whole_pool_moves(hlo, state_shape) == []
+
+
+def test_the_rows_of_a_dispatch_add_no_program():
+    """Dispatches of 1, 2 and 5 sequences of one (chunk, context) shape run
+    ONE compiled ``prefill_packed``: how many rows it computes is decided
+    inside it, on the device, so the set of programs a warm-up loads is
+    what it was."""
+    from llm_d_kv_cache_manager_tpu.models import TINY_LLAMA
+    from llm_d_kv_cache_manager_tpu.server import (
+        BlockManagerConfig, Engine, EngineConfig, SamplingParams,
+        SchedulerConfig,
+    )
+
+    eng = Engine(EngineConfig(
+        model=TINY_LLAMA,
+        block_manager=BlockManagerConfig(total_pages=64, page_size=4),
+        scheduler=SchedulerConfig(max_prefill_batch=8), max_model_len=64,
+        decode_batch_size=8, prefill_bucket=8, interpret=True,
+    ))
+    llama.prefill_packed.clear_cache()
+    rng = np.random.default_rng(9)
+    sizes = []
+    # the first two settle the jit's own entries (fresh pools, then a
+    # program's results: two entries of ONE compiled program, as before)
+    for n_seqs, rows in ((1, 1), (1, 1), (2, 2), (5, 5), (1, 1)):
+        before = dict(eng.prefill_stats)
+        for _ in range(n_seqs):
+            eng.add_request(
+                list(map(int, rng.integers(1, 256, 7))),
+                SamplingParams(max_new_tokens=1),
+            )
+        eng.run_until_complete()
+        assert eng.prefill_stats["dispatches"] == before["dispatches"] + 1
+        assert eng.prefill_stats["token_slots"] == before["token_slots"] + 8 * rows
+        sizes.append(llama.prefill_packed._cache_size())
+    assert sizes[0] == 1 and sizes[1:] == [sizes[1]] * 4, sizes
+
+
 _RECORDED = """\
 HloModule jit_step, is_scheduled=true
 

@@ -44,8 +44,13 @@ CONFIGS = Path(__file__).resolve().parent.parent / "chipbench" / "configs"
 PROGRAMS = ("decode_steps", "denoise_steps", "prefill")
 PREFILL_ROWS, PREFILL_CHUNK = 8, 128  # a dispatch of the cells: 1024 rows
 
-#: Opcodes that move no bytes.
-FREE = frozenset({"parameter", "bitcast", "get-tuple-element", "tuple"})
+#: Opcodes that move no bytes. A ``while`` hands its state to its body and
+#: back in one buffer (its operand, the body's parameter and root and its
+#: result are assigned the same; where that cannot be, the compiler inserts
+#: a ``copy``, which is listed), so a pool that rides in a loop's state
+#: (``prefill_packed``'s loop over rows reads the pools) costs what the
+#: body's own instructions cost, and those are listed like any other.
+FREE = frozenset({"parameter", "bitcast", "get-tuple-element", "tuple", "while"})
 
 
 class PoolInstruction(NamedTuple):
@@ -189,18 +194,21 @@ def compile_text(fn, *args, **kwargs) -> str:
             m.require_tpu_unless_interpret = guard
 
 
-def served_program(config: str, program: str, one_chip):
+def served_program(config: str, program: str, one_chip, **replace):
     """(jitted function, args, kwargs, pool shape) of a configuration's
     served ``program`` at the shapes its cell pins, or None where the
     configuration does not serve it (``decode_steps`` under a block mask,
-    ``denoise_steps`` without one)."""
+    ``denoise_steps`` without one). ``replace``: fields of the model's
+    configuration over the cell's own (a test's shallower ``n_layers``)."""
     import jax
     import jax.numpy as jnp
 
     from llm_d_kv_cache_manager_tpu.models import llama
 
     spec = json.loads((CONFIGS / f"{config}.json").read_text())["chipbench"]
-    cfg = dataclasses.replace(getattr(llama, spec["preset"]), **spec["replace"])
+    cfg = dataclasses.replace(
+        getattr(llama, spec["preset"]), **{**spec["replace"], **replace}
+    )
     env, engine = spec["env"], spec["engine"]
     lanes, page = env["DECODE_BATCH_SIZE"], env["BLOCK_SIZE"]
     table_w = engine["decode_pages_bucket"]
