@@ -29,6 +29,8 @@ def load_hf_state_dict(
     state_dict: Mapping[str, Any], cfg: LlamaConfig
 ) -> Params:
     sd = state_dict
+    if cfg.sliding_window:
+        return _load_afmoe(sd, cfg)
     if cfg.layer_types is not None:
         return _load_lfm2_moe(sd, cfg)
     if cfg.double_layer:
@@ -332,6 +334,133 @@ def _load_lfm2_moe(sd: Mapping[str, Any], cfg: LlamaConfig) -> Params:
     }
 
 
+def _load_afmoe(sd: Mapping[str, Any], cfg: LlamaConfig) -> Params:
+    """``model_type: afmoe`` (Trinity). The checkpoint's names are written
+    here AS THE AUTHOR REMEMBERS the published modelling code
+    (``modeling_afmoe.py``), with no network at hand to read it again: a
+    layer's four norms ``input_layernorm`` (before the attention),
+    ``post_attention_layernorm`` (on its output, before the residual add),
+    ``pre_mlp_layernorm`` and ``post_mlp_layernorm``; ``self_attn.{q, k, v,
+    o}_proj``, ``self_attn.gate_proj`` (the output gate) and ``self_attn.
+    q_norm`` / ``k_norm``; ``mlp.{gate, up, down}_proj`` in the leading dense
+    layers, else ``mlp.router.gate`` (the router), ``mlp.expert_bias``,
+    ``mlp.experts.N.{gate, up, down}_proj`` and ``mlp.shared_experts.{gate,
+    up, down}_proj``; ``model.norm`` and an untied ``lm_head``. A checkpoint
+    that names them otherwise fails on the missing key, named. A held range
+    of the experts (``expert_first`` / ``expert_count``) reads those experts
+    alone; a layer whose kind is sliding gets the leaf ``window``."""
+    def get(name: str) -> np.ndarray:
+        return _to_np(sd[name])
+
+    def vector(name: str) -> jnp.ndarray:
+        return jnp.asarray(get(name), cfg.dtype)
+
+    def linear(name: str) -> jnp.ndarray:
+        return jnp.asarray(get(name).T, cfg.dtype)  # [out,in] -> [in,out]
+
+    ffn = (("gate", "gate_proj"), ("up", "up_proj"), ("down", "down_proj"))
+    layers = []
+    for i in range(cfg.n_layers):
+        p = f"model.layers.{i}."
+        layer = {
+            "attn_norm": vector(p + "input_layernorm.weight"),
+            "attn_post_norm": vector(p + "post_attention_layernorm.weight"),
+            "mlp_norm": vector(p + "pre_mlp_layernorm.weight"),
+            "mlp_post_norm": vector(p + "post_mlp_layernorm.weight"),
+            "q_norm": vector(p + "self_attn.q_norm.weight"),
+            "k_norm": vector(p + "self_attn.k_norm.weight"),
+        }
+        for ours, theirs in (("wq", "q_proj"), ("wk", "k_proj"),
+                             ("wv", "v_proj"), ("wo", "o_proj"),
+                             ("wg", "gate_proj")):
+            layer[ours] = linear(f"{p}self_attn.{theirs}.weight")
+        if cfg.layer_kind(i) == "sliding":
+            layer["window"] = jnp.asarray(cfg.sliding_window, jnp.int32)
+        if i >= cfg.first_k_dense:
+            layer["router"] = linear(p + "mlp.router.gate.weight")
+            layer["router_bias"] = jnp.asarray(
+                get(p + "mlp.expert_bias"), jnp.float32
+            )
+            held = range(cfg.expert_first, cfg.expert_first + cfg.experts_held)
+            for ours, theirs in ffn:
+                layer["w_" + ours] = jnp.stack([
+                    linear(f"{p}mlp.experts.{j}.{theirs}.weight") for j in held
+                ])
+                layer["ws_" + ours] = linear(
+                    f"{p}mlp.shared_experts.{theirs}.weight"
+                )
+        else:
+            for ours, theirs in ffn:
+                layer["w_" + ours] = linear(f"{p}mlp.{theirs}.weight")
+        layers.append(layer)
+    return {
+        "embed": vector("model.embed_tokens.weight"),
+        "final_norm": vector("model.norm.weight"),
+        "lm_head": linear("lm_head.weight"),
+        "layers": layers,
+    }
+
+
+def _afmoe_config(hf_config, rope_scaling) -> LlamaConfig:
+    """``model_type: afmoe``: window and full attention layers in one model
+    (``layer_types``), a gate on the attention's output, four norms a layer,
+    the embedding times sqrt(hidden), leading dense layers, sigmoid-routed
+    experts with a bias that chooses beside shared experts. What the
+    program does not run is refused here by name."""
+    def has(key, default=None):
+        return getattr(hf_config, key, default)
+
+    kinds = tuple(has("layer_types") or ())
+    unknown = sorted(set(kinds) - {"sliding_attention", "full_attention"})
+    if unknown or len(kinds) != hf_config.num_hidden_layers:
+        raise NotImplementedError(
+            f"layer_types {unknown or len(kinds)}: one of 'sliding_attention' "
+            "/ 'full_attention' a layer is supported"
+        )
+    if has("score_func", "sigmoid") != "sigmoid":
+        raise NotImplementedError(
+            f"score_func={has('score_func')!r} is not supported yet (sigmoid)"
+        )
+    if has("n_group", 1) != 1 or has("topk_group", 1) != 1:
+        raise NotImplementedError(
+            "n_group / topk_group > 1 (group-limited routing) is not "
+            "supported yet"
+        )
+    if not has("mup_enabled", False):
+        raise NotImplementedError(
+            "mup_enabled=false is not supported yet (the embedding is scaled "
+            "by sqrt(hidden))"
+        )
+    if rope_scaling is not None:
+        raise NotImplementedError("rope_scaling with sliding layers")
+    return LlamaConfig(
+        vocab_size=hf_config.vocab_size,
+        hidden_size=hf_config.hidden_size,
+        intermediate_size=hf_config.intermediate_size,
+        n_layers=hf_config.num_hidden_layers,
+        n_heads=hf_config.num_attention_heads,
+        n_kv_heads=hf_config.num_key_value_heads,
+        head_dim=has("head_dim"),
+        rope_theta=float(has("rope_theta", 10_000.0)),
+        rms_norm_eps=has("rms_norm_eps", 1e-5),
+        qk_norm=True,
+        scale_embeddings=True,
+        tie_word_embeddings=bool(has("tie_word_embeddings", False)),
+        n_experts=hf_config.num_experts,
+        n_experts_per_tok=hf_config.num_experts_per_tok,
+        moe_intermediate_size=hf_config.moe_intermediate_size,
+        norm_topk_prob=bool(has("route_norm", True)),
+        n_shared_experts=has("num_shared_experts", 0),
+        moe_scoring="sigmoid",
+        routed_scaling_factor=float(has("route_scale", 1.0)),
+        first_k_dense=has("num_dense_layers", 0),
+        layer_types=kinds,
+        sliding_window=hf_config.sliding_window,
+        attn_output_gate=True,
+        sandwich_norm=True,
+    )
+
+
 def _lfm2_moe_config(hf_config, rope_scaling) -> LlamaConfig:
     """``model_type: lfm2_moe``: gated short convolutions with some layers
     of GQA (``layer_types``), leading dense layers, sigmoid-routed experts
@@ -412,6 +541,8 @@ def config_from_hf(hf_config) -> LlamaConfig:
             )
     if getattr(hf_config, "model_type", "") == "lfm2_moe":
         return _lfm2_moe_config(hf_config, rope_scaling)
+    if getattr(hf_config, "model_type", "") == "afmoe":
+        return _afmoe_config(hf_config, rope_scaling)
     if getattr(hf_config, "model_type", "") == "longcat_flash":
         return _longcat_flash_config(hf_config, rope_scaling)
     cls_name = hf_config.__class__.__name__

@@ -82,7 +82,8 @@ from .quant import QuantizedTensor, materialize as _w
 #: ``op_name`` of the HLO instruction, which the profiler carries as the
 #: ``tf_op`` of the device event's metadata): no operation, always on.
 #: ``attn`` / ``conv``: a layer's operator from its norm to its output
-#: projection and residual; ``ffn``: ``mlp_norm`` and the dense SwiGLU;
+#: projection and residual (``attn_window``: the same of a sliding layer,
+#: whose kernels read the window pool); ``ffn``: ``mlp_norm`` and the dense SwiGLU;
 #: ``moe_router``: a routed layer's ``mlp_norm``, ``_moe_gates`` and the
 #: sort / permutation of rows by expert; ``moe_experts``: the grouped
 #: matmuls, the gate weighting and the un-permutation; ``moe_zero``: the
@@ -95,8 +96,8 @@ from .quant import QuantizedTensor, materialize as _w
 #: Readers and documents quote this tuple, as they do ``server/engine.py``'s
 #: ``STEP_PHASES`` for the host's side.
 MODEL_SCOPES = (
-    "attn", "conv", "ffn", "moe_router", "moe_experts", "moe_zero",
-    "moe_shared", "cache_write", "head", "sample",
+    "attn", "attn_window", "conv", "ffn", "moe_router", "moe_experts",
+    "moe_zero", "moe_shared", "cache_write", "head", "sample",
 )
 
 
@@ -108,7 +109,8 @@ def _scope(part: str):
 
 def _paged_attention_tp(
     q, kp, vp, block_tables, seq_lens, fresh_k, fresh_v, *, interpret, mesh,
-    layer: int = 0, k_scale=None, v_scale=None, scale=None,
+    layer: int = 0, k_scale=None, v_scale=None, scale=None, window: int = 0,
+    table_start=None,
 ):
     """Decode attention, head-parallel over the ``tp`` mesh axis.
 
@@ -123,8 +125,18 @@ def _paged_attention_tp(
     layer here would force XLA to copy a whole per-layer pool per call
     (see paged_attention's docstring). ``fresh_k``/``fresh_v``
     ([b, n_kv, hd]) carry the current token's K/V so pool writes can be
-    deferred past attention.
+    deferred past attention. ``window`` / ``table_start``: a sliding
+    layer's call over the window pools (``ops/paged_attention.py``; single
+    shard only).
     """
+    if window:
+        if mesh is not None or k_scale is not None:
+            raise ValueError("a window pool: tp, sp and int8 are not run")
+        return paged_attention(
+            q, kp, vp, block_tables, seq_lens, fresh_k, fresh_v,
+            interpret=interpret, layer=layer, scale=scale, window=window,
+            table_start=table_start,
+        )
     if mesh is None:
         return paged_attention(
             q, kp, vp, block_tables, seq_lens, fresh_k, fresh_v,
@@ -269,7 +281,8 @@ def _check_right_padded_mask(ok) -> None:
 
 def _flash_prefill_tp(
     q, k, v, k_pages, v_pages, block_tables, ctx_lens, n_valid, *,
-    layer, interpret, mesh, block_length=0, scale=None,
+    layer, interpret, mesh, block_length=0, scale=None, window: int = 0,
+    table_start=None,
 ):
     """Pallas flash prefill, head-parallel over the ``tp`` mesh axis.
 
@@ -282,6 +295,16 @@ def _flash_prefill_tp(
     reduction).
     """
     from ..ops.flash_prefill import flash_prefill_paged
+
+    if window:
+        # a sliding layer's call over the window pools (single shard only)
+        if mesh is not None:
+            raise ValueError("a window pool: tp and sp are not run")
+        return flash_prefill_paged(
+            q, k, v, k_pages, v_pages, block_tables, ctx_lens, n_valid,
+            interpret=interpret, layer=jnp.int32(layer), scale=scale,
+            window=window, table_start=table_start,
+        )
 
     def kernel(q, k, v, k_pages, v_pages, block_tables, ctx_lens, n_valid, layer):
         return flash_prefill_paged(
@@ -444,6 +467,27 @@ class LlamaConfig:
     layer_types: Optional[tuple] = None
     conv_L_cache: int = 0
     conv_bias: bool = False
+    # Window and full attention layers in one model (Trinity / afmoe):
+    # ``layer_types[i]`` "sliding_attention" is a layer whose token at ``t``
+    # sees the ``sliding_window`` positions ``(t - W, t]`` and no earlier one.
+    # Its keys and values live in a WINDOW POOL with page ids of its own
+    # (``init_window_pages``: the layer axis counts the sliding layers, the
+    # key/value pools' the full ones), which a sequence gives back page by
+    # page as it moves on (``server/block_manager.py``). In such a model the
+    # sliding layers alone rotate q and k; a full layer takes no positions.
+    # ``layer_types`` decides what ``init_params`` and the loader make; the
+    # bodies read the layer: a sliding one has the leaf ``window`` (its
+    # window as an int32 scalar, read as ``conv_in`` is read: for what it
+    # says the layer is, never for its value, which is this static field).
+    # 0: no layer has a window, and no program has a window operand.
+    sliding_window: int = 0
+    # ``sigmoid(x wg)`` on the heads' output before ``wo`` (``x`` the normed
+    # input the projections read); a layer that has ``wg`` is gated.
+    attn_output_gate: bool = False
+    # A norm after the attention and one after the FFN, each before its
+    # residual add (``attn_post_norm`` / ``mlp_post_norm``; a layer that has
+    # them applies them): ``a = h + N2(Attn(N1 h)); h' = a + N4(F(N3 a))``.
+    sandwich_norm: bool = False
     dtype: Any = jnp.bfloat16
 
     @property
@@ -451,21 +495,30 @@ class LlamaConfig:
         return self.head_dim or self.hidden_size // self.n_heads
 
     def layer_kind(self, i: int) -> str:
-        """"conv" or "attention", as ``layer_types`` publishes layer ``i``."""
-        return (
-            "conv" if self.layer_types and self.layer_types[i] == "conv"
-            else "attention"
+        """"conv", "sliding" or "attention", as ``layer_types`` publishes
+        layer ``i``."""
+        kind = self.layer_types[i] if self.layer_types else "full_attention"
+        return {"conv": "conv", "sliding_attention": "sliding"}.get(
+            kind, "attention"
         )
+
+    def _n_layers_of(self, kind: str) -> int:
+        return sum(self.layer_kind(i) == kind for i in range(self.n_layers))
 
     @property
     def n_conv_layers(self) -> int:
-        return sum(self.layer_kind(i) == "conv" for i in range(self.n_layers))
+        return self._n_layers_of("conv")
+
+    @property
+    def n_window_layers(self) -> int:
+        """Layers of the window pools: the sliding attentions."""
+        return self._n_layers_of("sliding")
 
     @property
     def n_attn_layers(self) -> int:
-        """Layers of the key/value pools: the attentions (two a published
-        layer of a ``double_layer`` model)."""
-        return (self.n_layers - self.n_conv_layers) * (1 + self.double_layer)
+        """Layers of the key/value pools: the attentions that see their
+        whole context (two a published layer of a ``double_layer`` model)."""
+        return self._n_layers_of("attention") * (1 + self.double_layer)
 
     @property
     def experts_held(self) -> int:
@@ -914,6 +967,74 @@ TINY_LFM2_MOE = LlamaConfig(
     dtype=jnp.float32,
 )
 
+_SWA = "sliding_attention"
+
+#: arcee-ai/Trinity-Large-Preview (``model_type: afmoe``): 45 sliding layers
+#: of window 4096 and 15 full ones, every fourth; GQA 48 / 8 with per-head
+#: q/k norm and a sigmoid gate on the heads' output; rope on the sliding
+#: layers only; four norms a layer; the embedding times sqrt(hidden); six
+#: leading dense layers, then 256 sigmoid-routed experts top-4 (a bias that
+#: chooses, gates renormalised, x 2.448) beside one shared expert. No chip
+#: holds a layer's 256 experts: a configuration states its share
+#: (``expert_first`` / ``expert_count``) and its cut of depth and vocabulary.
+TRINITY_LARGE_PREVIEW = LlamaConfig(
+    vocab_size=200_192,
+    hidden_size=3_072,
+    intermediate_size=12_288,
+    n_layers=60,
+    n_heads=48,
+    n_kv_heads=8,
+    head_dim=128,
+    rope_theta=10_000.0,
+    rms_norm_eps=1e-5,
+    qk_norm=True,
+    scale_embeddings=True,
+    n_experts=256,
+    n_experts_per_tok=4,
+    moe_intermediate_size=3_072,
+    norm_topk_prob=True,
+    n_shared_experts=1,
+    moe_scoring="sigmoid",
+    routed_scaling_factor=2.448,
+    first_k_dense=6,
+    layer_types=(_SWA, _SWA, _SWA, _ATTN) * 15,
+    sliding_window=4_096,
+    attn_output_gate=True,
+    sandwich_norm=True,
+)
+
+#: Tiny window-and-full MoE (sliding, sliding, sliding, full, sliding; window
+#: 8; a dense layer, then 8 experts top-2 of which 4 are held, one shared)
+#: for tests / CPU dry-runs.
+TINY_SWA_MOE = LlamaConfig(
+    vocab_size=256,
+    hidden_size=64,
+    intermediate_size=128,
+    n_layers=5,
+    n_heads=4,
+    n_kv_heads=2,
+    head_dim=16,
+    rope_theta=10_000.0,
+    rms_norm_eps=1e-5,
+    qk_norm=True,
+    scale_embeddings=True,
+    n_experts=8,
+    n_experts_per_tok=2,
+    moe_intermediate_size=48,
+    norm_topk_prob=True,
+    n_shared_experts=1,
+    moe_scoring="sigmoid",
+    routed_scaling_factor=2.448,
+    first_k_dense=1,
+    expert_first=2,
+    expert_count=4,
+    layer_types=(_SWA, _SWA, _SWA, _ATTN, _SWA),
+    sliding_window=8,
+    attn_output_gate=True,
+    sandwich_norm=True,
+    dtype=jnp.float32,
+)
+
 #: Tiny MoE config (Mixtral-shaped) for tests / CPU dry-runs.
 TINY_MOE = LlamaConfig(
     vocab_size=256,
@@ -1022,11 +1143,13 @@ def init_params(
             # this model: half a sigmoid router's spread of scores (0.21);
             # a fifth of a softmax router's over n outputs (sqrt(e - 1) / n:
             # at the whole spread a drawn bias moved the load of a rank's 16
-            # experts by a half from seed to seed, PERF.md section 6, PR 41).
-            spread = (
-                0.1 if cfg.moe_scoring == "sigmoid"
-                else 0.25 / cfg.router_outputs
-            )
+            # experts by a half from seed to seed, PERF.md section 6, PR 41),
+            # and a fifth of a sigmoid router's too where a rank holds a share
+            # of the experts, for that reason.
+            if cfg.moe_scoring != "sigmoid":
+                spread = 0.25 / cfg.router_outputs
+            else:
+                spread = 0.1 if cfg.holds_every_expert else 0.04
             part["router_bias"] = spread * jax.random.normal(
                 extra[0], (cfg.router_outputs,), jnp.float32
             )
@@ -1096,6 +1219,16 @@ def init_params(
         if cfg.qk_norm and "wq" in layer:
             layer["q_norm"] = norm_init((hd,))
             layer["k_norm"] = norm_init((hd,))
+        # (keys folded in, as ``routed_ffn``'s: older trees stay what they were)
+        if cfg.attn_output_gate and "wq" in layer:
+            layer["wg"] = dense(
+                jax.random.fold_in(keys[i], 4), (d, n_q * hd), d
+            )
+        if cfg.sandwich_norm:
+            layer["attn_post_norm"] = norm_init((d,))
+            layer["mlp_post_norm"] = norm_init((d,))
+        if cfg.layer_kind(i) == "sliding":
+            layer["window"] = jnp.asarray(cfg.sliding_window, jnp.int32)
         layers.append(layer)
 
     params: Params = {
@@ -1147,6 +1280,23 @@ def init_kv_pages(
     return (
         jnp.zeros(shape, dtype, device=sharding),
         jnp.zeros(shape, dtype, device=sharding),
+    )
+
+
+def init_window_pages(
+    cfg: LlamaConfig, window_pages: int, page_size: int, sharding=None
+) -> Optional[tuple[jnp.ndarray, jnp.ndarray]]:
+    """The zeroed K and V window pools of a model with sliding layers,
+    ``[sliding layers, window_pages, page_size, n_kv_heads, head_dim]`` each,
+    with page ids of their own (page 0 reserved, as in the key/value pools);
+    None for a model without such layers. Every program takes and returns
+    the pair as ONE argument, ``window_pages``."""
+    if not cfg.n_window_layers:
+        return None
+    shape = (cfg.n_window_layers, window_pages, page_size, *cfg.kv_row_shape)
+    return (
+        jnp.zeros(shape, cfg.dtype, device=sharding),
+        jnp.zeros(shape, cfg.dtype, device=sharding),
     )
 
 
@@ -1247,6 +1397,29 @@ def _qkv(layer: Params, cfg: LlamaConfig, x: jnp.ndarray):
         q = rms_norm(q, layer["q_norm"], cfg.rms_norm_eps, cfg.norm_offset)
         k = rms_norm(k, layer["k_norm"], cfg.rms_norm_eps, cfg.norm_offset)
     return q, k, v
+
+
+def _rotates(layer: Params, cfg: LlamaConfig) -> bool:
+    """Whether a layer that attends rotates q and k: every one, except the
+    full layers of a model that has sliding ones (they take no positions)."""
+    return "window" in layer or not cfg.sliding_window
+
+
+def _attn_gate(layer: Params, x: jnp.ndarray, heads: jnp.ndarray):
+    """The heads' output ``[..., n_heads * hd]`` times ``sigmoid(x wg)`` where
+    the layer has an output gate; as it came where it has none."""
+    if "wg" not in layer:
+        return heads
+    gate = jax.nn.sigmoid((x @ _w(layer["wg"], x.dtype)).astype(jnp.float32))
+    return (heads.astype(jnp.float32) * gate).astype(heads.dtype)
+
+
+def _post_norm(layer: Params, cfg: LlamaConfig, name: str, out: jnp.ndarray):
+    """A sandwich norm (``attn_post_norm`` / ``mlp_post_norm``) on what a
+    half of the layer adds to the residual, where the layer has it."""
+    if name not in layer:
+        return out
+    return rms_norm(out, layer[name], cfg.rms_norm_eps, cfg.norm_offset)
 
 
 # -- latent attention (MLA) ---------------------------------------------------
@@ -1889,7 +2062,7 @@ def _ffn(
         valid=valid,
     )
     with _scope("moe_experts" if routed else "ffn"):
-        h = h + out
+        h = h + _post_norm(layer, cfg, "mlp_post_norm", out)
     if aside and "moe" not in layer:
         with _scope("moe_experts"):
             h = h + aside.pop()
@@ -2060,21 +2233,23 @@ def _prefill_body(
     v_scales=None,
     experts_touched: Optional[list] = None,  # see ``_moe_mlp_routed``
     state_pages=None,  # ``init_state_pages``: a model with conv layers
-) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, Any, Any, Any]:
+    window_pages=None,  # ``init_window_pages``: a model with sliding layers
+    window_rows=None,  # its rows' (page_ids [b, s], tables [b, w], starts [b])
+) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, Any, Any, Any, Any]:
     """Traced prefill shared by ``prefill``, the fused speculative-decode
     scan (``spec_decode_steps``) and ``_denoise_body``: the chunk's forward
     over the paged context (``_prefill_forward``), then its one write of
     the pools (``_prefill_write``). Returns (hidden states [b, s, d],
-    k_pages, v_pages, k_scales, v_scales, state_pages); logits selection
-    stays with the caller."""
+    k_pages, v_pages, k_scales, v_scales, state_pages, window_pages); logits
+    selection stays with the caller."""
     h, fresh = _prefill_forward(
         params, cfg, tokens, positions, valid, k_pages, v_pages, page_ids,
         block_tables, ctx_lens, mesh, attn_impl, interpret, k_scales,
-        v_scales, experts_touched, state_pages,
+        v_scales, experts_touched, state_pages, window_pages, window_rows,
     )
     return (h,) + _prefill_write(
         fresh, positions, valid, k_pages, v_pages, page_ids, slot_ids,
-        ctx_lens, k_scales, v_scales, state_pages,
+        ctx_lens, k_scales, v_scales, state_pages, window_pages, window_rows,
     )
 
 
@@ -2096,13 +2271,20 @@ def _prefill_forward(
     v_scales,
     experts_touched: Optional[list],
     state_pages,
+    window_pages=None,
+    window_rows=None,
 ) -> tuple[jnp.ndarray, tuple]:
     """The layer loop of ``_prefill_body`` (whose operands these are): it
     reads the pools and writes none. Returns (hidden states [b, s, d], what
     ``_prefill_write`` puts into the pools: the layers' keys ``[L, b, s,
-    ...]``, their values (None for a latent pool) and the convolution
-    layers' states ``[conv layers, b, pages touched, state row]``, each
-    None where the model has none). A convolution layer (one that
+    ...]``, their values (None for a latent pool), the convolution
+    layers' states ``[conv layers, b, pages touched, state row]`` and the
+    sliding layers' keys and values ``[sliding layers, b, s, ...]``, each
+    None where the model has none). A sliding layer (one that has
+    ``window``) reads ``window_pages`` through its rows' window tables, whose
+    first slot stands for the position ``window_rows`` gives, and every
+    layer that attends rotates q and k unless ``_rotates`` says it takes no
+    positions. A convolution layer (one that
     has ``conv_in``) takes its state before the chunk from the slot of the
     page that holds the token before ``ctx_lens`` (``page_ids[:, 0]`` where
     the chunk starts inside a page, else the last page of ``block_tables``'
@@ -2158,14 +2340,29 @@ def _prefill_forward(
             last[:, :, None] + 1 + jnp.arange(cfg.conv_L_cache - 1)[None, None]
         )
     head_scale = cfg.hd**-0.5 if cfg.kv_heads_per_row > 1 else None
+    if any("window" in layer for layer in params["layers"]):
+        if (window_pages is None or window_rows is None or sp > 1
+                or k_scales is not None or cfg.block_length > 1):
+            raise ValueError(
+                "sliding layers: the window pools and the rows' window "
+                "tables are needed; sp, int8 and block_length are not run"
+            )
+        _, window_tables, window_start = window_rows
 
     fresh_k = []  # per-layer [b, s, n_kv, hd] — written to pages in one go
     fresh_v = []
     fresh_state = []  # per conv layer [b, pages touched, state row]
+    fresh_wk = []  # per sliding layer, as ``fresh_k``: the window pools'
+    fresh_wv = []
     aside = []  # a double layer's routed sum, from its first FFN to its second
     for layer in _sublayers(params["layers"]):
-        li = len(fresh_k)  # the layer's index in the key/value pools
-        with _scope("conv" if "conv_in" in layer else "attn"):
+        sliding = "window" in layer
+        # the layer's index in the pools it reads and writes
+        li = len(fresh_wk if sliding else fresh_k)
+        with _scope(
+            "conv" if "conv_in" in layer
+            else "attn_window" if sliding else "attn"
+        ):
             x = rms_norm(h, layer["attn_norm"], cfg.rms_norm_eps, cfg.norm_offset)
             if "conv_in" in layer:
                 out, z = _conv_operator(layer, cfg, x, states[len(fresh_state)])
@@ -2187,10 +2384,30 @@ def _prefill_forward(
                 )
             else:
                 q, k, v = _qkv(layer, cfg, x)
-                q = apply_rope(q, positions, inv_freq)
-                k = apply_rope(k, positions, inv_freq)
+                if _rotates(layer, cfg):
+                    q = apply_rope(q, positions, inv_freq)
+                    k = apply_rope(k, positions, inv_freq)
 
-                if sp > 1:
+                if sliding:
+                    # the window pools through the row's window table, whose
+                    # first slot is position ``window_start``; ``xla`` is the
+                    # kernel's oracle, window included
+                    seen = dict(
+                        window=cfg.sliding_window, table_start=window_start
+                    )
+                    if attn_impl == "pallas":
+                        attn = _flash_prefill_tp(
+                            q, k, v, *window_pages, window_tables, ctx_lens,
+                            n_valid, layer=li, interpret=interpret, mesh=mesh,
+                            **seen,
+                        )
+                    else:
+                        attn = prefill_with_paged_context(
+                            q, k, v, window_pages[0][li], window_pages[1][li],
+                            window_tables, ctx_lens, positions=positions,
+                            valid=valid, **seen,
+                        )
+                elif sp > 1:
                     # Sequence-parallel chunk: ring attention over the sp axis,
                     # merged exactly with the paged context (see
                     # _sp_prefill_attention). Takes precedence over attn_impl —
@@ -2220,20 +2437,25 @@ def _prefill_forward(
                     )
             if "conv_in" not in layer:
                 b, s, _, _ = attn.shape
-                out = attn.reshape(b, s, -1) @ _w(layer["wo"], h.dtype)
-                fresh_k.append(k)
-                fresh_v.append(v)
-            h = h + out
+                out = _attn_gate(layer, x, attn.reshape(b, s, -1)) @ _w(
+                    layer["wo"], h.dtype
+                )
+                (fresh_wk if sliding else fresh_k).append(k)
+                (fresh_wv if sliding else fresh_v).append(v)
+            h = h + _post_norm(layer, cfg, "attn_post_norm", out)
         h = _ffn(
             layer, cfg, h, mesh=mesh, interpret=interpret,
             touched=experts_touched, valid=valid, aside=aside,
         )
 
-    return h, (
+    fresh = (
         jnp.stack(fresh_k) if fresh_k else None,
         jnp.stack(fresh_v) if fresh_k and not latent else None,
         jnp.stack(fresh_state) if fresh_state else None,
     )
+    if fresh_wk:
+        fresh += (jnp.stack(fresh_wk), jnp.stack(fresh_wv))
+    return h, fresh
 
 
 def _prefill_write(
@@ -2248,13 +2470,25 @@ def _prefill_write(
     k_scales,
     v_scales,
     state_pages,
-) -> tuple[jnp.ndarray, jnp.ndarray, Any, Any, Any]:
+    window_pages=None,
+    window_rows=None,
+) -> tuple[jnp.ndarray, jnp.ndarray, Any, Any, Any, Any]:
     """What a chunk leaves in the (donated) pools, each in one scatter over
-    all its layers: (k_pages, v_pages, k_scales, v_scales, state_pages). A
-    pool ``fresh`` has nothing for (a latent model's second, a tree of
-    convolution layers alone) passes through untouched, as do the scales
-    unless the pools are int8."""
-    fresh_k, fresh_v, fresh_state = fresh
+    all its layers: (k_pages, v_pages, k_scales, v_scales, state_pages,
+    window_pages). A pool ``fresh`` has nothing for (a latent model's second,
+    a tree of convolution layers alone) passes through untouched, as do the
+    scales unless the pools are int8. The sliding layers' keys and values go
+    to the window pools at their rows' own page ids (``window_rows``) and the
+    slots every pool shares; a token of a block the sequence keeps no window
+    page for carries the reserved page 0, which nothing reads."""
+    fresh_k, fresh_v, fresh_state, *fresh_window = fresh
+    if fresh_window:
+        window_pages = tuple(
+            _scatter_kv_pages_all_layers(
+                pool, new.astype(pool.dtype), window_rows[0], slot_ids, valid
+            )
+            for pool, new in zip(window_pages, fresh_window)
+        )
     if fresh_state is not None:
         _, state_page, state_ok = _conv_state_plan(
             ctx_lens, jnp.sum(valid.astype(jnp.int32), axis=1), page_ids,
@@ -2274,7 +2508,7 @@ def _prefill_write(
         v_pages, v_scales = _quantized_scatter_kv_all_layers(
             v_pages, v_scales, fresh_v, page_ids, slot_ids, valid, positions,
         )
-        return k_pages, v_pages, k_scales, v_scales, state_pages
+        return k_pages, v_pages, k_scales, v_scales, state_pages, window_pages
     if fresh_k is not None:
         k_pages = _scatter_kv_pages_all_layers(
             k_pages, fresh_k.astype(k_pages.dtype), page_ids, slot_ids, valid
@@ -2283,7 +2517,7 @@ def _prefill_write(
         v_pages = _scatter_kv_pages_all_layers(
             v_pages, fresh_v.astype(v_pages.dtype), page_ids, slot_ids, valid
         )
-    return k_pages, v_pages, k_scales, v_scales, state_pages
+    return k_pages, v_pages, k_scales, v_scales, state_pages, window_pages
 
 
 @functools.partial(
@@ -2293,6 +2527,7 @@ def _prefill_write(
     ),
     donate_argnames=(
         "k_pages", "v_pages", "k_scales", "v_scales", "state_pages",
+        "window_pages",
     ),
 )
 def prefill(
@@ -2315,12 +2550,19 @@ def prefill(
     interpret: bool = False,  # Pallas kernels interpreted (CPU tests)
     *,
     state_pages=None,  # ``init_state_pages``: a model with conv layers
+    window_pages=None,  # ``init_window_pages``: a model with sliding layers
+    window_rows=None,  # its rows' (page_ids [b, s], tables [b, w], starts [b])
 ) -> tuple[jnp.ndarray, ...]:
     """Process a prompt chunk: returns (logits at last valid position per
     sequence [b, vocab], updated k_pages, v_pages), then the updated scale
     pools where the pools are int8, then the updated ``state_pages`` where
     one was given (a model with convolution layers; absent, in arguments
-    and results, for every other).
+    and results, for every other), then the updated ``window_pages`` pair
+    where one was given (a model with sliding layers; absent for every
+    other). ``window_rows``: where each token's keys and values go in the
+    window pools (the slots are ``slot_ids``), the window table of each row
+    (the window pages that hold its context from position ``starts[i]`` up
+    to ``ctx_lens[i]``) and that position.
 
     The chunk attends causally within itself AND to ``ctx_lens`` tokens of
     prefix-cached context already resident in the page pool — this is how a
@@ -2344,6 +2586,7 @@ def prefill(
         params, cfg, tokens, positions, valid, k_pages, v_pages,
         page_ids, slot_ids, block_tables, ctx_lens, mesh, attn_impl,
         interpret, k_scales, v_scales, state_pages=state_pages,
+        window_pages=window_pages, window_rows=window_rows,
     )
     if return_all_logits:
         # Every chunk position's next-token logits [b, s, vocab] — the
@@ -2353,7 +2596,9 @@ def prefill(
         logits = _logits(params, cfg, h)
     else:
         logits = _last_logits(params, cfg, h, valid)
-    return (logits,) + _prefill_results(pools, k_scales, state_pages)
+    return (logits,) + _prefill_results(
+        pools, k_scales, state_pages, window_pages
+    )
 
 
 def _check_prefill(mesh, attn_impl: str, valid, k_scales, v_scales) -> None:
@@ -2387,18 +2632,33 @@ def _last_logits(params: Params, cfg: LlamaConfig, h, valid) -> jnp.ndarray:
     return _logits(params, cfg, h_last[:, None, :])[:, 0]
 
 
-def _prefill_results(pools, k_scales, state_pages) -> tuple:
-    """``_prefill_write``'s five as ``prefill`` returns them: knob-off
+def _prefill_results(pools, k_scales, state_pages, window_pages=None) -> tuple:
+    """``_prefill_write``'s six as ``prefill`` returns them: knob-off
     callers keep the legacy (k_pages, v_pages); quantized callers get the
-    updated scale pools appended, a model with state its state pool
-    (``k_scales`` / ``state_pages``: the caller's own, None without)."""
-    k_pages, v_pages, new_k_scales, new_v_scales, new_state = pools
+    updated scale pools appended, a model with state its state pool, a
+    model with sliding layers its pair of window pools (``k_scales`` /
+    ``state_pages`` / ``window_pages``: the caller's own, None without)."""
+    k_pages, v_pages, new_k_scales, new_v_scales, new_state, new_window = pools
     out = (k_pages, v_pages)
     if k_scales is not None:
         out += (new_k_scales, new_v_scales)
     if state_pages is not None:
         out += (new_state,)
+    if window_pages is not None:
+        out += (new_window,)
     return out
+
+
+def pack_window_rows(page_ids, tables, starts) -> np.ndarray:
+    """A prefill dispatch's ``window_rows`` as ONE host array, int32 ``[b,
+    chunk + window table + 1]`` = ``[page ids | window table | start]``
+    (``prefill_packed`` slices it apart by its ``chunk``); a decode
+    dispatch's window tables and starts likewise, with no page ids
+    (``decode_steps``: ``[window table | start]``)."""
+    parts = [tables, np.asarray(starts)[:, None]]
+    if page_ids is not None:
+        parts.insert(0, page_ids)
+    return np.concatenate(parts, axis=1, dtype=np.int32)
 
 
 def pack_prefill_inputs(
@@ -2421,12 +2681,14 @@ def pack_prefill_inputs(
 def _prefill_rows(
     params: Params,
     cfg: LlamaConfig,
-    rows: tuple,  # (tokens, positions, valid, page_ids, block_tables, ctx_lens)
+    rows: tuple,  # (tokens, positions, valid, page_ids, block_tables,
+    # ctx_lens, window_rows: None for a model without sliding layers)
     k_pages,
     v_pages,
     k_scales,
     v_scales,
     state_pages,
+    window_pages=None,
     *,
     mesh,
     attn_impl: str,
@@ -2438,11 +2700,11 @@ def _prefill_rows(
     It reads the pools and writes none. A jit of its own so that
     ``prefill_packed`` traces it ONCE for the shapes of its results and for
     its loop's body."""
-    tokens, positions, valid, page_ids, block_tables, ctx_lens = rows
+    tokens, positions, valid, page_ids, block_tables, ctx_lens, window_rows = rows
     h, fresh = _prefill_forward(
         params, cfg, tokens, positions, valid, k_pages, v_pages, page_ids,
         block_tables, ctx_lens, mesh, attn_impl, interpret, k_scales,
-        v_scales, None, state_pages,
+        v_scales, None, state_pages, window_pages, window_rows,
     )
     return _last_logits(params, cfg, h, valid)[None], fresh
 
@@ -2452,6 +2714,7 @@ def _prefill_rows(
     static_argnames=("cfg", "chunk", "mesh", "attn_impl", "interpret"),
     donate_argnames=(
         "k_pages", "v_pages", "k_scales", "v_scales", "state_pages",
+        "window_pages",
     ),
 )
 def prefill_packed(
@@ -2468,11 +2731,15 @@ def prefill_packed(
     v_scales=None,
     interpret: bool = False,
     state_pages=None,
+    window_pages=None,
+    window_packed=None,  # [b, chunk + window table + 1]: ``pack_window_rows``
 ) -> tuple[jnp.ndarray, ...]:
     """``prefill`` as the engine dispatches it: the same forward, logits
     and write behind one packed operand that is sliced apart here. ``chunk``
     and the operand's width are the two buckets that key ``prefill``, so the
-    set of programs is the same.
+    set of programs is the same. A model with sliding layers brings its
+    ``window_rows`` as a second packed operand (``[page ids | window table |
+    start]``), and no other model has it.
 
     The program holds the forward (``_prefill_forward``, last-position
     logits) for ONE row of the bucketed width, inside a loop that runs as
@@ -2494,17 +2761,29 @@ def prefill_packed(
     )
     valid, ctx_lens = valid != 0, packed[:, -1]
     _check_prefill(mesh, attn_impl, valid, k_scales, v_scales)
-    rows = (tokens, positions, valid, page_ids, packed[:, 5 * chunk : -1], ctx_lens)
+    window_rows = None
+    if window_packed is not None:
+        window_rows = (
+            window_packed[:, :chunk], window_packed[:, chunk:-1],
+            window_packed[:, -1],
+        )
+    rows = (
+        tokens, positions, valid, page_ids, packed[:, 5 * chunk : -1],
+        ctx_lens, window_rows,
+    )
     forward = functools.partial(
         _prefill_rows, params, cfg, k_pages=k_pages, v_pages=v_pages,
         k_scales=k_scales, v_scales=v_scales, state_pages=state_pages,
+        window_pages=window_pages,
         mesh=mesh, attn_impl=attn_impl, interpret=interpret,
     )
     if mesh is not None or b == 1:
         logits, fresh = forward(rows)
     else:
         def row(i):
-            return tuple(jax.lax.dynamic_slice_in_dim(x, i, 1) for x in rows)
+            return jax.tree.map(
+                lambda x: jax.lax.dynamic_slice_in_dim(x, i, 1), rows
+            )
 
         def turn(i, out):
             return jax.tree.map(
@@ -2524,9 +2803,11 @@ def prefill_packed(
         )
     pools = _prefill_write(
         fresh, positions, valid, k_pages, v_pages, page_ids, slot_ids,
-        ctx_lens, k_scales, v_scales, state_pages,
+        ctx_lens, k_scales, v_scales, state_pages, window_pages, window_rows,
     )
-    return (logits[0],) + _prefill_results(pools, k_scales, state_pages)
+    return (logits[0],) + _prefill_results(
+        pools, k_scales, state_pages, window_pages
+    )
 
 
 def _decode_body(
@@ -2545,11 +2826,18 @@ def _decode_body(
     v_scales=None,
     state_pages=None,  # ``init_state_pages``: a model with conv layers
     experts_touched: Optional[list] = None,  # see ``_moe_mlp_routed``
-) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, Any, Any, Any]:
+    window_pages=None,  # ``init_window_pages``: a model with sliding layers
+    window_tables=None,  # [b, window pages] int32: its lanes' window tables
+    window_start=None,  # [b] int32: the position of each table's first slot
+) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, Any, Any, Any, Any]:
     """Single decode step (traced body shared by ``decode_step`` and the
     fused ``decode_steps`` scan). Writes this token's K/V into its page
     slot, runs paged attention over the full context, returns
-    (logits [b, vocab], k_pages, v_pages, k_scales, v_scales, state_pages)
+    (logits [b, vocab], k_pages, v_pages, k_scales, v_scales, state_pages,
+    window_pages): ``window_pages`` is a None pass-through unless the model
+    has sliding layers, each of which attends over its lane's window table
+    alone (the kernel's grid is as wide as that table, whatever the
+    context) and writes this token's K/V into the window pools
     — scales are None pass-throughs unless the pools are int8
     (``KV_QUANT_HBM``), ``state_pages`` unless the model has convolution
     layers: such a layer reads the slot of the page that holds the token
@@ -2588,14 +2876,33 @@ def _decode_body(
             positions > 0,
         )
     head_scale = cfg.hd**-0.5 if cfg.kv_heads_per_row > 1 else None
+    if any("window" in layer for layer in params["layers"]):
+        if (window_pages is None or window_tables is None
+                or mesh is not None or k_scales is not None):
+            raise ValueError(
+                "sliding layers: the window pools and the lanes' window "
+                "tables are needed; tp, sp and int8 are not run"
+            )
+        my_window_page = jnp.take_along_axis(
+            window_tables,
+            jnp.maximum(positions - window_start, 0)[:, None] // page_size,
+            axis=1,
+        )[:, 0]
 
     fresh_k = []  # per-layer [b, 1, n_kv, hd]; written to pages in one go
     fresh_v = []
     fresh_state = []  # per conv layer [b, 1, state row]
+    fresh_wk = []  # per sliding layer, as ``fresh_k``: the window pools'
+    fresh_wv = []
     aside = []  # a double layer's routed sum, from its first FFN to its second
     for layer in _sublayers(params["layers"]):
-        li = len(fresh_k)  # the layer's index in the key/value pools
-        with _scope("conv" if "conv_in" in layer else "attn"):
+        sliding = "window" in layer
+        # the layer's index in the pools it reads and writes
+        li = len(fresh_wk if sliding else fresh_k)
+        with _scope(
+            "conv" if "conv_in" in layer
+            else "attn_window" if sliding else "attn"
+        ):
             x = rms_norm(h, layer["attn_norm"], cfg.rms_norm_eps, cfg.norm_offset)
             if "conv_in" in layer:
                 out, z = _conv_operator(layer, cfg, x, states[len(fresh_state)])
@@ -2616,8 +2923,9 @@ def _decode_body(
                 )  # [b, 1, H, d_v]
             else:
                 q, k, v = _qkv(layer, cfg, x)
-                q = apply_rope(q, positions[:, None], inv_freq)
-                k = apply_rope(k, positions[:, None], inv_freq)
+                if _rotates(layer, cfg):
+                    q = apply_rope(q, positions[:, None], inv_freq)
+                    k = apply_rope(k, positions[:, None], inv_freq)
                 q, k, v = _pack_heads(cfg, q, k, v)
 
                 # The kernel takes the current token's K/V as arguments (pages hold
@@ -2627,31 +2935,48 @@ def _decode_body(
                 # kernel reads the pool in the default layout; that the write does
                 # too (it did not until PR 29: see _scatter_kv_pages_all_layers) is
                 # what tests/test_pool_layout.py holds on the compiled program.
+                # A sliding layer reads its own pools through its lane's
+                # window table, whose first slot is ``window_start``.
+                if sliding:
+                    pools = (*window_pages, window_tables)
+                    seen = dict(window=cfg.sliding_window,
+                                table_start=window_start)
+                else:
+                    pools = (k_pages, v_pages, block_tables)
+                    seen = dict(k_scale=k_scales, v_scale=v_scales)
                 attn = _unpack_heads(cfg, _paged_attention_tp(
                     q[:, 0],  # [b, n_heads, hd]
-                    k_pages,  # FULL [L, P, ps, n_kv, hd] pool; layer via index map
-                    v_pages,
-                    block_tables,
+                    *pools,  # FULL [L, P, ps, n_kv, hd] pools; layer via index map
                     seq_lens,
                     k[:, 0],  # [b, n_kv, hd]
                     v[:, 0],
                     interpret=interpret,
                     mesh=mesh,
                     layer=li,
-                    k_scale=k_scales,
-                    v_scale=v_scales,
                     scale=head_scale,
+                    **seen,
                 ))  # [b, n_heads, hd]
             if "conv_in" not in layer:
-                out = (attn.reshape(b, -1) @ _w(layer["wo"], h.dtype))[:, None, :]
-                fresh_k.append(k)
-                fresh_v.append(v)
-            h = h + out
+                out = (
+                    _attn_gate(layer, x[:, 0], attn.reshape(b, -1))
+                    @ _w(layer["wo"], h.dtype)
+                )[:, None, :]
+                (fresh_wk if sliding else fresh_k).append(k)
+                (fresh_wv if sliding else fresh_v).append(v)
+            h = h + _post_norm(layer, cfg, "attn_post_norm", out)
         h = _ffn(
             layer, cfg, h, mesh=mesh, interpret=interpret,
             touched=experts_touched, aside=aside,
         )
 
+    if fresh_wk:
+        window_pages = tuple(
+            _scatter_kv_pages_all_layers(
+                pool, jnp.stack(new).astype(pool.dtype),
+                my_window_page[:, None], my_slot[:, None], valid,
+            )
+            for pool, new in zip(window_pages, (fresh_wk, fresh_wv))
+        )
     if fresh_state:
         state_pages = _scatter_state_pages(
             state_pages, jnp.stack(fresh_state), my_page[:, None], valid
@@ -2659,7 +2984,7 @@ def _decode_body(
     if not fresh_k:  # a tree of convolution layers alone: no key or value
         return (
             _logits(params, cfg, h)[:, 0], k_pages, v_pages, k_scales,
-            v_scales, state_pages,
+            v_scales, state_pages, window_pages,
         )
     if k_scales is not None:
         k_pages, k_scales = _quantized_scatter_kv_all_layers(
@@ -2687,6 +3012,7 @@ def _decode_body(
         k_scales,
         v_scales,
         state_pages,
+        window_pages,
     )
 
 
@@ -2746,6 +3072,7 @@ def _unpack_decode_inputs(packed: jnp.ndarray):
     static_argnames=("cfg", "page_size", "interpret", "mesh"),
     donate_argnames=(
         "k_pages", "v_pages", "k_scales", "v_scales", "state_pages",
+        "window_pages",
     ),
 )
 def decode_step(
@@ -2764,20 +3091,30 @@ def decode_step(
     k_scales=None,  # [L, P, n_kv] f32 — int8 pools (KV_QUANT_HBM)
     v_scales=None,
     state_pages=None,  # ``init_state_pages``: a model with conv layers
+    window_pages=None,  # ``init_window_pages``: a model with sliding layers
+    window_tables=None,  # [b, window pages] int32
+    window_start=None,  # [b] int32: the position of each table's first slot
 ) -> tuple[jnp.ndarray, ...]:
     """One decode step; sampling stays with the caller (host or jit).
     Returns the legacy 3-tuple, with updated scale pools appended when
-    the pools are quantized and the updated state pool when one was given
-    (a model with convolution layers)."""
-    stateful = state_pages is not None
-    logits, k_pages, v_pages, k_scales, v_scales, state_pages = _decode_body(
+    the pools are quantized, the updated state pool when one was given
+    (a model with convolution layers) and the updated pair of window pools
+    when one was given (a model with sliding layers)."""
+    stateful, windowed = state_pages is not None, window_pages is not None
+    (
+        logits, k_pages, v_pages, k_scales, v_scales, state_pages,
+        window_pages,
+    ) = _decode_body(
         params, cfg, tokens, positions, k_pages, v_pages,
         block_tables, seq_lens, page_size, interpret, mesh,
-        k_scales, v_scales, state_pages,
+        k_scales, v_scales, state_pages, window_pages=window_pages,
+        window_tables=window_tables, window_start=window_start,
     )
     extra = () if k_scales is None else (k_scales, v_scales)
     if stateful:
         extra += (state_pages,)
+    if windowed:
+        extra += (window_pages,)
     return (logits, k_pages, v_pages) + extra
 
 
@@ -2786,6 +3123,7 @@ def decode_step(
     static_argnames=("cfg", "page_size", "num_steps", "interpret", "mesh"),
     donate_argnames=(
         "k_pages", "v_pages", "k_scales", "v_scales", "state_pages",
+        "window_pages",
     ),
 )
 def decode_steps(
@@ -2806,6 +3144,8 @@ def decode_steps(
     k_scales=None,  # [L, P, n_kv] f32 — int8 pools (KV_QUANT_HBM)
     v_scales=None,
     state_pages=None,  # ``init_state_pages``: a model with conv layers
+    window_pages=None,  # ``init_window_pages``: a model with sliding layers
+    window_packed=None,  # [b, window pages + 1] int32: ``pack_window_rows``
 ) -> tuple[jnp.ndarray, ...]:
     """``num_steps`` fused decode iterations with on-device sampling.
 
@@ -2816,7 +3156,11 @@ def decode_steps(
     its vLLM pods solve this on the GPU side). Returns (``burst [b, 1 +
     num_steps]`` int32, k_pages, v_pages), then the scale pools where the
     pools are int8, then ``state_pages`` where one was given (carried
-    through the scan as the pools are). ``burst[:, 1:]`` are the sampled
+    through the scan as the pools are), then the pair ``window_pages`` where
+    one was given (a model with sliding layers, whose lanes' window tables
+    and the positions they start at ride in ``window_packed``, a second
+    small operand no other model has; the tables cover the burst's growth as
+    ``block_tables`` do). ``burst[:, 1:]`` are the sampled
     tokens (behind ``burst_counts(cfg)`` columns of counts: one for every
     model but one whose routed layers are told what they hold, which has
     ``BURST_COUNTS_HELD``); ``burst[:, 0]`` is, in every lane, the number of distinct
@@ -2839,7 +3183,12 @@ def decode_steps(
     own: a chained dispatch hands them over where they lie on the device.
     """
     quantized = k_scales is not None
-    stateful = state_pages is not None
+    stateful, windowed = state_pages is not None, window_pages is not None
+    window = {}
+    if windowed:
+        window = dict(
+            window_tables=window_packed[:, :-1], window_start=window_packed[:, -1]
+        )
     if tokens.ndim == 2:
         tokens = tokens[:, -1]
     block_tables, positions, seq_lens, temperature, top_k, top_p = (
@@ -2850,26 +3199,29 @@ def decode_steps(
     any_sampled = jnp.any(temperature > 0)
 
     def body(carry, key):
-        tokens, positions, seq_lens, k_pages, v_pages, k_sc, v_sc, st = carry
+        tokens, positions, seq_lens, k_pages, v_pages, k_sc, v_sc, st, wp = carry
         touched = []
-        logits, k_pages, v_pages, k_sc, v_sc, st = _decode_body(
+        logits, k_pages, v_pages, k_sc, v_sc, st, wp = _decode_body(
             params, cfg, tokens, positions, k_pages, v_pages,
             block_tables, seq_lens, page_size, interpret, mesh,
-            k_sc, v_sc, st, experts_touched=touched,
+            k_sc, v_sc, st, experts_touched=touched, window_pages=wp,
+            **window,
         )
         nxt = sample_tokens(
             logits.astype(jnp.float32), temperature, top_k, top_p, key,
             any_sampled,
         )
         return (
-            nxt, positions + 1, seq_lens + 1, k_pages, v_pages, k_sc, v_sc, st
+            nxt, positions + 1, seq_lens + 1, k_pages, v_pages, k_sc, v_sc,
+            st, wp,
         ), (nxt, sum(touched, jnp.zeros((), jnp.int32)))
 
-    # None scales (and a None state pool) are valid (empty) scan-carry
-    # leaves, so the knob-off trace is unchanged apart from the tuple arity.
+    # None scales (and a None state pool, and None window pools) are valid
+    # (empty) scan-carry leaves, so the knob-off trace is unchanged apart
+    # from the tuple arity.
     carry0 = (
         tokens, positions, seq_lens, k_pages, v_pages, k_scales, v_scales,
-        state_pages,
+        state_pages, window_pages,
     )
     keys = jax.random.split(rng_key, num_steps)
     if num_steps == 1:
@@ -2878,12 +3230,14 @@ def decode_steps(
         # like the scan's first slice, so sampled streams are
         # bit-identical across paths.
         (
-            _, _, _, k_pages, v_pages, k_scales, v_scales, state_pages
+            _, _, _, k_pages, v_pages, k_scales, v_scales, state_pages,
+            window_pages,
         ), (nxt, n_touched) = body(carry0, keys[0])
         toks = nxt[:, None]
     else:
         (
-            _, _, _, k_pages, v_pages, k_scales, v_scales, state_pages
+            _, _, _, k_pages, v_pages, k_scales, v_scales, state_pages,
+            window_pages,
         ), (toks, n_touched) = jax.lax.scan(body, carry0, keys)
         toks, n_touched = toks.T, jnp.sum(n_touched, axis=0)
     # one column, or ``BURST_COUNTS_HELD`` of them (``burst_counts``)
@@ -2895,6 +3249,8 @@ def decode_steps(
     extra = (k_scales, v_scales) if quantized else ()
     if stateful:
         extra += (state_pages,)
+    if windowed:
+        extra += (window_pages,)
     return (burst, k_pages, v_pages) + extra
 
 

@@ -37,7 +37,7 @@ import numpy as np
 #: Parameter names eligible for quantization (matmul weights only).
 QUANTIZABLE = frozenset(
     {"wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down", "lm_head",
-     "conv_in", "conv_out"}
+     "conv_in", "conv_out", "wg"}
 )
 
 

@@ -79,6 +79,7 @@ def _flash_over_keys(
     return_accumulators: bool = False,
     init_state=None,
     block_length: int = 0,
+    window: int = 0,
 ) -> jnp.ndarray:
     """Online-softmax (flash) attention over a virtual key sequence, scanned
     in key blocks so the [s, T] score matrix is never materialized — the
@@ -96,7 +97,8 @@ def _flash_over_keys(
     block-causal: a query sees every key up to the end of its own block of
     ``block_length`` absolute positions (``models/llama.py``: generation
     by diffusion over blocks). 0 and 1 are the causal program, op for
-    op."""
+    op. ``window`` > 0 (static) narrows it: a query also sees no key more
+    than ``window - 1`` positions before itself."""
     b, s, n_kv, group, d = qf.shape
     T = k_all.shape[2]
     if block_length > 1:
@@ -135,6 +137,11 @@ def _flash_over_keys(
             vblk_valid[:, None, None, None, :]
             & (pblk[:, None, None, None, :] <= q_pos[:, None, None, :, None])
         )
+        if window:
+            mask &= (
+                pblk[:, None, None, None, :] + window
+                > q_pos[:, None, None, :, None]
+            )
         scores = jnp.where(mask, scores, _NEG_INF)
         m_new = jnp.maximum(m, scores.max(axis=-1))
         p = jnp.exp(scores - m_new[..., None]) * mask
@@ -168,6 +175,8 @@ def prefill_with_paged_context(
     k_scales: Optional[jnp.ndarray] = None,  # [total_pages, n_kv] f32
     v_scales: Optional[jnp.ndarray] = None,  # (KV_QUANT_HBM: int8 pools)
     block_length: int = 0,  # > 1: full inside a block, causal between
+    window: int = 0,  # > 0: a query sees the ``window`` positions ending at it
+    table_start: Optional[jnp.ndarray] = None,  # [batch]: the table's first position
 ) -> jnp.ndarray:
     """Chunked prefill attending to prefix-cached pages *and* causally within
     the fresh chunk.
@@ -181,6 +190,10 @@ def prefill_with_paged_context(
     With ``block_length`` > 1 the chunk is causal between blocks of that
     many absolute positions and full inside one (position ``i`` sees ``j``
     iff ``j // B <= i // B``); 0 and 1 are the causal program.
+
+    With ``window`` > 0 (a sliding layer) the context keys stand at their
+    own positions, ``table_start`` (a row; None: 0) + their slot in the
+    table, and a query sees only the ``window`` positions that end with it.
 
     One online softmax over the virtual key sequence [context ++ chunk],
     flash-scanned in ``FLASH_KEY_BLOCK``-sized key blocks (memory stays
@@ -219,16 +232,22 @@ def prefill_with_paged_context(
     k_all = jnp.concatenate([ctx_k, jnp.moveaxis(k, 1, 2)], axis=2)
     v_all = jnp.concatenate([ctx_v, jnp.moveaxis(v, 1, 2)], axis=2)
     ctx_valid = jnp.arange(max_ctx)[None, :] < ctx_lens[:, None]
+    ctx_pos = jnp.full((b, max_ctx), -1, jnp.int32)
+    if window:
+        start = (
+            jnp.zeros((b,), jnp.int32) if table_start is None
+            else table_start.astype(jnp.int32)
+        )
+        ctx_pos = jnp.arange(max_ctx, dtype=jnp.int32)[None, :] + start[:, None]
+        ctx_valid = ctx_pos < ctx_lens[:, None]
     chunk_valid = (
         valid if valid is not None else jnp.ones((b, s), bool)
     )
     k_valid = jnp.concatenate([ctx_valid, chunk_valid], axis=1)
-    k_pos = jnp.concatenate(
-        [jnp.full((b, max_ctx), -1, jnp.int32), positions.astype(jnp.int32)], axis=1
-    )
+    k_pos = jnp.concatenate([ctx_pos, positions.astype(jnp.int32)], axis=1)
 
     out = _flash_over_keys(
         qf, k_all, v_all, k_valid, k_pos, positions.astype(jnp.int32),
-        scale, FLASH_KEY_BLOCK, block_length=block_length,
+        scale, FLASH_KEY_BLOCK, block_length=block_length, window=window,
     )
     return out.reshape(b, s, n_q, d).astype(q.dtype)

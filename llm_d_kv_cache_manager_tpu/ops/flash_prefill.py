@@ -139,6 +139,7 @@ def _flash_prefill_kernel(
     table_pages: int,
     scale: float,
     block_length: int,
+    window: int,
 ):
     b = pl.program_id(0)
     qb = pl.program_id(1)
@@ -244,6 +245,10 @@ def _flash_prefill_kernel(
                 jnp.int32, (rows, bk_ctx), 1
             )
             mask = (k_idx < ctx_len) & (qb * bq + q_idx < n_valid)
+            if window:
+                # the query at chunk index i stands ``ctx_len + i`` slots
+                # after the table's first and sees ``window`` slots back
+                mask &= k_idx + window > ctx_len + qb * bq + q_idx
             attend(k, v, mask)
             return carry
 
@@ -275,6 +280,8 @@ def _flash_prefill_kernel(
         # [rows, 1], broadcasts over lanes
         q_pos = _visible_through(qb * bq + q_idx, block_length)
         mask = (k_idx <= q_pos) & (k_idx < n_valid) & (q_idx < n_valid - qb * bq)
+        if window:
+            mask &= k_idx + window > q_pos
         attend(ck_ref[0], cv_ref[0], mask)
 
     @pl.when(cks == pl.num_programs(2) - 1)
@@ -299,6 +306,7 @@ def _kernel_name(block_length: int):
     jax.jit,
     static_argnames=(
         "scale", "interpret", "q_block", "key_block", "block_length",
+        "window",
     ),
 )
 def flash_prefill_paged(
@@ -317,6 +325,8 @@ def flash_prefill_paged(
     key_block: int = KEY_BLOCK,
     block_length: int = 0,
     layer=0,
+    window: int = 0,
+    table_start: Optional[jnp.ndarray] = None,  # [batch] int32
 ) -> jnp.ndarray:
     """Pallas flash prefill over [paged context ++ fresh chunk].
 
@@ -339,8 +349,19 @@ def flash_prefill_paged(
     ``python -m tools.aot_pool_copies``). A four-dimensional pool is one
     layer (a free bitcast, layer 0). Under ``tp`` each shard passes its
     head slice of the pool and its page tile is ``[ps, n_kv / tp, hd]``.
+
+    ``window`` > 0 (a sliding layer; the pools are then the window pools and
+    ``block_tables`` a row's window table): a query sees the ``window``
+    positions that end with itself, in the context and in the chunk.
+    ``table_start`` is the position the table's first slot stands for, a row
+    (None: 0); it comes off ``ctx_lens`` here, so the kernel's operands are
+    the ones it always had and the copies follow the live window.
     """
     b, s, n_q, d = q.shape
+    if window and block_length > 1:
+        raise ValueError("no block mask over a window")
+    if table_start is not None:
+        ctx_lens = ctx_lens - table_start
     n_kv = k.shape[2]
     group = n_q // n_kv
     if scale is None:
@@ -434,6 +455,7 @@ def flash_prefill_paged(
         table_pages=table_pages,
         scale=scale,
         block_length=block_length,
+        window=window,
     )
     out = pl.pallas_call(
         kernel,

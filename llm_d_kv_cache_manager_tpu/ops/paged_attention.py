@@ -83,6 +83,7 @@ def _decode_kernel(
     scale: float,
     has_fresh: bool,
     quantized: bool,
+    window: int = 0,
 ):
     """All KV heads of one (sequence, page) in a single program: 8× fewer
     grid steps than a per-head grid, one fully-contiguous page tile
@@ -103,7 +104,14 @@ def _decode_kernel(
     the codes dequantize IN-REGISTER
     to f32 before the online softmax — full-width pages never exist
     anywhere. The ``has_fresh`` current-token path stays full-precision:
-    fresh K/V arrive unquantized and never round-trip through int8."""
+    fresh K/V arrive unquantized and never round-trip through int8.
+
+    ``window`` > 0 (a sliding layer): the token at ``seq_len - 1`` sees the
+    ``window`` positions that end with itself and no earlier one. Slots are
+    numbered from the table's first (the caller's ``table_start`` is already
+    taken off ``seq_len``), so a slot is visible when it lies at or after
+    ``seq_len - window``; a page that holds no such slot is skipped like one
+    past the history. 0 is the program it always was, op for op."""
     if quantized:
         k_scale_ref, v_scale_ref = refs[0], refs[1]  # [1, 8, n_kv] f32
         refs = refs[2:]
@@ -123,8 +131,13 @@ def _decode_kernel(
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    # Only pages holding historical tokens contribute.
-    @pl.when(p * page_size < hist)
+    # Only pages holding historical tokens contribute (inside the window,
+    # where the layer has one).
+    live = p * page_size < hist
+    if window:
+        live = jnp.logical_and(live, (p + 1) * page_size > seq_len - window)
+
+    @pl.when(live)
     def _compute():
         q = q_ref[0].astype(jnp.float32)  # [n_kv, group, d]
         # Page tile arrives [page_size, n_kv, d] (one fully-contiguous
@@ -149,7 +162,10 @@ def _decode_kernel(
         token_idx = p * page_size + jax.lax.broadcasted_iota(
             jnp.int32, scores.shape, dimension=2
         )
-        scores = jnp.where(token_idx < hist, scores, _NEG_INF)
+        visible = token_idx < hist
+        if window:
+            visible = jnp.logical_and(visible, token_idx >= seq_len - window)
+        scores = jnp.where(visible, scores, _NEG_INF)
 
         m_prev = m_ref[:, :, :1]  # [n_kv, group, 1]
         m_cur = jnp.max(scores, axis=-1, keepdims=True)
@@ -197,7 +213,7 @@ def _decode_kernel(
 
 @functools.partial(
     jax.jit,
-    static_argnames=("page_size", "scale", "interpret", "layer"),
+    static_argnames=("page_size", "scale", "interpret", "layer", "window"),
 )
 def paged_attention(
     q: jnp.ndarray,  # [batch, n_heads, head_dim]
@@ -214,6 +230,8 @@ def paged_attention(
     scale: Optional[float] = None,
     interpret: bool = False,
     layer: int = 0,
+    window: int = 0,
+    table_start: Optional[jnp.ndarray] = None,  # [batch] int32
 ) -> jnp.ndarray:
     """Batched single-token (decode) paged attention.
 
@@ -240,6 +258,14 @@ def paged_attention(
     in-register inside the kernel. The scalar-prefetch operand set
     (block_tables, seq_lens) is IDENTICAL in both variants; kvlint pins
     the full operand order against tools/kvlint/kernel_abi.json.
+
+    ``window`` > 0 (a sliding layer; ``k_pages`` / ``v_pages`` are then the
+    window pools and ``block_tables`` a row's window table): the token sees
+    the last ``window`` positions, itself among them. ``table_start`` is the
+    position the table's first slot stands for, a row (None: 0): it comes
+    off ``seq_lens`` here, so the kernel's operands are the ones above and
+    its grid is as wide as the window table, whatever the context. The call
+    is named ``paged_attention_window`` in the trace.
     """
     batch, n_heads, head_dim = q.shape
     if (k_scale is None) != (v_scale is None):
@@ -266,6 +292,8 @@ def paged_attention(
     q_blocked = q.reshape(batch, n_kv_heads, group, head_dim)
     block_tables = block_tables.astype(jnp.int32)
     seq_lens = seq_lens.astype(jnp.int32)
+    if table_start is not None:
+        seq_lens = jnp.maximum(seq_lens - table_start.astype(jnp.int32), 0)
 
     grid = (batch, max_pages)
 
@@ -322,12 +350,14 @@ def paged_attention(
         scale=scale,
         has_fresh=has_fresh,
         quantized=quantized,
+        window=window,
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((batch, n_kv_heads, group, head_dim), q.dtype),
         interpret=interpret,
+        name="paged_attention_window" if window else None,
     )(*inputs)
     return out.reshape(batch, n_heads, head_dim)
 
@@ -340,8 +370,12 @@ def paged_attention_reference(
     seq_lens: jnp.ndarray,
     *,
     scale: Optional[float] = None,
+    window: int = 0,
+    table_start: Optional[jnp.ndarray] = None,
 ) -> jnp.ndarray:
-    """Pure-jnp oracle: gather pages per sequence, mask, softmax."""
+    """Pure-jnp oracle: gather pages per sequence, mask, softmax. ``window``
+    / ``table_start`` as ``paged_attention`` takes them (every token of
+    ``seq_lens`` is in the pages here)."""
     batch, n_heads, head_dim = q.shape
     _, page_size, n_kv_heads, _ = k_pages.shape
     group = n_heads // n_kv_heads
@@ -362,7 +396,11 @@ def paged_attention_reference(
     qf = q.astype(jnp.float32).reshape(batch, n_kv_heads, group, head_dim)
     scores = jnp.einsum("bhgd,bhtd->bhgt", qf, gathered_k.astype(jnp.float32)) * scale
     token_idx = jnp.arange(max_pages * page_size)[None, None, None, :]
+    if table_start is not None:
+        seq_lens = jnp.maximum(seq_lens - table_start, 0)
     mask = token_idx < seq_lens[:, None, None, None]
+    if window:
+        mask &= token_idx >= (seq_lens - window)[:, None, None, None]
     scores = jnp.where(mask, scores, _NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1)
     probs = jnp.where(jnp.isnan(probs), 0.0, probs)  # len-0 seqs

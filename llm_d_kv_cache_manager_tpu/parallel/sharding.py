@@ -28,12 +28,14 @@ from ..models.llama import LlamaConfig, Params
 
 
 def _layer_specs(
-    cfg: LlamaConfig, tp: int = 1, routed: bool = True, conv: bool = False
+    cfg: LlamaConfig, tp: int = 1, routed: bool = True, conv: bool = False,
+    sliding: bool = False,
 ) -> dict[str, P]:
     """``routed``: the layer's FFN is the routed one (False for the leading
     dense layers of a model with ``first_k_dense``). ``conv``: its operator
     is a gated short convolution (replicated: the engine refuses tp > 1 for
-    a model with such layers)."""
+    a model with such layers). ``sliding``: it attends over a window (the
+    leaf ``window``; the engine refuses tp > 1 for such a model too)."""
     specs = {
         "attn_norm": P(),
         "wq": P(None, "tp"),
@@ -82,8 +84,16 @@ def _layer_specs(
         # Per-head-dim scale, identical across heads → replicated.
         specs["q_norm"] = P()
         specs["k_norm"] = P()
+    if cfg.attn_output_gate:
+        specs["wg"] = P(None, "tp")  # column-parallel over heads, as ``wq``
+    if cfg.sandwich_norm:
+        specs.update(attn_post_norm=P(), mlp_post_norm=P())
+    if sliding:
+        specs["window"] = P()
     if conv:
-        for name in ("wq", "wk", "wv", "wo", "bq", "bk", "bv", "q_norm", "k_norm"):
+        for name in (
+            "wq", "wk", "wv", "wo", "bq", "bk", "bv", "q_norm", "k_norm", "wg",
+        ):
             specs.pop(name, None)
         specs.update(conv_in=P(), conv_w=P(), conv_out=P())
     return specs
@@ -111,6 +121,7 @@ def param_specs(cfg: LlamaConfig, tp: int = 1) -> dict[str, Any]:
             else _layer_specs(
                 cfg, tp, routed=i >= cfg.first_k_dense,
                 conv=cfg.layer_kind(i) == "conv",
+                sliding=cfg.layer_kind(i) == "sliding",
             )
             for i in range(cfg.n_layers)
         ],

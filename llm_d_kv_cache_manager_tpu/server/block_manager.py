@@ -20,6 +20,14 @@ ecosystem, designed so the routing indexer can track this engine's cache:
   evictable page is recycled — the engine forwards them to the ZMQ
   publisher (write path of SURVEY §3.2).
 
+A model with sliding-window layers keeps those layers' keys and values in
+a second pool with page ids of its own (``WindowPool``, ``config.
+window_pages``). Everything above speaks of the first, the CONTEXT pool: a
+page there lives as long as its prefix does, and an event asserts that the
+full layers' keys and values of a block are held. A window page lives while
+some sequence stands less than a window past it, or while it is part of the
+last window of something a sequence left behind; it publishes nothing.
+
 The allocator is *width-agnostic*: it tracks page identity, hashes, and
 tier membership (HBM / host DRAM / remote) but never touches page bytes,
 so the same lifecycle drives full-width bf16 pools and the int8 pools of
@@ -56,6 +64,192 @@ class BlockManagerConfig:
     #: host-DRAM offload tier capacity in pages (0 = disabled). Evicted
     #: HBM pages spill here instead of vanishing; prefix hits restore them.
     host_pages: int = 0
+    #: the window pool of a model with sliding-window layers: its pages
+    #: (ids of their own, page 0 reserved) and the window in tokens. The
+    #: engine sets both from the model; 0 = no such layers, no second pool.
+    window_pages: int = 0
+    sliding_window: int = 0
+
+
+class WindowPool:
+    """The sliding layers' pages: ids of their own, hashes shared with the
+    context pool (a window page of block ``i`` is found under the chain hash
+    the context page of block ``i`` has), reference counts, and an order in
+    which pages nobody holds are used again.
+
+    A query at position ``t`` of a sliding layer sees ``(t - W, t]``, so a
+    sequence holds the window pages from ``first_block(t)`` on and GIVES
+    BACK the ones before as it moves (``BlockManager.reserve_window``), and
+    a prefix hit of length ``L`` needs the cached run of blocks
+    ``first_block(L) .. L / page - 1`` whole (``longest_run``) and no window
+    page before it.
+
+    Pages nobody holds are used again in this order: never written or
+    unhashed ones (``_free``); GIVEN BACK ones, oldest first (``_passed``: a
+    sequence moved a window past them, or a hit took the run after them and
+    passed them over; such a page can serve only a hit that ends less than a
+    window after it, and the sequence that went on has just shown where the
+    hits on its chain end); pages a finished sequence LEFT inside its last
+    window, least recently left first (``_left``); last, pages that SERVED A
+    HIT since they were last passed over, least recently used first
+    (``_kept``). The last class is what keeps a shared document's last
+    window through the gaps between the requests that ask of it: every one
+    of them takes the run, moves on past its first pages and gives them
+    back, and the next request needs them again; given back into ``_passed``
+    they would be the first pages reused. A window page that is reused this
+    way loses its hash and publishes nothing: the context page of its block
+    stays, and the next hit there is cut back (``window_short_hits``)."""
+
+    def __init__(self, n_pages: int, window: int, page_size: int):
+        if n_pages < 2 or window < 1:
+            raise ValueError("a window pool needs pages (page 0 is reserved) "
+                             "and a window")
+        self.n_pages, self.window, self.page_size = n_pages, window, page_size
+        self._free: list[int] = list(range(n_pages - 1, 0, -1))
+        self._ref: dict[int, int] = {}  # page -> holders, allocated pages
+        self._hash: dict[int, int] = {}  # page -> chain hash, registered pages
+        self._cached: dict[int, int] = {}  # chain hash -> page
+        self._served: set[int] = set()  # took part in a hit since passed over
+        self._passed: OrderedDict[int, None] = OrderedDict()
+        self._left: OrderedDict[int, None] = OrderedDict()
+        self._kept: OrderedDict[int, None] = OrderedDict()
+        #: monotone: pages given back by sequences that moved on, cached
+        #: pages that lost their hash (reused, or gone with their context
+        #: page), hits ``longest_run`` cut and the tokens it cut off
+        self.stats = {
+            "window_pages_dropped": 0,
+            "window_pages_evicted": 0,
+            "window_short_hits": 0,
+            "window_short_hit_tokens": 0,
+        }
+
+    def first_block(self, pos: int) -> int:
+        """The first block the query at position ``pos`` sees a slot of."""
+        return max(pos - self.window + 1, 0) // self.page_size
+
+    @property
+    def num_free(self) -> int:
+        """Pages an allocation may take: every one no sequence holds."""
+        return (len(self._free) + len(self._passed) + len(self._left)
+                + len(self._kept))
+
+    @property
+    def num_held(self) -> int:
+        """Pages a sequence holds or that are kept because they served a hit:
+        the pool's, less the free, the given back and what finished
+        sequences left that no hit has taken since (those fill whatever is
+        spare, as any cache does, and are reused before a kept page is)."""
+        return (self.n_pages - 1 - len(self._free) - len(self._passed)
+                - len(self._left))
+
+    def _idle(self, page: int) -> Optional[OrderedDict]:
+        for order in (self._passed, self._left, self._kept):
+            if page in order:
+                return order
+        return None
+
+    def _forget(self, page: int) -> None:
+        """A cached page loses its hash (it is reused, or its context page
+        was evicted)."""
+        del self._cached[self._hash.pop(page)]
+        self._served.discard(page)
+        self.stats["window_pages_evicted"] += 1
+
+    def pop(self, spare: bool = False) -> int:
+        """A page for a sequence to write (reference count 1). ``spare``: a
+        page nothing is lost by taking (free or given back), or the reserved
+        page 0 where there is none."""
+        orders = (self._passed,) if spare else (
+            self._passed, self._left, self._kept)
+        if self._free:
+            page = self._free.pop()
+        else:
+            for order in orders:
+                if order:
+                    page, _ = order.popitem(last=False)
+                    self._forget(page)
+                    break
+            else:
+                if spare:
+                    return 0
+                raise AllocationError("window page pool exhausted")
+        self._ref[page] = 1
+        return page
+
+    def take(self, page: int) -> None:
+        """One more holder of a cached page (a hit)."""
+        order = self._idle(page)
+        if order is not None:
+            del order[page]
+        self._ref[page] = self._ref.get(page, 0) + 1
+        self._served.add(page)
+
+    def release(self, page: int, given_back: bool) -> None:
+        """One holder less. ``given_back``: the holder moved a window past
+        the page (else it finished, or was preempted, inside it)."""
+        self.stats["window_pages_dropped"] += given_back
+        self._ref[page] -= 1
+        if self._ref[page]:
+            return
+        del self._ref[page]
+        if page not in self._hash:
+            self._free.append(page)
+        elif page in self._served:
+            self._kept[page] = None
+        elif given_back:
+            self._passed[page] = None
+        else:
+            self._left[page] = None
+
+    def register(self, page: int, h: int) -> None:
+        """A full page's hash; a block some other page is cached under keeps
+        that page (this one then frees like a partial one)."""
+        if page not in self._hash and h not in self._cached:
+            self._hash[page] = h
+            self._cached[h] = page
+
+    def longest_run(self, hashes: Seq[int], n: int) -> int:
+        """The largest ``m <= n`` for which a hit of ``m`` blocks has its
+        window: the blocks ``first_block(m * page) .. m - 1`` all cached."""
+        missing = -1  # the last block before ``m`` that is not cached
+        last_missing = []
+        for i in range(n + 1):
+            last_missing.append(missing)
+            if i < n and hashes[i] not in self._cached:
+                missing = i
+        for m in range(n, 0, -1):
+            if last_missing[m] < self.first_block(m * self.page_size):
+                return m
+        return 0
+
+    def take_run(self, hashes: Seq[int], m: int) -> tuple[int, list[int]]:
+        """The window of a hit of ``m`` blocks: (its first block, its pages,
+        each with one more holder). Cached pages of the chain before it that
+        nobody holds are passed over: given back."""
+        first = self.first_block(m * self.page_size)
+        for h in hashes[:first]:
+            page = self._cached.get(h)
+            order = None if page is None else self._idle(page)
+            if order is not None and order is not self._passed:
+                del order[page]
+                self._served.discard(page)
+                self._passed[page] = None
+        pages = [self._cached[h] for h in hashes[first:m]]
+        for page in pages:
+            self.take(page)
+        return first, pages
+
+    def evict_hash(self, h: int) -> None:
+        """The context page of block ``h`` is evicted: its window page, if
+        it has one, goes with it (free, if nobody holds it)."""
+        page = self._cached.get(h)
+        if page is None:
+            return
+        order = self._idle(page)
+        self._forget(page)
+        if order is not None:
+            del order[page]
+            self._free.append(page)
 
 
 @dataclass
@@ -92,6 +286,18 @@ class BlockManager:
         # evictable cached pages (ref_count == 0), LRU order
         self._evictable: OrderedDict[int, None] = OrderedDict()  # page ids
         self._pending_events: list[Event] = []
+        #: the sliding layers' pages (None: the model has no such layer and
+        #: nothing below reads it)
+        self.window: Optional[WindowPool] = None
+        if config.window_pages:
+            if config.host_pages:
+                raise ValueError(
+                    "a window pool is incompatible with host_pages > 0 (the "
+                    "host tier moves the context pool's pages alone)"
+                )
+            self.window = WindowPool(
+                config.window_pages, config.sliding_window, config.page_size
+            )
         # -- host-DRAM tier (SURVEY §2.3 device-tier mapping) --------------
         # The engine attaches the actual KV movers via attach_host_pool();
         # this class only does the tiering bookkeeping.
@@ -447,6 +653,8 @@ class BlockManager:
                         info.chain_hash, "none", "evict", tenant=info.tenant
                     )
             self._emit(BlockRemoved(block_hashes=[info.chain_hash], medium="tpu_hbm"))
+            if self.window is not None:
+                self.window.evict_hash(info.chain_hash)
             self._pages[page] = _PageInfo(ref_count=1, tenant=self._alloc_tenant)
             return page
         raise AllocationError("KV page pool exhausted")
@@ -769,6 +977,22 @@ class BlockManager:
             page = block_table.pop()
             self._decref(page)
             cached_tokens -= ps
+        if self.window is not None:
+            # A hit needs both: cut back to the longest prefix whose last
+            # window the window pool still holds whole, and take that run.
+            n_hit = self.window.longest_run(hashes, len(block_table))
+            if n_hit < len(block_table):
+                self.window.stats["window_short_hits"] += 1
+                self.window.stats["window_short_hit_tokens"] += (
+                    len(block_table) - n_hit
+                ) * ps
+                for page in block_table[n_hit:]:
+                    self._decref(page)
+                del block_table[n_hit:]
+                cached_tokens = n_hit * ps
+            seq.window_first, seq.window_table = self.window.take_run(
+                hashes, n_hit
+            )
 
         n_pages_needed = -(-len(tokens) // ps)
         try:
@@ -777,6 +1001,7 @@ class BlockManager:
         except AllocationError:
             for page in block_table:
                 self._decref(page)
+            self._free_window(seq)
             raise
 
         seq.block_table = block_table
@@ -812,7 +1037,63 @@ class BlockManager:
     def can_allocate(self, seq: Sequence) -> bool:
         # Conservative: ignores prefix-cache hits (which only reduce demand).
         ps = self.config.page_size
-        return -(-len(seq.prompt_tokens) // ps) <= self.num_free
+        need = -(-len(seq.prompt_tokens) // ps)
+        if self.window is not None and (
+            min(need, self.window.window // ps + 2) > self.window.num_free
+        ):
+            # the pages of a last window and its boundary, or of the prompt
+            return False
+        return need <= self.num_free
+
+    def reserve_window(
+        self, seq: Sequence, query_pos: int, end: int, chunk: bool = False
+    ) -> None:
+        """The window pages of a dispatch whose first query stands at
+        ``query_pos`` and which writes the positions up to ``end - 1``: the
+        pages before ``first_block(query_pos)`` are GIVEN BACK (every
+        position in them lies a window behind every query to come), pages
+        are taken through ``end - 1``. ``chunk``: one prefill chunk, whose
+        queries read the chunk's own keys from the dispatch and not from
+        pages: a block of it that already lies a window behind ``end`` is
+        stored only for the hits it may serve, so it takes a spare page
+        (free or given back) or, where there is none, the reserved page 0
+        (its keys are written there and never read); either way it is given
+        back with the next call, and a chunk needs no more than a window's
+        pages however long it is. On exhaustion the growth so far is
+        kept, as ``reserve_slots`` keeps it. A model without sliding layers
+        returns at once."""
+        w = self.window
+        if w is None:
+            return
+        ps = self.config.page_size
+        # (a block goes once it has its hash: a long chunk's blocks, behind
+        # the window before ``register_full_pages`` saw them, wait a call)
+        drop = min(w.first_block(query_pos), seq.num_registered_pages) - (
+            seq.window_first)
+        drop = min(drop, len(seq.window_table))
+        if drop > 0:
+            for page in seq.window_table[:drop]:
+                if page:
+                    w.release(page, given_back=True)
+            del seq.window_table[:drop]
+            seq.window_first += drop
+        if not seq.window_table:  # nothing held: start where the window does
+            seq.window_first = max(seq.window_first, w.first_block(query_pos))
+        written_from = w.first_block(end) if chunk else 0
+        while (seq.window_first + len(seq.window_table)) * ps < end:
+            block = seq.window_first + len(seq.window_table)
+            seq.window_table.append(w.pop(spare=block < written_from))
+
+    def _free_window(self, seq: Sequence) -> None:
+        """Release ``seq``'s window pages: given back where they lie a window
+        behind its end, left where they lie inside its last window."""
+        if self.window is None:
+            return
+        kept_from = self.window.first_block(seq.num_computed) - seq.window_first
+        for i, page in enumerate(seq.window_table):
+            if page:
+                self.window.release(page, given_back=i < kept_from)
+        seq.window_table, seq.window_first = [], 0
 
     def append_slot(self, seq: Sequence) -> None:
         """Ensure capacity for one more token during decode; allocates a new
@@ -821,6 +1102,7 @@ class BlockManager:
         if seq.num_tokens > len(seq.block_table) * ps:
             self._alloc_tenant = seq.tenant
             seq.block_table.append(self._pop_free_page())
+        self.reserve_window(seq, seq.num_tokens - 1, seq.num_tokens)
 
     def reserve_slots(self, seq: Sequence, n: int) -> None:
         """Ensure KV-slot capacity for a fused decode burst: positions up to
@@ -833,6 +1115,7 @@ class BlockManager:
         self._alloc_tenant = seq.tenant
         while len(seq.block_table) < needed:
             seq.block_table.append(self._pop_free_page())
+        self.reserve_window(seq, seq.num_tokens - 1, seq.num_tokens + n - 1)
 
     def register_full_pages(self, seq: Sequence) -> None:
         """Hash + cache-register any newly-completed pages of ``seq`` and
@@ -855,6 +1138,10 @@ class BlockManager:
         for i in range(seq.num_registered_pages, n_full):
             block = tuple(int(t) for t in tokens[i * ps : (i + 1) * ps])
             h = hash_block(parent, block)
+            if self.window is not None:
+                held = i - seq.window_first
+                if 0 <= held < len(seq.window_table) and seq.window_table[held]:
+                    self.window.register(seq.window_table[held], h)
             page = seq.block_table[i]
             info = self._pages[page]
             if info.chain_hash is None:
@@ -887,3 +1174,4 @@ class BlockManager:
         for page in seq.block_table:
             self._decref(page)
         seq.block_table = []
+        self._free_window(seq)

@@ -388,9 +388,61 @@ class Engine:
             // ps
         )
 
-        self.block_manager = BlockManager(config.block_manager, on_events=on_events)
         import dataclasses
         import math
+
+        if cfg.n_window_layers:
+            # Sliding layers keep their keys and values in a window pool
+            # with page ids of its own (``llama.init_window_pages``,
+            # ``block_manager.WindowPool``): what does not know the second
+            # pool is refused here by name. Unset, the window pool has as
+            # many pages as the context pool.
+            refused = {
+                "host_pages > 0 (the host tier moves the context pool's "
+                "pages alone)": config.block_manager.host_pages > 0,
+                "remote_tier (demotion payloads are the context pool's "
+                "pages)": config.remote_tier,
+                "kv_quant_hbm (the window pool has no int8 form)":
+                    config.kv_quant_hbm is not None,
+                "tp > 1 (the window kernels run on one shard)": config.tp > 1,
+                "sp > 1 (the ring knows no window)": config.sp > 1,
+                "spec_decode (the verify scan is not run over a window "
+                "pool)": config.spec_decode != "off",
+                "block_length > 0 (no block mask over a window)":
+                    cfg.block_length > 0,
+                "conv layers or kv_lora_rank > 0 (one second pool a model)":
+                    cfg.n_conv_layers > 0 or cfg.kv_lora_rank > 0,
+                f"sliding_window={cfg.sliding_window} (whole pages of {ps})":
+                    cfg.sliding_window < ps or cfg.sliding_window % ps != 0,
+            }
+            for what, on in refused.items():
+                if on:
+                    raise ValueError(
+                        f"layer_types with {cfg.n_window_layers} sliding "
+                        f"layers (a window pool beside the KV pool) is "
+                        f"incompatible with {what}"
+                    )
+            config.block_manager = dataclasses.replace(
+                config.block_manager,
+                window_pages=config.block_manager.window_pages
+                or config.block_manager.total_pages,
+                sliding_window=cfg.sliding_window,
+            )
+            # a lone sequence holds at most the run of a hit and the last
+            # window of a long chunk at once
+            least = 2 * (cfg.sliding_window // ps + 2) + 1
+            if config.block_manager.window_pages < least:
+                raise ValueError(
+                    f"window_pages={config.block_manager.window_pages}: a "
+                    f"window of {cfg.sliding_window} tokens in pages of {ps} "
+                    f"needs at least {least}"
+                )
+        elif config.block_manager.window_pages:
+            # a number a model without sliding layers never reads
+            config.block_manager = dataclasses.replace(
+                config.block_manager, window_pages=0
+            )
+        self.block_manager = BlockManager(config.block_manager, on_events=on_events)
 
         cpt = config.scheduler.chunked_prefill_tokens
         if cpt is not None and cpt < 1:
@@ -686,6 +738,26 @@ class Engine:
         self.state_bytes_per_token = (
             0 if self.state_pages is None else self.state_pages.nbytes
         ) // (config.block_manager.total_pages * ps)
+        #: the sliding layers' window pools, a (K, V) pair with page ids of
+        #: their own (None: the model has no such layer), what a token slot
+        #: costs there (``/stats`` reports it beside ``kv_bytes_per_token``,
+        #: which is then the full layers' alone) and how wide a decode
+        #: dispatch's window table is: a window, the page its first slot
+        #: lies in, and what two bursts add (one may be in flight)
+        self.window_pages: Optional[tuple] = llama.init_window_pages(
+            cfg, config.block_manager.window_pages, ps,
+            sharding=self._replicated,
+        )
+        self.window_bytes_per_token = 0
+        self.window_table_pages = 0
+        if self.window_pages is not None:
+            self.window_bytes_per_token = sum(
+                pool.nbytes for pool in self.window_pages
+            ) // (config.block_manager.window_pages * ps)
+            self.window_table_pages = (
+                cfg.sliding_window // ps + 2
+                + -(-2 * config.decode_steps_per_iter // ps)
+            )
         #: how many of the model's layers route their rows to experts, read
         #: from the layers' own parameters as ``llama._mlp`` reads them:
         #: ``step_stats["experts_touched"]`` sums over these, so ``/stats``
@@ -939,7 +1011,11 @@ class Engine:
         #: lengths, summed over the decode dispatches: the rows the
         #: ``mla_decode`` kernel must read, a layer). Every model:
         #: ``attn_ctx_tokens``, the same sum (what each layer that attends
-        #: reads of its pool in the fused decode dispatches).
+        #: reads of its pool in the fused decode dispatches; a full layer,
+        #: in a model with sliding ones). Sliding layers:
+        #: ``window_ctx_tokens``, the sum of ``min(context, window)`` over
+        #: the same lanes and steps (what a sliding layer reads of the
+        #: window pool; 0 for a model without such layers).
         #: Off by default: ``obs_step_timing=False`` skips every clock
         #: read and every count, so the legacy step path is untouched.
         self.obs_step_timing = False
@@ -962,6 +1038,7 @@ class Engine:
             "held_places": 0,
             "latent_ctx_tokens": 0,
             "attn_ctx_tokens": 0,
+            "window_ctx_tokens": 0,
             "prefill_s": 0.0,
             "decode_s": 0.0,
             "sample_s": 0.0,
@@ -1588,10 +1665,11 @@ class Engine:
         bytes, so a full-width figure here would overestimate pull cost
         ~2x and wrongly decline break-even pulls."""
         cfg = self.model_cfg
-        if cfg.kv_lora_rank or cfg.n_conv_layers:
+        if cfg.kv_lora_rank or cfg.n_conv_layers or cfg.n_window_layers:
             # one pool of latent rows, or the attention layers' K and V
-            # beside the convolution layers' state: a block is one page of
-            # every pool the model has
+            # beside the convolution layers' state, or the full layers' K
+            # and V alone (a window page never leaves the engine): a block
+            # is one page of every pool whose pages live as long as a prefix
             return self.page_size * (
                 self.kv_bytes_per_token + self.state_bytes_per_token
             )
@@ -1622,6 +1700,13 @@ class Engine:
                 f"layers (convolution state beside the KV pool) is "
                 f"incompatible with {what} (export, import and migration "
                 f"move K and V pages and no state)"
+            )
+        if self.model_cfg.n_window_layers:
+            raise ValueError(
+                f"layer_types with {self.model_cfg.n_window_layers} sliding "
+                f"layers (a window pool beside the KV pool) is incompatible "
+                f"with {what} (export, import and migration move the "
+                f"context pool's pages and no window page)"
             )
 
     def export_kv_blocks(self, hashes: list, max_blocks: Optional[int] = None):
@@ -2085,7 +2170,7 @@ class Engine:
         the sequence (migration committed) or clear ``importing``
         (fallback: local recompute, pages back to baseline). Engine
         thread only."""
-        if self.model_cfg.n_conv_layers:
+        if self.model_cfg.n_conv_layers or self.model_cfg.n_window_layers:
             self._refuse_latent_page_moves("freeze_for_migration")
         seq = None
         for cand in (
@@ -2285,6 +2370,21 @@ class Engine:
                 # so there is nothing to forward.
                 self.scheduler.on_prefill_done(seqs)
                 return
+            if self.window_pages is not None:
+                # The chunks' window pages, given back and taken before any
+                # row is built: taking may preempt (a mid-prefill batchmate
+                # too, in chunked mode), and a preempted row leaves the batch.
+                rows = [
+                    (seq, n) for seq, n in zip(seqs, chunks)
+                    if seq.block_table and self._reserve_window_or_requeue(
+                        seq, seq.num_prefilled, seq.num_prefilled + n
+                    )
+                ]
+                # (a later row's reservation may have preempted an earlier one)
+                rows = [(seq, n) for seq, n in rows if seq.block_table]
+                if not rows:
+                    return
+                seqs, chunks = [s for s, _ in rows], [n for _, n in rows]
             # Static shapes for jit-cache stability: batch padded to the
             # configured prefill width, chunk length and context pages
             # bucketed. The width is bucketed on what is left of the whole
@@ -2311,6 +2411,18 @@ class Engine:
             ctx_pages = _round_up(max_ctx, self.config.prefill_ctx_bucket)
             ctx_bt = np.zeros((b, ctx_pages), np.int32)
             ctx_lens = np.zeros((b,), np.int32)
+            windowed = self.window_pages is not None
+            if windowed:
+                # the rows' window tables (the window pages that hold their
+                # context, from the block ``window_first`` on), bucketed as
+                # the context tables are, and their tokens' window pages
+                w_ctx = _round_up(
+                    max(s.num_prefilled // ps - s.window_first for s in seqs),
+                    self.config.prefill_ctx_bucket,
+                )
+                w_tables = np.zeros((b, w_ctx), np.int32)
+                w_page_ids = np.zeros((b, chunk), np.int32)
+                w_starts = np.zeros((b,), np.int32)
 
             for i, (seq, n) in enumerate(zip(seqs, chunks)):
                 start = seq.num_prefilled
@@ -2323,13 +2435,26 @@ class Engine:
                 n_ctx_pages = start // ps
                 ctx_bt[i, :n_ctx_pages] = seq.block_table[:n_ctx_pages]
                 ctx_lens[i] = start
+                if windowed:
+                    held = np.asarray(seq.window_table, np.int32)
+                    n_w = n_ctx_pages - seq.window_first
+                    w_tables[i, :n_w] = held[:n_w]
+                    w_page_ids[i, :n] = held[pos // ps - seq.window_first]
+                    w_starts[i] = seq.window_first * ps
 
             packed = llama.pack_prefill_inputs(
                 tokens, positions, valid, page_ids, slot_ids, ctx_bt, ctx_lens
             )
 
+            uploads = [packed]
+            if windowed:
+                uploads.append(
+                    llama.pack_window_rows(w_page_ids, w_tables, w_starts)
+                )
+
         with self.phase("prefill_put"):
-            (packed_d,) = self._stage("prefill", packed)
+            packed_d, *window_d = self._stage("prefill", *uploads)
+            window = dict(zip(("window_packed",), window_d))
             t0 = time.perf_counter()
         with self.phase("prefill_dispatch"):
             out = llama.prefill_packed(
@@ -2345,6 +2470,7 @@ class Engine:
                 v_scales=self.v_scales,
                 interpret=self.config.interpret,
                 **self._state_arg(),
+                **window,
             )
             logits = self._keep_pools(out)
         if diffusion:
@@ -2394,21 +2520,27 @@ class Engine:
                 self.block_manager.register_full_pages(seq)
 
     def _state_arg(self) -> dict:
-        """The state pool as ``llama.prefill`` / ``decode_steps`` take it:
-        a keyword a model without convolution layers never sees."""
-        if self.state_pages is None:
-            return {}
-        return {"state_pages": self.state_pages}
+        """The pool beside the key/value pools as ``llama.prefill`` /
+        ``decode_steps`` take it: the state pool or the pair of window pools,
+        a keyword a model without convolution or sliding layers never
+        sees."""
+        if self.state_pages is not None:
+            return {"state_pages": self.state_pages}
+        if self.window_pages is not None:
+            return {"window_pages": self.window_pages}
+        return {}
 
     def _keep_pools(self, out: tuple):
         """Take back the donated pools a model program returned after its
         first result, ``(first, k_pages, v_pages[, k_scales, v_scales][,
-        state_pages])``; returns the first."""
+        state_pages][, window_pages])``; returns the first."""
         first, self.k_pages, self.v_pages, *rest = out
         if self.k_scales is not None:
             self.k_scales, self.v_scales, *rest = rest
         if self.state_pages is not None:
             (self.state_pages,) = rest
+        if self.window_pages is not None:
+            (self.window_pages,) = rest
         return first
 
     def _decode_table_width(self, seqs: list[Sequence]) -> int:
@@ -2603,16 +2735,29 @@ class Engine:
             packed = llama.pack_decode_inputs(
                 positions, block_tables, seq_lens, temperature, top_k, top_p
             )
+            uploads = [packed]
+            if self.window_pages is not None:
+                # the lanes' window tables, one fixed width (a window and
+                # what a boundary and two bursts add), and where each starts
+                w_tables = np.zeros((lanes, self.window_table_pages), np.int32)
+                w_starts = np.zeros((lanes,), np.int32)
+                for i, seq in enumerate(active):
+                    w_tables[i, : len(seq.window_table)] = seq.window_table
+                    w_starts[i] = seq.window_first * self.page_size
+                uploads.append(llama.pack_window_rows(None, w_tables, w_starts))
 
         with self.phase("decode_put"):
             key = self._draw_key(temperature)
             if prev is not None:
                 # chained: the burst's sampled ids stay on the device, placed
                 # as an upload is (no copy where they already lie so)
-                (packed_d,) = self._stage("decode", packed)
+                packed_d, *window_d = self._stage("decode", *uploads)
                 tokens_d = jax.device_put(prev["toks"], self._replicated)
             else:
-                packed_d, tokens_d = self._stage("decode", packed, tokens)
+                packed_d, tokens_d, *window_d = self._stage(
+                    "decode", packed, tokens, *uploads[1:]
+                )
+            window = dict(zip(("window_packed",), window_d))
         with self.phase("decode_dispatch"):
             out = llama.decode_steps(
                 self.params,
@@ -2629,6 +2774,7 @@ class Engine:
                 k_scales=self.k_scales,
                 v_scales=self.v_scales,
                 **self._state_arg(),
+                **window,
             )
             toks = self._keep_pools(out)
         self._count_decode_dispatch(
@@ -3086,6 +3232,35 @@ class Engine:
         """Grow ``seq`` by one slot, preempting on pool exhaustion."""
         self._grow_or_preempt(seq, lambda: self.block_manager.append_slot(seq))
 
+    def _reserve_window_or_requeue(
+        self, seq: Sequence, start: int, end: int
+    ) -> bool:
+        """The window pages of ``seq``'s prefill chunk ``[start, end)``
+        (``BlockManager.reserve_window``). Where the window pool is dry,
+        victims are preempted as ``_grow_or_preempt`` preempts them; with
+        none left ``seq`` itself goes back to the head of the queue (its
+        batchmates of one admission walk may together have taken what each
+        was promised alone) and False is returned."""
+        from .block_manager import AllocationError
+
+        while True:
+            try:
+                self.block_manager.reserve_window(seq, start, end, chunk=True)
+                return True
+            except AllocationError:
+                victim = self._pick_victim(seq) or seq
+                log.warning(
+                    "preempting sequence for window pages",
+                    victim=victim.seq_id,
+                    for_seq=seq.seq_id,
+                )
+                self.scheduler.on_preempted(victim)
+                self.block_manager.free_sequence(victim)
+                victim.fold_for_preemption()
+                self.scheduler.waiting.appendleft(victim)
+                if victim is seq:
+                    return False
+
     def _bring_back_cost_s(self, cand: Sequence) -> float:
         """Modeled cost of preempting ``cand`` and bringing it back later:
         registered pages survive in the prefix cache or spill to the
@@ -3253,6 +3428,13 @@ class Engine:
                 self.step_stats["attn_ctx_tokens"] += ctx
                 if self.model_cfg.kv_lora_rank:
                     self.step_stats["latent_ctx_tokens"] += ctx
+                if self.window_pages is not None:
+                    # a sliding layer reads at most a window of each context
+                    w = self.model_cfg.sliding_window
+                    self.step_stats["window_ctx_tokens"] += int(sum(
+                        np.minimum(seq_lens[seq_lens > 0] + j, w).sum()
+                        for j in range(steps)
+                    ))
 
     def _sample(self, logits: jnp.ndarray, seqs: list[Sequence]) -> np.ndarray:
         """First tokens of a prefill batch (decode samples on the device,

@@ -85,6 +85,13 @@ class Sequence:
     status: SequenceStatus = SequenceStatus.WAITING
     output_tokens: list[int] = field(default_factory=list)
     block_table: list[int] = field(default_factory=list)
+    #: a model with sliding-window layers: the window pool's pages that hold
+    #: the blocks ``window_first, window_first + 1, ...`` of this sequence
+    #: (its last window and what it is computing; 0 for a block that is
+    #: never read). The block manager gives the front back as the sequence
+    #: moves on. Empty for every other model.
+    window_table: list[int] = field(default_factory=list)
+    window_first: int = 0
     #: tokens whose K/V are resident in pages (cached prefix + processed)
     num_computed: int = 0
     #: tokens of the prompt served from the prefix cache

@@ -381,6 +381,13 @@ class _ServingMetrics:
                 registry=self.registry,
             )
             self._attn_ctx_seen = 0
+            self.engine_window_ctx = prom.Counter(
+                "kvcache_engine_window_ctx_tokens_total",
+                "Context rows the fused decode dispatches read a sliding "
+                "layer: the real lanes' min(context, window), summed",
+                registry=self.registry,
+            )
+            self._window_ctx_seen = 0
             self.engine_chained = prom.Counter(
                 "kvcache_engine_decode_chained_dispatches_total",
                 "Decode dispatches enqueued one ahead: their input ids came "
@@ -416,6 +423,28 @@ class _ServingMetrics:
                 "pool, all such layers (0: the model has none)",
                 registry=self.registry,
             )
+            self.window_bytes_per_token_g = prom.Gauge(
+                "kvcache_window_bytes_per_token",
+                "Bytes one token slot holds in the sliding layers' window "
+                "pools, all such layers (0: the model has none)",
+                registry=self.registry,
+            )
+            self.window_pages_held_g = prom.Gauge(
+                "kvcache_window_pages_held",
+                "Window pages a sequence holds or a hit may still take (the "
+                "pool's, less the free and the given back)",
+                registry=self.registry,
+            )
+            self.window_events = prom.Counter(
+                "kvcache_window_pages_total",
+                "Window pool: pages given back by sequences that moved on "
+                "(pages_dropped), cached pages that lost their hash "
+                "(pages_evicted), prefix hits cut for want of their last "
+                "window (short_hits) and the tokens cut off "
+                "(short_hit_tokens)",
+                ["event"], registry=self.registry,
+            )
+            self._window_seen: dict = {}
             # Host-DRAM tier + prefetch (ISSUE 6): tier occupancy, pages
             # served back from host DRAM (by path: ahead-of-scheduler
             # prefetch vs blocking allocate), and prefetch-round wall time.
@@ -643,6 +672,10 @@ class _ServingMetrics:
         if attn_ctx > self._attn_ctx_seen:
             self.engine_attn_ctx.inc(attn_ctx - self._attn_ctx_seen)
             self._attn_ctx_seen = attn_ctx
+        window_ctx = step_stats.get("window_ctx_tokens", 0)
+        if window_ctx > self._window_ctx_seen:
+            self.engine_window_ctx.inc(window_ctx - self._window_ctx_seen)
+            self._window_ctx_seen = window_ctx
         chained = step_stats.get("decode_chained_dispatches", 0)
         if chained > self._chained_seen:
             self.engine_chained.inc(chained - self._chained_seen)
@@ -661,14 +694,27 @@ class _ServingMetrics:
 
     def set_engine_gauges(
         self, occupancy: float, free_pages: int, kv_bytes_per_token: int,
-        state_bytes_per_token: int = 0,
+        state_bytes_per_token: int = 0, window_bytes_per_token: int = 0,
+        window=None,
     ) -> None:
+        """``window``: the block manager's ``WindowPool`` (None: the model
+        has no sliding layers); its monotone counts are mirrored by delta."""
         if self._prom is None or not self._obs:
             return
         self.engine_occupancy.set(occupancy)
         self.engine_free_pages.set(free_pages)
         self.kv_bytes_per_token_g.set(kv_bytes_per_token)
         self.state_bytes_per_token_g.set(state_bytes_per_token)
+        self.window_bytes_per_token_g.set(window_bytes_per_token)
+        if window is not None:
+            self.window_pages_held_g.set(window.num_held)
+            for key, count in window.stats.items():
+                seen = self._window_seen.get(key, 0)
+                if count > seen:
+                    self.window_events.labels(
+                        event=key.removeprefix("window_")
+                    ).inc(count - seen)
+                    self._window_seen[key] = count
 
     def observe_host_prefetch(self, seconds: float) -> None:
         if self._prom is None or not self._obs:
@@ -1183,6 +1229,10 @@ class PodServerConfig:
             # indexer's (token_processor.go:37-40).
             hash_seed=os.environ.get("PYTHONHASHSEED", ""),
             host_pages=int(os.environ.get("HOST_PAGES", 0)),
+            # the window pool of a model with sliding-window layers, in
+            # pages; unset it has TOTAL_PAGES, and a model without such
+            # layers never reads it
+            window_pages=int(os.environ.get("WINDOW_PAGES", 0)),
         )
         # Host-tier admission: "auto" (self-calibrating recompute-vs-
         # restore cost model) or "always" (unconditional spill/restore).
@@ -1300,6 +1350,13 @@ class PodServer:
                 f"(convolution state beside the KV pool) is incompatible "
                 f"with transfer_endpoint (TRANSFER_ENDPOINT: export, import "
                 f"and migration move K and V pages and no state)"
+            )
+        if model.n_window_layers and self.config.transfer_endpoint:
+            raise ValueError(
+                f"layer_types with {model.n_window_layers} sliding layers (a "
+                f"window pool beside the KV pool) is incompatible with "
+                f"transfer_endpoint (TRANSFER_ENDPOINT: export, import and "
+                f"migration move the context pool's pages and no window page)"
             )
         if self.config.remote_tier and engine is None:
             # Thread the knob family into the engine config BEFORE the
@@ -2291,6 +2348,8 @@ class PodServer:
                             self.engine.block_manager.num_free,
                             self.engine.kv_bytes_per_token,
                             self.engine.state_bytes_per_token,
+                            self.engine.window_bytes_per_token,
+                            self.engine.block_manager.window,
                         )
                         if self.config.engine.block_manager.host_pages:
                             bm = self.engine.block_manager
@@ -3718,6 +3777,12 @@ class PodServer:
                 "total_pages": bm.config.total_pages,
                 "kv_bytes_per_token": self.engine.kv_bytes_per_token,
                 "state_bytes_per_token": self.engine.state_bytes_per_token,
+                "window_bytes_per_token": self.engine.window_bytes_per_token,
+                "window_pages": bm.config.window_pages,
+                "window_pages_held": (
+                    bm.window.num_held if bm.window is not None else 0
+                ),
+                **(bm.window.stats if bm.window is not None else {}),
                 "routed_layers": self.engine.routed_layers,
                 "experts_held": self.engine.model_cfg.experts_held,
                 "zero_experts": self.engine.model_cfg.n_zero_experts,
@@ -4090,6 +4155,8 @@ def _resolve_model(name: str) -> LlamaConfig:
         "tiny-lfm2-moe": models.TINY_LFM2_MOE,
         "meituan-longcat/LongCat-Flash-Omni": models.LONGCAT_FLASH_OMNI,
         "tiny-scmoe": models.TINY_SCMOE,
+        "arcee-ai/Trinity-Large-Preview": models.TRINITY_LARGE_PREVIEW,
+        "tiny-swa-moe": models.TINY_SWA_MOE,
     }
     if name in presets:
         return presets[name]

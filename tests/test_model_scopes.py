@@ -22,6 +22,7 @@ from llm_d_kv_cache_manager_tpu.models import (
     TINY_QWEN3_MOE,
     TINY_SCMOE,
     TINY_SDAR_MOE,
+    TINY_SWA_MOE,
     llama,
 )
 from llm_d_kv_cache_manager_tpu.ops import sampling
@@ -45,6 +46,8 @@ CONFIGS = {
     # the double layer: both attentions with the query's two matmuls under
     # ``attn``, both dense FFNs under ``ffn``, the identity experts' own
     "double": (TINY_SCMOE, EVERY | ROUTED - {"moe_shared"} | {"moe_zero"}),
+    # sliding layers under a scope of their own beside the one full layer's
+    "window": (TINY_SWA_MOE, EVERY | ROUTED | {"attn_window"}),
 }
 
 
@@ -56,9 +59,20 @@ def _shapes(cfg):
         lambda: llama.init_kv_pages(cfg, PAGES, PS)
     )
     state = jax.eval_shape(lambda: llama.init_state_pages(cfg, PAGES))
+    window = jax.eval_shape(lambda: llama.init_window_pages(cfg, PAGES, PS))
+    if window is not None:  # the pair of window pools; the tables ride apart
+        return params, k_pages, v_pages, {"window_pages": window}
     return params, k_pages, v_pages, (
         {} if state is None else {"state_pages": state}
     )
+
+
+def _window_tables(state, width):
+    """A window model's second packed operand (``width`` columns of page
+    ids before the table), for the programs that take one."""
+    if "window_pages" not in state:
+        return {}
+    return {"window_packed": jnp.zeros((LANES, width + TABLE_W + 1), jnp.int32)}
 
 
 def _scopes_in(lowered_text: str) -> frozenset:
@@ -75,14 +89,14 @@ def _lowered_scopes(cfg, program) -> frozenset:
             params, cfg, jnp.zeros((LANES, 3), jnp.int32),
             jnp.zeros((LANES, TABLE_W + llama.DECODE_PACKED_TAIL), jnp.int32),
             k_pages, v_pages, key, page_size=PS, num_steps=2, interpret=True,
-            **state,
+            **state, **_window_tables(state, 0),
         )
     elif program == "prefill":
         lowered = llama.prefill_packed.lower(
             params, cfg,
             jnp.zeros((LANES, 5 * CHUNK + TABLE_W + 1), jnp.int32),
             k_pages, v_pages, chunk=CHUNK, attn_impl="xla", interpret=True,
-            **state,
+            **state, **_window_tables(state, CHUNK),
         )
     elif program == "denoise_steps":
         width = cfg.block_length
@@ -99,7 +113,7 @@ def _lowered_scopes(cfg, program) -> frozenset:
 
 CASES = [
     (kind, program)
-    for kind in ("dense", "routed", "latent", "conv", "double")
+    for kind in ("dense", "routed", "latent", "conv", "double", "window")
     for program in ("decode_steps", "prefill")
 ] + [("blocks", "prefill"), ("blocks", "denoise_steps")]
 
@@ -129,7 +143,8 @@ def test_the_rows_loop_of_a_prefill_program_carries_its_scopes(kind):
     lowered = llama.prefill_packed.lower(
         params, cfg, jnp.zeros((8, 5 * CHUNK + TABLE_W + 1), jnp.int32),
         k_pages, v_pages, chunk=CHUNK, attn_impl="xla", interpret=True,
-        **state,
+        **state, **{k: jnp.zeros((8, v.shape[1]), jnp.int32)
+                    for k, v in _window_tables(state, CHUNK).items()},
     )
     text = lowered.as_text(debug_info=True)
     assert "@jit_prefill_packed" in text

@@ -417,6 +417,8 @@ _SERVED = [
     ("qwen3-32b", "prefill"),
     ("sdar-30b-a3b", "denoise_steps"),
     ("sdar-30b-a3b", "prefill"),
+    ("trinity-large-preview", "decode_steps"),
+    ("trinity-large-preview", "prefill"),
 ]
 
 
@@ -448,6 +450,11 @@ class TestServedPrograms:
         if state_shape:  # the convolution layers' state pool beside them
             assert aot_pool_copies.pool_instructions(hlo, state_shape)
             assert _whole_pool_moves(hlo, state_shape) == []
+        window_shape = aot_pool_copies.window_pool_shape(kwargs)
+        assert (window_shape is not None) == (config == "trinity-large-preview")
+        if window_shape:  # the sliding layers' window pools beside them
+            assert aot_pool_copies.pool_instructions(hlo, window_shape)
+            assert _whole_pool_moves(hlo, window_shape) == []
 
     def test_a_program_the_configuration_does_not_serve(self, topo):
         one_chip = SingleDeviceSharding(topo.devices[0])
@@ -462,11 +469,13 @@ class TestThePrefillLoopReadsThePools:
     it whole on the way in and on the way out: the TPU compiler, PR 42).
     The slow cases above hold that for the six cells' programs at their
     depth; these are three of them cut to the fewest layers that keep every
-    kind of layer (a K/V pool, a state pool beside one, a latent pool),
+    kind of layer (a K/V pool, a state pool beside one, a latent pool, a
+    pair of window pools beside one: three sliding layers and a full one),
     13-28 s each."""
 
     @pytest.mark.parametrize("config, n_layers", [
         ("qwen3-32b", 1), ("lfm2-8b-a1b", 3), ("longcat-flash-omni", 1),
+        ("trinity-large-preview", 4),
     ])
     def test_the_loop_copies_no_pool(self, topo, config, n_layers):
         one_chip = SingleDeviceSharding(topo.devices[0])
@@ -481,6 +490,11 @@ class TestThePrefillLoopReadsThePools:
         assert (state_shape is not None) == (config == "lfm2-8b-a1b")
         if state_shape:
             assert _whole_pool_moves(hlo, state_shape) == []
+        window_shape = aot_pool_copies.window_pool_shape(kwargs)
+        assert (window_shape is not None) == (config == "trinity-large-preview")
+        if window_shape:
+            assert aot_pool_copies.pool_instructions(hlo, window_shape)
+            assert _whole_pool_moves(hlo, window_shape) == []
 
 
 def test_the_rows_of_a_dispatch_add_no_program():

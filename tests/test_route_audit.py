@@ -601,7 +601,8 @@ class TestKnobsOffParity:
             assert set(stats) == {
                 "pod", "model", "data_parallel_rank", "staged", "waiting",
                 "running", "free_pages", "total_pages", "kv_bytes_per_token",
-                "state_bytes_per_token", "routed_layers",
+                "state_bytes_per_token", "window_bytes_per_token", "window_pages",
+                "window_pages_held", "routed_layers",
                 "experts_held", "zero_experts", "prefill",
                 "transfer", "self_heal", "admission", "drain",
             }

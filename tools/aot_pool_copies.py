@@ -9,8 +9,10 @@ every instruction outside a fusion's body whose result has the shape of a
 whole K or V pool ``[L, P, page, n_kv, hd]`` (a latent model's one pool:
 ``[L, P, page, row]``) or of one layer's slice of it, and the layout the
 compiler gives the pool. A model with convolution layers has a state pool
-beside them (``[conv layers, P, row]``, ``state_pool_shape``): it is listed
-the same way.
+beside them (``[conv layers, P, row]``, ``state_pool_shape``), a model with
+sliding-window layers a pair of window pools (``[sliding layers, window
+pages, page, n_kv, hd]``, ``window_pool_shape``): they are listed the same
+way.
 
 A pool is hundreds of MiB: any such instruction that is not free (a
 ``bitcast``, a ``parameter``, tuple plumbing) reads and writes that much
@@ -233,13 +235,26 @@ def served_program(config: str, program: str, one_chip, **replace):
         lambda: llama.init_state_pages(cfg, env["TOTAL_PAGES"]))
     stateful = {} if state is None else {
         "state_pages": S(state.shape, state.dtype)}
+    # ... or, for a model with sliding layers, the pair of window pools and
+    # the dispatch's window tables (one width: ``Engine.window_table_pages``
+    # in a decode dispatch, the context bucket in a prefill)
+    window = jax.eval_shape(lambda: llama.init_window_pages(
+        cfg, env.get("WINDOW_PAGES", env["TOTAL_PAGES"]), page))
+    decode_window, prefill_window = {}, {}
+    if window is not None:
+        pools = tuple(S(w.shape, w.dtype) for w in window)
+        decode_window = {"window_pages": pools, "window_packed": S(
+            (lanes, cfg.sliding_window // page + 3 + 1), i32)}
+        prefill_window = {"window_pages": pools, "window_packed": S(
+            (PREFILL_ROWS, PREFILL_CHUNK + engine["prefill_ctx_bucket"] + 1),
+            i32)}
     key = S((2,), jnp.uint32)
     if program == "decode_steps" and cfg.block_length == 0:
         # ids, then ``llama.pack_decode_inputs``' one array
         packed = S((lanes, table_w + llama.DECODE_PACKED_TAIL), i32)
         args = (params, cfg, S((lanes,), i32), packed, pool, second, key)
         kwargs = dict(page_size=page, num_steps=1, interpret=False, mesh=None,
-                      **stateful)
+                      **stateful, **decode_window)
         return llama.decode_steps, args, kwargs, pool_shape
     if program == "denoise_steps" and cfg.block_length > 0:
         width = 2 * cfg.block_length + table_w + 5
@@ -254,7 +269,7 @@ def served_program(config: str, program: str, one_chip, **replace):
         width = 5 * PREFILL_CHUNK + engine["prefill_ctx_bucket"] + 1
         args = (params, cfg, S((PREFILL_ROWS, width), i32), pool, second)
         kwargs = dict(chunk=PREFILL_CHUNK, mesh=None, attn_impl="pallas",
-                      interpret=False, **stateful)
+                      interpret=False, **stateful, **prefill_window)
         return llama.prefill_packed, args, kwargs, pool_shape
     return None
 
@@ -264,6 +279,13 @@ def state_pool_shape(kwargs: dict):
     (``served_program``), or None: the model has no convolution layers."""
     state = kwargs.get("state_pages")
     return None if state is None else tuple(state.shape)
+
+
+def window_pool_shape(kwargs: dict):
+    """The shape of each of the two window pools among a served program's
+    keyword arguments, or None: the model has no sliding layers."""
+    window = kwargs.get("window_pages")
+    return None if window is None else tuple(window[0].shape)
 
 
 def main(argv=None) -> int:
@@ -290,6 +312,8 @@ def main(argv=None) -> int:
             pools = {"pool": pool_shape}
             if state_pool_shape(kwargs):
                 pools["state pool"] = state_pool_shape(kwargs)
+            if window_pool_shape(kwargs):
+                pools["window pool"] = window_pool_shape(kwargs)
             for what, shape in pools.items():
                 report(f"{config} {program} {what}", hlo, shape)
     return 0
