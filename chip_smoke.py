@@ -343,7 +343,7 @@ def kernel_phase(dry: bool) -> dict:
         seq_lens = [2092, 2061, 17, 1, 0, 300, 2304, 16]
         dtype, tol_dec = jnp.bfloat16, TOL_ATTN_BF16
 
-    def decode_inputs(seed):
+    def decode_inputs(seed, geo=geo):
         rng = np.random.default_rng(seed)
         b, width, ps = geo["b"], geo["width"], geo["ps"]
         total = b * width + 1
@@ -359,9 +359,10 @@ def kernel_phase(dry: bool) -> dict:
         bt = (rng.permutation(total - 1)[: b * width] + 1).reshape(b, width)
         return rng, shape, q, fk, fv, bt.astype(np.int32)
 
-    def reference_with_fresh(q, k_l, v_l, fk, fv, bt, sl):
+    def reference_with_fresh(q, k_l, v_l, fk, fv, bt, sl, **seen):
         """Oracle: write each lane's current token into its slot
-        full-width, then plain gather-softmax over ``seq_len`` tokens."""
+        full-width, then plain gather-softmax over ``seq_len`` tokens
+        (``seen``: a sliding layer's ``window``)."""
         k_l, v_l = np.array(k_l), np.array(v_l)
         for i, n in enumerate(sl):
             if n > 0:
@@ -370,7 +371,7 @@ def kernel_phase(dry: bool) -> dict:
                 v_l[page, slot] = np.asarray(fv[i])
         return paged_attention_reference(
             q, jnp.asarray(k_l), jnp.asarray(v_l), jnp.asarray(bt),
-            jnp.asarray(sl, jnp.int32),
+            jnp.asarray(sl, jnp.int32), **seen,
         )
 
     def decode_case():
@@ -415,8 +416,35 @@ def kernel_phase(dry: bool) -> dict:
         )
         return _max_err(got, ref)
 
+    def decode_window_case():
+        # A sliding layer's call (Trinity-Large-Preview: 48/8 heads, window
+        # 4096 through a 259-page window table whose first slot stands for
+        # ``starts``): lanes at every offset of the window in its first
+        # page, one that fills its table, short ones and an idle one.
+        if dry:
+            window, width, lens = 24, 6, [24 + 5, 0, 23, 96]
+        else:
+            window, width, lens = 4096, 259, [4101, 4097, 4096, 300, 1, 0, 4111, 4144]
+        rng, shape, q, fk, fv, bt = decode_inputs(
+            3, dict(geo, width=width, n_q=6 * geo["n_kv"]))
+        kp = jnp.asarray(rng.standard_normal(shape), dtype)
+        vp = jnp.asarray(rng.standard_normal(shape), dtype)
+        starts = (np.arange(len(lens)) * 3 * geo["ps"]).astype(np.int32)
+        layer = geo["L"] - 1
+        got = paged_attention(
+            q, kp, vp, jnp.asarray(bt), jnp.asarray(lens, jnp.int32) + starts,
+            fk, fv, interpret=interpret, layer=layer, window=window,
+            table_start=jnp.asarray(starts),
+        )
+        ref = reference_with_fresh(
+            q, kp[layer], vp[layer], fk, fv, bt, lens, window=window
+        )
+        return _max_err(got, ref)
+
     run("paged_attention[bf16 pool, 5-D, layer, has_fresh]",
         decode_case, tol_dec, main_path=True)
+    run("paged_attention_window[bf16 pools, 5-D, layer operand, has_fresh]",
+        decode_window_case, tol_dec, main_path=True)
     run("paged_attention[int8 pool, in-kernel dequant]",
         decode_int8_pool_case, TOL_DRY if dry else TOL_ATTN_INT8_POOL,
         main_path=False)

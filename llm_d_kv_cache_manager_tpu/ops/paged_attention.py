@@ -1,9 +1,13 @@
-"""Paged decode attention: Pallas TPU kernel + reference implementation.
+"""Paged decode attention: two Pallas TPU kernels + reference implementation.
 
 The serving engine stores KV in fixed-size pages (blocks) scattered across a
 pool; at decode each sequence reads its pages via a block table. This is the
 hot op the reference ecosystem gets from vLLM's CUDA paged attention — here
-it is a TPU kernel designed for the hardware:
+it is a TPU kernel designed for the hardware. ``paged_attention`` is the one
+entry; which call is which:
+
+**The full-context call** (``_decode_kernel``: every layer that sees its
+whole context, int8 pools, ``tp`` shards), a program a (lane, table page):
 
 - KV pool layout ``[total_pages, page_size, n_kv_heads, head_dim]``:
   page-major, so one page's full KV tile ``[page_size, n_kv, head_dim]`` is
@@ -20,7 +24,31 @@ it is a TPU kernel designed for the hardware:
   in float32; GQA handled by blocking query heads [group, head_dim] against
   one KV head.
 
-CPU tests run the same kernel with ``interpret=True`` (the caller's choice —
+**A sliding layer's call** (``window=``; ``paged_window_attention`` /
+``_window_decode_kernel``, named ``paged_attention_window`` in the trace;
+PR 44), a program a lane that walks the lane's window itself:
+
+- Grid ``(batch,)``; the window pools whole in ``ANY`` memory space, read
+  in place; the window table, the lengths and the LAYER as scalar prefetch,
+  so a model's sliding layers share one kernel.
+- A loop from the first page that holds a visible slot to the page of the
+  last historical token, ``KEY_BLOCK / page_size`` pages a step: the kernel
+  copies those page tiles of K and of V into one of two VMEM slots with
+  ``make_async_copy``, starts the next step's copies before it computes,
+  and makes one float32 online-softmax update over the whole block. Work
+  follows the live window, not the table's width; a lane of length 0 runs
+  no step.
+- On a TPU v5e at `longdocs`' shape (32 lanes, 8 KV heads, a group of 6, a
+  259-page table, four sliding layers) the one-page kernel took 15.9 ms a
+  decode step for 2.2 GB of keys and values, bound by issuing 33 000
+  programs; this one takes 4.3 ms (chip run, PR 44: PERF.md section 6).
+
+The seam between them is in ``paged_attention`` (ROADMAP S12): the
+full-context call moves onto the window call's body, and ``_decode_kernel``
+goes, once the benchmark's ``decode_step_roofline`` counts what the program
+counts (ROADMAP D9 (0)).
+
+CPU tests run the same kernels with ``interpret=True`` (the caller's choice —
 asking for the compiled kernel off-TPU raises);
 ``paged_attention_reference`` is the numerics oracle.
 """
@@ -28,6 +56,7 @@ asking for the compiled kernel off-TPU raises);
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -38,6 +67,22 @@ from jax.experimental.pallas import tpu as pltpu
 from ._mosaic import require_tpu_unless_interpret
 
 _NEG_INF = float("-inf")
+# Finite, for the window kernel: a block whose every slot is masked must give
+# exp(-1e30 - -1e30) = 1, zeroed by the mask multiply, not inf - inf = NaN.
+_MASKED = -1e30
+
+#: tokens of page tiles a step of the window kernel copies and attends over
+#: (``KEY_BLOCK / page_size`` pages of K and of V into each of two VMEM slots).
+#: On a TPU v5e the four sliding layers' calls of a `longdocs` decode step (32
+#: lanes, 8 KV heads, a group of 6, 256-257 live pages of a 259-page table)
+#: took 5.02 / 4.32 / 4.29 / 4.59 / 5.12 ms at 128 / 256 / 384 / 512 / 1024
+#: tokens a step: a lane's last step holds one page and computes a whole
+#: block, and the copies alone are 3.0-3.5 ms (chip runs, PR 44: PERF.md
+#: section 6).
+KEY_BLOCK = 256
+#: what the window kernel may use of a v5e core's 128 MiB of VMEM (the
+#: compiler's default scoped limit is 16 MiB)
+_VMEM_LIMIT = 64 * 1024 * 1024
 
 #: pages per scale block: the int8 pool's scale operand ``[L, pages, n_kv]``
 #: is tiled (8, 128) over its last two dims, and Mosaic wants a block's
@@ -83,7 +128,6 @@ def _decode_kernel(
     scale: float,
     has_fresh: bool,
     quantized: bool,
-    window: int = 0,
 ):
     """All KV heads of one (sequence, page) in a single program: 8× fewer
     grid steps than a per-head grid, one fully-contiguous page tile
@@ -104,14 +148,7 @@ def _decode_kernel(
     the codes dequantize IN-REGISTER
     to f32 before the online softmax — full-width pages never exist
     anywhere. The ``has_fresh`` current-token path stays full-precision:
-    fresh K/V arrive unquantized and never round-trip through int8.
-
-    ``window`` > 0 (a sliding layer): the token at ``seq_len - 1`` sees the
-    ``window`` positions that end with itself and no earlier one. Slots are
-    numbered from the table's first (the caller's ``table_start`` is already
-    taken off ``seq_len``), so a slot is visible when it lies at or after
-    ``seq_len - window``; a page that holds no such slot is skipped like one
-    past the history. 0 is the program it always was, op for op."""
+    fresh K/V arrive unquantized and never round-trip through int8."""
     if quantized:
         k_scale_ref, v_scale_ref = refs[0], refs[1]  # [1, 8, n_kv] f32
         refs = refs[2:]
@@ -131,13 +168,8 @@ def _decode_kernel(
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    # Only pages holding historical tokens contribute (inside the window,
-    # where the layer has one).
-    live = p * page_size < hist
-    if window:
-        live = jnp.logical_and(live, (p + 1) * page_size > seq_len - window)
-
-    @pl.when(live)
+    # Only pages holding historical tokens contribute.
+    @pl.when(p * page_size < hist)
     def _compute():
         q = q_ref[0].astype(jnp.float32)  # [n_kv, group, d]
         # Page tile arrives [page_size, n_kv, d] (one fully-contiguous
@@ -162,10 +194,7 @@ def _decode_kernel(
         token_idx = p * page_size + jax.lax.broadcasted_iota(
             jnp.int32, scores.shape, dimension=2
         )
-        visible = token_idx < hist
-        if window:
-            visible = jnp.logical_and(visible, token_idx >= seq_len - window)
-        scores = jnp.where(visible, scores, _NEG_INF)
+        scores = jnp.where(token_idx < hist, scores, _NEG_INF)
 
         m_prev = m_ref[:, :, :1]  # [n_kv, group, 1]
         m_cur = jnp.max(scores, axis=-1, keepdims=True)
@@ -209,6 +238,226 @@ def _decode_kernel(
         denom = l_ref[:, :, :1]
         safe_l = jnp.where(denom == 0.0, 1.0, denom)  # len-0 seq → zeros, not NaN
         out_ref[0] = (acc_ref[:] / safe_l).astype(out_ref.dtype)
+
+
+def _window_decode_kernel(
+    # scalar prefetch
+    layer_ref,  # [1] int32: a scalar, so every sliding layer's call is one kernel
+    tables_ref,  # [batch, table_pages] int32: the lanes' window tables
+    seq_lens_ref,  # [batch] int32, counted from the table's first slot
+    # operands
+    q_ref,  # [1, n_kv, group, head_dim]
+    k_pool_ref,  # [L, P, page_size, n_kv, head_dim]: the whole pool (ANY)
+    v_pool_ref,
+    *refs,  # [fresh_k_ref, fresh_v_ref,] out_ref, k_buf, v_buf, sem
+    window: int,
+    page_size: int,
+    block_pages: int,
+    table_pages: int,
+    scale: float,
+    has_fresh: bool,
+):
+    """One lane's sliding-window decode attention, every KV head at once.
+
+    The token at ``seq_len - 1`` sees the ``window`` slots that end with
+    itself. The program walks its lane's window table from the first page
+    that holds a visible slot to the page of the last historical token,
+    ``block_pages`` pages a step: it copies those page tiles ``pool[layer,
+    table[b, page]]`` of K and of V into one of two VMEM slots itself, starts
+    the next step's copies before it computes, and makes one online-softmax
+    update over the whole block. A lane of length 0 runs no step; the current
+    token's K/V merge after the loop as in ``_decode_kernel``."""
+    if has_fresh:
+        fresh_k_ref, fresh_v_ref, out_ref, k_buf, v_buf, sem = refs
+    else:
+        out_ref, k_buf, v_buf, sem = refs
+    b = pl.program_id(0)
+    layer = layer_ref[0]
+    seq_len = seq_lens_ref[b]
+    # tokens resident in the pages, as far as the table reaches
+    hist = jnp.minimum(seq_len - 1 if has_fresh else seq_len,
+                       table_pages * page_size)
+    low = jnp.maximum(seq_len - window, 0)  # the first visible slot
+    first_page = low // page_size
+    n_pages = jnp.maximum(pl.cdiv(hist, page_size) - first_page, 0)
+    n_steps = pl.cdiv(n_pages, block_pages)
+    block = block_pages * page_size
+    n_kv, group, head_dim = q_ref.shape[1:]
+    q = q_ref[0].astype(jnp.float32)  # [n_kv, group, d]
+
+    def for_live_pages(step, act):
+        """``act`` on the (K, V) copy of every page of ``step`` that holds
+        history. The handles are rebuilt identically at start and at wait
+        time (the standard Pallas async-copy idiom)."""
+        slot = step % 2
+        first = step * block_pages
+
+        def one_page(i, carry):
+            page = tables_ref[b, first_page + first + i]
+            dst = pl.ds(i * page_size, page_size)
+            act(pltpu.make_async_copy(
+                k_pool_ref.at[layer, page], k_buf.at[slot, dst], sem.at[0, slot]
+            ))
+            act(pltpu.make_async_copy(
+                v_pool_ref.at[layer, page], v_buf.at[slot, dst], sem.at[1, slot]
+            ))
+            return carry
+
+        jax.lax.fori_loop(
+            0, jnp.minimum(block_pages, n_pages - first), one_page, 0
+        )
+
+    def merge(state, k, v, visible):
+        """One online-softmax update: ``k`` / ``v`` ``[n_kv, keys, d]``
+        float32, ``visible`` ``[n_kv, group, keys]`` or None (all)."""
+        m_prev, l_prev, acc = state
+        scores = jax.lax.dot_general(
+            q, k, (((2,), (2,)), ((0,), (0,))), preferred_element_type=jnp.float32
+        ) * scale  # [n_kv, group, keys]
+        if visible is not None:
+            scores = jnp.where(visible, scores, _MASKED)
+        m_new = jnp.maximum(m_prev, jnp.max(scores, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        probs = jnp.exp(scores - m_new)
+        if visible is not None:
+            # the multiply (not the mask value alone) zeroes a masked slot
+            probs = probs * visible
+        return (
+            m_new,
+            l_prev * alpha + jnp.sum(probs, axis=-1, keepdims=True),
+            acc * alpha + jax.lax.dot_general(
+                probs, v, (((2,), (1,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32,
+            ),
+        )
+
+    @pl.when(n_steps > 0)
+    def _prologue():
+        for_live_pages(0, lambda copy: copy.start())
+
+    def step_body(step, state):
+        for_live_pages(step, lambda copy: copy.wait())
+
+        # Stream the NEXT step's pages under this step's compute.
+        @pl.when(step + 1 < n_steps)
+        def _prefetch_next():
+            for_live_pages(step + 1, lambda copy: copy.start())
+
+        slot = step % 2
+        start = (first_page + step * block_pages) * page_size
+        # The tiles arrive [keys, n_kv, d]; heads go first for the batched
+        # dots. Slots past the live pages hold what an earlier step or call
+        # left (or nothing yet): a zero probability times a stray NaN would
+        # still be NaN, so those values are zeroed, not only masked.
+        tok = start + jax.lax.broadcasted_iota(jnp.int32, k_buf.shape[1:], 0)
+        k = jnp.swapaxes(k_buf[slot].astype(jnp.float32), 0, 1)
+        v = jnp.swapaxes(
+            jnp.where(tok < hist, v_buf[slot].astype(jnp.float32), 0.0), 0, 1
+        )
+        slot_idx = start + jax.lax.broadcasted_iota(
+            jnp.int32, (n_kv, group, block), 2
+        )
+        visible = jnp.logical_and(slot_idx >= low, slot_idx < hist)
+        return merge(state, k, v, visible)
+
+    state = jax.lax.fori_loop(0, n_steps, step_body, (
+        jnp.full((n_kv, group, 1), _MASKED, jnp.float32),
+        jnp.zeros((n_kv, group, 1), jnp.float32),
+        jnp.zeros((n_kv, group, head_dim), jnp.float32),
+    ))
+    if has_fresh:
+        # The current token is a one-slot block, always visible to itself;
+        # a lane of length 0 holds no token and keeps its zeros.
+        kf = fresh_k_ref[0].astype(jnp.float32)  # [n_kv, 1, d]
+        vf = fresh_v_ref[0].astype(jnp.float32)
+        merged = merge(state, kf, vf, None)
+        state = tuple(
+            jnp.where(seq_len > 0, new, old) for new, old in zip(merged, state)
+        )
+    _, denom, acc = state
+    safe_l = jnp.where(denom == 0.0, 1.0, denom)  # len-0 lane -> zeros, not NaN
+    out_ref[0] = (acc / safe_l).astype(out_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("window", "scale", "interpret"))
+def paged_window_attention(
+    q: jnp.ndarray,  # [batch, n_heads, head_dim]
+    k_pages: jnp.ndarray,  # [n_layers, window_pages, page_size, n_kv, head_dim]
+    v_pages: jnp.ndarray,
+    block_tables: jnp.ndarray,  # [batch, table_pages] int32: window tables
+    seq_lens: jnp.ndarray,  # [batch] int32, counted from the table's first slot
+    fresh_k: Optional[jnp.ndarray] = None,  # [batch, n_kv_heads, head_dim]
+    fresh_v: Optional[jnp.ndarray] = None,
+    *,
+    window: int,
+    scale: float,
+    interpret: bool = False,
+    layer=0,
+) -> jnp.ndarray:
+    """A sliding layer's decode attention (``paged_attention`` sends its
+    ``window`` calls here): a program a lane that walks the lane's window
+    itself, ``KEY_BLOCK`` tokens of page tiles a step. ``layer`` is an
+    operand, not a static argument, so a model's sliding layers share one
+    trace and one lowering of the kernel a program. The call is named
+    ``paged_attention_window`` in the trace."""
+    require_tpu_unless_interpret("paged_window_attention", interpret)
+    batch, n_heads, head_dim = q.shape
+    _L, _total, page_size, n_kv_heads, _hd = k_pages.shape
+    group = n_heads // n_kv_heads
+    table_pages = block_tables.shape[1]
+    has_fresh = fresh_k is not None
+    # Pages a step: whole lane tiles of keys, no wider than the table.
+    lane_pages = 128 // math.gcd(128, page_size)
+    block_pages = -(-min(KEY_BLOCK // page_size, table_pages) // lane_pages) * lane_pages
+    block = block_pages * page_size
+
+    q_blocked = q.reshape(batch, n_kv_heads, group, head_dim)
+    layer_word = jnp.asarray(layer, jnp.int32).reshape(1)
+
+    def lane_index(b, *_):
+        return (b, 0, 0, 0)
+
+    in_specs = [
+        pl.BlockSpec((1, n_kv_heads, group, head_dim), lane_index),
+        pl.BlockSpec(memory_space=pl.ANY),
+        pl.BlockSpec(memory_space=pl.ANY),
+    ]
+    inputs = [layer_word, block_tables, seq_lens, q_blocked, k_pages, v_pages]
+    if has_fresh:
+        in_specs.append(pl.BlockSpec((1, n_kv_heads, 1, head_dim), lane_index))
+        in_specs.append(pl.BlockSpec((1, n_kv_heads, 1, head_dim), lane_index))
+        inputs.append(fresh_k.reshape(batch, n_kv_heads, 1, head_dim))
+        inputs.append(fresh_v.reshape(batch, n_kv_heads, 1, head_dim))
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(batch,),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((1, n_kv_heads, group, head_dim), lane_index),
+        scratch_shapes=[
+            pltpu.VMEM((2, block, n_kv_heads, head_dim), k_pages.dtype),
+            pltpu.VMEM((2, block, n_kv_heads, head_dim), v_pages.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),  # (K, V) x slot
+        ],
+    )
+    kernel = functools.partial(
+        _window_decode_kernel,
+        window=window,
+        page_size=page_size,
+        block_pages=block_pages,
+        table_pages=table_pages,
+        scale=scale,
+        has_fresh=has_fresh,
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((batch, n_kv_heads, group, head_dim), q.dtype),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=interpret,
+        name="paged_attention_window",
+    )(*inputs)
+    return out.reshape(batch, n_heads, head_dim)
 
 
 @functools.partial(
@@ -289,12 +538,23 @@ def paged_attention(
         raise ValueError("fresh_k and fresh_v must be passed together")
     has_fresh = fresh_k is not None
 
-    q_blocked = q.reshape(batch, n_kv_heads, group, head_dim)
     block_tables = block_tables.astype(jnp.int32)
     seq_lens = seq_lens.astype(jnp.int32)
     if table_start is not None:
         seq_lens = jnp.maximum(seq_lens - table_start.astype(jnp.int32), 0)
+    if window:
+        # The seam (ROADMAP S12): a sliding layer's call walks its window
+        # in ``paged_window_attention``; the full-context call below stays a
+        # program a page until D9 (0) lets it move onto that body, and
+        # ``_decode_kernel`` goes then.
+        if quantized:
+            raise ValueError("a window pool holds no int8 codes")
+        return paged_window_attention(
+            q, k_pages, v_pages, block_tables, seq_lens, fresh_k, fresh_v,
+            window=window, scale=scale, interpret=interpret, layer=layer,
+        )
 
+    q_blocked = q.reshape(batch, n_kv_heads, group, head_dim)
     grid = (batch, max_pages)
 
     def q_index(b, p, bt, sl):
@@ -350,14 +610,12 @@ def paged_attention(
         scale=scale,
         has_fresh=has_fresh,
         quantized=quantized,
-        window=window,
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((batch, n_kv_heads, group, head_dim), q.dtype),
         interpret=interpret,
-        name="paged_attention_window" if window else None,
     )(*inputs)
     return out.reshape(batch, n_heads, head_dim)
 

@@ -404,6 +404,52 @@ class TestTheStatePoolAndTheRowOfTwoHeads:
         assert layout.startswith("bf16[11,16384,4096]{2,1,0:T(8,128)(2,1)"), layout
 
 
+_WINDOW_POOL = (4, 8192, 16, 8, 128)  # trinity-large-preview: four sliding layers
+
+
+class TestTheWindowIsWalkedInPlace:
+    """A sliding layer's decode call (PR 44) as ``_paged_attention_tp`` makes
+    it at `longdocs`' shape: 32 lanes, a group of 6 over 8 KV heads, a
+    259-page window table, the pools five-dimensional. One kernel, named for
+    the window, that takes the pools as they are."""
+
+    def test_no_slice_of_the_pool_and_no_gathered_window(self, topo):
+        one_chip = SingleDeviceSharding(topo.devices[0])
+        L, _, page, n_kv, hd = _WINDOW_POOL
+        lanes, table_pages, window = 32, 259, 4096
+
+        def shaped(shape, dtype=jnp.bfloat16):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+        def one_layer(q, k_pages, v_pages, tables, lens, starts, k, v):
+            return llama._paged_attention_tp(
+                q, k_pages, v_pages, tables, lens, k, v, interpret=False,
+                mesh=None, layer=L - 1, scale=hd**-0.5, window=window,
+                table_start=starts,
+            )
+
+        hlo = aot_pool_copies.compile_text(
+            jax.jit(one_layer),
+            shaped((lanes, 6 * n_kv, hd)),
+            shaped(_WINDOW_POOL), shaped(_WINDOW_POOL),
+            shaped((lanes, table_pages), jnp.int32),
+            shaped((lanes,), jnp.int32), shaped((lanes,), jnp.int32),
+            shaped((lanes, n_kv, hd)), shaped((lanes, n_kv, hd)),
+        )
+        found = aot_pool_copies.pool_instructions(hlo, _WINDOW_POOL, layer_slices=True)
+        assert found, "the pool is nowhere in the module: the reader is blind"
+        assert [(i.opcode, i.name, i.result) for i in found if i.moves_bytes] == []
+        gathered = lanes * window * n_kv * hd  # a lane's window, gathered
+        everything = list(aot_pool_copies.instructions(hlo))
+        assert [
+            (op, name, result) for _, name, op, result in everything
+            if op not in aot_pool_copies.FREE and _elements(result) >= gathered
+        ] == []
+        kernels = [name for _, name, op, _ in everything if op == "custom-call"]
+        assert len(kernels) == 1, kernels
+        assert "paged_attention_window" in hlo
+
+
 _SERVED = [
     ("kanana-2-30b-a3b", "decode_steps"),
     ("kanana-2-30b-a3b", "prefill"),
@@ -455,6 +501,10 @@ class TestServedPrograms:
         if window_shape:  # the sliding layers' window pools beside them
             assert aot_pool_copies.pool_instructions(hlo, window_shape)
             assert _whole_pool_moves(hlo, window_shape) == []
+        # the kernel that walks a window is in a model with sliding layers'
+        # decode program and in no other
+        assert ("paged_attention_window" in hlo) == (
+            (config, program) == ("trinity-large-preview", "decode_steps"))
 
     def test_a_program_the_configuration_does_not_serve(self, topo):
         one_chip = SingleDeviceSharding(topo.devices[0])
