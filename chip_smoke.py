@@ -427,6 +427,10 @@ def kernel_phase(dry: bool) -> dict:
             window, width, lens = 4096, 259, [4101, 4097, 4096, 300, 1, 0, 4111, 4144]
         rng, shape, q, fk, fv, bt = decode_inputs(
             3, dict(geo, width=width, n_q=6 * geo["n_kv"]))
+        # a stretch of the pool a lane: as it lies on odd lanes (runs of
+        # pages, which the kernel copies a group at once), shuffled on even
+        bt = 1 + np.arange(bt.size, dtype=np.int32).reshape(bt.shape)
+        bt[::2] = rng.permuted(bt[::2], axis=1)
         kp = jnp.asarray(rng.standard_normal(shape), dtype)
         vp = jnp.asarray(rng.standard_normal(shape), dtype)
         starts = (np.arange(len(lens)) * 3 * geo["ps"]).astype(np.int32)
@@ -561,10 +565,14 @@ def kernel_phase(dry: bool) -> dict:
             pool[:, 0] = 1e4  # the dead tail of every table points here
             pool[0] *= 1e3  # another layer's rows would be seen
             bt = np.zeros((b, width), np.int32)
-            order, at = rng.permutation(np.arange(1, pages)), 0
+            # a stretch of the pool a lane: as it lies on odd lanes (runs of
+            # pages, which the kernel copies a group at once), shuffled on
+            # even ones
+            at = 1
             for i, c in enumerate(ctx_lens):
                 n = -(-c // ps)
-                bt[i, :n] = order[at:at + n]
+                ids = np.arange(at, at + n)
+                bt[i, :n] = ids if i % 2 else rng.permutation(ids)
                 at += n
             q = jnp.asarray(rng.standard_normal((b, s, heads, dk)), dt)
             fresh = jnp.asarray(rng.standard_normal((b, s, dk)), dt)

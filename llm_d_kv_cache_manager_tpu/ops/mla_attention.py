@@ -22,8 +22,10 @@ all 32 heads, which is what this kernel computes.
   ``[page_size, width]`` copied by ``(layer, block_tables[b, page])`` into
   double-buffered VMEM, ``KEY_BLOCK / page_size`` pages a step, the next
   step's pages in flight under this step's matmuls, and only as far as the
-  row's context reaches. There is no head axis to swap: the tile is the
-  key operand as it lands, and its first ``dv`` columns the value operand.
+  row's context reaches. A group of pages whose pool ids are consecutive is
+  ONE copy (``_page_copies.for_step_pages``, PR 45: what a copy costs here
+  is starting it). There is no head axis to swap: the tile is the key
+  operand as it lands, and its first ``dv`` columns the value operand.
 - Score tiles are ``[bq * heads, keys]`` (query rows x heads collapsed to
   the MXU's row dimension), float32 online softmax, operands in the pool's
   dtype with float32 accumulation. No temporary grows with rows x context
@@ -50,6 +52,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ._mosaic import require_tpu_unless_interpret
+from ._page_copies import for_step_pages
 
 # finite, as in flash_prefill: a fully masked row must give exp(0) = 1,
 # zeroed by the mask multiply, not inf - inf
@@ -58,7 +61,11 @@ _NEG_INF = -1e30
 #: most context keys a step (pages a step x ``page_size``): 64 pages of 16
 #: tokens. On a TPU v5e the decode call over 32 lanes x 12-29k tokens, all 8
 #: layers, took 23.2 / 18.7 / 16.9 ms at 256 / 512 / 1024 keys a step (chip
-#: run, PR 32: about 0.05 us a page copy and 0.5 us a step).
+#: run, PR 32: about 0.05 us a page copy and 0.5 us a step). Read again once
+#: a run of pages is one copy (chip runs, PR 45, tables that are one run):
+#: 13.1 / 11.3 / 10.5 ms at 512 / 1024 / 2048 there, but 4.97 / 5.36 ms at
+#: 1024 / 2048 over 64 lanes x 64 heads x 1-5k tokens (`turns`) and 10.0 /
+#: 10.8 ms for a question's 128 rows over 20k tokens: 1024 is kept.
 KEY_BLOCK = 1024
 #: cap on bq * heads score rows a program: with ``KEY_BLOCK`` it bounds the
 #: f32 score tile (8 MiB) and the f32 accumulator (4 MiB at dv = 512)
@@ -80,7 +87,7 @@ def _mla_kernel(
     m_ref,  # [rows, 128] f32 scratch
     l_ref,  # [rows, 128] f32 scratch
     acc_ref,  # [rows, dv] f32 scratch
-    ctx_buf,  # [2, bk_ctx, dk] VMEM — double-buffered page tiles
+    ctx_buf,  # [2, bk_ctx / page_size, page_size, dk] VMEM — page tiles
     sem,  # DMA semaphores [2 (slot)]
     *,
     bq: int,
@@ -132,18 +139,10 @@ def _mla_kernel(
         def for_live_pages(step, act):
             slot = step % 2
             first = step * pages_per_step
-
-            def one_page(i, carry):
-                page = bt_ref[b, first + i]
-                act(pltpu.make_async_copy(
-                    pool_ref.at[layer, page],
-                    ctx_buf.at[slot, pl.ds(i * page_size, page_size)],
-                    sem.at[slot],
-                ))
-                return carry
-
-            jax.lax.fori_loop(
-                0, jnp.minimum(pages_per_step, n_pages - first), one_page, 0
+            for_step_pages(
+                act, bt_ref, b, first,
+                jnp.minimum(pages_per_step, n_pages - first), layer,
+                ((pool_ref, ctx_buf.at[slot], sem.at[slot]),),
             )
 
         @pl.when(n_steps > 0)
@@ -161,10 +160,14 @@ def _mla_kernel(
             # Slots past the live pages hold what an earlier step or call
             # left: a zero probability times a stray NaN is NaN, so those
             # rows are zeroed, not only masked in the scores.
+            # (a slot is [pages, page_size, dk] so that a run lands in it
+            # as it lies in the pool; merging the two leading dimensions
+            # moves no bytes at a page of whole sublane tiles)
+            rows_kv = ctx_buf[slot].reshape(bk_ctx, ctx_buf.shape[-1])
             tok = step * bk_ctx + jax.lax.broadcasted_iota(
-                jnp.int32, ctx_buf.shape[1:], 0
+                jnp.int32, rows_kv.shape, 0
             )
-            kv = jnp.where(tok < ctx_len, ctx_buf[slot], 0).astype(ctx_buf.dtype)
+            kv = jnp.where(tok < ctx_len, rows_kv, 0).astype(ctx_buf.dtype)
             k_idx = step * bk_ctx + jax.lax.broadcasted_iota(
                 jnp.int32, (rows, bk_ctx), 1
             )
@@ -210,6 +213,18 @@ def _round_up(x: int, m: int) -> int:
     return -(-x // m) * m
 
 
+def ctx_step_pages(
+    table_pages: int, page_size: int, key_block: int = KEY_BLOCK
+) -> int:
+    """Table pages a context step of the kernel: ``key_block`` keys in whole
+    lane tiles and whole pages, no more than the table holds."""
+    keys = _round_up(
+        min(_round_up(key_block, 128), max(table_pages * page_size, 1)),
+        math.lcm(page_size, 128),
+    )
+    return keys // page_size
+
+
 @functools.partial(
     jax.jit, static_argnames=("dv", "scale", "interpret", "key_block"),
 )
@@ -245,10 +260,8 @@ def mla_paged_attention(
     # Fresh keys a step: one sublane tile where the chunk is that short
     # (decode: one row), else whole lane tiles.
     bk_chunk = 16 if s <= 16 else min(_round_up(key_block, 128), _round_up(s, 128))
-    bk_ctx = _round_up(
-        min(_round_up(key_block, 128), max(table_pages * page_size, 1)),
-        math.lcm(page_size, 128),
-    )
+    step_pages = ctx_step_pages(table_pages, page_size, key_block)
+    bk_ctx = step_pages * page_size
     s_padq = _round_up(s, bq)
     s_padk = _round_up(s, bk_chunk)
     rows = bq * heads
@@ -281,7 +294,7 @@ def mla_paged_attention(
             pltpu.VMEM((rows, 128), jnp.float32),
             pltpu.VMEM((rows, 128), jnp.float32),
             pltpu.VMEM((rows, dv), jnp.float32),
-            pltpu.VMEM((2, bk_ctx, dk), pool.dtype),
+            pltpu.VMEM((2, step_pages, page_size, dk), pool.dtype),
             pltpu.SemaphoreType.DMA((2,)),
         ],
     )

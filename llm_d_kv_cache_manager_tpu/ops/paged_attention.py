@@ -34,7 +34,9 @@ PR 44), a program a lane that walks the lane's window itself:
 - A loop from the first page that holds a visible slot to the page of the
   last historical token, ``KEY_BLOCK / page_size`` pages a step: the kernel
   copies those page tiles of K and of V into one of two VMEM slots with
-  ``make_async_copy``, starts the next step's copies before it computes,
+  ``make_async_copy`` (a group of pages whose pool ids are consecutive as
+  ONE copy: ``_page_copies.for_step_pages``, PR 45), starts the next step's
+  copies before it computes,
   and makes one float32 online-softmax update over the whole block. Work
   follows the live window, not the table's width; a lane of length 0 runs
   no step.
@@ -65,6 +67,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ._mosaic import require_tpu_unless_interpret
+from ._page_copies import for_step_pages
 
 _NEG_INF = float("-inf")
 # Finite, for the window kernel: a block whose every slot is masked must give
@@ -78,7 +81,10 @@ _MASKED = -1e30
 #: took 5.02 / 4.32 / 4.29 / 4.59 / 5.12 ms at 128 / 256 / 384 / 512 / 1024
 #: tokens a step: a lane's last step holds one page and computes a whole
 #: block, and the copies alone are 3.0-3.5 ms (chip runs, PR 44: PERF.md
-#: section 6).
+#: section 6). With a run of pages as one copy (``_page_copies``; chip runs,
+#: PR 45, another table and pool than PR 44's): 5.12 ms before, 4.82 / 4.74
+#: at 256 / 512 over tables that are one run, 4.96 / 5.16 over tables that
+#: hold none: 256 is kept.
 KEY_BLOCK = 256
 #: what the window kernel may use of a v5e core's 128 MiB of VMEM (the
 #: compiler's default scoped limit is 16 MiB)
@@ -286,25 +292,15 @@ def _window_decode_kernel(
     q = q_ref[0].astype(jnp.float32)  # [n_kv, group, d]
 
     def for_live_pages(step, act):
-        """``act`` on the (K, V) copy of every page of ``step`` that holds
-        history. The handles are rebuilt identically at start and at wait
-        time (the standard Pallas async-copy idiom)."""
+        """``act`` on the (K, V) copies of the pages of ``step`` that hold
+        history, a run of pages as one copy (``_page_copies``)."""
         slot = step % 2
         first = step * block_pages
-
-        def one_page(i, carry):
-            page = tables_ref[b, first_page + first + i]
-            dst = pl.ds(i * page_size, page_size)
-            act(pltpu.make_async_copy(
-                k_pool_ref.at[layer, page], k_buf.at[slot, dst], sem.at[0, slot]
-            ))
-            act(pltpu.make_async_copy(
-                v_pool_ref.at[layer, page], v_buf.at[slot, dst], sem.at[1, slot]
-            ))
-            return carry
-
-        jax.lax.fori_loop(
-            0, jnp.minimum(block_pages, n_pages - first), one_page, 0
+        for_step_pages(
+            act, tables_ref, b, first_page + first,
+            jnp.minimum(block_pages, n_pages - first), layer,
+            ((k_pool_ref, k_buf.at[slot], sem.at[0, slot]),
+             (v_pool_ref, v_buf.at[slot], sem.at[1, slot])),
         )
 
     def merge(state, k, v, visible):
@@ -349,10 +345,15 @@ def _window_decode_kernel(
         # dots. Slots past the live pages hold what an earlier step or call
         # left (or nothing yet): a zero probability times a stray NaN would
         # still be NaN, so those values are zeroed, not only masked.
-        tok = start + jax.lax.broadcasted_iota(jnp.int32, k_buf.shape[1:], 0)
-        k = jnp.swapaxes(k_buf[slot].astype(jnp.float32), 0, 1)
+        # A slot is [pages, page_size, n_kv, d], as a run lies in the pool:
+        # merging its two leading dimensions moves nothing.
+        keys = (block, n_kv, head_dim)
+        tok = start + jax.lax.broadcasted_iota(jnp.int32, keys, 0)
+        k = jnp.swapaxes(k_buf[slot].reshape(keys).astype(jnp.float32), 0, 1)
         v = jnp.swapaxes(
-            jnp.where(tok < hist, v_buf[slot].astype(jnp.float32), 0.0), 0, 1
+            jnp.where(
+                tok < hist, v_buf[slot].reshape(keys).astype(jnp.float32), 0.0
+            ), 0, 1,
         )
         slot_idx = start + jax.lax.broadcasted_iota(
             jnp.int32, (n_kv, group, block), 2
@@ -377,6 +378,13 @@ def _window_decode_kernel(
     _, denom, acc = state
     safe_l = jnp.where(denom == 0.0, 1.0, denom)  # len-0 lane -> zeros, not NaN
     out_ref[0] = (acc / safe_l).astype(out_ref.dtype)
+
+
+def window_step_pages(table_pages: int, page_size: int) -> int:
+    """Pages a step of the window kernel: ``KEY_BLOCK`` tokens in whole lane
+    tiles of keys, no wider than the table."""
+    lane_pages = 128 // math.gcd(128, page_size)
+    return -(-min(KEY_BLOCK // page_size, table_pages) // lane_pages) * lane_pages
 
 
 @functools.partial(jax.jit, static_argnames=("window", "scale", "interpret"))
@@ -406,9 +414,7 @@ def paged_window_attention(
     group = n_heads // n_kv_heads
     table_pages = block_tables.shape[1]
     has_fresh = fresh_k is not None
-    # Pages a step: whole lane tiles of keys, no wider than the table.
-    lane_pages = 128 // math.gcd(128, page_size)
-    block_pages = -(-min(KEY_BLOCK // page_size, table_pages) // lane_pages) * lane_pages
+    block_pages = window_step_pages(table_pages, page_size)
     block = block_pages * page_size
 
     q_blocked = q.reshape(batch, n_kv_heads, group, head_dim)
@@ -435,8 +441,12 @@ def paged_window_attention(
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, n_kv_heads, group, head_dim), lane_index),
         scratch_shapes=[
-            pltpu.VMEM((2, block, n_kv_heads, head_dim), k_pages.dtype),
-            pltpu.VMEM((2, block, n_kv_heads, head_dim), v_pages.dtype),
+            pltpu.VMEM(
+                (2, block_pages, page_size, n_kv_heads, head_dim), k_pages.dtype
+            ),
+            pltpu.VMEM(
+                (2, block_pages, page_size, n_kv_heads, head_dim), v_pages.dtype
+            ),
             pltpu.SemaphoreType.DMA((2, 2)),  # (K, V) x slot
         ],
     )

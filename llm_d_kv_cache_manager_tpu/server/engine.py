@@ -1015,7 +1015,11 @@ class Engine:
         #: in a model with sliding ones). Sliding layers:
         #: ``window_ctx_tokens``, the sum of ``min(context, window)`` over
         #: the same lanes and steps (what a sliding layer reads of the
-        #: window pool; 0 for a model without such layers).
+        #: window pool; 0 for a model without such layers). A kernel that
+        #: copies its lanes' table pages itself (the latent kernel, the
+        #: sliding layers'): ``ctx_pages``, the pages a layer's call copies
+        #: over the same dispatches, and ``ctx_run_pages``, those copied as
+        #: part of a run of consecutive pool pages (``_count_ctx_pages``).
         #: Off by default: ``obs_step_timing=False`` skips every clock
         #: read and every count, so the legacy step path is untouched.
         self.obs_step_timing = False
@@ -1039,6 +1043,8 @@ class Engine:
             "latent_ctx_tokens": 0,
             "attn_ctx_tokens": 0,
             "window_ctx_tokens": 0,
+            "ctx_pages": 0,
+            "ctx_run_pages": 0,
             "prefill_s": 0.0,
             "decode_s": 0.0,
             "sample_s": 0.0,
@@ -2778,7 +2784,11 @@ class Engine:
             )
             toks = self._keep_pools(out)
         self._count_decode_dispatch(
-            len(active), temperature, seq_lens, k, chained=prev is not None
+            len(active), temperature, seq_lens, k, chained=prev is not None,
+            tables=(
+                (w_tables, w_starts) if self.window_pages is not None
+                else (block_tables, 0)
+            ),
         )
         # Start the D2H copy of the sampled ids now: the bytes land while
         # the host goes on (with a burst chained behind this one, while
@@ -3401,7 +3411,7 @@ class Engine:
     def _count_decode_dispatch(
         self, rows: int, temperature: np.ndarray,
         seq_lens: Optional[np.ndarray] = None, steps: int = 1,
-        chained: bool = False,
+        chained: bool = False, tables: Optional[tuple] = None,
     ) -> None:
         """``step_stats``' counters of one decode dispatch: its real lanes,
         whether any of them samples (``temperature`` is the host-side
@@ -3411,7 +3421,10 @@ class Engine:
         latent pool also under ``latent_ctx_tokens``) (``seq_lens``: the
         host-side lengths of the dispatch, 0 for a lane that is not real;
         a lane's context grows by one a step). ``decode_forwards`` grows by
-        ``steps``: the forwards ``experts_touched`` is summed over."""
+        ``steps``: the forwards ``experts_touched`` is summed over.
+        ``tables``: the table array a kernel that walks its lanes' tables
+        itself was given and the position each row's first slot stands for
+        (``_count_ctx_pages``)."""
         if self.obs_step_timing:
             self.step_stats["decode_dispatches"] += 1
             self.step_stats["decode_forwards"] += steps
@@ -3435,6 +3448,47 @@ class Engine:
                         np.minimum(seq_lens[seq_lens > 0] + j, w).sum()
                         for j in range(steps)
                     ))
+                if tables is not None:
+                    self._count_ctx_pages(seq_lens, *tables, steps)
+
+    def _count_ctx_pages(
+        self, seq_lens: np.ndarray, tables: np.ndarray, starts, steps: int
+    ) -> None:
+        """``step_stats["ctx_pages"]`` / ``["ctx_run_pages"]``: the table
+        pages a layer's call of a decode dispatch copies, and those of them
+        it copies as part of a run (``ops/_page_copies.py``: the kernel's
+        own rule, group size and alignment). The latent kernel walks the
+        block table from its first slot over ``seq_len - 1`` rows; the
+        window kernel the window table (whose first slot stands for
+        ``starts``) from the first page that holds a visible slot. Counted
+        as the dispatch's first step finds the tables, times its ``steps``;
+        a model that runs neither kernel counts nothing."""
+        latent = bool(self.model_cfg.kv_lora_rank)
+        if not latent and self.window_pages is None:
+            return
+        from ..ops._page_copies import count_run_pages
+
+        ps, width = self.page_size, tables.shape[1]
+        hist = np.clip(seq_lens - starts - 1, 0, width * ps)
+        if latent:
+            from ..ops.mla_attention import ctx_step_pages
+
+            first = 0
+            step_pages = ctx_step_pages(width, ps)
+            pool_pages = self.k_pages.shape[1]
+        else:
+            from ..ops.paged_attention import window_step_pages
+
+            first = np.maximum(
+                seq_lens - starts - self.model_cfg.sliding_window, 0
+            ) // ps
+            step_pages = window_step_pages(width, ps)
+            pool_pages = self.window_pages[0].shape[1]
+        pages, in_runs = count_run_pages(
+            tables, first, -(-hist // ps) - first, step_pages, pool_pages
+        )
+        self.step_stats["ctx_pages"] += steps * pages
+        self.step_stats["ctx_run_pages"] += steps * in_runs
 
     def _sample(self, logits: jnp.ndarray, seqs: list[Sequence]) -> np.ndarray:
         """First tokens of a prefill batch (decode samples on the device,

@@ -388,6 +388,16 @@ class _ServingMetrics:
                 registry=self.registry,
             )
             self._window_ctx_seen = 0
+            self.engine_ctx_pages = prom.Counter(
+                "kvcache_engine_ctx_pages_total",
+                "Table pages a layer's call of the fused decode dispatches "
+                "copied, for a kernel that walks its lanes' tables itself "
+                "(the latent kernel, the sliding layers'), by kind: all, "
+                "run (those copied as part of a run of consecutive pool "
+                "pages, one copy a group)",
+                ["kind"], registry=self.registry,
+            )
+            self._ctx_pages_seen = {"ctx_pages": 0, "ctx_run_pages": 0}
             self.engine_chained = prom.Counter(
                 "kvcache_engine_decode_chained_dispatches_total",
                 "Decode dispatches enqueued one ahead: their input ids came "
@@ -676,6 +686,12 @@ class _ServingMetrics:
         if window_ctx > self._window_ctx_seen:
             self.engine_window_ctx.inc(window_ctx - self._window_ctx_seen)
             self._window_ctx_seen = window_ctx
+        for key, seen in self._ctx_pages_seen.items():
+            delta = step_stats.get(key, 0) - seen
+            if delta > 0:
+                kind = "run" if key == "ctx_run_pages" else "all"
+                self.engine_ctx_pages.labels(kind=kind).inc(delta)
+                self._ctx_pages_seen[key] = step_stats[key]
         chained = step_stats.get("decode_chained_dispatches", 0)
         if chained > self._chained_seen:
             self.engine_chained.inc(chained - self._chained_seen)
