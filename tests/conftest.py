@@ -10,8 +10,12 @@ The platform is pinned both ways — the environment for child processes,
 initialization, so a test run can never take (or wait for) a chip.
 """
 
+import contextlib
+import faulthandler
 import os
+import signal
 import sys
+import threading
 
 # Must precede the first jax backend initialization (not merely jax import).
 flags = os.environ.get("XLA_FLAGS", "")
@@ -37,16 +41,37 @@ class CharTokenizer(Tokenizer):
         return [ord(c) for c in prompt], [(i, i + 1) for i in range(len(prompt))]
 
 
+#: ``free_tcp_port`` hands each xdist worker a range of its own, below the
+#: kernel's ephemeral range (32768 up: what a client socket of another test
+#: may be given), and never the same port twice in a process.
+_PORT_BASE, _PORTS_PER_WORKER, _PORT_RANGES = 20000, 1000, 12
+_ports_given: set[int] = set()
+
+
 def free_tcp_port() -> int:
-    """An ephemeral TCP port — fixed test ports collide when suites run
-    concurrently (two pytest processes, or pytest alongside a dev server)."""
+    """A TCP port nobody holds — fixed test ports collide when suites run
+    concurrently (two pytest processes, or pytest alongside a dev server),
+    and a port the kernel picked is free only until the next caller asks: two
+    workers, or one worker twice, were handed the same one before the first
+    had bound it. Each worker draws from its own range, starting where its
+    process id says (two runs side by side start apart), skips what it has
+    given out before, and checks the rest free by a bind."""
     import socket
 
-    s = socket.socket()
-    s.bind(("", 0))
-    port = s.getsockname()[1]
-    s.close()
-    return port
+    worker = os.environ.get("PYTEST_XDIST_WORKER", "gw0")  # "gw3"
+    base = _PORT_BASE + int(worker[2:]) % _PORT_RANGES * _PORTS_PER_WORKER
+    for step in range(_PORTS_PER_WORKER):
+        port = base + (os.getpid() + step) % _PORTS_PER_WORKER
+        if port in _ports_given:
+            continue
+        _ports_given.add(port)
+        with socket.socket() as s:
+            try:
+                s.bind(("", port))
+            except OSError:
+                continue  # somebody else's
+        return port
+    raise RuntimeError(f"no free port left in {base}..{base + _PORTS_PER_WORKER}")
 
 
 def _build_native_once() -> None:
@@ -72,6 +97,12 @@ def _build_native_once() -> None:
 def pytest_configure(config):
     if not hasattr(config, "workerinput"):  # the controller, or no xdist
         _build_native_once()
+    # ``--dist loadfile`` deals files out most tests first unless told not
+    # to, and a file of few tests is a long one here (a cell's rehearsals, an
+    # engine's cases): they came last and one worker ran the run's tail
+    # alone. Collection order it is, and ``_LONGEST_FIRST`` sets that.
+    if hasattr(config.option, "loadscopereorder"):
+        config.option.loadscopereorder = False
     # Lock-order/race harness: LOCKTRACE=1 routes every lock created from
     # here on through utils.locktrace's TracingLock, so the concurrency
     # hammer and chaos suites run under cycle + guarded-attribute checking
@@ -87,48 +118,114 @@ def pytest_configure(config):
     )
     config.addinivalue_line(
         "markers",
-        "slow: heavy fuzz matrices / multi-config sweeps — excluded from the "
-        "fast pre-commit loop (`pytest -m 'not slow'`); CI's full job runs "
-        "everything",
+        "slow: run by nobody (the driver's tier-1 command is `-m 'not slow'` "
+        "and there is no other job); the classes and what covers each are "
+        "`_SLOW_CLASSES` in tests/conftest.py",
     )
 
 
-#: Heavy suites (fuzz matrices, multi-config sweeps, cross-engine numerics
-#: oracles) auto-marked ``slow`` — kept as one table instead of markers
-#: scattered over seven files. Measured on the dev rig: the full suite is
-#: ~12.5 min; `pytest -m "not slow"` keeps the per-commit loop under 5.
-#: Coverage rationale: everything here is either randomized re-coverage of
-#: paths the fast tests pin directly, or parity oracles that only move when
-#: the model/ops layer changes.
+#: The classes NO RUN EXECUTES: the driver's tier-1 command is ``-m 'not
+#: slow'`` and this repository has no other job, so ``slow`` means "run by
+#: nobody". This table is the whole list. A line a class: tests, cpu-seconds
+#: of the class alone on the CPU (junit sums of PR 46's census: every class
+#: run once, all passed), and the tier-1 test that covers the same path, or
+#: "none". A class leaves the table when it passes three times in a row under
+#: ``-n 6``, draws nothing at random without a fixed seed and costs under 10 s
+#: a test; the tier-1 clock is what keeps the others here. ``ROADMAP.md`` D15
+#: names the code that only these classes reach.
 _SLOW_CLASSES = {
+    # 5 tests, 32 s. Covered: test_chunked_prefill.py::TestChunkedPrefillParity
     ("test_chunked_prefill.py", "TestChunkedInterference"),
+    # 5 tests, 79 s (seeded). Covered for fixed cases:
+    # test_run_ahead.py::test_greedy_parity_with_the_engine_that_waits
     ("test_engine.py", "TestDecodePathParityFuzz"),
-    ("test_engine.py", "TestMoEServing"),
-    ("test_engine.py", "TestGemmaServing"),
-    ("test_engine.py", "TestHostDramOffloadTier"),
-    ("test_engine.py", "TestTensorParallelServing"),
+    # 8 tests, 49 s. Covered for the expert-parallel dispatch: test_quant.py::
+    # TestQuantizedSharding::test_quantized_moe_with_expert_parallel_dispatch;
+    # for its two train steps: none
     ("test_parallel.py", "TestMoEExpertParallel"),
+    # 2 tests, 18 s. Covered: none (parallel/train.py)
     ("test_parallel.py", "TestShardedTraining"),
-    ("test_parallel.py", "TestSharding"),
+    # 1 test, 17 s. Covered: none (parallel/train.py)
     ("test_parallel.py", "TestTrainForwardMatchesServing"),
+    # 7 tests, 42 s (needs ``transformers``). Covered: none for the dense,
+    # Gemma and Qwen3 loaders against ``transformers``; the four newer
+    # architectures' loaders are held to the float32 references
+    # (test_mla.py::test_a_saved_state_dict_loads_to_the_references_logits
+    # and its like)
     ("test_llama_model.py", "TestHFNumericsParity"),
+    # 3 tests, 39 s. Covered for the routed layer:
+    # test_llama_model.py::TestRoutedDispatch; against ``transformers``: none
     ("test_llama_model.py", "TestMixtralMoE"),
-    ("test_llama_model.py", "TestPrefillDecodeConsistency"),
+    # 1 test, 22 s. Covered: none for the kernel under expert parallelism
+    # (the XLA dispatch: test_quant.py::TestQuantizedSharding)
     ("test_gmm.py", "TestExpertParallelWithKernel"),
+    # 3 tests, 19 s. Covered a layer at a time:
+    # test_moe_padding.py::test_real_rows_read_what_they_read_alone[*-kernel-*]
     ("test_gmm.py", "TestRoutedDispatchWithKernel"),
+    # 8 tests, 20 s. Covered through the prefill that calls it:
+    # test_ring_attention.py::TestSpPrefill
     ("test_ring_attention.py", "TestRingAttention"),
+    # 1 test, 12 s. Covered: none (``EngineConfig.sp`` end to end)
     ("test_ring_attention.py", "TestSpEngine"),
+    # 3 tests, 16 s. Covered: none (parallel/checkpoint.py)
     ("test_checkpoint.py", "TestQuantizedCheckpoint"),
+    # 4 tests, 24 s. Covered: none (parallel/checkpoint.py)
     ("test_checkpoint.py", "TestCheckpoint"),
+    # 15 tests, 291 s (a whole served program a case, 10-41 s each). Covered
+    # at the fewest layers that keep every kind: test_pool_layout.py::
+    # TestThePrefillLoopReadsThePools::test_the_loop_copies_no_pool
+    ("test_pool_layout.py", "TestServedPrograms"),
 }
 
 
-#: per-test wall-clock cap (seconds) applied when pytest-timeout is
-#: installed (CI installs it; local runs without it are unchanged). A
-#: deadlocked drain/abort test then fails fast with a stack dump instead of
-#: eating the whole tier-1 budget. Generous: the slowest legitimate tests
-#: (fuzz matrices, multi-config sweeps) finish well under it.
-_PER_TEST_TIMEOUT_S = 300
+#: The files over a minute of the last whole tier-1 run, longest first
+#: (cpu-seconds a file: ``python tests/junit_costs.py <junit file> --over
+#: 60``; PR 46's run on six workers). They are collected in this order and
+#: every other file after them, so a worker's last file is a short one: the
+#: run ends some 5 % over the cpu-seconds a worker where it ended 40 % over. A
+#: stale list costs balance, nothing else; a file that is not here sorts
+#: where it always did.
+_LONGEST_FIRST = (
+    "chipbench_tests/test_swa_cell.py",  # 552
+    "chipbench_tests/test_hybrid_cell.py",  # 327
+    "chipbench_tests/test_scmoe_cell.py",  # 302
+    "chipbench_tests/test_chipbench.py",  # 165
+    "chipbench_tests/test_latent_cell.py",  # 155
+    "test_swa_engine_decode.py",  # 134
+    "test_conv_state_engine.py",  # 133
+    "test_moe_padding.py",  # 125
+    "test_mla.py",  # 125
+    "test_swa_rows.py",  # 122
+    "test_paged_attention_window_steps.py",  # 120
+    "test_conv_state.py",  # 114
+    "test_engine.py",  # 107
+    "test_paged_attention_window.py",  # 104
+    "test_block_diffusion_engine.py",  # 100
+    "test_scmoe.py",  # 98
+    "test_chip_smoke_dry_run.py",  # 98
+    "test_swa.py",  # 97
+    "test_flash_prefill.py",  # 96
+    "test_block_diffusion.py",  # 95
+    "test_packed_inputs.py",  # 95
+    "test_swa_kernels.py",  # 94
+    "test_swa_harness.py",  # 88
+    "test_pool_layout.py",  # 75
+    "test_mla_engine.py",  # 73
+    "test_run_ahead.py",  # 65
+    "test_packed_inputs_engine.py",  # 65
+    "test_swa_engine.py",  # 63
+    "chipbench_tests/test_block_diffusion_cell.py",  # 63
+)
+
+
+#: per-test wall-clock cap (seconds): a deadlocked drain/abort test fails
+#: with a dump of every thread's stack and the run goes on, where it would
+#: take the rest of the tier-1 clock with it. ``pytest-timeout`` applies it
+#: where it is installed (it is not here), ``_per_test_cap`` below where not.
+#: Twice the slowest test of the driver's run at PR 45 (302 s,
+#: ``test_swa_cell.py::test_the_probes_controls_each_read_not_correct``): no
+#: test that passes may fail by it.
+_PER_TEST_TIMEOUT_S = 600
 
 
 def pytest_unconfigure(config):
@@ -139,6 +236,41 @@ def pytest_unconfigure(config):
 
 
 import pytest  # noqa: E402
+
+
+@contextlib.contextmanager
+def capped(seconds: float):
+    """The body under a timer: when it rings, every thread's stack goes to
+    stderr and ``pytest.fail`` is raised where the main thread stands (a
+    lock's ``acquire``, a ``join`` and a ``sleep`` are woken by the signal),
+    with the cap in its message. A timer that was running is set again on
+    the way out."""
+
+    def ring(signum, frame):
+        faulthandler.dump_traceback(file=sys.stderr, all_threads=True)
+        pytest.fail(
+            f"over the per-test cap of {seconds:g} s (tests/conftest.py); "
+            "the stacks of all threads are on stderr", pytrace=False)
+
+    handler = signal.signal(signal.SIGALRM, ring)
+    outer = signal.setitimer(signal.ITIMER_REAL, seconds)
+    try:
+        yield
+    finally:
+        signal.setitimer(signal.ITIMER_REAL, *outer)
+        signal.signal(signal.SIGALRM, handler)
+
+
+@pytest.fixture(autouse=True)
+def _per_test_cap(request):
+    """``_PER_TEST_TIMEOUT_S`` without the plugin. A signal's handler runs on
+    the main thread, which is where pytest, and an xdist worker, run a test."""
+    if (request.config.pluginmanager.hasplugin("timeout")
+            or threading.current_thread() is not threading.main_thread()):
+        yield
+        return
+    with capped(_PER_TEST_TIMEOUT_S):
+        yield
 
 
 @pytest.fixture(autouse=True)
@@ -158,8 +290,10 @@ def _locktrace_gate():
 
 
 def pytest_collection_modifyitems(config, items):
-    import pytest
-
+    tests_dir = os.path.dirname(os.path.abspath(__file__))
+    rank = {name: i for i, name in enumerate(_LONGEST_FIRST)}
+    items.sort(key=lambda item: rank.get(  # stable: a file stays together
+        os.path.relpath(str(item.fspath), tests_dir), len(rank)))
     have_timeout = config.pluginmanager.hasplugin("timeout")
     for item in items:
         if have_timeout and item.get_closest_marker("timeout") is None:

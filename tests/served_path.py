@@ -1,0 +1,261 @@
+"""What the architectures' test files share (``test_mla*``, ``test_scmoe*``,
+``test_conv_state*``, ``test_swa*``, ``test_block_diffusion*``): prompts, the
+error that is compared, the reference's logits and picks, an engine at the
+tests' sizes, and the served programs driven as the engine drives them.
+Not collected (no ``test_`` in its name); the files import from here, not
+from each other.
+
+What is an architecture's own (its preset, its reference, its tolerance, the
+cases that are its mechanism's) stays in its files; so does the second pool it
+lays beside the keys and values where one file drives it (the state pool of
+``tests/test_conv_state.py``), and the window pool is here because two do.
+"""
+
+import numpy as np
+
+from llm_d_kv_cache_manager_tpu.models import llama
+from llm_d_kv_cache_manager_tpu.server import (
+    EngineConfig,
+    SamplingParams,
+    SchedulerConfig,
+)
+from llm_d_kv_cache_manager_tpu.server.engine import Engine
+
+#: ``served`` pads a call's chunk to whole ``CHUNK_BUCKET`` tokens and its
+#: context table to whole ``CTX_BUCKET`` pages (none stays none: a cold call
+#: is a program of its own, as it is in the engine), the block tables to
+#: whole ``TABLE_BUCKET`` pages and the pools to whole ``POOL_BUCKET`` pages,
+#: as the engine does with ``prefill_bucket`` and ``prefill_ctx_bucket``: the
+#: cases of one contract then share a handful of compiled programs. What lies
+#: past a row's end is masked by ``valid`` and by the lengths, so the logits
+#: compared are what they were.
+CHUNK_BUCKET = 16
+CTX_BUCKET = 4
+TABLE_BUCKET = 16
+POOL_BUCKET = 32
+
+
+def round_up(n: int, bucket: int) -> int:
+    return -(-n // bucket) * bucket
+
+
+def prompt_of(seed: int, n: int) -> list[int]:
+    return np.random.default_rng(seed).integers(1, 200, n).tolist()
+
+
+def rel_err(got, want) -> float:
+    return float(np.abs(got - want).max() / np.abs(want).max())
+
+
+def reference_logits(ref, params, cfg, tokens) -> np.ndarray:
+    """``ref``: a module of ``chipbench/references`` (float32, the whole
+    sequence at once, nothing of the program's model code)."""
+    return np.asarray(ref.forward(params, cfg, list(tokens))[0], np.float32)
+
+
+def picks(ref, params, cfg, ask, generated) -> list[int]:
+    """The reference's greedy choice at each generated position, given the
+    tokens the engine generated before it."""
+    logits = reference_logits(ref, params, cfg, ask + generated)
+    return logits[len(ask) - 1: -1].argmax(-1).tolist()
+
+
+def make_engine(cfg, params, pages, *, lanes=4, on_events=None, **engine):
+    """An interpreted engine at the tests' sizes. ``pages``: its
+    ``BlockManagerConfig``; ``engine``: any other field of ``EngineConfig``."""
+    engine.setdefault("scheduler", SchedulerConfig(max_prefill_batch=4))
+    engine.setdefault("max_model_len", 128)
+    return Engine(
+        EngineConfig(
+            model=cfg, block_manager=pages, decode_batch_size=lanes,
+            prefill_bucket=16, interpret=True, **engine,
+        ),
+        params=params, on_events=on_events,
+    )
+
+
+def run_all(engine, prompts, n):
+    seqs = [engine.add_request(p, SamplingParams(max_new_tokens=n))
+            for p in prompts]
+    while engine.has_work:
+        engine.step()
+    return seqs
+
+
+def run_one(engine, prompt, **sampling):
+    """(the finished sequence, its block table while it ran)."""
+    seq = engine.add_request(prompt, SamplingParams(**sampling))
+    table = []
+    while engine.has_work:
+        engine.step()
+        table = list(seq.block_table) or table
+    return seq, table
+
+
+def reference_generate(ref, params, cfg, prompt, max_tokens, steps, threshold):
+    """Generation by diffusion over blocks (``cfg.block_length`` > 0): the
+    published procedure on the reference's logits, greedy: the completion,
+    and every token of the final blocks (prompt included)."""
+    block, mask = cfg.block_length, cfg.mask_token_id
+    n_final = len(prompt) // block * block
+    final, tail = list(prompt[:n_final]), list(prompt[n_final:])
+    while len(final) - len(prompt) < max_tokens:
+        cur = tail + [mask] * (block - len(tail))
+        masked = np.array([False] * len(tail) + [True] * (block - len(tail)))
+        step = 0
+        while masked.any():
+            logits = reference_logits(ref, params, cfg, final + cur)[-block:]
+            probs = np.exp(logits - logits.max(-1, keepdims=True))
+            probs /= probs.sum(-1, keepdims=True)
+            x0, p = probs.argmax(-1), probs.max(-1)
+            owed = block // steps + (step < block % steps)
+            high = masked & (p > threshold)
+            if high.sum() >= owed:
+                fix = high
+            else:
+                order = np.argsort(-np.where(masked, p, -np.inf), kind="stable")
+                fix = np.zeros(block, bool)
+                fix[order[:owed]] = True
+                fix &= masked
+            cur = [int(x0[i]) if fix[i] else cur[i] for i in range(block)]
+            masked &= ~fix
+            step += 1
+        final, tail = final + cur, []
+    return final[len(prompt):len(prompt) + max_tokens], final
+
+
+class NoSecondPool:
+    """What ``served`` asks of the pool an architecture lays beside the keys
+    and values; this one is none (a latent pool, a plain GQA pool)."""
+
+    def make(self, cfg, rows: int, pages: int, table_pages: int) -> None:
+        """Before the first call: ``rows`` sequences, ``pages`` pages in the
+        key/value pools, block tables of ``table_pages`` pages."""
+
+    def prefill(self, chunks, positions, ctx_pages: int) -> dict:
+        """``llama.prefill``'s keywords for a call of ``chunks`` [(row,
+        first position, end)] at ``positions`` [rows, width] with a context
+        table of ``ctx_pages`` pages."""
+        return {}
+
+    def decode(self, positions) -> dict:
+        """``llama.decode_step``'s keywords for a step of every row at
+        ``positions`` [rows]."""
+        return {}
+
+    def keep(self, results) -> None:
+        """What the call returned after the logits, keys and values."""
+        assert not results
+
+
+class WindowPages(NoSecondPool):
+    """The second pool of a model with sliding layers: a row's window pages
+    come from its own free list and are given back as the engine's block
+    manager gives them back. ``window_table``: the reference's own table of
+    one sequence's window pages (``chipbench/references/swa_moe.WindowTable``)."""
+
+    def __init__(self, window_table, page_size: int):
+        self.window_table, self.page_size = window_table, page_size
+
+    def make(self, cfg, rows, pages, table_pages):
+        self.width = table_pages + 2
+        self.pool = llama.init_window_pages(
+            cfg, rows * self.width + 1, self.page_size)
+        self.tables = []
+        for i in range(rows):  # a row's window pages: its own range of the pool
+            wt = self.window_table(
+                self.width + 1, cfg.sliding_window, self.page_size)
+            wt.free = [i * self.width + p for p in wt.free]
+            self.tables.append(wt)
+
+    def prefill(self, chunks, positions, ctx_pages):
+        b, width = positions.shape
+        w_ids = np.zeros((b, width), np.int32)
+        w_tab = np.zeros((b, ctx_pages), np.int32)
+        w_start = np.zeros((b,), np.int32)
+        for i, lo, hi in chunks:
+            wt = self.tables[i]
+            wt.move_to(lo, hi)
+            w_ids[i, : hi - lo] = wt.page_of(positions[i, : hi - lo])
+            w_tab[i] = wt.row(ctx_pages)[0]
+            w_start[i] = wt.first * self.page_size
+        return dict(window_pages=self.pool, window_rows=(w_ids, w_tab, w_start))
+
+    def decode(self, positions):
+        for wt, at in zip(self.tables, positions):
+            wt.move_to(at, at + 1)
+        return dict(
+            window_pages=self.pool,
+            window_tables=np.concatenate(
+                [wt.row(self.width) for wt in self.tables]),
+            window_start=np.array(
+                [wt.first * self.page_size for wt in self.tables], np.int32),
+        )
+
+    def keep(self, results):
+        (self.pool,) = results
+
+
+def served(params, cfg, rows, steps, attn_impl, *, page_size, second=None):
+    """``rows``: [(prompt, tokens resident before the batched call)]: each
+    row's first ``resident`` tokens are prefilled cold (a call of their own;
+    ``resident`` need not end a page), the rest in ONE batched, right-padded
+    call against them; then ``steps`` greedy decode steps of every row in one
+    batch. Returns the logits a row, [1 + steps, vocab], the tokens fed, and
+    (the key pool, the value pool, the block tables)."""
+    ps, b = page_size, len(rows)
+    second = second or NoSecondPool()
+    need = [-(-(len(p) + steps) // ps) for p, _ in rows]
+    tables = np.zeros((b, round_up(max(need), TABLE_BUCKET)), np.int32)
+    nxt = 1
+    for i, n in enumerate(need):
+        tables[i, :n] = np.arange(nxt, nxt + n)
+        nxt += n
+    pages = round_up(nxt + 1, POOL_BUCKET)
+    k_pages, v_pages = llama.init_kv_pages(cfg, pages, ps)
+    second.make(cfg, b, pages, tables.shape[1])
+
+    def prefill(chunks):
+        nonlocal k_pages, v_pages
+        width = round_up(max(hi - lo for _, lo, hi in chunks), CHUNK_BUCKET)
+        ctx_w = round_up(max(-(-lo // ps) for _, lo, _ in chunks), CTX_BUCKET)
+        tok = np.zeros((b, width), np.int32)
+        pos = np.zeros((b, width), np.int32)
+        ok = np.zeros((b, width), bool)
+        ctx_bt = np.zeros((b, ctx_w), np.int32)
+        ctx_len = np.zeros((b,), np.int32)
+        for i, lo, hi in chunks:
+            n = hi - lo
+            tok[i, :n] = rows[i][0][lo:hi]
+            pos[i, :n] = np.arange(lo, hi)
+            ok[i, :n] = True
+            ctx_bt[i, : -(-lo // ps)] = tables[i, : -(-lo // ps)]
+            ctx_len[i] = lo
+        page = np.take_along_axis(tables, pos // ps, axis=1)
+        logits, k_pages, v_pages, *rest = llama.prefill(
+            params, cfg, tok, pos, ok, k_pages, v_pages, page, pos % ps,
+            ctx_bt, ctx_len, attn_impl=attn_impl, interpret=True,
+            **second.prefill(chunks, pos, ctx_w),
+        )
+        second.keep(rest)
+        return np.asarray(logits, np.float32)
+
+    for i, (_, resident) in enumerate(rows):
+        if resident:
+            prefill([(i, 0, resident)])
+    last = prefill([(i, r, len(p)) for i, (p, r) in enumerate(rows)])
+    out = [[last[i]] for i in range(b)]
+    fed = [[] for _ in range(b)]
+    lens = np.array([len(p) for p, _ in rows], np.int32)
+    for step in range(steps):
+        toks = np.array([int(np.argmax(o[-1])) for o in out], np.int32)
+        logits, k_pages, v_pages, *rest = llama.decode_step(
+            params, cfg, toks, lens + step, k_pages, v_pages, tables,
+            lens + step + 1, page_size=ps, interpret=True,
+            **second.decode(lens + step),
+        )
+        second.keep(rest)
+        for i in range(b):
+            fed[i].append(int(toks[i]))
+            out[i].append(np.asarray(logits, np.float32)[i])
+    return [np.stack(o) for o in out], fed, (k_pages, v_pages, tables)

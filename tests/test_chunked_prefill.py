@@ -273,7 +273,8 @@ class TestChunkedPrefillParity:
 class TestChunkedInterference:
     """Heavier chunked-prefill coverage: the interference microbenchmark
     as a test, plus parity sweeps against the other decode paths
-    (auto-marked slow in conftest; CI's full job runs them)."""
+    (marked ``slow`` by the table in ``tests/conftest.py``: no run executes
+    them)."""
 
     @pytest.mark.parametrize(
         "kw",

@@ -387,6 +387,7 @@ class TestZMQReconnect:
 
         monkeypatch.setattr(zmq_subscriber, "_RECONNECT_BACKOFF_S", 0.1)
 
+        from chaos import wait_until
         from conftest import free_tcp_port
 
         port = free_tcp_port()
@@ -404,9 +405,13 @@ class TestZMQReconnect:
         pool = KVEventsPool(index, KVEventsPoolConfig(concurrency=1))
         pool.start()
         sub = ZMQSubscriber(pool, ZMQSubscriberConfig(endpoint=f"tcp://*:{port}"))
+        binds = []
+        run_subscriber = sub._run_subscriber
+        sub._run_subscriber = lambda ctx: (binds.append(1), run_subscriber(ctx))[1]
         sub.start()
         try:
-            time.sleep(0.5)  # a few failed bind/backoff cycles
+            # a few failed bind/backoff cycles: counted, not slept through
+            assert wait_until(lambda: len(binds) >= 4, timeout=20)
             squatter.close(linger=0)
 
             pub = ZMQPublisher(

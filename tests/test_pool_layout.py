@@ -468,7 +468,6 @@ _SERVED = [
 ]
 
 
-@pytest.mark.slow  # 20-60 s a program
 class TestServedPrograms:
     """The whole served programs at the cells' shapes, as
     ``python -m tools.aot_pool_copies`` compiles them: inside a whole

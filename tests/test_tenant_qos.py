@@ -426,6 +426,18 @@ class TestPreemptShedInterplay:
         server.start()
         try:
             free0 = server.engine.block_manager.num_free
+            # Expire the preempted (now WAITING) background request: the
+            # next shed scan drops it before any re-prefill compute. On the
+            # engine's thread, as the victim is folded: a loaded test thread
+            # that came to it a moment later found it admitted again.
+            scheduler = server.engine.scheduler
+            on_preempted = scheduler.on_preempted
+
+            def expire(seq):
+                on_preempted(seq)
+                seq.deadline = time.monotonic() - 1.0
+
+            scheduler.on_preempted = expire
             bg = server.submit(
                 _prompt(70, 8),
                 SamplingParams(max_new_tokens=32),
@@ -433,21 +445,17 @@ class TestPreemptShedInterplay:
                 deadline_s=600,
             )
             assert _wait_until(
-                lambda: any(
-                    s.num_generated > 0 for s in server.engine.scheduler.running
-                )
+                lambda: any(s.num_generated > 0 for s in scheduler.running),
+                timeout=120,  # the first programs compile under six workers
             )
             prem = server.submit(
                 _prompt(71, 28), SamplingParams(max_new_tokens=4), tenant="premium"
             )
             assert _wait_until(
                 lambda: server.engine.lifecycle_stats.get("priority_preempted", 0)
-                >= 1
+                >= 1,
+                timeout=120,
             )
-            # Expire the preempted (now WAITING) background request: the
-            # next shed scan drops it before any re-prefill compute.
-            for s in list(server.engine.scheduler.waiting):
-                s.deadline = time.monotonic() - 1.0
             bg_seq = bg.result(timeout=120)
             prem_seq = prem.result(timeout=120)
             assert bg_seq.finish_reason == "deadline"
