@@ -421,11 +421,6 @@ def _afmoe_config(hf_config, rope_scaling) -> LlamaConfig:
         raise NotImplementedError(
             f"score_func={has('score_func')!r} is not supported yet (sigmoid)"
         )
-    if has("n_group", 1) != 1 or has("topk_group", 1) != 1:
-        raise NotImplementedError(
-            "n_group / topk_group > 1 (group-limited routing) is not "
-            "supported yet"
-        )
     if not has("mup_enabled", False):
         raise NotImplementedError(
             "mup_enabled=false is not supported yet (the embedding is scaled "
@@ -454,6 +449,8 @@ def _afmoe_config(hf_config, rope_scaling) -> LlamaConfig:
         moe_scoring="sigmoid",
         routed_scaling_factor=float(has("route_scale", 1.0)),
         first_k_dense=has("num_dense_layers", 0),
+        n_group=has("n_group", 1) or 1,
+        topk_group=has("topk_group", 1) or 1,
         layer_types=kinds,
         sliding_window=hf_config.sliding_window,
         attn_output_gate=True,
@@ -545,6 +542,8 @@ def config_from_hf(hf_config) -> LlamaConfig:
         return _afmoe_config(hf_config, rope_scaling)
     if getattr(hf_config, "model_type", "") == "longcat_flash":
         return _longcat_flash_config(hf_config, rope_scaling)
+    if getattr(hf_config, "model_type", "") == "bailing_hybrid":
+        return _bailing_hybrid_config(hf_config, rope_scaling)
     cls_name = hf_config.__class__.__name__
     is_gemma = cls_name == "GemmaConfig"
     if cls_name.startswith("Gemma") and not is_gemma:
@@ -629,11 +628,6 @@ def _deepseek_v3_fields(hf_config) -> dict:
     def has(key, default=None):
         return getattr(hf_config, key, default)
 
-    if has("n_group", 1) != 1 or has("topk_group", 1) != 1:
-        raise NotImplementedError(
-            f"group-limited routing is not supported yet (n_group="
-            f"{has('n_group')}, topk_group={has('topk_group')})"
-        )
     if has("scoring_func", "sigmoid") != "sigmoid" or has(
         "topk_method", "noaux_tc"
     ) != "noaux_tc":
@@ -656,4 +650,102 @@ def _deepseek_v3_fields(hf_config) -> dict:
         moe_scoring="sigmoid",
         routed_scaling_factor=float(has("routed_scaling_factor", 1.0)),
         first_k_dense=has("first_k_dense_replace", 0),
+        n_group=has("n_group", 1) or 1,
+        topk_group=has("topk_group", 1) or 1,
+    )
+
+
+def _bailing_hybrid_config(hf_config, rope_scaling) -> LlamaConfig:
+    """``model_type: bailing_hybrid`` (Ling-3.0): groups of
+    ``layer_group_size`` layers, each a run of delta-rule linear attentions
+    closed by one latent attention (layer ``i`` is latent where ``(i + 1) %
+    layer_group_size == 0``), leading dense layers, then sigmoid-routed
+    experts chosen within the best groups beside shared experts. What the
+    program does not run is refused here by name; the multi-token-prediction
+    module (``num_nextn_predict_layers``) is not part of the decoder and is
+    not loaded."""
+    def has(key, default=None):
+        return getattr(hf_config, key, default)
+
+    if has("use_kda_lora", False) or not has("no_kda_lora", True):
+        raise NotImplementedError(
+            "use_kda_lora (low-rank gate projections) is not supported yet"
+        )
+    if has("score_function", "sigmoid") != "sigmoid" or not has(
+        "moe_router_enable_expert_bias", True
+    ):
+        raise NotImplementedError(
+            f"score_function={has('score_function')!r} / "
+            "moe_router_enable_expert_bias=false is not supported yet "
+            "(sigmoid scores with a bias that chooses)"
+        )
+    if has("num_kv_heads_for_linear_attn", 0) not in (
+        0, hf_config.num_attention_heads
+    ):
+        raise NotImplementedError(
+            "num_kv_heads_for_linear_attn other than the attention heads is "
+            "not supported yet"
+        )
+    if has("group_norm_size", 1) != 1 or has(
+        "gated_attention_proj_granularity_type", "head_wise"
+    ) != "head_wise":
+        raise NotImplementedError(
+            "group_norm_size / gated_attention_proj_granularity_type other "
+            "than 1 / head_wise is not supported yet"
+        )
+    if has("q_lora_rank") or rope_scaling is not None:
+        raise NotImplementedError(
+            "q_lora_rank / rope_scaling with linear layers is not supported yet"
+        )
+    for key in ("use_nGPT", "use_mla_nope", "value_norm", "up_proj_norm",
+                "scale_router_input", "use_bias", "use_qkv_bias"):
+        if has(key, False):
+            raise NotImplementedError(f"{key}=true is not supported yet")
+    if not (has("use_qk_norm", True) and has("linear_silu", True)):
+        raise NotImplementedError(
+            "use_qk_norm=false / linear_silu=false is not supported yet"
+        )
+    n, group = hf_config.num_hidden_layers, hf_config.layer_group_size
+    limits = {
+        name: tuple(has(key) or ()) or None
+        for name, key in (
+            ("expert_swiglu_limits", "expert_swiglu_limit_list"),
+            ("shared_swiglu_limits", "share_expert_swiglu_limit_list"),
+        )
+    }
+    return LlamaConfig(
+        vocab_size=hf_config.vocab_size,
+        hidden_size=hf_config.hidden_size,
+        intermediate_size=hf_config.intermediate_size,
+        n_layers=n,
+        n_heads=hf_config.num_attention_heads,
+        n_kv_heads=hf_config.num_key_value_heads,
+        head_dim=has("head_dim"),
+        rope_theta=float(has("rope_theta", 10_000.0)),
+        rms_norm_eps=has("rms_norm_eps", 1e-6),
+        tie_word_embeddings=bool(has("tie_word_embeddings", False)),
+        n_experts=hf_config.num_experts,
+        n_experts_per_tok=hf_config.num_experts_per_tok,
+        moe_intermediate_size=hf_config.moe_intermediate_size,
+        norm_topk_prob=bool(has("norm_topk_prob", True)),
+        kv_lora_rank=hf_config.kv_lora_rank,
+        qk_nope_head_dim=hf_config.qk_nope_head_dim,
+        qk_rope_head_dim=hf_config.qk_rope_head_dim,
+        v_head_dim=hf_config.v_head_dim,
+        rope_interleave=bool(has("rope_interleave", True)),
+        n_shared_experts=has("num_shared_experts", 0) or 0,
+        moe_scoring="sigmoid",
+        routed_scaling_factor=float(has("routed_scaling_factor", 1.0)),
+        n_group=has("n_group", 1) or 1,
+        topk_group=has("topk_group", 1) or 1,
+        first_k_dense=has("first_k_dense_replace", 0),
+        layer_types=tuple(
+            "full_attention" if (i + 1) % group == 0 else "linear_attention"
+            for i in range(n)
+        ),
+        kda_head_dim=has("head_dim"),
+        kda_conv_kernel=hf_config.short_conv_kernel_size,
+        kda_safe_gate=bool(has("kda_safe_gate", False)),
+        kda_lower_bound=float(has("kda_lower_bound", -5.0)),
+        **limits,
     )

@@ -87,7 +87,9 @@ from .quant import QuantizedTensor, materialize as _w
 #: ``moe_router``: a routed layer's ``mlp_norm``, ``_moe_gates`` and the
 #: sort / permutation of rows by expert; ``moe_experts``: the grouped
 #: matmuls, the gate weighting and the un-permutation; ``moe_zero``: the
-#: identity (zero-compute) experts' multiply-add; ``moe_shared``: the
+#: identity (zero-compute) experts' multiply-add; ``kda``: a delta-rule
+#: linear-attention mixer whole (projections, convolution, recurrence, gate,
+#: output); ``moe_shared``: the
 #: shared experts; ``cache_write``: the all-layer scatters into the pools;
 #: ``head``: the final norm and the logits; ``sample``:
 #: ``ops/sampling.py``'s entry points. The Pallas kernels keep their own
@@ -96,7 +98,7 @@ from .quant import QuantizedTensor, materialize as _w
 #: Readers and documents quote this tuple, as they do ``server/engine.py``'s
 #: ``STEP_PHASES`` for the host's side.
 MODEL_SCOPES = (
-    "attn", "attn_window", "conv", "ffn", "moe_router", "moe_experts",
+    "attn", "attn_window", "conv", "kda", "ffn", "moe_router", "moe_experts",
     "moe_zero", "moe_shared", "cache_write", "head", "sample",
 )
 
@@ -416,7 +418,10 @@ class LlamaConfig:
     # moe_inter), the router's scoring function ("softmax" | "sigmoid":
     # with "sigmoid" a per-expert correction bias chooses the experts and
     # does not weigh them), a factor on the routed sum, group-limited
-    # routing (only n_group = topk_group = 1 is run), and the number of
+    # routing (``n_group`` > 1: the experts lie in that many groups, a
+    # group's score is the sum of its two largest choice scores, and only
+    # the experts of the ``topk_group`` best groups can be chosen:
+    # ``_group_limited``), and the number of
     # leading layers whose FFN is the dense one. ``first_k_dense`` decides
     # only which parameters ``init_params`` and the loader make: a layer's
     # FFN is read from the layer itself (it has a ``router`` or not).
@@ -488,6 +493,29 @@ class LlamaConfig:
     # residual add (``attn_post_norm`` / ``mlp_post_norm``; a layer that has
     # them applies them): ``a = h + N2(Attn(N1 h)); h' = a + N4(F(N3 a))``.
     sandwich_norm: bool = False
+    # Delta-rule linear-attention layers whose state is a matrix a head
+    # (Kimi Delta Attention as ``bailing_hybrid`` configures it):
+    # ``layer_types[i]`` "linear_attention" is a layer that keeps, a sequence,
+    # ``n_heads`` matrices ``[kda_head_dim, kda_head_dim]`` float32 and the
+    # ``kda_conv_kernel - 1`` newest rows of its q, k, v convolution inputs,
+    # and no key or value: its state lives in a STATE POOL OF SLOTS
+    # (``init_kda_state``; ``server/block_manager.py`` ``StatePool``), one
+    # live slot a sequence and snapshots every ``STATE_SNAPSHOT_TOKENS``. A
+    # position never enters. ``kda_safe_gate``: the log decay is
+    # ``kda_lower_bound * sigmoid(exp(A_log) * a)``, in (lower bound, 0);
+    # else the paper's ``-exp(A_log) * softplus(a)``. ``layer_types`` decides
+    # what ``init_params`` and the loader make; the bodies read the layer (it
+    # has ``kda_qkv`` or not). ``kda_lora`` (low-rank gate projections) and a
+    # non-zero SwiGLU clamp (``expert_swiglu_limits`` /
+    # ``shared_swiglu_limits``, a published layer each) are carried for the
+    # engine's refusals: neither is run.
+    kda_head_dim: int = 0
+    kda_conv_kernel: int = 0
+    kda_safe_gate: bool = False
+    kda_lower_bound: float = -5.0
+    kda_lora: bool = False
+    expert_swiglu_limits: Optional[tuple] = None
+    shared_swiglu_limits: Optional[tuple] = None
     dtype: Any = jnp.bfloat16
 
     @property
@@ -495,12 +523,13 @@ class LlamaConfig:
         return self.head_dim or self.hidden_size // self.n_heads
 
     def layer_kind(self, i: int) -> str:
-        """"conv", "sliding" or "attention", as ``layer_types`` publishes
-        layer ``i``."""
+        """"conv", "sliding", "linear" or "attention", as ``layer_types``
+        publishes layer ``i``."""
         kind = self.layer_types[i] if self.layer_types else "full_attention"
-        return {"conv": "conv", "sliding_attention": "sliding"}.get(
-            kind, "attention"
-        )
+        return {
+            "conv": "conv", "sliding_attention": "sliding",
+            "linear_attention": "linear",
+        }.get(kind, "attention")
 
     def _n_layers_of(self, kind: str) -> int:
         return sum(self.layer_kind(i) == kind for i in range(self.n_layers))
@@ -508,6 +537,51 @@ class LlamaConfig:
     @property
     def n_conv_layers(self) -> int:
         return self._n_layers_of("conv")
+
+    @property
+    def n_kda_layers(self) -> int:
+        """Layers of the state pool of slots: the linear attentions."""
+        return self._n_layers_of("linear")
+
+    @property
+    def kda_conv_row(self) -> int:
+        """Values of one slot's carried convolution rows in one linear
+        layer: the ``kda_conv_kernel - 1`` newest rows of ``[q | k | v]``
+        before the convolution, side by side (a ``[3, 12288]`` minor shape
+        would be padded to whole sublane tiles in HBM, as a convolution
+        layer's state would: ``init_state_pages``)."""
+        return max(self.kda_conv_kernel - 1, 0) * 3 * self.n_heads * self.kda_head_dim
+
+    @property
+    def kda_state_bytes(self) -> int:
+        """Bytes of one slot of the state pool, every linear layer."""
+        return self.n_kda_layers * (
+            self.n_heads * self.kda_head_dim**2 * 4
+            + self.kda_conv_row * jnp.dtype(self.dtype).itemsize
+        )
+
+    @property
+    def layer_group_size(self) -> Optional[int]:
+        """``layer_group_size`` as ``bailing_hybrid`` publishes it: every
+        layer of a group is a linear attention but its last."""
+        if not self.layer_types or "linear_attention" not in self.layer_types:
+            return None
+        return 1 + next(
+            i for i, kind in enumerate(self.layer_types)
+            if kind != "linear_attention"
+        )
+
+    @property
+    def expert_swiglu_limit_list(self) -> Optional[list]:
+        """The routed experts' SwiGLU clamps as a published file's JSON gives
+        them (a list a published layer; a tuple here, so a preset hashes)."""
+        limits = self.expert_swiglu_limits
+        return None if limits is None else list(limits)
+
+    @property
+    def share_expert_swiglu_limit_list(self) -> Optional[list]:
+        limits = self.shared_swiglu_limits
+        return None if limits is None else list(limits)
 
     @property
     def n_window_layers(self) -> int:
@@ -1035,6 +1109,91 @@ TINY_SWA_MOE = LlamaConfig(
     dtype=jnp.float32,
 )
 
+_KDA = "linear_attention"
+
+#: inclusionAI/Ling-3.0-flash (``model_type: bailing_hybrid``): 42 layers in
+#: groups of six, five delta-rule linear attentions (32 heads, a state of
+#: 128 x 128 a head, a four-tap convolution on q, k and v, the safe gate)
+#: then one latent attention of ``KANANA_2_30B_A3B``'s shape; two leading
+#: dense layers, then 512 sigmoid-routed experts of width 768, top-8 over
+#: the 4 best of 8 groups, one shared expert, gates renormalised, x 2.5.
+#: ``head_dim`` 128 is the published file's (a linear head's size; the
+#: latent layers' rope part is ``qk_rope_head_dim``), 32 KV heads its head
+#: count: neither sizes the latent pool. No chip holds a layer's 512 experts: a
+#: configuration states its share (``expert_first`` / ``expert_count``: one
+#: routing group) and its cut of depth and vocabulary. The SwiGLU clamp of
+#: the last eight layers is carried and refused where a run layer has one.
+LING_3_FLASH = LlamaConfig(
+    vocab_size=157_184,
+    hidden_size=2_560,
+    intermediate_size=6_144,
+    n_layers=42,
+    n_heads=32,
+    n_kv_heads=32,
+    head_dim=128,
+    rope_theta=6_000_000.0,
+    rms_norm_eps=1e-6,
+    n_experts=512,
+    n_experts_per_tok=8,
+    moe_intermediate_size=768,
+    norm_topk_prob=True,
+    kv_lora_rank=512,
+    qk_nope_head_dim=128,
+    qk_rope_head_dim=64,
+    v_head_dim=128,
+    rope_interleave=True,
+    n_shared_experts=1,
+    moe_scoring="sigmoid",
+    routed_scaling_factor=2.5,
+    n_group=8,
+    topk_group=4,
+    first_k_dense=2,
+    layer_types=((_KDA,) * 5 + (_ATTN,)) * 7,
+    kda_head_dim=128,
+    kda_conv_kernel=4,
+    kda_safe_gate=True,
+    kda_lower_bound=-5.0,
+    expert_swiglu_limits=(0,) * 35 + (4,) * 7,
+    shared_swiglu_limits=(0,) * 34 + (5,) * 6 + (7,) * 2,
+)
+
+#: Tiny hybrid of linear and latent attention (two groups of 2 linear + 1
+#: latent; a leading dense layer, then 8 experts in 4 groups, top-2 over the
+#: 2 best groups, one shared; 4 heads with a 16 x 16 state, four taps) for
+#: tests / CPU dry-runs.
+TINY_LING_HYBRID = LlamaConfig(
+    vocab_size=256,
+    hidden_size=64,
+    intermediate_size=128,
+    n_layers=6,
+    n_heads=4,
+    n_kv_heads=4,
+    head_dim=16,
+    rope_theta=10_000.0,
+    rms_norm_eps=1e-6,
+    n_experts=8,
+    n_experts_per_tok=2,
+    moe_intermediate_size=48,
+    norm_topk_prob=True,
+    kv_lora_rank=32,
+    qk_nope_head_dim=16,
+    qk_rope_head_dim=8,
+    v_head_dim=16,
+    rope_interleave=True,
+    n_shared_experts=1,
+    moe_scoring="sigmoid",
+    routed_scaling_factor=2.5,
+    n_group=4,
+    topk_group=2,
+    first_k_dense=1,
+    layer_types=(_KDA, _KDA, _ATTN) * 2,
+    kda_head_dim=16,
+    kda_conv_kernel=4,
+    kda_safe_gate=True,
+    kda_lower_bound=-5.0,
+    dtype=jnp.float32,
+)
+
 #: Tiny MoE config (Mixtral-shaped) for tests / CPU dry-runs.
 TINY_MOE = LlamaConfig(
     vocab_size=256,
@@ -1145,11 +1304,17 @@ def init_params(
             # at the whole spread a drawn bias moved the load of a rank's 16
             # experts by a half from seed to seed, PERF.md section 6, PR 41),
             # and a fifth of a sigmoid router's too where a rank holds a share
-            # of the experts, for that reason.
+            # of the experts, for that reason; a twentieth where that share
+            # is a routing GROUP: a group's score is the sum of its two
+            # LARGEST biased scores, so a drawn bias moves a whole group's
+            # load (the held group's places by a fifth from seed to seed at
+            # 0.04, a twentieth at 0.01: PERF.md section 6, PR 47).
             if cfg.moe_scoring != "sigmoid":
                 spread = 0.25 / cfg.router_outputs
+            elif cfg.holds_every_expert:
+                spread = 0.1
             else:
-                spread = 0.1 if cfg.holds_every_expert else 0.04
+                spread = 0.01 if cfg.n_group > 1 else 0.04
             part["router_bias"] = spread * jax.random.normal(
                 extra[0], (cfg.router_outputs,), jnp.float32
             )
@@ -1195,6 +1360,33 @@ def init_params(
                     quantizable=False,
                 ),
                 "conv_out": dense(k[3], (d, d), d),
+                "mlp_norm": norm_init((d,)),
+            }
+        elif cfg.layer_kind(i) == "linear":
+            # ``[q | k | v] = x kda_qkv`` (one product), a ``kda_conv_kernel``
+            # -tap filter a channel over the three (row j weighs the input
+            # of ``kda_conv_kernel - 1 - j`` tokens back; full precision),
+            # the gate's projection with its bias and a head's ``A_log``,
+            # one ``beta`` and one output gate a head, the heads' norm.
+            # (keys folded in: every other tree stays what it was)
+            hk = n_q * cfg.kda_head_dim
+            kk = jax.random.split(jax.random.fold_in(keys[i], 5), 4)
+            layer = {
+                "attn_norm": norm_init((d,)),
+                "kda_qkv": dense(k[0], (d, 3 * hk), d),
+                "kda_conv_w": dense(
+                    k[1], (cfg.kda_conv_kernel, 3 * hk), cfg.kda_conv_kernel,
+                    quantizable=False,
+                ),
+                "kda_wf": dense(k[2], (d, hk), d),
+                "kda_dt_bias": 0.5 * jax.random.normal(kk[0], (hk,), jnp.float32),
+                "kda_A_log": jnp.log(
+                    jax.random.uniform(kk[1], (n_q,), jnp.float32, 0.5, 4.0)
+                ),
+                "kda_wb": dense(kk[2], (d, n_q), d, quantizable=False),
+                "kda_wg": dense(kk[3], (d, n_q), d, quantizable=False),
+                "kda_o_norm": norm_init((cfg.kda_head_dim,)),
+                "wo": dense(k[3], (hk, d), hk),
                 "mlp_norm": norm_init((d,)),
             }
         elif cfg.kv_lora_rank:
@@ -1320,6 +1512,33 @@ def init_state_pages(
     return jnp.zeros(
         (cfg.n_conv_layers, total_pages, cfg.state_row), cfg.dtype,
         device=sharding,
+    )
+
+
+def init_kda_state(
+    cfg: LlamaConfig, slots: int, sharding=None
+) -> Optional[tuple[jnp.ndarray, jnp.ndarray]]:
+    """The zeroed state pool of a model with linear-attention layers, a
+    pair every program takes and returns as ONE argument, ``state_pages``:
+    the heads' matrices ``[linear layers, slots, n_heads, K, V]`` float32 and
+    the carried convolution rows ``[linear layers, slots, kda_conv_row]`` in
+    the model's dtype (the ``kda_conv_kernel - 1`` newest rows of ``[q | k |
+    v]`` before the convolution, oldest first, side by side). None for a
+    model without such layers.
+
+    A slot is no page's: ``server/block_manager.py``'s ``StatePool`` hands
+    them out, one live slot a sequence and snapshots beside them in the same
+    arrays. A program reads a row's state from one slot and writes it to
+    another (or the same): slot 0 is reserved for the rows that hold no
+    sequence."""
+    if not cfg.n_kda_layers:
+        return None
+    hk = cfg.kda_head_dim
+    return (
+        jnp.zeros((cfg.n_kda_layers, slots, cfg.n_heads, hk, hk), jnp.float32,
+                  device=sharding),
+        jnp.zeros((cfg.n_kda_layers, slots, cfg.kda_conv_row), cfg.dtype,
+                  device=sharding),
     )
 
 
@@ -1548,17 +1767,7 @@ def _conv_prev_state(state_pages, cfg: LlamaConfig, prev_page, has_prev):
     """Every convolution layer's state before a chunk's first token: the
     slot of the page holding the token before it (``prev_page [b]``), zeros
     where there is none (position 0). ``[conv layers, b, K - 1, d]``."""
-    # read as flat slots, one index a (layer, lane), as the write is: with
-    # the layer axis in the gather's window (``state_pages[:, prev_page]``)
-    # the TPU compiler copies the whole pool into a layer-inward layout
-    # first (``python -m tools.aot_pool_copies --config lfm2-8b-a1b``)
-    n_layers, pages, row = state_pages.shape
-    slots = (
-        jnp.arange(n_layers, dtype=prev_page.dtype)[:, None] * pages
-        + jnp.clip(prev_page, 0, pages - 1)[None, :]
-    )
-    got = state_pages.reshape(n_layers * pages, row)[slots]
-    got = jnp.where(has_prev[None, :, None], got, 0)
+    got = _slot_rows(state_pages, prev_page, has_prev)
     return got.reshape(*got.shape[:2], cfg.conv_L_cache - 1, cfg.hidden_size)
 
 
@@ -1583,25 +1792,127 @@ def _conv_operator(layer: Params, cfg: LlamaConfig, x, state):
     return y @ _w(layer["conv_out"], x.dtype), z
 
 
-@_scope("cache_write")
 def _scatter_state_pages(state_pages, fresh, page, ok):
     """Write every convolution layer's new slots with one update (aliased
     into the donated pool, as ``_scatter_kv_pages_all_layers``): ``fresh
     [conv layers, b, n, row]`` to the flat slots ``layer * pages + page[b,
     n]``, dropped where not ``ok``."""
-    n_layers, pages, row = state_pages.shape
-    keep = ok & (page < pages)
-    slots = jnp.where(
-        keep.reshape(1, -1),
-        jnp.arange(n_layers, dtype=page.dtype)[:, None] * pages
-        + page.reshape(1, -1),
-        n_layers * pages,
+    n_layers, _, row = state_pages.shape
+    return _scatter_slots(
+        state_pages, fresh.reshape(n_layers, -1, row), page.reshape(-1),
+        ok.reshape(-1),
     )
-    flat = state_pages.reshape(n_layers * pages, row)
-    flat = flat.at[slots.reshape(-1)].set(
-        fresh.reshape(-1, row).astype(flat.dtype), mode="drop"
+
+
+# -- delta-rule linear attention (KDA) ----------------------------------------
+def _slot_rows(pool, slot, has=None):
+    """Every layer's slot ``slot [b]`` of a state pool ``[layers, slots,
+    ...]``, zeros where not ``has [b]`` (None: every row has one): ``[layers,
+    b, ...]``. Read as flat slots, one index a (layer, row), as the write is:
+    with the layer axis in the gather's window (``pool[:, slot]``) the TPU
+    compiler copies the whole pool into a layer-inward layout first
+    (``python -m tools.aot_pool_copies --config lfm2-8b-a1b``)."""
+    n_layers, slots = pool.shape[:2]
+    idx = (
+        jnp.arange(n_layers, dtype=slot.dtype)[:, None] * slots
+        + jnp.clip(slot, 0, slots - 1)[None, :]
     )
-    return flat.reshape(state_pages.shape)
+    got = pool.reshape(n_layers * slots, *pool.shape[2:])[idx]
+    if has is None:
+        return got
+    return jnp.where(has.reshape(1, -1, *(1,) * (got.ndim - 2)), got, 0)
+
+
+@_scope("cache_write")
+def _scatter_slots(pool, fresh, slot, ok):
+    """``fresh [layers, b, ...]`` into the slots ``slot [b]`` of every layer
+    of a state pool with one update (aliased into the donated pool), dropped
+    where not ``ok [b]``."""
+    n_layers, slots = pool.shape[:2]
+    idx = jnp.where(
+        (ok & (slot < slots))[None, :],
+        jnp.arange(n_layers, dtype=slot.dtype)[:, None] * slots + slot[None, :],
+        n_layers * slots,
+    )
+    flat = pool.reshape(n_layers * slots, *pool.shape[2:])
+    flat = flat.at[idx.reshape(-1)].set(
+        fresh.reshape(-1, *pool.shape[2:]).astype(flat.dtype), mode="drop"
+    )
+    return flat.reshape(pool.shape)
+
+
+def _l2norm(t: jnp.ndarray) -> jnp.ndarray:
+    """A head's vector over its length (``use_qk_norm`` of the family)."""
+    return t * jax.lax.rsqrt(jnp.sum(t * t, axis=-1, keepdims=True) + 1e-6)
+
+
+def _kda_inputs(layer: Params, cfg: LlamaConfig, x, rows):
+    """A linear layer's per-token operands for ``x [b, s, d]`` (the normed
+    input) that follows the carried rows ``rows [b, taps - 1, 3 H K]`` (the
+    newest inputs of the convolution before it, oldest first): ``(q, k, v, g
+    [b, s, H, K], beta [b, s, H]`` float32, ``z [b, taps - 1 + s, 3 H K]``:
+    the carried rows after chunk index ``i`` are its rows ``i + 1 .. i + taps
+    - 1``). ``[q | k | v] = SiLU(conv(x kda_qkv))``, q and k l2-normed a head
+    (q also over sqrt(K)); ``g`` the log decay, a channel of the key, from the
+    gate's projection by the published form (``kda_safe_gate``); ``beta =
+    sigmoid(x w_b)``. No position enters."""
+    b, s, _ = x.shape
+    H, K = cfg.n_heads, cfg.kda_head_dim
+    f32 = jnp.float32
+    z = jnp.concatenate(
+        [rows.astype(x.dtype), x @ _w(layer["kda_qkv"], x.dtype)], axis=1
+    )
+    taps = layer["kda_conv_w"].astype(f32)
+    conv = jax.nn.silu(sum(
+        taps[j] * z[:, j : j + s].astype(f32) for j in range(taps.shape[0])
+    ))
+    q, k, v = (t.reshape(b, s, H, K) for t in jnp.split(conv, 3, axis=-1))
+    q, k = _l2norm(q) * K**-0.5, _l2norm(k)
+    a = (x @ _w(layer["kda_wf"], x.dtype)).astype(f32) + layer["kda_dt_bias"]
+    a = a.reshape(b, s, H, K)
+    rate = jnp.exp(layer["kda_A_log"].astype(f32))[:, None]
+    if cfg.kda_safe_gate:
+        g = cfg.kda_lower_bound * jax.nn.sigmoid(rate * a)
+    else:
+        g = -rate * jax.nn.softplus(a)
+    beta = jax.nn.sigmoid((x @ layer["kda_wb"].astype(x.dtype)).astype(f32))
+    return q, k, v, g, beta, z
+
+
+def _kda_output(layer: Params, cfg: LlamaConfig, x, o):
+    """The heads' outputs ``o [b, s, H, V]`` float32 to the residual's
+    width: a head's RMSNorm, times ``sigmoid(x w_g)`` (one gate a head),
+    ``wo``."""
+    b, s = o.shape[:2]
+    o = rms_norm(o, layer["kda_o_norm"].astype(o.dtype), cfg.rms_norm_eps,
+                 cfg.norm_offset)
+    gate = jax.nn.sigmoid(
+        (x @ layer["kda_wg"].astype(x.dtype)).astype(jnp.float32)
+    )
+    o = (o * gate[..., None]).astype(x.dtype).reshape(b, s, -1)
+    return o @ _w(layer["wo"], x.dtype)
+
+
+def _group_limited(choice: jnp.ndarray, cfg: LlamaConfig) -> jnp.ndarray:
+    """Group-limited routing on the scores that choose (``[..., E]``): the
+    ``E`` outputs lie in ``n_group`` groups of neighbours, a group's score is
+    the sum of its two largest, and outside the ``topk_group`` best groups a
+    score is -inf, so that the top-k after it takes no expert there. One
+    group: the scores as they came."""
+    if cfg.n_group == 1:
+        return choice
+    if choice.shape[-1] % cfg.n_group or not 1 <= cfg.topk_group <= cfg.n_group:
+        raise ValueError(
+            f"n_group={cfg.n_group}, topk_group={cfg.topk_group} do not "
+            f"divide the router's {choice.shape[-1]} outputs"
+        )
+    grouped = choice.reshape(*choice.shape[:-1], cfg.n_group, -1)
+    best = jnp.sum(jax.lax.top_k(grouped, min(2, grouped.shape[-1]))[0], axis=-1)
+    _, kept = jax.lax.top_k(best, cfg.topk_group)
+    keep = jnp.any(
+        kept[..., :, None] == jnp.arange(cfg.n_group)[None, :], axis=-2
+    )  # [..., n_group]
+    return jnp.where(keep[..., None], grouped, -jnp.inf).reshape(choice.shape)
 
 
 @_scope("moe_router")
@@ -1617,14 +1928,14 @@ def _moe_gates(layer: Params, cfg: LlamaConfig, x: jnp.ndarray):
     """
     router_logits = (x @ layer["router"]).astype(jnp.float32)  # [..., E]
     if cfg.moe_scoring == "sigmoid":
-        # DeepSeek-V3's ``noaux_tc`` with one group: the k largest of score
-        # + correction bias are CHOSEN; the gates are the scores alone
-        # (never the bias), renormalised, times the scaling factor.
-        if cfg.n_group != 1 or cfg.topk_group != 1:
-            raise ValueError("group-limited routing (n_group > 1) is not run")
+        # DeepSeek-V3's ``noaux_tc``: the k largest of score + correction
+        # bias, among the experts of the best groups (``_group_limited``),
+        # are CHOSEN; the gates are the scores alone (never the bias),
+        # renormalised, times the scaling factor.
         scores = jax.nn.sigmoid(router_logits)
         _, topi = jax.lax.top_k(
-            scores + layer["router_bias"], cfg.n_experts_per_tok
+            _group_limited(scores + layer["router_bias"], cfg),
+            cfg.n_experts_per_tok,
         )
         topv = jnp.take_along_axis(scores, topi, axis=-1)
         if cfg.norm_topk_prob:
@@ -1639,7 +1950,13 @@ def _moe_gates(layer: Params, cfg: LlamaConfig, x: jnp.ndarray):
         # LongCat-Flash: chosen by probability + correction bias, weighed
         # by the probability alone
         _, topi = jax.lax.top_k(
-            weights + layer["router_bias"], cfg.n_experts_per_tok
+            _group_limited(weights + layer["router_bias"], cfg),
+            cfg.n_experts_per_tok,
+        )
+        topv = jnp.take_along_axis(weights, topi, axis=-1)
+    elif cfg.n_group != 1:
+        _, topi = jax.lax.top_k(
+            _group_limited(weights, cfg), cfg.n_experts_per_tok
         )
         topv = jnp.take_along_axis(weights, topi, axis=-1)
     else:
@@ -2235,6 +2552,7 @@ def _prefill_body(
     state_pages=None,  # ``init_state_pages``: a model with conv layers
     window_pages=None,  # ``init_window_pages``: a model with sliding layers
     window_rows=None,  # its rows' (page_ids [b, s], tables [b, w], starts [b])
+    state_slots=None,  # [b, 2]: a model with linear layers (``prefill``)
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, Any, Any, Any, Any]:
     """Traced prefill shared by ``prefill``, the fused speculative-decode
     scan (``spec_decode_steps``) and ``_denoise_body``: the chunk's forward
@@ -2246,10 +2564,12 @@ def _prefill_body(
         params, cfg, tokens, positions, valid, k_pages, v_pages, page_ids,
         block_tables, ctx_lens, mesh, attn_impl, interpret, k_scales,
         v_scales, experts_touched, state_pages, window_pages, window_rows,
+        state_slots,
     )
     return (h,) + _prefill_write(
         fresh, positions, valid, k_pages, v_pages, page_ids, slot_ids,
         ctx_lens, k_scales, v_scales, state_pages, window_pages, window_rows,
+        state_slots,
     )
 
 
@@ -2273,6 +2593,7 @@ def _prefill_forward(
     state_pages,
     window_pages=None,
     window_rows=None,
+    state_slots=None,
 ) -> tuple[jnp.ndarray, tuple]:
     """The layer loop of ``_prefill_body`` (whose operands these are): it
     reads the pools and writes none. Returns (hidden states [b, s, d], what
@@ -2293,7 +2614,13 @@ def _prefill_forward(
     Scales are None unless the pools are int8 (``KV_QUANT_HBM``), in which
     case the write quantizes and the paged-context gather dequantizes
     chunk-locally — the engine restricts the quantized path to the ``xla``
-    single-shard prefill.
+    single-shard prefill. A linear layer (one that has ``kda_qkv``) takes
+    its matrices and carried rows from the slot ``state_slots[:, 0]`` of the
+    state pool of slots (``state_pages`` is then ``init_kda_state``'s pair;
+    zeros where ``ctx_lens`` is 0), runs the chunked recurrence and leaves
+    the state after the row's last valid token for the one write to the slot
+    ``state_slots[:, 1]``: a chunk emits no state but its last, so the
+    caller cuts chunks where a snapshot is due.
 
     ``cfg.block_length`` > 1 makes the chunk block-causal (full inside a
     block of that many absolute positions). Callers start the chunk on a
@@ -2310,8 +2637,24 @@ def _prefill_forward(
     ))
     h = _embed(params, cfg, tokens)  # [b, s, d]
     has_conv = any("conv_in" in layer for layer in params["layers"])
-    if attn_impl == "pallas" or latent or has_conv:
+    has_kda = any("kda_qkv" in layer for layer in params["layers"])
+    if attn_impl == "pallas" or latent or has_conv or has_kda:
         n_valid = jnp.sum(valid.astype(jnp.int32), axis=1)
+    if has_kda:
+        if (state_pages is None or state_slots is None
+                or sp > 1 or k_scales is not None or cfg.block_length > 1):
+            raise ValueError(
+                "linear layers: the state pool of slots and the rows' slots "
+                "are needed; sp, int8 and block_length are not run"
+            )
+        from ..ops.kda import kda_chunked
+
+        with _scope("kda"):
+            kda_s0 = _slot_rows(state_pages[0], state_slots[:, 0], ctx_lens > 0)
+            kda_rows0 = _slot_rows(
+                state_pages[1], state_slots[:, 0], ctx_lens > 0
+            )
+        fresh_ks, fresh_krows = [], []
     if has_conv:
         if state_pages is None or sp > 1 or k_scales is not None:
             raise ValueError(
@@ -2361,6 +2704,7 @@ def _prefill_forward(
         li = len(fresh_wk if sliding else fresh_k)
         with _scope(
             "conv" if "conv_in" in layer
+            else "kda" if "kda_qkv" in layer
             else "attn_window" if sliding else "attn"
         ):
             x = rms_norm(h, layer["attn_norm"], cfg.rms_norm_eps, cfg.norm_offset)
@@ -2371,6 +2715,24 @@ def _prefill_forward(
                         *state_rows.shape[:2], -1
                     )
                 )
+            elif "kda_qkv" in layer:
+                lk = len(fresh_ks)
+                taps = cfg.kda_conv_kernel
+                q, k, v, g, beta, z = _kda_inputs(
+                    layer, cfg, x,
+                    kda_rows0[lk].reshape(x.shape[0], taps - 1, -1),
+                )
+                # a slot that holds no token leaves the state as it was
+                g = jnp.where(valid[..., None, None], g, 0.0)
+                beta = jnp.where(valid[..., None], beta, 0.0)
+                o, s_new = kda_chunked(q, k, v, g, beta, kda_s0[lk])
+                out = _kda_output(layer, cfg, x, o)
+                fresh_ks.append(s_new)
+                # the carried rows after the last valid token
+                fresh_krows.append(jnp.take_along_axis(
+                    z, (n_valid[:, None] + jnp.arange(taps - 1))[:, :, None],
+                    axis=1,
+                ).reshape(x.shape[0], -1))
             elif latent:
                 # One row a token, absorbed: the kernel over the pool in place
                 # or (``xla``) its oracle over gathered pages; the rows go to
@@ -2435,7 +2797,7 @@ def _prefill_forward(
                         v_scales=None if v_scales is None else v_scales[li],
                         block_length=cfg.block_length,
                     )
-            if "conv_in" not in layer:
+            if "conv_in" not in layer and "kda_qkv" not in layer:
                 b, s, _, _ = attn.shape
                 out = _attn_gate(layer, x, attn.reshape(b, s, -1)) @ _w(
                     layer["wo"], h.dtype
@@ -2453,6 +2815,9 @@ def _prefill_forward(
         jnp.stack(fresh_v) if fresh_k and not latent else None,
         jnp.stack(fresh_state) if fresh_state else None,
     )
+    if has_kda:
+        # (the matrices, the carried rows), every linear layer's
+        fresh = fresh[:2] + ((jnp.stack(fresh_ks), jnp.stack(fresh_krows)),)
     if fresh_wk:
         fresh += (jnp.stack(fresh_wk), jnp.stack(fresh_wv))
     return h, fresh
@@ -2472,6 +2837,7 @@ def _prefill_write(
     state_pages,
     window_pages=None,
     window_rows=None,
+    state_slots=None,
 ) -> tuple[jnp.ndarray, jnp.ndarray, Any, Any, Any, Any]:
     """What a chunk leaves in the (donated) pools, each in one scatter over
     all its layers: (k_pages, v_pages, k_scales, v_scales, state_pages,
@@ -2489,7 +2855,16 @@ def _prefill_write(
             )
             for pool, new in zip(window_pages, fresh_window)
         )
-    if fresh_state is not None:
+    if state_slots is not None:
+        # linear layers (the pool of slots: matrices and carried rows, and
+        # ``fresh_state`` the same pair): every layer's to the rows' write
+        # slots, where the row holds a token
+        held = jnp.any(valid, axis=1)
+        state_pages = tuple(
+            _scatter_slots(pool, new, state_slots[:, 1], held)
+            for pool, new in zip(state_pages, fresh_state)
+        )
+    elif fresh_state is not None:
         _, state_page, state_ok = _conv_state_plan(
             ctx_lens, jnp.sum(valid.astype(jnp.int32), axis=1), page_ids,
             k_pages.shape[2],
@@ -2552,6 +2927,7 @@ def prefill(
     state_pages=None,  # ``init_state_pages``: a model with conv layers
     window_pages=None,  # ``init_window_pages``: a model with sliding layers
     window_rows=None,  # its rows' (page_ids [b, s], tables [b, w], starts [b])
+    state_slots=None,  # [b, 2] int32: a model with linear layers (below)
 ) -> tuple[jnp.ndarray, ...]:
     """Process a prompt chunk: returns (logits at last valid position per
     sequence [b, vocab], updated k_pages, v_pages), then the updated scale
@@ -2562,7 +2938,11 @@ def prefill(
     other). ``window_rows``: where each token's keys and values go in the
     window pools (the slots are ``slot_ids``), the window table of each row
     (the window pages that hold its context from position ``starts[i]`` up
-    to ``ctx_lens[i]``) and that position.
+    to ``ctx_lens[i]``) and that position. ``state_slots``: for a model with
+    linear layers (``state_pages`` is ``init_kda_state``'s pair) the slot
+    each row's state is read from and the slot its state after the chunk is
+    written to, ``[read, write]`` a row: the same slot for a sequence that
+    goes on, two where it starts from a snapshot, which is left as it was.
 
     The chunk attends causally within itself AND to ``ctx_lens`` tokens of
     prefix-cached context already resident in the page pool — this is how a
@@ -2587,6 +2967,7 @@ def prefill(
         page_ids, slot_ids, block_tables, ctx_lens, mesh, attn_impl,
         interpret, k_scales, v_scales, state_pages=state_pages,
         window_pages=window_pages, window_rows=window_rows,
+        state_slots=state_slots,
     )
     if return_all_logits:
         # Every chunk position's next-token logits [b, s, vocab] — the
@@ -2682,7 +3063,8 @@ def _prefill_rows(
     params: Params,
     cfg: LlamaConfig,
     rows: tuple,  # (tokens, positions, valid, page_ids, block_tables,
-    # ctx_lens, window_rows: None for a model without sliding layers)
+    # ctx_lens, window_rows: None for a model without sliding layers,
+    # state_slots: None for a model without linear layers)
     k_pages,
     v_pages,
     k_scales,
@@ -2700,11 +3082,12 @@ def _prefill_rows(
     It reads the pools and writes none. A jit of its own so that
     ``prefill_packed`` traces it ONCE for the shapes of its results and for
     its loop's body."""
-    tokens, positions, valid, page_ids, block_tables, ctx_lens, window_rows = rows
+    (tokens, positions, valid, page_ids, block_tables, ctx_lens, window_rows,
+     state_slots) = rows
     h, fresh = _prefill_forward(
         params, cfg, tokens, positions, valid, k_pages, v_pages, page_ids,
         block_tables, ctx_lens, mesh, attn_impl, interpret, k_scales,
-        v_scales, None, state_pages, window_pages, window_rows,
+        v_scales, None, state_pages, window_pages, window_rows, state_slots,
     )
     return _last_logits(params, cfg, h, valid)[None], fresh
 
@@ -2733,6 +3116,7 @@ def prefill_packed(
     state_pages=None,
     window_pages=None,
     window_packed=None,  # [b, chunk + window table + 1]: ``pack_window_rows``
+    state_slots=None,  # [b, 2] int32: ``prefill``'s, a model with linear layers
 ) -> tuple[jnp.ndarray, ...]:
     """``prefill`` as the engine dispatches it: the same forward, logits
     and write behind one packed operand that is sliced apart here. ``chunk``
@@ -2769,7 +3153,7 @@ def prefill_packed(
         )
     rows = (
         tokens, positions, valid, page_ids, packed[:, 5 * chunk : -1],
-        ctx_lens, window_rows,
+        ctx_lens, window_rows, state_slots,
     )
     forward = functools.partial(
         _prefill_rows, params, cfg, k_pages=k_pages, v_pages=v_pages,
@@ -2804,6 +3188,7 @@ def prefill_packed(
     pools = _prefill_write(
         fresh, positions, valid, k_pages, v_pages, page_ids, slot_ids,
         ctx_lens, k_scales, v_scales, state_pages, window_pages, window_rows,
+        state_slots,
     )
     return (logits[0],) + _prefill_results(
         pools, k_scales, state_pages, window_pages
@@ -2829,6 +3214,7 @@ def _decode_body(
     window_pages=None,  # ``init_window_pages``: a model with sliding layers
     window_tables=None,  # [b, window pages] int32: its lanes' window tables
     window_start=None,  # [b] int32: the position of each table's first slot
+    state_slots=None,  # [b, 3] int32: a model with linear layers (below)
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, Any, Any, Any, Any]:
     """Single decode step (traced body shared by ``decode_step`` and the
     fused ``decode_steps`` scan). Writes this token's K/V into its page
@@ -2844,7 +3230,13 @@ def _decode_body(
     before this one (through the block table: the page before, at a page's
     first slot) and writes the new state to this token's page's slot, so a
     lane that crosses a page boundary inside a burst leaves the finished
-    page's snapshot behind on the device."""
+    page's snapshot behind on the device. A model with linear layers
+    (``state_pages`` is ``init_kda_state``'s pair) brings ``state_slots``,
+    ``[slot a, slot b, switch]`` a lane: the token at a position before
+    ``switch`` reads and writes slot ``a``, the one AT ``switch`` reads ``a``
+    and writes ``b``, later ones read and write ``b`` — so a lane whose
+    burst passes a snapshot boundary leaves the state at the boundary behind
+    in ``a`` and goes on in ``b``, with no copy (``a == b``: one slot)."""
     latent = cfg.kv_lora_rank > 0
     if latent and (mesh is not None or k_scales is not None):
         raise ValueError("a latent pool: tp, sp and int8 are not run")
@@ -2875,6 +3267,24 @@ def _decode_body(
             )[:, 0],
             positions > 0,
         )
+    has_kda = any("kda_qkv" in layer for layer in params["layers"])
+    if has_kda:
+        if (state_pages is None or state_slots is None
+                or mesh is not None or k_scales is not None):
+            raise ValueError(
+                "linear layers: the state pool of slots and the lanes' slots "
+                "are needed; tp, sp and int8 are not run"
+            )
+        from ..ops.kda import kda_decode
+
+        kda_pool, kda_rows_pool = state_pages
+        slot_a, slot_b, switch = (state_slots[:, i] for i in range(3))
+        kda_read = jnp.where(positions <= switch, slot_a, slot_b)
+        kda_write = jnp.where(positions < switch, slot_a, slot_b)
+        kda_fresh = jnp.zeros_like(positions)  # position 0 is a prefill's
+        with _scope("kda"):
+            kda_rows0 = _slot_rows(kda_rows_pool, kda_read)
+        fresh_krows = []
     head_scale = cfg.hd**-0.5 if cfg.kv_heads_per_row > 1 else None
     if any("window" in layer for layer in params["layers"]):
         if (window_pages is None or window_tables is None
@@ -2901,12 +3311,28 @@ def _decode_body(
         li = len(fresh_wk if sliding else fresh_k)
         with _scope(
             "conv" if "conv_in" in layer
+            else "kda" if "kda_qkv" in layer
             else "attn_window" if sliding else "attn"
         ):
             x = rms_norm(h, layer["attn_norm"], cfg.rms_norm_eps, cfg.norm_offset)
             if "conv_in" in layer:
                 out, z = _conv_operator(layer, cfg, x, states[len(fresh_state)])
                 fresh_state.append(z[:, 1:].reshape(b, 1, -1))
+            elif "kda_qkv" in layer:
+                # one step of every lane's state where it lies in the pool
+                # (kernel ``kda_decode``); the carried rows go to the pool
+                # after the loop, in one update
+                lk = len(fresh_krows)
+                q, k, v, g, beta, z = _kda_inputs(
+                    layer, cfg, x,
+                    kda_rows0[lk].reshape(b, cfg.kda_conv_kernel - 1, -1),
+                )
+                o, kda_pool = kda_decode(
+                    kda_pool, q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0],
+                    kda_read, kda_write, kda_fresh, lk, interpret=interpret,
+                )
+                out = _kda_output(layer, cfg, x, o[:, None])
+                fresh_krows.append(z[:, 1:].reshape(b, -1))
             elif latent:
                 # Absorbed decode (kernel ``mla_decode``): every head reads the
                 # lane's latent rows where they lie, once, as key and as value;
@@ -2956,7 +3382,7 @@ def _decode_body(
                     scale=head_scale,
                     **seen,
                 ))  # [b, n_heads, hd]
-            if "conv_in" not in layer:
+            if "conv_in" not in layer and "kda_qkv" not in layer:
                 out = (
                     _attn_gate(layer, x[:, 0], attn.reshape(b, -1))
                     @ _w(layer["wo"], h.dtype)
@@ -2981,6 +3407,10 @@ def _decode_body(
         state_pages = _scatter_state_pages(
             state_pages, jnp.stack(fresh_state), my_page[:, None], valid
         )
+    if has_kda:
+        state_pages = (kda_pool, _scatter_slots(
+            kda_rows_pool, jnp.stack(fresh_krows), kda_write, valid[:, 0]
+        ))
     if not fresh_k:  # a tree of convolution layers alone: no key or value
         return (
             _logits(params, cfg, h)[:, 0], k_pages, v_pages, k_scales,
@@ -3094,6 +3524,7 @@ def decode_step(
     window_pages=None,  # ``init_window_pages``: a model with sliding layers
     window_tables=None,  # [b, window pages] int32
     window_start=None,  # [b] int32: the position of each table's first slot
+    state_slots=None,  # [b, 3] int32: ``_decode_body``'s, linear layers
 ) -> tuple[jnp.ndarray, ...]:
     """One decode step; sampling stays with the caller (host or jit).
     Returns the legacy 3-tuple, with updated scale pools appended when
@@ -3109,6 +3540,7 @@ def decode_step(
         block_tables, seq_lens, page_size, interpret, mesh,
         k_scales, v_scales, state_pages, window_pages=window_pages,
         window_tables=window_tables, window_start=window_start,
+        state_slots=state_slots,
     )
     extra = () if k_scales is None else (k_scales, v_scales)
     if stateful:
@@ -3146,6 +3578,7 @@ def decode_steps(
     state_pages=None,  # ``init_state_pages``: a model with conv layers
     window_pages=None,  # ``init_window_pages``: a model with sliding layers
     window_packed=None,  # [b, window pages + 1] int32: ``pack_window_rows``
+    state_slots=None,  # [b, 3] int32: ``_decode_body``'s, linear layers
 ) -> tuple[jnp.ndarray, ...]:
     """``num_steps`` fused decode iterations with on-device sampling.
 
@@ -3184,11 +3617,14 @@ def decode_steps(
     """
     quantized = k_scales is not None
     stateful, windowed = state_pages is not None, window_pages is not None
+    # the small operand a model's second pool needs (a model has one such pool)
     window = {}
     if windowed:
         window = dict(
             window_tables=window_packed[:, :-1], window_start=window_packed[:, -1]
         )
+    elif state_slots is not None:
+        window = dict(state_slots=state_slots)
     if tokens.ndim == 2:
         tokens = tokens[:, -1]
     block_tables, positions, seq_lens, temperature, top_k, top_p = (

@@ -29,13 +29,15 @@ from ..models.llama import LlamaConfig, Params
 
 def _layer_specs(
     cfg: LlamaConfig, tp: int = 1, routed: bool = True, conv: bool = False,
-    sliding: bool = False,
+    sliding: bool = False, linear: bool = False,
 ) -> dict[str, P]:
     """``routed``: the layer's FFN is the routed one (False for the leading
     dense layers of a model with ``first_k_dense``). ``conv``: its operator
     is a gated short convolution (replicated: the engine refuses tp > 1 for
     a model with such layers). ``sliding``: it attends over a window (the
-    leaf ``window``; the engine refuses tp > 1 for such a model too)."""
+    leaf ``window``; the engine refuses tp > 1 for such a model too).
+    ``linear``: its mixer is a delta-rule linear attention (replicated: tp >
+    1 is refused for such a model)."""
     specs = {
         "attn_norm": P(),
         "wq": P(None, "tp"),
@@ -96,6 +98,15 @@ def _layer_specs(
         ):
             specs.pop(name, None)
         specs.update(conv_in=P(), conv_w=P(), conv_out=P())
+    if linear:
+        for name in (
+            "wq", "wq_a", "q_a_norm", "wq_b", "wkv_a", "kv_norm", "wkv_b", "wo",
+        ):
+            specs.pop(name, None)
+        specs.update({name: P() for name in (
+            "kda_qkv", "kda_conv_w", "kda_wf", "kda_dt_bias", "kda_A_log",
+            "kda_wb", "kda_wg", "kda_o_norm", "wo",
+        )})
     return specs
 
 
@@ -122,6 +133,7 @@ def param_specs(cfg: LlamaConfig, tp: int = 1) -> dict[str, Any]:
                 cfg, tp, routed=i >= cfg.first_k_dense,
                 conv=cfg.layer_kind(i) == "conv",
                 sliding=cfg.layer_kind(i) == "sliding",
+                linear=cfg.layer_kind(i) == "linear",
             )
             for i in range(cfg.n_layers)
         ],

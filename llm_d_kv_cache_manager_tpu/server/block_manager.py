@@ -28,6 +28,18 @@ full layers' keys and values of a block are held. A window page lives while
 some sequence stands less than a window past it, or while it is part of the
 last window of something a sequence left behind; it publishes nothing.
 
+A model with linear-attention layers keeps, a sequence, a STATE that is no
+page's: a matrix a head a layer (``StatePool``, ``config.state_slots``). A
+sequence holds one live slot; where its length passes a multiple of
+``config.state_snapshot_tokens`` the slot it held becomes a SNAPSHOT, keyed by
+the hash of the block that ends there, and the sequence goes on in a new one.
+A prefix hit in the context pool is cut back to the last boundary whose
+snapshot is still held, and the sequence starts by reading that snapshot.
+Events keep speaking of pages: an event asserts that a block's context rows
+are held, not that a request for its prefix is served from that length (a
+scorer overstates a pod's warmth by up to a stride, by more once a snapshot
+is gone: ``state_cutback_tokens`` / ``state_cutback_lost`` count it).
+
 The allocator is *width-agnostic*: it tracks page identity, hashes, and
 tier membership (HBM / host DRAM / remote) but never touches page bytes,
 so the same lifecycle drives full-width bf16 pools and the int8 pools of
@@ -69,6 +81,14 @@ class BlockManagerConfig:
     #: engine sets both from the model; 0 = no such layers, no second pool.
     window_pages: int = 0
     sliding_window: int = 0
+    #: the state pool of a model with linear-attention layers: its slots
+    #: (slot 0 reserved; live slots and snapshots from one free list) and
+    #: the tokens between two snapshots (a multiple of the page size). The
+    #: engine sets the first from the model, its lanes and
+    #: ``state_snapshot_slots``; 0 = no such layers, no state pool.
+    state_slots: int = 0
+    state_snapshot_tokens: int = 512
+    state_snapshot_slots: int = 0
 
 
 class WindowPool:
@@ -252,6 +272,135 @@ class WindowPool:
             self._free.append(page)
 
 
+class StatePool:
+    """The linear layers' state slots: live slots (one a sequence, read and
+    written by every step) and snapshots (the state after a whole number of
+    ``stride`` tokens, keyed by the chain hash of the block that ends there)
+    in the same arrays, from one free list. Slot 0 is reserved for the rows
+    of a dispatch that hold no sequence.
+
+    Nothing is ever copied slot to slot: a program reads a row's state from
+    one slot and writes it to another (``ops/kda.py``). A snapshot is TAKEN by
+    letting the sequence go on in a new slot (the old one, left behind, is
+    the snapshot) and RESTORED by reading it into a new sequence's live slot.
+    While a sequence has still to read a snapshot it is pinned. Snapshots
+    nobody is about to read are reused, when the free list is dry, in this
+    order: those that never SERVED A HIT (``_idle``: what a sequence left
+    behind at a boundary inside its own turn, which only a request that
+    repeats that turn could use), oldest first; then those that did
+    (``_kept``), least recently hit first. That keeps a resident thread's
+    last snapshot through the gaps between the requests that continue it,
+    however many boundaries the turns in between pass. A snapshot goes with
+    its block when the context page is evicted (``evict_hash``); a page may
+    outlive its snapshot."""
+
+    def __init__(self, n_slots: int, stride: int, page_size: int):
+        if n_slots < 2 or stride < page_size or stride % page_size:
+            raise ValueError(
+                f"a state pool needs slots (slot 0 is reserved) and a stride "
+                f"of whole pages: {n_slots} slots, {stride} tokens in pages "
+                f"of {page_size}"
+            )
+        self.n_slots, self.stride = n_slots, stride
+        self._free: list[int] = list(range(n_slots - 1, 0, -1))
+        self._snap: dict[int, int] = {}  # chain hash -> snapshot slot
+        self._hash: dict[int, int] = {}  # snapshot slot -> chain hash
+        self._pins: dict[int, int] = {}  # snapshot slot -> readers to come
+        self._served: set[int] = set()  # snapshot slots that served a hit
+        self._idle: OrderedDict[int, None] = OrderedDict()  # unpinned, never hit
+        self._kept: OrderedDict[int, None] = OrderedDict()  # unpinned, hit: LRU first
+        #: monotone: admissions, snapshots taken / read by a new sequence /
+        #: reused or gone with their page, tokens of a hit prefilled again
+        #: because it was cut back to a snapshot, and admissions whose
+        #: nearest boundary had lost its snapshot
+        self.stats = {
+            "state_admissions": 0,
+            "state_snapshots_taken": 0,
+            "state_restores": 0,
+            "state_snapshots_evicted": 0,
+            "state_cutback_tokens": 0,
+            "state_cutback_lost": 0,
+        }
+
+    @property
+    def num_snapshots(self) -> int:
+        return len(self._snap)
+
+    @property
+    def num_available(self) -> int:
+        """Slots an allocation may take: free ones and unpinned snapshots."""
+        return len(self._free) + len(self._idle) + len(self._kept)
+
+    def _forget(self, slot: int) -> None:
+        del self._snap[self._hash.pop(slot)]
+        self._served.discard(slot)
+        self.stats["state_snapshots_evicted"] += 1
+
+    def pop(self) -> int:
+        """A slot for a sequence to write."""
+        if self._free:
+            return self._free.pop()
+        for order in (self._idle, self._kept):
+            if order:
+                slot, _ = order.popitem(last=False)
+                self._forget(slot)
+                del self._pins[slot]
+                return slot
+        raise AllocationError("state slot pool exhausted")
+
+    def free(self, slot: int) -> None:
+        """A slot no snapshot is registered in goes back."""
+        self._free.append(slot)
+
+    def lookup(self, h: int) -> Optional[int]:
+        return self._snap.get(h)
+
+    def register(self, slot: int, h: int) -> bool:
+        """``slot`` holds the state at the end of block ``h``: a snapshot
+        from now on (unpinned). False where that block has one already."""
+        if h in self._snap:
+            return False
+        self._snap[h], self._hash[slot], self._pins[slot] = slot, h, 0
+        self._idle[slot] = None
+        self.stats["state_snapshots_taken"] += 1
+        return True
+
+    def pin(self, slot: int, hit: bool = False) -> None:
+        """One more sequence that has still to read snapshot ``slot``;
+        ``hit``: a new sequence starts from it (else the sequence that left
+        it behind goes on from it)."""
+        self._idle.pop(slot, None)
+        self._kept.pop(slot, None)
+        self._pins[slot] += 1
+        if hit:
+            self._served.add(slot)
+
+    def unpin(self, slot: int) -> None:
+        """A reader has read (its dispatch is enqueued)."""
+        self._pins[slot] -= 1
+        if self._pins[slot]:
+            return
+        if slot not in self._hash:  # its block went while it was pinned
+            del self._pins[slot]
+            self._free.append(slot)
+        elif slot in self._served:
+            self._kept[slot] = None
+        else:
+            self._idle[slot] = None
+
+    def evict_hash(self, h: int) -> None:
+        """The context page of block ``h`` is evicted: its snapshot, if it
+        has one, goes with it (free at once, or when its readers have read)."""
+        slot = self._snap.get(h)
+        if slot is None:
+            return
+        self._forget(slot)
+        for order in (self._idle, self._kept):
+            if slot in order:
+                del order[slot], self._pins[slot]
+                self._free.append(slot)
+
+
 @dataclass
 class _PageInfo:
     ref_count: int = 0
@@ -297,6 +446,20 @@ class BlockManager:
                 )
             self.window = WindowPool(
                 config.window_pages, config.sliding_window, config.page_size
+            )
+        #: the linear layers' state slots (None: the model has no such layer
+        #: and nothing below reads it)
+        self.state: Optional[StatePool] = None
+        if config.state_slots:
+            if config.host_pages or config.window_pages:
+                raise ValueError(
+                    "a state pool of slots is incompatible with host_pages > "
+                    "0 and with a window pool (one second pool a model; the "
+                    "host tier moves the context pool's pages alone)"
+                )
+            self.state = StatePool(
+                config.state_slots, config.state_snapshot_tokens,
+                config.page_size,
             )
         # -- host-DRAM tier (SURVEY §2.3 device-tier mapping) --------------
         # The engine attaches the actual KV movers via attach_host_pool();
@@ -655,6 +818,8 @@ class BlockManager:
             self._emit(BlockRemoved(block_hashes=[info.chain_hash], medium="tpu_hbm"))
             if self.window is not None:
                 self.window.evict_hash(info.chain_hash)
+            if self.state is not None:
+                self.state.evict_hash(info.chain_hash)
             self._pages[page] = _PageInfo(ref_count=1, tenant=self._alloc_tenant)
             return page
         raise AllocationError("KV page pool exhausted")
@@ -993,15 +1158,48 @@ class BlockManager:
             seq.window_first, seq.window_table = self.window.take_run(
                 hashes, n_hit
             )
+        if self.state is not None:
+            # A hit needs the state at its end: cut back to the last
+            # boundary whose snapshot is still held (none: position 0, zero
+            # state). The sequence reads it with its first chunk.
+            st = self.state
+            n_hit = cached_tokens // st.stride
+            snapshot, lost = None, False
+            while n_hit:
+                snapshot = st.lookup(hashes[n_hit * st.stride // ps - 1])
+                if snapshot is not None:
+                    break
+                n_hit, lost = n_hit - 1, True
+            kept = n_hit * st.stride
+            st.stats["state_admissions"] += 1
+            st.stats["state_cutback_lost"] += lost
+            st.stats["state_cutback_tokens"] += cached_tokens - kept
+            for page in block_table[kept // ps:]:
+                self._decref(page)
+            del block_table[kept // ps:]
+            cached_tokens = kept
+            if snapshot is not None:
+                # (before the pop, which may reuse snapshots)
+                st.pin(snapshot, hit=True)
+                st.stats["state_restores"] += 1
 
         n_pages_needed = -(-len(tokens) // ps)
         try:
+            if self.state is not None:
+                try:
+                    seq.state_slot = self.state.pop()
+                except AllocationError:
+                    if snapshot is not None:
+                        self.state.unpin(snapshot)
+                    raise
+                seq.state_from = snapshot or seq.state_slot
             while len(block_table) < n_pages_needed:
                 block_table.append(self._pop_free_page())
         except AllocationError:
             for page in block_table:
                 self._decref(page)
             self._free_window(seq)
+            self._free_state(seq)
             raise
 
         seq.block_table = block_table
@@ -1042,6 +1240,8 @@ class BlockManager:
             min(need, self.window.window // ps + 2) > self.window.num_free
         ):
             # the pages of a last window and its boundary, or of the prompt
+            return False
+        if self.state is not None and not self.state.num_available:
             return False
         return need <= self.num_free
 
@@ -1095,6 +1295,102 @@ class BlockManager:
                 self.window.release(page, given_back=i < kept_from)
         seq.window_table, seq.window_first = [], 0
 
+    # -- the state pool of slots (a model with linear-attention layers) -----
+    def _free_state(self, seq: Sequence) -> None:
+        """Give back ``seq``'s live slot, what it left behind and had not yet
+        registered, and its claim on a snapshot it had still to read."""
+        st = self.state
+        if st is None or not seq.state_slot:
+            return
+        if seq.state_from != seq.state_slot:
+            st.unpin(seq.state_from)
+        st.free(seq.state_slot)
+        for _, slot in seq.state_due:
+            st.free(slot)
+        seq.state_slot = seq.state_from = 0
+        seq.state_due, seq.state_hashes = [], {}
+
+    def prefill_cut(self, seq: Sequence, n: int) -> int:
+        """``n`` tokens of ``seq``'s prompt, cut where a snapshot is due: a
+        chunk's final state is the only one a prefill emits, so a chunk ends
+        at the next multiple of the stride. Every other model: ``n``."""
+        if self.state is None:
+            return n
+        stride = self.state.stride
+        return min(n, stride - seq.num_prefilled % stride)
+
+    def state_prefill_done(self, seq: Sequence) -> None:
+        """``seq``'s chunk is enqueued and its full pages registered: the
+        snapshot it read, if any, is released, and where the chunk ended on a
+        boundary the slot it wrote becomes that block's snapshot (if the
+        block has none) and the sequence goes on in a new slot, reading the
+        snapshot with its next dispatch."""
+        st = self.state
+        if st is None or not seq.state_slot:
+            return
+        self.state_release_reads([seq])
+        n = seq.num_computed
+        if not n or n % st.stride:
+            return
+        h = seq.state_hashes.pop(n, None)
+        if h is None or st.lookup(h) is not None:
+            return
+        try:
+            new = st.pop()
+        except AllocationError:
+            return  # no slot to go on in: no snapshot here
+        st.register(seq.state_slot, h)
+        st.pin(seq.state_slot)
+        seq.state_from, seq.state_slot = seq.state_slot, new
+
+    def state_decode_slots(self, seq: Sequence, pos: int, k: int) -> tuple:
+        """``(slot a, slot b, switch)`` of ``seq``'s lane for a burst of ``k``
+        steps whose first token stands at ``pos`` (``llama._decode_body``:
+        the token at ``switch`` reads ``a`` and writes ``b``). A snapshot the
+        sequence has still to read is ``a`` with ``switch = pos``; a burst
+        that passes a boundary takes a new slot for what follows it and
+        leaves the old one to be registered when the tokens are committed
+        (``state_commit``). The caller releases the reads after the build
+        (``state_release_reads``): a slot released earlier could be reused
+        as another lane's ``b`` in the same dispatch."""
+        st = self.state
+        slot = seq.state_slot
+        if seq.state_from != slot:
+            return seq.state_from, slot, pos
+        # the first position of the burst whose token finds a whole number
+        # of strides before it (``pos`` itself, where it stands on one)
+        boundary = -(-pos // st.stride) * st.stride
+        if boundary > pos + k - 1:
+            return slot, slot, 0
+        try:
+            new = st.pop()
+        except AllocationError:
+            return slot, slot, 0  # no slot to go on in: no snapshot here
+        seq.state_due.append((boundary, slot))
+        seq.state_slot = seq.state_from = new
+        return slot, new, boundary
+
+    def state_release_reads(self, seqs: Seq[Sequence]) -> None:
+        """The dispatch that reads these sequences' snapshots is built."""
+        for seq in seqs:
+            if seq.state_slot and seq.state_from != seq.state_slot:
+                self.state.unpin(seq.state_from)
+                seq.state_from = seq.state_slot
+
+    def state_commit(self, seq: Sequence) -> None:
+        """``seq``'s tokens up to ``num_computed`` are committed and its full
+        pages registered: the slots it left behind at the boundaries it has
+        passed become those blocks' snapshots (or go back, where a block has
+        one already)."""
+        st = self.state
+        if st is None:
+            return
+        while seq.state_due and seq.state_due[0][0] <= seq.num_computed:
+            boundary, slot = seq.state_due.pop(0)
+            h = seq.state_hashes.pop(boundary, None)
+            if h is None or not st.register(slot, h):
+                st.free(slot)
+
     def append_slot(self, seq: Sequence) -> None:
         """Ensure capacity for one more token during decode; allocates a new
         page when the sequence crosses a page boundary."""
@@ -1138,6 +1434,8 @@ class BlockManager:
         for i in range(seq.num_registered_pages, n_full):
             block = tuple(int(t) for t in tokens[i * ps : (i + 1) * ps])
             h = hash_block(parent, block)
+            if self.state is not None and (i + 1) * ps % self.state.stride == 0:
+                seq.state_hashes[(i + 1) * ps] = h  # a snapshot's key
             if self.window is not None:
                 held = i - seq.window_first
                 if 0 <= held < len(seq.window_table) and seq.window_table[held]:
@@ -1175,3 +1473,4 @@ class BlockManager:
             self._decref(page)
         seq.block_table = []
         self._free_window(seq)
+        self._free_state(seq)

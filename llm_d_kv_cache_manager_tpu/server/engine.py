@@ -442,6 +442,73 @@ class Engine:
             config.block_manager = dataclasses.replace(
                 config.block_manager, window_pages=0
             )
+        if cfg.n_kda_layers:
+            # Linear-attention layers keep a matrix a head in a state pool of
+            # slots beside the latent pool (``llama.init_kda_state``,
+            # ``block_manager.StatePool``): what does not carry a slot is
+            # refused here by name. Events, the index and the scorer keep
+            # speaking of pages (``block_manager``'s docstring).
+            stride = config.block_manager.state_snapshot_tokens
+            rows = config.decode_batch_size + config.scheduler.max_prefill_batch
+            clamps = (cfg.expert_swiglu_limits or ())[: cfg.n_layers] + (
+                cfg.shared_swiglu_limits or ())[: cfg.n_layers]
+            refused = {
+                "host_pages > 0 (the host tier moves the context pool's "
+                "pages; a state slot has no tier)":
+                    config.block_manager.host_pages > 0,
+                "remote_tier (demotion payloads are pages, not slots)":
+                    config.remote_tier,
+                "kv_quant_hbm (neither the state nor the latent pool has "
+                "an int8 form)": config.kv_quant_hbm is not None,
+                "tp > 1 (the state and the latent row are not sharded)":
+                    config.tp > 1,
+                "sp > 1 (the ring carries no state across shards)":
+                    config.sp > 1,
+                "spec_decode (a rejected draft would have advanced a state "
+                "slot)": config.spec_decode != "off",
+                "block_length > 0 (a block is not forwarded token by token)":
+                    cfg.block_length > 0,
+                "chunked_prefill_tokens (a prompt is already prefilled in "
+                "chunks, cut where a snapshot is due: one scheduling path)":
+                    config.scheduler.chunked_prefill_tokens is not None,
+                "sliding or conv layers (a window pool or a page's state "
+                "beside: one second pool a model)":
+                    cfg.n_window_layers > 0 or cfg.n_conv_layers > 0,
+                "kv_lora_rank == 0 (the layers between are latent attentions)":
+                    cfg.kv_lora_rank == 0,
+                "use_kda_lora (low-rank gate projections are not run)":
+                    cfg.kda_lora,
+                "a non-zero SwiGLU limit on a run layer (the published "
+                "config gives the limits and not where they clamp)":
+                    any(clamps),
+                f"state_snapshot_tokens={stride} (whole pages of {ps}, at "
+                f"least a burst of {config.decode_steps_per_iter})":
+                    stride < ps or stride % ps != 0
+                    or stride < config.decode_steps_per_iter,
+                "kda_head_dim / kda_conv_kernel (a state of a matrix a head "
+                "and a convolution of at least two taps)":
+                    cfg.kda_head_dim < 1 or cfg.kda_conv_kernel < 2,
+            }
+            for what, on in refused.items():
+                if on:
+                    raise ValueError(
+                        f"layer_types with {cfg.n_kda_layers} linear_attention "
+                        f"layers (a state pool of slots beside the latent "
+                        f"pool) is incompatible with {what}"
+                    )
+            # a live slot a row that can hold a sequence, a second one while
+            # it passes a boundary, then the snapshots; slot 0 is reserved
+            config.block_manager = dataclasses.replace(
+                config.block_manager,
+                state_slots=max(
+                    rows + config.block_manager.state_snapshot_slots,
+                    2 * rows + 1,
+                ),
+            )
+        elif config.block_manager.state_slots:
+            config.block_manager = dataclasses.replace(
+                config.block_manager, state_slots=0
+            )
         self.block_manager = BlockManager(config.block_manager, on_events=on_events)
 
         cpt = config.scheduler.chunked_prefill_tokens
@@ -580,8 +647,6 @@ class Engine:
                     config.spec_decode != "off",
                 "block_length > 0 (no block mask in the latent kernel)":
                     cfg.block_length > 0,
-                "n_group, topk_group > 1 (group-limited routing is not run)":
-                    cfg.n_group != 1 or cfg.topk_group != 1,
             }
             for what, on in refused.items():
                 if on:
@@ -732,12 +797,24 @@ class Engine:
         #: the convolution layers' state pool (None: the model has no such
         #: layer), addressed by the same page ids, and what it costs a
         #: token slot: ``/stats`` reports it beside ``kv_bytes_per_token``
-        self.state_pages: Optional[jnp.ndarray] = llama.init_state_pages(
+        self.state_pages = llama.init_state_pages(
             cfg, config.block_manager.total_pages, sharding=self._replicated
         )
         self.state_bytes_per_token = (
             0 if self.state_pages is None else self.state_pages.nbytes
         ) // (config.block_manager.total_pages * ps)
+        if cfg.n_kda_layers:
+            # the linear layers' state pool of slots, a (matrices, carried
+            # rows) pair in the same keyword: what prefix caching costs a
+            # token here is a snapshot's bytes over the stride
+            self.state_pages = llama.init_kda_state(
+                cfg, config.block_manager.state_slots,
+                sharding=self._replicated,
+            )
+            self.state_bytes_per_token = (
+                cfg.kda_state_bytes
+                // config.block_manager.state_snapshot_tokens
+            )
         #: the sliding layers' window pools, a (K, V) pair with page ids of
         #: their own (None: the model has no such layer), what a token slot
         #: costs there (``/stats`` reports it beside ``kv_bytes_per_token``,
@@ -1676,8 +1753,10 @@ class Engine:
             # beside the convolution layers' state, or the full layers' K
             # and V alone (a window page never leaves the engine): a block
             # is one page of every pool whose pages live as long as a prefix
+            # (a state slot of linear layers is no page's and never moves)
             return self.page_size * (
-                self.kv_bytes_per_token + self.state_bytes_per_token
+                self.kv_bytes_per_token
+                + (0 if cfg.n_kda_layers else self.state_bytes_per_token)
             )
         elems = cfg.n_layers * self.page_size * cfg.n_kv_heads * cfg.hd
         if (
@@ -1694,6 +1773,13 @@ class Engine:
         convolution layers, whose pages have a state slot beside them.
         ``PodServer`` refuses ``transfer_endpoint`` for such a model at
         construction; this holds any other caller."""
+        if self.model_cfg.n_kda_layers:
+            raise ValueError(
+                f"layer_types with {self.model_cfg.n_kda_layers} "
+                f"linear_attention layers (a state pool of slots beside the "
+                f"latent pool) is incompatible with {what} (export, import "
+                f"and migration move pages and no state slot)"
+            )
         if self.model_cfg.kv_lora_rank:
             raise ValueError(
                 f"kv_lora_rank={self.model_cfg.kv_lora_rank} (a latent KV "
@@ -2176,7 +2262,8 @@ class Engine:
         the sequence (migration committed) or clear ``importing``
         (fallback: local recompute, pages back to baseline). Engine
         thread only."""
-        if self.model_cfg.n_conv_layers or self.model_cfg.n_window_layers:
+        if (self.model_cfg.n_conv_layers or self.model_cfg.n_window_layers
+                or self.model_cfg.n_kda_layers):
             self._refuse_latent_page_moves("freeze_for_migration")
         seq = None
         for cand in (
@@ -2452,15 +2539,24 @@ class Engine:
                 tokens, positions, valid, page_ids, slot_ids, ctx_bt, ctx_lens
             )
 
-            uploads = [packed]
+            uploads, second = [packed], ()
             if windowed:
+                second = ("window_packed",)
                 uploads.append(
                     llama.pack_window_rows(w_page_ids, w_tables, w_starts)
                 )
+            elif self.block_manager.state is not None:
+                # the slot each row's state is read from and written to
+                # (rows that hold no sequence: the reserved slot 0)
+                second = ("state_slots",)
+                slots = np.zeros((b, 2), np.int32)
+                for i, seq in enumerate(seqs):
+                    slots[i] = seq.state_from, seq.state_slot
+                uploads.append(slots)
 
         with self.phase("prefill_put"):
             packed_d, *window_d = self._stage("prefill", *uploads)
-            window = dict(zip(("window_packed",), window_d))
+            window = dict(zip(second, window_d))
             t0 = time.perf_counter()
         with self.phase("prefill_dispatch"):
             out = llama.prefill_packed(
@@ -2524,6 +2620,24 @@ class Engine:
                         seq.first_token_time = now
                     self._append_slot_or_preempt(seq)
                 self.block_manager.register_full_pages(seq)
+                if seq.block_table:
+                    self.block_manager.state_prefill_done(seq)
+
+    def state_pool_stats(self) -> dict:
+        """What ``/stats`` says of the linear layers' state pool of slots
+        (nothing for every other model): its size, the snapshots held, what a
+        snapshot weighs and how many tokens lie between two, and the block
+        manager's monotone counts (``StatePool.stats``)."""
+        st = self.block_manager.state
+        if st is None:
+            return {}
+        return {
+            "state_slots": st.n_slots,
+            "state_snapshots_held": st.num_snapshots,
+            "state_bytes_per_snapshot": self.model_cfg.kda_state_bytes,
+            "state_snapshot_tokens": st.stride,
+            **st.stats,
+        }
 
     def _state_arg(self) -> dict:
         """The pool beside the key/value pools as ``llama.prefill`` /
@@ -2741,16 +2855,29 @@ class Engine:
             packed = llama.pack_decode_inputs(
                 positions, block_tables, seq_lens, temperature, top_k, top_p
             )
-            uploads = [packed]
+            uploads, second = [packed], ()
             if self.window_pages is not None:
                 # the lanes' window tables, one fixed width (a window and
                 # what a boundary and two bursts add), and where each starts
+                second = ("window_packed",)
                 w_tables = np.zeros((lanes, self.window_table_pages), np.int32)
                 w_starts = np.zeros((lanes,), np.int32)
                 for i, seq in enumerate(active):
                     w_tables[i, : len(seq.window_table)] = seq.window_table
                     w_starts[i] = seq.window_first * self.page_size
                 uploads.append(llama.pack_window_rows(None, w_tables, w_starts))
+            elif self.block_manager.state is not None:
+                # the lanes' state slots ``[a, b, switch]`` (idle lanes: the
+                # reserved slot 0); a lane that passes a boundary inside the
+                # burst leaves its slot behind there and goes on in another
+                second = ("state_slots",)
+                slots = np.zeros((lanes, 3), np.int32)
+                for i, seq in enumerate(active):
+                    slots[i] = self.block_manager.state_decode_slots(
+                        seq, int(positions[i]), k
+                    )
+                self.block_manager.state_release_reads(active)
+                uploads.append(slots)
 
         with self.phase("decode_put"):
             key = self._draw_key(temperature)
@@ -2763,7 +2890,7 @@ class Engine:
                 packed_d, tokens_d, *window_d = self._stage(
                     "decode", packed, tokens, *uploads[1:]
                 )
-            window = dict(zip(("window_packed",), window_d))
+            window = dict(zip(second, window_d))
         with self.phase("decode_dispatch"):
             out = llama.decode_steps(
                 self.params,
@@ -3231,6 +3358,7 @@ class Engine:
                     seq.output_tokens.append(int(toks[i, j]))
                     seq.num_generated += 1
                 self.block_manager.register_full_pages(seq)
+                self.block_manager.state_commit(seq)
 
     def _reserve_slots_or_preempt(self, seq: Sequence, n: int) -> None:
         """Ensure ``seq`` can grow by ``n`` tokens (KV slots for positions

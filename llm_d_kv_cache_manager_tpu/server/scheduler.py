@@ -230,7 +230,13 @@ class Scheduler:
         # (admission continues past them — the wire must never stall
         # later arrivals); with no imports in flight the walk is the
         # legacy head-of-deque FCFS loop exactly.
-        prefill: list[Sequence] = []
+        # A model whose state is snapshotted every so many tokens prefills a
+        # prompt in chunks cut at those boundaries (``BlockManager.
+        # prefill_cut``): what a step left unfinished is resumed first, and
+        # the step is still a prefill step or a decode step. (Its one path:
+        # the engine refuses ``chunked_prefill_tokens`` for such a model.)
+        cut = self.block_manager.state is not None
+        prefill: list[Sequence] = list(self.prefilling) if cut else []
         budget = self.config.max_prefill_tokens
         idx = 0
         while (
@@ -260,6 +266,15 @@ class Scheduler:
             self._qos_charge(seq, suffix)
             prefill.append(seq)
 
+        if prefill and cut:
+            chunks = [
+                self.block_manager.prefill_cut(seq, seq.prompt_remaining)
+                for seq in prefill
+            ]
+            for seq, n in zip(prefill, chunks):
+                if n < seq.prompt_remaining and seq not in self.prefilling:
+                    self.prefilling.append(seq)
+            return ScheduleOutput(prefill=prefill, decode=[], chunks=chunks)
         if prefill:
             return ScheduleOutput(prefill=prefill, decode=[])
         return ScheduleOutput(prefill=[], decode=list(self.running))

@@ -92,6 +92,17 @@ class Sequence:
     #: moves on. Empty for every other model.
     window_table: list[int] = field(default_factory=list)
     window_first: int = 0
+    #: a model with linear-attention layers: the state pool's slot this
+    #: sequence's state is written to (its live slot; 0: none), the slot its
+    #: next dispatch reads it from (the same, or a snapshot it starts from or
+    #: has just left behind), the slots it left at boundaries whose tokens
+    #: are not committed yet ``[(boundary, slot)]`` and the chain hashes of
+    #: the blocks that end at boundaries it has registered ``{boundary:
+    #: hash}`` (``BlockManager``'s ``state_*``). Untouched by other models.
+    state_slot: int = 0
+    state_from: int = 0
+    state_due: list = field(default_factory=list)
+    state_hashes: dict = field(default_factory=dict)
     #: tokens whose K/V are resident in pages (cached prefix + processed)
     num_computed: int = 0
     #: tokens of the prompt served from the prefix cache

@@ -1249,6 +1249,16 @@ class PodServerConfig:
             # pages; unset it has TOTAL_PAGES, and a model without such
             # layers never reads it
             window_pages=int(os.environ.get("WINDOW_PAGES", 0)),
+            # the state pool of a model with linear-attention layers: tokens
+            # between two snapshots of a sequence's state, and the slots
+            # kept for snapshots beside the live ones; a model without such
+            # layers never reads them
+            state_snapshot_tokens=int(
+                os.environ.get("STATE_SNAPSHOT_TOKENS", 512)
+            ),
+            state_snapshot_slots=int(
+                os.environ.get("STATE_SNAPSHOT_SLOTS", 0)
+            ),
         )
         # Host-tier admission: "auto" (self-calibrating recompute-vs-
         # restore cost model) or "always" (unconditional spill/restore).
@@ -1351,6 +1361,13 @@ class PodServer:
                 f"{self.config.pod_role!r}"
             )
         model = engine.model_cfg if engine is not None else self.config.engine.model
+        if model.n_kda_layers and self.config.transfer_endpoint:
+            raise ValueError(
+                f"layer_types with {model.n_kda_layers} linear_attention "
+                f"layers (a state pool of slots beside the latent pool) is "
+                f"incompatible with transfer_endpoint (TRANSFER_ENDPOINT: "
+                f"export, import and migration move pages and no state slot)"
+            )
         if model.kv_lora_rank and self.config.transfer_endpoint:
             # refused here by name, beside the engine's own refusals for a
             # latent pool: the service would gather pages of a pool that
@@ -3799,6 +3816,7 @@ class PodServer:
                     bm.window.num_held if bm.window is not None else 0
                 ),
                 **(bm.window.stats if bm.window is not None else {}),
+                **self.engine.state_pool_stats(),
                 "routed_layers": self.engine.routed_layers,
                 "experts_held": self.engine.model_cfg.experts_held,
                 "zero_experts": self.engine.model_cfg.n_zero_experts,
@@ -4173,6 +4191,8 @@ def _resolve_model(name: str) -> LlamaConfig:
         "tiny-scmoe": models.TINY_SCMOE,
         "arcee-ai/Trinity-Large-Preview": models.TRINITY_LARGE_PREVIEW,
         "tiny-swa-moe": models.TINY_SWA_MOE,
+        "inclusionAI/Ling-3.0-flash": models.LING_3_FLASH,
+        "tiny-ling-hybrid": models.TINY_LING_HYBRID,
     }
     if name in presets:
         return presets[name]

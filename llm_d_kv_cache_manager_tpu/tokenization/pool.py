@@ -9,7 +9,15 @@ uncovered tail grow with the prompt (a fifth of a 28k-character document is
 350 blocks of 16 tokens the scorer would never see, and a document that grows
 piece by piece never crosses the threshold again), so a prompt whose uncovered
 tail reaches ``MAX_UNCOVERED_BYTES`` (1024: four store blocks) is tokenized
-fully whatever the ratio. ``tokenize`` blocks for the result;
+fully whatever the ratio. Another: the store answers in whole blocks of 256
+bytes, so a cached prefix that is taken leaves up to that cap of the prompt
+behind it without tokens (a thread of 1920 characters that is resident on a pod
+was scored as 1792); where the store says at which byte its tokens end
+(``Indexer.find_longest_contained``), what lies behind is tokenized on its own
+(``Tokenizer.encode_tail``: under a kilobyte, no special tokens) and appended,
+and nothing of it is written back. Where the two tokenizations disagree at the
+seam the block hashes behind it match nothing, which is what leaving them out
+gave. ``tokenize`` blocks for the result;
 ``enqueue_tokenization`` is fire-and-forget. Failed tasks are retried with
 exponential backoff, mirroring the rate-limited workqueue (``:150-155``).
 """
@@ -189,12 +197,13 @@ class TokenizationPool:
             )
 
     def _process_task(self, task: _Task) -> None:
-        token_ids, overlap_ratio = self.indexer.find_longest_contained_tokens(
+        token_ids, overlap_ratio, tokens_end = self.indexer.find_longest_contained(
             task.prompt, task.model_name
         )
 
         # whole bytes: the store's ratio is covered bytes over all bytes
-        n_bytes = len(task.prompt.encode("utf-8"))
+        prompt_bytes = task.prompt.encode("utf-8")
+        n_bytes = len(prompt_bytes)
         uncovered = n_bytes - round(overlap_ratio * n_bytes)
         if (
             overlap_ratio < self.config.min_prefix_overlap_ratio
@@ -203,6 +212,11 @@ class TokenizationPool:
             tokens, offsets = self.tokenizer.encode(task.prompt, task.model_name)
             self.indexer.add_tokenization(task.model_name, task.prompt, tokens, offsets)
             token_ids = tokens
+        elif tokens_end is not None and tokens_end < n_bytes:
+            tail = prompt_bytes[tokens_end:].decode("utf-8", errors="ignore")
+            token_ids = [
+                *token_ids, *self.tokenizer.encode_tail(tail, task.model_name)
+            ]
 
         if task.result is not None:
             task.result.set(list(token_ids))

@@ -4,7 +4,7 @@ from __future__ import annotations
 
 from abc import ABC, abstractmethod
 from dataclasses import dataclass
-from typing import Sequence
+from typing import Optional, Sequence
 
 #: (low, high) byte offsets of a token within the original prompt string.
 Offset = tuple[int, int]
@@ -44,3 +44,11 @@ class Indexer(ABC):
     ) -> tuple[list[int], float]:
         """Return (tokens, covered-byte ratio) for the longest cached prefix
         of ``prompt``."""
+
+    def find_longest_contained(
+        self, prompt: str, model_name: str
+    ) -> tuple[list[int], float, Optional[int]]:
+        """``find_longest_contained_tokens`` and the byte of ``prompt`` at
+        which the last of those tokens ends, where the store keeps it (None
+        where it does not): what lies behind it has no token yet."""
+        return (*self.find_longest_contained_tokens(prompt, model_name), None)

@@ -11,6 +11,10 @@ Parity with reference ``pkg/tokenization/prefixstore/lru_store.go``:
   to that byte;
 - lookup walks the chain until the first miss and reports the covered-byte
   ratio (``:160-205``).
+
+Beside its tokens a block keeps the byte at which the last token at or before
+it ends, so that a lookup can say where the bytes it found no token for begin
+(``find_longest_contained``; the pool tokenizes them on their own).
 """
 
 from __future__ import annotations
@@ -31,7 +35,7 @@ class LRUTokenStore(Indexer):
         if self.config.block_size < 1:
             raise ValueError("block_size must be >= 1")
         self._mu = threading.Lock()
-        self._stores: dict[str, LRUCache[int, list[int]]] = {}  # guarded_by: _mu
+        self._stores: dict[str, LRUCache[int, tuple[list[int], int]]] = {}  # guarded_by: _mu
 
     def _model_cache(self, model_name: str, create: bool) -> Optional[LRUCache]:
         with self._mu:
@@ -66,6 +70,7 @@ class LRUTokenStore(Indexer):
 
         token_idx = 0
         prev_hash = 0
+        tokens_end = 0
         for start in range(0, len(prompt_bytes) - bs + 1, bs):
             end = start + bs
             block_hash = self._chain_hash(prev_hash, prompt_bytes[start:end])
@@ -74,17 +79,25 @@ class LRUTokenStore(Indexer):
             block_tokens: list[int] = []
             while token_idx < len(tokens) and offsets[token_idx][1] <= end:
                 block_tokens.append(int(tokens[token_idx]))
+                # (a special token's offsets are (0, 0): the end never goes back)
+                tokens_end = max(tokens_end, offsets[token_idx][1])
                 token_idx += 1
-            cache.put(block_hash, block_tokens)
+            cache.put(block_hash, (block_tokens, tokens_end))
 
     def find_longest_contained_tokens(
         self, prompt: str, model_name: str
     ) -> tuple[list[int], float]:
+        return self.find_longest_contained(prompt, model_name)[:2]
+
+    def find_longest_contained(
+        self, prompt: str, model_name: str
+    ) -> tuple[list[int], float, int]:
         cache = self._model_cache(model_name, create=False)
         if cache is None:
-            return [], 0.0
+            return [], 0.0, 0
 
         contained: list[int] = []
+        tokens_end = 0
         prompt_bytes = prompt.encode("utf-8")
         bs = self.config.block_size
         prev_hash = 0
@@ -96,6 +109,7 @@ class LRUTokenStore(Indexer):
             block = cache.get(block_hash)
             if block is None:
                 break  # early-stop at first miss
-            contained.extend(block)
+            contained.extend(block[0])
+            tokens_end = block[1]
             overlap_ratio = end / len(prompt_bytes)
-        return contained, overlap_ratio
+        return contained, overlap_ratio, tokens_end

@@ -42,6 +42,11 @@ class Tokenizer(ABC):
     def encode(self, prompt: str, model_name: str) -> tuple[list[int], list[Offset]]:
         """Return (token ids, byte offsets) for ``prompt``."""
 
+    def encode_tail(self, text: str, model_name: str) -> list[int]:
+        """Token ids of ``text`` as the rest of a prompt whose beginning has
+        its tokens already: nothing a tokenizer puts before a whole prompt."""
+        return self.encode(text, model_name)[0]
+
     def decode(self, token_ids: Sequence[int], model_name: str) -> Optional[str]:
         """Detokenize, or None if this tokenizer cannot produce text (the
         serving path then returns token ids only)."""
@@ -99,6 +104,10 @@ class CachedHFTokenizer(Tokenizer):
         tok = self._get_tokenizer(model_name)
         enc = tok.encode(prompt)
         return list(enc.ids), char_offsets_to_byte_offsets(prompt, enc.offsets)
+
+    def encode_tail(self, text: str, model_name: str) -> list[int]:
+        tok = self._get_tokenizer(model_name)
+        return list(tok.encode(text, add_special_tokens=False).ids)
 
     def decode(self, token_ids: Sequence[int], model_name: str) -> str:
         """Detokenize (the serving path's response text)."""

@@ -26,6 +26,7 @@ if "xla_force_host_platform_device_count" not in flags:
 os.environ["JAX_PLATFORMS"] = "cpu"
 
 import jax  # noqa: E402
+import pytest  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
 
@@ -92,6 +93,25 @@ def _build_native_once() -> None:
     except (OSError, subprocess.CalledProcessError) as e:
         print(f"native libraries not built ({e!r}): their tests skip",
               file=sys.stderr)
+
+
+@pytest.hookimpl(tryfirst=True, optionalhook=True)  # (no hook under no:xdist)
+def pytest_xdist_make_scheduler(config, log):
+    """A FILE is the unit a worker is dealt, whatever ``--dist`` says. The
+    suite is laid out for that: a file's cases share module fixtures (a
+    parameter tree, a built engine) and the programs the first of them
+    compiled in its process, and ``_LONGEST_FIRST`` sorts whole files. Under
+    ``--dist load`` (the driver's command since PR 47) xdist deals the
+    collection out in runs of consecutive tests, a 24th of it to each worker
+    at the start: the first worker got the five longest files in one piece
+    (1 700 cpu-seconds of the run's 5 900) and the run was cut at its 1 470 s
+    with five workers idle. ``each`` is left alone: it is asked for on
+    purpose."""
+    if config.getvalue("dist") in ("load", "worksteal"):
+        from xdist.scheduler import LoadFileScheduling
+
+        return LoadFileScheduling(config, log)
+    return None  # xdist's own choice
 
 
 def pytest_configure(config):
@@ -189,10 +209,13 @@ _LONGEST_FIRST = (
     "chipbench_tests/test_swa_cell.py",  # 552
     "chipbench_tests/test_hybrid_cell.py",  # 327
     "chipbench_tests/test_scmoe_cell.py",  # 302
+    "chipbench_tests/test_kda_cell.py",  # 230
+    "test_kda.py",  # 150
     "chipbench_tests/test_chipbench.py",  # 165
     "chipbench_tests/test_latent_cell.py",  # 155
     "test_swa_engine_decode.py",  # 134
     "test_conv_state_engine.py",  # 133
+    "test_kda_engine.py",  # 130
     "test_moe_padding.py",  # 125
     "test_mla.py",  # 125
     "test_swa_rows.py",  # 122
@@ -233,9 +256,6 @@ def pytest_unconfigure(config):
 
     if locktrace.enabled():
         locktrace.deactivate()
-
-
-import pytest  # noqa: E402
 
 
 @contextlib.contextmanager

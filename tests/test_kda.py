@@ -1,0 +1,283 @@
+"""Delta-rule linear-attention layers beside latent ones (``layer_types``
+"linear_attention", ``TINY_LING_HYBRID``) over group-limited sparse experts:
+the served programs against the plain reference, in float32.
+
+The reference side is ``chipbench/references/kda_mla_moe.forward`` (float32,
+the recurrence token by token, nothing of the program's model code); its
+recurrence is itself held to ``transformers``' gated delta rule where the
+gate is one value a head. The program's prefill is the chunked form, its
+decode the kernel over a pool of slots (interpreted): every call here reads a
+row's state from one slot and writes it to ANOTHER, which is how the engine
+takes and restores snapshots. The kernels alone are in
+``tests/test_kda_kernels.py``, the engine in ``tests/test_kda_engine.py``,
+refusals, presets and the loader in ``tests/test_kda_config.py``; the
+helpers they share with the other architectures are ``tests/served_path.py``.
+"""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import served_path
+from chipbench import reference as chip_reference
+from llm_d_kv_cache_manager_tpu.models import TINY_LING_HYBRID, llama
+from served_path import prompt_of, rel_err
+
+CFG = TINY_LING_HYBRID
+#: one period of the two (linear and dense, linear and routed, latent and
+#: routed): every kind of layer once, half the program to compile. The cases
+#: that do not need the second period (where a layer's place among its kind
+#: is not its place in the model) run on it.
+PERIOD = dataclasses.replace(CFG, n_layers=3)
+PS = 4
+TOL = 1e-4
+REF = chip_reference.load("kda_mla_moe")
+
+
+@pytest.fixture(scope="module")
+def params():
+    return llama.init_params(jax.random.PRNGKey(47), CFG)
+
+
+def one_period(tree):
+    return {**tree, "layers": tree["layers"][:PERIOD.n_layers]}
+
+
+def reference_logits(params, tokens, cfg=CFG) -> np.ndarray:
+    return served_path.reference_logits(REF, params, cfg, tokens)
+
+
+class StateSlots(served_path.NoSecondPool):
+    """The second pool of a model with linear layers: two slots a row, and
+    every call reads the row's state from the one and writes it to the other
+    (a prefill as ``[read, write]``, a decode step as ``[a, b, switch]`` with
+    the switch at the step's own position)."""
+
+    def make(self, cfg, rows, pages, table_pages):
+        self.pool = llama.init_kda_state(cfg, 2 * rows + 1)
+        self.slots = [[1 + 2 * i, 2 + 2 * i] for i in range(rows)]
+
+    def _swap(self, i):
+        self.slots[i].reverse()
+        return list(reversed(self.slots[i]))  # [the one read, the one written]
+
+    def prefill(self, chunks, positions, ctx_pages):
+        if self.pool is None:
+            return {}
+        slots = np.zeros((positions.shape[0], 2), np.int32)
+        for i, _, _ in chunks:
+            slots[i] = self._swap(i)
+        return dict(state_pages=self.pool, state_slots=slots)
+
+    def decode(self, positions):
+        if self.pool is None:
+            return {}
+        slots = np.array([[*self._swap(i), at]
+                          for i, at in enumerate(positions)], np.int32)
+        return dict(state_pages=self.pool, state_slots=slots)
+
+    def keep(self, results):
+        if self.pool is not None:
+            (self.pool,) = results
+
+
+def served(params, rows, steps, attn_impl, cfg=CFG):
+    got, fed, _ = served_path.served(
+        params, REF.pool_config(params, cfg), rows, steps, attn_impl,
+        page_size=PS, second=StateSlots())
+    return got, fed
+
+
+# -- the served programs against the token-by-token reference ------------------
+@pytest.mark.parametrize("attn_impl, cfg, rows", [
+    # both periods; a cold row beside two that go on from a state
+    pytest.param("xla", CFG, [(30, 16), (9, 0), (21, 8)],
+                 id="xla-batch-of-unequal-lengths"),
+    pytest.param("xla", PERIOD, [(70, 64)],
+                 id="xla-over-a-chunk-of-the-recurrence"),
+    # (the latent layers' kernel, interpreted: the linear layers' prefill is
+    # the same program under both)
+    pytest.param("pallas", PERIOD, [(30, 16), (9, 0), (21, 8)],
+                 id="pallas-batch-of-unequal-lengths"),
+])
+def test_prefill_then_decode_through_the_pools(params, rows, cfg, attn_impl):
+    if cfg is PERIOD:
+        params = one_period(params)
+    rows = [(prompt_of(40 + i, n), r) for i, (n, r) in enumerate(rows)]
+    got, fed = served(params, rows, 5, attn_impl, cfg=cfg)
+    for (prompt, _), logits, tokens in zip(rows, got, fed):
+        want = reference_logits(params, prompt + tokens, cfg)[len(prompt) - 1:]
+        assert rel_err(logits, want) < TOL
+
+
+@pytest.mark.parametrize("safe", [True, False], ids=["safe_gate", "softplus"])
+def test_both_forms_of_the_gate(safe):
+    # (the gate is the linear layers' alone: one of them, over the dense FFN)
+    cfg = dataclasses.replace(CFG, n_layers=1, kda_safe_gate=safe)
+    params = llama.init_params(jax.random.PRNGKey(5), cfg)
+    prompt = prompt_of(7, 21)
+    got, fed = served(params, [(prompt, 8)], 3, "xla", cfg=cfg)
+    want = reference_logits(params, prompt + fed[0], cfg)[len(prompt) - 1:]
+    assert rel_err(got[0], want) < TOL
+    # ... and they are two models: the other form's reference is not this one
+    other = dataclasses.replace(cfg, kda_safe_gate=not safe)
+    wrong = reference_logits(params, prompt + fed[0], other)[len(prompt) - 1:]
+    assert rel_err(got[0], wrong) > 100 * TOL
+
+
+@pytest.mark.parametrize("index", [0, 1, 2], ids=[
+    "linear-dense", "linear-routed", "latent-routed"])
+def test_a_layer_run_alone_is_what_its_parameters_say(params, index):
+    """The benchmark's layer-alone comparison: a one-layer tree under
+    ``replace(cfg, n_layers=1)``, whose ``layer_types`` still speak of the
+    whole model; the mixer and the FFN are read from the layer."""
+    layer = params["layers"][index]
+    assert ("kda_qkv" in layer) == (CFG.layer_kind(index) == "linear")
+    assert ("router" in layer) == (index >= CFG.first_k_dense)
+    cfg1 = dataclasses.replace(CFG, n_layers=1)
+    alone = {**params, "layers": [layer]}
+    prompt = prompt_of(60 + index, 13)
+    got, fed = served(alone, [(prompt, 4)], 2, "xla", cfg=cfg1)
+    want = reference_logits(alone, prompt + fed[0], cfg1)[len(prompt) - 1:]
+    assert rel_err(got[0], want) < TOL
+
+
+def test_a_snapshot_is_left_as_it_was(params):
+    """A prefill that reads slot 1 and writes slot 2 leaves slot 1 bit for
+    bit; a second one from slot 1 gives the first's logits bit for bit."""
+    cfg, params = PERIOD, one_period(params)
+    prompt = prompt_of(3, 24)
+    k_pages, v_pages = llama.init_kv_pages(cfg, 16, PS)
+    state = llama.init_kda_state(cfg, 4)
+
+    def piece(lo, hi, read, write):
+        nonlocal k_pages, v_pages, state
+        pos = np.arange(lo, hi)[None, :]
+        logits, k_pages, v_pages, state = llama.prefill(
+            params, cfg, np.asarray([prompt[lo:hi]], np.int32), pos,
+            np.ones((1, hi - lo), bool), k_pages, v_pages, 1 + pos // PS,
+            pos % PS, 1 + np.arange(lo // PS)[None, :], np.array([lo]),
+            interpret=True, state_pages=state,
+            state_slots=np.array([[read, write]], np.int32))
+        return np.asarray(logits)
+
+    piece(0, 12, 1, 1)
+    before = jax.tree.map(lambda x: np.asarray(x[:, 1]), state)
+    first = piece(12, 24, 1, 2)
+    again = piece(12, 24, 1, 3)
+    after = jax.tree.map(lambda x: np.asarray(x[:, 1]), state)
+    assert np.array_equal(first, again)
+    assert all(np.array_equal(a, b) for a, b in zip(before, after))
+    assert all(np.array_equal(x[:, 2], x[:, 3]) for x in state)
+    assert not np.array_equal(state[0][:, 1], state[0][:, 2])
+
+
+# -- the reference's recurrence -------------------------------------------------
+def test_the_reference_recurrence_is_the_gated_delta_rule():
+    """With ``g`` equal over a head's channels the recurrence is the gated
+    delta rule: ``transformers``' token-by-token form (torch, CPU)."""
+    torch = pytest.importorskip("torch")
+    from transformers.models.qwen3_next.modeling_qwen3_next import (
+        torch_recurrent_gated_delta_rule,
+    )
+
+    rng = np.random.default_rng(0)
+    s, H, K = 37, 3, 16
+    q, k, v = (rng.standard_normal((s, H, K)).astype(np.float32)
+               for _ in range(3))
+    k /= np.linalg.norm(k, axis=-1, keepdims=True)
+    g = -np.abs(rng.standard_normal((s, H))).astype(np.float32)
+    beta = 1 / (1 + np.exp(-rng.standard_normal((s, H)).astype(np.float32)))
+    S0 = rng.standard_normal((H, K, K)).astype(np.float32)
+    # (theirs scales q by 1 / sqrt(K) inside; ours takes q as the layer
+    # scaled it)
+    got_o, got_S = REF.recurrence(
+        jnp.asarray(q / np.sqrt(K)), jnp.asarray(k), jnp.asarray(v),
+        jnp.asarray(np.repeat(g[..., None], K, axis=-1)), jnp.asarray(beta),
+        jnp.asarray(S0))
+    want_o, want_S = torch_recurrent_gated_delta_rule(
+        *(torch.tensor(x)[None] for x in (q, k, v, g, beta)),
+        initial_state=torch.tensor(S0)[None], output_final_state=True)
+    np.testing.assert_allclose(got_o, want_o[0].numpy(), atol=2e-5)
+    np.testing.assert_allclose(got_S, want_S[0].numpy(), atol=2e-5)
+
+
+# -- group-limited routing -------------------------------------------------------
+def _x(seed, n=9):
+    return jnp.asarray(
+        np.random.default_rng(seed).normal(size=(n, CFG.hidden_size)),
+        jnp.float32)
+
+
+@pytest.mark.parametrize("n_group, topk_group", [(4, 2), (4, 1), (2, 1), (8, 3)])
+def test_the_router_chooses_within_the_best_groups(params, n_group, topk_group):
+    cfg = dataclasses.replace(CFG, n_group=n_group, topk_group=topk_group,
+                              n_experts_per_tok=1 if n_group == 8 else 2)
+    layer, x = params["layers"][1], _x(n_group)
+    topv, topi = llama._moe_gates(layer, cfg, x)
+    scores = np.asarray(jax.nn.sigmoid(x @ layer["router"]))
+    want_i, _ = REF.choose(
+        jnp.asarray(scores), layer["router_bias"], cfg)
+    assert np.array_equal(np.sort(topi, axis=1), np.sort(want_i, axis=1))
+    # by hand: a group's score is the sum of its two largest choice scores
+    c = scores + np.asarray(layer["router_bias"])
+    size = c.shape[1] // n_group
+    grouped = c.reshape(len(c), n_group, size)
+    best = np.sort(grouped, axis=-1)[..., -min(2, size):].sum(-1)
+    kept = np.argsort(-best, axis=1)[:, :topk_group]
+    for row, groups in zip(np.asarray(topi), kept):
+        assert set(row // size) <= set(groups.tolist())
+    # the gates weigh with the scores alone, renormalised, times the factor
+    picked = np.take_along_axis(scores, np.asarray(topi), axis=1)
+    np.testing.assert_allclose(
+        topv, picked / picked.sum(1, keepdims=True) * cfg.routed_scaling_factor,
+        rtol=1e-5)
+
+
+def test_one_group_is_the_routing_it_always_was(params):
+    layer, x = params["layers"][1], _x(1)
+    one = dataclasses.replace(CFG, n_group=1, topk_group=1)
+    topv, topi = llama._moe_gates(layer, one, x)
+    scores = np.asarray(jax.nn.sigmoid(x @ layer["router"]))
+    want = np.argsort(-(scores + np.asarray(layer["router_bias"])), axis=1)[:, :2]
+    assert np.array_equal(np.sort(topi, axis=1), np.sort(want, axis=1))
+    # ... and the groups do leave experts out: some row's choice differs
+    _, limited = llama._moe_gates(
+        layer, dataclasses.replace(CFG, n_group=4, topk_group=1), x)
+    assert not np.array_equal(np.sort(limited, axis=1), np.sort(topi, axis=1))
+
+
+@pytest.mark.parametrize("dispatch", ["routed", "dense"])
+def test_both_dispatches_route_within_the_groups(params, dispatch):
+    cfg = dataclasses.replace(CFG, moe_dispatch=dispatch)
+    layer, x = params["layers"][1], _x(2)[None]
+    got = llama._mlp(layer, cfg, x, interpret=True)[0]
+    want, _ = REF._ffn(layer, cfg, x[0])
+    assert rel_err(np.asarray(got), np.asarray(want)) < TOL
+
+
+def test_a_routing_group_a_chip_adds_up_to_the_uncut_layer(params):
+    """The deployment's cut: each of four chips holds one group's experts
+    (``expert_first`` / ``expert_count``), every chip routes over all of them
+    and adds the places that fall in its own group; the shared expert is
+    counted once."""
+    layer, x = params["layers"][2], _x(3)[None]
+    whole = llama._mlp(layer, CFG, x, interpret=True)[0]
+    shared = llama._swiglu(
+        CFG, x, layer["ws_gate"], layer["ws_up"], layer["ws_down"])[0]
+    size = CFG.n_experts // CFG.n_group
+    parts = []
+    for group in range(CFG.n_group):
+        cut = dataclasses.replace(
+            CFG, expert_first=group * size, expert_count=size)
+        held = {**layer, **{
+            name: layer[name][group * size: (group + 1) * size]
+            for name in ("w_gate", "w_up", "w_down")}}
+        part = llama._mlp(held, cut, x, interpret=True)[0]
+        want, _ = REF._ffn(held, cut, x[0])
+        assert rel_err(np.asarray(part), np.asarray(want)) < TOL
+        parts.append(part - shared)
+    np.testing.assert_allclose(sum(parts) + shared, whole, atol=2e-5)

@@ -56,7 +56,6 @@ def test_the_pool_is_one_row_a_token_and_nothing_else():
     (dict(tp=2), "tp > 1"),
     (dict(spec_decode="prompt_lookup"), "spec_decode"),
     (dict(model=dataclasses.replace(CFG, block_length=4)), "block_length"),
-    (dict(model=dataclasses.replace(CFG, n_group=2, topk_group=2)), "n_group"),
 ])
 def test_engine_refuses_by_name(what, name):
     config = EngineConfig(
@@ -65,6 +64,26 @@ def test_engine_refuses_by_name(what, name):
     config = dataclasses.replace(config, **what)
     with pytest.raises(ValueError, match="kv_lora_rank.*" + name):
         Engine(config)
+
+
+def test_the_engine_serves_group_limited_routing(params):
+    """What the engine refused until PR 47: a latent model whose router
+    chooses within the best groups is built and generates."""
+    from llm_d_kv_cache_manager_tpu.server import SamplingParams
+
+    cfg = dataclasses.replace(CFG, n_group=2, topk_group=1)
+    engine = Engine(EngineConfig(
+        model=cfg, block_manager=BlockManagerConfig(total_pages=16, page_size=PS),
+        interpret=True, prefill_bucket=16), params=params)
+    seq = engine.add_request([3, 1, 4, 1, 5, 9, 2, 6], SamplingParams(max_new_tokens=3))
+    plain = Engine(EngineConfig(
+        model=CFG, block_manager=BlockManagerConfig(total_pages=16, page_size=PS),
+        interpret=True, prefill_bucket=16), params=params)
+    other = plain.add_request([3, 1, 4, 1, 5, 9, 2, 6], SamplingParams(max_new_tokens=3))
+    for eng in (engine, plain):
+        while eng.has_work:
+            eng.step()
+    assert len(seq.output_tokens) == len(other.output_tokens) == 3
 
 
 def test_page_export_and_import_are_refused_by_name(params):
@@ -172,8 +191,18 @@ def test_the_loader_reads_a_low_rank_query_path():
         assert a.dtype == b.dtype and np.array_equal(a, b)
 
 
+def test_the_loader_reads_the_routing_groups():
+    """What the loader refused until PR 47: ``n_group`` / ``topk_group`` are
+    read into the fields the router runs."""
+    from llm_d_kv_cache_manager_tpu.models.hf_loader import config_from_hf
+
+    hf = _KananaConfig()
+    hf.n_group, hf.topk_group = 8, 4
+    assert config_from_hf(hf) == dataclasses.replace(
+        KANANA_2_30B_A3B, n_group=8, topk_group=4)
+
+
 @pytest.mark.parametrize("change, name", [
-    (dict(n_group=8, topk_group=4), "group-limited"),
     (dict(scoring_func="softmax"), "scoring_func"),
     (dict(topk_method="greedy"), "topk_method"),
     (dict(moe_layer_freq=2), "moe_layer_freq"),

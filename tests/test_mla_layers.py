@@ -60,8 +60,18 @@ def test_the_bias_chooses_and_does_not_weigh(params):
     np.testing.assert_allclose(np.asarray(plain).sum(1), 1.0, rtol=1e-6)
     raw, _ = _gates(layer, x, routed_scaling_factor=1.0, norm_topk_prob=False)
     np.testing.assert_allclose(raw, picked, rtol=1e-6)
-    with pytest.raises(ValueError, match="group-limited"):
-        _gates(layer, x, n_group=2)
+    # two groups of four, the better one kept: experts 6 and 7 lie in the
+    # second and are still the choice; with the bias on 1 and 6 instead, the
+    # group of 6 wins (at least 8 against at most 5 + 1 + 1) and 1 is left out
+    _, grouped = _gates(layer, x, n_group=2, topk_group=1)
+    assert (np.sort(np.asarray(grouped), axis=1) == [6, 7]).all()
+    layer["router_bias"] = jnp.zeros(8).at[1].set(5.0).at[6].set(8.0)
+    _, split = _gates(layer, x)
+    assert (np.sort(np.asarray(split), axis=1) == [1, 6]).all()
+    _, kept = _gates(layer, x, n_group=2, topk_group=1)
+    assert (np.asarray(kept) >= 4).all() and (np.asarray(kept) == 6).any(1).all()
+    with pytest.raises(ValueError, match="n_group=3"):
+        _gates(layer, x, n_group=3)
 
 
 def test_a_layers_ffn_is_read_from_its_parameters(params):

@@ -22,6 +22,7 @@ from llm_d_kv_cache_manager_tpu.models import (
     TINY_QWEN3_MOE,
     TINY_SCMOE,
     TINY_SDAR_MOE,
+    TINY_LING_HYBRID,
     TINY_SWA_MOE,
     llama,
 )
@@ -48,6 +49,8 @@ CONFIGS = {
     "double": (TINY_SCMOE, EVERY | ROUTED - {"moe_shared"} | {"moe_zero"}),
     # sliding layers under a scope of their own beside the one full layer's
     "window": (TINY_SWA_MOE, EVERY | ROUTED | {"attn_window"}),
+    # linear layers (the whole mixer under ``kda``) beside the latent ones
+    "linear": (TINY_LING_HYBRID, EVERY | ROUTED | {"kda"}),
 }
 
 
@@ -62,6 +65,9 @@ def _shapes(cfg):
     window = jax.eval_shape(lambda: llama.init_window_pages(cfg, PAGES, PS))
     if window is not None:  # the pair of window pools; the tables ride apart
         return params, k_pages, v_pages, {"window_pages": window}
+    slots = jax.eval_shape(lambda: llama.init_kda_state(cfg, PAGES))
+    if slots is not None:  # the state pool of slots; the rows' slots apart
+        return params, k_pages, v_pages, {"state_pages": slots}
     return params, k_pages, v_pages, (
         {} if state is None else {"state_pages": state}
     )
@@ -70,6 +76,10 @@ def _shapes(cfg):
 def _window_tables(state, width):
     """A window model's second packed operand (``width`` columns of page
     ids before the table), for the programs that take one."""
+    if isinstance(state.get("state_pages"), tuple):
+        # a linear model's second operand: its rows' slots ([read, write] a
+        # prefill row, [a, b, switch] a decode lane)
+        return {"state_slots": jnp.zeros((LANES, 2 if width else 3), jnp.int32)}
     if "window_pages" not in state:
         return {}
     return {"window_packed": jnp.zeros((LANES, width + TABLE_W + 1), jnp.int32)}
@@ -113,7 +123,8 @@ def _lowered_scopes(cfg, program) -> frozenset:
 
 CASES = [
     (kind, program)
-    for kind in ("dense", "routed", "latent", "conv", "double", "window")
+    for kind in ("dense", "routed", "latent", "conv", "double", "window",
+                 "linear")
     for program in ("decode_steps", "prefill")
 ] + [("blocks", "prefill"), ("blocks", "denoise_steps")]
 

@@ -461,6 +461,8 @@ _SERVED = [
     ("qwen3-30b-a3b", "prefill"),
     ("qwen3-32b", "decode_steps"),
     ("qwen3-32b", "prefill"),
+    ("ling-3.0-flash", "decode_steps"),
+    ("ling-3.0-flash", "prefill"),
     ("sdar-30b-a3b", "denoise_steps"),
     ("sdar-30b-a3b", "prefill"),
     ("trinity-large-preview", "decode_steps"),
@@ -491,10 +493,16 @@ class TestServedPrograms:
             if i.moves_bytes and "scatter" not in inside.get(i.name, ())
         ] == []
         state_shape = aot_pool_copies.state_pool_shape(kwargs)
-        assert (state_shape is not None) == (config == "lfm2-8b-a1b")
+        assert (state_shape is not None) == (
+            config in ("lfm2-8b-a1b", "ling-3.0-flash"))
         if state_shape:  # the convolution layers' state pool beside them
             assert aot_pool_copies.pool_instructions(hlo, state_shape)
             assert _whole_pool_moves(hlo, state_shape) == []
+        rows_shape = aot_pool_copies.state_rows_shape(kwargs)
+        assert (rows_shape is not None) == (config == "ling-3.0-flash")
+        if rows_shape:  # the linear layers' carried rows beside the matrices
+            assert aot_pool_copies.pool_instructions(hlo, rows_shape)
+            assert _whole_pool_moves(hlo, rows_shape) == []
         window_shape = aot_pool_copies.window_pool_shape(kwargs)
         assert (window_shape is not None) == (config == "trinity-large-preview")
         if window_shape:  # the sliding layers' window pools beside them
@@ -609,6 +617,40 @@ ENTRY %main.9 (k_pages.1: bf16[2,8,4,2,128], rows.1: s32[6]) -> (f32[3], bf16[2,
   ROOT %tuple.9 = (f32[3]{0:T(128)}, bf16[2,8,4,2,128]{4,3,2,1,0:T(2,128)(2,1)}) tuple(%logits.1, %bitcast.4)
 }
 """
+
+
+class TestTheStatePoolOfSlots:
+    """A model with linear-attention layers (``ling-3.0-flash`` cut to a
+    linear and a latent layer): the decode program's kernel updates the
+    lanes' matrices where they lie in the pool (``kda_decode``,
+    ``input_output_aliases``: its custom call's result is the pool and the
+    one thing that may produce it), the carried rows and a prefill's final
+    states go in by one flat scatter each, and nothing copies either pool or
+    the latent pool beside them."""
+
+    @pytest.mark.parametrize("program", ["decode_steps", "prefill"])
+    def test_the_pools_are_updated_where_they_lie(self, topo, program):
+        one_chip = SingleDeviceSharding(topo.devices[0])
+        fn, args, kwargs, pool_shape = aot_pool_copies.served_program(
+            "ling-3.0-flash", program, one_chip, n_layers=2,
+            layer_types=("linear_attention", "full_attention"),
+        )
+        hlo = aot_pool_copies.compile_text(fn, *args, **kwargs)
+        matrices = aot_pool_copies.state_pool_shape(kwargs)
+        rows = aot_pool_copies.state_rows_shape(kwargs)
+        assert matrices == (1, 456, 32, 128, 128) and rows == (1, 456, 36864)
+        for shape in (pool_shape, matrices, rows):
+            assert aot_pool_copies.pool_instructions(hlo, shape)
+            assert _whole_pool_moves(hlo, shape) == []
+        kernels = [
+            i for i in aot_pool_copies.pool_instructions(hlo, matrices)
+            if i.opcode == "custom-call"
+        ]
+        # one call a linear layer in a decode step; a prefill has none (its
+        # recurrence is plain matrix products, its write one scatter)
+        assert len(kernels) == (program == "decode_steps")
+        assert all(i.name.startswith("kda_decode") and not i.moves_bytes
+                   for i in kernels)
 
 
 class TestTheReader:

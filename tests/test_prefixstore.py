@@ -64,6 +64,14 @@ class TestLRUTokenStore:
         store.add_tokenization("m", prompt, tokens, offsets)
         got, ratio = store.find_longest_contained_tokens(prompt[:4] + "XXXX", "m")
         assert got == [1]  # token 2 only contained in block 2, which missed
+        # and the lookup says where the tokens it found end: byte 3, not 4
+        assert store.find_longest_contained(prompt[:4] + "XXXX", "m") == (
+            [1], 0.5, 3)
+        assert store.find_longest_contained(prompt, "m") == ([1, 2], 1.0, 6)
+        assert store.find_longest_contained("none", "m") == ([], 0.0, 0)
+        # a special token behind the text has the offsets (0, 0): the end stays
+        store.add_tokenization("s", "abcd", [1, 2, 9], [(0, 2), (2, 4), (0, 0)])
+        assert store.find_longest_contained("abcdXY", "s") == ([1, 2, 9], 4 / 6, 4)
 
     def test_eviction(self):
         store = LRUTokenStore(Config(block_size=4, cache_size=2))

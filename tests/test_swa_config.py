@@ -142,10 +142,20 @@ def test_the_loader_reads_the_published_config():
     assert config_from_hf(_TrinityConfig()) == TRINITY_LARGE_PREVIEW
 
 
+def test_the_loader_reads_the_routing_groups():
+    """What the loader refused until PR 47: ``n_group`` / ``topk_group`` are
+    read into the fields the router runs."""
+    from llm_d_kv_cache_manager_tpu.models.hf_loader import config_from_hf
+
+    hf = _TrinityConfig()
+    hf.n_group, hf.topk_group = 4, 2
+    assert config_from_hf(hf) == dataclasses.replace(
+        TRINITY_LARGE_PREVIEW, n_group=4, topk_group=2)
+
+
 @pytest.mark.parametrize("change, name", [
     (dict(layer_types=["conv", "sliding_attention"] * 30), "layer_types"),
     (dict(score_func="softmax"), "score_func"),
-    (dict(n_group=2), "n_group"),
     (dict(mup_enabled=False), "mup_enabled"),
     (dict(rope_scaling={"type": "yarn", "factor": 4}), "yarn"),
 ])

@@ -20,6 +20,7 @@ class MockTokenizer(Tokenizer):
 
     def __init__(self, fail_times: int = 0, delay: float = 0.0):
         self.calls = 0
+        self.tails: list[str] = []
         self.fail_times = fail_times
         self.delay = delay
         self._lock = threading.Lock()
@@ -34,6 +35,10 @@ class MockTokenizer(Tokenizer):
         tokens = [ord(c) for c in prompt]
         offsets = [(i, i + 1) for i in range(len(prompt))]
         return tokens, offsets
+
+    def encode_tail(self, text, model_name):
+        self.tails.append(text)
+        return [ord(c) for c in text]
 
 
 @pytest.fixture
@@ -77,12 +82,72 @@ class TestTokenizationPool:
     def test_a_long_uncovered_tail_is_tokenized_whatever_the_ratio(
         self, cap, calls, monkeypatch
     ):
-        # 96 of 120 bytes cached: the ratio (0.8) takes the cached prefix;
-        # the 24 bytes it leaves uncovered do not when the cap is that low
+        # 96 of 120 bytes cached: the ratio (0.8) takes the cached prefix
+        # and tokenizes the 24 bytes behind it on their own; when the cap is
+        # that low the whole prompt is tokenized and written back
         from llm_d_kv_cache_manager_tpu.tokenization import pool as pool_module
 
         monkeypatch.setattr(pool_module, "MAX_UNCOVERED_BYTES", cap)
         tok = MockTokenizer()
+        store = LRUTokenStore(Config(block_size=4))
+        p = TokenizationPool(
+            TokenizationPoolConfig(workers_count=1), store=store, tokenizer=tok,
+        )
+        p.run()
+        try:
+            document = "abcd" * 24
+            p.tokenize(document, "m")
+            grown = document + "wxyz" * 6
+            assert p.tokenize(grown, "m") == [ord(c) for c in grown]
+            assert tok.calls == calls
+            assert tok.tails == ([] if calls == 2 else ["wxyz" * 6])
+            # a tail is not written back: the store holds what it held
+            held = len(store.find_longest_contained_tokens(grown, "m")[0])
+            assert held == (120 if calls == 2 else 96)
+        finally:
+            p.shutdown()
+
+    def test_a_prompt_that_is_no_whole_blocks_comes_back_whole(self):
+        # a thread of 1920 characters is seven and a half of the store's
+        # blocks, and was only ever tokenized with a turn behind it: asked
+        # for alone, its last 128 characters are tokenized on their own
+        tok = MockTokenizer()
+        p = TokenizationPool(
+            TokenizationPoolConfig(workers_count=1),
+            store=LRUTokenStore(Config(block_size=256)),
+            tokenizer=tok,
+        )
+        p.run()
+        try:
+            thread = "".join(chr(97 + i % 23) for i in range(1920))
+            p.tokenize(thread + "?" * 16, "m")
+            assert p.tokenize(thread, "m") == [ord(c) for c in thread]
+            assert tok.calls == 1 and tok.tails == [thread[1792:]]
+            # and with another turn behind it
+            turn = thread + "!" * 96
+            assert p.tokenize(turn, "m") == [ord(c) for c in turn]
+            assert tok.calls == 1 and tok.tails[-1] == turn[1792:]
+        finally:
+            p.shutdown()
+
+    def test_the_tail_begins_where_the_cached_tokens_end(self):
+        # tokens of three bytes over blocks of four: a token that lies
+        # across a block's end belongs to the next block, so the cached
+        # tokens end before the covered bytes do and the tail begins there
+        class Threes(MockTokenizer):
+            def encode(self, prompt, model_name):
+                self.calls += 1
+                n = len(prompt)
+                return (
+                    [sum(map(ord, prompt[i:i + 3])) for i in range(0, n, 3)],
+                    [(i, min(i + 3, n)) for i in range(0, n, 3)],
+                )
+
+            def encode_tail(self, text, model_name):
+                self.tails.append(text)
+                return Threes.encode(self, text, model_name)[0]
+
+        tok = Threes()
         p = TokenizationPool(
             TokenizationPoolConfig(workers_count=1),
             store=LRUTokenStore(Config(block_size=4)),
@@ -90,11 +155,31 @@ class TestTokenizationPool:
         )
         p.run()
         try:
+            text = "abcdefghijklmnopqrstuvwxyzABCDEF"  # 32 bytes: 8 blocks
+            whole = p.tokenize(text, "m")
+            # 28 of 34 bytes covered (0.82); the tokens found end at byte 27
+            assert p.tokenize(text[:28] + "012345", "m") == [
+                *whole[:9], *tok.encode(text[27] + "012345", "m")[0]]
+            assert tok.tails == [text[27] + "012345"]
+        finally:
+            p.shutdown()
+
+    def test_a_store_that_keeps_no_end_leaves_the_tail_out(self):
+        from llm_d_kv_cache_manager_tpu.tokenization.prefixstore import (
+            ContainedTokenStore,
+        )
+
+        tok = MockTokenizer()
+        p = TokenizationPool(
+            TokenizationPoolConfig(workers_count=1),
+            store=ContainedTokenStore(Config()), tokenizer=tok,
+        )
+        p.run()
+        try:
             document = "abcd" * 24
             p.tokenize(document, "m")
-            grown = p.tokenize(document + "wxyz" * 6, "m")
-            assert tok.calls == calls
-            assert len(grown) == (120 if calls == 2 else 96)
+            assert len(p.tokenize(document + "wxyz" * 6, "m")) == 96
+            assert tok.tails == []
         finally:
             p.shutdown()
 

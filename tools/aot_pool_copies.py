@@ -11,8 +11,10 @@ whole K or V pool ``[L, P, page, n_kv, hd]`` (a latent model's one pool:
 compiler gives the pool. A model with convolution layers has a state pool
 beside them (``[conv layers, P, row]``, ``state_pool_shape``), a model with
 sliding-window layers a pair of window pools (``[sliding layers, window
-pages, page, n_kv, hd]``, ``window_pool_shape``): they are listed the same
-way.
+pages, page, n_kv, hd]``, ``window_pool_shape``), a model with
+linear-attention layers a state pool of slots (``[linear layers, slots,
+heads, K, V]`` float32 and the carried rows ``[linear layers, slots, row]``,
+``state_pool_shape`` / ``state_rows_shape``): they are listed the same way.
 
 A pool is hundreds of MiB: any such instruction that is not free (a
 ``bitcast``, a ``parameter``, tuple plumbing) reads and writes that much
@@ -53,6 +55,11 @@ PREFILL_ROWS, PREFILL_CHUNK = 8, 128  # a dispatch of the cells: 1024 rows
 #: (``prefill_packed``'s loop over rows reads the pools) costs what the
 #: body's own instructions cost, and those are listed like any other.
 FREE = frozenset({"parameter", "bitcast", "get-tuple-element", "tuple", "while"})
+
+#: Pallas kernels that update a pool where it lies (``input_output_aliases``):
+#: their custom call's result IS the pool, the same buffer, and what they
+#: move is the slots they were given, not the pool (``ops/kda.py``).
+IN_PLACE_KERNELS = ("kda_decode",)
 
 
 class PoolInstruction(NamedTuple):
@@ -140,7 +147,11 @@ def pool_instructions(
         "|".join(r"\[" + ",".join(map(str, s)) + r"\]" for s in shapes)
     )
     return [
-        PoolInstruction(comp, name, op, result, op not in FREE)
+        PoolInstruction(
+            comp, name, op, result,
+            op not in FREE and not (
+                op == "custom-call" and name.startswith(IN_PLACE_KERNELS)),
+        )
         for comp, name, op, result in instructions(hlo)
         if wanted.search(result)
     ]
@@ -184,7 +195,9 @@ def compile_text(fn, *args, **kwargs) -> str:
     here, so the refusal is lifted for the lowering alone."""
     modules = [
         importlib.import_module(f"llm_d_kv_cache_manager_tpu.ops.{name}")
-        for name in ("flash_prefill", "gmm", "mla_attention", "paged_attention")
+        for name in (
+            "flash_prefill", "gmm", "kda", "mla_attention", "paged_attention",
+        )
     ]
     kept = [m.require_tpu_unless_interpret for m in modules]
     for m in modules:
@@ -235,6 +248,17 @@ def served_program(config: str, program: str, one_chip, **replace):
         lambda: llama.init_state_pages(cfg, env["TOTAL_PAGES"]))
     stateful = {} if state is None else {
         "state_pages": S(state.shape, state.dtype)}
+    # ... or, for a model with linear-attention layers, the state pool of
+    # slots (matrices, carried rows) and the rows' slots, sized as the
+    # engine sizes it (``Engine.__init__``)
+    slots = lanes + PREFILL_ROWS + env.get("STATE_SNAPSHOT_SLOTS", 0)
+    kda = jax.eval_shape(lambda: llama.init_kda_state(cfg, slots))
+    decode_slots, prefill_slots = {}, {}
+    if kda is not None:
+        stateful = {
+            "state_pages": tuple(S(x.shape, x.dtype) for x in kda)}
+        decode_slots = {"state_slots": S((lanes, 3), i32)}
+        prefill_slots = {"state_slots": S((PREFILL_ROWS, 2), i32)}
     # ... or, for a model with sliding layers, the pair of window pools and
     # the dispatch's window tables (one width: ``Engine.window_table_pages``
     # in a decode dispatch, the context bucket in a prefill)
@@ -254,7 +278,7 @@ def served_program(config: str, program: str, one_chip, **replace):
         packed = S((lanes, table_w + llama.DECODE_PACKED_TAIL), i32)
         args = (params, cfg, S((lanes,), i32), packed, pool, second, key)
         kwargs = dict(page_size=page, num_steps=1, interpret=False, mesh=None,
-                      **stateful, **decode_window)
+                      **stateful, **decode_window, **decode_slots)
         return llama.decode_steps, args, kwargs, pool_shape
     if program == "denoise_steps" and cfg.block_length > 0:
         width = 2 * cfg.block_length + table_w + 5
@@ -269,16 +293,28 @@ def served_program(config: str, program: str, one_chip, **replace):
         width = 5 * PREFILL_CHUNK + engine["prefill_ctx_bucket"] + 1
         args = (params, cfg, S((PREFILL_ROWS, width), i32), pool, second)
         kwargs = dict(chunk=PREFILL_CHUNK, mesh=None, attn_impl="pallas",
-                      interpret=False, **stateful, **prefill_window)
+                      interpret=False, **stateful, **prefill_window,
+                      **prefill_slots)
         return llama.prefill_packed, args, kwargs, pool_shape
     return None
 
 
 def state_pool_shape(kwargs: dict):
     """The state pool's shape among a served program's keyword arguments
-    (``served_program``), or None: the model has no convolution layers."""
+    (``served_program``), or None: the model has no convolution layers. A
+    model with linear-attention layers has a pair there: the shape of the
+    matrices' pool (``state_rows_shape``: the carried rows')."""
     state = kwargs.get("state_pages")
+    if isinstance(state, tuple):
+        state = state[0]
     return None if state is None else tuple(state.shape)
+
+
+def state_rows_shape(kwargs: dict):
+    """The shape of the carried rows' pool of a model with linear-attention
+    layers among a served program's keyword arguments, or None."""
+    state = kwargs.get("state_pages")
+    return tuple(state[1].shape) if isinstance(state, tuple) else None
 
 
 def window_pool_shape(kwargs: dict):
@@ -312,6 +348,8 @@ def main(argv=None) -> int:
             pools = {"pool": pool_shape}
             if state_pool_shape(kwargs):
                 pools["state pool"] = state_pool_shape(kwargs)
+            if state_rows_shape(kwargs):
+                pools["state rows pool"] = state_rows_shape(kwargs)
             if window_pool_shape(kwargs):
                 pools["window pool"] = window_pool_shape(kwargs)
             for what, shape in pools.items():
