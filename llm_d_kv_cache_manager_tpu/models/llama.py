@@ -46,6 +46,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 import os
 from dataclasses import dataclass
 from typing import Any, Optional
@@ -547,10 +548,27 @@ class LlamaConfig:
     def kda_conv_row(self) -> int:
         """Values of one slot's carried convolution rows in one linear
         layer: the ``kda_conv_kernel - 1`` newest rows of ``[q | k | v]``
-        before the convolution, side by side (a ``[3, 12288]`` minor shape
-        would be padded to whole sublane tiles in HBM, as a convolution
-        layer's state would: ``init_state_pages``)."""
+        before the convolution, oldest first, side by side
+        (``kda_conv_tile``: how the state pool holds them)."""
         return max(self.kda_conv_kernel - 1, 0) * 3 * self.n_heads * self.kda_head_dim
+
+    @property
+    def kda_conv_tile(self) -> tuple[int, int]:
+        """The two minor axes a slot's ``kda_conv_row`` values take in the
+        state pool, so that a slot is whole tiles and one contiguous piece
+        of HBM: ``[row // lanes, lanes]`` with ``lanes`` the largest power of
+        two up to 128 that divides the row. At the published widths ``[288,
+        128]``, 18 whole bf16 tiles of ``(16, 128)`` and no padding, and the
+        TPU compiler makes ONE scatter fusion of a decode step's write of
+        384 slots. With the slots second-minor (``[.., slots, row]``) a slot
+        is one sublane of ``row / 128`` tiles it shares with fifteen other
+        slots, and the compiler writes a step's slots in a loop of
+        single-row updates that each rewrite all sixteen
+        (``tests/test_pool_layout.py``, ``TestTheStatePoolOfSlots``; the
+        times: PERF.md section 6, PR 48); a ``[3, 12288]`` minor shape would
+        pad three sublanes to sixteen."""
+        lanes = math.gcd(self.kda_conv_row, 128)
+        return self.kda_conv_row // lanes, lanes
 
     @property
     def kda_state_bytes(self) -> int:
@@ -1521,9 +1539,10 @@ def init_kda_state(
     """The zeroed state pool of a model with linear-attention layers, a
     pair every program takes and returns as ONE argument, ``state_pages``:
     the heads' matrices ``[linear layers, slots, n_heads, K, V]`` float32 and
-    the carried convolution rows ``[linear layers, slots, kda_conv_row]`` in
-    the model's dtype (the ``kda_conv_kernel - 1`` newest rows of ``[q | k |
-    v]`` before the convolution, oldest first, side by side). None for a
+    the carried convolution rows ``[linear layers, slots, *kda_conv_tile]``
+    in the model's dtype (the ``kda_conv_kernel - 1`` newest rows of ``[q | k
+    | v]`` before the convolution, oldest first, side by side: a slot's
+    ``kda_conv_row`` values as whole tiles that lie together). None for a
     model without such layers.
 
     A slot is no page's: ``server/block_manager.py``'s ``StatePool`` hands
@@ -1537,7 +1556,7 @@ def init_kda_state(
     return (
         jnp.zeros((cfg.n_kda_layers, slots, cfg.n_heads, hk, hk), jnp.float32,
                   device=sharding),
-        jnp.zeros((cfg.n_kda_layers, slots, cfg.kda_conv_row), cfg.dtype,
+        jnp.zeros((cfg.n_kda_layers, slots, *cfg.kda_conv_tile), cfg.dtype,
                   device=sharding),
     )
 
@@ -3321,7 +3340,8 @@ def _decode_body(
             elif "kda_qkv" in layer:
                 # one step of every lane's state where it lies in the pool
                 # (kernel ``kda_decode``); the carried rows go to the pool
-                # after the loop, in one update
+                # after the loop, every layer's in one scatter of whole slots
+                # (``kda_conv_tile``)
                 lk = len(fresh_krows)
                 q, k, v, g, beta, z = _kda_inputs(
                     layer, cfg, x,

@@ -175,6 +175,60 @@ def test_a_snapshot_is_left_as_it_was(params):
     assert not np.array_equal(state[0][:, 1], state[0][:, 2])
 
 
+# -- the carried rows' pool ------------------------------------------------------
+ROWS_SLOTS, ROWS_LANES = 7, 4
+ROWS_CASES = {
+    # (the slot a lane read, the slot it writes)
+    "keeps_its_slot": ([1, 2, 3, 4], [1, 2, 3, 4]),
+    # lanes 1 and 2 write another slot than they read: a snapshot is left
+    "another_slot": ([1, 2, 3, 4], [1, 5, 6, 4]),
+    # three padded lanes, all on the reserved slot
+    "padded": ([1, 0, 0, 0], [1, 0, 0, 0]),
+}
+
+
+@pytest.mark.parametrize("case", list(ROWS_CASES))
+@pytest.mark.parametrize("head_dim", [16, 32], ids=["row_of_576", "row_of_1152"])
+def test_a_slot_of_whole_tiles_holds_what_the_flat_row_held(case, head_dim):
+    """A decode step's write of the carried rows (``_scatter_slots`` over
+    the pool ``[layers, slots, *kda_conv_tile]``) against the flat
+    ``.at[].set`` over ``[layers * slots, row]`` it was until PR 48, bit for
+    bit, in every layer of a pool of three: a row that is whole 128-lane
+    tiles (9 x 128) and one that is not (the tiny preset's 576 values, 9 x
+    64). What a lane reads back is what it wrote, a tap a row, oldest
+    first."""
+    cfg = dataclasses.replace(
+        CFG, n_layers=3, layer_types=("linear_attention",) * 3,
+        kda_head_dim=head_dim)
+    row, taps = cfg.kda_conv_row, cfg.kda_conv_kernel
+    assert cfg.kda_conv_tile == (9, 64 if head_dim == 16 else 128)
+    empty = llama.init_kda_state(cfg, ROWS_SLOTS)[1]
+    assert empty.shape == (3, ROWS_SLOTS, *cfg.kda_conv_tile)
+    rng = np.random.default_rng(len(case) + head_dim)
+    pool = jnp.asarray(rng.standard_normal(empty.shape), empty.dtype)
+    fresh = jnp.asarray(rng.standard_normal((3, ROWS_LANES, row)), pool.dtype)
+    read, write = (np.asarray(x, np.int32) for x in ROWS_CASES[case])
+    got = np.asarray(llama._scatter_slots(
+        pool, fresh, jnp.asarray(write), jnp.ones(ROWS_LANES, bool)))
+    flat = pool.reshape(3 * ROWS_SLOTS, row)
+    idx = np.arange(3)[:, None] * ROWS_SLOTS + write[None, :]
+    want = np.asarray(
+        flat.at[idx.reshape(-1)].set(fresh.reshape(-1, row))
+    ).reshape(3, ROWS_SLOTS, row)
+    assert got.shape == pool.shape
+    # (slot 0 is written by every padded lane and read by nobody who cares)
+    assert np.array_equal(got.reshape(want.shape)[:, 1:], want[:, 1:])
+    # a slot nobody writes is as it was: what a lane read and left behind
+    untouched = np.setdiff1d(np.arange(ROWS_SLOTS), write)
+    assert set(read) - set(write) <= set(untouched)
+    assert np.array_equal(got[:, untouched], np.asarray(pool)[:, untouched])
+    back = np.asarray(llama._slot_rows(jnp.asarray(got), jnp.asarray(write)))
+    real = write > 0
+    assert np.array_equal(
+        back.reshape(3, ROWS_LANES, taps - 1, -1)[:, real],
+        np.asarray(fresh).reshape(3, ROWS_LANES, taps - 1, -1)[:, real])
+
+
 # -- the reference's recurrence -------------------------------------------------
 def test_the_reference_recurrence_is_the_gated_delta_rule():
     """With ``g`` equal over a head's channels the recurrence is the gated

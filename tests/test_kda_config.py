@@ -152,7 +152,9 @@ def test_the_pool_has_a_slot_a_row_and_the_snapshots(params):
     matrices, rows = engine.state_pages
     assert engine.block_manager.state.n_slots == 4 + 2 + 40
     assert matrices.shape == (4, 46, 4, 16, 16) and matrices.dtype == jnp.float32
-    assert rows.shape == (4, 46, 3 * 3 * 4 * 16)
+    # a slot's 3 x 3 x 4 x 16 = 576 carried values as whole lanes that lie
+    # together: 64 is the largest power of two up to 128 that divides them
+    assert rows.shape == (4, 46, 9, 64) and CFG.kda_conv_row == 576
     # a snapshot's bytes over the stride: what prefix caching costs a token
     assert engine.state_bytes_per_token == CFG.kda_state_bytes // 8
     assert CFG.kda_state_bytes == 4 * (4 * 16 * 16 * 4 + 3 * 192 * 4)
@@ -205,6 +207,8 @@ def test_presets():
     # a slot of the cut: 6 x (2 MiB of matrices + 72 KiB of carried rows)
     assert cut.kda_state_bytes == 6 * (2 * 2**20 + 72 * 2**10)
     assert cut.kda_state_bytes // 512 == 25440  # 24.8 KiB a token
+    # the carried rows of a slot: 18 whole bf16 tiles of (16, 128), no padding
+    assert cut.kda_conv_tile == (288, 128) and cut.kda_conv_row == 288 * 128
     assert hash(cut) != hash(big)
     assert not TINY_MLA_MOE.n_kda_layers and TINY_MLA_MOE.layer_group_size is None
     assert llama.init_kda_state(TINY_MLA_MOE, 4) is None

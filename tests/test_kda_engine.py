@@ -10,11 +10,14 @@ restore shows as a wrong token.
 import dataclasses
 
 import jax
+import jax.numpy as jnp
+import numpy as np
 import pytest
 
 import served_path
 from chipbench import reference as chip_reference
 from llm_d_kv_cache_manager_tpu.models import TINY_LING_HYBRID, llama
+from llm_d_kv_cache_manager_tpu.ops import kda
 from llm_d_kv_cache_manager_tpu.server import (
     BlockManagerConfig,
     SamplingParams,
@@ -24,7 +27,7 @@ from llm_d_kv_cache_manager_tpu.server.block_manager import (
     AllocationError,
     StatePool,
 )
-from served_path import make_engine, prompt_of, run_all
+from served_path import make_engine, prompt_of, rel_err, run_all
 
 #: one period of the preset's two (linear and dense, linear and routed, latent
 #: and routed): every kind of layer once and half the programs to compile. The
@@ -175,6 +178,76 @@ def test_a_snapshot_taken_in_decode_is_hit_later(params, burst):
     assert turn.output_tokens == picks(
         params, thread + [7, 7, 7], turn.output_tokens)
     assert stats(engine)["state_restores"] == 1
+
+
+def reference_state(params, tokens):
+    """Every linear layer's state after ``tokens`` by the plain reference
+    (float32, nothing of the program's model code but the recurrence token by
+    token, ``kda_recurrent``): (the heads' matrices ``[H, K, K]``, the carried
+    rows ``[taps - 1, 3 H K]``: the newest inputs of the plain convolution)
+    a layer."""
+    f32, s = jnp.float32, len(tokens)
+    H, K = CFG.n_heads, CFG.kda_head_dim
+    layer_forward = chip_reference._layer_fn(CFG, REF._ffn, REF._mixer)
+    states = []
+    with jax.default_matmul_precision("highest"):
+        h = params["embed"][jnp.asarray(tokens)].astype(f32)
+        for layer in params["layers"]:
+            if "kda_qkv" in layer:
+                x = chip_reference._rms(
+                    h, layer["attn_norm"].astype(f32), CFG.rms_norm_eps)
+                z = x @ layer["kda_qkv"].astype(f32)
+                taps = layer["kda_conv_w"].astype(f32)
+                n = taps.shape[0]
+                zp = jnp.concatenate([jnp.zeros((n - 1, z.shape[1]), f32), z])
+                conv = jax.nn.silu(sum(taps[j] * zp[j: j + s] for j in range(n)))
+                q, k, v = (t.reshape(1, s, H, K) for t in jnp.split(conv, 3, -1))
+                beta = jax.nn.sigmoid(x @ layer["kda_wb"].astype(f32))
+                _, S = kda.kda_recurrent(
+                    REF._l2(q) / np.sqrt(K), REF._l2(k), v,
+                    REF.gate(layer, CFG, x)[None], beta[None],
+                    jnp.zeros((1, H, K, K), f32))
+                states.append((np.asarray(S[0]), np.asarray(zp[s:])))
+            h, _ = layer_forward(layer, h)
+    return states
+
+
+def test_a_burst_that_passes_a_boundary_leaves_the_state_at_it(params):
+    """A burst of four decodes positions 13 to 16 and so passes the boundary
+    at 16: the token AT it reads slot a and writes slot b, the matrices by
+    ``kda_decode`` and every layer's carried rows by the one scatter after
+    the layer loop. Slot a is the snapshot: it holds the token-by-token
+    reference's state after 16 tokens, a later turn restores from it and
+    generates what an engine that never saw the thread generates, and the
+    restore leaves it as it was."""
+    engine = engine_of(params, decode_steps_per_iter=4)
+    first = prompt_of(21, 13)
+    (seq,) = run_all(engine, [first], 14)
+    thread = first + seq.output_tokens
+    bm, st = engine.block_manager, engine.block_manager.state
+    slot = st.lookup(bm.token_db.prefix_hashes(thread)[16 // PS - 1])
+    assert slot is not None
+
+    def snapshot():
+        return [np.asarray(pool[:, slot]) for pool in engine.state_pages]
+
+    matrices, rows = before = snapshot()
+    want = reference_state(params, thread[:16])
+    assert len(want) == CFG.n_kda_layers == 2
+    for layer, (want_S, want_rows) in enumerate(want):
+        assert rel_err(matrices[layer], want_S) < 1e-4
+        assert rel_err(rows[layer].reshape(want_rows.shape), want_rows) < 1e-4
+    # a turn on the thread so far: four pages hit, the state restored at 16
+    ask = thread[:19] + [7, 7, 7]
+    (turn,) = run_all(engine, [ask], 9)
+    assert turn.num_cached_prompt == 16
+    assert stats(engine)["state_restores"] == 1
+    (alone,) = run_all(engine_of(params, decode_steps_per_iter=4), [ask], 9)
+    assert alone.num_cached_prompt == 0
+    assert turn.output_tokens == alone.output_tokens
+    assert turn.output_tokens == picks(params, ask, turn.output_tokens)
+    assert all(np.array_equal(a, b) for a, b in zip(before, snapshot()))
+    pool_is_whole(engine)
 
 
 def test_a_page_takes_its_snapshot_with_it(params):

@@ -625,8 +625,14 @@ class TestTheStatePoolOfSlots:
     lanes' matrices where they lie in the pool (``kda_decode``,
     ``input_output_aliases``: its custom call's result is the pool and the
     one thing that may produce it), the carried rows and a prefill's final
-    states go in by one flat scatter each, and nothing copies either pool or
-    the latent pool beside them."""
+    states go in by ONE scatter fusion each, and nothing copies either pool
+    or the latent pool beside them. A slot of the rows' pool is whole tiles
+    that lie together (``[.., 288, 128]``, ``LlamaConfig.kda_conv_tile``):
+    until PR 48 the pool was ``[.., slots, 36864]``, a slot one sublane of
+    288 tiles, and the compiler wrote a step's rows in a ``while`` of one
+    ``dynamic-update-slice`` a (layer, lane), a fifth of the decode step
+    (PERF_LEDGER.jsonl, PR 47: ``dynamic-update-slice_bf16_2736_36864_``;
+    PERF.md section 6, PR 48)."""
 
     @pytest.mark.parametrize("program", ["decode_steps", "prefill"])
     def test_the_pools_are_updated_where_they_lie(self, topo, program):
@@ -638,7 +644,7 @@ class TestTheStatePoolOfSlots:
         hlo = aot_pool_copies.compile_text(fn, *args, **kwargs)
         matrices = aot_pool_copies.state_pool_shape(kwargs)
         rows = aot_pool_copies.state_rows_shape(kwargs)
-        assert matrices == (1, 456, 32, 128, 128) and rows == (1, 456, 36864)
+        assert matrices == (1, 456, 32, 128, 128) and rows == (1, 456, 288, 128)
         for shape in (pool_shape, matrices, rows):
             assert aot_pool_copies.pool_instructions(hlo, shape)
             assert _whole_pool_moves(hlo, shape) == []
@@ -651,6 +657,19 @@ class TestTheStatePoolOfSlots:
         assert len(kernels) == (program == "decode_steps")
         assert all(i.name.startswith("kda_decode") and not i.moves_bytes
                    for i in kernels)
+        # what writes the rows' pool, or a layer of it: one fusion around a
+        # scatter, in no loop's body, and no single-row update anywhere
+        inside = aot_pool_copies.fusion_opcodes(hlo)
+        writes = [
+            i for i in
+            aot_pool_copies.pool_instructions(hlo, rows, layer_slices=True)
+            if {"scatter", "dynamic-update-slice"}
+            & ({i.opcode} | inside.get(i.name, set()))
+        ]
+        assert [i.opcode for i in writes] == ["fusion"], writes
+        assert "scatter" in inside[writes[0].name]
+        assert writes[0].computation not in re.findall(
+            r"\bbody=%?([\w.\-]+)", hlo)
 
 
 class TestTheReader:

@@ -13,8 +13,9 @@ beside them (``[conv layers, P, row]``, ``state_pool_shape``), a model with
 sliding-window layers a pair of window pools (``[sliding layers, window
 pages, page, n_kv, hd]``, ``window_pool_shape``), a model with
 linear-attention layers a state pool of slots (``[linear layers, slots,
-heads, K, V]`` float32 and the carried rows ``[linear layers, slots, row]``,
-``state_pool_shape`` / ``state_rows_shape``): they are listed the same way.
+heads, K, V]`` float32 and the carried rows ``[linear layers, slots, R, C]``,
+a slot whole tiles that lie together: ``state_pool_shape`` /
+``state_rows_shape``): they are listed the same way.
 
 A pool is hundreds of MiB: any such instruction that is not free (a
 ``bitcast``, a ``parameter``, tuple plumbing) reads and writes that much
@@ -312,7 +313,8 @@ def state_pool_shape(kwargs: dict):
 
 def state_rows_shape(kwargs: dict):
     """The shape of the carried rows' pool of a model with linear-attention
-    layers among a served program's keyword arguments, or None."""
+    layers among a served program's keyword arguments (``[linear layers,
+    slots, *LlamaConfig.kda_conv_tile]``), or None."""
     state = kwargs.get("state_pages")
     return tuple(state[1].shape) if isinstance(state, tuple) else None
 
