@@ -40,37 +40,84 @@ from jax.experimental.pallas import tpu as pltpu
 from ..models.quant import QuantizedTensor
 from ._mosaic import require_tpu_unless_interpret
 
-#: (tm, tk, tn) tile-size ceilings, from a sweep on a rig that is gone, at
-#: Qwen3-30B geometry (128 experts, d=2048, f=768, 16k rows); it has no TPU
-#: v5e measurement (ROADMAP.md S5). The reasoning then: 256-row tiles balance
-#: boundary-visit waste (visits ≈ max(m_tiles, nonempty groups) whatever
-#: tm is) against MXU pipeline depth, and large tk/tn cut grid steps
-#: (it beat (256,512,512) and (512,512,512) there). Tiles stay well
-#: under VMEM (rhs tile 1.5 MB bf16).
-DEFAULT_TILING = (256, 1024, 768)
+# -- the tiles, chosen for the TPU v5e from what a call can see -----------
+#
+# Both kernels walk (row tile, group) pairs: a visit multiplies the WHOLE
+# ``tm``-row tile by one expert's ``[tk, tn]`` tiles and stores that group's
+# rows alone, and there are ``row tiles + touched groups - 1`` visits
+# whatever ``tm`` is. So ``tm`` sets a visit's FLOPs and ``(tk, tn)`` the
+# grid steps its matrix is streamed in. Measured on one v5e chip at the
+# seven sparse cells' shapes (PERF.md section 6, PR 49).
+
+#: Rows of a v5e MXU (128 x 128). A taller row tile buys no utilisation
+#: there, it only multiplies the rows a visit masks away: a decode call has a
+#: handful of real rows an expert.
+MXU_ROWS = 128
+
+#: What a visit's tiles may take of the 16 MiB a Pallas call's scoped VMEM is
+#: on a v5e unless the call asks for more (megablox's ``gmm`` cannot). The
+#: compiler's own count is ``tile_bytes`` plus up to 0.3 MiB.
+VMEM_TILE_BUDGET = 15 * 2**20
+
+#: The widest dimension that may run as one unaligned full-width tile.
+UNALIGNED_DIM_MAX = 1024
 
 
-def _round8(m: int) -> int:
-    return -(-m // 8) * 8
-
-
-def _divisor_tile(dim: int, cap: int) -> int:
-    """Largest lane-aligned tile <= cap that divides ``dim`` exactly (the
-    kernels skip remainder-tile masking). A dim with no such divisor runs
-    as ONE full-width tile — fine for small (tiny-test) geometries, but a
-    LARGE unaligned dim would silently blow VMEM with no pointer at the
-    cause, so that case fails loudly instead."""
+def _tile_choices(dim: int) -> list[int]:
+    """The lane-aligned tiles that divide ``dim`` exactly (the kernels skip
+    remainder-tile masking). A dim with no such divisor runs as ONE
+    full-width tile — fine for small (tiny-test) geometries, but a LARGE
+    unaligned dim would silently blow VMEM with no pointer at the cause, so
+    that case fails loudly instead."""
     if dim % 128 == 0:
-        for t in range(min(cap, dim), 127, -128):
-            if dim % t == 0:
-                return t
-    if dim > cap:
+        return [t for t in range(128, dim + 1, 128) if dim % t == 0]
+    if dim > UNALIGNED_DIM_MAX:
         raise ValueError(
             f"gmm kernel tiling: dim {dim} is not 128-aligned and exceeds "
-            f"the tile cap {cap} (a full-width tile would exhaust VMEM); "
+            f"{UNALIGNED_DIM_MAX} (a full-width tile would exhaust VMEM); "
             "use moe_gmm='xla' (ragged_dot) for this geometry"
         )
-    return dim
+    return [dim]
+
+
+def tile_bytes(
+    tm: int, tk: int, tn: int, lhs_itemsize: int, rhs_itemsize: int
+) -> int:
+    """VMEM a visit holds: the weight tile and the ``lhs`` tile double
+    buffered, the float32 output tile double buffered and the float32
+    accumulator (the int8 kernel's conversion to float32 is not held: the
+    compiler counts the same for it)."""
+    return (
+        2 * tk * tn * rhs_itemsize
+        + 2 * tm * tk * lhs_itemsize
+        + 3 * tm * tn * 4
+    )
+
+
+def gmm_tiling(
+    rows: int, d: int, f: int, lhs_itemsize: int, rhs_itemsize: int
+) -> tuple[int, int, int]:
+    """``(tm, tk, tn)`` of a ``[rows, d] x [E, d, f]`` call on a TPU v5e.
+
+    ``tm``: the rows cut evenly into the fewest tiles no taller than the
+    MXU, in whole bf16 sublane packs of 16 (192 rows are two tiles of 96 and
+    no padding). ``tk``: the whole contraction wherever a 128-wide tile of
+    it fits, so a visit has no accumulation steps and consecutive visits of
+    one group find its tile where it is; ``tn``: the widest that then fits
+    ``VMEM_TILE_BUDGET``. One whole expert matrix a visit where that fits
+    (``[2048, 768]``, ``[2560, 768]``), ``[2048, 896]`` of ``[2048, 1792]``,
+    ``[3072, 1024]`` of ``[3072, 3072]``, ``[6144, 256]`` of ``[6144, 2048]``."""
+    row_tiles = -(-rows // MXU_ROWS)
+    tm = -(-rows // (16 * row_tiles)) * 16
+    fits = [
+        (tk, tn)
+        for tk in _tile_choices(d)
+        for tn in _tile_choices(f)
+        if tile_bytes(tm, tk, tn, lhs_itemsize, rhs_itemsize)
+        <= VMEM_TILE_BUDGET
+    ]
+    tk, tn = max(fits)
+    return tm, tk, tn
 
 
 def grouped_matmul(
@@ -121,9 +168,9 @@ def _gmm_library(lhs, rhs, group_sizes, *, interpret: bool):
     from jax.experimental.pallas.ops.tpu.megablox import gmm as mb_gmm
 
     rows, d = lhs.shape
-    f = rhs.shape[2]
-    tm, tk, tn = DEFAULT_TILING
-    tm = min(tm, max(_round8(rows), 8))
+    tm, tk, tn = gmm_tiling(
+        rows, d, rhs.shape[2], lhs.dtype.itemsize, rhs.dtype.itemsize
+    )
     # megablox requires m % tm == 0: pad rows (beyond every group — the
     # pad region's output is garbage and sliced off).
     pad = (-rows) % tm
@@ -134,7 +181,7 @@ def _gmm_library(lhs, rhs, group_sizes, *, interpret: bool):
         rhs,
         group_sizes.astype(jnp.int32),
         preferred_element_type=jnp.float32,
-        tiling=(tm, _divisor_tile(d, tk), _divisor_tile(f, tn)),
+        tiling=(tm, tk, tn),
         interpret=interpret,
     )
     return out[:rows].astype(lhs.dtype)
@@ -195,9 +242,7 @@ def _gmm_int8(lhs, q, group_sizes, *, interpret: bool):
 
     rows, d = lhs.shape
     n_groups, _, f = q.shape
-    tm = min(DEFAULT_TILING[0], max(_round8(rows), 8))
-    tk = _divisor_tile(d, DEFAULT_TILING[1])
-    tn = _divisor_tile(f, DEFAULT_TILING[2])
+    tm, tk, tn = gmm_tiling(rows, d, f, lhs.dtype.itemsize, q.dtype.itemsize)
     tiles_k = d // tk
     tiles_n = f // tn
 

@@ -15,7 +15,78 @@ import jax.numpy as jnp
 from llm_d_kv_cache_manager_tpu.models import TINY_MOE, init_params
 from llm_d_kv_cache_manager_tpu.models import llama
 from llm_d_kv_cache_manager_tpu.models.quant import quantize_tensor
-from llm_d_kv_cache_manager_tpu.ops.gmm import grouped_matmul
+from llm_d_kv_cache_manager_tpu.ops import gmm
+from llm_d_kv_cache_manager_tpu.ops.gmm import gmm_tiling, grouped_matmul
+from tools.aot_pool_copies import routed_decode_calls
+
+#: sizes whose groups lie across the borders of the row tiles the rule picks
+#: for their 320 rows (three of 112) and for 600 rows (five of 128)
+STRADDLING = [100, 60, 0, 90, 50, 0, 10, 10]
+
+
+class TestTheTileRule:
+    @pytest.mark.parametrize("rhs_itemsize", [2, 1], ids=["bf16", "int8"])
+    @pytest.mark.parametrize(
+        "rows, d, f",
+        [  # the cells' decode calls, from chipbench/configs
+            pytest.param(rows, d, f, id=name)
+            for name, rows, _, d, f in routed_decode_calls()
+        ]
+        + [  # a prefill dispatch's calls: 1 024 to ROUTED_ROW_BLOCK rows
+            pytest.param(1024, 2048, 768, id="prefill-1024"),
+            pytest.param(llama.ROUTED_ROW_BLOCK, 6144, 2048, id="prefill-block"),
+        ],
+    )
+    def test_the_served_shapes_tiles(self, rows, d, f, rhs_itemsize):
+        tm, tk, tn = gmm_tiling(rows, d, f, 2, rhs_itemsize)
+        assert d % tk == 0 and f % tn == 0
+        assert tk % 128 == 0 and tn % 128 == 0
+        assert tm % 16 == 0 and tm <= gmm.MXU_ROWS  # bf16 packs 16 rows a tile
+        held = (
+            2 * tk * tn * rhs_itemsize  # the weight tile, double buffered
+            + 2 * tm * tk * 2  # the lhs tile
+            + 2 * tm * tn * 4  # the float32 output tile
+            + tm * tn * 4  # the accumulator
+        )
+        assert held == gmm.tile_bytes(tm, tk, tn, 2, rhs_itemsize)
+        assert held <= gmm.VMEM_TILE_BUDGET < 16 * 2**20
+        # the whole contraction, and columns as wide as the budget takes
+        assert tk == d
+        if f % (2 * tn) == 0:
+            assert (
+                gmm.tile_bytes(tm, tk, 2 * tn, 2, rhs_itemsize)
+                > gmm.VMEM_TILE_BUDGET
+            )
+
+    def test_the_cells_are_all_read(self):
+        assert len(routed_decode_calls()) >= 14  # seven sparse configurations
+
+    def test_a_small_unaligned_shape_is_one_tile(self):
+        assert gmm_tiling(36, 200, 72, 4, 4) == (48, 200, 72)
+
+    @pytest.mark.parametrize("rows, tm", [
+        (8, 16), (128, 128), (192, 96), (512, 128), (600, 128), (768, 128),
+        (130, 80), (4096, 128),
+    ])
+    def test_the_rows_are_cut_evenly(self, rows, tm):
+        assert gmm_tiling(rows, 256, 384, 2, 2)[0] == tm
+
+    @pytest.mark.parametrize("d, f", [(2000, 768), (768, 1100)])
+    def test_a_large_unaligned_dimension_is_refused(self, d, f):
+        with pytest.raises(ValueError, match="not 128-aligned"):
+            gmm_tiling(128, d, f, 2, 2)
+
+    def test_the_parity_cases_cross_a_row_tile(self):
+        """What the cases below rely on: at their small shape the rule's
+        row tile is shorter than the rows, and a group lies on each border
+        (of the 320 rows alone, and of the 600 of which they are the real)."""
+        ends = np.cumsum(STRADDLING)
+        starts = ends - STRADDLING
+        for rows in (sum(STRADDLING), 600):
+            tm, tk, tn = gmm_tiling(rows, 256, 384, 2, 2)
+            assert (tk, tn) == (256, 384) and 2 * tm < sum(STRADDLING)
+            for border in (tm, 2 * tm):
+                assert any(s < border < e for s, e in zip(starts, ends))
 
 
 def _problem(rng, E, d, f, sizes, dtype=jnp.bfloat16):
@@ -36,6 +107,7 @@ class TestGroupedMatmul:
             [0, 0, 128, 0, 0, 0, 0, 128],  # mostly empty
             [32] * 8,  # uniform
             [1, 2, 3, 4, 5, 6, 7, 8],  # tiny groups, rows % 8 != 0
+            STRADDLING,  # three row tiles, a group across each border
         ],
     )
     def test_bf16_kernel_matches_ragged_dot(self, sizes):
@@ -45,9 +117,11 @@ class TestGroupedMatmul:
         out = grouped_matmul(lhs, w, gs, interpret=True).astype(jnp.float32)
         np.testing.assert_allclose(np.asarray(out), np.asarray(oracle), atol=2e-2)
 
-    def test_int8_kernel_matches_dequant_oracle(self):
+    @pytest.mark.parametrize(
+        "sizes", [[40, 0, 25, 60, 10, 30, 20, 15], STRADDLING]
+    )
+    def test_int8_kernel_matches_dequant_oracle(self, sizes):
         rng = np.random.default_rng(2)
-        sizes = [40, 0, 25, 60, 10, 30, 20, 15]
         lhs, w, gs, rgi = _problem(rng, 8, 256, 384, sizes)
         qw = quantize_tensor(w)
         oracle = grouped_matmul(
@@ -69,6 +143,7 @@ class TestGroupedMatmul:
             [40, 0, 25, 60, 10, 30, 20, 15],  # 200 of 600 rows, tiles unvisited
             [0, 0, 3, 0, 0, 0, 0, 0],  # three real rows
             [0] * 8,  # no real row: no tile is visited
+            STRADDLING,  # 320 of 600 rows: the third tile part real
         ],
     )
     def test_group_sizes_may_sum_to_fewer_than_the_rows(self, kernel, sizes):

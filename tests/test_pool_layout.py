@@ -519,6 +519,45 @@ class TestServedPrograms:
         assert aot_pool_copies.served_program("sdar-30b-a3b", "decode_steps", one_chip) is None
 
 
+class TestTheGroupedMatmulsTiles:
+    """``ops/gmm.py::gmm_tiling`` sizes a visit's tiles against the 16 MiB of
+    scoped VMEM a Pallas call has on a v5e by its own arithmetic; the
+    compiler's count is the one that refuses a program. Both kernels at the
+    decode shapes of the seven sparse cells, under a second each."""
+
+    @pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
+    @pytest.mark.parametrize("rows, experts, d, f", [
+        pytest.param(*call[1:], id=call[0])
+        for call in aot_pool_copies.routed_decode_calls()
+    ])
+    def test_the_rules_tiles_compile_for_the_chip(
+        self, topo, rows, experts, d, f, int8
+    ):
+        from llm_d_kv_cache_manager_tpu.models.quant import QuantizedTensor
+        from llm_d_kv_cache_manager_tpu.ops.gmm import grouped_matmul
+
+        one_chip = SingleDeviceSharding(topo.devices[0])
+
+        def S(shape, dtype):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+        def call(lhs, w, scale, sizes, ids):
+            rhs = QuantizedTensor(w, scale) if int8 else w
+            return grouped_matmul(lhs, rhs, sizes, row_group_ids=ids)
+
+        hlo = aot_pool_copies.compile_text(
+            jax.jit(call),
+            S((rows, d), jnp.bfloat16),
+            S((experts, d, f), jnp.int8 if int8 else jnp.bfloat16),
+            S((experts, 1, f), jnp.float32),
+            S((experts,), jnp.int32),
+            S((rows,), jnp.int32),
+        )
+        assert "tpu_custom_call" in hlo
+        if not int8:  # the name the benchmark's readers find the kernel by
+            assert re.search(r"%gmm[.\d]* = f32\[", hlo)
+
+
 class TestThePrefillLoopReadsThePools:
     """``prefill_packed`` holds its forward for one row, in a loop over the
     rows that hold a sequence; the loop reads the pools and the one write

@@ -210,6 +210,31 @@ def compile_text(fn, *args, **kwargs) -> str:
             m.require_tpu_unless_interpret = guard
 
 
+def routed_decode_calls() -> list[tuple[str, int, int, int, int]]:
+    """(name, rows, experts held, d, f) of every grouped matmul the cells'
+    decode programs make, from ``chipbench/configs``: rows = lanes x top-k
+    (x the block of a block-diffusion model), gate/up ``[d, f]`` and down
+    ``[f, d]`` of each configuration with routed layers."""
+    from llm_d_kv_cache_manager_tpu.models import llama
+
+    calls = []
+    for path in sorted(CONFIGS.glob("*.json")):
+        spec = json.loads(path.read_text())["chipbench"]
+        cfg = dataclasses.replace(getattr(llama, spec["preset"]), **spec["replace"])
+        if not cfg.n_experts:
+            continue  # a dense model has no routed layer
+        rows = (
+            spec["env"]["DECODE_BATCH_SIZE"] * cfg.n_experts_per_tok
+            * max(cfg.block_length, 1)
+        )
+        d, f = cfg.hidden_size, cfg.moe_intermediate_size
+        calls += [
+            (f"{path.stem}-up", rows, cfg.experts_held, d, f),
+            (f"{path.stem}-down", rows, cfg.experts_held, f, d),
+        ]
+    return calls
+
+
 def served_program(config: str, program: str, one_chip, **replace):
     """(jitted function, args, kwargs, pool shape) of a configuration's
     served ``program`` at the shapes its cell pins, or None where the
