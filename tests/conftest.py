@@ -100,7 +100,7 @@ def pytest_xdist_make_scheduler(config, log):
     """A FILE is the unit a worker is dealt, whatever ``--dist`` says. The
     suite is laid out for that: a file's cases share module fixtures (a
     parameter tree, a built engine) and the programs the first of them
-    compiled in its process, and ``_LONGEST_FIRST`` sorts whole files. Under
+    compiled in its process, and ``longest_first`` sorts whole files. Under
     ``--dist load`` (the driver's command since PR 47) xdist deals the
     collection out in runs of consecutive tests, a 24th of it to each worker
     at the start: the first worker got the five longest files in one piece
@@ -120,7 +120,7 @@ def pytest_configure(config):
     # ``--dist loadfile`` deals files out most tests first unless told not
     # to, and a file of few tests is a long one here (a cell's rehearsals, an
     # engine's cases): they came last and one worker ran the run's tail
-    # alone. Collection order it is, and ``_LONGEST_FIRST`` sets that.
+    # alone. Collection order it is, and ``longest_first`` sets that.
     if hasattr(config.option, "loadscopereorder"):
         config.option.loadscopereorder = False
     # Lock-order/race harness: LOCKTRACE=1 routes every lock created from
@@ -157,7 +157,7 @@ _SLOW_CLASSES = {
     # 5 tests, 32 s. Covered: test_chunked_prefill.py::TestChunkedPrefillParity
     ("test_chunked_prefill.py", "TestChunkedInterference"),
     # 5 tests, 79 s (seeded). Covered for fixed cases:
-    # test_run_ahead.py::test_greedy_parity_with_the_engine_that_waits
+    # test_run_ahead_parity.py::test_greedy_parity_with_the_engine_that_waits
     ("test_engine.py", "TestDecodePathParityFuzz"),
     # 8 tests, 49 s. Covered for the expert-parallel dispatch: test_quant.py::
     # TestQuantizedSharding::test_quantized_moe_with_expert_parallel_dispatch;
@@ -192,62 +192,57 @@ _SLOW_CLASSES = {
     # 4 tests, 24 s. Covered: none (parallel/checkpoint.py)
     ("test_checkpoint.py", "TestCheckpoint"),
     # 15 tests, 291 s (a whole served program a case, 10-41 s each). Covered
-    # at the fewest layers that keep every kind: test_pool_layout.py::
-    # TestThePrefillLoopReadsThePools::test_the_loop_copies_no_pool
+    # at the fewest layers that keep every kind of pool:
+    # test_pool_layout.py::TestThePrefillLoopReadsThePools
     ("test_pool_layout.py", "TestServedPrograms"),
 }
 
 
-#: The files over a minute of the last whole tier-1 run, longest first
-#: (cpu-seconds a file: ``python tests/junit_costs.py <junit file> --over
-#: 60``; PR 46's run on six workers). They are collected in this order and
-#: every other file after them, so a worker's last file is a short one: the
-#: run ends some 5 % over the cpu-seconds a worker where it ended 40 % over. A
-#: stale list costs balance, nothing else; a file that is not here sorts
-#: where it always did.
-_LONGEST_FIRST = (
-    "chipbench_tests/test_swa_cell.py",  # 552
-    "chipbench_tests/test_hybrid_cell.py",  # 327
-    "chipbench_tests/test_scmoe_cell.py",  # 302
-    "chipbench_tests/test_kda_cell.py",  # 230
-    "test_kda.py",  # 150
-    "chipbench_tests/test_chipbench.py",  # 165
-    "chipbench_tests/test_latent_cell.py",  # 155
-    "test_swa_engine_decode.py",  # 134
-    "test_conv_state_engine.py",  # 133
-    "test_kda_engine.py",  # 130
-    "test_moe_padding.py",  # 125
-    "test_mla.py",  # 125
-    "test_swa_rows.py",  # 122
-    "test_paged_attention_window_steps.py",  # 120
-    "test_conv_state.py",  # 114
-    "test_engine.py",  # 107
-    "test_paged_attention_window.py",  # 104
-    "test_block_diffusion_engine.py",  # 100
-    "test_scmoe.py",  # 98
-    "test_chip_smoke_dry_run.py",  # 98
-    "test_swa.py",  # 97
-    "test_flash_prefill.py",  # 96
-    "test_block_diffusion.py",  # 95
-    "test_packed_inputs.py",  # 95
-    "test_swa_kernels.py",  # 94
-    "test_swa_harness.py",  # 88
-    "test_pool_layout.py",  # 75
-    "test_mla_engine.py",  # 73
-    "test_run_ahead.py",  # 65
-    "test_packed_inputs_engine.py",  # 65
-    "test_swa_engine.py",  # 63
-    "chipbench_tests/test_block_diffusion_cell.py",  # 63
-)
+#: What each file cost the last whole tier-1 run this tree records, longest
+#: first: the output of ``python tests/junit_costs.py <junit file>``, which
+#: writes it (``docs/development.md``, "The tests").
+_RECORDED_COSTS = os.path.join(
+    os.path.dirname(os.path.abspath(__file__)), "junit_costs.txt")
+
+
+def recorded_order(path: str = _RECORDED_COSTS) -> list[str] | None:
+    """The files of a recorded run (relative to ``tests/``), longest first;
+    None where there is no such record or it cannot be read."""
+    try:
+        with open(path) as f:
+            rows = [line.split() for line in f]
+    except (OSError, UnicodeDecodeError):
+        return None
+    return [row[2] for row in rows if len(row) == 3 and row[2].endswith(".py")]
+
+
+def longest_first(items: list, recorded: list[str] | None, file_of=str) -> list:
+    """``items`` (files, or tests with ``file_of`` naming each one's file) in
+    the order they are to be collected: what is of a file the record does not
+    name FIRST (a new file's cost is not known, and a long one may not hide at
+    the run's tail, where one worker would run it alone), then the recorded
+    files longest first, so that a worker's last file is a short one. Stable: a
+    file stays together and as it was. No record: as they were."""
+    if recorded is None:
+        return list(items)
+    rank = {name: i for i, name in enumerate(recorded)}
+    return sorted(items, key=lambda item: rank.get(file_of(item), -1))
 
 
 #: per-test wall-clock cap (seconds): a deadlocked drain/abort test fails
 #: with a dump of every thread's stack and the run goes on, where it would
 #: take the rest of the tier-1 clock with it. ``pytest-timeout`` applies it
 #: where it is installed (it is not here), ``_per_test_cap`` below where not.
-#: Twice the slowest test of the driver's run at PR 45 (302 s,
-#: ``test_swa_cell.py::test_the_probes_controls_each_read_not_correct``): no
-#: test that passes may fail by it.
+#: What holds it up is the benchmark's directory: ``tests/chipbench_tests/
+#: test_swa_cell.py::test_the_probes_controls_each_read_not_correct`` took
+#: 488 s of PR 50's whole run of its parent (302 s at PR 45) and no test that
+#: passes may fail by the cap. Outside that directory the slowest tests after
+#: PR 50 are the two halves of ``chip_smoke.py --dry-run``
+#: (``tests/test_chip_smoke_dry_run_kernels.py`` 47-83 s and
+#: ``tests/test_chip_smoke_dry_run.py`` 59-99 s of a whole run; 165 s as the
+#: one test they were), then ``tests/test_swa.py::test_the_windows_edge``,
+#: 53-62 s: once a ``benchmark`` PR has split the cells' files (ROADMAP D9
+#: (m)) the cap can come down to twice the slowest test that is left.
 _PER_TEST_TIMEOUT_S = 600
 
 
@@ -311,9 +306,9 @@ def _locktrace_gate():
 
 def pytest_collection_modifyitems(config, items):
     tests_dir = os.path.dirname(os.path.abspath(__file__))
-    rank = {name: i for i, name in enumerate(_LONGEST_FIRST)}
-    items.sort(key=lambda item: rank.get(  # stable: a file stays together
-        os.path.relpath(str(item.fspath), tests_dir), len(rank)))
+    items[:] = longest_first(
+        items, recorded_order(),
+        lambda item: os.path.relpath(str(item.fspath), tests_dir))
     have_timeout = config.pluginmanager.hasplugin("timeout")
     for item in items:
         if have_timeout and item.get_closest_marker("timeout") is None:

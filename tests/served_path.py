@@ -8,12 +8,44 @@ from each other.
 What is an architecture's own (its preset, its reference, its tolerance, the
 cases that are its mechanism's) stays in its files; so does the second pool it
 lays beside the keys and values where one file drives it (the state pool of
-``tests/test_conv_state.py``), and the window pool is here because two do.
+``tests/test_conv_state.py``); the window pool and the state pool of slots
+are here because two files drive each.
+
+**One set of compiled programs a process.** A test here costs what it is the
+first in its worker to compile (a tiny model's ``decode_steps`` is 5 s, a
+prefill 2-4 s, a reference's layer 0.4 s a length), so everything that decides
+a program is asked for in as few forms as the cases allow, and whatever was
+built is kept for the process:
+
+- the served programs are ``llama``'s module-level jitted functions, so JAX
+  keeps one program a (configuration, shapes) whoever calls, for the life of
+  the process: ``served`` pads rows, chunk, context, tables and pool to the
+  buckets below, and an engine file asks ``make_engine`` for as few (pool,
+  lanes, burst) shapes as its cases allow (a roomy pool on four lanes, a
+  tight one on two): a case gets a preemption, an eviction or a full set of
+  lanes from the traffic it sends, and says why where it keeps a size of its
+  own. Where depth is not the point the file runs the fewest layers that keep
+  every kind of layer: a program's cost is its layers';
+- the parameters of a (configuration, seed) are built once (``params_of``);
+- a reference's jitted layer is kept a (configuration, operators), where
+  ``chipbench/references`` build it anew in every ``forward``, and a sequence
+  is padded to whole ``REFERENCE_BUCKET`` tokens (``reference_logits``).
 """
 
+import contextlib
+import dataclasses
+import functools
+
+import jax
 import numpy as np
 
-from llm_d_kv_cache_manager_tpu.models import llama
+from chipbench import reference as chip_reference
+from llm_d_kv_cache_manager_tpu.models import (
+    TINY_LFM2_MOE,
+    TINY_MLA_MOE,
+    TINY_SWA_MOE,
+    llama,
+)
 from llm_d_kv_cache_manager_tpu.server import (
     EngineConfig,
     SamplingParams,
@@ -33,6 +65,31 @@ CHUNK_BUCKET = 16
 CTX_BUCKET = 4
 TABLE_BUCKET = 16
 POOL_BUCKET = 32
+#: ... and the rows of a call to whole ``ROW_BUCKET`` rows, as the engine pads a
+#: dispatch to its lanes: a row alone and a batch of three are ONE prefill and
+#: ONE decode program a (chunk, context) shape. A row that pads is a sequence
+#: of one token, cold, in pages of its own: every second pool treats it as it
+#: treats any row, and nothing of it is returned.
+ROW_BUCKET = 4
+
+
+#: The deeper tiny presets at ONE OF EACH KIND OF LAYER, for the files whose
+#: cases are not about depth (an engine's scheduling, pools and counters, how a
+#: dispatch packs its inputs, a window's edge): the block manager, the
+#: scheduler and the engine's pools do not see the depth, and a program costs
+#: what its layers cost to compile. The files against the reference
+#: (``tests/test_swa.py`` and its like) run the whole presets.
+#: a sliding layer over the dense FFN and a full one over the routed (of five)
+ONE_OF_EACH_SWA = dataclasses.replace(
+    TINY_SWA_MOE, n_layers=2,
+    layer_types=("sliding_attention", "full_attention"))
+#: a convolution over the dense FFN, an attention and a convolution over the
+#: routed (of eight)
+ONE_OF_EACH_LFM2 = dataclasses.replace(
+    TINY_LFM2_MOE, n_layers=3, first_k_dense=1,
+    layer_types=("conv", "full_attention", "conv"))
+#: the leading dense layer and one routed layer (of four)
+ONE_OF_EACH_MLA = dataclasses.replace(TINY_MLA_MOE, n_layers=2)
 
 
 def round_up(n: int, bucket: int) -> int:
@@ -47,10 +104,57 @@ def rel_err(got, want) -> float:
     return float(np.abs(got - want).max() / np.abs(want).max())
 
 
+@functools.lru_cache(maxsize=None)
+def params_of(cfg, seed: int):
+    """``llama.init_params`` of a (configuration, seed), once a process:
+    files of one architecture that land on one worker share the tree."""
+    return llama.init_params(jax.random.PRNGKey(seed), cfg)
+
+
+#: ``reference_logits`` pads a sequence to whole ``REFERENCE_BUCKET`` tokens:
+#: the references are causal (a token sees nothing behind it), so the rows
+#: compared are what they were, and the lengths of a file's cases are a
+#: handful of compiled programs where they were one each.
+REFERENCE_BUCKET = 32
+
+
+_KEPT_LAYER_FNS = {}
+
+
+@contextlib.contextmanager
+def kept_layer_programs(*modules):
+    """``_layer_fn`` of a reference module returns a NEW jitted function
+    every call, and ``forward`` calls it once a sequence: every sequence
+    compiled every layer again, whatever had been compiled before (4.5 s of
+    a four-prompt engine case, 30 of ``tests/test_kda_engine.py``'s 100).
+    Inside this block the modules' ``_layer_fn`` is one kept a
+    (configuration, operators) for the process, so the function is the same
+    one and its programs are found again; on the way out the modules have
+    their own back. ``chipbench/`` is the benchmark's and stays as it is,
+    and so does what ``tests/chipbench_tests/`` runs against, whichever
+    file its worker ran before."""
+    own = {m: m._layer_fn for m in modules if hasattr(m, "_layer_fn")}
+    for module, build in own.items():
+        if module not in _KEPT_LAYER_FNS:
+            _KEPT_LAYER_FNS[module] = functools.lru_cache(maxsize=None)(build)
+        module._layer_fn = _KEPT_LAYER_FNS[module]
+    try:
+        yield
+    finally:
+        for module, build in own.items():
+            module._layer_fn = build
+
+
 def reference_logits(ref, params, cfg, tokens) -> np.ndarray:
     """``ref``: a module of ``chipbench/references`` (float32, the whole
     sequence at once, nothing of the program's model code)."""
-    return np.asarray(ref.forward(params, cfg, list(tokens))[0], np.float32)
+    tokens = list(tokens)
+    # (under a block mask a block sees all of itself: no pad inside one)
+    whole_blocks = len(tokens) % (cfg.block_length or 1) == 0
+    pad = -len(tokens) % REFERENCE_BUCKET if whole_blocks else 0
+    with kept_layer_programs(chip_reference, ref):
+        logits = ref.forward(params, cfg, tokens + [0] * pad)[0]
+    return np.asarray(logits, np.float32)[: len(tokens)]
 
 
 def picks(ref, params, cfg, ask, generated) -> list[int]:
@@ -196,14 +300,51 @@ class WindowPages(NoSecondPool):
         (self.pool,) = results
 
 
+class StateSlots(NoSecondPool):
+    """The second pool of a model with linear layers: two slots a row, and
+    every call reads the row's state from the one and writes it to the other
+    (a prefill as ``[read, write]``, a decode step as ``[a, b, switch]`` with
+    the switch at the step's own position)."""
+
+    def make(self, cfg, rows, pages, table_pages):
+        self.pool = llama.init_kda_state(cfg, 2 * rows + 1)
+        self.slots = [[1 + 2 * i, 2 + 2 * i] for i in range(rows)]
+
+    def _swap(self, i):
+        self.slots[i].reverse()
+        return list(reversed(self.slots[i]))  # [the one read, the one written]
+
+    def prefill(self, chunks, positions, ctx_pages):
+        if self.pool is None:
+            return {}
+        slots = np.zeros((positions.shape[0], 2), np.int32)
+        for i, _, _ in chunks:
+            slots[i] = self._swap(i)
+        return dict(state_pages=self.pool, state_slots=slots)
+
+    def decode(self, positions):
+        if self.pool is None:
+            return {}
+        slots = np.array([[*self._swap(i), at]
+                          for i, at in enumerate(positions)], np.int32)
+        return dict(state_pages=self.pool, state_slots=slots)
+
+    def keep(self, results):
+        if self.pool is not None:
+            (self.pool,) = results
+
+
 def served(params, cfg, rows, steps, attn_impl, *, page_size, second=None):
     """``rows``: [(prompt, tokens resident before the batched call)]: each
     row's first ``resident`` tokens are prefilled cold (a call of their own;
     ``resident`` need not end a page), the rest in ONE batched, right-padded
     call against them; then ``steps`` greedy decode steps of every row in one
     batch. Returns the logits a row, [1 + steps, vocab], the tokens fed, and
-    (the key pool, the value pool, the block tables)."""
-    ps, b = page_size, len(rows)
+    (the key pool, the value pool, the block tables), of ``rows`` alone (the
+    call's rows are padded to whole ``ROW_BUCKET``)."""
+    ps, real = page_size, len(rows)
+    rows = list(rows) + [([1], 0)] * (-real % ROW_BUCKET)
+    b = len(rows)
     second = second or NoSecondPool()
     need = [-(-(len(p) + steps) // ps) for p, _ in rows]
     tables = np.zeros((b, round_up(max(need), TABLE_BUCKET)), np.int32)
@@ -258,4 +399,5 @@ def served(params, cfg, rows, steps, attn_impl, *, page_size, second=None):
         for i in range(b):
             fed[i].append(int(toks[i]))
             out[i].append(np.asarray(logits, np.float32)[i])
-    return [np.stack(o) for o in out], fed, (k_pages, v_pages, tables)
+    return ([np.stack(o) for o in out[:real]], fed[:real],
+            (k_pages, v_pages, tables[:real]))

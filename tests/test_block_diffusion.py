@@ -21,7 +21,6 @@ architectures are ``tests/served_path.py``.
 
 import dataclasses
 
-import jax
 import numpy as np
 import pytest
 
@@ -42,7 +41,7 @@ REF = chip_reference.load("moe_block_diffusion")
 
 @pytest.fixture(scope="module")
 def params():
-    return llama.init_params(jax.random.PRNGKey(11), CFG)
+    return served_path.params_of(CFG, 11)
 
 
 def make_engine(params, cfg=CFG, prefill_attn="xla", on_events=None):

@@ -9,13 +9,12 @@ block, sampled lanes. The stored keys and values read back as logits:
 ``tests/test_block_diffusion.py``.
 """
 
-import jax
 import pytest
 
 import served_path
 from chipbench import reference as chip_reference
 from llm_d_kv_cache_manager_tpu.kvcache.kvevents import BlockStored
-from llm_d_kv_cache_manager_tpu.models import TINY_SDAR_MOE, llama
+from llm_d_kv_cache_manager_tpu.models import TINY_SDAR_MOE
 from llm_d_kv_cache_manager_tpu.server import (
     BlockManagerConfig,
     SamplingParams,
@@ -32,7 +31,7 @@ REF = chip_reference.load("moe_block_diffusion")
 
 @pytest.fixture(scope="module")
 def params():
-    return llama.init_params(jax.random.PRNGKey(11), CFG)
+    return served_path.params_of(CFG, 11)
 
 
 def make_engine(params, total_pages=96, on_events=None, **scheduler):

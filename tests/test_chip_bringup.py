@@ -1,7 +1,8 @@
 """Bring-up invariants (ISSUE 21): nothing on the main path hides the
 device, replicas land on their own device, the compile cache can be placed
 from outside, and ``chip_smoke.py`` without its flag and without a chip is an
-error (its explicit dry run, end to end: ``tests/test_chip_smoke_dry_run.py``).
+error (its explicit dry run: ``tests/test_chip_smoke_dry_run.py``, the
+serving phases, and ``tests/test_chip_smoke_dry_run_kernels.py``).
 
 Everything here runs on the CPU's virtual devices; the compiled path is
 proven on the chip by ``chip_smoke.py`` itself.

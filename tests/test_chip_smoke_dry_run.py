@@ -1,7 +1,9 @@
-"""``chip_smoke.py --dry-run`` end to end on the CPU's virtual devices
-(ISSUE 21): the one subprocess test of some 150 s, in a file of its own so
-that a worker of the tier-1 run takes it alone. The other bring-up
-invariants are in ``tests/test_chip_bringup.py``.
+"""``chip_smoke.py --dry-run`` on the CPU's virtual devices (ISSUE 21), its
+serving phases: one subprocess test of some 60-90 s, in a file of its own so
+that a worker of the tier-1 run takes it alone. The kernel phase of the same
+command (half of what was one test of 120-165 s) is
+``tests/test_chip_smoke_dry_run_kernels.py``, the other bring-up invariants
+are in ``tests/test_chip_bringup.py``.
 """
 
 import json
@@ -23,7 +25,7 @@ class TestChipSmokeCommand:
     def test_dry_run_end_to_end_on_virtual_devices(self):
         """The explicit CPU rehearsal: same code, tiny preset, interpreter,
         four replicas on four virtual devices, then tp=4 vs tp=1."""
-        r = _run(["chip_smoke.py", "--dry-run"])
+        r = _run(["chip_smoke.py", "--dry-run", "--phases", "serve,tp"])
         assert r.returncode == 0, r.stdout[-4000:] + r.stderr[-4000:]
         lines = r.stdout.strip().splitlines()
         last = json.loads(lines[-1])
@@ -38,5 +40,4 @@ class TestChipSmokeCommand:
         assert (serve["routing"]["routed_hit_rate"]
                 > serve["routing"]["round_robin_hit_rate"])
         assert len({p["device"] for p in serve["placement"]}) == 4
-        assert "tp" in summary["phases"]
-        assert all(c["ok"] for c in summary["phases"]["kernels"]["cases"].values())
+        assert "tp" in summary["phases"] and "kernels" not in summary["phases"]

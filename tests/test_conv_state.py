@@ -41,7 +41,7 @@ REF = chip_reference.load("conv_moe")
 
 @pytest.fixture(scope="module")
 def params():
-    return llama.init_params(jax.random.PRNGKey(34), CFG)
+    return served_path.params_of(CFG, 34)
 
 
 def reference_logits(params, tokens, cfg=CFG) -> np.ndarray:

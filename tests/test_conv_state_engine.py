@@ -6,18 +6,13 @@ after the state) gives the stateless reference's pick at every step
 (``chipbench/references/conv_moe.forward``, float32).
 """
 
-import jax
 import numpy as np
 import pytest
 
 import served_path
 from chipbench import reference as chip_reference
 from llm_d_kv_cache_manager_tpu.kvcache.kvevents import BlockStored
-from llm_d_kv_cache_manager_tpu.models import (
-    TINY_LFM2_MOE,
-    TINY_QWEN3_MOE,
-    llama,
-)
+from llm_d_kv_cache_manager_tpu.models import TINY_QWEN3_MOE
 from llm_d_kv_cache_manager_tpu.server import (
     BlockManagerConfig,
     SamplingParams,
@@ -25,14 +20,20 @@ from llm_d_kv_cache_manager_tpu.server import (
 )
 from served_path import prompt_of
 
-CFG = TINY_LFM2_MOE
+CFG = served_path.ONE_OF_EACH_LFM2  # depth is not these cases' point
 PS = 4
 REF = chip_reference.load("conv_moe")
 
 
 @pytest.fixture(scope="module")
 def params():
-    return llama.init_params(jax.random.PRNGKey(34), CFG)
+    return served_path.params_of(CFG, 34)
+
+
+#: the pool that runs out (19 pages of 4 tokens to give), on two lanes: the
+#: cases that need one (a preemption, an eviction) share its programs and get
+#: what they need from the traffic they send
+TIGHT = dict(total_pages=20, lanes=2)
 
 
 def make_engine(params, cfg=CFG, total_pages=96, **engine):
@@ -126,15 +127,15 @@ def test_preemption_and_resume(params):
     prefilled again from its own registered pages (their slots hold its
     state) and goes on as if nothing had happened."""
     asks = [prompt_of(90 + i, 14) for i in range(2)]
-    engine = make_engine(params, total_pages=13, lanes=2)
+    engine = make_engine(params, **TIGHT)
     preempted = []
     on_preempted = engine.scheduler.on_preempted
     engine.scheduler.on_preempted = lambda seq: (
         preempted.append(seq), on_preempted(seq))[1]
-    seqs = run_all(engine, asks, n=18)
+    seqs = run_all(engine, asks, n=30)  # 2 x 11 pages, of 19
     assert preempted
     for seq, ask in zip(seqs, asks):
-        assert len(seq.all_tokens) - len(ask) == 18
+        assert len(seq.all_tokens) - len(ask) == 30
         generated = seq.all_tokens[len(ask):]
         assert generated == picks(params, ask, generated)
 
@@ -145,7 +146,7 @@ def test_a_page_evicted_and_refilled(params):
     again (no hit) into whatever pages are free, and a third request hits
     the refilled pages."""
     ask = prompt_of(100, 17)
-    engine = make_engine(params, total_pages=20, lanes=2)
+    engine = make_engine(params, **TIGHT)
     first = run_all(engine, [ask], n=5)[0]
     for i in range(4):  # 4 x 8 pages pass through an 19-page pool
         run_all(engine, [prompt_of(110 + i, 29)], n=3)
@@ -196,11 +197,12 @@ def test_bytes_per_token_from_shapes(params):
     assert engine.kv_bytes_per_token == kv
     assert engine.state_bytes_per_token == state
     assert engine.kv_block_bytes == PS * (kv + state)
-    assert engine.k_pages.shape == (2, 96, PS, 2, 128)
-    assert engine.state_pages.shape == (6, 96, 2 * CFG.hidden_size)
+    assert engine.k_pages.shape == (CFG.n_attn_layers, 96, PS, 2, 128)
+    assert engine.state_pages.shape == (
+        CFG.n_conv_layers, 96, 2 * CFG.hidden_size)
+    assert (CFG.n_attn_layers, CFG.n_conv_layers) == (1, 2)
     gqa = make_engine(
-        llama.init_params(jax.random.PRNGKey(1), TINY_QWEN3_MOE),
-        cfg=TINY_QWEN3_MOE)
+        served_path.params_of(TINY_QWEN3_MOE, 1), cfg=TINY_QWEN3_MOE)
     assert gqa.state_pages is None and gqa.state_bytes_per_token == 0
 
 

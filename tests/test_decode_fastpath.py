@@ -83,7 +83,7 @@ class TestFusedSampling:
     PROMPTS = [(0, 10), (1, 17), (2, 5)]
 
     def _both(self, drive, monkeypatch, **kw):
-        from test_run_ahead import both
+        from run_ahead import both
 
         return both(lambda: Engine(_engine_cfg(**kw)), drive, monkeypatch)
 

@@ -501,7 +501,7 @@ class TestRunAhead:
     """
 
     def _outputs(self, drive, monkeypatch, **kw):
-        from test_run_ahead import both
+        from run_ahead import both
 
         kw.setdefault("decode_steps_per_iter", 4)
         return both(lambda: _engine(**kw), drive, monkeypatch)

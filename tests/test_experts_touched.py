@@ -25,7 +25,7 @@ from llm_d_kv_cache_manager_tpu.server import (
     SchedulerConfig,
 )
 
-from test_run_ahead import never_ahead
+from run_ahead import never_ahead
 
 PS, LANES = 4, 2
 

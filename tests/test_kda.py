@@ -8,16 +8,21 @@ recurrence is itself held to ``transformers``' gated delta rule where the
 gate is one value a head. The program's prefill is the chunked form, its
 decode the kernel over a pool of slots (interpreted): every call here reads a
 row's state from one slot and writes it to ANOTHER, which is how the engine
-takes and restores snapshots. The kernels alone are in
-``tests/test_kda_kernels.py``, the engine in ``tests/test_kda_engine.py``,
-refusals, presets and the loader in ``tests/test_kda_config.py``; the
+takes and restores snapshots. The kernels alone, and the reference's
+recurrence against ``transformers``', are in ``tests/test_kda_kernels.py``
+(the import of ``transformers`` alone is 20-80 s of a loaded worker, and this
+file is the architecture's dearest without it), the engine in
+``tests/test_kda_engine.py``,
+a layer alone, the carried rows' pool and group-limited routing in
+``tests/test_kda_layers.py`` (one file with this until it passed 120
+cpu-seconds of a whole run), refusals, presets and the loader in
+``tests/test_kda_config.py``; the
 helpers they share with the other architectures are ``tests/served_path.py``.
 """
 
 import dataclasses
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -39,7 +44,7 @@ REF = chip_reference.load("kda_mla_moe")
 
 @pytest.fixture(scope="module")
 def params():
-    return llama.init_params(jax.random.PRNGKey(47), CFG)
+    return served_path.params_of(CFG, 47)
 
 
 def one_period(tree):
@@ -50,44 +55,10 @@ def reference_logits(params, tokens, cfg=CFG) -> np.ndarray:
     return served_path.reference_logits(REF, params, cfg, tokens)
 
 
-class StateSlots(served_path.NoSecondPool):
-    """The second pool of a model with linear layers: two slots a row, and
-    every call reads the row's state from the one and writes it to the other
-    (a prefill as ``[read, write]``, a decode step as ``[a, b, switch]`` with
-    the switch at the step's own position)."""
-
-    def make(self, cfg, rows, pages, table_pages):
-        self.pool = llama.init_kda_state(cfg, 2 * rows + 1)
-        self.slots = [[1 + 2 * i, 2 + 2 * i] for i in range(rows)]
-
-    def _swap(self, i):
-        self.slots[i].reverse()
-        return list(reversed(self.slots[i]))  # [the one read, the one written]
-
-    def prefill(self, chunks, positions, ctx_pages):
-        if self.pool is None:
-            return {}
-        slots = np.zeros((positions.shape[0], 2), np.int32)
-        for i, _, _ in chunks:
-            slots[i] = self._swap(i)
-        return dict(state_pages=self.pool, state_slots=slots)
-
-    def decode(self, positions):
-        if self.pool is None:
-            return {}
-        slots = np.array([[*self._swap(i), at]
-                          for i, at in enumerate(positions)], np.int32)
-        return dict(state_pages=self.pool, state_slots=slots)
-
-    def keep(self, results):
-        if self.pool is not None:
-            (self.pool,) = results
-
-
 def served(params, rows, steps, attn_impl, cfg=CFG):
     got, fed, _ = served_path.served(
         params, REF.pool_config(params, cfg), rows, steps, attn_impl,
-        page_size=PS, second=StateSlots())
+        page_size=PS, second=served_path.StateSlots())
     return got, fed
 
 
@@ -111,38 +82,6 @@ def test_prefill_then_decode_through_the_pools(params, rows, cfg, attn_impl):
     for (prompt, _), logits, tokens in zip(rows, got, fed):
         want = reference_logits(params, prompt + tokens, cfg)[len(prompt) - 1:]
         assert rel_err(logits, want) < TOL
-
-
-@pytest.mark.parametrize("safe", [True, False], ids=["safe_gate", "softplus"])
-def test_both_forms_of_the_gate(safe):
-    # (the gate is the linear layers' alone: one of them, over the dense FFN)
-    cfg = dataclasses.replace(CFG, n_layers=1, kda_safe_gate=safe)
-    params = llama.init_params(jax.random.PRNGKey(5), cfg)
-    prompt = prompt_of(7, 21)
-    got, fed = served(params, [(prompt, 8)], 3, "xla", cfg=cfg)
-    want = reference_logits(params, prompt + fed[0], cfg)[len(prompt) - 1:]
-    assert rel_err(got[0], want) < TOL
-    # ... and they are two models: the other form's reference is not this one
-    other = dataclasses.replace(cfg, kda_safe_gate=not safe)
-    wrong = reference_logits(params, prompt + fed[0], other)[len(prompt) - 1:]
-    assert rel_err(got[0], wrong) > 100 * TOL
-
-
-@pytest.mark.parametrize("index", [0, 1, 2], ids=[
-    "linear-dense", "linear-routed", "latent-routed"])
-def test_a_layer_run_alone_is_what_its_parameters_say(params, index):
-    """The benchmark's layer-alone comparison: a one-layer tree under
-    ``replace(cfg, n_layers=1)``, whose ``layer_types`` still speak of the
-    whole model; the mixer and the FFN are read from the layer."""
-    layer = params["layers"][index]
-    assert ("kda_qkv" in layer) == (CFG.layer_kind(index) == "linear")
-    assert ("router" in layer) == (index >= CFG.first_k_dense)
-    cfg1 = dataclasses.replace(CFG, n_layers=1)
-    alone = {**params, "layers": [layer]}
-    prompt = prompt_of(60 + index, 13)
-    got, fed = served(alone, [(prompt, 4)], 2, "xla", cfg=cfg1)
-    want = reference_logits(alone, prompt + fed[0], cfg1)[len(prompt) - 1:]
-    assert rel_err(got[0], want) < TOL
 
 
 def test_a_snapshot_is_left_as_it_was(params):
@@ -173,165 +112,3 @@ def test_a_snapshot_is_left_as_it_was(params):
     assert all(np.array_equal(a, b) for a, b in zip(before, after))
     assert all(np.array_equal(x[:, 2], x[:, 3]) for x in state)
     assert not np.array_equal(state[0][:, 1], state[0][:, 2])
-
-
-# -- the carried rows' pool ------------------------------------------------------
-ROWS_SLOTS, ROWS_LANES = 7, 4
-ROWS_CASES = {
-    # (the slot a lane read, the slot it writes)
-    "keeps_its_slot": ([1, 2, 3, 4], [1, 2, 3, 4]),
-    # lanes 1 and 2 write another slot than they read: a snapshot is left
-    "another_slot": ([1, 2, 3, 4], [1, 5, 6, 4]),
-    # three padded lanes, all on the reserved slot
-    "padded": ([1, 0, 0, 0], [1, 0, 0, 0]),
-}
-
-
-@pytest.mark.parametrize("case", list(ROWS_CASES))
-@pytest.mark.parametrize("head_dim", [16, 32], ids=["row_of_576", "row_of_1152"])
-def test_a_slot_of_whole_tiles_holds_what_the_flat_row_held(case, head_dim):
-    """A decode step's write of the carried rows (``_scatter_slots`` over
-    the pool ``[layers, slots, *kda_conv_tile]``) against the flat
-    ``.at[].set`` over ``[layers * slots, row]`` it was until PR 48, bit for
-    bit, in every layer of a pool of three: a row that is whole 128-lane
-    tiles (9 x 128) and one that is not (the tiny preset's 576 values, 9 x
-    64). What a lane reads back is what it wrote, a tap a row, oldest
-    first."""
-    cfg = dataclasses.replace(
-        CFG, n_layers=3, layer_types=("linear_attention",) * 3,
-        kda_head_dim=head_dim)
-    row, taps = cfg.kda_conv_row, cfg.kda_conv_kernel
-    assert cfg.kda_conv_tile == (9, 64 if head_dim == 16 else 128)
-    empty = llama.init_kda_state(cfg, ROWS_SLOTS)[1]
-    assert empty.shape == (3, ROWS_SLOTS, *cfg.kda_conv_tile)
-    rng = np.random.default_rng(len(case) + head_dim)
-    pool = jnp.asarray(rng.standard_normal(empty.shape), empty.dtype)
-    fresh = jnp.asarray(rng.standard_normal((3, ROWS_LANES, row)), pool.dtype)
-    read, write = (np.asarray(x, np.int32) for x in ROWS_CASES[case])
-    got = np.asarray(llama._scatter_slots(
-        pool, fresh, jnp.asarray(write), jnp.ones(ROWS_LANES, bool)))
-    flat = pool.reshape(3 * ROWS_SLOTS, row)
-    idx = np.arange(3)[:, None] * ROWS_SLOTS + write[None, :]
-    want = np.asarray(
-        flat.at[idx.reshape(-1)].set(fresh.reshape(-1, row))
-    ).reshape(3, ROWS_SLOTS, row)
-    assert got.shape == pool.shape
-    # (slot 0 is written by every padded lane and read by nobody who cares)
-    assert np.array_equal(got.reshape(want.shape)[:, 1:], want[:, 1:])
-    # a slot nobody writes is as it was: what a lane read and left behind
-    untouched = np.setdiff1d(np.arange(ROWS_SLOTS), write)
-    assert set(read) - set(write) <= set(untouched)
-    assert np.array_equal(got[:, untouched], np.asarray(pool)[:, untouched])
-    back = np.asarray(llama._slot_rows(jnp.asarray(got), jnp.asarray(write)))
-    real = write > 0
-    assert np.array_equal(
-        back.reshape(3, ROWS_LANES, taps - 1, -1)[:, real],
-        np.asarray(fresh).reshape(3, ROWS_LANES, taps - 1, -1)[:, real])
-
-
-# -- the reference's recurrence -------------------------------------------------
-def test_the_reference_recurrence_is_the_gated_delta_rule():
-    """With ``g`` equal over a head's channels the recurrence is the gated
-    delta rule: ``transformers``' token-by-token form (torch, CPU)."""
-    torch = pytest.importorskip("torch")
-    from transformers.models.qwen3_next.modeling_qwen3_next import (
-        torch_recurrent_gated_delta_rule,
-    )
-
-    rng = np.random.default_rng(0)
-    s, H, K = 37, 3, 16
-    q, k, v = (rng.standard_normal((s, H, K)).astype(np.float32)
-               for _ in range(3))
-    k /= np.linalg.norm(k, axis=-1, keepdims=True)
-    g = -np.abs(rng.standard_normal((s, H))).astype(np.float32)
-    beta = 1 / (1 + np.exp(-rng.standard_normal((s, H)).astype(np.float32)))
-    S0 = rng.standard_normal((H, K, K)).astype(np.float32)
-    # (theirs scales q by 1 / sqrt(K) inside; ours takes q as the layer
-    # scaled it)
-    got_o, got_S = REF.recurrence(
-        jnp.asarray(q / np.sqrt(K)), jnp.asarray(k), jnp.asarray(v),
-        jnp.asarray(np.repeat(g[..., None], K, axis=-1)), jnp.asarray(beta),
-        jnp.asarray(S0))
-    want_o, want_S = torch_recurrent_gated_delta_rule(
-        *(torch.tensor(x)[None] for x in (q, k, v, g, beta)),
-        initial_state=torch.tensor(S0)[None], output_final_state=True)
-    np.testing.assert_allclose(got_o, want_o[0].numpy(), atol=2e-5)
-    np.testing.assert_allclose(got_S, want_S[0].numpy(), atol=2e-5)
-
-
-# -- group-limited routing -------------------------------------------------------
-def _x(seed, n=9):
-    return jnp.asarray(
-        np.random.default_rng(seed).normal(size=(n, CFG.hidden_size)),
-        jnp.float32)
-
-
-@pytest.mark.parametrize("n_group, topk_group", [(4, 2), (4, 1), (2, 1), (8, 3)])
-def test_the_router_chooses_within_the_best_groups(params, n_group, topk_group):
-    cfg = dataclasses.replace(CFG, n_group=n_group, topk_group=topk_group,
-                              n_experts_per_tok=1 if n_group == 8 else 2)
-    layer, x = params["layers"][1], _x(n_group)
-    topv, topi = llama._moe_gates(layer, cfg, x)
-    scores = np.asarray(jax.nn.sigmoid(x @ layer["router"]))
-    want_i, _ = REF.choose(
-        jnp.asarray(scores), layer["router_bias"], cfg)
-    assert np.array_equal(np.sort(topi, axis=1), np.sort(want_i, axis=1))
-    # by hand: a group's score is the sum of its two largest choice scores
-    c = scores + np.asarray(layer["router_bias"])
-    size = c.shape[1] // n_group
-    grouped = c.reshape(len(c), n_group, size)
-    best = np.sort(grouped, axis=-1)[..., -min(2, size):].sum(-1)
-    kept = np.argsort(-best, axis=1)[:, :topk_group]
-    for row, groups in zip(np.asarray(topi), kept):
-        assert set(row // size) <= set(groups.tolist())
-    # the gates weigh with the scores alone, renormalised, times the factor
-    picked = np.take_along_axis(scores, np.asarray(topi), axis=1)
-    np.testing.assert_allclose(
-        topv, picked / picked.sum(1, keepdims=True) * cfg.routed_scaling_factor,
-        rtol=1e-5)
-
-
-def test_one_group_is_the_routing_it_always_was(params):
-    layer, x = params["layers"][1], _x(1)
-    one = dataclasses.replace(CFG, n_group=1, topk_group=1)
-    topv, topi = llama._moe_gates(layer, one, x)
-    scores = np.asarray(jax.nn.sigmoid(x @ layer["router"]))
-    want = np.argsort(-(scores + np.asarray(layer["router_bias"])), axis=1)[:, :2]
-    assert np.array_equal(np.sort(topi, axis=1), np.sort(want, axis=1))
-    # ... and the groups do leave experts out: some row's choice differs
-    _, limited = llama._moe_gates(
-        layer, dataclasses.replace(CFG, n_group=4, topk_group=1), x)
-    assert not np.array_equal(np.sort(limited, axis=1), np.sort(topi, axis=1))
-
-
-@pytest.mark.parametrize("dispatch", ["routed", "dense"])
-def test_both_dispatches_route_within_the_groups(params, dispatch):
-    cfg = dataclasses.replace(CFG, moe_dispatch=dispatch)
-    layer, x = params["layers"][1], _x(2)[None]
-    got = llama._mlp(layer, cfg, x, interpret=True)[0]
-    want, _ = REF._ffn(layer, cfg, x[0])
-    assert rel_err(np.asarray(got), np.asarray(want)) < TOL
-
-
-def test_a_routing_group_a_chip_adds_up_to_the_uncut_layer(params):
-    """The deployment's cut: each of four chips holds one group's experts
-    (``expert_first`` / ``expert_count``), every chip routes over all of them
-    and adds the places that fall in its own group; the shared expert is
-    counted once."""
-    layer, x = params["layers"][2], _x(3)[None]
-    whole = llama._mlp(layer, CFG, x, interpret=True)[0]
-    shared = llama._swiglu(
-        CFG, x, layer["ws_gate"], layer["ws_up"], layer["ws_down"])[0]
-    size = CFG.n_experts // CFG.n_group
-    parts = []
-    for group in range(CFG.n_group):
-        cut = dataclasses.replace(
-            CFG, expert_first=group * size, expert_count=size)
-        held = {**layer, **{
-            name: layer[name][group * size: (group + 1) * size]
-            for name in ("w_gate", "w_up", "w_down")}}
-        part = llama._mlp(held, cut, x, interpret=True)[0]
-        want, _ = REF._ffn(held, cut, x[0])
-        assert rel_err(np.asarray(part), np.asarray(want)) < TOL
-        parts.append(part - shared)
-    np.testing.assert_allclose(sum(parts) + shared, whole, atol=2e-5)

@@ -16,7 +16,7 @@ import pytest
 
 import served_path
 from chipbench import reference as chip_reference
-from llm_d_kv_cache_manager_tpu.models import TINY_LING_HYBRID, llama
+from llm_d_kv_cache_manager_tpu.models import TINY_LING_HYBRID
 from llm_d_kv_cache_manager_tpu.ops import kda
 from llm_d_kv_cache_manager_tpu.server import (
     BlockManagerConfig,
@@ -27,7 +27,13 @@ from llm_d_kv_cache_manager_tpu.server.block_manager import (
     AllocationError,
     StatePool,
 )
-from served_path import make_engine, prompt_of, rel_err, run_all
+from served_path import (
+    kept_layer_programs,
+    make_engine,
+    prompt_of,
+    rel_err,
+    run_all,
+)
 
 #: one period of the preset's two (linear and dense, linear and routed, latent
 #: and routed): every kind of layer once and half the programs to compile. The
@@ -40,7 +46,7 @@ REF = chip_reference.load("kda_mla_moe")
 
 @pytest.fixture(scope="module")
 def params():
-    return llama.init_params(jax.random.PRNGKey(47), CFG)
+    return served_path.params_of(CFG, 47)
 
 
 def engine_of(params, *, snapshots=16, pages=128, **kw):
@@ -95,9 +101,11 @@ def test_lanes_all_taken_run_a_dispatch_ahead(params):
     """Every lane taken: a burst stays in flight over the step's end and the
     next is planned from positions the host has not seen committed; the slots
     a lane leaves behind are registered when their tokens are."""
-    engine = engine_of(params, **SMALL, decode_steps_per_iter=2)
-    prompts = [prompt_of(20 + i, n) for i, n in enumerate((13, 6))]
+    engine = engine_of(params, decode_steps_per_iter=4)
+    engine.obs_step_timing = True
+    prompts = [prompt_of(20 + i, n) for i, n in enumerate((13, 6, 10, 7))]
     seqs = run_all(engine, prompts, 14)
+    assert engine.step_stats["decode_chained_dispatches"] > 0
     for prompt, seq in zip(prompts, seqs):
         assert seq.output_tokens == picks(params, prompt, seq.output_tokens)
     pool_is_whole(engine)
@@ -188,7 +196,8 @@ def reference_state(params, tokens):
     a layer."""
     f32, s = jnp.float32, len(tokens)
     H, K = CFG.n_heads, CFG.kda_head_dim
-    layer_forward = chip_reference._layer_fn(CFG, REF._ffn, REF._mixer)
+    with kept_layer_programs(chip_reference):
+        layer_forward = chip_reference._layer_fn(CFG, REF._ffn, REF._mixer)
     states = []
     with jax.default_matmul_precision("highest"):
         h = params["embed"][jnp.asarray(tokens)].astype(f32)

@@ -1,8 +1,10 @@
 """The delta-rule recurrence of ``ops/kda.py``: the decode kernel in
 interpret mode against its ``jax.numpy`` oracle over a pool of slots (a slot
 read and written in place, a slot left behind, a fresh lane, the padded
-lanes' slot), and the chunked prefill against the token-by-token recurrence
-from a non-zero initial state.
+lanes' slot), the chunked prefill against the token-by-token recurrence
+from a non-zero initial state, and the plain reference's own recurrence
+(``chipbench/references/kda_mla_moe``) against ``transformers``' gated delta
+rule.
 """
 
 import jax
@@ -10,9 +12,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from chipbench import reference as chip_reference
 from llm_d_kv_cache_manager_tpu.ops import kda
 
 H, K = 3, 16
+REF = chip_reference.load("kda_mla_moe")
 
 
 def _rng(seed):
@@ -140,3 +144,33 @@ def test_a_compiled_kernel_is_refused_off_the_chip():
     one = jnp.ones(1, jnp.int32)
     with pytest.raises(RuntimeError, match="kda_decode.*interpret"):
         kda.kda_decode(pool, *ops, one, one, one * 0, 0)
+
+
+# -- the reference's recurrence -------------------------------------------------
+def test_the_reference_recurrence_is_the_gated_delta_rule():
+    """With ``g`` equal over a head's channels the recurrence is the gated
+    delta rule: ``transformers``' token-by-token form (torch, CPU)."""
+    torch = pytest.importorskip("torch")
+    from transformers.models.qwen3_next.modeling_qwen3_next import (
+        torch_recurrent_gated_delta_rule,
+    )
+
+    rng = np.random.default_rng(0)
+    s, H, K = 37, 3, 16
+    q, k, v = (rng.standard_normal((s, H, K)).astype(np.float32)
+               for _ in range(3))
+    k /= np.linalg.norm(k, axis=-1, keepdims=True)
+    g = -np.abs(rng.standard_normal((s, H))).astype(np.float32)
+    beta = 1 / (1 + np.exp(-rng.standard_normal((s, H)).astype(np.float32)))
+    S0 = rng.standard_normal((H, K, K)).astype(np.float32)
+    # (theirs scales q by 1 / sqrt(K) inside; ours takes q as the layer
+    # scaled it)
+    got_o, got_S = REF.recurrence(
+        jnp.asarray(q / np.sqrt(K)), jnp.asarray(k), jnp.asarray(v),
+        jnp.asarray(np.repeat(g[..., None], K, axis=-1)), jnp.asarray(beta),
+        jnp.asarray(S0))
+    want_o, want_S = torch_recurrent_gated_delta_rule(
+        *(torch.tensor(x)[None] for x in (q, k, v, g, beta)),
+        initial_state=torch.tensor(S0)[None], output_final_state=True)
+    np.testing.assert_allclose(got_o, want_o[0].numpy(), atol=2e-5)
+    np.testing.assert_allclose(got_S, want_S[0].numpy(), atol=2e-5)

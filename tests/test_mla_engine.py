@@ -7,24 +7,23 @@ path.
 
 import dataclasses
 
-import jax
 import pytest
 
 import served_path
 from chipbench import reference as chip_reference
 from llm_d_kv_cache_manager_tpu.kvcache.kvevents import BlockStored
-from llm_d_kv_cache_manager_tpu.models import TINY_MLA_MOE, TINY_QWEN3_MOE, llama
+from llm_d_kv_cache_manager_tpu.models import TINY_QWEN3_MOE
 from llm_d_kv_cache_manager_tpu.server import BlockManagerConfig
 from served_path import prompt_of
 
-CFG = TINY_MLA_MOE
+CFG = served_path.ONE_OF_EACH_MLA  # depth is not these cases' point
 PS = 4
 REF = chip_reference.load("mla_moe")
 
 
 @pytest.fixture(scope="module")
 def params():
-    return llama.init_params(jax.random.PRNGKey(11), CFG)
+    return served_path.params_of(CFG, 11)
 
 
 def reference_logits(params, tokens, cfg=CFG):
@@ -74,7 +73,7 @@ def test_a_shared_document_hits_the_prefix_cache(params, prefill_attn):
     # the same tokenizer emits the same events for the same requests
     gqa_events = []
     gqa = make_engine(
-        llama.init_params(jax.random.PRNGKey(1), TINY_QWEN3_MOE),
+        served_path.params_of(TINY_QWEN3_MOE, 1),
         cfg=TINY_QWEN3_MOE, on_events=gqa_events.extend,
     )
     for ask in asks:
@@ -90,7 +89,7 @@ def test_a_low_rank_query_path_runs():
     model than the full-rank one (``tests/test_scmoe.py`` holds the path to
     its reference)."""
     cfg = dataclasses.replace(CFG, q_lora_rank=16)
-    params = llama.init_params(jax.random.PRNGKey(11), cfg)
+    params = served_path.params_of(cfg, 11)
     layer = params["layers"][1]
     assert "wq" not in layer and layer["wq_a"].shape == (64, 16)
     assert layer["wq_b"].shape == (16, 4 * 24) and layer["q_a_norm"].shape == (16,)

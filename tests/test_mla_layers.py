@@ -25,7 +25,7 @@ REF = chip_reference.load("mla_moe")
 
 @pytest.fixture(scope="module")
 def params():
-    return llama.init_params(jax.random.PRNGKey(11), CFG)
+    return served_path.params_of(CFG, 11)
 
 
 def reference_logits(params, tokens, cfg=CFG) -> np.ndarray:

@@ -7,48 +7,37 @@ alone. Held here on the CPU in float32, with ``ragged_dot`` and with both
 kernels in interpret mode: the real rows of a padded ``[8, w]`` dispatch read
 what they read alone and what they read with no mask; what the kernels leave
 past the last group (undefined: poisoned here with NaN) reaches nothing; the
-count of experts is the real rows'; an idle lane of ``denoise_steps`` moves
-no active lane's tokens."""
+count of experts is the real rows'. The whole programs (a padded prefill, an
+idle lane of ``denoise_steps``) are in ``tests/test_moe_padding_programs.py``,
+the presets, the lengths and the poisoning both files use in
+``tests/moe_padding.py``."""
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from llm_d_kv_cache_manager_tpu.models import (
-    TINY_LFM2_MOE,
-    TINY_MLA_MOE,
-    TINY_QWEN3_MOE,
-    TINY_SDAR_MOE,
-    llama,
+from llm_d_kv_cache_manager_tpu.models import TINY_QWEN3_MOE, llama
+from moe_padding import (
+    LENGTHS,
+    PRESETS,
+    ROWS,
+    TOL,
+    WIDTH,
+    poisoned_grouped_matmul,
 )
-from llm_d_kv_cache_manager_tpu.ops import gmm as gmm_ops
-
-ROWS, WIDTH, PS = 8, 12, 4
-
-#: softmax routing; sigmoid routing with a bias that chooses and a shared
-#: expert; the hybrid one (sigmoid, convolution layers beside attention)
-PRESETS = {
-    "softmax": TINY_QWEN3_MOE,
-    "sigmoid-shared": TINY_MLA_MOE,
-    "hybrid": TINY_LFM2_MOE,
-}
-#: real tokens of each row, for 1, 3 and 8 real rows of the 8
-LENGTHS = {
-    1: [7, 0, 0, 0, 0, 0, 0, 0],
-    3: [12, 5, 1, 0, 0, 0, 0, 0],
-    8: [12, 12, 12, 12, 12, 12, 12, 12],
-}
-TOL = dict(atol=2e-5, rtol=2e-4)
 
 
 def _cfg(preset: str, gmm: str):
     return dataclasses.replace(PRESETS[preset], moe_gmm=gmm)
 
 
+@functools.lru_cache(maxsize=None)
 def _routed_layer(cfg, seed=0, **init):
+    """(kept a process: the eighteen cases of a preset read one tree)"""
     params = llama.init_params(jax.random.PRNGKey(seed), cfg, **init)
     layer = next(lay for lay in params["layers"] if "router" in lay)
     if "router_bias" in layer:  # a bias that really chooses
@@ -69,23 +58,7 @@ def _inputs(cfg, real_rows: int, seed=1):
     return x, lengths, valid
 
 
-@pytest.fixture
-def grouped(monkeypatch):
-    """Every ``group_sizes`` the routed FFN hands the grouped matmul while
-    the fixture is live, and the rows past the last group poisoned with NaN
-    in what it returns: what a kernel may leave there."""
-    seen = []
-    real = gmm_ops.grouped_matmul
-
-    def poisoning(lhs, rhs, group_sizes, **kw):
-        out = real(lhs, rhs, group_sizes, **kw)
-        if not isinstance(group_sizes, jax.core.Tracer):
-            seen.append(np.asarray(group_sizes))
-        past = jnp.arange(out.shape[0]) >= jnp.sum(group_sizes)
-        return jnp.where(past[:, None], jnp.nan, out)
-
-    monkeypatch.setattr(gmm_ops, "grouped_matmul", poisoning)
-    return seen
+grouped = pytest.fixture(poisoned_grouped_matmul)
 
 
 @pytest.mark.parametrize("real_rows", [1, 3, 8])
@@ -160,79 +133,3 @@ def test_touched_counts_the_real_rows_experts(preset, real_rows):
     every = []
     llama._moe_mlp_routed(layer, cfg, x, interpret=True, touched=every)
     assert int(every[0]) == len(np.unique(np.asarray(topi))) >= want
-
-
-def _prefill(cfg, params, tokens, lengths, gmm):
-    cfg = dataclasses.replace(cfg, moe_gmm=gmm)
-    rows, width = tokens.shape
-    pages_a_row = -(-width // PS)
-    k_pages, v_pages = llama.init_kv_pages(cfg, 1 + rows * pages_a_row, PS)
-    state = llama.init_state_pages(cfg, 1 + rows * pages_a_row)
-    pos = np.broadcast_to(np.arange(width, dtype=np.int32), (rows, width))
-    valid = pos < np.asarray(lengths)[:, None]
-    first = 1 + pages_a_row * np.arange(rows, dtype=np.int32)[:, None]
-    out = llama.prefill(
-        params, cfg, tokens, pos, valid, k_pages, v_pages,
-        np.where(valid, first + pos // PS, 0), pos % PS,
-        np.zeros((rows, 0), np.int32), np.zeros((rows,), np.int32),
-        interpret=True, **({} if state is None else {"state_pages": state}),
-    )
-    return np.asarray(out[0], np.float32)
-
-
-@pytest.mark.parametrize("gmm", ["xla", "kernel"])
-@pytest.mark.parametrize("preset", list(PRESETS))
-def test_a_padded_prefill_reads_its_real_rows_logits(preset, gmm, grouped):
-    """The whole program: the last-token logits of three real rows among
-    eight are what each row reads in a dispatch of its own, with the rows
-    past the groups poisoned in every layer."""
-    # a configuration of this test's own: its programs are traced here, with
-    # the poison inside, and no other test's cache holds them
-    cfg = dataclasses.replace(
-        PRESETS[preset], rms_norm_eps=PRESETS[preset].rms_norm_eps + 3e-9
-    )
-    params = llama.init_params(jax.random.PRNGKey(3), cfg)
-    rng = np.random.default_rng(4)
-    tokens = rng.integers(1, 200, (ROWS, WIDTH)).astype(np.int32)
-    lengths = LENGTHS[3]
-    got = _prefill(cfg, params, tokens, lengths, gmm)
-    assert np.isfinite(got[:3]).all()
-    for row in range(3):
-        n = lengths[row]
-        alone = _prefill(cfg, params, tokens[row:row + 1, :n], [n], gmm)
-        np.testing.assert_allclose(got[row], alone[0], atol=1e-4, rtol=1e-3)
-
-
-def test_an_idle_lane_moves_no_active_lanes_tokens():
-    cfg = TINY_SDAR_MOE
-    width, lanes, table_w = cfg.block_length, 3, 4
-    params = llama.init_params(jax.random.PRNGKey(5), cfg)
-    rng = np.random.default_rng(6)
-    k_pages, v_pages = llama.init_kv_pages(cfg, 1 + lanes * table_w, PS)
-    k_pages = jnp.asarray(rng.normal(size=k_pages.shape), k_pages.dtype)
-    v_pages = jnp.asarray(rng.normal(size=v_pages.shape), v_pages.dtype)
-    tables = 1 + np.arange(lanes * table_w, dtype=np.int32).reshape(lanes, -1)
-    seq_lens = np.asarray([8, 4, 12], np.int32)
-
-    def run(active):
-        packed = np.concatenate([
-            np.full((lanes, width), cfg.mask_token_id, np.int32),
-            np.ones((lanes, width), np.int32), tables, seq_lens[:, None],
-            np.zeros((lanes, 1), np.int32),  # step
-            np.full((lanes, 1), 2, np.int32),  # denoising steps
-            np.zeros((lanes, 1), np.int32),  # top_k
-            np.asarray(active, np.int32)[:, None],
-        ], axis=1)
-        fparams = np.tile(np.asarray([[0.9, 0.0, 1.0]], np.float32), (lanes, 1))
-        out, _, _ = llama.denoise_steps(
-            params, cfg, packed, fparams, jnp.copy(k_pages), jnp.copy(v_pages),
-            jax.random.PRNGKey(0), page_size=PS, table_w=table_w,
-            attn_impl="xla", interpret=True,
-        )
-        return np.asarray(out)
-
-    every, two = run([1, 1, 1]), run([1, 0, 1])
-    np.testing.assert_array_equal(two[[0, 2], :-1], every[[0, 2], :-1])
-    # the idle lane fixes nothing and its rows choose no expert
-    np.testing.assert_array_equal(two[1, :width], cfg.mask_token_id)
-    assert 0 < two[0, -1] <= every[0, -1]

@@ -21,10 +21,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import served_path
 from llm_d_kv_cache_manager_tpu.models import (
-    TINY_LFM2_MOE,
     TINY_LLAMA,
-    TINY_MLA_MOE,
     TINY_QWEN3_MOE,
     llama,
 )
@@ -36,9 +35,12 @@ from llm_d_kv_cache_manager_tpu.ops.sampling import (
 )
 
 PS = 4
+#: (the latent and the hybrid preset at one of each kind of layer: how a
+#: dispatch's inputs are packed does not see the depth)
 PRESETS = {
-    "dense": TINY_LLAMA, "moe": TINY_QWEN3_MOE, "latent": TINY_MLA_MOE,
-    "hybrid": TINY_LFM2_MOE,
+    "dense": TINY_LLAMA, "moe": TINY_QWEN3_MOE,
+    "latent": served_path.ONE_OF_EACH_MLA,
+    "hybrid": served_path.ONE_OF_EACH_LFM2,
 }
 MODELS = pytest.mark.parametrize("kind", list(PRESETS))
 

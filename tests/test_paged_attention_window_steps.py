@@ -14,7 +14,12 @@ from llm_d_kv_cache_manager_tpu.ops.paged_attention import (
     paged_attention_reference,
     paged_window_attention,
 )
-from window_walks import WINDOW_WALKS, window_setup, with_fresh_written
+from window_walks import (
+    WINDOW_WALKS,
+    check_walk,
+    window_setup,
+    with_fresh_written,
+)
 
 #: a table of 72 pages of 16 is four and a half steps of 16 pages
 SEVERAL_STEPS = [case for case, (_, _, pages, _) in WINDOW_WALKS.items() if pages >= 16]
@@ -24,23 +29,7 @@ class TestWindowWalk:
     @pytest.mark.parametrize("fresh", [False, True], ids=["resident", "fresh"])
     @pytest.mark.parametrize("case", SEVERAL_STEPS)
     def test_matches_reference(self, case, fresh):
-        ps, window, pages, lens = WINDOW_WALKS[case]
-        q, k, v, tables, starts, abs_lens, fk, fv = window_setup(21, ps, pages, lens)
-        layer = 2  # of a five-dimensional pool, as the served program passes it
-        if fresh:
-            k_ref, v_ref = with_fresh_written(k, v, tables, lens, fk, fv, layer, ps)
-            args = (fk, fv)
-        else:
-            k_ref, v_ref, args = k[layer], v[layer], ()
-        got = paged_attention(
-            q, k, v, tables, abs_lens, *args, interpret=True, layer=layer,
-            window=window, table_start=starts)
-        want = paged_attention_reference(
-            q, k_ref, v_ref, tables, abs_lens, window=window, table_start=starts)
-        assert bool(jnp.all(jnp.isfinite(got)))
-        np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
-        for i, n in enumerate(lens):  # no NaN from a never-written VMEM slot
-            assert (float(jnp.abs(got[i]).max()) == 0.0) == (n == 0)
+        check_walk(case, fresh)
 
     @pytest.mark.parametrize(
         "case", ["window-starts-mid-page", "history-ends-mid-block",

@@ -31,12 +31,12 @@ REF = chip_reference.load("scmoe_mla")
 
 @pytest.fixture(scope="module")
 def params():
-    return llama.init_params(jax.random.PRNGKey(7), CFG)
+    return served_path.params_of(CFG, 7)
 
 
 @pytest.fixture(scope="module")
 def uncut_params():
-    return llama.init_params(jax.random.PRNGKey(7), UNCUT)
+    return served_path.params_of(UNCUT, 7)
 
 
 def reference_logits(params, tokens, cfg=CFG) -> np.ndarray:

@@ -2,12 +2,11 @@
 ``chipbench/references/scmoe_mla.forward`` (float32) and the counters of a
 layer that is told what it holds add up."""
 
-import jax
 import pytest
 
 import served_path
 from chipbench import reference as chip_reference
-from llm_d_kv_cache_manager_tpu.models import TINY_SCMOE, llama
+from llm_d_kv_cache_manager_tpu.models import TINY_SCMOE
 from llm_d_kv_cache_manager_tpu.server import BlockManagerConfig
 from served_path import prompt_of
 
@@ -18,7 +17,7 @@ REF = chip_reference.load("scmoe_mla")
 
 @pytest.fixture(scope="module")
 def params():
-    return llama.init_params(jax.random.PRNGKey(7), CFG)
+    return served_path.params_of(CFG, 7)
 
 
 def reference_logits(params, tokens, cfg=CFG):

@@ -12,7 +12,7 @@ import pytest
 
 import served_path
 from chipbench import reference as chip_reference
-from llm_d_kv_cache_manager_tpu.models import TINY_QWEN3_MOE, TINY_SWA_MOE, llama
+from llm_d_kv_cache_manager_tpu.models import TINY_QWEN3_MOE, llama
 from llm_d_kv_cache_manager_tpu.server import (
     BlockManagerConfig,
     SamplingParams,
@@ -21,7 +21,7 @@ from llm_d_kv_cache_manager_tpu.server import (
 from llm_d_kv_cache_manager_tpu.server.engine import Engine
 from served_path import prompt_of
 
-CFG = TINY_SWA_MOE
+CFG = served_path.ONE_OF_EACH_SWA  # depth is not these cases' point
 PS = 4
 W = CFG.sliding_window
 REF = chip_reference.load("swa_moe")
@@ -29,7 +29,7 @@ REF = chip_reference.load("swa_moe")
 
 @pytest.fixture(scope="module")
 def params():
-    return llama.init_params(jax.random.PRNGKey(43), CFG)
+    return served_path.params_of(CFG, 43)
 
 
 def make_engine(params, cfg=CFG, total_pages=96, window_pages=48, **engine):
@@ -74,11 +74,11 @@ def test_the_served_path_on_a_prompt_of_several_windows(params, prefill_attn):
     engine = pod.engine
     stats = engine.block_manager.window.stats
     assert stats["window_short_hits"] == 0 and stats["window_pages_dropped"] > 0
-    # one full layer in the context pool, four sliding ones in the window pool
-    assert engine.k_pages.shape[0] == 1 and engine.window_pages[0].shape[0] == 4
+    # the full layer in the context pool, the sliding one in the window pool
+    assert engine.k_pages.shape[0] == 1 and engine.window_pages[0].shape[0] == 1
     row = 2 * CFG.n_kv_heads * CFG.hd * 4
     assert engine.kv_bytes_per_token == row
-    assert engine.window_bytes_per_token == 4 * row
+    assert engine.window_bytes_per_token == row
     assert engine.kv_block_bytes == PS * row
     # a sliding layer read at most a window of each context
     steps = engine.step_stats
@@ -141,7 +141,7 @@ def test_stats_and_gauges(params):
     text = pod.metrics.exposition().decode()
     assert 'kvcache_engine_ctx_pages_total{kind="all"} 50.0' in text
     assert 'kvcache_engine_ctx_pages_total{kind="run"} 32.0' in text
-    assert "kvcache_window_bytes_per_token 5.0" in text
+    assert "kvcache_window_bytes_per_token 5.0" in text  # (set_engine_gauges' 5)
     assert f"kvcache_window_pages_held {float(window.num_held)}" in text
     assert 'kvcache_window_pages_total{event="pages_dropped"}' in text
     assert "kvcache_engine_window_ctx_tokens_total 41.0" in text

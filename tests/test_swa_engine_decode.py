@@ -5,16 +5,14 @@ resume in either pool: the reference's pick at every step
 ``/stats`` are in ``tests/test_swa_engine.py``.
 """
 
-import jax
 import pytest
 
 import served_path
 from chipbench import reference as chip_reference
-from llm_d_kv_cache_manager_tpu.models import TINY_SWA_MOE, llama
 from llm_d_kv_cache_manager_tpu.server import BlockManagerConfig
 from served_path import prompt_of
 
-CFG = TINY_SWA_MOE
+CFG = served_path.ONE_OF_EACH_SWA  # depth is not these cases' point
 PS = 4
 W = CFG.sliding_window
 REF = chip_reference.load("swa_moe")
@@ -22,7 +20,7 @@ REF = chip_reference.load("swa_moe")
 
 @pytest.fixture(scope="module")
 def params():
-    return llama.init_params(jax.random.PRNGKey(43), CFG)
+    return served_path.params_of(CFG, 43)
 
 
 def make_engine(params, total_pages=96, window_pages=48, **engine):

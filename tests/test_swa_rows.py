@@ -7,12 +7,11 @@ unequal lengths), each held to ``chipbench/references/swa_moe.forward`` at
 ``tests/test_swa.py``.
 """
 
-import jax
 import pytest
 
 import served_path
 from chipbench import reference as chip_reference
-from llm_d_kv_cache_manager_tpu.models import TINY_SWA_MOE, llama
+from llm_d_kv_cache_manager_tpu.models import TINY_SWA_MOE
 from served_path import prompt_of, rel_err
 
 CFG = TINY_SWA_MOE
@@ -24,7 +23,7 @@ REF = chip_reference.load("swa_moe")
 
 @pytest.fixture(scope="module")
 def params():
-    return llama.init_params(jax.random.PRNGKey(43), CFG)
+    return served_path.params_of(CFG, 43)
 
 
 def reference_logits(params, tokens):

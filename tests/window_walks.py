@@ -1,13 +1,19 @@
 """The window walks of the sliding layers' decode call (``paged_attention``
 with ``window=``: a program a lane that walks the lane's window table itself,
 ``KEY_BLOCK`` tokens of page tiles a step), and the pools, tables and oracle
-inputs a walk is run on. Shared by ``tests/test_paged_attention_window.py``
-(tables narrower than one step) and ``tests/test_paged_attention_window_steps.py``
-(tables of several steps). Not collected.
+inputs a walk is run on, and the comparison itself. Shared by
+``tests/test_paged_attention_window.py`` (tables narrower than one step) and
+``tests/test_paged_attention_window_steps.py`` (tables of several steps).
+Not collected.
 """
 
 import jax.numpy as jnp
 import numpy as np
+
+from llm_d_kv_cache_manager_tpu.ops.paged_attention import (
+    paged_attention,
+    paged_attention_reference,
+)
 
 # A sliding layer's call (``window=``): a program a lane that walks the
 # lane's window table itself, ``KEY_BLOCK`` tokens of page tiles a step.
@@ -59,3 +65,25 @@ def with_fresh_written(k, v, tables, lens, fk, fv, layer, ps):
             k = k.at[page, (n - 1) % ps].set(fk[i])
             v = v.at[page, (n - 1) % ps].set(fv[i])
     return k, v
+
+
+def check_walk(case: str, fresh: bool) -> None:
+    """The kernel (interpreted) against its oracle on ``WINDOW_WALKS[case]``,
+    the current token resident or handed in as an operand (``fresh``)."""
+    ps, window, pages, lens = WINDOW_WALKS[case]
+    q, k, v, tables, starts, abs_lens, fk, fv = window_setup(21, ps, pages, lens)
+    layer = 2  # of a five-dimensional pool, as the served program passes it
+    if fresh:
+        k_ref, v_ref = with_fresh_written(k, v, tables, lens, fk, fv, layer, ps)
+        args = (fk, fv)
+    else:
+        k_ref, v_ref, args = k[layer], v[layer], ()
+    got = paged_attention(
+        q, k, v, tables, abs_lens, *args, interpret=True, layer=layer,
+        window=window, table_start=starts)
+    want = paged_attention_reference(
+        q, k_ref, v_ref, tables, abs_lens, window=window, table_start=starts)
+    assert bool(jnp.all(jnp.isfinite(got)))
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+    for i, n in enumerate(lens):  # no NaN from a never-written VMEM slot
+        assert (float(jnp.abs(got[i]).max()) == 0.0) == (n == 0)
