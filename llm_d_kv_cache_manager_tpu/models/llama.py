@@ -3990,6 +3990,11 @@ def denoise_step(
     )
 
 
+#: ``denoise_steps``' per-lane ``active`` word of a lane whose block is the
+#: one the dispatch before left on the device (1: the block is the host's).
+BLOCK_CARRIED = 2
+
+
 @functools.partial(
     jax.jit,
     static_argnames=(
@@ -4011,6 +4016,7 @@ def denoise_steps(
     mesh=None,
     attn_impl: str = "xla",
     interpret: bool = False,
+    carried: Optional[jnp.ndarray] = None,  # [b, 2 * B + 1] int32 — see below
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """One step of generation by diffusion over blocks for every lane: the
     served program (``B = cfg.block_length``).
@@ -4033,11 +4039,25 @@ def denoise_steps(
     v_pages)``: the block's tokens after the step, its rows still masked,
     and in the last column the distinct experts this forward's rows chose,
     summed over the layers (the same number in every lane; 0 where the FFN
-    is not the routed one) — one array, one fetch."""
+    is not the routed one) — one array, one fetch.
+
+    ``carried``: the ``packed`` the dispatch before this one returned, still
+    on the device. A lane whose ``active`` word is ``BLOCK_CARRIED`` takes
+    its block (tokens, rows masked) from there and not from ``packed_i32``,
+    so the engine can enqueue this forward before it has fetched that one
+    (``Engine._run_decode_block``); ``seq_len``, ``step`` and the table are
+    the host's for every lane. None (the references' call): every lane's
+    block is ``packed_i32``'s."""
     width = cfg.block_length
+    tail = 2 * width + table_w
     tokens = packed_i32[:, :width]
     masked = packed_i32[:, width : 2 * width] != 0
-    tail = 2 * width + table_w
+    if carried is not None:
+        from_carried = (packed_i32[:, tail + 4] == BLOCK_CARRIED)[:, None]
+        tokens = jnp.where(from_carried, carried[:, :width], tokens)
+        masked = jnp.where(
+            from_carried, carried[:, width : 2 * width] != 0, masked
+        )
     block_tables = packed_i32[:, 2 * width : tail]
     seq_lens = packed_i32[:, tail]
     step = packed_i32[:, tail + 1]

@@ -15,7 +15,9 @@ A model whose configuration has ``block_length`` > 0 generates by diffusion
 over blocks, and the engine takes that path from the configuration alone:
 the prefill commits the whole blocks of the prompt and samples nothing, a
 running lane holds a block in progress, and a decode dispatch
-(``_run_decode_block``) advances each lane by 0..``block_length`` tokens.
+(``_run_decode_block``) advances each lane by 0..``block_length`` tokens
+(one dispatch ahead under the fused path's rule: the block in progress then
+stays on the device between two forwards).
 
 XLA discipline: all jitted entry points see bucketed static shapes
 (prefill length rounded up to a bucket, decode batch padded to a fixed
@@ -1135,8 +1137,21 @@ class Engine:
         #: the decode burst left on the device over the end of a step
         #: (``_next_schedule_decided``): toks device array, lane-ordered
         #: active list, and the np position/len arrays the NEXT burst
-        #: derives from. None whenever a lane is free.
+        #: derives from. Of ``_run_decode_block``: the forward's ``packed``
+        #: device array (the lanes' blocks after it, which the next forward
+        #: takes from there) and the active list; the blocks it was given
+        #: are the sequences' own until it is committed. None whenever a
+        #: lane is free.
         self._inflight: Optional[dict] = None
+        #: what an unchained ``_run_decode_block`` dispatch hands over as
+        #: the forward before it: resident, never read
+        self._no_block = (
+            jnp.zeros(
+                (config.decode_batch_size, 2 * cfg.block_length + 1),
+                jnp.int32, device=self._replicated,
+            )
+            if cfg.block_length else None
+        )
 
     def _dev(self, x, dtype=None) -> jax.Array:
         """Stage a host value on the device(s) this engine owns."""
@@ -2702,12 +2717,18 @@ class Engine:
         # logits API for tests and external callers.
         self._run_decode_fused(seqs)
 
-    def _next_schedule_decided(self, active: list[Sequence], k: int) -> bool:
+    def _next_schedule_decided(
+        self, active: list[Sequence], k: "int | list[int]"
+    ) -> bool:
         """The rule for running one decode dispatch ahead, read when the
         burst over ``active`` has been enqueued: may it stay on the device
         over the end of this step, so that the next step enqueues its
         successor before fetching it? Only where the next schedule is
-        already decided, the same lanes decoding again:
+        already decided, the same lanes decoding again. ``k``: the tokens
+        the dispatch may add to a lane, one number for a fused burst (its
+        steps) or one a lane for a forward of ``_run_decode_block`` (the new
+        tokens of the lane's block where the forward commits it, 0 where it
+        denoises):
 
         - the scheduler could admit nothing (``admission_closed``) and
           every running lane is in this burst. With a lane free an arrival
@@ -2727,11 +2748,12 @@ class Engine:
         ):
             return False
         limit = self.config.max_model_len
+        gains = itertools.repeat(k) if isinstance(k, int) else k
         return not any(
-            seq.num_generated + k >= seq.sampling.max_new_tokens
-            or seq.num_tokens + k >= limit
+            seq.num_generated + gain >= seq.sampling.max_new_tokens
+            or seq.num_tokens + gain >= limit
             or self._should_finish(seq)
-            for seq in active
+            for seq, gain in zip(active, gains)
         )
 
     def _run_decode_fused(self, seqs: list[Sequence]) -> None:
@@ -3209,8 +3231,25 @@ class Engine:
         a lane by 0..``block_length`` tokens, and lanes sit at different
         points of their blocks.
 
+        One dispatch ahead where that is free, in ``_run_decode_fused``'s
+        order and under its rule: a forward that ``_next_schedule_decided``
+        left in flight is not fetched before its successor is enqueued.
+        What the successor needs of a lane the forward in flight is
+        denoising is that forward's own result, still on the device
+        (``denoise_steps``' ``carried``: the tokens after the step and the
+        rows still masked; the host adds ``step + 1``); a lane whose block
+        the forward in flight commits opens its next block, masks at
+        ``num_computed + block_length``, which the host writes without the
+        result. Which of the two a lane is the host knows from the block as
+        the forward in flight was given it (``Sequence.block_masked``, the
+        commit just before). Anything but an unchanged lane set drains
+        first, so greedy results are those of the engine that never runs
+        ahead; a lane found finished or preempted when its forward is
+        committed loses that forward (``_commit_block``).
+
         What a denoising forward writes lies beyond ``num_computed`` in
-        pages reserved a whole block ahead, which nothing reads and no
+        pages reserved a whole block ahead (two, for a lane whose block the
+        forward in flight commits), which nothing reads and no
         event names (registration stops at ``num_computed``: the argument
         ``_run_decode_spec`` makes for rejected drafts). A lane preempted or
         aborted in the middle of a block loses the block, not its final
@@ -3221,18 +3260,54 @@ class Engine:
         lanes = self.config.decode_batch_size
         assert len(seqs) <= lanes
 
+        prev = self._inflight
+        if prev is not None and not _same_lanes(prev["active"], seqs):
+            # what the rule could not foresee changed the lane set
+            self._drain_inflight()
+            prev = None
+        # a drain can finish lanes (commit lag), as on the fused path
+        seqs = [s for s in seqs if not self._should_finish(s)]
+        if not seqs:
+            self._drain_inflight()
+            return
+
+        def commits(seq: Sequence) -> bool:
+            """The forward in flight is ``seq``'s committing one."""
+            return prev is not None and seq.block_fixed
+
         with self.phase("decode_build"):
             # Pages for the whole block ahead (positions below num_computed +
             # width); reserving can preempt batchmates, or abort.
             for seq in seqs:
-                if seq.block_table and not self._should_finish(seq):
-                    self._reserve_slots_or_preempt(
-                        seq, seq.num_computed + width + 1 - seq.num_tokens
-                    )
+                if not seq.block_table or self._should_finish(seq):
+                    continue
+                if commits(seq):
+                    # ... and for the block after it, which this dispatch
+                    # opens. Not worth a lane: a pool too tight for it
+                    # drains and waits, as the fused path's second burst.
+                    try:
+                        self.block_manager.reserve_slots(
+                            seq, seq.num_computed + 2 * width + 1 - seq.num_tokens
+                        )
+                        continue
+                    except AllocationError:
+                        self._drain_inflight()
+                        prev = None
+                        if self._should_finish(seq):
+                            continue  # the drain just finished this lane
+                self._reserve_slots_or_preempt(
+                    seq, seq.num_computed + width + 1 - seq.num_tokens
+                )
             active = [
                 s for s in seqs if s.block_table and not self._should_finish(s)
             ]
+            if prev is not None and not _same_lanes(prev["active"], active):
+                # reservation preempted a lane of the forward in flight
+                self._drain_inflight()
+                prev = None
+                active = [s for s in active if not self._should_finish(s)]
             if not active:
+                self._drain_inflight()
                 return
             table_w = self._decode_table_width(active)
             # ONE int32 and ONE f32 upload, as ``spec_decode_steps`` packs:
@@ -3244,16 +3319,24 @@ class Engine:
             tail = 2 * width + table_w
             for i, seq in enumerate(active):
                 if seq.block_tokens is None:
+                    # (chained: the block the forward in flight was given)
                     seq.open_block(cfg.mask_token_id)
                 sp = seq.sampling
-                packed_i32[i, :width] = seq.block_tokens
-                packed_i32[i, width : 2 * width] = seq.block_masked
+                start, step, source = seq.num_computed, seq.block_step, 1
+                if prev is None:
+                    packed_i32[i, :width] = seq.block_tokens
+                    packed_i32[i, width : 2 * width] = seq.block_masked
+                elif commits(seq):
+                    start, step = start + width, 0
+                    packed_i32[i, :width] = cfg.mask_token_id
+                    packed_i32[i, width : 2 * width] = 1
+                else:
+                    step, source = step + 1, llama.BLOCK_CARRIED
                 packed_i32[i, 2 * width : 2 * width + len(seq.block_table)] = (
                     seq.block_table
                 )
                 packed_i32[i, tail:] = (
-                    seq.num_computed, seq.block_step,
-                    sp.denoising_steps or width, sp.top_k, 1,
+                    start, step, sp.denoising_steps or width, sp.top_k, source,
                 )
                 fparams[i] = (
                     DEFAULT_CONFIDENCE_THRESHOLD
@@ -3265,6 +3348,13 @@ class Engine:
         with self.phase("decode_put"):
             key = self._draw_key(fparams[:, 1])
             packed_i32_d, fparams_d = self._stage("decode", packed_i32, fparams)
+            # One program a (lanes, table width): every dispatch hands the
+            # operand over, an unchained one an array that lies there and
+            # that no lane's word points at.
+            carried_d = (
+                self._no_block if prev is None
+                else jax.device_put(prev["packed"], self._replicated)
+            )
         with self.phase("decode_dispatch"):
             packed, self.k_pages, self.v_pages = llama.denoise_steps(
                 self.params,
@@ -3279,16 +3369,50 @@ class Engine:
                 mesh=self.mesh,
                 attn_impl=self.prefill_attn,
                 interpret=self.config.interpret,
+                carried=carried_d,
             )
-        self._count_decode_dispatch(len(active), fparams[:, 1])
+        self._count_decode_dispatch(
+            len(active), fparams[:, 1], chained=prev is not None
+        )
+        packed.copy_to_host_async()  # as the fused path's: a hint
+        burst = {"packed": packed, "active": active}
+        if prev is not None:
+            # commit forward N while forward N+1 runs
+            self._inflight = None
+            self._commit_block(prev)
+        # What this forward may add to a lane is known now: a whole block's
+        # new tokens where it is the committing one, else nothing.
+        gains = [
+            width - (seq.num_tokens - seq.num_computed) if seq.block_fixed else 0
+            for seq in active
+        ]
+        if self._next_schedule_decided(active, gains):
+            self._inflight = burst
+        else:
+            self._commit_block(burst)
+
+    def _commit_block(self, burst: dict) -> None:
+        """Fetch and commit one dispatch of ``_run_decode_block``: each
+        lane's block is, on the host, still what that forward was given. A
+        lane that finished or was preempted since the forward was enqueued
+        (a stop token inside the block committed before it, a deadline, an
+        abort) loses it: its writes lie beyond ``num_computed`` in pages the
+        lane owned, and it is counted nowhere."""
+        width = self.model_cfg.block_length
         with self.phase("decode_fetch"):
-            packed = np.asarray(packed)  # [lanes, 2 * width + 1]
+            packed = np.asarray(burst["packed"])  # [lanes, 2 * width + 1]
         with self.phase("decode_commit"):
             now = time.monotonic()
-            n_commit = n_fixed = 0
-            for i, seq in enumerate(active):
-                if any(seq.block_masked):
+            n_denoise = n_commit = n_fixed = 0
+            for i, seq in enumerate(burst["active"]):
+                if not seq.block_table or self._should_finish(seq):
+                    continue
+                if seq.block_tokens is None:
+                    # a chained forward opened this block itself
+                    seq.open_block(self.model_cfg.mask_token_id)
+                if not seq.block_fixed:
                     # a denoising forward: some of its masked rows are fixed
+                    n_denoise += 1
                     still = packed[i, width : 2 * width] != 0
                     n_fixed += sum(seq.block_masked) - int(still.sum())
                     seq.block_tokens = packed[i, :width].tolist()
@@ -3312,9 +3436,9 @@ class Engine:
                 if seq.first_token_time is None:
                     seq.first_token_time = now
                 self.block_manager.register_full_pages(seq)
-            if self.obs_step_timing:
+            if self.obs_step_timing and n_denoise + n_commit:
                 stats = self.step_stats
-                stats["denoise_lane_forwards"] += len(active) - n_commit
+                stats["denoise_lane_forwards"] += n_denoise
                 stats["commit_lane_forwards"] += n_commit
                 stats["block_tokens_fixed"] += n_fixed
                 stats["blocks_final"] += n_commit
@@ -3324,7 +3448,10 @@ class Engine:
         if self._inflight is None:
             return
         burst, self._inflight = self._inflight, None
-        self._commit_burst(burst)
+        if "packed" in burst:
+            self._commit_block(burst)
+        else:
+            self._commit_burst(burst)
 
     def _commit_burst(self, burst: dict) -> None:
         with self.phase("decode_fetch"):

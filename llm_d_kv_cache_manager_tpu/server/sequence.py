@@ -258,6 +258,12 @@ class Sequence:
         self.block_masked = [False] * len(tail) + [True] * n_mask
         self.block_step = 0
 
+    @property
+    def block_fixed(self) -> bool:
+        """A block is open and none of its rows is masked any more: its
+        next forward is the committing one."""
+        return self.block_masked is not None and not any(self.block_masked)
+
     def reset_allocation(self) -> None:
         """Clear all page/prefix-cache bookkeeping (single source of truth
         for rollback and preemption)."""

@@ -47,7 +47,7 @@ def both(make, drive, monkeypatch, chained=True):
 
 
 def make_engine(total_pages=64, lanes=2, model=TINY_LLAMA, params=None,
-            max_model_len=64, **kw):
+            max_model_len=64, on_events=None, **kw):
     kw.setdefault("scheduler", SchedulerConfig(max_prefill_batch=4))
     kw.setdefault("prefill_bucket", 8)
     return Engine(
@@ -61,7 +61,7 @@ def make_engine(total_pages=64, lanes=2, model=TINY_LLAMA, params=None,
             interpret=True,
             **kw,
         ),
-        params=params,
+        params=params, on_events=on_events,
     )
 
 

@@ -309,9 +309,10 @@ def served_program(config: str, program: str, one_chip, **replace):
     if program == "denoise_steps" and cfg.block_length > 0:
         width = 2 * cfg.block_length + table_w + 5
         args = (params, cfg, S((lanes, width), i32), S((lanes, 3), f32), pool, second, key)
+        # as the engine dispatches it: the forward before rides along
         kwargs = dict(
             page_size=page, table_w=table_w, mesh=None, attn_impl="pallas",
-            interpret=False,
+            interpret=False, carried=S((lanes, 2 * cfg.block_length + 1), i32),
         )
         return llama.denoise_steps, args, kwargs, pool_shape
     if program == "prefill":
