@@ -57,6 +57,7 @@ from typing import Callable, Optional, Sequence as Seq
 from ..kvcache.kvblock import ChunkedTokenDatabase, TokenProcessorConfig
 from ..kvcache.kvevents.events import BadBlock, BlockRemoved, BlockStored, Event
 from ..utils import get_logger
+from .phases import no_part
 from .sequence import Sequence
 
 log = get_logger("server.block_manager")
@@ -427,6 +428,9 @@ class BlockManager:
             TokenProcessorConfig(block_size=config.page_size, hash_seed=config.hash_seed)
         )
         self.on_events = on_events
+        #: the child spans and counts of an admission (``Engine.part``: the
+        #: engine that owns this manager sets its own; alone, a no-op)
+        self.part = no_part
         # page id -> info, for allocated pages only
         self._pages: dict[int, _PageInfo] = {}
         self._free: list[int] = list(range(config.total_pages - 1, 0, -1))  # pop() -> 1,2,..
@@ -1078,10 +1082,20 @@ class BlockManager:
         pages. Sets ``seq.block_table`` / ``seq.num_cached_prompt``; returns
         the number of prompt tokens served from cache."""
         assert not seq.block_table, "sequence already allocated"
-        self._alloc_tenant = seq.tenant
         tokens = seq.prompt_tokens
+        with self.part(seq=seq.seq_id, tokens=len(tokens)) as admit:
+            admit.add(admit_attempts=1, admit_tokens=len(tokens))
+            cached_tokens = self._allocate(seq, tokens)
+            admit.add(admit_blocks_hit=cached_tokens // self.config.page_size)
+        return cached_tokens
+
+    def _allocate(self, seq: Sequence, tokens: Seq[int]) -> int:
+        """``allocate`` inside its span: the parts are ``Engine.part``'s
+        (``ADMIT_PARTS``), what lies between them the span's own time."""
+        self._alloc_tenant = seq.tenant
         ps = self.config.page_size
-        hashes = self.token_db.prefix_hashes(tokens)
+        with self.part("hash"):
+            hashes = self.token_db.prefix_hashes(tokens)
         observe_tenant = (
             self._tenant_mrc_factory is not None and bool(seq.tenant)
         )
@@ -1104,6 +1118,98 @@ class BlockManager:
                     est = self._tenant_mrc[seq.tenant] = self._tenant_mrc_factory()
                 est.observe_chain(hashes)
 
+        with self.part("walk"):
+            block_table, cached_tokens = self._walk_cached(hashes, len(tokens))
+        if self.window is not None:
+            with self.part("window"):
+                # A hit needs both: cut back to the longest prefix whose
+                # last window the window pool still holds whole, and take
+                # that run.
+                n_hit = self.window.longest_run(hashes, len(block_table))
+                if n_hit < len(block_table):
+                    self.window.stats["window_short_hits"] += 1
+                    self.window.stats["window_short_hit_tokens"] += (
+                        len(block_table) - n_hit
+                    ) * ps
+                    for page in block_table[n_hit:]:
+                        self._decref(page)
+                    del block_table[n_hit:]
+                    cached_tokens = n_hit * ps
+                seq.window_first, seq.window_table = self.window.take_run(
+                    hashes, n_hit
+                )
+        snapshot = None
+        try:
+            if self.state is not None:
+                with self.part("state"):
+                    cached_tokens, snapshot = self._cut_to_snapshot(
+                        hashes, block_table, cached_tokens
+                    )
+                    try:
+                        seq.state_slot = self.state.pop()
+                    except AllocationError:
+                        if snapshot is not None:
+                            self.state.unpin(snapshot)
+                        raise
+                    seq.state_from = snapshot or seq.state_slot
+            n_pages_needed = -(-len(tokens) // ps)
+            with self.part("pages") as pages:
+                n_hit, free = len(block_table), len(self._free)
+                try:
+                    while len(block_table) < n_pages_needed:
+                        block_table.append(self._pop_free_page())
+                finally:
+                    # a pop that the free list did not serve evicted
+                    popped = len(block_table) - n_hit
+                    pages.add(
+                        admit_pages=popped,
+                        admit_evictions=popped - (free - len(self._free)),
+                    )
+        except AllocationError:
+            with self.part("rollback", seq=seq.seq_id) as undo:
+                undo.add(admit_rollbacks=1)
+                for page in block_table:
+                    self._decref(page)
+                self._free_window(seq)
+                self._free_state(seq)
+            raise
+
+        seq.block_table = block_table
+        seq.num_cached_prompt = cached_tokens
+        seq.num_computed = cached_tokens
+        seq.num_prefilled = cached_tokens
+        # Cache-hit pages are already registered; continue the hash chain
+        # from the last reused page.
+        n_reused = cached_tokens // ps
+        seq.num_registered_pages = n_reused
+        seq.last_chain_hash = (
+            self._pages[block_table[n_reused - 1]].chain_hash if n_reused else None
+        )
+        if self._qos is not None and seq.tenant and not seq.qos_observed:
+            # Per-tenant hit accounting, first successful prefill only
+            # (the hit_stats rule): rollbacks raise above, preemption
+            # re-prefills have qos_observed already set.
+            seq.qos_observed = True
+            st = self.tenant_stats.setdefault(
+                seq.tenant,
+                {
+                    "requests": 0,
+                    "prompt_tokens": 0,
+                    "cached_tokens": 0,
+                    "capped_evictions": 0,
+                },
+            )
+            st["requests"] += 1
+            st["prompt_tokens"] += len(tokens)
+            st["cached_tokens"] += cached_tokens
+        return cached_tokens
+
+    def _walk_cached(
+        self, hashes: Seq[int], n_tokens: int
+    ) -> tuple[list[int], int]:
+        """(the pages of the prompt's longest cached prefix, each with a
+        reference taken; its tokens): ``allocate``'s walk."""
+        ps = self.config.page_size
         block_table: list[int] = []
         cached_tokens = 0
         restore_until = -1  # hash index below which restores are approved
@@ -1138,99 +1244,40 @@ class BlockManager:
             cached_tokens += ps
         # Never serve the *entire* prompt from cache: the engine needs at
         # least one fresh position to produce first-token logits.
-        if cached_tokens >= len(tokens) and block_table:
+        if cached_tokens >= n_tokens and block_table:
             page = block_table.pop()
             self._decref(page)
             cached_tokens -= ps
-        if self.window is not None:
-            # A hit needs both: cut back to the longest prefix whose last
-            # window the window pool still holds whole, and take that run.
-            n_hit = self.window.longest_run(hashes, len(block_table))
-            if n_hit < len(block_table):
-                self.window.stats["window_short_hits"] += 1
-                self.window.stats["window_short_hit_tokens"] += (
-                    len(block_table) - n_hit
-                ) * ps
-                for page in block_table[n_hit:]:
-                    self._decref(page)
-                del block_table[n_hit:]
-                cached_tokens = n_hit * ps
-            seq.window_first, seq.window_table = self.window.take_run(
-                hashes, n_hit
-            )
-        if self.state is not None:
-            # A hit needs the state at its end: cut back to the last
-            # boundary whose snapshot is still held (none: position 0, zero
-            # state). The sequence reads it with its first chunk.
-            st = self.state
-            n_hit = cached_tokens // st.stride
-            snapshot, lost = None, False
-            while n_hit:
-                snapshot = st.lookup(hashes[n_hit * st.stride // ps - 1])
-                if snapshot is not None:
-                    break
-                n_hit, lost = n_hit - 1, True
-            kept = n_hit * st.stride
-            st.stats["state_admissions"] += 1
-            st.stats["state_cutback_lost"] += lost
-            st.stats["state_cutback_tokens"] += cached_tokens - kept
-            for page in block_table[kept // ps:]:
-                self._decref(page)
-            del block_table[kept // ps:]
-            cached_tokens = kept
+        return block_table, cached_tokens
+
+    def _cut_to_snapshot(
+        self, hashes: Seq[int], block_table: list[int], cached_tokens: int
+    ) -> tuple[int, Optional[int]]:
+        """A hit needs the state at its end: cuts ``block_table`` back to
+        the last boundary whose snapshot is still held (none: position 0,
+        zero state) and pins that snapshot, which the sequence reads with
+        its first chunk. (The tokens kept, the snapshot's slot or None.)"""
+        st = self.state
+        ps = self.config.page_size
+        n_hit = cached_tokens // st.stride
+        snapshot, lost = None, False
+        while n_hit:
+            snapshot = st.lookup(hashes[n_hit * st.stride // ps - 1])
             if snapshot is not None:
-                # (before the pop, which may reuse snapshots)
-                st.pin(snapshot, hit=True)
-                st.stats["state_restores"] += 1
-
-        n_pages_needed = -(-len(tokens) // ps)
-        try:
-            if self.state is not None:
-                try:
-                    seq.state_slot = self.state.pop()
-                except AllocationError:
-                    if snapshot is not None:
-                        self.state.unpin(snapshot)
-                    raise
-                seq.state_from = snapshot or seq.state_slot
-            while len(block_table) < n_pages_needed:
-                block_table.append(self._pop_free_page())
-        except AllocationError:
-            for page in block_table:
-                self._decref(page)
-            self._free_window(seq)
-            self._free_state(seq)
-            raise
-
-        seq.block_table = block_table
-        seq.num_cached_prompt = cached_tokens
-        seq.num_computed = cached_tokens
-        seq.num_prefilled = cached_tokens
-        # Cache-hit pages are already registered; continue the hash chain
-        # from the last reused page.
-        n_reused = cached_tokens // ps
-        seq.num_registered_pages = n_reused
-        seq.last_chain_hash = (
-            self._pages[block_table[n_reused - 1]].chain_hash if n_reused else None
-        )
-        if self._qos is not None and seq.tenant and not seq.qos_observed:
-            # Per-tenant hit accounting, first successful prefill only
-            # (the hit_stats rule): rollbacks raise above, preemption
-            # re-prefills have qos_observed already set.
-            seq.qos_observed = True
-            st = self.tenant_stats.setdefault(
-                seq.tenant,
-                {
-                    "requests": 0,
-                    "prompt_tokens": 0,
-                    "cached_tokens": 0,
-                    "capped_evictions": 0,
-                },
-            )
-            st["requests"] += 1
-            st["prompt_tokens"] += len(tokens)
-            st["cached_tokens"] += cached_tokens
-        return cached_tokens
+                break
+            n_hit, lost = n_hit - 1, True
+        kept = n_hit * st.stride
+        st.stats["state_admissions"] += 1
+        st.stats["state_cutback_lost"] += lost
+        st.stats["state_cutback_tokens"] += cached_tokens - kept
+        for page in block_table[kept // ps:]:
+            self._decref(page)
+        del block_table[kept // ps:]
+        if snapshot is not None:
+            # (before the pop, which may reuse snapshots)
+            st.pin(snapshot, hit=True)
+            st.stats["state_restores"] += 1
+        return kept, snapshot
 
     def can_allocate(self, seq: Sequence) -> bool:
         # Conservative: ignores prefix-cache hits (which only reduce demand).

@@ -42,6 +42,7 @@ from ..models.llama import LlamaConfig
 from ..utils import get_logger
 from .block_manager import AllocationError, BlockManager, BlockManagerConfig
 from ..ops.sampling import pack_sampling_params, sample_tokens_packed
+from .phases import NO_PHASE
 from .scheduler import Scheduler, SchedulerConfig
 from .sequence import (
     DEFAULT_CONFIDENCE_THRESHOLD,
@@ -93,21 +94,34 @@ def _phase_keys(name: str) -> tuple[str, ...]:
 
 _PHASE_KEYS = {name: _phase_keys(name) for name in STEP_PHASES}
 
+#: The parts of one admission (``BlockManager.allocate``, and what the
+#: scheduler undoes of it), in the order they run: the names ``Engine.part``
+#: takes, the ``step_stats["admit_<part>_s"]`` keys, and the child spans
+#: ``admit.<part>`` inside the span ``admit`` (``Engine.part()``, one a call
+#: of ``allocate``), which lie inside ``engine.schedule`` on the profiler's
+#: timeline (readers and docs quote this tuple). ``hash`` is the prompt's
+#: chain of block hashes; ``walk`` the loop over them (cached lookup,
+#: host-restore decisions, the reference counts) and the
+#: never-the-whole-prompt pop; ``window`` / ``state`` the cut back to what
+#: the window pool / the state pool still holds, and what is taken there (a
+#: model with such a pool only); ``pages`` the pop of the fresh pages,
+#: evictions included; ``rollback`` what undoes an admission: ``allocate``
+#: out of pages, or the scheduler's when the fresh suffix is over the step's
+#: budget (that one FOLLOWS its ``admit`` span). ``admit`` less its parts
+#: is the rest of ``allocate``.
+ADMIT_PARTS = ("hash", "walk", "window", "state", "pages", "rollback")
+#: their ``step_stats`` keys, the whole call's first
+ADMIT_SECONDS = ("admit_s", *(f"admit_{name}_s" for name in ADMIT_PARTS))
 
-class _NoPhase:
-    """What ``Engine.phase`` hands out with ``obs_step_timing`` off: one
-    shared object, no clock read, no allocation."""
-
-    __slots__ = ()
-
-    def __enter__(self) -> None:
-        pass
-
-    def __exit__(self, *_exc) -> None:
-        pass
-
-
-NO_PHASE = _NoPhase()
+#: The counts an admission adds to ``step_stats`` through ``_Part.add``:
+#: calls of ``allocate``; attempts undone (out of pages, or over the step's
+#: budget: so ``admit_attempts - admit_rollbacks`` stood); prompt tokens of
+#: the attempts; whole blocks served from the cache; fresh pages popped;
+#: cached pages that lost their hash to serve a pop.
+ADMIT_COUNTS = (
+    "admit_attempts", "admit_rollbacks", "admit_tokens",
+    "admit_blocks_hit", "admit_pages", "admit_evictions",
+)
 
 
 class _Phase:
@@ -137,6 +151,11 @@ class _Phase:
 
     def __enter__(self) -> None:
         engine = self._engine
+        # nothing inside an admission drains the burst in flight: a phase
+        # that started there would lie inside spans that cannot suspend
+        assert not engine._open_parts, (
+            f"phase {self._name!r} entered inside an open admit span"
+        )
         self._outer = engine._open_phase
         if self._outer is not None:
             self._outer._stop()
@@ -167,6 +186,49 @@ class _Phase:
         stats = self._engine.step_stats
         for key in _PHASE_KEYS[self._name]:
             stats[key] += elapsed
+
+
+class _Part:
+    """One timed part of an admission (``Engine.part``): a child span of the
+    phase that is open. While it runs its wall time goes to
+    ``step_stats["admit_s"]`` (no name) or ``["admit_<name>_s"]`` and a
+    ``jax.profiler.TraceAnnotation`` named ``admit`` / ``admit.<name>`` is
+    open on the calling thread, carrying the step's number and the replica
+    as a phase's does, and what the caller adds.
+
+    It NESTS: it suspends nothing and leaves ``Engine._open_phase`` alone,
+    and its name does not start with ``engine.``, so the phases still tile
+    the loop's time. ``add`` puts counts (``ADMIT_COUNTS``) into
+    ``step_stats`` from where the work happened."""
+
+    __slots__ = ("_engine", "_key", "_span", "_t0")
+
+    def __init__(self, engine: "Engine", name: str, stats: dict):
+        self._engine = engine
+        self._key = f"admit_{name}_s" if name else "admit_s"  # ADMIT_SECONDS
+        self._span = jax.profiler.TraceAnnotation(
+            f"admit.{name}" if name else "admit",
+            step=engine._step_count,
+            replica=engine.replica,
+            **stats,
+        )
+
+    def __enter__(self) -> "_Part":
+        self._engine._open_parts += 1
+        self._t0 = time.perf_counter()
+        self._span.__enter__()
+        return self
+
+    def __exit__(self, *_exc) -> None:
+        self._span.__exit__(None, None, None)
+        engine = self._engine
+        engine.step_stats[self._key] += time.perf_counter() - self._t0
+        engine._open_parts -= 1
+
+    def add(self, **counts: int) -> None:
+        stats = self._engine.step_stats
+        for key, n in counts.items():
+            stats[key] += n
 
 
 def _round_up(n: int, multiple: int) -> int:
@@ -526,6 +588,7 @@ class Engine:
             chunk_align=math.lcm(config.prefill_bucket, ps),
         )
         self.scheduler = Scheduler(self.block_manager, sched_cfg)
+        self.block_manager.part = self.scheduler.part = self.part
 
         from jax.sharding import NamedSharding, PartitionSpec
 
@@ -1130,10 +1193,14 @@ class Engine:
             "gather_s": 0.0,
             "demote_s": 0.0,
             **{f"{name}_s": 0.0 for name in STEP_PHASES},
+            **dict.fromkeys(ADMIT_SECONDS, 0.0),
+            **dict.fromkeys(ADMIT_COUNTS, 0),
         }
         #: the phase open right now (the burst in flight, drained inside
         #: it, suspends and resumes it: ``_Phase``)
         self._open_phase: Optional[_Phase] = None
+        #: child spans open inside it (``_Part``: they nest)
+        self._open_parts = 0
         #: the decode burst left on the device over the end of a step
         #: (``_next_schedule_decided``): toks device array, lane-ordered
         #: active list, and the np position/len arrays the NEXT burst
@@ -1188,6 +1255,16 @@ class Engine:
         if not self.obs_step_timing:
             return NO_PHASE
         return _Phase(self, name)
+
+    def part(self, name: str = "", **stats):
+        """Context manager for an admission (no name: the span ``admit``)
+        or one of its ``ADMIT_PARTS`` (``admit.<name>``), with ``stats`` on
+        the span. The block manager and the scheduler call it as their
+        ``part``. Off (the default): the shared ``NO_PHASE``. On: see
+        ``_Part``."""
+        if not self.obs_step_timing:
+            return NO_PHASE
+        return _Part(self, name, stats)
 
     # -- host-DRAM tier movers (batched) ------------------------------------
     #

@@ -35,6 +35,7 @@ from typing import Optional
 
 from ..utils import get_logger
 from .block_manager import AllocationError, BlockManager
+from .phases import no_part
 from .sequence import Sequence, SequenceStatus
 
 log = get_logger("server.scheduler")
@@ -71,6 +72,9 @@ class Scheduler:
     def __init__(self, block_manager: BlockManager, config: Optional[SchedulerConfig] = None):
         self.config = config or SchedulerConfig()
         self.block_manager = block_manager
+        #: the child span and count of a roll-back (``Engine.part``: the
+        #: engine that owns this scheduler sets its own; alone, a no-op)
+        self.part = no_part
         self.waiting: deque[Sequence] = deque()
         self.running: list[Sequence] = []
         #: admitted (pages allocated) but only partially prefilled — only
@@ -258,8 +262,7 @@ class Scheduler:
             # the prefix-cache hit. Roll back rather than over-commit.
             suffix = max(len(seq.prompt_tokens) - seq.num_cached_prompt, 1)
             if prefill and suffix > budget:
-                self.block_manager.free_sequence(seq)
-                seq.reset_allocation()
+                self._roll_back(seq)
                 break
             del self.waiting[idx]
             budget -= suffix
@@ -278,6 +281,14 @@ class Scheduler:
         if prefill:
             return ScheduleOutput(prefill=prefill, decode=[])
         return ScheduleOutput(prefill=[], decode=list(self.running))
+
+    def _roll_back(self, seq: Sequence) -> None:
+        """Undo an admission the step has no budget for: the pages go back
+        and the next step hashes and walks the prompt again."""
+        with self.part("rollback", seq=seq.seq_id) as undo:
+            undo.add(admit_rollbacks=1)
+            self.block_manager.free_sequence(seq)
+            seq.reset_allocation()
 
     def _take_chunk(self, remaining: int, budget: int, align: int) -> int:
         """Chunk size for a sequence with ``remaining`` fresh prompt tokens
@@ -345,8 +356,7 @@ class Scheduler:
                 # Not even one aligned chunk fits the leftover budget: roll
                 # back rather than hold pages for a sequence doing nothing
                 # this step.
-                self.block_manager.free_sequence(seq)
-                seq.reset_allocation()
+                self._roll_back(seq)
                 break
             del self.waiting[idx]
             self.prefilling.append(seq)

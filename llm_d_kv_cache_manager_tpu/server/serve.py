@@ -67,7 +67,7 @@ from ..models import LlamaConfig
 from ..obs import lifecycle as lifecycle_mod
 from ..obs.tracing import Tracer, format_traceparent, parse_traceparent
 from ..utils import get_logger, log_context
-from .engine import NO_PHASE, Engine, EngineConfig
+from .engine import ADMIT_COUNTS, ADMIT_SECONDS, NO_PHASE, Engine, EngineConfig
 from .block_manager import BlockManagerConfig
 from .sequence import SamplingParams, Sequence, check_remasking_strategy
 
@@ -421,6 +421,29 @@ class _ServingMetrics:
                 ["dispatch"], registry=self.registry,
             )
             self._uploads_seen = {"decode": 0, "prefill": 0}
+            self.engine_admit_s = prom.Counter(
+                "kvcache_engine_admit_seconds_total",
+                "Cumulative wall seconds of admissions (the block "
+                "manager's allocate, inside the schedule phase) by part: "
+                "all (the whole call), hash / walk / window / state / pages "
+                "/ rollback (inside it; rollback also where the scheduler "
+                "undoes one for the step's budget, after the call)",
+                ["part"], registry=self.registry,
+            )
+            self.engine_admit = prom.Counter(
+                "kvcache_engine_admit_total",
+                "Admissions by count: admit_attempts (calls of allocate), "
+                "admit_rollbacks (attempts undone: out of pages, or over "
+                "the step's budget), admit_tokens (prompt tokens of the attempts), "
+                "admit_blocks_hit (whole blocks served from the cache), "
+                "admit_pages (fresh pages popped), admit_evictions (cached "
+                "pages that lost their hash to serve a pop)",
+                ["count"], registry=self.registry,
+            )
+            self._admit_seen = {
+                **dict.fromkeys(ADMIT_SECONDS, 0.0),
+                **dict.fromkeys(ADMIT_COUNTS, 0),
+            }
             self.kv_bytes_per_token_g = prom.Gauge(
                 "kvcache_kv_bytes_per_token",
                 "Bytes one token holds in the KV pools, all layers, as held "
@@ -705,6 +728,15 @@ class _ServingMetrics:
             if uploads > seen:
                 self.engine_uploads.labels(dispatch=kind).inc(uploads - seen)
                 self._uploads_seen[kind] = uploads
+        for key, seen in self._admit_seen.items():
+            delta = step_stats.get(key, 0) - seen
+            if delta > 0:
+                if key in ADMIT_COUNTS:
+                    self.engine_admit.labels(count=key).inc(delta)
+                else:  # "admit_s" -> all, "admit_hash_s" -> hash
+                    part = key[len("admit_"):-2] or "all"
+                    self.engine_admit_s.labels(part=part).inc(delta)
+                self._admit_seen[key] = step_stats[key]
         if lag_s is not None:
             self.engine_loop_lag.set(lag_s)
 
