@@ -1077,13 +1077,17 @@ class BlockManager:
         return page
 
     # -- sequence lifecycle -------------------------------------------------
-    def allocate(self, seq: Sequence) -> int:
+    def allocate(self, seq: Sequence, ahead: bool = False) -> int:
         """Allocate pages for a sequence's prompt, reusing prefix-cached
         pages. Sets ``seq.block_table`` / ``seq.num_cached_prompt``; returns
-        the number of prompt tokens served from cache."""
+        the number of prompt tokens served from cache. ``ahead``: what the
+        span says of itself, an admission made while the burst that frees
+        its lane is on the device (``Scheduler.schedule``'s ``leaving``)."""
         assert not seq.block_table, "sequence already allocated"
         tokens = seq.prompt_tokens
-        with self.part(seq=seq.seq_id, tokens=len(tokens)) as admit:
+        with self.part(
+            seq=seq.seq_id, tokens=len(tokens), ahead=int(ahead)
+        ) as admit:
             admit.add(admit_attempts=1, admit_tokens=len(tokens))
             cached_tokens = self._allocate(seq, tokens)
             admit.add(admit_blocks_hit=cached_tokens // self.config.page_size)

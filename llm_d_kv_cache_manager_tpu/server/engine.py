@@ -117,10 +117,13 @@ ADMIT_SECONDS = ("admit_s", *(f"admit_{name}_s" for name in ADMIT_PARTS))
 #: calls of ``allocate``; attempts undone (out of pages, or over the step's
 #: budget: so ``admit_attempts - admit_rollbacks`` stood); prompt tokens of
 #: the attempts; whole blocks served from the cache; fresh pages popped;
-#: cached pages that lost their hash to serve a pop.
+#: cached pages that lost their hash to serve a pop; admissions whose prefill
+#: was dispatched behind the burst that ends their predecessor
+#: (``Engine._admit_ahead``: counted by the engine at that dispatch, so over
+#: ``admit_attempts - admit_rollbacks`` it is the share admitted ahead).
 ADMIT_COUNTS = (
     "admit_attempts", "admit_rollbacks", "admit_tokens",
-    "admit_blocks_hit", "admit_pages", "admit_evictions",
+    "admit_blocks_hit", "admit_pages", "admit_evictions", "admit_ahead",
 )
 
 
@@ -1210,6 +1213,13 @@ class Engine:
         #: are the sequences' own until it is committed. None whenever a
         #: lane is free.
         self._inflight: Optional[dict] = None
+        #: The prefill dispatched behind the burst that ends its rows'
+        #: predecessors (``_admit_ahead``), not yet fetched: what
+        #: ``_enqueue_prefill`` returned. Its sequences wait in
+        #: ``scheduler.prefilling``; the next ``step()`` commits it before
+        #: anything else, an abort or a migration before it looks for its
+        #: sequence. Never set while ``_inflight`` is.
+        self._prefill_ahead: Optional[dict] = None
         #: what an unchained ``_run_decode_block`` dispatch hands over as
         #: the forward before it: resident, never read
         self._no_block = (
@@ -2265,13 +2275,16 @@ class Engine:
     def abort(self, request_id: str) -> Optional[Sequence]:
         """Abort a request mid-flight — client disconnect, generate()
         timeout, operator action — releasing its pages/slots immediately
-        instead of decoding into the void. Finds the sequence in whichever
+        instead of decoding into the void. (A prefill dispatched ahead is
+        committed first, so its sequences are where a prefill leaves them.)
+        Finds the sequence in whichever
         scheduler state holds it (waiting, mid-prefill, running), removes
         it, frees its pages, and marks it FINISHED with
         ``finish_reason="abort"``. Returns the aborted sequence, or None
         when no live sequence carries ``request_id`` (already finished, or
         never admitted). Must run on the engine thread (page-pool
         ownership rule — the serving layer stages aborts onto the loop)."""
+        self._commit_prefill_ahead()
         seq = None
         for cand in (
             list(self.scheduler.waiting)
@@ -2316,6 +2329,7 @@ class Engine:
         """Abort every live sequence (the drain-timeout hammer): commits
         any in-flight burst, then releases all pages. Engine thread only."""
         self._drain_inflight()
+        self._commit_prefill_ahead()
         out: list[Sequence] = []
         for seq in (
             list(self.scheduler.waiting)
@@ -2357,6 +2371,7 @@ class Engine:
         if (self.model_cfg.n_conv_layers or self.model_cfg.n_window_layers
                 or self.model_cfg.n_kda_layers):
             self._refuse_latent_page_moves("freeze_for_migration")
+        self._commit_prefill_ahead()
         seq = None
         for cand in (
             list(self.scheduler.waiting)
@@ -2435,8 +2450,16 @@ class Engine:
         With ``chunked_prefill_tokens`` set the scheduler returns a MIXED
         step — a budgeted chunk batch *and* every running decode lane —
         and both dispatch in the same iteration, so a long prompt's ingest
-        never stalls running decodes for more than one chunk's compute."""
+        never stalls running decodes for more than one chunk's compute.
+
+        A step may end with work on the device: a decode burst whose
+        successor is decided (``_next_schedule_decided``), or the prefill of
+        the requests that take the lanes a burst has just ended
+        (``_admit_ahead``). That prefill is committed here first, so its
+        rows decode in this step and the scheduler below finds them where a
+        prefill step would have left them."""
         timed = self.obs_step_timing
+        self._commit_prefill_ahead()
         with self.phase("schedule"):
             shed: list[Sequence] = []
             if self._deadlines_used:
@@ -2468,8 +2491,11 @@ class Engine:
                 self._prefetch_host_pages()
             out = self.scheduler.schedule()
         if out.prefill:
-            # Prefill must see committed decode state (page accounting,
-            # finish detection) — never overlaps an in-flight burst.
+            # A prefill the scheduler hands out here must see committed
+            # decode state (page accounting, finish detection): its rows
+            # were admitted against what the drain may change. (The one
+            # prefill that does follow a burst still on the device is
+            # ``_admit_ahead``'s, admitted without a page of that burst's.)
             self._drain_inflight()
             self._run_prefill(out.prefill, out.chunks)
         if out.decode:
@@ -2525,7 +2551,17 @@ class Engine:
     def _run_prefill(
         self, seqs: list[Sequence], chunks: Optional[list[int]] = None
     ) -> None:
-        """Prefill one batch. ``chunks[i]`` = prompt tokens to process for
+        """Prefill one batch and wait for it: ``_enqueue_prefill`` (build,
+        upload, dispatch), then ``_commit_prefill`` (fetch, commit)."""
+        self._commit_prefill(self._enqueue_prefill(seqs, chunks))
+
+    def _enqueue_prefill(
+        self, seqs: list[Sequence], chunks: Optional[list[int]] = None,
+        ahead: bool = False,
+    ) -> Optional[dict]:
+        """Build, upload and dispatch one prefill batch; returns what
+        ``_commit_prefill`` needs to fetch and commit it (None: no row was
+        left to dispatch). ``chunks[i]`` = prompt tokens to process for
         ``seqs[i]`` this step (chunked mixed-step scheduling); ``None`` =
         each sequence's whole fresh suffix (legacy whole-prompt prefill).
         Either way every row is the same warm-prefill dispatch shape: a
@@ -2537,7 +2573,15 @@ class Engine:
         Block diffusion (``block_length`` > 0): the rows are the prompt's
         whole blocks under the block mask, chunks are cut at block
         boundaries, and the final chunk samples nothing — the prompt's
-        tail opens the first generated block, in the decode lanes."""
+        tail opens the first generated block, in the decode lanes.
+
+        ``ahead``: the batch follows a decode burst still on the device
+        (``_admit_ahead``). Nothing here reads that burst's result: the
+        rows write pages they were just given and attend over pages
+        registered at earlier commits, and the pools are the burst's own
+        outputs, so the device runs the prefill after it. Nothing is
+        preempted for such a row: one whose window pages are short goes
+        back to the head of the queue."""
         diffusion = self.model_cfg.block_length > 0
         with self.phase("prefill_build"):
             ps = self.page_size
@@ -2553,8 +2597,7 @@ class Engine:
                 # Block diffusion only: every whole block of these prompts
                 # is cached already (or the prompt is shorter than a block),
                 # so there is nothing to forward.
-                self.scheduler.on_prefill_done(seqs)
-                return
+                return {"seqs": seqs, "chunks": chunks, "out": None}
             if self.window_pages is not None:
                 # The chunks' window pages, given back and taken before any
                 # row is built: taking may preempt (a mid-prefill batchmate
@@ -2562,13 +2605,13 @@ class Engine:
                 rows = [
                     (seq, n) for seq, n in zip(seqs, chunks)
                     if seq.block_table and self._reserve_window_or_requeue(
-                        seq, seq.num_prefilled, seq.num_prefilled + n
+                        seq, seq.num_prefilled, seq.num_prefilled + n, ahead
                     )
                 ]
                 # (a later row's reservation may have preempted an earlier one)
                 rows = [(seq, n) for seq, n in rows if seq.block_table]
                 if not rows:
-                    return
+                    return None
                 seqs, chunks = [s for s, _ in rows], [n for _, n in rows]
             # Static shapes for jit-cache stability: batch padded to the
             # configured prefill width, chunk length and context pages
@@ -2630,6 +2673,16 @@ class Engine:
             packed = llama.pack_prefill_inputs(
                 tokens, positions, valid, page_ids, slot_ids, ctx_bt, ctx_lens
             )
+            # the rows the program computes (``llama.prefill_packed`` reads
+            # the same mask on the device): as far as the last that holds a
+            # sequence; under a mesh the one body of all ``b``
+            held = int(np.flatnonzero(valid.any(axis=1))[-1]) + 1
+            job = {
+                "seqs": seqs,
+                "chunks": chunks,
+                "tokens": int(valid.sum()),
+                "slots": chunk * (held if self.mesh is None else b),
+            }
 
             uploads, second = [packed], ()
             if windowed:
@@ -2666,31 +2719,52 @@ class Engine:
                 **self._state_arg(),
                 **window,
             )
-            logits = self._keep_pools(out)
-        if diffusion:
-            # nothing is sampled from a block-diffusion prefill; the wait
-            # for the dispatch keeps ``prefill_fetch`` what it is
-            with self.phase("prefill_fetch"):
-                jax.block_until_ready(logits)
-            first_tokens = [None] * len(seqs)
-        else:
-            first_tokens = self._sample(logits, seqs)  # syncs the dispatch
+            out = self._keep_pools(out)
+        if not diffusion:
+            # (nothing is sampled from a block-diffusion prefill)
+            out = self._sample(out, seqs)
+        if ahead and self.obs_step_timing:
+            self.step_stats["admit_ahead"] += len(seqs)
+        # (a dispatch that waited behind a burst would time that burst too)
+        return {**job, "out": out, "t0": None if ahead else t0}
+
+    def _commit_prefill_ahead(self) -> None:
+        """Fetch and commit the prefill ``_admit_ahead`` left on the device,
+        if there is one."""
+        if self._prefill_ahead is not None:
+            job, self._prefill_ahead = self._prefill_ahead, None
+            self._commit_prefill(job)
+
+    def _commit_prefill(self, job: Optional[dict]) -> None:
+        """The second half of a prefill (``_enqueue_prefill``'s result):
+        wait for the dispatch, fetch the first tokens, and make the rows
+        whose prompt is through running lanes."""
+        if job is None:
+            return
+        seqs, chunks = job["seqs"], job["chunks"]
+        diffusion = self.model_cfg.block_length > 0
+        if job["out"] is None:
+            # nothing was forwarded (block diffusion only)
+            self.scheduler.on_prefill_done(seqs)
+            return
+        with self.phase("prefill_fetch"):
+            if diffusion:
+                # the wait for the dispatch keeps the phase what it is
+                jax.block_until_ready(job["out"])
+                first_tokens = [None] * len(seqs)
+            else:
+                first_tokens = np.asarray(job["out"])  # syncs the dispatch
         with self.phase("prefill_commit"):
-            # Online prefill-rate sample for the recompute-vs-restore model
-            # (chunk tokens over the synced dispatch wall time).
-            self._prefill_rate = self._ema(
-                self._prefill_rate,
-                float(valid.sum()) / max(time.perf_counter() - t0, 1e-6),
-            )
-            self.prefill_stats["tokens_computed"] += int(valid.sum())
+            if job["t0"] is not None:
+                # Online prefill-rate sample for the recompute-vs-restore
+                # model (chunk tokens over the synced dispatch wall time).
+                self._prefill_rate = self._ema(
+                    self._prefill_rate,
+                    job["tokens"] / max(time.perf_counter() - job["t0"], 1e-6),
+                )
+            self.prefill_stats["tokens_computed"] += job["tokens"]
             self.prefill_stats["dispatches"] += 1
-            # the rows the program computed (``llama.prefill_packed`` reads
-            # the same mask on the device): as far as the last that holds a
-            # sequence; under a mesh the one body of all ``b``
-            held = int(np.flatnonzero(valid.any(axis=1))[-1]) + 1
-            self.prefill_stats["token_slots"] += chunk * (
-                held if self.mesh is None else b
-            )
+            self.prefill_stats["token_slots"] += job["slots"]
             now = time.monotonic()
             finals = [
                 seq for seq, n in zip(seqs, chunks) if n >= seq.prompt_remaining
@@ -2812,26 +2886,101 @@ class Engine:
           must find no burst in the way of its prefill;
         - no lane reaches its token budget (``max_new_tokens``,
           ``max_model_len``) within this burst, which the host knows before
-          the burst returns: a successor's prefill must not wait a step,
-          and no surplus row is computed. (A lane the commit just before
-          found finished, by a stop token or a deadline, leaves the same
-          way.)
+          the burst returns: the lane leaves with this step, no surplus row
+          is computed for it, and the request that takes its place is
+          admitted now, behind this burst (``_admit_ahead``), or by the
+          next step. (A lane the commit just before found finished, by a
+          stop token or a deadline, leaves the same way.)
 
         What it cannot foresee — a stop token inside the burst, a deadline,
         an abort, a preemption, a migration — meets the drains."""
-        if not (
+        return (
             _same_lanes(active, self.scheduler.running)
             and self.scheduler.admission_closed()
-        ):
-            return False
+            and not self._lanes_leaving(active, k)
+        )
+
+    def _lanes_leaving(self, active: list[Sequence], k: "int | list[int]") -> int:
+        """How many running lanes this step's ``publish`` is certain to
+        finish, known while the burst over ``active`` is still on the
+        device (``k`` as ``_next_schedule_decided`` takes it): those that
+        reach their token budget within the burst, and those the commit
+        just before found finished."""
         limit = self.config.max_model_len
         gains = itertools.repeat(k) if isinstance(k, int) else k
-        return not any(
-            seq.num_generated + gain >= seq.sampling.max_new_tokens
-            or seq.num_tokens + gain >= limit
-            or self._should_finish(seq)
-            for seq, gain in zip(active, gains)
-        )
+        gain_of = {id(seq): gain for seq, gain in zip(active, gains)}
+
+        def leaves(seq: Sequence) -> bool:
+            gain = gain_of.get(id(seq), 0)  # (a lane not in the burst: none)
+            return (
+                seq.num_generated + gain >= seq.sampling.max_new_tokens
+                or seq.num_tokens + gain >= limit
+                or self._should_finish(seq)
+            )
+
+        return sum(map(leaves, self.scheduler.running))
+
+    def _admit_ahead(self, active: list[Sequence], k: "int | list[int]") -> None:
+        """Admission ahead, at the seam where the burst over ``active`` has
+        been enqueued and is about to be fetched because lanes of it leave:
+        the requests that take those lanes are admitted NOW
+        (``Scheduler.schedule(leaving=)``: the next step's walk, the same
+        head, budget and roll-back) and their prefill is built, uploaded
+        and dispatched behind the burst (``_enqueue_prefill``), so the
+        host's work of an admission runs while the device does. The burst
+        is fetched and committed after it, ``publish`` finishes the lanes,
+        and the next ``step()`` finds the prefill in ``_prefill_ahead``.
+        The device's order is the waiting engine's: this burst, the
+        prefill, the next burst with the new lanes.
+
+        A rule read from the engine's own state, no switch. It DECLINES,
+        and the next step admits as it always did, wherever the view from
+        here could differ from the view one step later:
+
+        - no lane is certain to leave (``_lanes_leaving``: a stop token or a
+          deadline is not foreseen), nobody waits, or the head is still
+          importing;
+        - the head cannot allocate as the pool stands: the leaving lanes'
+          pages are theirs until ``publish``, and nothing is preempted for
+          an admission ahead (``can_allocate`` in the walk; a row short of
+          window pages goes back to the head of the queue);
+        - tenant QoS (an arrival of a higher class becomes the head),
+          deadlines (shedding comes before a step's walk), chunked prefill
+          (a mixed step), speculation, or a chunk still owed.
+
+        A state pool or a window pool is no reason: what an admission reads
+        there (snapshots, last windows) was registered at earlier commits.
+        What is NOT seen one step early is what the leaving lanes register
+        at their last commit: a successor whose prompt continues its
+        predecessor's output hits those blocks a step later and not here.
+
+        Outputs are the waiting engine's. Greedy lanes: token for token
+        (the device runs the same programs in the same order). Sampled
+        lanes: an admission ahead draws no key the waiting engine would not
+        draw (the prefill's sampler takes its split of ``_rng`` after this
+        burst's and before the next one's, where it took it one step
+        later), so the streams are the same too unless a request arrives
+        between the two steps and the waiting engine batches it with these:
+        identically distributed then, as after a discarded surplus burst."""
+        sched = self.scheduler
+        if (
+            not sched.waiting
+            or sched.prefilling
+            or sched.qos_enabled
+            or self._deadlines_used
+            or sched.config.chunked_prefill_tokens is not None
+            or self.config.spec_decode != "off"
+        ):
+            return
+        leaving = self._lanes_leaving(active, k)
+        if not leaving:
+            return
+        with self.phase("schedule"):
+            out = sched.schedule(leaving=leaving)
+        if out.prefill:
+            self._prefill_ahead = self._enqueue_prefill(
+                out.prefill, out.chunks, ahead=True
+            )
 
     def _run_decode_fused(self, seqs: list[Sequence]) -> None:
         """Fused multi-token decode: reserve page capacity for the whole
@@ -3035,6 +3184,7 @@ class Engine:
         if self._next_schedule_decided(active, k):
             self._inflight = burst
         else:
+            self._admit_ahead(active, k)
             self._commit_burst(burst)
 
     def _propose_prompt_lookup(self, seq: Sequence) -> list[int]:
@@ -3466,6 +3616,7 @@ class Engine:
         if self._next_schedule_decided(active, gains):
             self._inflight = burst
         else:
+            self._admit_ahead(active, gains)
             self._commit_block(burst)
 
     def _commit_block(self, burst: dict) -> None:
@@ -3575,12 +3726,13 @@ class Engine:
         self._grow_or_preempt(seq, lambda: self.block_manager.append_slot(seq))
 
     def _reserve_window_or_requeue(
-        self, seq: Sequence, start: int, end: int
+        self, seq: Sequence, start: int, end: int, ahead: bool = False
     ) -> bool:
         """The window pages of ``seq``'s prefill chunk ``[start, end)``
         (``BlockManager.reserve_window``). Where the window pool is dry,
         victims are preempted as ``_grow_or_preempt`` preempts them; with
-        none left ``seq`` itself goes back to the head of the queue (its
+        none left (or ``ahead``: nothing is preempted for an admission
+        ahead) ``seq`` itself goes back to the head of the queue (its
         batchmates of one admission walk may together have taken what each
         was promised alone) and False is returned."""
         from .block_manager import AllocationError
@@ -3590,7 +3742,7 @@ class Engine:
                 self.block_manager.reserve_window(seq, start, end, chunk=True)
                 return True
             except AllocationError:
-                victim = self._pick_victim(seq) or seq
+                victim = (None if ahead else self._pick_victim(seq)) or seq
                 log.warning(
                     "preempting sequence for window pages",
                     victim=victim.seq_id,
@@ -3822,10 +3974,11 @@ class Engine:
         self.step_stats["ctx_pages"] += steps * pages
         self.step_stats["ctx_run_pages"] += steps * in_runs
 
-    def _sample(self, logits: jnp.ndarray, seqs: list[Sequence]) -> np.ndarray:
-        """First tokens of a prefill batch (decode samples on the device,
-        inside its own dispatch). The sampler's inputs go up while the
-        prefill runs; the fetch waits for the prefill dispatch too."""
+    def _sample(self, logits: jnp.ndarray, seqs: list[Sequence]) -> jax.Array:
+        """First tokens of a prefill batch, on the device (decode samples
+        there too, inside its own dispatch). The sampler's inputs go up
+        while the prefill runs; ``_commit_prefill``'s fetch waits for both
+        dispatches."""
         with self.phase("prefill_build"):
             b = logits.shape[0]
             temperature = np.zeros((b,), np.float32)
@@ -3840,5 +3993,5 @@ class Engine:
             (sampling_d,) = self._stage(
                 "prefill", pack_sampling_params(temperature, top_k, top_p)
             )
-        with self.phase("prefill_fetch"):
-            return np.asarray(sample_tokens_packed(logits, sampling_d, key))
+        with self.phase("prefill_dispatch"):
+            return sample_tokens_packed(logits, sampling_d, key)

@@ -214,7 +214,10 @@ class Scheduler:
         passes it) — the two tests the admission walks below make. With a
         lane free and nobody waiting an arrival would be admitted: open.
         The engine reads this to decide whether the next decode dispatch
-        may be enqueued before this one's tokens are fetched."""
+        may be enqueued before this one's tokens are fetched. It speaks of
+        the lanes as they stand: with the lanes full it says closed even
+        where a lane is about to leave, and the engine, which alone knows
+        that, then asks ``schedule(leaving=)`` for the successor instead."""
         if self.prefilling:
             return False
         if len(self.running) >= self.config.max_running:
@@ -224,9 +227,20 @@ class Scheduler:
         head = next((s for s in self.waiting if not s.importing), None)
         return head is not None and not self.block_manager.can_allocate(head)
 
-    def schedule(self) -> ScheduleOutput:
-        """Pick the work for one engine step."""
+    def schedule(self, leaving: int = 0) -> ScheduleOutput:
+        """Pick the work for one engine step.
+
+        ``leaving`` > 0 is the admission AHEAD (``Engine._admit_ahead``; the
+        legacy mode only): the same walk one step early, while the burst
+        that ends ``leaving`` running lanes is still on the device. The
+        lanes count as gone, nothing else does: their pages are still
+        theirs, so the head must allocate as the pool stands, and the walk
+        stops at a request still importing (its import may end before the
+        step this stands in for, and FCFS would then admit it first). What
+        it admits waits in ``prefilling`` for its prefill to be committed,
+        whole prompt or first chunk."""
         if self.config.chunked_prefill_tokens is not None:
+            assert not leaving, "no admission ahead in chunked mode"
             return self._schedule_chunked()
         self.qos_reorder_waiting()
         # Admit waiting sequences first (prefill priority). Sequences
@@ -245,16 +259,20 @@ class Scheduler:
         idx = 0
         while (
             len(prefill) < self.config.max_prefill_batch
-            and len(self.running) + len(prefill) < self.config.max_running
+            and len(self.running) - leaving + len(prefill)
+            < self.config.max_running
         ):
-            idx = self._skip_importing(idx)
+            if not leaving:
+                idx = self._skip_importing(idx)
             if idx >= len(self.waiting):
                 break
             seq = self.waiting[idx]
+            if leaving and seq.importing:
+                break
             if not self.block_manager.can_allocate(seq):
                 break  # FCFS: wait for pages rather than starving this seq
             try:
-                self.block_manager.allocate(seq)
+                self.block_manager.allocate(seq, ahead=leaving > 0)
             except AllocationError:
                 break
             # The token budget bounds prefill *compute*, which is only the
@@ -269,18 +287,22 @@ class Scheduler:
             self._qos_charge(seq, suffix)
             prefill.append(seq)
 
-        if prefill and cut:
+        if not prefill:
+            return ScheduleOutput(prefill=[], decode=list(self.running))
+        chunks = None
+        if cut:
             chunks = [
                 self.block_manager.prefill_cut(seq, seq.prompt_remaining)
                 for seq in prefill
             ]
-            for seq, n in zip(prefill, chunks):
-                if n < seq.prompt_remaining and seq not in self.prefilling:
-                    self.prefilling.append(seq)
-            return ScheduleOutput(prefill=prefill, decode=[], chunks=chunks)
-        if prefill:
-            return ScheduleOutput(prefill=prefill, decode=[])
-        return ScheduleOutput(prefill=[], decode=list(self.running))
+        # held in ``prefilling``: a sequence with a chunk still owed, and
+        # every one admitted ahead (until its prefill is committed)
+        held = prefill if leaving else [
+            seq for seq, n in zip(prefill, chunks or ())
+            if n < seq.prompt_remaining
+        ]
+        self.prefilling.extend(s for s in held if s not in self.prefilling)
+        return ScheduleOutput(prefill=prefill, decode=[], chunks=chunks)
 
     def _roll_back(self, seq: Sequence) -> None:
         """Undo an admission the step has no budget for: the pages go back

@@ -437,7 +437,9 @@ class _ServingMetrics:
                 "the step's budget), admit_tokens (prompt tokens of the attempts), "
                 "admit_blocks_hit (whole blocks served from the cache), "
                 "admit_pages (fresh pages popped), admit_evictions (cached "
-                "pages that lost their hash to serve a pop)",
+                "pages that lost their hash to serve a pop), admit_ahead "
+                "(admissions dispatched behind the burst that ends their "
+                "predecessor)",
                 ["count"], registry=self.registry,
             )
             self._admit_seen = {

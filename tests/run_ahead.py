@@ -20,11 +20,19 @@ from llm_d_kv_cache_manager_tpu.server import (
 PS = 4
 
 
+def never_admits_ahead(monkeypatch):
+    """The engine whose admissions wait for their step (the dispatch ahead
+    stays what it is): ``Engine._admit_ahead`` does nothing."""
+    monkeypatch.setattr(Engine, "_admit_ahead", lambda self, active, k: None)
+
+
 def never_ahead(monkeypatch):
-    """The engine that waits: the one predicate answers no."""
+    """The engine that waits: the one predicate answers no, and no
+    admission goes ahead either."""
     monkeypatch.setattr(
         Engine, "_next_schedule_decided", lambda self, active, k: False
     )
+    never_admits_ahead(monkeypatch)
 
 
 def both(make, drive, monkeypatch, chained=True):

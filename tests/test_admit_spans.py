@@ -6,7 +6,8 @@ Off (the default) the primitive is the phases' one shared do-nothing object
 and ``step_stats`` stays at zero. On, an admission is one span ``admit`` with
 its parts inside it, all inside ``engine.schedule``; the phases tile the
 loop's time as they did; a roll-back of either kind is counted; a phase that
-starts inside a child fails.
+starts inside a child fails. An admission ahead (``Engine._admit_ahead``) says
+so on its span, lies in the step of the burst it follows, and is counted.
 """
 
 import dataclasses
@@ -44,11 +45,11 @@ PART_KEYS = ADMIT_SECONDS[1:]
 
 def engine_of(total_pages=64, **kw):
     kw.setdefault("scheduler", SchedulerConfig(max_prefill_batch=4))
+    kw.setdefault("decode_batch_size", 4)
     return Engine(EngineConfig(
         model=TINY_LLAMA,
         block_manager=BlockManagerConfig(total_pages=total_pages, page_size=PS),
-        max_model_len=64, prefill_bucket=8, decode_batch_size=4,
-        interpret=True, **kw,
+        max_model_len=64, prefill_bucket=8, interpret=True, **kw,
     ))
 
 
@@ -68,7 +69,7 @@ def test_the_names_are_fixed_in_two_tuples_beside_the_phases():
     assert ADMIT_PARTS == ("hash", "walk", "window", "state", "pages", "rollback")
     assert ADMIT_COUNTS == (
         "admit_attempts", "admit_rollbacks", "admit_tokens",
-        "admit_blocks_hit", "admit_pages", "admit_evictions")
+        "admit_blocks_hit", "admit_pages", "admit_evictions", "admit_ahead")
     assert not set(ADMIT_PARTS) & set(STEP_PHASES)
     eng = engine_of()
     assert ADMIT_SECONDS == ("admit_s", *(f"admit_{p}_s" for p in ADMIT_PARTS))
@@ -174,7 +175,7 @@ def test_an_allocation_error_counts_one_rollback(monkeypatch):
     assert grown == {
         "admit_attempts": 1, "admit_rollbacks": 1,
         "admit_tokens": 17, "admit_blocks_hit": 0, "admit_pages": 1,
-        "admit_evictions": 0}
+        "admit_evictions": 0, "admit_ahead": 0}
     assert 0 < st["admit_rollback_s"] - before["admit_rollback_s"] < st["admit_s"]
     assert eng._open_parts == 0
     # the next step hashes the prompt again, and admits
@@ -340,3 +341,70 @@ def test_a_profiler_capture_holds_the_children_inside_engine_schedule(tmp_path):
     for name, start, end, _ in children:
         if name not in ("admit", "admit.rollback"):
             assert any(w[1] <= start and end <= w[2] for w in whole)
+
+
+# -- an admission ahead ----------------------------------------------------------------
+def test_an_admission_ahead_lies_in_the_step_of_the_burst_it_follows(tmp_path):
+    """Two lanes, both five tokens from their end; two wait, and the batch's
+    budget holds one of them: the first is admitted behind the burst that
+    ends the lanes (``ahead=1``, inside a second ``engine.schedule`` of that
+    step, between the burst's dispatch and its fetch), the second is walked
+    there too and rolled back, and admitted by a step of its own
+    (``ahead=0``)."""
+    from jax.profiler import ProfileData
+
+    eng = engine_of(decode_batch_size=2, scheduler=SchedulerConfig(
+        max_prefill_batch=4, max_prefill_tokens=20))
+    short = eng.add_request(prompt_of(20, 9), SamplingParams(max_new_tokens=5))
+    eng.add_request(prompt_of(21, 9), SamplingParams(max_new_tokens=5))
+    eng.step()
+    c = eng.add_request(prompt_of(22, 12), SamplingParams(max_new_tokens=2))
+    d = eng.add_request(prompt_of(23, 12), SamplingParams(max_new_tokens=2))
+    eng.obs_step_timing = True
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0  # as the benchmark's traced run
+    options.host_tracer_level = 2
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        while not short.is_finished():
+            eng.step()
+        went_ahead = eng._step_count - 1
+        assert eng._prefill_ahead["seqs"] == [c]
+        eng.run_until_complete()
+    finally:
+        jax.profiler.stop_trace()
+    st = eng.step_stats
+    assert (st["admit_attempts"], st["admit_rollbacks"], st["admit_ahead"]) == (3, 1, 1)
+    assert st["admit_attempts"] - st["admit_rollbacks"] == 2  # c and d
+    (pb,) = tmp_path.glob("plugins/profile/*/*.xplane.pb")
+    events = sorted(
+        (ev.start_ns, ev.start_ns + ev.duration_ns, ev.name, dict(ev.stats))
+        for plane in ProfileData.from_file(str(pb)).planes
+        for line in plane.lines for ev in line.events
+        if ev.name.split(".")[0] in ("admit", "engine")
+    )
+    whole = [e for e in events if e[2] == "admit"]
+    assert [(e[3]["seq"], e[3]["ahead"]) for e in whole] == [
+        (c.seq_id, 1), (d.seq_id, 1), (d.seq_id, 0)]
+    (undo,) = [e for e in events if e[2] == "admit.rollback"]
+    assert undo[3]["seq"] == d.seq_id and undo[3]["step"] == went_ahead
+    ahead, rolled, later = whole
+    assert ahead[3]["step"] == rolled[3]["step"] == went_ahead < later[3]["step"]
+    # inside the second ``engine.schedule`` of its step, which follows the
+    # burst's dispatch; the prefill's build, upload and dispatch follow it,
+    # and the burst's fetch follows them
+    step = [e for e in events
+            if e[2].startswith("engine.") and e[3]["step"] == went_ahead]
+    names = [e[2][len("engine."):] for e in step]
+    assert names[:4] == ["schedule", "decode_build", "decode_put", "decode_dispatch"]
+    at = names.index("schedule", 1)
+    assert names[at:at + 5] == [
+        "schedule", "prefill_build", "prefill_put", "prefill_dispatch",
+        "prefill_build"]  # (the last: the sampler's inputs)
+    assert names.index("decode_dispatch") < at < names.index("decode_fetch", at)
+    outer = step[at]
+    assert outer[0] <= ahead[0] and rolled[1] <= undo[1] <= outer[1]
+    # the step after commits that prefill before it schedules
+    after = [e[2][len("engine."):] for e in events
+             if e[2].startswith("engine.") and e[3]["step"] == went_ahead + 1]
+    assert after[:3] == ["prefill_fetch", "prefill_commit", "schedule"]

@@ -379,7 +379,7 @@ def _fetch_sites():
 def test_every_fetch_site_sits_inside_a_fetch_phase():
     sites = _fetch_sites()
     assert {m for m, _, _ in sites} == {
-        "_sample", "_commit_burst", "_run_decode_spec", "_commit_block"
+        "_commit_prefill", "_commit_burst", "_run_decode_spec", "_commit_block"
     }
     assert all(inside for _, _, inside in sites), sites
 

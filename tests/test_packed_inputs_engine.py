@@ -123,7 +123,10 @@ def test_what_a_dispatch_uploads(kind, monkeypatch):
     """Lanes full and budgets far: dispatches chain. An unchained decode
     dispatch uploads its ids and the packed array, a chained one the packed
     array alone (its ids lie on the device), a prefill its packed array and
-    the sampler's parameters; ``step_stats`` counts the same."""
+    the sampler's parameters; ``step_stats`` counts the same. The third
+    request waits for a lane and is admitted behind the burst that ends one
+    (``Engine._admit_ahead``): that call of ``_run_decode_fused`` holds its
+    prefill's two uploads too."""
     calls = _count_uploads(monkeypatch)
     eng = _engine(kind, lanes=2)
     eng.obs_step_timing = True
@@ -135,7 +138,7 @@ def test_what_a_dispatch_uploads(kind, monkeypatch):
         by.setdefault(what, set()).add(n)
     assert by["_run_prefill"] == {2}
     assert by["_run_decode_fused"] <= {0, 2}  # 0: every lane had finished
-    assert by["_run_decode_fused+chained"] == {1}
+    assert by["_run_decode_fused+chained"] == {1, 1 + 2}
     st = eng.step_stats
     assert st["decode_chained_dispatches"] > 0
     assert st["decode_uploads"] == (
