@@ -29,6 +29,8 @@ def load_hf_state_dict(
     state_dict: Mapping[str, Any], cfg: LlamaConfig
 ) -> Params:
     sd = state_dict
+    if cfg.router_before_attention:
+        return _load_smallthinker(sd, cfg)
     if cfg.sliding_window:
         return _load_afmoe(sd, cfg)
     if cfg.layer_types is not None:
@@ -401,6 +403,131 @@ def _load_afmoe(sd: Mapping[str, Any], cfg: LlamaConfig) -> Params:
     }
 
 
+def _load_smallthinker(sd: Mapping[str, Any], cfg: LlamaConfig) -> Params:
+    """``model_type: smallthinker``. The checkpoint's names are written here
+    AS THE AUTHOR REMEMBERS the published modelling code
+    (``modeling_smallthinker.py``), with no network at hand to read it again:
+    a layer's two norms ``input_layernorm`` and ``post_attention_layernorm``;
+    ``self_attn.{q, k, v, o}_proj``; ``block_sparse_moe.primary_router`` (the
+    router, which reads the layer's input) and ``block_sparse_moe.experts.N.
+    {gate, up, down}``; ``model.norm`` and an untied ``lm_head``. A checkpoint
+    that names them otherwise fails on the missing key, named. A layer whose
+    kind is sliding gets the leaf ``window``, every layer ``preroute``."""
+    def get(name: str) -> np.ndarray:
+        return _to_np(sd[name])
+
+    def vector(name: str) -> jnp.ndarray:
+        return jnp.asarray(get(name), cfg.dtype)
+
+    def linear(name: str) -> jnp.ndarray:
+        return jnp.asarray(get(name).T, cfg.dtype)  # [out,in] -> [in,out]
+
+    layers = []
+    for i in range(cfg.n_layers):
+        p = f"model.layers.{i}."
+        layer = {
+            "attn_norm": vector(p + "input_layernorm.weight"),
+            "mlp_norm": vector(p + "post_attention_layernorm.weight"),
+            "router": linear(p + "block_sparse_moe.primary_router.weight"),
+            "preroute": jnp.asarray(1, jnp.int32),
+        }
+        for ours, theirs in (("wq", "q_proj"), ("wk", "k_proj"),
+                             ("wv", "v_proj"), ("wo", "o_proj")):
+            layer[ours] = linear(f"{p}self_attn.{theirs}.weight")
+        if cfg.layer_kind(i) == "sliding":
+            layer["window"] = jnp.asarray(cfg.sliding_window, jnp.int32)
+        for name in ("gate", "up", "down"):
+            layer["w_" + name] = jnp.stack([
+                linear(f"{p}block_sparse_moe.experts.{j}.{name}.weight")
+                for j in range(cfg.n_experts)
+            ])
+        layers.append(layer)
+    params = {
+        "embed": vector("model.embed_tokens.weight"),
+        "final_norm": vector("model.norm.weight"),
+        "layers": layers,
+    }
+    if not cfg.tie_word_embeddings:
+        params["lm_head"] = linear("lm_head.weight")
+    return params
+
+
+def _smallthinker_config(hf_config, rope_scaling) -> LlamaConfig:
+    """``model_type: smallthinker``: ``sliding_window_layout`` says which
+    layers see a window of ``sliding_window_size`` positions, ``rope_layout``
+    which rotate; every layer's FFN is ``moe_num_primary_experts`` ReGLU
+    experts, ``moe_num_active_primary_experts`` a token, chosen by a router
+    that reads the layer's input before the attention. What the program does
+    not run is refused here by name: the program rotates a layer exactly
+    where it slides (``llama._rotates``), so a file whose two layouts differ
+    is not this program's model."""
+    def has(key, default=None):
+        return getattr(hf_config, key, default)
+
+    n = hf_config.num_hidden_layers
+    slides = [int(x) for x in has("sliding_window_layout") or ()]
+    rotates = [int(x) for x in has("rope_layout") or ()]
+    if len(slides) != n or set(slides) - {0, 1}:
+        raise NotImplementedError(
+            f"sliding_window_layout {slides}: a 0 or a 1 a layer, "
+            f"{n} of them, is supported"
+        )
+    if rotates != slides:
+        raise NotImplementedError(
+            f"rope_layout {rotates} is not sliding_window_layout {slides}: "
+            "a layer that rotates is one that slides, and no other, here"
+        )
+    if not any(slides):
+        raise NotImplementedError(
+            "sliding_window_layout without a sliding layer: with rope_layout "
+            "equal to it no layer takes a position, which is not supported"
+        )
+    if not has("moe_primary_router_apply_softmax", True):
+        raise NotImplementedError(
+            "moe_primary_router_apply_softmax=false is not supported yet "
+            "(a softmax over the chosen logits)"
+        )
+    if not has("norm_topk_prob", True):
+        raise NotImplementedError("norm_topk_prob=false is not supported yet")
+    for key in ("moe_enable_secondary_experts", "moe_num_secondary_experts",
+                "moe_num_active_secondary_experts"):
+        if has(key):
+            raise NotImplementedError(
+                f"{key}={has(key)!r}: a secondary level of experts is not "
+                "supported yet"
+            )
+    layout = has("moe_layer_layout")
+    if layout is not None and not all(layout):
+        raise NotImplementedError(
+            "moe_layer_layout with a dense layer is not supported yet"
+        )
+    if rope_scaling is not None or getattr(hf_config, "rope_scaling", None):
+        raise NotImplementedError("rope_scaling with sliding layers")
+    width = hf_config.moe_ffn_hidden_size
+    return LlamaConfig(
+        vocab_size=hf_config.vocab_size,
+        hidden_size=hf_config.hidden_size,
+        intermediate_size=width,  # the file has no dense FFN
+        n_layers=n,
+        n_heads=hf_config.num_attention_heads,
+        n_kv_heads=hf_config.num_key_value_heads,
+        head_dim=has("head_dim"),
+        rope_theta=float(has("rope_theta", 10_000.0)),
+        rms_norm_eps=has("rms_norm_eps", 1e-6),
+        tie_word_embeddings=bool(has("tie_word_embeddings", False)),
+        n_experts=hf_config.moe_num_primary_experts,
+        n_experts_per_tok=hf_config.moe_num_active_primary_experts,
+        moe_intermediate_size=width,
+        norm_topk_prob=True,
+        hidden_act="relu",
+        layer_types=tuple(
+            "sliding_attention" if x else "full_attention" for x in slides
+        ),
+        sliding_window=hf_config.sliding_window_size,
+        router_before_attention=True,
+    )
+
+
 def _afmoe_config(hf_config, rope_scaling) -> LlamaConfig:
     """``model_type: afmoe``: window and full attention layers in one model
     (``layer_types``), a gate on the attention's output, four norms a layer,
@@ -540,6 +667,8 @@ def config_from_hf(hf_config) -> LlamaConfig:
         return _lfm2_moe_config(hf_config, rope_scaling)
     if getattr(hf_config, "model_type", "") == "afmoe":
         return _afmoe_config(hf_config, rope_scaling)
+    if getattr(hf_config, "model_type", "") == "smallthinker":
+        return _smallthinker_config(hf_config, rope_scaling)
     if getattr(hf_config, "model_type", "") == "longcat_flash":
         return _longcat_flash_config(hf_config, rope_scaling)
     if getattr(hf_config, "model_type", "") == "bailing_hybrid":

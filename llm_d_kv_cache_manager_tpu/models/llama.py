@@ -93,14 +93,16 @@ from .quant import QuantizedTensor, materialize as _w
 #: output); ``moe_shared``: the
 #: shared experts; ``cache_write``: the all-layer scatters into the pools;
 #: ``head``: the final norm and the logits; ``sample``:
-#: ``ops/sampling.py``'s entry points. The Pallas kernels keep their own
+#: ``ops/sampling.py``'s entry points; ``moe_preroute``: the gates of a layer
+#: whose router reads the layer's input, made before its attention
+#: (``_preroute``). The Pallas kernels keep their own
 #: names inside ``attn`` / ``moe_experts``. What lies under none (the
 #: embedding gather, a burst's bookkeeping) a reader calls ``unscoped``.
 #: Readers and documents quote this tuple, as they do ``server/engine.py``'s
 #: ``STEP_PHASES`` for the host's side.
 MODEL_SCOPES = (
-    "attn", "attn_window", "conv", "kda", "ffn", "moe_router", "moe_experts",
-    "moe_zero", "moe_shared", "cache_write", "head", "sample",
+    "attn", "attn_window", "conv", "kda", "ffn", "moe_preroute", "moe_router",
+    "moe_experts", "moe_zero", "moe_shared", "cache_write", "head", "sample",
 )
 
 
@@ -517,6 +519,15 @@ class LlamaConfig:
     kda_lora: bool = False
     expert_swiglu_limits: Optional[tuple] = None
     shared_swiglu_limits: Optional[tuple] = None
+    # The router reads the stream that ENTERS the layer, before any norm and
+    # before the attention (SmallThinker): the experts a token takes are
+    # chosen from ``h``, its gates carried across the attention, and the
+    # experts read ``mlp_norm(h + attention)`` as everywhere. Decides what
+    # ``init_params`` and the loader make; the bodies read the layer: a
+    # routed one with the leaf ``preroute`` (an int32 scalar, read for what
+    # it says the layer is, as ``window`` is) gets its gates from
+    # ``_preroute`` ahead of the attention, every other from its own input.
+    router_before_attention: bool = False
     dtype: Any = jnp.bfloat16
 
     @property
@@ -642,6 +653,27 @@ class LlamaConfig:
         return None if self.layer_types is None else list(self.layer_types)
 
     @property
+    def sliding_window_layout(self) -> Optional[list]:
+        """``layer_types`` as ``smallthinker`` publishes it: a 1 for a layer
+        that sees a window, a 0 for one that sees its whole context (whole,
+        whatever ``n_layers`` is run of it)."""
+        if self.layer_types is None:
+            return None
+        return [int(kind == "sliding_attention") for kind in self.layer_types]
+
+    @property
+    def rope_layout(self) -> Optional[list]:
+        """Which layers rotate q and k, as ``smallthinker`` publishes it: in
+        a model with sliding layers those and no other (``_rotates``)."""
+        if not self.sliding_window or self.layer_types is None:
+            return None
+        return self.sliding_window_layout
+
+    @property
+    def router_applies_softmax(self) -> bool:
+        return self.n_experts > 0 and self.moe_scoring == "softmax"
+
+    @property
     def use_expert_bias(self) -> bool:
         """A sigmoid router's experts are chosen by score + bias."""
         return self.n_experts > 0 and self.moe_scoring == "sigmoid"
@@ -697,6 +729,8 @@ class LlamaConfig:
             return functools.partial(jax.nn.gelu, approximate=True)
         if self.hidden_act == "gelu":
             return functools.partial(jax.nn.gelu, approximate=False)
+        if self.hidden_act == "relu":  # a ReGLU expert: relu(gate) * up
+            return jax.nn.relu
         raise ValueError(f"unsupported hidden_act {self.hidden_act!r}")
 
 
@@ -1127,6 +1161,60 @@ TINY_SWA_MOE = LlamaConfig(
     dtype=jnp.float32,
 )
 
+#: PowerInfer/SmallThinker-21BA3B-Instruct (``model_type: smallthinker``): 52
+#: layers in periods of four, a FULL layer that rotates nothing FIRST, then
+#: three sliding ones of window 4096 that rotate (``sliding_window_layout``
+#: and ``rope_layout`` ``[0, 1, 1, 1]`` x 13); GQA 28 / 4 heads of 128 (a
+#: group of 7; 28 x 128 is not the hidden size), no q/k norm, no bias; every
+#: layer 64 ReGLU experts of width 768 (``relu(gate) * up``), top-6 of the
+#: logits with a softmax over the six, no shared expert, no dense layer, and
+#: a router that reads the layer's INPUT, before the first norm and the
+#: attention (``router_before_attention``). ``intermediate_size`` restates
+#: the experts' width: the published file has no dense FFN and no key for one.
+SMALLTHINKER_21B_A3B = LlamaConfig(
+    vocab_size=151_936,
+    hidden_size=2_560,
+    intermediate_size=768,
+    n_layers=52,
+    n_heads=28,
+    n_kv_heads=4,
+    head_dim=128,
+    rope_theta=1_500_000.0,
+    rms_norm_eps=1e-6,
+    n_experts=64,
+    n_experts_per_tok=6,
+    moe_intermediate_size=768,
+    norm_topk_prob=True,
+    hidden_act="relu",
+    layer_types=(_ATTN, _SWA, _SWA, _SWA) * 13,
+    sliding_window=4_096,
+    router_before_attention=True,
+)
+
+#: Tiny SmallThinker (full, sliding, sliding, sliding; window 8; 7 query
+#: heads on 1 KV head; 8 ReGLU experts top-2, the router before the
+#: attention) for tests / CPU dry-runs.
+TINY_SMALLTHINKER = LlamaConfig(
+    vocab_size=256,
+    hidden_size=64,
+    intermediate_size=48,
+    n_layers=4,
+    n_heads=7,
+    n_kv_heads=1,
+    head_dim=16,
+    rope_theta=10_000.0,
+    rms_norm_eps=1e-6,
+    n_experts=8,
+    n_experts_per_tok=2,
+    moe_intermediate_size=48,
+    norm_topk_prob=True,
+    hidden_act="relu",
+    layer_types=(_ATTN, _SWA, _SWA, _SWA),
+    sliding_window=8,
+    router_before_attention=True,
+    dtype=jnp.float32,
+)
+
 _KDA = "linear_attention"
 
 #: inclusionAI/Ling-3.0-flash (``model_type: bailing_hybrid``): 42 layers in
@@ -1439,6 +1527,8 @@ def init_params(
             layer["mlp_post_norm"] = norm_init((d,))
         if cfg.layer_kind(i) == "sliding":
             layer["window"] = jnp.asarray(cfg.sliding_window, jnp.int32)
+        if cfg.router_before_attention and "router" in layer:
+            layer["preroute"] = jnp.asarray(1, jnp.int32)
         layers.append(layer)
 
     params: Params = {
@@ -1934,8 +2024,26 @@ def _group_limited(choice: jnp.ndarray, cfg: LlamaConfig) -> jnp.ndarray:
     return jnp.where(keep[..., None], grouped, -jnp.inf).reshape(choice.shape)
 
 
+def _preroute(layer: Params, cfg: LlamaConfig, h: jnp.ndarray):
+    """The gates ``(top values, top indices)``, each ``[b, s, k]``, of a
+    routed layer whose router reads the stream that enters the layer (the
+    leaf ``preroute``): made from ``h`` before any norm and before the
+    attention, under ``model.moe_preroute``, and handed to ``_ffn`` after it.
+    None for every other layer, whose dispatch makes its own."""
+    if "preroute" not in layer:
+        return None
+    with _scope("moe_preroute"):
+        return _gates(layer, cfg, h)
+
+
 @_scope("moe_router")
 def _moe_gates(layer: Params, cfg: LlamaConfig, x: jnp.ndarray):
+    """``_gates`` of the input the experts read, under ``model.moe_router``:
+    every layer's routing but a pre-routed one's (``_preroute``)."""
+    return _gates(layer, cfg, x)
+
+
+def _gates(layer: Params, cfg: LlamaConfig, x: jnp.ndarray):
     """Top-k routing shared by both dispatch strategies.
 
     Gating matches HF Mixtral (`MixtralSparseMoeBlock`): softmax over ALL
@@ -1987,7 +2095,10 @@ def _moe_gates(layer: Params, cfg: LlamaConfig, x: jnp.ndarray):
     return topv, topi
 
 
-def _moe_mlp_dense(layer: Params, cfg: LlamaConfig, x: jnp.ndarray) -> jnp.ndarray:
+def _moe_mlp_dense(
+    layer: Params, cfg: LlamaConfig, x: jnp.ndarray,
+    gates: Optional[tuple] = None,
+) -> jnp.ndarray:
     """Masked-dense sparse-MoE SwiGLU FFN (the numerics oracle).
 
     The combine is a masked-dense einsum over stacked expert weights
@@ -1999,9 +2110,10 @@ def _moe_mlp_dense(layer: Params, cfg: LlamaConfig, x: jnp.ndarray) -> jnp.ndarr
     an XLA-inserted psum over ICI. With E == tp (Mixtral 8x7B on a v5e-8
     slice) per-device work is exactly one expert per token — but at
     E >> top-k (Qwen3-MoE's 128/8) it wastes ~E/k× expert FLOPs, which is
-    what the routed dispatch below avoids.
+    what the routed dispatch below avoids. ``gates``: ``_preroute``'s, for
+    a layer whose router does not read ``x``.
     """
-    topv, topi = _moe_gates(layer, cfg, x)  # [b, s, k]
+    topv, topi = _moe_gates(layer, cfg, x) if gates is None else gates  # [b, s, k]
     with _scope("moe_experts"):
         # Scatter the renormalized top-k gates back to a dense [b, s, E] mask.
         gates = jnp.sum(
@@ -2066,6 +2178,7 @@ def _moe_mlp_routed(
     layer: Params, cfg: LlamaConfig, x: jnp.ndarray, interpret: bool = False,
     touched: Optional[list] = None, valid: Optional[jnp.ndarray] = None,
     held: Optional[tuple] = None, psum_axis: Optional[str] = None,
+    gates: Optional[tuple] = None,
 ) -> jnp.ndarray:
     """Routed sparse-MoE SwiGLU FFN: grouped top-k gather dispatch.
 
@@ -2109,12 +2222,19 @@ def _moe_mlp_routed(
 
     ``psum_axis``: sum the float32 result over that mesh axis before it is
     cast (the expert-parallel combine).
+
+    ``gates``: the rows' ``(top values, top indices)`` where the layer's
+    router does not read ``x`` (``_preroute``: made before the attention);
+    None: the router reads ``x`` here. What follows is the same either way.
     """
     b, s, d = x.shape
     n = b * s
     k = cfg.n_experts_per_tok
     xf = x.reshape(n, d)
-    topv, topi = _moe_gates(layer, cfg, xf)  # [n, k]
+    if gates is None:
+        topv, topi = _moe_gates(layer, cfg, xf)  # [n, k]
+    else:
+        topv, topi = (g.reshape(n, k) for g in gates)
     if held is None and not cfg.holds_every_expert:
         if cfg.expert_first + cfg.experts_held > cfg.n_experts:
             raise ValueError(
@@ -2241,7 +2361,7 @@ def _moe_mlp_routed(
 
 def _moe_mlp_routed_ep(
     layer: Params, cfg: LlamaConfig, x: jnp.ndarray, mesh,
-    interpret: bool = False,
+    interpret: bool = False, gates: Optional[tuple] = None,
 ) -> jnp.ndarray:
     """Expert-parallel routed dispatch under ``shard_map`` over the tp axis.
 
@@ -2256,7 +2376,8 @@ def _moe_mlp_routed_ep(
     the per-token combine is a psum over ICI. Shapes stay static with no
     capacity factor and NO dropped tokens; the grouped dots visit the
     tiles of the shard's own rows alone. ``_moe_mlp`` selects this path
-    whenever ``k*tp < E`` (Qwen3-MoE 128/8 at tp=8).
+    whenever ``k*tp < E`` (Qwen3-MoE 128/8 at tp=8). ``gates``
+    (``_preroute``'s) ride in beside the rows they belong to.
     """
     from jax.sharding import PartitionSpec as P
 
@@ -2270,15 +2391,15 @@ def _moe_mlp_routed_ep(
     # activations are replicated across tp either way.
     batch_axis = "dp" if "dp" in mesh.shape else None
 
-    def body(gates, w_gate, w_up, w_down, xs):
+    def body(router, w_gate, w_up, w_down, xs, made):
         # QuantizedTensor expert shards flow into the gmm kernel as-is
         # (specs are pytree prefixes, so q and scale both shard on E);
         # the kernel dequantizes per-tile in VMEM.
-        shard = {**gates, "w_gate": w_gate, "w_up": w_up, "w_down": w_down}
+        shard = {**router, "w_gate": w_gate, "w_up": w_up, "w_down": w_down}
         return _moe_mlp_routed(
             shard, cfg, xs, interpret,
             held=(jax.lax.axis_index("tp") * e_local, e_local),
-            psum_axis="tp",
+            psum_axis="tp", gates=made,
         )
 
     fn = jax.shard_map(
@@ -2290,22 +2411,26 @@ def _moe_mlp_routed_ep(
             P("tp", None, None),
             P("tp", None, None),
             P(batch_axis),
+            P(batch_axis),  # a prefix of ``gates``: None has no leaf
         ),
         out_specs=P(batch_axis),
         check_vma=False,
     )
-    gates = {k: layer[k] for k in ("router", "router_bias") if k in layer}
-    return fn(gates, layer["w_gate"], layer["w_up"], layer["w_down"], x)
+    router = {k: layer[k] for k in ("router", "router_bias") if k in layer}
+    return fn(
+        router, layer["w_gate"], layer["w_up"], layer["w_down"], x, gates
+    )
 
 
 def _moe_mlp(
     layer: Params, cfg: LlamaConfig, x: jnp.ndarray, mesh=None,
     interpret: bool = False, touched: Optional[list] = None,
-    valid: Optional[jnp.ndarray] = None,
+    valid: Optional[jnp.ndarray] = None, gates: Optional[tuple] = None,
 ) -> jnp.ndarray:
     # ``valid`` reaches the single-shard routed dispatch alone: the
     # expert-parallel one (``tp`` > 1) and the dense oracle compute every
-    # row, padding included, as they always have.
+    # row, padding included, as they always have. ``gates`` (``_preroute``'s)
+    # reach all three.
     if cfg.moe_dispatch not in ("routed", "dense"):
         raise ValueError(f"unknown moe_dispatch {cfg.moe_dispatch!r}")
     if cfg.moe_dispatch == "dense" and not cfg.holds_every_expert:
@@ -2323,30 +2448,38 @@ def _moe_mlp(
                 cfg.moe_dispatch == "routed"
                 and cfg.n_experts_per_tok * tp < cfg.n_experts
             ):
-                return _moe_mlp_routed_ep(layer, cfg, x, mesh, interpret)
-            return _moe_mlp_dense(layer, cfg, x)
+                return _moe_mlp_routed_ep(layer, cfg, x, mesh, interpret, gates)
+            return _moe_mlp_dense(layer, cfg, x, gates)
         # E % tp != 0: weights use the Megatron intermediate-dim fallback
         # (sharding.py). The global routed path would make GSPMD all-gather
         # the full expert stacks, so ALWAYS use the dense einsum here —
         # GSPMD partitions it along the f dimension.
-        return _moe_mlp_dense(layer, cfg, x)
+        return _moe_mlp_dense(layer, cfg, x, gates)
     if cfg.moe_dispatch == "routed":
-        return _moe_mlp_routed(layer, cfg, x, interpret, touched, valid)
-    return _moe_mlp_dense(layer, cfg, x)
+        return _moe_mlp_routed(
+            layer, cfg, x, interpret, touched, valid, gates=gates
+        )
+    return _moe_mlp_dense(layer, cfg, x, gates)
 
 
 def _mlp(
     layer: Params, cfg: LlamaConfig, x: jnp.ndarray, mesh=None,
     interpret: bool = False, touched: Optional[list] = None,
-    valid: Optional[jnp.ndarray] = None,
+    valid: Optional[jnp.ndarray] = None, gates: Optional[tuple] = None,
 ) -> jnp.ndarray:
     # The layer says what its FFN is (it has a router or it has not), never
     # its index: a layer run alone (the benchmark's comparison) or a model
     # with leading dense layers is served by what its parameters hold.
+    if ("preroute" in layer) != (gates is not None):
+        raise ValueError(
+            "a layer whose router reads the layer's input (and no other) "
+            "takes its gates from the body that runs it: _preroute, before "
+            "the attention"
+        )
     if "router" in layer:
         out = _moe_mlp(
             layer, cfg, x, mesh=mesh, interpret=interpret, touched=touched,
-            valid=valid,
+            valid=valid, gates=gates,
         )
         if "ws_gate" in layer:
             # the shared experts: one SwiGLU every token takes, a plain
@@ -2374,6 +2507,7 @@ def _ffn(
     layer: Params, cfg: LlamaConfig, h: jnp.ndarray, mesh=None,
     interpret: bool = False, touched: Optional[list] = None,
     valid: Optional[jnp.ndarray] = None, aside: Optional[list] = None,
+    gates: Optional[tuple] = None,
 ) -> jnp.ndarray:
     """A layer's second half as every body runs it: ``h + _mlp(mlp_norm(h))``.
     The norm lies under the scope of what reads it (``model.moe_router``
@@ -2384,7 +2518,10 @@ def _ffn(
     that carries a double layer's routed sum: a layer with a ``moe`` part
     (the first half) also runs that routed FFN on its normed input and puts
     the result there; the next FFN that has none (the second half) adds it
-    after its own: ``out = c + FFN_1(y) + MoE(x)``."""
+    after its own: ``out = c + FFN_1(y) + MoE(x)``.
+
+    ``gates``: what ``_preroute`` made of the stream that entered the layer,
+    for a layer whose router reads that (None for every other)."""
     routed = "router" in layer
     with _scope("moe_router" if routed else "ffn"):
         x = rms_norm(h, layer["mlp_norm"], cfg.rms_norm_eps, cfg.norm_offset)
@@ -2395,7 +2532,7 @@ def _ffn(
         ))
     out = _mlp(
         layer, cfg, x, mesh=mesh, interpret=interpret, touched=touched,
-        valid=valid,
+        valid=valid, gates=gates,
     )
     with _scope("moe_experts" if routed else "ffn"):
         h = h + _post_norm(layer, cfg, "mlp_post_norm", out)
@@ -2721,6 +2858,7 @@ def _prefill_forward(
         sliding = "window" in layer
         # the layer's index in the pools it reads and writes
         li = len(fresh_wk if sliding else fresh_k)
+        gates = _preroute(layer, cfg, h)
         with _scope(
             "conv" if "conv_in" in layer
             else "kda" if "kda_qkv" in layer
@@ -2826,7 +2964,7 @@ def _prefill_forward(
             h = h + _post_norm(layer, cfg, "attn_post_norm", out)
         h = _ffn(
             layer, cfg, h, mesh=mesh, interpret=interpret,
-            touched=experts_touched, valid=valid, aside=aside,
+            touched=experts_touched, valid=valid, aside=aside, gates=gates,
         )
 
     fresh = (
@@ -3328,6 +3466,7 @@ def _decode_body(
         sliding = "window" in layer
         # the layer's index in the pools it reads and writes
         li = len(fresh_wk if sliding else fresh_k)
+        gates = _preroute(layer, cfg, h)
         with _scope(
             "conv" if "conv_in" in layer
             else "kda" if "kda_qkv" in layer
@@ -3412,7 +3551,7 @@ def _decode_body(
             h = h + _post_norm(layer, cfg, "attn_post_norm", out)
         h = _ffn(
             layer, cfg, h, mesh=mesh, interpret=interpret,
-            touched=experts_touched, aside=aside,
+            touched=experts_touched, aside=aside, gates=gates,
         )
 
     if fresh_wk:

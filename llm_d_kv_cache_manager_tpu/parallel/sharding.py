@@ -92,6 +92,8 @@ def _layer_specs(
         specs.update(attn_post_norm=P(), mlp_post_norm=P())
     if sliding:
         specs["window"] = P()
+    if cfg.router_before_attention and "router" in specs:
+        specs["preroute"] = P()  # the leaf that says where the router reads
     if conv:
         for name in (
             "wq", "wk", "wv", "wo", "bq", "bk", "bv", "q_norm", "k_norm", "wg",

@@ -1157,7 +1157,12 @@ class Engine:
         #: ``mla_decode`` kernel must read, a layer). Every model:
         #: ``attn_ctx_tokens``, the same sum (what each layer that attends
         #: reads of its pool in the fused decode dispatches; a full layer,
-        #: in a model with sliding ones). Sliding layers:
+        #: in a model with sliding ones), and ``decode_table_slots``, the
+        #: token slots the block tables of the same lanes and steps had room
+        #: for (real lanes x the dispatch's table width x the page: every
+        #: lane's table is as wide as the longest lane's bucket, so
+        #: ``attn_ctx_tokens`` over it is what of a table is context).
+        #: Sliding layers:
         #: ``window_ctx_tokens``, the sum of ``min(context, window)`` over
         #: the same lanes and steps (what a sliding layer reads of the
         #: window pool; 0 for a model without such layers). A kernel that
@@ -1187,6 +1192,7 @@ class Engine:
             "held_places": 0,
             "latent_ctx_tokens": 0,
             "attn_ctx_tokens": 0,
+            "decode_table_slots": 0,
             "window_ctx_tokens": 0,
             "ctx_pages": 0,
             "ctx_run_pages": 0,
@@ -3164,6 +3170,7 @@ class Engine:
                 (w_tables, w_starts) if self.window_pages is not None
                 else (block_tables, 0)
             ),
+            table_width=block_tables.shape[1],
         )
         # Start the D2H copy of the sampled ids now: the bytes land while
         # the host goes on (with a burst chained behind this one, while
@@ -3896,6 +3903,7 @@ class Engine:
         self, rows: int, temperature: np.ndarray,
         seq_lens: Optional[np.ndarray] = None, steps: int = 1,
         chained: bool = False, tables: Optional[tuple] = None,
+        table_width: int = 0,
     ) -> None:
         """``step_stats``' counters of one decode dispatch: its real lanes,
         whether any of them samples (``temperature`` is the host-side
@@ -3908,7 +3916,8 @@ class Engine:
         ``steps``: the forwards ``experts_touched`` is summed over.
         ``tables``: the table array a kernel that walks its lanes' tables
         itself was given and the position each row's first slot stands for
-        (``_count_ctx_pages``)."""
+        (``_count_ctx_pages``). ``table_width``: the pages of the dispatch's
+        block tables (``decode_table_slots``)."""
         if self.obs_step_timing:
             self.step_stats["decode_dispatches"] += 1
             self.step_stats["decode_forwards"] += steps
@@ -3923,6 +3932,9 @@ class Engine:
                     + rows * steps * (steps - 1) // 2
                 )
                 self.step_stats["attn_ctx_tokens"] += ctx
+                self.step_stats["decode_table_slots"] += (
+                    rows * steps * table_width * self.page_size
+                )
                 if self.model_cfg.kv_lora_rank:
                     self.step_stats["latent_ctx_tokens"] += ctx
                 if self.window_pages is not None:

@@ -381,6 +381,13 @@ class _ServingMetrics:
                 registry=self.registry,
             )
             self._attn_ctx_seen = 0
+            self.engine_table_slots = prom.Counter(
+                "kvcache_engine_decode_table_slots_total",
+                "Token slots the fused decode dispatches' block tables had "
+                "room for: real lanes x table width x page, a step",
+                registry=self.registry,
+            )
+            self._table_slots_seen = 0
             self.engine_window_ctx = prom.Counter(
                 "kvcache_engine_window_ctx_tokens_total",
                 "Context rows the fused decode dispatches read a sliding "
@@ -707,6 +714,10 @@ class _ServingMetrics:
         if attn_ctx > self._attn_ctx_seen:
             self.engine_attn_ctx.inc(attn_ctx - self._attn_ctx_seen)
             self._attn_ctx_seen = attn_ctx
+        table_slots = step_stats.get("decode_table_slots", 0)
+        if table_slots > self._table_slots_seen:
+            self.engine_table_slots.inc(table_slots - self._table_slots_seen)
+            self._table_slots_seen = table_slots
         window_ctx = step_stats.get("window_ctx_tokens", 0)
         if window_ctx > self._window_ctx_seen:
             self.engine_window_ctx.inc(window_ctx - self._window_ctx_seen)
@@ -4225,6 +4236,8 @@ def _resolve_model(name: str) -> LlamaConfig:
         "tiny-scmoe": models.TINY_SCMOE,
         "arcee-ai/Trinity-Large-Preview": models.TRINITY_LARGE_PREVIEW,
         "tiny-swa-moe": models.TINY_SWA_MOE,
+        "PowerInfer/SmallThinker-21BA3B-Instruct": models.SMALLTHINKER_21B_A3B,
+        "tiny-smallthinker": models.TINY_SMALLTHINKER,
         "inclusionAI/Ling-3.0-flash": models.LING_3_FLASH,
         "tiny-ling-hybrid": models.TINY_LING_HYBRID,
     }

@@ -23,6 +23,7 @@ from llm_d_kv_cache_manager_tpu.models import (
     TINY_SCMOE,
     TINY_SDAR_MOE,
     TINY_LING_HYBRID,
+    TINY_SMALLTHINKER,
     TINY_SWA_MOE,
     llama,
 )
@@ -51,6 +52,10 @@ CONFIGS = {
     "window": (TINY_SWA_MOE, EVERY | ROUTED | {"attn_window"}),
     # linear layers (the whole mixer under ``kda``) beside the latent ones
     "linear": (TINY_LING_HYBRID, EVERY | ROUTED | {"kda"}),
+    # every layer routed, its gates made ahead of its attention under a
+    # scope of their own: no dense FFN, no shared expert
+    "prerouted": (TINY_SMALLTHINKER, EVERY | {
+        "attn_window", "moe_preroute", "moe_router", "moe_experts"}),
 }
 
 
@@ -124,7 +129,7 @@ def _lowered_scopes(cfg, program) -> frozenset:
 CASES = [
     (kind, program)
     for kind in ("dense", "routed", "latent", "conv", "double", "window",
-                 "linear")
+                 "linear", "prerouted")
     for program in ("decode_steps", "prefill")
 ] + [("blocks", "prefill"), ("blocks", "denoise_steps")]
 
