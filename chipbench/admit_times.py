@@ -12,10 +12,8 @@ and then writes every stat into the text of the host's ``admit*`` and
 The form stays ``trace_reduce``'s plain form, so a test hands these functions
 planes made by hand and ``idle_gap_share.shares`` reads the same planes.
 
-Everything here is a time inside the traced window, so nothing holds the
-tail after the window's close as a ``step_after - step_before`` does. A
-program without the spans (one that predates them) gives ``{}``, and every
-reader ``None``."""
+Everything here is a time inside the traced window. A program without the
+spans (one that predates them) gives ``{}``, and every reader ``None``."""
 
 from __future__ import annotations
 

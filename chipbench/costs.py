@@ -76,7 +76,10 @@ def kv_bytes_per_token(cfg) -> int:
 def expected_experts_touched(cfg, lanes: float) -> float:
     """Distinct experts a decode step of ``lanes`` tokens reads in one layer
     when each token picks its top-k uniformly (a random router):
-    E * (1 - (1 - k/E) ** lanes). 16 lanes x top-8 of 128: 82."""
+    E * (1 - (1 - k/E) ** lanes). 16 lanes x top-8 of 128: 82. An
+    expectation to hold a count against (the program counts 60: its rows do
+    not route independently); nothing that divides by a traced time calls
+    it."""
     e, k = cfg.n_experts, cfg.n_experts_per_tok
     return e * (1.0 - (1.0 - k / e) ** lanes)
 
@@ -86,7 +89,8 @@ def decode_step_min_bytes(cfg, lanes: int, mean_context_tokens: float,
     """The least a decode step must read from HBM: every layer's attention
     weights, the FFN weights the batch touches (all of a dense FFN; for a
     sparse one ``experts_touched`` experts, default every expert: an upper
-    bound, so a roofline share must pass ``expected_experts_touched``), the
+    bound, so a roofline share passes what the program counted,
+    ``program_counts.experts_touched_per_layer``, never an expectation), the
     output head, one embedding row a lane, and each lane's live keys and
     values."""
     d = cfg.hidden_size

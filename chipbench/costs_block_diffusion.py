@@ -52,10 +52,10 @@ def denoise_forward_min_bytes(cfg, lanes: float, mean_context_tokens: float,
 
 
 def live_lanes_and_context(run) -> tuple[float, float]:
-    """(lanes busy, mean context of a running request) of a window, as
-    ``decode_step_roofline`` reads them: the 10 Hz samples of the first
-    replica's running sequences, and prompt + half the output over the
-    completed requests."""
+    """(lanes busy, mean context of a running request) of a window,
+    estimated: the 10 Hz samples of the first replica's running sequences,
+    and prompt + half the output over the completed requests (the block
+    path counts neither lanes nor context rows: PERF.md section 7)."""
     ctx = [r["prompt_len"] + r["max_tokens"] / 2 for r in run.good]
     lanes = run.lanes
     if run.running_samples:

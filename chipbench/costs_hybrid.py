@@ -100,16 +100,15 @@ def page_bytes(cfg, page: int) -> int:
 
 
 def decode_step_min_bytes(cfg, lanes: float, ctx_tokens: float,
-                          experts_touched: float = None) -> float:
+                          experts_touched: float) -> float:
     """The least a decode step must read from HBM: every layer's operator
     weights by kind, the dense layers' FFN, ``experts_touched`` routed
-    experts an expert layer (default: the expectation for ``lanes`` x top-k
-    uniform draws) with the router, the head, one embedding row a lane,
-    ``ctx_tokens`` live keys and values of the attention layers (the real
-    lanes' contexts, summed: ``step_stats["attn_ctx_tokens"]`` a dispatch)
-    and one state slot a convolution layer a lane."""
-    if experts_touched is None:
-        experts_touched = costs.expected_experts_touched(cfg, lanes)
+    experts an expert layer (what the program counted: a caller passes a
+    count, never an expectation over a router's draws) with the router, the
+    head, one embedding row a lane, ``ctx_tokens`` live keys and values of
+    the attention layers (the real lanes' contexts, summed:
+    ``step_stats["attn_ctx_tokens"]`` a forward) and one state slot a
+    convolution layer a lane."""
     dense = n_dense(cfg)
     params = (operator_params(cfg) + dense * dense_ffn_params(cfg)
               + (cfg.n_layers - dense) * expert_ffn_params(cfg, experts_touched)

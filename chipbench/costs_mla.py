@@ -34,11 +34,24 @@ def latent_bytes_per_token_per_layer(cfg, held: bool = True) -> int:
     return values * costs.itemsize(cfg)
 
 
+def n_attentions(cfg) -> int:
+    """The latent attentions of the depth that is run, which is the layer
+    axis of the pool: two a published layer for a double layer
+    (``longcat-flash-omni``: 8 for 4 layers), the layers that are not
+    linear ones where ``layer_types`` names kinds (``ling-3.0-flash``: 1 of
+    7), every layer otherwise (``kanana-2-30b-a3b``: 8 of 8)."""
+    if getattr(cfg, "double_layer", False):
+        return 2 * cfg.n_layers
+    kinds = list(getattr(cfg, "layer_types", None) or ())[: cfg.n_layers]
+    return cfg.n_layers - kinds.count("linear_attention")
+
+
 def latent_bytes_per_token(cfg, held: bool = True) -> int:
-    """One token's rows in every layer: what ``/stats``'
-    ``kv_bytes_per_token`` must read (8 x 1280 in the cell; 8 x 1152 if the
-    row were held unpadded)."""
-    return cfg.n_layers * latent_bytes_per_token_per_layer(cfg, held)
+    """One token's rows in every attention: what ``/stats``'
+    ``kv_bytes_per_token`` must read (8 x 1280 in ``docqa`` and in
+    ``turns``, 1 x 1280 in ``threads``; 8 x 1152 if the row were held
+    unpadded)."""
+    return n_attentions(cfg) * latent_bytes_per_token_per_layer(cfg, held)
 
 
 def attn_params_per_layer(cfg) -> int:
@@ -76,11 +89,11 @@ def resident_weight_bytes(cfg) -> int:
 
 
 def mla_decode_bytes(cfg, ctx_tokens: float) -> float:
-    """What the ``mla_decode`` kernel's calls of ONE decode step (one call a
-    layer) must read: ``ctx_tokens`` latent rows (the live lanes' contexts,
-    summed: ``step_stats["latent_ctx_tokens"]`` a dispatch) in every layer,
-    as held. Queries, the fresh rows and the outputs are under a thousandth
-    at the cell's contexts and left out."""
+    """What the ``mla_decode`` kernel's calls of ONE decode step (one call
+    an attention) must read: ``ctx_tokens`` latent rows (the live lanes'
+    contexts, summed: ``step_stats["latent_ctx_tokens"]`` a dispatch) in
+    every attention, as held. Queries, the fresh rows and the outputs are
+    under a thousandth at the cell's contexts and left out."""
     return ctx_tokens * latent_bytes_per_token(cfg, held=True)
 
 
@@ -88,18 +101,17 @@ def mla_decode_flops(cfg, ctx_tokens: float) -> float:
     """Matmul FLOPs of the same calls: every head's score against a row's
     ``row_values`` and its sum over the row's ``kv_lora_rank``."""
     per_row = 2 * cfg.n_heads * (row_values(cfg) + cfg.kv_lora_rank)
-    return cfg.n_layers * ctx_tokens * per_row
+    return n_attentions(cfg) * ctx_tokens * per_row
 
 
 def decode_step_min_bytes(cfg, lanes: int, ctx_tokens: float,
-                          experts_touched: float = None) -> float:
+                          experts_touched: float) -> float:
     """The least a decode step must read from HBM: every layer's attention
     weights, the dense layers' FFN, ``experts_touched`` routed experts a
-    layer (default: the expectation for ``lanes`` x top-k uniform draws)
-    with the router and the shared experts, the head, one embedding row a
-    lane, and the live latent rows."""
-    if experts_touched is None:
-        experts_touched = costs.expected_experts_touched(cfg, lanes)
+    layer (what the program counted: a caller passes a count, never an
+    expectation over a router's draws) with the router and the shared
+    experts, the head, one embedding row a lane, and the live latent rows
+    (a model of single layers that all attend: ``kanana-2-30b-a3b``)."""
     dense = min(cfg.first_k_dense, cfg.n_layers)
     params = (dense * dense_layer_params(cfg)
               + (cfg.n_layers - dense) * expert_layer_params(cfg, experts_touched)

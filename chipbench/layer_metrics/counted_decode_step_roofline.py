@@ -1,17 +1,15 @@
 """The least time a decode step could take over the time it took, from the
-program's own counts alone: ``costs.decode_step_min_bytes`` (the cost
-function ``decode_step_roofline`` uses, unedited) fed the real lanes a
-forward (``decode_rows`` / ``decode_dispatches``), their mean context
+program's own counts alone: ``costs.decode_step_min_bytes`` fed the real
+lanes a forward (``decode_rows`` / ``decode_dispatches``), their mean context
 (``attn_ctx_tokens`` over lanes x forwards) and, for a routed model, the
 distinct experts a layer a forward the program counted on the device
 (``decode_experts_touched_mean``), / the chip's HBM bandwidth, over the mean
 device time of the module ``decode_steps`` in the trace (a dispatch's fused
-steps times the bytes of one). No expectation over independent draws, no
-10 Hz sample, no request lengths. The counters are read after the window's
-close (PERF.md 7 (g)): the emptying tail has fewer lanes, fewer experts and
-less context, so the bytes, and the share, read low against the traced
-steps, never high. Bound: HBM bandwidth. None where the program does not
-count (one from before the counters) or the trace holds no such module."""
+steps times the bytes of one). No expectation over independent draws, no 10
+Hz sample, no request lengths. The counters are the window's: read at its
+close (``run.py``, ``on_close``), as the traced steps are. Bound: HBM
+bandwidth. None where the program does not count (one from before the
+counters) or the trace holds no such module."""
 
 from chipbench import costs, program_counts, trace_reduce
 

@@ -2,12 +2,11 @@
 layer, as the program counted them on the device: the window's growth of
 ``step_stats["experts_touched"]`` (``llama._moe_mlp_routed``: the experts
 whose weights the grouped matmuls read, padded lanes' rows included; fetched
-with the burst's tokens) over that of ``decode_forwards`` (dispatches x
-fused steps) and ``/stats``' ``routed_layers``. Under block diffusion it is
-``block_counters.experts_touched_per_layer``. Like every reader of
-``step_after`` it includes the emptying tail after the window (fewer lanes,
-so fewer experts: it reads low, never high). None for a program that does
-not count them on the path the cell runs."""
+with the burst's tokens) over that of ``decode_forwards`` (dispatches x fused
+steps) and ``/stats``' ``routed_layers``. Under block diffusion it is
+``block_counters.experts_touched_per_layer``. ``step_after`` is read at the
+window's close, so the emptying tail (fewer lanes, so fewer experts) is not
+in it. None for a program that does not count them on the path the cell runs."""
 
 from chipbench import program_counts
 

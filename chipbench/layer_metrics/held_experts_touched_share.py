@@ -1,11 +1,11 @@
-"""The share of the experts this process holds that a routed layer read in
-a decode forward (mean over layers and forwards): the growth of
+"""The share of the experts this process holds that a routed layer read in a
+decode forward (mean over layers and forwards): the growth of
 ``step_stats["experts_touched"]`` over forwards x ``routed_layers`` x
 ``/stats``' ``experts_held``. It says how near the one-rank share is to its
 deployment, which batches enough tokens to touch every expert (100 %): the
-grouped matmuls are bound by the weights they read. Like every reader of
-``step_after`` it includes the emptying tail after the window (fewer lanes:
-it reads low, never high). None for a program that does not count them."""
+grouped matmuls are bound by the weights they read. ``step_after`` is read at
+the window's close, so the emptying tail is not in it. None for a program
+that does not count them."""
 
 from chipbench import scmoe_counts
 

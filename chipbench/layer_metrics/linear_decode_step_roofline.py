@@ -1,5 +1,5 @@
-"""The least time a decode step of a hybrid of linear and latent attention
-that holds a share of its experts could take over the time it took:
+"""The least time a decode step of a hybrid of linear and latent attention that
+holds a share of its experts could take over the time it took:
 ``costs_kda.decode_step_min_s`` (the larger of the step's bytes over the HBM
 bandwidth and its FLOPs over the bf16 peak) fed ONLY what the program
 counted: the real lanes a forward (``decode_rows`` / ``decode_dispatches``),
@@ -7,11 +7,10 @@ their context rows a forward (``attn_ctx_tokens`` / ``decode_forwards``), the
 held experts a routed layer read (``experts_touched``) and the rows its
 grouped matmuls computed (``held_places``), over the mean device time of the
 module ``decode_steps`` in the trace. No expectation over a router's draws,
-no 10 Hz sample, no request lengths. The counters are read after the
-window's close (PERF.md 7 (g)): the emptying tail has fewer lanes, fewer
-experts and less context, so the share reads low against the traced steps,
-never high. None where the program does not count, the model is no such
-model, or the trace holds no such module."""
+no 10 Hz sample, no request lengths. The counters are the window's: read at
+its close (``run.py``, ``on_close``), as the traced steps are. None where the
+program does not count, the model is no such model, or the trace holds no
+such module."""
 
 from chipbench import costs_kda, kda_counts, scmoe_counts, trace_reduce
 

@@ -1,12 +1,13 @@
 """The least time the ``mla_decode`` kernel's calls of one decode step could
-take over the time they took: the live lanes' latent rows in every layer, as
-held (``costs_mla.mla_decode_bytes`` of the context rows the program counted
-a dispatch: ``step_stats["latent_ctx_tokens"]`` over ``decode_dispatches``) /
+take over the time they took: the live lanes' latent rows in every attention
+of the pool (``costs_mla.n_attentions``: two a double layer), as held
+(``costs_mla.mla_decode_bytes`` of the context rows the program counted a
+dispatch: ``step_stats["latent_ctx_tokens"]`` over ``decode_dispatches``) /
 the chip's HBM bandwidth, over the kernel's device time in the trace divided
-by the calls of the module ``decode_steps`` (one kernel call a layer a step).
-Bound: HBM bandwidth (the matmuls of 32 heads against a row are 60 FLOP a
-byte, a quarter of the chip's ridge). None where the program does not count
-the rows (a program from before the counter, or a model with no latent
+by the calls of the module ``decode_steps`` (one kernel call an attention a
+step). Bound: HBM bandwidth (the matmuls of 32 heads against a row are 60
+FLOP a byte, a quarter of the chip's ridge). None where the program does not
+count the rows (a program from before the counter, or a model with no latent
 pool) or the trace holds no such kernel."""
 
 from chipbench import costs_mla, trace_reduce

@@ -8,10 +8,9 @@ full layer read of the context pool a forward (``attn_ctx_tokens`` /
 (``window_ctx_tokens``), the experts a layer read (``experts_touched``), over
 the mean device time of the module ``decode_steps`` in the trace (a
 dispatch's fused steps times one step's least): the share of the whole step.
-The counters are read after the window's close (PERF.md 7 (g)): the emptying
-tail has fewer lanes, fewer experts and less context, so the share reads low
-against the traced steps, never high. None where the program does not count,
-the model is no such model, or the trace holds no such module."""
+The counters are the window's: read at its close (``run.py``, ``on_close``),
+as the traced steps are. None where the program does not count, the model is
+no such model, or the trace holds no such module."""
 
 from chipbench import costs_prerouted, swa_counts, trace_reduce
 

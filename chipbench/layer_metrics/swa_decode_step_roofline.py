@@ -1,5 +1,5 @@
-"""The least time a decode step of a window-and-full model that holds a
-share of its experts could take over the time it took: ``costs_swa.
+"""The least time a decode step of a window-and-full model that holds a share of
+its experts could take over the time it took: ``costs_swa.
 decode_step_min_s`` (the larger of the step's bytes over the HBM bandwidth
 and its FLOPs over the bf16 peak) fed ONLY what the program counted: the real
 lanes a forward (``decode_rows`` / ``decode_dispatches``), the positions a
@@ -9,12 +9,10 @@ full layer read of the context pool a forward (``attn_ctx_tokens`` /
 (``experts_touched``; every place on a held expert is a row of its grouped
 matmuls: at most lanes x top-k), over the mean device time of the module
 ``decode_steps`` in the trace (a dispatch's fused steps times one step's
-least): the share of the whole step. The counters are read after the
-window's close (PERF.md 7 (g)): the emptying tail has fewer lanes, fewer
-experts and less context, so the share reads low against the traced steps,
-never high. None where the program does not count (the parent of the PR that
-added the counters), the model is no such model, or the trace holds no such
-module."""
+least): the share of the whole step. The counters are the window's: read at
+its close (``run.py``, ``on_close``), as the traced steps are. None where the
+program does not count (the parent of the PR that added the counters), the
+model is no such model, or the trace holds no such module."""
 
 from chipbench import costs_swa, swa_counts, trace_reduce
 

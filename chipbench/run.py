@@ -218,6 +218,24 @@ class CompileCounter:
             self.count += 1
 
 
+def at_the_close(pods) -> tuple:
+    """``run_window``'s ``on_close`` for these pods, called at the instant
+    the window closes, before anything is aborted: (what the requests still
+    in the schedulers have generated so far, every one of them having
+    arrived inside the window; a copy of each engine's ``step_stats``).
+    In-process reads, nothing waited for, so the counters the readers see
+    are the window's and hold none of the dispatches of fewer lanes that
+    the aborts, the profiler's stop and the emptying tail add after it."""
+    tokens = sum(
+        seq.num_generated for p in pods
+        for queue in (p.engine.scheduler.running,
+                      p.engine.scheduler.prefilling,
+                      p.engine.scheduler.waiting)
+        for seq in list(queue)
+    )
+    return tokens, [dict(p.engine.step_stats) for p in pods]
+
+
 # -- set-up traffic -----------------------------------------------------------
 def must_succeed(records, what: str) -> None:
     for r in records:
@@ -382,17 +400,6 @@ def run(args) -> dict:
         def tick():
             samples.append([len(p.engine.scheduler.running) for p in pods])
 
-        def tokens_in_flight():
-            # what the requests still in the schedulers have generated so
-            # far; every one of them arrived inside the window
-            return sum(
-                seq.num_generated for p in pods
-                for queue in (p.engine.scheduler.running,
-                              p.engine.scheduler.prefilling,
-                              p.engine.scheduler.waiting)
-                for seq in list(queue)
-            )
-
         trace_dir = os.path.join(HERE, "out", "trace")
         tracer = None
         trace_window = [0.0]
@@ -418,7 +425,7 @@ def run(args) -> dict:
             tracer.start()
         result = client.run(gw.run_window(
             gateway, schedule, args.seconds, on_tick=tick,
-            on_close=tokens_in_flight,
+            on_close=lambda: at_the_close(pods),
         ))
         compiles_in_window = compiles.count - compiles_before
         if tracer:
@@ -426,14 +433,14 @@ def run(args) -> dict:
             trace_window[0] = time.perf_counter() - trace_window[0]
             jax.profiler.stop_trace()
             profiling.clear()
+        # an HTTP round trip a pod at the close would hold the aborts back
         stats_after = [fl.http("GET", f"{p.url}/stats")[1] for p in pods]
-        step_after = [dict(p.engine.step_stats) for p in pods]
         peak = memory_peak(devices)
 
         good, failed, _ = metrics.split(result["records"])
         in_flight = result["in_flight"]
         window_s = result["window_s"]
-        in_flight_tokens = int(result["at_close"])
+        in_flight_tokens, step_after = result["at_close"]
         for r in failed[:5]:
             say(f"FAILED request {r['index']}: {metrics.response_fault(r)}")
         say(f"window {window_s:.3f} s: {len(good)} completed, {len(failed)} "

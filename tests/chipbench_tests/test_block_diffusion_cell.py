@@ -4,11 +4,11 @@ functions) and that nothing the benchmark had was touched. No chip, no
 child process; nothing here is a measurement.
 """
 
-import hashlib
 import json
 import os
 import types
 
+import accepted_entries
 import pytest
 
 from chipbench import block_counters, costs, probe_block_diffusion, run, traffic
@@ -22,30 +22,65 @@ NEW_METRICS = ["tokens_per_forward_mean", "forwards_per_block_mean",
                "kernel_time_share.block_attention", "block_attention_roofline"]
 
 
+#: what the benchmark held when PR 28 began, by name (``BENCHMARK.json`` at
+#: PR 27, less ``decode_step_roofline``, which PR 55 retired): nothing here
+#: says where in its list an entry stands
+ACCEPTED = {
+    "configs": "qwen3-32b qwen3-30b-a3b",
+    "workloads": "qwen3-32b.sessions qwen3-30b-a3b.reasoning",
+    "end_to_end": "ttft_ms_p50 itl_ms_p50 out_tokens_per_s setup_s",
+    "per_layer": """
+        score_ms_p50 prefix_hit_share prefix_hit_share.bypass pool_cached_share
+        pod_ttft_ms_p50 ttft_ms_p95 lanes_busy_mean prefill_rows_mean step_ms_mean
+        compiles_in_window.serve compiles_in_window.decode
+        kernel_time_share.paged_attention kernel_time_share.flash_prefill
+        kernel_time_share.gmm device_idle_share peak_hbm_gib loadgen_late_ms_p95
+        step_phase_ms.schedule step_phase_ms.decode_build step_phase_ms.decode_put
+        step_phase_ms.decode_dispatch step_phase_ms.decode_fetch
+        step_phase_ms.decode_commit step_phase_ms.publish step_phase_ms.loop
+        step_phase_ms.prefill_build step_phase_ms.prefill_put
+        step_phase_ms.prefill_dispatch step_phase_ms.prefill_fetch
+        step_phase_ms.prefill_commit step_phase_ms.prefill idle_gap_share.schedule
+        idle_gap_share.prefill_build idle_gap_share.prefill_put
+        idle_gap_share.prefill_dispatch idle_gap_share.prefill_fetch
+        idle_gap_share.prefill_commit idle_gap_share.decode_build
+        idle_gap_share.decode_put idle_gap_share.decode_dispatch
+        idle_gap_share.decode_fetch idle_gap_share.decode_commit
+        idle_gap_share.publish idle_gap_share.loop idle_gap_share.unattributed
+        queue_wait_ms_p50 staged_wait_ms_p50 decode_rows_mean
+        sampled_dispatch_share""",
+}
+
+
 def test_old_entries_are_as_they_were():
     """The benchmark PR 27 left (2 configurations, 2 cells, 4 end-to-end and
-    50 per-layer metrics, command, paths, run_seconds), byte for byte: the
-    new entries stand at the ends of their lists."""
-    old = dict(BENCH, configs=BENCH["configs"][:2], workloads=BENCH["workloads"][:2],
-               per_layer=BENCH["per_layer"][:50])
-    digest = hashlib.sha256(json.dumps(old, sort_keys=True).encode()).hexdigest()
-    assert digest == "a2906a638115c8d6b361bbfbb3cee35087fb2b7e111560b61ceebf9a51b52572"
-    assert [c["name"] for c in BENCH["configs"][2:]] == ["sdar-30b-a3b"]
-    assert [w["name"] for w in BENCH["workloads"][2:]] == [CELL]
-    assert [m["name"] for m in BENCH["per_layer"][50:]] == NEW_METRICS
-    for m in BENCH["per_layer"][50:]:
-        assert m["workloads"] == [CELL]
+    50 per-layer metrics, command, paths, run_seconds), as they stood: each
+    accepted entry is looked up by its NAME and held without its
+    ``workloads`` list (``accepted_entries.py``), never by its place, so the
+    cells and entries every later PR appended do not falsify this. The new
+    entries exist, once each, and list the new cell."""
+    assert accepted_entries.digest(BENCH, ACCEPTED) == "0a128620931e470d7a72a288f5d85b57929c416a2037a0ac19b2bd71af19a6ad"
+    # ... less ``decode_step_roofline``, which PR 55 retired
+    assert accepted_entries.count(ACCEPTED) == 2 + 2 + 4 + 49
+    assert "sdar-30b-a3b" in [c["name"] for c in BENCH["configs"]]
+    assert CELL in [w["name"] for w in BENCH["workloads"]]
+    names = [m["name"] for m in BENCH["per_layer"]]
+    by_name = {m["name"]: m for m in BENCH["per_layer"]}
+    for name in NEW_METRICS:
+        assert names.count(name) == 1 and CELL in by_name[name]["workloads"]
 
 
 def test_the_accepted_cells_mixes_still_state_no_request():
     """What ``test_the_cells_own_mixes_state_no_request`` held, for the cells
-    it was written for; the new cell's mix states exactly one key."""
+    it was written for; the mixes are looked up by name in the cells that
+    run them, whatever cells came later."""
+    by_cell = {w["name"]: w["traffic"] for w in BENCH["workloads"]}
+    assert (by_cell["qwen3-32b.sessions"], by_cell["qwen3-30b-a3b.reasoning"],
+            by_cell[CELL]) == ("sessions", "reasoning", "blockgen")
     for name in ("sessions", "reasoning"):
         spec = traffic.load_traffic(name)
         assert "request" not in spec and "request_share" not in spec
         assert traffic.request_params(spec, 3, 6) == [None] * 3
-    assert [w["traffic"] for w in BENCH["workloads"]] == [
-        "sessions", "reasoning", "blockgen"]
 
 
 def test_costs_of_the_new_configuration_against_hand_sums():

@@ -21,11 +21,13 @@ def counts(dispatches, chained=None):
 def test_the_entry():
     # looked up by its name: where it stands in the list is not held
     (entry,) = [m for m in BENCH["per_layer"] if m["name"] == NAME]
-    assert entry == {
+    first = [SESSIONS, REASONING, DOCQA]
+    assert {**entry, "workloads": first} == {
         "name": NAME, "unit": "%", "better": "higher",
         "source": "program_counter", "layer": "engine step",
-        "moves": "itl_ms_p50", "workloads": [SESSIONS, REASONING, DOCQA],
+        "moves": "itl_ms_p50", "workloads": first,
     }
+    assert set(first) <= set(entry["workloads"])  # a benchmark PR adds cells
     cells = {w["name"] for w in BENCH["workloads"]}
     moved = next(m for m in BENCH["end_to_end"] if m["name"] == entry["moves"])
     assert set(entry["workloads"]) <= cells

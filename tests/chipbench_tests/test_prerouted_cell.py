@@ -30,17 +30,18 @@ NEW_METRICS = {
 
 
 def test_this_prs_entries_list_the_new_cell_alone():
+    """They exist and list the cell; nothing here says that nobody else
+    does, nor where in ``per_layer`` they stand (a ``benchmark`` PR lists a
+    cell a reader works in, as PR 55 did this cell under accepted entries,
+    and later PRs append)."""
     by_name = {m["name"]: m for m in BENCH["per_layer"]}
     for name, (layer, moves) in NEW_METRICS.items():
-        assert by_name[name]["workloads"] == [CELL]
+        assert CELL in by_name[name]["workloads"]
         assert (by_name[name]["layer"], by_name[name]["moves"]) == (layer, moves)
         assert callable(run.load_layer_metric(name))  # by file or by family
-    # no accepted entry names the new cell: they are as they were
-    for m in BENCH["per_layer"] + BENCH["end_to_end"]:
-        if m["name"] not in NEW_METRICS:
-            assert CELL not in m.get("workloads", [])
-    # (nothing here says where in its list an entry stands: the next PR's
-    # entries come after these)
+    # no end-to-end entry was given a list for the new cell
+    for m in BENCH["end_to_end"]:
+        assert CELL not in m.get("workloads", [])
     cell = run.find_cell(BENCH, CELL)
     config = next(c for c in BENCH["configs"] if c["name"] == CONFIG)
     assert len(cell["why"]) <= 200 and len(config["why"]) <= 200
@@ -49,9 +50,10 @@ def test_this_prs_entries_list_the_new_cell_alone():
     assert config["source"] == SOURCE
     e2e = {m["name"] for m in run.metrics_of_cell(BENCH["end_to_end"], CELL)}
     assert e2e == {"itl_ms_p50", "out_tokens_per_s", "setup_s"}
-    # the accepted metrics without a list are read in the new cell too
+    # the accepted metrics without a list are read in the new cell too (and
+    # those a ``benchmark`` PR found to work here: a superset)
     read_here = {m["name"] for m in run.metrics_of_cell(BENCH["per_layer"], CELL)}
-    assert read_here == set(NEW_METRICS) | {
+    assert read_here >= set(NEW_METRICS) | {
         "lanes_busy_mean", "step_ms_mean", "kernel_time_share.paged_attention",
         "device_idle_share", "peak_hbm_gib"}
 

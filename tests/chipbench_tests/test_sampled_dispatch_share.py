@@ -18,10 +18,9 @@ def counts(dispatches, sampled=None):
 
 def test_the_entry():
     (entry,) = [m for m in BENCH["per_layer"] if m["name"] == NAME]
-    assert entry == BENCH["per_layer"][-1]  # appended: nothing before it moved
     assert entry["source"] == "program_counter" and entry["unit"] == "%"
     assert entry["layer"] == "engine step" and entry["moves"] == "itl_ms_p50"
-    assert entry["workloads"] == [SESSIONS, REASONING]
+    assert {SESSIONS, REASONING} <= set(entry["workloads"])  # by name
 
 
 def test_the_engine_counts_what_it_reads():

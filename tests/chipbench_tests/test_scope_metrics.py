@@ -45,13 +45,14 @@ NEW = {
 def test_the_entry(name):
     unit, better, source, moves, cells = NEW[name]
     (entry,) = [m for m in BENCH["per_layer"] if m["name"] == name]
-    assert entry == {"name": name, "unit": unit, "better": better,
-                     "source": source, "layer": "model step", "moves": moves,
-                     "workloads": cells}
+    assert {**entry, "workloads": cells} == {
+        "name": name, "unit": unit, "better": better, "source": source,
+        "layer": "model step", "moves": moves, "workloads": cells}
+    assert set(cells) <= set(entry["workloads"])  # a benchmark PR adds cells
     part = name.partition(".")[2]
     assert not part or part == "unscoped" or part in SCOPES
     run.load_layer_metric(name)  # a reader is found by the name
-    for cell in cells:  # every listed cell reports the metric it moves
+    for cell in entry["workloads"]:  # every listed cell reports what it moves
         moved = next(m for m in BENCH["end_to_end"] if m["name"] == moves)
         assert "workloads" not in moved or cell in moved["workloads"]
 

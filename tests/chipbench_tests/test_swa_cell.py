@@ -4,11 +4,11 @@ cost functions, probe) and that nothing the benchmark had was touched. No
 chip, no child process; nothing here is a measurement.
 """
 
-import hashlib
 import json
 import os
 import types
 
+import accepted_entries
 import pytest
 
 from chipbench import costs, costs_swa, run, traffic
@@ -42,7 +42,7 @@ ACCEPTED = {
         score_ms_p50 prefix_hit_share prefix_hit_share.bypass pool_cached_share
         pod_ttft_ms_p50 ttft_ms_p95 lanes_busy_mean prefill_rows_mean
         step_ms_mean compiles_in_window.serve compiles_in_window.decode
-        decode_step_roofline kernel_time_share.paged_attention
+        kernel_time_share.paged_attention
         kernel_time_share.flash_prefill kernel_time_share.gmm device_idle_share
         peak_hbm_gib loadgen_late_ms_p95 step_phase_ms.schedule
         step_phase_ms.decode_build step_phase_ms.decode_put
@@ -80,23 +80,23 @@ ACCEPTED = {
 
 def test_accepted_entries_are_as_they_were():
     """The benchmark PR 42 left (6 configurations, 6 cells, 4 end-to-end and
-    89 per-layer metrics, command, paths, run_seconds), byte for byte: each
+    89 per-layer metrics, command, paths, run_seconds), as they stood: each
     accepted entry is looked up by its name, so an entry that a later PR
-    appends, wherever it stands, does not falsify this."""
-    held = {key: BENCH[key] for key in ("command", "paths", "run_seconds")}
-    for section, names in ACCEPTED.items():
-        by_name = {entry["name"]: entry for entry in BENCH[section]}
-        assert len(by_name) == len(BENCH[section])  # no name twice
-        held[section] = {name: by_name[name] for name in names.split()}
-    digest = hashlib.sha256(json.dumps(held, sort_keys=True).encode()).hexdigest()
-    assert digest == "a7f625cd00ad8949c41947652276c135ecf5c9760aa6b71fc091711dc6852a66"
-    assert sum(len(v.split()) for v in ACCEPTED.values()) == 6 + 6 + 4 + 89
+    appends, wherever it stands, does not falsify this, and without its
+    ``workloads`` list, which a ``benchmark`` PR may widen
+    (``accepted_entries.py``)."""
+    assert accepted_entries.digest(BENCH, ACCEPTED) == "c9592f9e160d2f2c310928fff75d7fda06f833bf7df14dbcfa0e649f4c688159"
+    # ... less ``decode_step_roofline``, which PR 55 retired
+    assert accepted_entries.count(ACCEPTED) == 6 + 6 + 4 + 88
 
 
 def test_this_prs_entries_list_the_new_cell_alone():
+    """They exist and list the cell; nothing here says that nobody else
+    does, nor where in ``per_layer`` they stand (a ``benchmark`` PR lists a
+    cell a reader works in, and later PRs append)."""
     by_name = {m["name"]: m for m in BENCH["per_layer"]}
     for name, (layer, moves) in NEW_METRICS.items():
-        assert by_name[name]["workloads"] == [CELL]
+        assert CELL in by_name[name]["workloads"]
         assert (by_name[name]["layer"], by_name[name]["moves"]) == (layer, moves)
         assert callable(run.load_layer_metric(name))  # by file or by family
     cell = run.find_cell(BENCH, CELL)
@@ -109,9 +109,10 @@ def test_this_prs_entries_list_the_new_cell_alone():
                                 "Trinity-Large-Preview/blob/main/config.json")
     e2e = {m["name"] for m in run.metrics_of_cell(BENCH["end_to_end"], CELL)}
     assert e2e == {"itl_ms_p50", "out_tokens_per_s", "setup_s"}
-    # the accepted metrics without a list are read in the new cell too
+    # the accepted metrics without a list are read in the new cell too (and
+    # those a ``benchmark`` PR found to work here: a superset)
     read_here = {m["name"] for m in run.metrics_of_cell(BENCH["per_layer"], CELL)}
-    assert read_here == set(NEW_METRICS) | {
+    assert read_here >= set(NEW_METRICS) | {
         "lanes_busy_mean", "step_ms_mean", "kernel_time_share.paged_attention",
         "device_idle_share", "peak_hbm_gib"}
 
