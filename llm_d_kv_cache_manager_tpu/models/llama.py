@@ -126,9 +126,11 @@ def _paged_attention_tp(
     ``wo`` matmul immediately after carries the cross-shard reduction).
 
     ``kp``/``vp`` are the FULL multi-layer pools ``[L, P, ps, n_kv, hd]``
-    with ``layer`` resolved inside the kernel's index map — slicing the
-    layer here would force XLA to copy a whole per-layer pool per call
-    (see paged_attention's docstring). ``fresh_k``/``fresh_v``
+    with ``layer`` a word the kernel reads (built inside the mapped function,
+    so every layer's call is one kernel a shard) — slicing the layer here
+    would force XLA to copy a whole per-layer pool per call (see
+    paged_attention's docstring). A shard walks its lanes' pages over its
+    own heads as one device does over all. ``fresh_k``/``fresh_v``
     ([b, n_kv, hd]) carry the current token's K/V so pool writes can be
     deferred past attention. ``window`` / ``table_start``: a sliding
     layer's call over the window pools (``ops/paged_attention.py``; single
@@ -3531,7 +3533,7 @@ def _decode_body(
                     seen = dict(k_scale=k_scales, v_scale=v_scales)
                 attn = _unpack_heads(cfg, _paged_attention_tp(
                     q[:, 0],  # [b, n_heads, hd]
-                    *pools,  # FULL [L, P, ps, n_kv, hd] pools; layer via index map
+                    *pools,  # FULL [L, P, ps, n_kv, hd] pools; layer a word
                     seq_lens,
                     k[:, 0],  # [b, n_kv, hd]
                     v[:, 0],

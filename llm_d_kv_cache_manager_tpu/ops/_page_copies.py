@@ -13,12 +13,14 @@ SMEM, ``G - 1`` compares a group, and is rebuilt from the same words when the
 copies are waited for, as the handles are.
 
 - ``for_step_pages`` is the loop (``ops/mla_attention.py::_mla_kernel`` and
-  ``ops/paged_attention.py::_window_decode_kernel`` call it, at start and at
-  wait time); ``group_is_run`` its predicate.
+  ``ops/paged_attention.py::_walk_decode_kernel``, the full-context call and
+  the sliding layers', call it, at start and at wait time);
+  ``group_is_run`` its predicate.
 - ``count_run_pages`` is the predicate's numpy twin over a dispatch's whole
-  table array, for ``Engine._count_decode_dispatch`` (``step_stats
-  ["ctx_pages"]`` / ``["ctx_run_pages"]``; ``tests/test_page_copies.py`` holds
-  the two to one answer).
+  table array, for ``Engine._count_ctx_pages`` (``step_stats["ctx_pages"]``
+  / ``["ctx_run_pages"]`` of the latent or the window walk,
+  ``["full_ctx_pages"]`` / ``["full_ctx_run_pages"]`` of a full layer's;
+  ``tests/test_page_copies.py`` holds the two to one answer).
 
 Groups count from a step's first slot, and a group that reaches past the
 lane's last live page is never a run: a dead table tail may hold anything
@@ -47,6 +49,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -64,13 +67,14 @@ def group_pages(step_pages: int, pool_pages: int) -> int:
 def group_is_run(ids):
     """Whether a group's pool ids ``ids`` (a scalar a page, every page live)
     are one run: ``ids[j] == ids[0] + j`` throughout."""
-    ok = ids[1] == ids[0] + 1
+    ok = lax.eq(ids[1], lax.add(ids[0], np.int32(1)))
     for j in range(2, len(ids)):
-        ok = jnp.logical_and(ok, ids[j] == ids[0] + j)
+        ok = lax.bitwise_and(ok, lax.eq(ids[j], lax.add(ids[0], np.int32(j))))
     return ok
 
 
-def for_step_pages(act, table_ref, lane, first, n_live, layer, streams):
+def for_step_pages(act, table_ref, lane, first, n_live, layer, streams,
+                   rolled: bool = False):
     """``act`` on the copy of every live page of one step, runs as one.
 
     ``table_ref[lane, first + i]`` is the pool id of the step's slot ``i``,
@@ -86,7 +90,19 @@ def for_step_pages(act, table_ref, lane, first, n_live, layer, streams):
     are read once, for the compares and for the copies, and a group that is
     no run is ``G`` copies with no loop around them. What is left of a
     step's live pages (fewer than ``G``) is a loop of single copies, so no
-    word past the lane's last live page is read."""
+    word past the lane's last live page is read.
+
+    ``rolled``: a group that is no run is the loop of single copies too, so
+    the function writes three copies a stream (the run, the group's loop,
+    the tail's) in place of ``G + 2``. Two callers ask for it. The Pallas interpreter, for every
+    kernel: it writes some 220 lines of HLO a copy, a kernel holds this
+    function three times over its streams, and 108 copies a kernel made
+    every served program of the CPU tests cost 3.5 s to lower and compile
+    where it had cost 0.4 (PR 56). And the full-context ``paged_attention``
+    on the chip (PR 57: ``ops/paged_attention.py`` says what a warm start
+    costs a program that holds the kernel, and what the loop costs a step).
+    The latent kernel and the sliding layers' call keep, on the chip, the
+    straight group they were swept with."""
     g = group_pages(streams[0][1].shape[0], streams[0][0].shape[1])
 
     def word(i):
@@ -120,8 +136,11 @@ def for_step_pages(act, table_ref, lane, first, n_live, layer, streams):
 
         @pl.when(jnp.logical_not(is_run))
         def _pages():
-            for j, page in enumerate(ids):
-                copy_page(page, base + j)
+            if rolled:
+                jax.lax.fori_loop(base, base + g, one_page, 0)
+            else:
+                for j, page in enumerate(ids):
+                    copy_page(page, base + j)
 
         return carry
 
@@ -135,24 +154,32 @@ def count_run_pages(tables, first, n_pages, step_pages: int, pool_pages: int):
     ``[lanes, width]``: the numpy twin of ``for_step_pages``' decision.
     Lane ``l`` copies ``tables[l, first[l] : first[l] + n_pages[l]]``,
     ``step_pages`` a step from ``first[l]``, each step in groups of
-    ``group_pages(step_pages, pool_pages)``."""
+    ``group_pages(step_pages, pool_pages)``. One pass over the table (which
+    neighbours are consecutive, summed along a row) and a compare a group:
+    0.36 ms for 32 lanes of 2176 pages, on the host beside the device."""
     tables = np.asarray(tables)
     lanes, width = tables.shape
-    # a value a lane (or one for all), against [lane, step, group, place]
-    first = np.broadcast_to(first, (lanes,)).reshape(lanes, 1, 1, 1)
-    n_pages = np.broadcast_to(n_pages, (lanes,)).reshape(lanes, 1, 1, 1)
+    # a value a lane (or one for all), against [lane, group]
+    first = np.broadcast_to(first, (lanes,))[:, None]
+    n_pages = np.broadcast_to(n_pages, (lanes,))[:, None]
+    live = int(np.clip(np.minimum(n_pages, width - first), 0, None).sum())
     g = group_pages(step_pages, pool_pages)
-    steps = -(-width // step_pages)
-    # slot [lane, step, group, place] -> the page's place among the lane's
-    # live pages; a step's last group may reach past the step
-    in_step = (
-        np.arange(-(-step_pages // g))[None, None, :, None] * g
-        + np.arange(g)[None, None, None, :]
-    )
-    place = np.arange(steps)[None, :, None, None] * step_pages + in_step
-    live = (in_step < step_pages) & (place < n_pages) & (first + place < width)
-    ids = np.take_along_axis(
-        tables, np.clip(first + place, 0, width - 1).reshape(lanes, -1), 1
-    ).reshape(live.shape)
-    runs = live.all(-1) & (ids == ids[..., :1] + np.arange(g)).all(-1)
-    return int(live.sum()), int(runs.sum()) * g
+    if g == 1:
+        return live, live
+    if g > min(width, n_pages.max()):
+        return live, 0  # no lane holds a whole group
+    # the groups that end inside their step, by their first page's place
+    # among a lane's live pages
+    starts = (
+        np.arange(-(-width // step_pages))[:, None] * step_pages
+        + np.arange(step_pages // g) * g
+    ).ravel()
+    cols = first + starts
+    whole = (starts + g <= n_pages) & (cols + g <= width)
+    # joined[l, x]: the slots before x whose successor holds the next pool id
+    joined = np.zeros((lanes, width), np.int32)
+    np.cumsum(tables[:, 1:] == tables[:, :-1] + 1, axis=1, out=joined[:, 1:])
+    at = np.clip(cols, 0, width - g)
+    lane = np.arange(lanes)[:, None]
+    runs = whole & (joined[lane, at + g - 1] - joined[lane, at] == g - 1)
+    return live, int(runs.sum()) * g

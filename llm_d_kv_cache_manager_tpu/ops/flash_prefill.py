@@ -16,7 +16,7 @@ chunk], entirely in VMEM; the [s, T] score matrix never exists.
   ``n_kv`` axis and Mosaic refuses it), so every KV head of a (row, query
   block) is served by one program and the heads are separated after the
   tile is in VMEM (``swapaxes``, batched ``dot_general`` over heads) as
-  ``_decode_kernel`` does.
+  ``paged_attention._walk_decode_kernel`` does.
 - Grid ``(batch, q_blocks, chunk_key_blocks)``. The context phase is a loop
   INSIDE the first chunk step whose trip count is the row's live context
   (``ceil(ctx_len / keys a step)``; 0 for a row or a query block with no

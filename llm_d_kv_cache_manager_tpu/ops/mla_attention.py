@@ -98,6 +98,7 @@ def _mla_kernel(
     table_pages: int,
     scale: float,
     dv: int,
+    interpret: bool,
 ):
     b = pl.program_id(0)
     qb = pl.program_id(1)
@@ -143,6 +144,7 @@ def _mla_kernel(
                 act, bt_ref, b, first,
                 jnp.minimum(pages_per_step, n_pages - first), layer,
                 ((pool_ref, ctx_buf.at[slot], sem.at[slot]),),
+                rolled=interpret,
             )
 
         @pl.when(n_steps > 0)
@@ -301,6 +303,7 @@ def mla_paged_attention(
     kernel = functools.partial(
         _mla_kernel, bq=bq, heads=heads, bk_ctx=bk_ctx, bk_chunk=bk_chunk,
         page_size=page_size, table_pages=table_pages, scale=scale, dv=dv,
+        interpret=interpret,
     )
     out = pl.pallas_call(
         kernel,

@@ -1169,7 +1169,9 @@ class Engine:
         #: copies its lanes' table pages itself (the latent kernel, the
         #: sliding layers'): ``ctx_pages``, the pages a layer's call copies
         #: over the same dispatches, and ``ctx_run_pages``, those copied as
-        #: part of a run of consecutive pool pages (``_count_ctx_pages``).
+        #: part of a run of consecutive pool pages (``_count_ctx_pages``);
+        #: ``full_ctx_pages`` / ``full_ctx_run_pages``, the same of a full
+        #: layer's ``paged_attention`` over the block tables.
         #: Off by default: ``obs_step_timing=False`` skips every clock
         #: read and every count, so the legacy step path is untouched.
         self.obs_step_timing = False
@@ -1196,6 +1198,8 @@ class Engine:
             "window_ctx_tokens": 0,
             "ctx_pages": 0,
             "ctx_run_pages": 0,
+            "full_ctx_pages": 0,
+            "full_ctx_run_pages": 0,
             "prefill_s": 0.0,
             "decode_s": 0.0,
             "sample_s": 0.0,
@@ -3166,11 +3170,10 @@ class Engine:
             toks = self._keep_pools(out)
         self._count_decode_dispatch(
             len(active), temperature, seq_lens, k, chained=prev is not None,
-            tables=(
-                (w_tables, w_starts) if self.window_pages is not None
-                else (block_tables, 0)
+            block_tables=block_tables,
+            window_tables=(
+                (w_tables, w_starts) if self.window_pages is not None else None
             ),
-            table_width=block_tables.shape[1],
         )
         # Start the D2H copy of the sampled ids now: the bytes land while
         # the host goes on (with a burst chained behind this one, while
@@ -3902,8 +3905,8 @@ class Engine:
     def _count_decode_dispatch(
         self, rows: int, temperature: np.ndarray,
         seq_lens: Optional[np.ndarray] = None, steps: int = 1,
-        chained: bool = False, tables: Optional[tuple] = None,
-        table_width: int = 0,
+        chained: bool = False, block_tables: Optional[np.ndarray] = None,
+        window_tables: Optional[tuple] = None,
     ) -> None:
         """``step_stats``' counters of one decode dispatch: its real lanes,
         whether any of them samples (``temperature`` is the host-side
@@ -3914,10 +3917,11 @@ class Engine:
         host-side lengths of the dispatch, 0 for a lane that is not real;
         a lane's context grows by one a step). ``decode_forwards`` grows by
         ``steps``: the forwards ``experts_touched`` is summed over.
-        ``tables``: the table array a kernel that walks its lanes' tables
-        itself was given and the position each row's first slot stands for
-        (``_count_ctx_pages``). ``table_width``: the pages of the dispatch's
-        block tables (``decode_table_slots``)."""
+        ``block_tables``: the dispatch's block table array (its width is
+        ``decode_table_slots``' table); ``window_tables``: the window table
+        array of a model with sliding layers and the position each row's
+        first slot stands for. The decode kernels walk both themselves
+        (``_count_ctx_pages``)."""
         if self.obs_step_timing:
             self.step_stats["decode_dispatches"] += 1
             self.step_stats["decode_forwards"] += steps
@@ -3933,7 +3937,7 @@ class Engine:
                 )
                 self.step_stats["attn_ctx_tokens"] += ctx
                 self.step_stats["decode_table_slots"] += (
-                    rows * steps * table_width * self.page_size
+                    rows * steps * block_tables.shape[1] * self.page_size
                 )
                 if self.model_cfg.kv_lora_rank:
                     self.step_stats["latent_ctx_tokens"] += ctx
@@ -3944,47 +3948,59 @@ class Engine:
                         np.minimum(seq_lens[seq_lens > 0] + j, w).sum()
                         for j in range(steps)
                     ))
-                if tables is not None:
-                    self._count_ctx_pages(seq_lens, *tables, steps)
+                self._count_ctx_pages(
+                    seq_lens, block_tables, window_tables, steps
+                )
 
     def _count_ctx_pages(
-        self, seq_lens: np.ndarray, tables: np.ndarray, starts, steps: int
+        self, seq_lens: np.ndarray, block_tables: np.ndarray,
+        window_tables: Optional[tuple], steps: int,
     ) -> None:
-        """``step_stats["ctx_pages"]`` / ``["ctx_run_pages"]``: the table
-        pages a layer's call of a decode dispatch copies, and those of them
-        it copies as part of a run (``ops/_page_copies.py``: the kernel's
-        own rule, group size and alignment). The latent kernel walks the
-        block table from its first slot over ``seq_len - 1`` rows; the
-        window kernel the window table (whose first slot stands for
-        ``starts``) from the first page that holds a visible slot. Counted
-        as the dispatch's first step finds the tables, times its ``steps``;
-        a model that runs neither kernel counts nothing."""
-        latent = bool(self.model_cfg.kv_lora_rank)
-        if not latent and self.window_pages is None:
-            return
+        """The table pages a layer's call of a decode dispatch copies, and
+        those of them it copies as part of a run (``ops/_page_copies.py``:
+        the kernel's own rule, group size and alignment), counted as the
+        dispatch's first step finds the tables, times its ``steps``. The
+        kernels walk a lane's table over the ``seq_len - 1`` rows that lie
+        in pages. ``step_stats["full_ctx_pages"]`` / ``["full_ctx_run_pages"]``:
+        a full layer's ``paged_attention``, the block table from its first
+        slot (a latent pool runs no such call and counts none).
+        ``["ctx_pages"]`` / ``["ctx_run_pages"]``: the latent kernel over
+        the block table from its first slot, or the sliding layers' call
+        over the window table (whose first slot stands for the second of
+        ``window_tables``) from the first page that holds a visible slot; a
+        model that runs neither counts none."""
         from ..ops._page_copies import count_run_pages
 
-        ps, width = self.page_size, tables.shape[1]
-        hist = np.clip(seq_lens - starts - 1, 0, width * ps)
-        if latent:
+        ps = self.page_size
+
+        def count(key, tables, starts, window, step_pages, pool):
+            width = tables.shape[1]
+            hist = np.clip(seq_lens - starts - 1, 0, width * ps)
+            first = (
+                np.maximum(seq_lens - starts - window, 0) // ps if window else 0
+            )
+            pages, in_runs = count_run_pages(
+                tables, first, -(-hist // ps) - first,
+                step_pages(width, ps), pool.shape[1],
+            )
+            self.step_stats[key + "pages"] += steps * pages
+            self.step_stats[key + "run_pages"] += steps * in_runs
+
+        if self.model_cfg.kv_lora_rank:
             from ..ops.mla_attention import ctx_step_pages
 
-            first = 0
-            step_pages = ctx_step_pages(width, ps)
-            pool_pages = self.k_pages.shape[1]
-        else:
-            from ..ops.paged_attention import window_step_pages
+            count("ctx_", block_tables, 0, 0, ctx_step_pages, self.k_pages)
+            return
+        from ..ops.paged_attention import walk_step_pages
 
-            first = np.maximum(
-                seq_lens - starts - self.model_cfg.sliding_window, 0
-            ) // ps
-            step_pages = window_step_pages(width, ps)
-            pool_pages = self.window_pages[0].shape[1]
-        pages, in_runs = count_run_pages(
-            tables, first, -(-hist // ps) - first, step_pages, pool_pages
-        )
-        self.step_stats["ctx_pages"] += steps * pages
-        self.step_stats["ctx_run_pages"] += steps * in_runs
+        count("full_ctx_", block_tables, 0, 0, walk_step_pages, self.k_pages)
+        if window_tables is not None:
+            window = self.model_cfg.sliding_window
+            count(
+                "ctx_", *window_tables, window,
+                functools.partial(walk_step_pages, window=window),
+                self.window_pages[0],
+            )
 
     def _sample(self, logits: jnp.ndarray, seqs: list[Sequence]) -> jax.Array:
         """First tokens of a prefill batch, on the device (decode samples

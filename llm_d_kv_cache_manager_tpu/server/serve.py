@@ -105,6 +105,13 @@ def admission_reject_response(web, err: AdmissionError):
     )
 
 
+#: ``Engine.step_stats`` key -> ``kind`` of ``kvcache_engine_ctx_pages_total``
+_CTX_PAGE_KINDS = {
+    "ctx_pages": "all", "ctx_run_pages": "run",
+    "full_ctx_pages": "full", "full_ctx_run_pages": "full_run",
+}
+
+
 class _ServingMetrics:
     """Prometheus serving metrics (the pod-side analogue of the indexer's
     collector): request/token counters, prefix-cache savings, TTFT histogram.
@@ -398,13 +405,14 @@ class _ServingMetrics:
             self.engine_ctx_pages = prom.Counter(
                 "kvcache_engine_ctx_pages_total",
                 "Table pages a layer's call of the fused decode dispatches "
-                "copied, for a kernel that walks its lanes' tables itself "
-                "(the latent kernel, the sliding layers'), by kind: all, "
-                "run (those copied as part of a run of consecutive pool "
-                "pages, one copy a group)",
+                "copied (the decode kernels walk their lanes' tables "
+                "themselves), by kind: all, run (the latent kernel or the "
+                "sliding layers' call; run: those copied as part of a run "
+                "of consecutive pool pages, one copy a group), full, "
+                "full_run (the same of a full layer's paged_attention)",
                 ["kind"], registry=self.registry,
             )
-            self._ctx_pages_seen = {"ctx_pages": 0, "ctx_run_pages": 0}
+            self._ctx_pages_seen = dict.fromkeys(_CTX_PAGE_KINDS, 0)
             self.engine_chained = prom.Counter(
                 "kvcache_engine_decode_chained_dispatches_total",
                 "Decode dispatches enqueued one ahead: their input ids came "
@@ -725,8 +733,7 @@ class _ServingMetrics:
         for key, seen in self._ctx_pages_seen.items():
             delta = step_stats.get(key, 0) - seen
             if delta > 0:
-                kind = "run" if key == "ctx_run_pages" else "all"
-                self.engine_ctx_pages.labels(kind=kind).inc(delta)
+                self.engine_ctx_pages.labels(kind=_CTX_PAGE_KINDS[key]).inc(delta)
                 self._ctx_pages_seen[key] = step_stats[key]
         chained = step_stats.get("decode_chained_dispatches", 0)
         if chained > self._chained_seen:

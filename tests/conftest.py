@@ -16,6 +16,7 @@ import os
 import signal
 import sys
 import threading
+import time
 
 # Must precede the first jax backend initialization (not merely jax import).
 flags = os.environ.get("XLA_FLAGS", "")
@@ -286,6 +287,53 @@ def _per_test_cap(request):
         return
     with capped(_PER_TEST_TIMEOUT_S):
         yield
+
+
+def _mappings_allowed() -> int:
+    try:
+        with open("/proc/sys/vm/max_map_count") as f:
+            return int(f.read())
+    except (OSError, ValueError):
+        return 65530  # Linux's default
+
+
+def _mappings() -> int:
+    """The memory mappings this process holds (0 where ``/proc`` has none)."""
+    try:
+        with open("/proc/self/maps", "rb") as f:
+            return f.read().count(b"\n")
+    except OSError:
+        return 0
+
+
+#: A program XLA compiled for the CPU is three mappings of the process (its
+#: code, its constants, its data: a page or two each), jax keeps every
+#: program a worker compiled until the worker exits, and a process may hold
+#: ``vm.max_map_count`` mappings, 65 530 unless a machine says otherwise.
+#: One file of served programs adds 7-13 thousand (``test_experts_touched.py``
+#: alone: 7 123 at PR 55's tree, 12 862 with every decode program walking its
+#: pages under the interpreter); a worker of a whole run passes the limit,
+#: ``mmap`` then fails inside LLVM, and the worker dies of a segmentation
+#: fault in ``backend_compile_and_load``, under whatever test compiles next:
+#: ROADMAP D13's deaths, three of eleven whole runs before PR 56 and five of
+#: five on its tree. From a third of the limit on, the programs go
+#: (``jax.clear_caches`` took 12 862 mappings to 711); one that is called
+#: again compiles again. A third, because a single test of the cells'
+#: rehearsals compiles for minutes and has to fit in what is left.
+_MAPPINGS_KEPT = _mappings_allowed() // 3
+_MAPPINGS_CHECK_EVERY_S = 5.0  # the count reads /proc/self/maps: 5-10 ms
+_mappings_checked_at = 0.0
+
+
+@pytest.fixture(autouse=True)
+def _bounded_mappings():
+    yield
+    global _mappings_checked_at
+    if time.monotonic() - _mappings_checked_at < _MAPPINGS_CHECK_EVERY_S:
+        return
+    _mappings_checked_at = time.monotonic()
+    if _mappings() > _MAPPINGS_KEPT:
+        jax.clear_caches()
 
 
 @pytest.fixture(autouse=True)

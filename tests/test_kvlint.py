@@ -710,10 +710,15 @@ class TestKernelAbi:
         pin = manifest["llm_d_kv_cache_manager_tpu/ops/paged_attention.py"][
             "paged_attention"
         ]
-        # The scalar-prefetch operands lead in BOTH kernel variants, and
-        # the quantized scales sit between the pages and the fresh tail.
-        assert pin["num_scalar_prefetch"] == 2
-        assert pin["operands"][:2] == ["block_tables", "seq_lens"]
+        # The scalar-prefetch operands (the layer word among them since
+        # the full-context call walks a lane's pages, PR 57) lead in EVERY
+        # variant, and the quantized scales sit between the pages and the
+        # fresh tail. The one function holds the one ``pallas_call``.
+        assert list(manifest["llm_d_kv_cache_manager_tpu/ops/paged_attention.py"]) == [
+            "paged_attention"
+        ]
+        assert pin["num_scalar_prefetch"] == 3
+        assert pin["operands"][:3] == ["layer_word", "block_tables", "seq_lens"]
         ops = pin["operands"]
         assert ops.index("k_scale") > ops.index("v_pages")
         assert ops.index("v_scale") < ops.index("fresh_k")

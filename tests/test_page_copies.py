@@ -135,6 +135,47 @@ def test_the_kernel_makes_the_copies_the_twin_counts(
     assert 0 < in_runs < pages
 
 
+@pytest.mark.parametrize("width", [24, 100, 160, 300])
+def test_the_full_calls_walk_is_what_the_twin_counts(width):
+    """A full layer's ``paged_attention`` (PR 57): from the table's first
+    page, ``walk_step_pages`` pages a step, groups of ``RUN_PAGES``, as
+    ``Engine._count_ctx_pages`` counts ``full_ctx_pages`` /
+    ``full_ctx_run_pages``."""
+    from llm_d_kv_cache_manager_tpu.ops.paged_attention import walk_step_pages
+
+    rng = np.random.default_rng(width)
+    lanes, pool_pages = 6, 400
+    step_pages = walk_step_pages(width, PS)
+    pool = rng.normal(size=(2, pool_pages, PS, D)).astype(np.float32)
+    tables = tables_of_runs(rng, lanes, width, pool_pages)
+    tables[0] = np.arange(5, 5 + width)  # one run
+    tables[1] = np.arange(width, 0, -1)  # none
+    n_pages = rng.integers(1, width + 1, lanes)
+    n_pages[:3] = width, width, 0
+    for row, lo in zip(tables, n_pages):  # dead tails that go on a run
+        row[lo:] = row[lo - 1] + 1 + np.arange(width - lo) if lo else pool_pages
+    first = np.zeros(lanes, np.int64)
+    landed, made = walk(tables, first, n_pages, jnp.asarray(pool), step_pages)
+    g = group_pages(step_pages, pool_pages)
+    assert g == min(_page_copies.RUN_PAGES, step_pages)
+    for lane in range(lanes):
+        live = tables[lane, :n_pages[lane]]
+        got = landed[lane].reshape(-1, PS, D)
+        np.testing.assert_array_equal(got[:len(live)], pool[1, live])
+        pages, in_runs = count_run_pages(
+            tables[lane:lane + 1], 0, n_pages[lane], step_pages, pool_pages)
+        assert pages == len(live)
+        assert made[lane] == pages - in_runs + in_runs // g
+    pages, in_runs = count_run_pages(tables, 0, n_pages, step_pages, pool_pages)
+    assert pages == n_pages.sum() and 0 < in_runs < pages
+    # the lane that is one run: every whole group of every step
+    one = count_run_pages(tables[:1], 0, width, step_pages, pool_pages)[1]
+    assert one == sum(
+        min(step_pages, width - at) // g * g for at in range(0, width, step_pages)
+    )
+    assert count_run_pages(tables[1:2], 0, width, step_pages, pool_pages)[1] == 0
+
+
 @pytest.mark.parametrize("row, n_pages, want", [
     pytest.param(range(10, 26), 16, 16, id="one-run"),
     pytest.param(range(25, 9, -1), 16, 0, id="descending"),

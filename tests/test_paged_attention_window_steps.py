@@ -12,7 +12,6 @@ import pytest
 from llm_d_kv_cache_manager_tpu.ops.paged_attention import (
     paged_attention,
     paged_attention_reference,
-    paged_window_attention,
 )
 from window_walks import (
     WINDOW_WALKS,
@@ -60,7 +59,7 @@ class TestWindowWalk:
         @jax.jit
         def call(layer):
             traces.append(layer)
-            return paged_window_attention(
+            return paged_attention(
                 q, k, v, tables, rel, fk, fv, window=window, scale=0.2,
                 interpret=True, layer=layer)
 

@@ -137,10 +137,14 @@ def test_stats_and_gauges(params):
     pod.engine.step_stats["window_ctx_tokens"] = 41
     pod.engine.step_stats["ctx_pages"] = 50
     pod.engine.step_stats["ctx_run_pages"] = 32
+    pod.engine.step_stats["full_ctx_pages"] = 70
+    pod.engine.step_stats["full_ctx_run_pages"] = 48
     pod.metrics.sync_step_stats(pod.engine.step_stats, None)
     text = pod.metrics.exposition().decode()
     assert 'kvcache_engine_ctx_pages_total{kind="all"} 50.0' in text
     assert 'kvcache_engine_ctx_pages_total{kind="run"} 32.0' in text
+    assert 'kvcache_engine_ctx_pages_total{kind="full"} 70.0' in text
+    assert 'kvcache_engine_ctx_pages_total{kind="full_run"} 48.0' in text
     assert "kvcache_window_bytes_per_token 5.0" in text  # (set_engine_gauges' 5)
     assert f"kvcache_window_pages_held {float(window.num_held)}" in text
     assert 'kvcache_window_pages_total{event="pages_dropped"}' in text

@@ -14,9 +14,9 @@ from llm_d_kv_cache_manager_tpu.ops._page_copies import (
     count_run_pages,
 )
 from llm_d_kv_cache_manager_tpu.ops.paged_attention import (
+    paged_attention,
     paged_attention_reference,
-    paged_window_attention,
-    window_step_pages,
+    walk_step_pages,
 )
 
 PS = 4
@@ -87,7 +87,7 @@ def test_window_kernel_over_tables_of_runs(kind, walk, fresh):
         for i, n in enumerate(lens):
             if n:
                 clean[:, tables[i, (n - 1) // PS], (n - 1) % PS] = new[:, i]
-    got = paged_window_attention(
+    got = paged_attention(
         q, jnp.asarray(pools[0]), jnp.asarray(pools[1]), jnp.asarray(tables),
         jnp.asarray(lens, jnp.int32), *(new if fresh else ()), window=window,
         scale=0.25, interpret=True, layer=jnp.int32(layer),
@@ -106,14 +106,14 @@ def test_window_kernel_over_tables_of_runs(kind, walk, fresh):
     first = np.maximum(np.asarray(lens) - window, 0) // PS
     pages, in_runs = count_run_pages(
         tables, first, np.maximum(-(-hist // PS) - first, 0),
-        window_step_pages(width, PS), _RUN_WINDOW_POOL,
+        walk_step_pages(width, PS, window), _RUN_WINDOW_POOL,
     )
     assert pages == sum(
         max(-(-h // PS) - f, 0) for h, f in zip(hist.tolist(), first.tolist())
     )
     # every whole group of a step of 32 pages (``KEY_BLOCK`` 256 / 4 would
     # be 64: the table is narrower)
-    step = window_step_pages(width, PS)
+    step = walk_step_pages(width, PS, window)
     whole = sum(
         min(step, n - at) // RUN_PAGES * RUN_PAGES
         for n in np.maximum(-(-hist // PS) - first, 0).tolist()
