@@ -31,6 +31,8 @@ def load_hf_state_dict(
     sd = state_dict
     if cfg.router_before_attention:
         return _load_smallthinker(sd, cfg)
+    if cfg.n_kda_layers and not cfg.kv_lora_rank:
+        return _load_solar_open2(sd, cfg)
     if cfg.sliding_window:
         return _load_afmoe(sd, cfg)
     if cfg.layer_types is not None:
@@ -452,6 +454,156 @@ def _load_smallthinker(sd: Mapping[str, Any], cfg: LlamaConfig) -> Params:
     return params
 
 
+def _load_solar_open2(sd: Mapping[str, Any], cfg: LlamaConfig) -> Params:
+    """``model_type: solar_open2``. The checkpoint's names are written here
+    AS THE AUTHOR REMEMBERS Kimi Linear's published modelling code, whose
+    linear layer this family runs, with no network at hand to read either
+    again: a layer's two norms ``input_layernorm`` and
+    ``post_attention_layernorm``; a GQA layer's ``self_attn.{q, k, v, g,
+    o}_proj`` (``g_proj`` the output gate); a linear layer's ``self_attn.{q,
+    k, v}_proj`` and ``{q, k, v}_conv1d.weight [channels, 1, taps]`` (the
+    program holds ``[q | k | v]`` as one product and one filter), ``f_a_proj``
+    / ``f_b_proj`` and ``g_a_proj`` / ``g_b_proj`` (the low-rank pairs),
+    ``b_proj``, ``A_log``, ``dt_bias``, ``o_norm``, ``o_proj``; ``mlp.gate``
+    with ``e_score_correction_bias``, ``mlp.experts.N.{gate, up, down}_proj``
+    and ``mlp.shared_experts``; ``model.norm`` and an untied ``lm_head``. A
+    checkpoint that names them otherwise fails on the missing key, named."""
+    def get(name: str) -> np.ndarray:
+        return _to_np(sd[name])
+
+    def vector(name: str, dtype=None) -> jnp.ndarray:
+        return jnp.asarray(get(name), dtype or cfg.dtype)
+
+    def linear(name: str) -> jnp.ndarray:
+        return jnp.asarray(get(name).T, cfg.dtype)  # [out,in] -> [in,out]
+
+    f32 = jnp.float32
+    layers = []
+    for i in range(cfg.n_layers):
+        p = f"model.layers.{i}."
+        a = p + "self_attn."
+        layer = {
+            "attn_norm": vector(p + "input_layernorm.weight"),
+            "mlp_norm": vector(p + "post_attention_layernorm.weight"),
+            "wo": linear(a + "o_proj.weight"),
+        }
+        if cfg.layer_kind(i) == "linear":
+            layer.update(
+                kda_qkv=jnp.concatenate(
+                    [linear(f"{a}{t}_proj.weight") for t in "qkv"], axis=1),
+                # [channels, 1, taps] -> [taps, channels], oldest tap first
+                kda_conv_w=jnp.concatenate([
+                    jnp.asarray(get(f"{a}{t}_conv1d.weight")[:, 0, :].T, cfg.dtype)
+                    for t in "qkv"], axis=1),
+                kda_wf_down=linear(a + "f_a_proj.weight"),
+                kda_wf_up=linear(a + "f_b_proj.weight"),
+                kda_dt_bias=vector(a + "dt_bias", f32),
+                kda_A_log=vector(a + "A_log", f32).reshape(-1),
+                kda_wb=linear(a + "b_proj.weight"),
+                kda_wg_down=linear(a + "g_a_proj.weight"),
+                kda_wg_up=linear(a + "g_b_proj.weight"),
+                kda_o_norm=vector(a + "o_norm.weight"),
+            )
+        else:
+            for ours, theirs in (("wq", "q_proj"), ("wk", "k_proj"),
+                                 ("wv", "v_proj"), ("wg", "g_proj")):
+                layer[ours] = linear(f"{a}{theirs}.weight")
+        moe = p + "mlp."
+        layer["router"] = linear(moe + "gate.weight")
+        layer["router_bias"] = vector(moe + "gate.e_score_correction_bias", f32)
+        first = cfg.expert_first
+        for name in ("gate", "up", "down"):
+            layer["w_" + name] = jnp.stack([
+                linear(f"{moe}experts.{first + j}.{name}_proj.weight")
+                for j in range(cfg.experts_held)
+            ])
+            layer["ws_" + name] = linear(f"{moe}shared_experts.{name}_proj.weight")
+        layers.append(layer)
+    return {
+        "embed": vector("model.embed_tokens.weight"),
+        "final_norm": vector("model.norm.weight"),
+        "lm_head": linear("lm_head.weight"),
+        "layers": layers,
+    }
+
+
+def _solar_open2_config(hf_config, rope_scaling) -> LlamaConfig:
+    """``model_type: solar_open2``: ``gqa_layers`` says which layers are
+    softmax GQA layers (every ``gqa_interval + 1``-th, the first of its
+    period; the others are delta-rule linear attentions sized by the nested
+    ``linear_attn_config``), ``use_rope`` false that nothing is rotated,
+    ``use_gqa_gate`` that the GQA heads' output is gated; every layer routed
+    over ``n_routed_experts`` sigmoid-scored experts beside shared ones. What
+    the program does not run is refused here by name."""
+    def has(key, default=None):
+        return getattr(hf_config, key, default)
+
+    n = hf_config.num_hidden_layers
+    gqa = sorted(int(i) for i in has("gqa_layers") or ())
+    period = int(has("gqa_interval", 3)) + 1
+    if gqa != list(range(0, n, period)):
+        raise NotImplementedError(
+            f"gqa_layers {gqa} with gqa_interval {period - 1}: a GQA layer "
+            f"first in every period of {period} layers is supported"
+        )
+    linear = dict(has("linear_attn_config") or {})
+    heads, kv_heads = linear.get("num_heads"), linear.get("num_kv_heads")
+    if heads != hf_config.num_attention_heads or kv_heads not in (None, heads):
+        raise NotImplementedError(
+            f"linear_attn_config num_heads={heads} / num_kv_heads={kv_heads}: "
+            "the attention heads for q, k and v alike are supported"
+        )
+    if has("use_rope", True) or rope_scaling is not None:
+        raise NotImplementedError(
+            "use_rope=true / rope_scaling with solar_open2 is not supported "
+            "yet (no layer takes a position)"
+        )
+    if has("kda_use_full_proj", False) or not has("kda_allow_neg_eigval", True):
+        raise NotImplementedError(
+            "kda_use_full_proj=true / kda_allow_neg_eigval=false is not "
+            "supported yet (low-rank gate projections, beta in (0, 2))"
+        )
+    if not has("use_gqa_gate", True) or has("first_k_dense_replace", 0):
+        raise NotImplementedError(
+            "use_gqa_gate=false / first_k_dense_replace > 0 is not supported yet"
+        )
+    if has("scoring_func", "sigmoid") != "sigmoid" or has("n_group", 1) != 1:
+        raise NotImplementedError(
+            f"scoring_func={has('scoring_func')!r} / n_group={has('n_group')}: "
+            "sigmoid scores in one group are supported"
+        )
+    return LlamaConfig(
+        vocab_size=hf_config.vocab_size,
+        hidden_size=hf_config.hidden_size,
+        intermediate_size=hf_config.intermediate_size,
+        n_layers=n,
+        n_heads=hf_config.num_attention_heads,
+        n_kv_heads=hf_config.num_key_value_heads,
+        head_dim=has("head_dim"),
+        rope_theta=float(has("rope_theta", 10_000.0)),
+        rms_norm_eps=has("rms_norm_eps", 1e-5),
+        tie_word_embeddings=bool(has("tie_word_embeddings", False)),
+        n_experts=hf_config.n_routed_experts,
+        n_experts_per_tok=hf_config.num_experts_per_tok,
+        moe_intermediate_size=hf_config.moe_intermediate_size,
+        norm_topk_prob=bool(has("norm_topk_prob", True)),
+        n_shared_experts=has("n_shared_experts", 0) or 0,
+        moe_scoring="sigmoid",
+        routed_scaling_factor=float(has("routed_scaling_factor", 1.0)),
+        layer_types=tuple(
+            "full_attention" if i % period == 0 else "linear_attention"
+            for i in range(n)
+        ),
+        kda_head_dim=linear["head_dim"],
+        kda_conv_kernel=linear["short_conv_kernel_size"],
+        kda_lora=True,
+        kda_channel_gate=True,
+        kda_neg_eigval=True,
+        attn_output_gate=True,
+        no_rope=True,
+    )
+
+
 def _smallthinker_config(hf_config, rope_scaling) -> LlamaConfig:
     """``model_type: smallthinker``: ``sliding_window_layout`` says which
     layers see a window of ``sliding_window_size`` positions, ``rope_layout``
@@ -671,6 +823,8 @@ def config_from_hf(hf_config) -> LlamaConfig:
         return _smallthinker_config(hf_config, rope_scaling)
     if getattr(hf_config, "model_type", "") == "longcat_flash":
         return _longcat_flash_config(hf_config, rope_scaling)
+    if getattr(hf_config, "model_type", "") == "solar_open2":
+        return _solar_open2_config(hf_config, rope_scaling)
     if getattr(hf_config, "model_type", "") == "bailing_hybrid":
         return _bailing_hybrid_config(hf_config, rope_scaling)
     cls_name = hf_config.__class__.__name__
@@ -796,10 +950,6 @@ def _bailing_hybrid_config(hf_config, rope_scaling) -> LlamaConfig:
     def has(key, default=None):
         return getattr(hf_config, key, default)
 
-    if has("use_kda_lora", False) or not has("no_kda_lora", True):
-        raise NotImplementedError(
-            "use_kda_lora (low-rank gate projections) is not supported yet"
-        )
     if has("score_function", "sigmoid") != "sigmoid" or not has(
         "moe_router_enable_expert_bias", True
     ):
@@ -876,5 +1026,7 @@ def _bailing_hybrid_config(hf_config, rope_scaling) -> LlamaConfig:
         kda_conv_kernel=hf_config.short_conv_kernel_size,
         kda_safe_gate=bool(has("kda_safe_gate", False)),
         kda_lower_bound=float(has("kda_lower_bound", -5.0)),
+        # the decay's projection as a low-rank pair of the head's size
+        kda_lora=bool(has("use_kda_lora", False) or not has("no_kda_lora", True)),
         **limits,
     )

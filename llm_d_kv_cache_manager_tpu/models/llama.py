@@ -510,15 +510,25 @@ class LlamaConfig:
     # ``kda_lower_bound * sigmoid(exp(A_log) * a)``, in (lower bound, 0);
     # else the paper's ``-exp(A_log) * softplus(a)``. ``layer_types`` decides
     # what ``init_params`` and the loader make; the bodies read the layer (it
-    # has ``kda_qkv`` or not). ``kda_lora`` (low-rank gate projections) and a
-    # non-zero SwiGLU clamp (``expert_swiglu_limits`` /
-    # ``shared_swiglu_limits``, a published layer each) are carried for the
-    # engine's refusals: neither is run.
+    # has ``kda_qkv`` or not). ``kda_lora``: the decay's projection is a
+    # low-rank pair (``kda_wf_down [d, kda_head_dim]``, ``kda_wf_up``: the
+    # rank is the head's size, Kimi Linear's published code) and not the
+    # full ``kda_wf``; ``kda_channel_gate``: the output gate is a value a
+    # CHANNEL through such a pair (``kda_wg_down``, ``kda_wg_up [rank, H
+    # V]``) and not ``kda_wg``'s one a head. Both decide what ``init_params``
+    # and the loader make: ``_kda_inputs`` / ``_kda_output`` read the layer's
+    # leaves. ``kda_neg_eigval``: ``beta = 2 sigmoid(.)`` in (0, 2), so that
+    # ``I - beta k k^T`` has an eigenvalue in (-1, 1) (``allow_neg_eigval``
+    # of flash-linear-attention). A non-zero SwiGLU clamp
+    # (``expert_swiglu_limits`` / ``shared_swiglu_limits``, a published
+    # layer each) is carried for the engine's refusal: it is not run.
     kda_head_dim: int = 0
     kda_conv_kernel: int = 0
     kda_safe_gate: bool = False
     kda_lower_bound: float = -5.0
     kda_lora: bool = False
+    kda_channel_gate: bool = False
+    kda_neg_eigval: bool = False
     expert_swiglu_limits: Optional[tuple] = None
     shared_swiglu_limits: Optional[tuple] = None
     # The router reads the stream that ENTERS the layer, before any norm and
@@ -530,6 +540,8 @@ class LlamaConfig:
     # it says the layer is, as ``window`` is) gets its gates from
     # ``_preroute`` ahead of the attention, every other from its own input.
     router_before_attention: bool = False
+    # No layer of the model takes a position (NoPE; ``_rotates``).
+    no_rope: bool = False
     dtype: Any = jnp.bfloat16
 
     @property
@@ -601,6 +613,32 @@ class LlamaConfig:
             i for i, kind in enumerate(self.layer_types)
             if kind != "linear_attention"
         )
+
+    @property
+    def context_pool_name(self) -> str:
+        """What a refusal calls the pool of pages a state pool of slots lies
+        beside: the layers between the linear ones are latent attentions or
+        attend over per-head keys and values."""
+        return "latent pool" if self.kv_lora_rank else "key/value pools"
+
+    @property
+    def gqa_layers(self) -> Optional[list]:
+        """The layers that attend over their whole context, as ``solar_open2``
+        publishes them (whole, whatever ``n_layers`` is run of them)."""
+        if self.layer_types is None:
+            return None
+        return [i for i, kind in enumerate(self.layer_types)
+                if kind == "full_attention"]
+
+    @property
+    def use_rope(self) -> bool:
+        """``use_rope`` of a published file: some layer rotates q and k."""
+        return not self.no_rope
+
+    @property
+    def kda_full_proj(self) -> bool:
+        """``kda_use_full_proj`` of a published file: no low-rank pair."""
+        return not self.kda_lora
 
     @property
     def expert_swiglu_limit_list(self) -> Optional[list]:
@@ -1302,6 +1340,77 @@ TINY_LING_HYBRID = LlamaConfig(
     dtype=jnp.float32,
 )
 
+#: upstage/Solar-Open2-250B (``model_type: solar_open2``): 48 layers in
+#: periods of four, a softmax GQA layer FIRST (``gqa_layers`` 0, 4, .. 44: 64
+#: query heads on 8 KV heads of 128, a sigmoid gate on the heads' output, no
+#: q/k norm) then three delta-rule linear attentions (64 heads, a state of
+#: 128 x 128 a head, a four-tap convolution, the paper's gate through a
+#: low-rank pair, a channel-wise output gate through another, ``beta`` in (0,
+#: 2)); NO layer takes a position (``no_rope``); every layer 320
+#: sigmoid-routed experts of width 1280, top-8 in one group, gates
+#: renormalised, x 1, beside one shared expert; no dense layer
+#: (``intermediate_size`` sizes nothing). No chip holds a layer's 320
+#: experts: a configuration states its share (``expert_first`` /
+#: ``expert_count``) and its cut of depth and vocabulary.
+SOLAR_OPEN2_250B = LlamaConfig(
+    vocab_size=196_608,
+    hidden_size=4_096,
+    intermediate_size=10_240,
+    n_layers=48,
+    n_heads=64,
+    n_kv_heads=8,
+    head_dim=128,
+    rope_theta=10_000.0,
+    rms_norm_eps=1e-5,
+    n_experts=320,
+    n_experts_per_tok=8,
+    moe_intermediate_size=1_280,
+    norm_topk_prob=True,
+    n_shared_experts=1,
+    moe_scoring="sigmoid",
+    routed_scaling_factor=1.0,
+    layer_types=(_ATTN, _KDA, _KDA, _KDA) * 12,
+    kda_head_dim=128,
+    kda_conv_kernel=4,
+    kda_lora=True,
+    kda_channel_gate=True,
+    kda_neg_eigval=True,
+    attn_output_gate=True,
+    no_rope=True,
+)
+
+#: Tiny hybrid of GQA and linear attention (two periods of one gated GQA
+#: layer, 4 query heads of 16 on 1 KV head, and three linear ones, 4 heads
+#: with a 16 x 16 state and low-rank pairs of rank 16; no positions; 8
+#: experts top-2 and one shared in every layer) for tests / CPU dry-runs.
+TINY_SOLAR_HYBRID = LlamaConfig(
+    vocab_size=256,
+    hidden_size=64,
+    intermediate_size=128,
+    n_layers=8,
+    n_heads=4,
+    n_kv_heads=1,
+    head_dim=16,
+    rope_theta=10_000.0,
+    rms_norm_eps=1e-5,
+    n_experts=8,
+    n_experts_per_tok=2,
+    moe_intermediate_size=48,
+    norm_topk_prob=True,
+    n_shared_experts=1,
+    moe_scoring="sigmoid",
+    routed_scaling_factor=1.0,
+    layer_types=(_ATTN, _KDA, _KDA, _KDA) * 2,
+    kda_head_dim=16,
+    kda_conv_kernel=4,
+    kda_lora=True,
+    kda_channel_gate=True,
+    kda_neg_eigval=True,
+    attn_output_gate=True,
+    no_rope=True,
+    dtype=jnp.float32,
+)
+
 #: Tiny MoE config (Mixtral-shaped) for tests / CPU dry-runs.
 TINY_MOE = LlamaConfig(
     vocab_size=256,
@@ -1433,6 +1542,18 @@ def init_params(
             part["ws_down"] = dense(extra[3], (fs, d), fs)
         return part
 
+    def low_rank(key, name) -> Params:
+        """A linear layer's pair ``[d, rank] [rank, H K]`` with the head's
+        size as rank, each drawn at the scale of its own input (the
+        product's entries spread as a full projection's)."""
+        rank = cfg.kda_head_dim
+        down, up = jax.random.split(key)
+        return {
+            name + "_down": dense(down, (d, rank), d, quantizable=False),
+            name + "_up": dense(
+                up, (rank, n_q * rank), rank, quantizable=False),
+        }
+
     keys = jax.random.split(rng, cfg.n_layers + 2)
     layers = []
     for i in range(cfg.n_layers):
@@ -1486,13 +1607,15 @@ def init_params(
                     k[1], (cfg.kda_conv_kernel, 3 * hk), cfg.kda_conv_kernel,
                     quantizable=False,
                 ),
-                "kda_wf": dense(k[2], (d, hk), d),
+                **(low_rank(k[2], "kda_wf") if cfg.kda_lora
+                   else {"kda_wf": dense(k[2], (d, hk), d)}),
                 "kda_dt_bias": 0.5 * jax.random.normal(kk[0], (hk,), jnp.float32),
                 "kda_A_log": jnp.log(
                     jax.random.uniform(kk[1], (n_q,), jnp.float32, 0.5, 4.0)
                 ),
                 "kda_wb": dense(kk[2], (d, n_q), d, quantizable=False),
-                "kda_wg": dense(kk[3], (d, n_q), d, quantizable=False),
+                **(low_rank(kk[3], "kda_wg") if cfg.kda_channel_gate
+                   else {"kda_wg": dense(kk[3], (d, n_q), d, quantizable=False)}),
                 "kda_o_norm": norm_init((cfg.kda_head_dim,)),
                 "wo": dense(k[3], (hk, d), hk),
                 "mlp_norm": norm_init((d,)),
@@ -1731,8 +1854,9 @@ def _qkv(layer: Params, cfg: LlamaConfig, x: jnp.ndarray):
 
 def _rotates(layer: Params, cfg: LlamaConfig) -> bool:
     """Whether a layer that attends rotates q and k: every one, except the
-    full layers of a model that has sliding ones (they take no positions)."""
-    return "window" in layer or not cfg.sliding_window
+    full layers of a model that has sliding ones (they take no positions),
+    and none of a model without positions (``no_rope``)."""
+    return not cfg.no_rope and ("window" in layer or not cfg.sliding_window)
 
 
 def _attn_gate(layer: Params, x: jnp.ndarray, heads: jnp.ndarray):
@@ -1957,6 +2081,12 @@ def _l2norm(t: jnp.ndarray) -> jnp.ndarray:
     return t * jax.lax.rsqrt(jnp.sum(t * t, axis=-1, keepdims=True) + 1e-6)
 
 
+def _low_rank(layer: Params, name: str, x: jnp.ndarray) -> jnp.ndarray:
+    """``(x W_down) W_up`` of a linear layer's low-rank pair ``name``."""
+    return (x @ _w(layer[name + "_down"], x.dtype)) @ _w(
+        layer[name + "_up"], x.dtype)
+
+
 def _kda_inputs(layer: Params, cfg: LlamaConfig, x, rows):
     """A linear layer's per-token operands for ``x [b, s, d]`` (the normed
     input) that follows the carried rows ``rows [b, taps - 1, 3 H K]`` (the
@@ -1965,8 +2095,9 @@ def _kda_inputs(layer: Params, cfg: LlamaConfig, x, rows):
     the carried rows after chunk index ``i`` are its rows ``i + 1 .. i + taps
     - 1``). ``[q | k | v] = SiLU(conv(x kda_qkv))``, q and k l2-normed a head
     (q also over sqrt(K)); ``g`` the log decay, a channel of the key, from the
-    gate's projection by the published form (``kda_safe_gate``); ``beta =
-    sigmoid(x w_b)``. No position enters."""
+    gate's projection (the low-rank pair where the layer has ``kda_wf_down``,
+    else ``kda_wf``) by the published form (``kda_safe_gate``); ``beta =
+    sigmoid(x w_b)``, doubled under ``kda_neg_eigval``. No position enters."""
     b, s, _ = x.shape
     H, K = cfg.n_heads, cfg.kda_head_dim
     f32 = jnp.float32
@@ -1979,28 +2110,39 @@ def _kda_inputs(layer: Params, cfg: LlamaConfig, x, rows):
     ))
     q, k, v = (t.reshape(b, s, H, K) for t in jnp.split(conv, 3, axis=-1))
     q, k = _l2norm(q) * K**-0.5, _l2norm(k)
-    a = (x @ _w(layer["kda_wf"], x.dtype)).astype(f32) + layer["kda_dt_bias"]
-    a = a.reshape(b, s, H, K)
+    if "kda_wf_down" in layer:
+        a = _low_rank(layer, "kda_wf", x)
+    else:
+        a = x @ _w(layer["kda_wf"], x.dtype)
+    a = (a.astype(f32) + layer["kda_dt_bias"]).reshape(b, s, H, K)
     rate = jnp.exp(layer["kda_A_log"].astype(f32))[:, None]
     if cfg.kda_safe_gate:
         g = cfg.kda_lower_bound * jax.nn.sigmoid(rate * a)
     else:
         g = -rate * jax.nn.softplus(a)
     beta = jax.nn.sigmoid((x @ layer["kda_wb"].astype(x.dtype)).astype(f32))
+    if cfg.kda_neg_eigval:
+        beta = 2.0 * beta
     return q, k, v, g, beta, z
 
 
 def _kda_output(layer: Params, cfg: LlamaConfig, x, o):
     """The heads' outputs ``o [b, s, H, V]`` float32 to the residual's
-    width: a head's RMSNorm, times ``sigmoid(x w_g)`` (one gate a head),
-    ``wo``."""
+    width: a head's RMSNorm, times ``sigmoid(x w_g)`` (one gate a head, or
+    one a channel through the low-rank pair where the layer has
+    ``kda_wg_down``), ``wo``."""
     b, s = o.shape[:2]
     o = rms_norm(o, layer["kda_o_norm"].astype(o.dtype), cfg.rms_norm_eps,
                  cfg.norm_offset)
-    gate = jax.nn.sigmoid(
-        (x @ layer["kda_wg"].astype(x.dtype)).astype(jnp.float32)
-    )
-    o = (o * gate[..., None]).astype(x.dtype).reshape(b, s, -1)
+    if "kda_wg_down" in layer:
+        gate = jax.nn.sigmoid(
+            _low_rank(layer, "kda_wg", x).astype(jnp.float32)
+        ).reshape(o.shape)
+    else:
+        gate = jax.nn.sigmoid(
+            (x @ layer["kda_wg"].astype(x.dtype)).astype(jnp.float32)
+        )[..., None]
+    o = (o * gate).astype(x.dtype).reshape(b, s, -1)
     return o @ _w(layer["wo"], x.dtype)
 
 

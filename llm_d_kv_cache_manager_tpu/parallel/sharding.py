@@ -103,12 +103,17 @@ def _layer_specs(
     if linear:
         for name in (
             "wq", "wq_a", "q_a_norm", "wq_b", "wkv_a", "kv_norm", "wkv_b", "wo",
+            "wk", "wv", "bq", "bk", "bv", "q_norm", "k_norm", "wg",
         ):
             specs.pop(name, None)
-        specs.update({name: P() for name in (
-            "kda_qkv", "kda_conv_w", "kda_wf", "kda_dt_bias", "kda_A_log",
-            "kda_wb", "kda_wg", "kda_o_norm", "wo",
-        )})
+        names = ["kda_qkv", "kda_conv_w", "kda_dt_bias", "kda_A_log", "kda_wb",
+                 "kda_o_norm", "wo"]
+        # the decay's projection and the output gate: each the full matrix
+        # or the low-rank pair, as ``init_params`` makes them
+        for name, low_rank in (("kda_wf", cfg.kda_lora),
+                               ("kda_wg", cfg.kda_channel_gate)):
+            names += [name + "_down", name + "_up"] if low_rank else [name]
+        specs.update(dict.fromkeys(names, P()))
     return specs
 
 

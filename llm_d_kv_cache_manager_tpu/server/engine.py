@@ -511,10 +511,11 @@ class Engine:
             )
         if cfg.n_kda_layers:
             # Linear-attention layers keep a matrix a head in a state pool of
-            # slots beside the latent pool (``llama.init_kda_state``,
-            # ``block_manager.StatePool``): what does not carry a slot is
-            # refused here by name. Events, the index and the scorer keep
-            # speaking of pages (``block_manager``'s docstring).
+            # slots (``llama.init_kda_state``, ``block_manager.StatePool``)
+            # beside the pool of the layers between them: latent rows, or
+            # per-head keys and values over ``n_attn_layers``. What does not
+            # carry a slot is refused here by name. Events, the index and the
+            # scorer keep speaking of pages (``block_manager``'s docstring).
             stride = config.block_manager.state_snapshot_tokens
             rows = config.decode_batch_size + config.scheduler.max_prefill_batch
             clamps = (cfg.expert_swiglu_limits or ())[: cfg.n_layers] + (
@@ -525,10 +526,10 @@ class Engine:
                     config.block_manager.host_pages > 0,
                 "remote_tier (demotion payloads are pages, not slots)":
                     config.remote_tier,
-                "kv_quant_hbm (neither the state nor the latent pool has "
-                "an int8 form)": config.kv_quant_hbm is not None,
-                "tp > 1 (the state and the latent row are not sharded)":
-                    config.tp > 1,
+                "kv_quant_hbm (the state has no int8 form, and the pool "
+                "beside it none in a program that carries slots)":
+                    config.kv_quant_hbm is not None,
+                "tp > 1 (the state is not sharded)": config.tp > 1,
                 "sp > 1 (the ring carries no state across shards)":
                     config.sp > 1,
                 "spec_decode (a rejected draft would have advanced a state "
@@ -541,10 +542,6 @@ class Engine:
                 "sliding or conv layers (a window pool or a page's state "
                 "beside: one second pool a model)":
                     cfg.n_window_layers > 0 or cfg.n_conv_layers > 0,
-                "kv_lora_rank == 0 (the layers between are latent attentions)":
-                    cfg.kv_lora_rank == 0,
-                "use_kda_lora (low-rank gate projections are not run)":
-                    cfg.kda_lora,
                 "a non-zero SwiGLU limit on a run layer (the published "
                 "config gives the limits and not where they clamp)":
                     any(clamps),
@@ -560,8 +557,8 @@ class Engine:
                 if on:
                     raise ValueError(
                         f"layer_types with {cfg.n_kda_layers} linear_attention "
-                        f"layers (a state pool of slots beside the latent "
-                        f"pool) is incompatible with {what}"
+                        f"layers (a state pool of slots beside the "
+                        f"{cfg.context_pool_name}) is incompatible with {what}"
                     )
             # a live slot a row that can hold a sequence, a second one while
             # it passes a boundary, then the snapshots; slot 0 is reserved
@@ -1860,7 +1857,8 @@ class Engine:
         bytes, so a full-width figure here would overestimate pull cost
         ~2x and wrongly decline break-even pulls."""
         cfg = self.model_cfg
-        if cfg.kv_lora_rank or cfg.n_conv_layers or cfg.n_window_layers:
+        if (cfg.kv_lora_rank or cfg.n_conv_layers or cfg.n_window_layers
+                or cfg.n_kda_layers):
             # one pool of latent rows, or the attention layers' K and V
             # beside the convolution layers' state, or the full layers' K
             # and V alone (a window page never leaves the engine): a block
@@ -1889,7 +1887,8 @@ class Engine:
             raise ValueError(
                 f"layer_types with {self.model_cfg.n_kda_layers} "
                 f"linear_attention layers (a state pool of slots beside the "
-                f"latent pool) is incompatible with {what} (export, import "
+                f"{self.model_cfg.context_pool_name}) is incompatible with "
+                f"{what} (export, import "
                 f"and migration move pages and no state slot)"
             )
         if self.model_cfg.kv_lora_rank:

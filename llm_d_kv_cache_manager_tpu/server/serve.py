@@ -1416,7 +1416,8 @@ class PodServer:
         if model.n_kda_layers and self.config.transfer_endpoint:
             raise ValueError(
                 f"layer_types with {model.n_kda_layers} linear_attention "
-                f"layers (a state pool of slots beside the latent pool) is "
+                f"layers (a state pool of slots beside the "
+                f"{model.context_pool_name}) is "
                 f"incompatible with transfer_endpoint (TRANSFER_ENDPOINT: "
                 f"export, import and migration move pages and no state slot)"
             )
@@ -4247,6 +4248,8 @@ def _resolve_model(name: str) -> LlamaConfig:
         "tiny-smallthinker": models.TINY_SMALLTHINKER,
         "inclusionAI/Ling-3.0-flash": models.LING_3_FLASH,
         "tiny-ling-hybrid": models.TINY_LING_HYBRID,
+        "upstage/Solar-Open2-250B": models.SOLAR_OPEN2_250B,
+        "tiny-solar-hybrid": models.TINY_SOLAR_HYBRID,
     }
     if name in presets:
         return presets[name]

@@ -16,6 +16,7 @@ from llm_d_kv_cache_manager_tpu.models import (
     LING_3_FLASH,
     TINY_LING_HYBRID,
     TINY_MLA_MOE,
+    TINY_SOLAR_HYBRID,
     llama,
 )
 from llm_d_kv_cache_manager_tpu.server import (
@@ -53,8 +54,6 @@ def params():
     (dict(model=dataclasses.replace(
         CFG, layer_types=("linear_attention", "conv") * 3,
         conv_L_cache=3)), "sliding or conv layers"),
-    (dict(model=dataclasses.replace(CFG, kv_lora_rank=0)), "kv_lora_rank == 0"),
-    (dict(model=dataclasses.replace(CFG, kda_lora=True)), "use_kda_lora"),
     (dict(model=dataclasses.replace(
         CFG, expert_swiglu_limits=(0, 0, 0, 4, 0, 0))), "SwiGLU limit"),
     (dict(model=dataclasses.replace(
@@ -74,6 +73,77 @@ def test_engine_refuses_by_name(what, name):
     config = dataclasses.replace(config, **what)
     with pytest.raises(ValueError, match="linear_attention.*" + name):
         Engine(config)
+
+
+# -- ... and beside per-head K/V pools (``TINY_SOLAR_HYBRID``) the same ---------
+SOLAR = TINY_SOLAR_HYBRID
+
+
+@pytest.mark.parametrize("what, name", [
+    (dict(block_manager=pages(host_pages=8)), "host_pages"),
+    (dict(remote_tier=True), "remote_tier"),
+    (dict(kv_quant_hbm="int8"), "kv_quant_hbm"),
+    (dict(tp=2), "tp > 1"),
+    (dict(sp=2), "sp > 1"),
+    (dict(spec_decode="prompt_lookup"), "spec_decode"),
+    (dict(model=dataclasses.replace(SOLAR, block_length=4)), "block_length"),
+    (dict(model=dataclasses.replace(
+        SOLAR, layer_types=("linear_attention", "sliding_attention") * 4,
+        sliding_window=8)), "sliding or conv layers"),
+    (dict(scheduler=SchedulerConfig(chunked_prefill_tokens=16)),
+     "chunked_prefill_tokens"),
+    (dict(block_manager=pages(state_snapshot_tokens=6)),
+     "state_snapshot_tokens=6"),
+])
+def test_engine_refuses_by_name_beside_kv_pools(what, name):
+    """What a model with state is refused stays refused where the pool
+    beside the slots holds per-head keys and values, and says so."""
+    config = EngineConfig(
+        model=SOLAR, block_manager=pages(), interpret=True, prefill_bucket=16)
+    config = dataclasses.replace(config, **what)
+    with pytest.raises(
+            ValueError, match="linear_attention.*key/value pools.*" + name):
+        Engine(config)
+
+
+def test_an_engine_is_built_with_both_pools():
+    """``kv_lora_rank == 0`` and low-rank gate projections are no refusals:
+    K and V pools over the GQA layers, the state pool of slots over the
+    linear ones, and ``/stats``' sizes of both."""
+    engine = Engine(EngineConfig(
+        model=SOLAR, block_manager=pages(state_snapshot_slots=40),
+        decode_batch_size=4, scheduler=SchedulerConfig(max_prefill_batch=2),
+        interpret=True, prefill_bucket=16))
+    assert SOLAR.kda_lora and SOLAR.kv_lora_rank == 0
+    assert (SOLAR.n_attn_layers, SOLAR.n_kda_layers) == (2, 6)
+    assert engine.k_pages.shape == engine.v_pages.shape == (2, 32, PS, 1, 16)
+    matrices, rows = engine.state_pages
+    assert matrices.shape == (6, 4 + 2 + 40, 4, 16, 16)
+    assert rows.shape == (6, 46, 9, 64)
+    # 2 GQA layers x (K + V) x 1 head of 16 float32 beside a snapshot's bytes
+    # over the stride
+    assert engine.kv_bytes_per_token == 2 * 2 * 16 * 4
+    assert engine.state_bytes_per_token == SOLAR.kda_state_bytes // 8
+    assert engine.kv_block_bytes == PS * engine.kv_bytes_per_token
+    stats = engine.state_pool_stats()
+    assert stats["state_slots"] == 46
+    assert stats["state_bytes_per_snapshot"] == SOLAR.kda_state_bytes
+    with pytest.raises(ValueError, match="key/value pools.*export_kv_blocks"):
+        engine.export_kv_blocks([1, 2])
+
+
+def test_a_low_rank_decay_beside_the_latent_pool_is_built(params):
+    """``use_kda_lora`` on the linear-and-latent model: the layer's leaves
+    are the pair, and the engine takes it."""
+    cfg = dataclasses.replace(CFG, kda_lora=True)
+    tree = jax.eval_shape(lambda: llama.init_params(jax.random.PRNGKey(0), cfg))
+    assert "kda_wf" not in tree["layers"][0]
+    assert tree["layers"][0]["kda_wf_down"].shape == (64, 16)
+    assert tree["layers"][0]["kda_wf_up"].shape == (16, 64)
+    assert tree["layers"][0]["kda_wg"].shape == (64, 4)  # the gate stays a head's
+    engine = Engine(EngineConfig(
+        model=cfg, block_manager=pages(), interpret=True, prefill_bucket=16))
+    assert engine.block_manager.state is not None
 
 
 def test_a_clamp_on_a_layer_that_is_not_run_is_no_refusal(params):
@@ -254,7 +324,6 @@ def test_the_configuration_file_is_the_catalogs_row_cut():
 
 
 @pytest.mark.parametrize("change, name", [
-    (dict(use_kda_lora=True), "use_kda_lora"),
     (dict(score_function="softmax"), "score_function"),
     (dict(num_kv_heads_for_linear_attn=8), "num_kv_heads_for_linear_attn"),
     (dict(group_norm_size=4), "group_norm_size"),
@@ -267,6 +336,45 @@ def test_the_loader_refuses_by_name(change, name):
     from llm_d_kv_cache_manager_tpu.models.hf_loader import config_from_hf
 
     hf = types.SimpleNamespace(**{**_catalog_row()["config"], **change})
+    with pytest.raises(NotImplementedError, match=name):
+        config_from_hf(hf)
+
+
+def test_the_loader_reads_use_kda_lora_as_a_low_rank_decay():
+    from llm_d_kv_cache_manager_tpu.models.hf_loader import config_from_hf
+
+    hf = types.SimpleNamespace(
+        **{**_catalog_row()["config"], "use_kda_lora": True})
+    assert config_from_hf(hf) == dataclasses.replace(LING_3_FLASH, kda_lora=True)
+
+
+def _solar_row():
+    with open(CATALOG) as f:
+        for line in f:
+            row = json.loads(line)
+            if row["name"] == "Solar-Open2-250B":
+                return row
+    pytest.skip("the catalog has no such row")
+
+
+@pytest.mark.parametrize("change, name", [
+    (dict(gqa_layers=[0, 5, 8, 12, 16, 20, 24, 28, 32, 36, 40, 44]), "gqa_layers"),
+    (dict(gqa_layers=[3, 7, 11, 15, 19, 23, 27, 31, 35, 39, 43, 47]), "gqa_layers"),
+    (dict(gqa_interval=5), "gqa_layers"),
+    (dict(use_rope=True), "use_rope"),
+    (dict(kda_use_full_proj=True), "kda_use_full_proj"),
+    (dict(kda_allow_neg_eigval=False), "kda_allow_neg_eigval"),
+    (dict(use_gqa_gate=False), "use_gqa_gate"),
+    (dict(first_k_dense_replace=1), "first_k_dense_replace"),
+    (dict(linear_attn_config={"short_conv_kernel_size": 4, "head_dim": 128,
+                              "num_heads": 64, "num_kv_heads": 8}), "num_kv_heads"),
+    (dict(scoring_func="softmax"), "scoring_func"),
+    (dict(rope_scaling={"type": "yarn", "factor": 4}), "yarn"),
+])
+def test_the_solar_open2_mapping_refuses_by_name(change, name):
+    from llm_d_kv_cache_manager_tpu.models.hf_loader import config_from_hf
+
+    hf = types.SimpleNamespace(**{**_solar_row()["config"], **change})
     with pytest.raises(NotImplementedError, match=name):
         config_from_hf(hf)
 

@@ -24,6 +24,7 @@ from llm_d_kv_cache_manager_tpu.models import (
     TINY_SDAR_MOE,
     TINY_LING_HYBRID,
     TINY_SMALLTHINKER,
+    TINY_SOLAR_HYBRID,
     TINY_SWA_MOE,
     llama,
 )
@@ -56,6 +57,11 @@ CONFIGS = {
     # scope of their own: no dense FFN, no shared expert
     "prerouted": (TINY_SMALLTHINKER, EVERY | {
         "attn_window", "moe_preroute", "moe_router", "moe_experts"}),
+    # linear layers beside a gated GQA layer in one program: the low-rank
+    # pairs, the kernel and the gated norm under ``kda``, the GQA gate under
+    # ``attn``; every layer routed beside a shared expert (one period)
+    "linear_gqa": (dataclasses.replace(TINY_SOLAR_HYBRID, n_layers=4),
+                   EVERY | ROUTED - {"ffn"} | {"kda"}),
 }
 
 
@@ -129,7 +135,7 @@ def _lowered_scopes(cfg, program) -> frozenset:
 CASES = [
     (kind, program)
     for kind in ("dense", "routed", "latent", "conv", "double", "window",
-                 "linear", "prerouted")
+                 "linear", "prerouted", "linear_gqa")
     for program in ("decode_steps", "prefill")
 ] + [("blocks", "prefill"), ("blocks", "denoise_steps")]
 
